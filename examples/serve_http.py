@@ -18,6 +18,11 @@ own per-tenant goodput accounting (``engine.stats()["tenants"]``) and
 the front door's shed counts — the operator view of one noisy
 neighbor being priced instead of everyone being slow.
 
+The engine lands on the default place (the TPU when jax finds one,
+else the CPU); the TTFT this demo prints is a host-clock illustration,
+not a measurement — on the measured paths (``chip_smoke.py``,
+``bench.py``) a missing chip is an error.
+
 Usage:
     python examples/serve_http.py [--interactive 6] [--batch 6]
 """

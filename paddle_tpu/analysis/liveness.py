@@ -22,6 +22,11 @@ The model (documented so the cross-check tolerance is auditable):
   consuming eqn; results unused later are charged at their producing
   point only (they materialize, then free);
 * **outputs are pinned** from their producing eqn to program end;
+* **updates run in place**: a ``scatter`` / ``dynamic_update_slice``
+  whose updated operand is freeable and dies at that eqn reuses the
+  operand's buffer for its result (what XLA does with a donated KV
+  pool or cache) — charging both would bill every pool twice and
+  refuse any engine whose pool takes more than half the device;
 * **sub-jaxprs** (pjit / shard_map / scan / while / cond /
   custom_vjp) are walked recursively: the inner program's peak is
   charged at the calling eqn with the operand/result bytes already
@@ -144,6 +149,13 @@ def _is_literal(v) -> bool:
     return hasattr(v, "val")
 
 
+# primitives whose result reuses operand 0's buffer when that operand
+# dies at the eqn (see "updates run in place" in the module docstring)
+_IN_PLACE_UPDATES = frozenset({
+    "scatter", "scatter-add", "scatter-mul", "scatter-min", "scatter-max",
+    "dynamic_update_slice"})
+
+
 def _walk(jaxpr, donated: Optional[Sequence[bool]], base: int,
           points: List[PeakPoint], depth: int) -> int:
     """Linear liveness scan over one (raw) jaxpr level. ``base`` is the
@@ -185,6 +197,11 @@ def _walk(jaxpr, donated: Optional[Sequence[bool]], base: int,
     for i, eqn in enumerate(eqns):
         out_total = sum(aval_bytes(v.aval) for v in eqn.outvars
                         if not _is_literal(v))
+        if eqn.primitive.name in _IN_PLACE_UPDATES:
+            target = eqn.invars[0]
+            if not _is_literal(target) and target in live \
+                    and last_use.get(target) == i:
+                out_total -= min(out_total, live[target])
         at_point = base + cur + out_total
         subs = [x for x in _sub_jaxprs_raw(eqn)]
         inner_peak = 0

@@ -57,8 +57,8 @@ Python cannot enforce (≙ the reference's tools/codestyle custom checks
   AST cannot see array shapes, so literal dims that fail the divisible
   test are flagged and a spec that is legal because the block IS the
   full array dim carries a ``# lint: ok`` suppression with the argument
-  adjacent. This is the exact ``(1, 128)``-block crash BENCH_r02
-  recorded on hardware (flash-attention LSE output), turned into a
+  adjacent. This is the ``(1, 128)``-block crash once recorded on
+  hardware (flash-attention LSE output), turned into a
   standing static check. SMEM specs and shapeless (whole-array) specs
   are exempt; dynamic dims (names/expressions) are trusted — the
   kernels derive them from array shapes.
@@ -372,7 +372,7 @@ def lint_source(path: str, source: str, relpath: str) -> List[LintFinding]:
                         "pallas-block-tiling", path, node.lineno,
                         f"BlockSpec second-to-last block dim {sub} is "
                         f"not divisible by 8: Mosaic rejects the layout "
-                        f"on TPU (the BENCH_r02 (1, 128) crash) unless "
+                        f"on TPU (the (1, 128) block crash) unless "
                         f"it equals the array dim — if it provably "
                         f"does, argue it in an adjacent comment and "
                         f"suppress with '# lint: ok'"))

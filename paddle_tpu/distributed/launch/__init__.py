@@ -36,6 +36,35 @@ RESTART_EXIT_CODE = 101
 from ..elastic import _free_port  # shared bind-port-0 helper  # noqa: E402
 
 
+def check_one_process_per_chip(nprocs: int, platforms: Optional[str]) -> None:
+    """Refuse ``nprocs > 1`` local processes that would all open the TPU.
+
+    A chip belongs to one process at a time, and nothing here maps a
+    rank to a visible chip (``FLAGS_selected_tpus`` is read by
+    ``ParallelEnv`` only): on a TPU host every child would claim ALL
+    local chips and all but the first would fail or hang at backend
+    start-up. The supported shape is one process driving all local chips
+    (the module docstring). ``platforms`` is the ``JAX_PLATFORMS`` value
+    the children get: an explicit list decides; with none, jax picks the
+    TPU exactly when libtpu and a chip's device node are present."""
+    if nprocs <= 1:
+        return
+    if platforms:
+        on_tpu = "tpu" in platforms.split(",")
+    else:
+        import glob
+        import importlib.util
+        on_tpu = importlib.util.find_spec("libtpu") is not None and bool(
+            glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+    if on_tpu:
+        raise RuntimeError(
+            f"{nprocs} processes per host on a TPU backend: every process "
+            f"would claim all local chips and all but one would fail or "
+            f"hang. Drive the local chips from ONE process "
+            f"(nprocs / --nproc_per_node 1 with a device mesh), or run "
+            f"the extra processes on the CPU backend")
+
+
 def _parse(argv: Optional[List[str]] = None):
     p = argparse.ArgumentParser(
         prog="paddle_tpu.distributed.launch",
@@ -91,6 +120,9 @@ class _Pod:
         if self.membership is not None:
             nnodes, node_rank, master = self.membership
         world = nnodes * a.nproc_per_node
+        check_one_process_per_chip(
+            a.nproc_per_node,
+            a.backend or os.environ.get("JAX_PLATFORMS"))
         if master is None:
             if nnodes > 1:
                 raise SystemExit(

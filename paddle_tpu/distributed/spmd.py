@@ -134,8 +134,8 @@ class ParallelEngine:
         from jax.sharding import NamedSharding
 
         # TPU Pallas smoke gate: a kernel that cannot lower on this chip
-        # must degrade to the lax path, never crash the compiled step
-        # (r2 verdict item 1b)
+        # raises PallasSmokeError naming it (FLAGS_use_pallas=0 is the
+        # user's way out, never taken on their behalf)
         from ..ops import pallas_smoke
         pallas_smoke.ensure()
 

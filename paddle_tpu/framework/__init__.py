@@ -5,33 +5,6 @@ import jax as _jax
 # in 32-bit unless explicitly asked for 64-bit values.
 _jax.config.update("jax_enable_x64", True)
 
-# jax < 0.5 ships shard_map only under jax.experimental (and with the
-# pre-rename kwargs: auto/check_rep instead of axis_names/check_vma);
-# every sharded path here (collectives, SPMD engine, pipeline, ring
-# attention) uses the public jax.shard_map surface, so adapt it on older
-# images: axis_names lists the axes that go MANUAL, which is the
-# complement of the old `auto` set.
-if not hasattr(_jax, "shard_map"):
-    try:
-        from jax.experimental.shard_map import shard_map as _sm_old
-
-        def _shard_map_compat(f, mesh, in_specs, out_specs,
-                              axis_names=None, check_vma=None, **kw):
-            if axis_names is not None:
-                kw["auto"] = frozenset(mesh.axis_names) - \
-                    frozenset(axis_names)
-            if check_vma is not None:
-                kw["check_rep"] = check_vma
-            return _sm_old(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
-        _jax.shard_map = _shard_map_compat
-    except Exception as _e:  # pragma: no cover - depends on jax build
-        import warnings
-        warnings.warn(
-            f"jax.shard_map unavailable and the compat import failed "
-            f"({_e!r}); sharded paths will raise AttributeError")
-
 from . import dtypes  # noqa: E402,F401
 from .dtypes import (bfloat16, bool_, complex64, complex128,  # noqa: E402,F401
                      convert_dtype, float16, float32, float64,
@@ -49,7 +22,7 @@ from .tensor import (GradNode, Parameter, Tensor,  # noqa: E402,F401
                      is_grad_enabled, no_grad, no_grad_guard, run_backward)
 from .dispatch import call_op  # noqa: E402,F401
 
-# env-seeded persistent XLA compilation cache: FLAGS_compile_cache=1
-# arms it for the whole process at import, mirroring FLAGS_enable_profiler
+# persistent XLA compilation cache: armed for the whole process at
+# import unless FLAGS_compile_cache=0 (directory rule: compile_cache.py)
 from . import compile_cache  # noqa: E402,F401
 compile_cache.maybe_enable()

@@ -1,6 +1,6 @@
 """Error-raising helpers.
 
-Analog of ``PADDLE_ENFORCE*`` and the typed error taxonomy in
+Analog of ``PADDLE_ENFORCE*`` and the typed error hierarchy in
 /root/reference/paddle/fluid/platform/enforce.h and
 paddle/phi/core/errors.h. Python-level since all device-side failure comes
 back through XLA as exceptions already carrying device context.

@@ -98,14 +98,12 @@ define_flag("FLAGS_enable_profiler", False,
 define_flag("FLAGS_profiler_max_events", 1_000_000,
             "Span buffer cap: past it events are dropped (and counted in "
             "profiler.dropped()) instead of growing host memory")
-define_flag("FLAGS_compile_cache", False,
+define_flag("FLAGS_compile_cache", True,
             "Persist XLA-compiled executables to disk "
             "(framework/compile_cache.py) so repeat runs skip recompiles; "
-            "armed at import when env-seeded (FLAGS_compile_cache=1)")
-define_flag("FLAGS_compile_cache_dir", "",
-            "Directory for the persistent XLA compilation cache; empty "
-            "means JAX_COMPILATION_CACHE_DIR or "
-            "~/.cache/paddle_tpu/xla_cache (the autotune-cache root)")
+            "armed at import. The directory is JAX_COMPILATION_CACHE_DIR "
+            "if set, else <checkout>/.cache/xla. FLAGS_compile_cache=0 "
+            "switches it off")
 define_flag("FLAGS_static_analysis", "off",
             "Default mode for the jaxpr-level program linter "
             "(paddle_tpu/analysis): 'warn' runs the pass pipeline over "

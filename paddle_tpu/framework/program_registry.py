@@ -46,18 +46,23 @@ import logging
 import os
 import threading
 import time
+import weakref
 from typing import Any, Dict, Optional, Tuple
 
 from .monitor import stat_add, stat_observe
 
 __all__ = ["ProgramRecord", "aot_site", "AotSite", "note_compile", "get",
            "snapshot", "reset", "analyze_compiled", "analyze_callable",
-           "peak_flops", "PEAK_FLOPS_TABLE"]
+           "compiled_text", "peak_flops", "PEAK_FLOPS_TABLE"]
 
 logger = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _records: Dict[str, "ProgramRecord"] = {}
+# live AotSites by name, for compiled_text(); weak, so a site dies with
+# the Model / engine that owns it
+_sites: "weakref.WeakValueDictionary[str, AotSite]" = \
+    weakref.WeakValueDictionary()
 # same bound discipline as trace_probe: a notebook sweep creating
 # thousands of Models must not grow host memory without bound; past the
 # cap records still accumulate for callers holding them by reference,
@@ -161,6 +166,20 @@ def snapshot() -> Dict[str, dict]:
         return {name: r.as_dict() for name, r in _records.items()}
 
 
+def compiled_text(site: str) -> Optional[str]:
+    """The optimized HLO text of every executable the live
+    :class:`AotSite` named ``site`` holds, concatenated — ``None`` when
+    no such site is alive or it compiled nothing explicitly. This is how
+    a caller confirms WHICH kernels a step really contains (a Pallas
+    kernel is a ``tpu_custom_call`` there), rather than which were
+    enabled. Rendered on demand; nothing is kept per compile."""
+    with _lock:
+        aot = _sites.get(site)
+    if aot is None or not aot._compiled:
+        return None
+    return "\n".join(c.as_text() for c in list(aot._compiled.values()))
+
+
 def reset() -> None:
     with _lock:
         _records.clear()
@@ -238,16 +257,10 @@ def analyze_callable(fn, *example_args, static_argnums=(),
         jitted = fn if hasattr(fn, "lower") else \
             jax.jit(fn, static_argnums=static_argnums)
         t0 = time.perf_counter()
-        eqns = None
-        static_peak = None
-        try:
-            traced = jitted.trace(*example_args)
-            eqns = len(traced.jaxpr.jaxpr.eqns)
-            static_peak = static_peak_of_trace(traced.jaxpr)
-            compiled = traced.lower().compile()
-        except AttributeError:
-            # older jax without .trace(): lower directly, skip eqn count
-            compiled = jitted.lower(*example_args).compile()
+        traced = jitted.trace(*example_args)
+        eqns = len(traced.jaxpr.jaxpr.eqns)
+        static_peak = static_peak_of_trace(traced.jaxpr)
+        compiled = traced.lower().compile()
         wall_ms = (time.perf_counter() - t0) * 1e3
     except Exception as e:                               # noqa: BLE001
         logger.debug("analyze_callable: trace/compile failed: %r", e)
@@ -351,6 +364,8 @@ class AotSite:
         self.last_dispatch_flops: Optional[float] = None
         self._fallback = False
         self._seen_fallback_keys: set = set()
+        with _lock:
+            _sites[name] = self
 
     # -- key building ------------------------------------------------------
     def _key(self, args):

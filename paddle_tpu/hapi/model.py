@@ -1035,7 +1035,7 @@ class Model:
     # -- single-batch APIs (reference train_batch/eval_batch/predict_batch) -
     def _pallas_gate(self):
         # same smoke gate as ParallelEngine._build: a Pallas kernel that
-        # cannot lower on this chip must degrade to lax, not crash fit()
+        # cannot lower on this chip raises PallasSmokeError naming it
         from ..ops import pallas_smoke
         pallas_smoke.ensure()
 

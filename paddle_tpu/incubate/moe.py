@@ -258,10 +258,7 @@ class MoELayer(nn.Layer):
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         s, d = tokens.shape
         e = self.num_experts

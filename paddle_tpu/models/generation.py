@@ -747,69 +747,118 @@ def _append_nonfinite_flag(nxt, logits):
 
 
 # ---------------------------------------------------------------------------
-# quantized KV blocks (PagedKVPool(dtype="int8"): per-block max-abs
-# scales in a parallel [L, 2, num_blocks + 1, H] f32 array — the EQuARX
-# per-chunk scheme of the PR-10 gradient wire, applied to KV storage)
+# the paged block pool's row layout: [L, NB + 1, H, bs, 2 * Dh] — K in
+# lanes [0, Dh), V in lanes [Dh, 2 * Dh) of one (bs, 2 * Dh) tile per
+# (block, head), so the tile is 128 lanes wide at Dh = 64 (what the fused
+# kernel's DMA needs, ops/ragged_paged_attention.py). Quantized pools
+# (PagedKVPool(dtype="int8")) keep per-block max-abs scales in a parallel
+# [L, 2, NB + 1, H] f32 array (plane 0 = K, 1 = V) — the EQuARX per-chunk
+# scheme of the PR-10 gradient wire, applied to KV storage.
 # ---------------------------------------------------------------------------
 
-def _quant_append(pool, scales, li, kv, wb, off, rows, qmax):
+def _kv_lanes(k, v):
+    """K|V folded into the lanes: two ``[..., Dh]`` arrays -> one
+    ``[..., 2 * Dh]`` pool row."""
+    import jax.numpy as jnp
+    return jnp.concatenate([k, v], axis=-1)
+
+
+def _write_rows(pool, li, wb, off, k_rows, v_rows):
+    """Write one K|V row per (token, head) into layer ``li`` of the
+    pool: ``k_rows``/``v_rows [N, H, Dh]`` land at ``(block wb[n], head
+    h, offset off[n])``.
+
+    Every axis but the lanes is INDEXED (the head axis by an explicit
+    ``arange``), so the scatter's window is one 128-lane row — the
+    minor-most dim. Written as ``pool.at[li, wb, :, off, :]``, window
+    ``(H, 2 * Dh)`` around the offset axis, XLA's TPU layout assignment
+    moves the head axis next to the lanes, and every layer then pays a
+    relayout COPY of the whole pool on the way into the attention
+    kernel, which needs the default layout (seen compiling the step for
+    a v5e: a second pool-sized HBM buffer)."""
+    import jax.numpy as jnp
+    H = pool.shape[2]
+    rows = _kv_lanes(k_rows, v_rows).astype(pool.dtype)       # [N,H,2Dh]
+    heads = jnp.arange(H, dtype=jnp.int32)[None, :]
+    return pool.at[li, wb[:, None], heads, off[:, None], :].set(rows)
+
+
+def _scale_lanes(sc, dh):
+    """Per-plane scales ``[2, ..., H]`` -> the lane-wise multiplier
+    ``[..., H, 2 * Dh]`` of a pool row (K scale over the K lanes, V
+    scale over the V lanes)."""
+    import jax.numpy as jnp
+    return jnp.repeat(jnp.moveaxis(sc, 0, -1), dh, axis=-1)
+
+
+def _gather_kv(pool, scales, li, tables):
+    """Gather-path read of the pool: materialize the virtual cache of
+    layer ``li`` through the page table, ``tables [S, T]`` -> ``(k, v)``
+    each ``[S, T * bs, H, Dh]``. A quantized pool (``scales`` given) is
+    dequantized AFTER the pool read — the per-block scales are
+    multiplied back in, f32 out."""
+    import jax.numpy as jnp
+    g = pool[li][tables]                          # [S, T, H, bs, 2*Dh]
+    S, T, H, bs, dh2 = g.shape
+    dh = dh2 // 2
+    if scales is not None:
+        g = g.astype(jnp.float32) * _scale_lanes(
+            scales[li][:, tables], dh)[..., None, :]
+    g = jnp.transpose(g, (0, 1, 3, 2, 4)).reshape(S, T * bs, H, dh2)
+    return g[..., :dh], g[..., dh:]
+
+
+def _quant_append(pool, scales, li, wb, off, k_rows, v_rows, qmax):
     """Scatter per-row K/V values into a QUANTIZED block pool.
 
-    ``rows [N, H, Dh]`` f32 land at ``(block wb[n], offset off[n])`` of
-    plane ``(li, kv)``. Per-block max-abs scales grow monotonically: a
-    row whose magnitude exceeds its block's current scale bumps the
-    scale (scatter-max) and the touched blocks are REQUANTIZED to the
-    new scale in the same step — when the scale is unchanged the
-    requantize ratio is exactly 1.0, so steady-state appends never
-    erode earlier rows. Duplicate ``wb`` entries (a prefill chunk
-    writing several offsets of one block, or pad rows aimed at the
-    scratch block) are safe: the scatter-max makes every duplicate see
-    the same old/new scales, so their requantized block bytes are
-    identical, and the row offsets are distinct by construction.
-    Returns ``(pool, scales)``."""
+    ``k_rows``/``v_rows [N, H, Dh]`` land at ``(block wb[n], offset
+    off[n])`` of layer ``li``. Per-block max-abs scales grow
+    monotonically: a row whose magnitude exceeds its block's current
+    scale bumps the scale (scatter-max) and the touched blocks are
+    REQUANTIZED to the new scale in the same step — when the scale is
+    unchanged the requantize ratio is exactly 1.0, so steady-state
+    appends never erode earlier rows. Duplicate ``wb`` entries (a
+    prefill chunk writing several offsets of one block, or pad rows
+    aimed at the scratch block) are safe: the scatter-max makes every
+    duplicate see the same old/new scales, so their requantized block
+    bytes are identical, and the row offsets are distinct by
+    construction. Returns ``(pool, scales)``."""
     import jax.numpy as jnp
-    rows = rows.astype(jnp.float32)
-    rmax = jnp.max(jnp.abs(rows), axis=-1) / qmax             # [N, H]
-    old = scales[li, kv]                                      # [NB+1, H]
-    new = old.at[wb].max(rmax)
-    nb = jnp.maximum(new[wb], 1e-30)                          # [N, H]
-    ratio = jnp.where(new[wb] > 0, old[wb] / nb, 1.0)
-    blk = pool[li, kv, wb].astype(jnp.float32)                # [N,H,bs,Dh]
-    requant = jnp.clip(jnp.round(blk * ratio[..., None, None]),
-                       -qmax, qmax).astype(pool.dtype)
-    pool = pool.at[li, kv, wb].set(requant)
-    qrow = jnp.clip(jnp.round(jnp.where(new[wb][..., None] > 0,
+    rows = jnp.stack([k_rows, v_rows]).astype(jnp.float32)  # [2,N,H,Dh]
+    dh = rows.shape[-1]
+    rmax = jnp.max(jnp.abs(rows), axis=-1) / qmax             # [2, N, H]
+    old = scales[li]                                          # [2,NB+1,H]
+    new = old.at[:, wb].max(rmax)
+    new_wb = new[:, wb]                                       # [2, N, H]
+    nb = jnp.maximum(new_wb, 1e-30)
+    ratio = jnp.where(new_wb > 0, old[:, wb] / nb, 1.0)
+    blk = pool[li, wb].astype(jnp.float32)                # [N,H,bs,2*Dh]
+    requant = jnp.clip(
+        jnp.round(blk * _scale_lanes(ratio, dh)[..., None, :]),
+        -qmax, qmax).astype(pool.dtype)
+    pool = pool.at[li, wb].set(requant)
+    qrow = jnp.clip(jnp.round(jnp.where(new_wb[..., None] > 0,
                                         rows / nb[..., None], 0.0)),
                     -qmax, qmax).astype(pool.dtype)
-    pool = pool.at[li, kv, wb, :, off, :].set(qrow)
-    return pool, scales.at[li, kv].set(new)
+    pool = _write_rows(pool, li, wb, off, qrow[0], qrow[1])
+    return pool, scales.at[li].set(new)
 
 
-def _quant_write_blocks(pool, scales, li, kv, table, vals, qmax):
-    """Whole-block quantized write (the paged prefill path): ``vals
-    [Tp, H, bs, Dh]`` f32 replace the blocks named by ``table [Tp]``,
-    each with a fresh per-(block, head) max-abs scale — freshly
-    allocated blocks have no prior content worth rescaling. Returns
-    ``(pool, scales)``."""
+def _quant_write_blocks(pool, scales, li, table, k_vals, v_vals, qmax):
+    """Whole-block quantized write (the paged prefill path):
+    ``k_vals``/``v_vals [Tp, H, bs, Dh]`` replace the blocks named by
+    ``table [Tp]``, each with a fresh per-(block, head) max-abs scale —
+    freshly allocated blocks have no prior content worth rescaling.
+    Returns ``(pool, scales)``."""
     import jax.numpy as jnp
-    vals = vals.astype(jnp.float32)
-    sc = jnp.max(jnp.abs(vals), axis=(-2, -1)) / qmax         # [Tp, H]
+    vals = jnp.stack([k_vals, v_vals]).astype(jnp.float32)
+    sc = jnp.max(jnp.abs(vals), axis=(-2, -1)) / qmax      # [2, Tp, H]
     denom = jnp.maximum(sc, 1e-30)[..., None, None]
     q = jnp.clip(jnp.round(jnp.where(sc[..., None, None] > 0,
                                      vals / denom, 0.0)),
                  -qmax, qmax).astype(pool.dtype)
-    pool = pool.at[li, kv, table].set(q)
-    return pool, scales.at[li, kv, table].set(sc)
-
-
-def _dequant_gather(pool, scales, li, kv, tables):
-    """Gather-path read of a quantized pool: materialize the virtual
-    cache through the page table and multiply the per-block scales
-    back in AFTER the pool read ("the gather path multiplies after the
-    pool read"). ``tables [S, T]`` -> f32 ``[S, T, H, bs, Dh]``."""
-    import jax.numpy as jnp
-    return pool[li, kv][tables].astype(jnp.float32) \
-        * scales[li, kv][tables][..., None, None]
+    pool = pool.at[li, table].set(_kv_lanes(q[0], q[1]))
+    return pool, scales.at[li].set(scales[li].at[:, table].set(sc))
 
 
 # ---------------------------------------------------------------------------
@@ -830,8 +879,8 @@ def build_paged_prefill_fn(model, bucket_len, block_size, top_k=0,
     K/V computed in the model dtype and written through
     :func:`_quant_write_blocks`:
 
-    * ``pool`` — the block pool ``[layers, 2, num_blocks + 1, heads,
-      block_size, head_dim]`` (``serving.PagedKVPool.data``); the
+    * ``pool`` — the block pool ``[layers, num_blocks + 1, heads,
+      block_size, 2 * head_dim]`` (``serving.PagedKVPool.data``); the
       prompt's K/V are scattered block-wise through ``table``
       ``[bucket_len // block_size]`` int32 (physical block per virtual
       block; 0 = the scratch block for entries past the allocation);
@@ -903,12 +952,11 @@ def build_paged_prefill_fn(model, bucket_len, block_size, top_k=0,
                                        (0, 2, 1, 3))
                     if quantized:
                         new_pool, new_scales = _quant_write_blocks(
-                            new_pool, new_scales, li, 0, table, kb, qmax)
-                        new_pool, new_scales = _quant_write_blocks(
-                            new_pool, new_scales, li, 1, table, vb, qmax)
+                            new_pool, new_scales, li, table, kb, vb,
+                            qmax)
                     else:
-                        new_pool = new_pool.at[li, 0, table].set(kb)
-                        new_pool = new_pool.at[li, 1, table].set(vb)
+                        new_pool = new_pool.at[li, table].set(
+                            _kv_lanes(kb, vb))
                 x = gpt.ln_f(x)
                 z = jnp.int32(0)
                 p = jnp.asarray(plen, jnp.int32).reshape(())
@@ -937,9 +985,9 @@ def build_paged_decode_fn(model, num_slots, table_len, block_size,
 
     Returns ``fn(params, buffers, pool, tokens, pos, lo, tables,
     sample_mask, temperature, key) -> (pool, next_tokens, key)`` over
-    the block pool ``[layers, 2, num_blocks + 1, heads, block_size,
-    head_dim]`` (``next_tokens`` ``[slots + 1]`` — the last element is
-    the logits-finite sentinel, see :func:`_append_nonfinite_flag`):
+    the block pool ``[layers, num_blocks + 1, heads, block_size,
+    2 * head_dim]`` (``next_tokens`` ``[slots + 1]`` — the last element
+    is the logits-finite sentinel, see :func:`_append_nonfinite_flag`):
 
     * ``tables`` ``[slots, table_len]`` int32 — each slot's page table
       padded with 0 (the scratch block) to the pow2 table bucket; the
@@ -948,7 +996,7 @@ def build_paged_decode_fn(model, num_slots, table_len, block_size,
       block_size`` (the per-slot scatter of the dense step, routed
       through the page table);
     * attention runs over the GATHERED virtual cache
-      ``pool[li, :, tables]`` reshaped to ``[slots, table_len *
+      ``pool[li][tables]`` reshaped to ``[slots, table_len *
       block_size, heads, head_dim]`` with the ``[lo, pos]`` mask and
       logical positions ``pos - lo`` unchanged from the dense step —
       scratch-block garbage is masked, never NaN;
@@ -961,7 +1009,7 @@ def build_paged_decode_fn(model, num_slots, table_len, block_size,
       per-block scale array beside the pool (``fn(params, buffers,
       pool, scales, tokens, ...) -> (pool, scales, next_tokens,
       key)``): appends go through :func:`_quant_append` and the
-      gathered virtual cache is dequantized by :func:`_dequant_gather`
+      gathered virtual cache is dequantized by :func:`_gather_kv`
       — the ``[lo, pos]`` mask, sentinel and sampling are unchanged.
     """
     import jax
@@ -977,8 +1025,6 @@ def build_paged_decode_fn(model, num_slots, table_len, block_size,
         raise ValueError(f"num_slots must be >= 1, got {S}")
     if T < 1:
         raise ValueError(f"table_len must be >= 1, got {T}")
-    H = gpt.cfg.num_attention_heads
-    Dh = gpt.cfg.hidden_size // H
     top_k = min(int(top_k), gpt.cfg.vocab_size)
 
     def fn(params, buffers, pool, *rest):
@@ -1005,30 +1051,17 @@ def build_paged_decode_fn(model, num_slots, table_len, block_size,
                     q, k, v = block._qkv(x)
                     if quantized:
                         new_pool, new_scales = _quant_append(
-                            new_pool, new_scales, li, 0, wb, off,
-                            k._data[:, 0], qmax)
-                        new_pool, new_scales = _quant_append(
-                            new_pool, new_scales, li, 1, wb, off,
-                            v._data[:, 0], qmax)
-                        kg = _dequant_gather(new_pool, new_scales, li, 0,
-                                             tables).astype(k._data.dtype)
-                        vg = _dequant_gather(new_pool, new_scales, li, 1,
-                                             tables).astype(v._data.dtype)
+                            new_pool, new_scales, li, wb, off,
+                            k._data[:, 0], v._data[:, 0], qmax)
                     else:
-                        kh = k._data[:, 0].astype(new_pool.dtype)
-                        vh = v._data[:, 0].astype(new_pool.dtype)
-                        new_pool = new_pool.at[
-                            li, 0, wb, :, off, :].set(kh)
-                        new_pool = new_pool.at[
-                            li, 1, wb, :, off, :].set(vh)
-                        kg = new_pool[li, 0][tables]
-                        vg = new_pool[li, 1][tables]
-                    # gather the virtual cache through the page table:
-                    # [NB+1, H, bs, Dh][tables] -> [S, T, H, bs, Dh]
-                    kf = jnp.transpose(kg, (0, 1, 3, 2, 4)).reshape(
-                        S, T * bs, H, Dh)
-                    vf = jnp.transpose(vg, (0, 1, 3, 2, 4)).reshape(
-                        S, T * bs, H, Dh)
+                        new_pool = _write_rows(
+                            new_pool, li, wb, off, k._data[:, 0],
+                            v._data[:, 0])
+                    # gather the virtual cache through the page table
+                    kf, vf = _gather_kv(new_pool, new_scales, li, tables)
+                    if quantized:
+                        kf = kf.astype(k._data.dtype)
+                        vf = vf.astype(v._data.dtype)
                     a = F.scaled_dot_product_attention(
                         q, Tensor(kf, stop_gradient=True),
                         Tensor(vf, stop_gradient=True), attn_mask=mask)
@@ -1069,16 +1102,11 @@ def _fused_tower(gpt, x, pool, scales, write_block, write_off, blk_seq,
         # block nobody reads
         if quantized:
             pool, scales = _quant_append(
-                pool, scales, li, 0, write_block, write_off,
-                k._data[0], qmax)
-            pool, scales = _quant_append(
-                pool, scales, li, 1, write_block, write_off,
-                v._data[0], qmax)
+                pool, scales, li, write_block, write_off,
+                k._data[0], v._data[0], qmax)
         else:
-            pool = pool.at[li, 0, write_block, :, write_off, :].set(
-                k._data[0].astype(pool.dtype))
-            pool = pool.at[li, 1, write_block, :, write_off, :].set(
-                v._data[0].astype(pool.dtype))
+            pool = _write_rows(pool, li, write_block, write_off,
+                               k._data[0], v._data[0])
         qh = jnp.transpose(q._data, (0, 2, 1, 3))[0]
         a = ragged_paged_attention(
             qh, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
@@ -1103,9 +1131,9 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     Returns ``fn(params, buffers, pool, token_ids, qpos, write_block,
     write_off, blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
     last_row, sample_mask, temperature, key) -> (pool, next_tokens,
-    key)`` over the block pool ``[layers, 2, num_blocks + 1, heads,
-    block_size, head_dim]`` (``next_tokens`` ``[num_slots + 1]`` — the
-    last element is the logits-finite sentinel of
+    key)`` over the block pool ``[layers, num_blocks + 1, heads,
+    block_size, 2 * head_dim]`` (``next_tokens`` ``[num_slots + 1]`` —
+    the last element is the logits-finite sentinel of
     :func:`_append_nonfinite_flag`):
 
     * ``token_ids``/``qpos``/``write_block``/``write_off`` ``[q_rows]``
@@ -1197,7 +1225,7 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
 # tensor-parallel serving steps (GenerationEngine(mesh=..., mp_axis="mp")):
 # the per-device Megatron twins of the paged/fused steps above, wrapped in
 # shard_map over a 1-D mp mesh. The block pool is head-partitioned
-# ([L, 2, NB+1, H/mp, bs, Dh] per device); page tables, free lists and the
+# ([L, NB+1, H/mp, bs, 2*Dh] per device); page tables, free lists and the
 # prefix trie stay replicated host-side, so the allocator/COW/preemption
 # logic never sees the mesh. Column-parallel projections slice the
 # replicated bias to their local output columns; row-parallel projections
@@ -1283,10 +1311,7 @@ def _mp_fused_tower(gpt, x, pool, write_block, write_off, blk_seq,
 
     for li, block in enumerate(gpt.blocks):
         q, k, v = _mp_qkv(block, x, mp, mp_axis)
-        pool = pool.at[li, 0, write_block, :, write_off, :].set(
-            k[0].astype(pool.dtype))
-        pool = pool.at[li, 1, write_block, :, write_off, :].set(
-            v[0].astype(pool.dtype))
+        pool = _write_rows(pool, li, write_block, write_off, k[0], v[0])
         qh = jnp.transpose(q, (0, 2, 1, 3))[0]       # [H/mp, Q, Dh]
         a = ragged_paged_attention(
             qh, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
@@ -1298,9 +1323,9 @@ def _mp_fused_tower(gpt, x, pool, write_block, write_off, blk_seq,
 
 def _mp_pool_spec(mp_axis):
     """The head-partitioned PartitionSpec of the paged block pool
-    ``[L, 2, NB+1, H, bs, Dh]`` — axis 3 (heads) over ``mp_axis``."""
+    ``[L, NB+1, H, bs, 2*Dh]`` — axis 2 (heads) over ``mp_axis``."""
     from jax.sharding import PartitionSpec as P
-    return P(None, None, None, mp_axis, None, None)
+    return P(None, None, mp_axis, None, None)
 
 
 def _mp_mesh_check(gpt, mesh, mp_axis):
@@ -1386,8 +1411,8 @@ def build_sharded_paged_prefill_fn(model, bucket_len, block_size, mesh,
                                        (0, 2, 1, 3))
                     vb = jnp.transpose(vc[0].reshape(Tp, bs, Hl, Dh),
                                        (0, 2, 1, 3))
-                    new_pool = new_pool.at[li, 0, table].set(kb)
-                    new_pool = new_pool.at[li, 1, table].set(vb)
+                    new_pool = new_pool.at[li, table].set(
+                        _kv_lanes(kb, vb))
                     a = F.scaled_dot_product_attention(
                         Tensor(q, stop_gradient=True),
                         Tensor(kc, stop_gradient=True),
@@ -1454,9 +1479,6 @@ def build_sharded_paged_decode_fn(model, num_slots, table_len,
     if T < 1:
         raise ValueError(f"table_len must be >= 1, got {T}")
     mp = _mp_mesh_check(gpt, mesh, mp_axis)
-    H = gpt.cfg.num_attention_heads
-    Hl = H // mp
-    Dh = gpt.cfg.hidden_size // H
     top_k = min(int(top_k), gpt.cfg.vocab_size)
 
     def body(params, buffers, pool, tokens, pos, lo, tables,
@@ -1476,16 +1498,10 @@ def build_sharded_paged_decode_fn(model, num_slots, table_len,
                 new_pool = pool
                 for li, block in enumerate(gpt.blocks):
                     q, k, v = _mp_qkv(block, x, mp, mp_axis)
-                    kh = k[:, 0].astype(new_pool.dtype)  # [S, H/mp, Dh]
-                    vh = v[:, 0].astype(new_pool.dtype)
-                    new_pool = new_pool.at[li, 0, wb, :, off, :].set(kh)
-                    new_pool = new_pool.at[li, 1, wb, :, off, :].set(vh)
-                    kg = new_pool[li, 0][tables]
-                    vg = new_pool[li, 1][tables]
-                    kf = jnp.transpose(kg, (0, 1, 3, 2, 4)).reshape(
-                        S, T * bs, Hl, Dh)
-                    vf = jnp.transpose(vg, (0, 1, 3, 2, 4)).reshape(
-                        S, T * bs, Hl, Dh)
+                    # [S, H/mp] rows into this device's shard
+                    new_pool = _write_rows(new_pool, li, wb, off,
+                                           k[:, 0], v[:, 0])
+                    kf, vf = _gather_kv(new_pool, None, li, tables)
                     a = F.scaled_dot_product_attention(
                         Tensor(q, stop_gradient=True),
                         Tensor(kf, stop_gradient=True),

@@ -12,9 +12,9 @@ must be static per jit trace, so selection happens at dispatch time
 - keys are SHAPE CLASSES — dims bucketed to powers of two — so one
   measurement covers a family of shapes, like the reference's cache
   keyed on (dims, dtype) tuples;
-- entries persist per device kind under ``~/.cache/paddle_tpu/`` so a
-  crossover measured once (e.g. by bench.py on real hardware) keeps
-  serving later processes on the same chip generation;
+- entries persist per device kind under ``<checkout>/.cache/`` (the
+  compile cache's root) so a crossover measured once keeps serving
+  later processes on the same chip generation;
 - ``measure()`` times candidate thunks on concrete arrays (eager mode /
   warmup), stores the winner; ``choose()`` is the hot-path lookup with a
   heuristic default and hit/miss counters.
@@ -77,10 +77,9 @@ def _kind() -> str:
 
 
 def cache_path() -> str:
-    # same per-user root as the persistent XLA compilation cache
-    # (framework/compile_cache.py): one directory carries all
-    # per-machine tuning state. PADDLE_AUTOTUNE_CACHE_DIR moves only
-    # the autotune entries; PADDLE_TPU_CACHE_ROOT moves everything.
+    # same in-checkout root as the persistent XLA compilation cache
+    # (framework/compile_cache.py): one directory carries all persistent
+    # tuning state. PADDLE_AUTOTUNE_CACHE_DIR moves the autotune entries.
     from ..framework.compile_cache import cache_root
     root = os.environ.get("PADDLE_AUTOTUNE_CACHE_DIR", cache_root())
     return os.path.join(root, f"autotune_{_kind()}.json")

@@ -15,7 +15,6 @@ for tests: FLAGS_pallas_force (runs kernels even off-TPU, interpreted).
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 
@@ -69,11 +68,7 @@ def _x64_off():
     arithmetic).  Every pallas_call invocation — which is when the kernel
     body is traced — runs under this x64-off scope; the surrounding jaxpr
     keeps its global setting."""
-    try:
-        from jax._src.config import enable_x64
-        return enable_x64(False)
-    except ImportError:  # future jax: fall back to no-op (x64 default off)
-        return contextlib.nullcontext()
+    return jax.enable_x64(False)
 
 
 # ===========================================================================
@@ -88,7 +83,7 @@ DEFAULT_BLOCK_K = 128
 # fp32 with the value replicated across the trailing lane dim.  A plain
 # [BH, S] layout with a (1, block_q) block violates the Mosaic tiling rule
 # (second-to-last block dim must be divisible by 8 or equal the array dim)
-# — the exact crash BENCH_r02 recorded on hardware.  With a trailing
+# — a crash once recorded on hardware.  With a trailing
 # LSE_LANES=8 dim, blocks are (1, block_q, 8): block_q is sublane-aligned
 # and the last block dim equals the array dim, so the layout is legal on
 # TPU at an 8x (not 128x) replication cost.
@@ -278,6 +273,7 @@ def _fa_call_fwd(q, k, v, scale, causal, block_q, block_k):
     with _x64_off():
         return pl.pallas_call(
             kernel,
+            name="flash_attention_fwd",
             grid=(bh, nq, nk),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -313,6 +309,7 @@ def _fa_call_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
             functools.partial(_fa_dq_kernel, scale=scale, causal=causal,
                               block_q=block_q, block_k=block_k,
                               n_k=sk // block_k),
+            name="flash_attention_dq",
             grid=(bh, sq // block_q, sk // block_k),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -334,6 +331,7 @@ def _fa_call_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
             functools.partial(_fa_dkv_kernel, scale=scale, causal=causal,
                               block_q=block_q, block_k=block_k,
                               n_q=sq // block_q),
+            name="flash_attention_dkv",
             grid=(bh, sk // block_k, sq // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
@@ -508,6 +506,7 @@ def _fa_call_fwd_resident(q, k, v, scale, causal, block_q, block_k):
     with _x64_off():
         return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(bh, nq),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -536,6 +535,7 @@ def _fa_call_bwd_resident(q, k, v, o, lse, do, scale, causal, block_q, block_k):
         dq = pl.pallas_call(
         functools.partial(_fa_dq_kernel_resident, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_k=sk),
+        name="flash_attention_dq",
         grid=(bh, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -552,6 +552,7 @@ def _fa_call_bwd_resident(q, k, v, o, lse, do, scale, causal, block_q, block_k):
         dk, dv = pl.pallas_call(
         functools.partial(_fa_dkv_kernel_resident, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_q=sq),
+        name="flash_attention_dkv",
         grid=(bh, sk // block_k),
         in_specs=[
             pl.BlockSpec((1, sq, d), lambda b, i: (b, 0, 0)),
@@ -872,6 +873,7 @@ def _fused_layer_norm_2d(x2, w, b, eps):
     with _x64_off():
         return pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
+        name="fused_layer_norm_fwd",
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
@@ -899,6 +901,7 @@ def _ln_bwd_rule(eps, res, g):
     with _x64_off():
         dx, dwp, dbp = pl.pallas_call(
         functools.partial(_ln_bwd_kernel, eps=eps),
+        name="fused_layer_norm_bwd",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
@@ -988,6 +991,13 @@ def _adamw_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
     new_v_ref[...] = v
 
 
+# rows of the flattened (rows, 128) parameter per grid step: 7 f32
+# operands x 2 pipeline buffers x 512 x 128 x 4 B = 3.5 MB of VMEM, far
+# inside v5e's 16 MB scoped limit — the un-gridded kernel mapped the
+# whole parameter as one block and was refused at (768, 3072)
+_ADAMW_BLOCK_ROWS = 512
+
+
 @functools.lru_cache(maxsize=1024)
 def _fused_adamw_callable(shape, dtype_name, interpret):
     """One jitted (pad → kernel → unpad) callable per param shape/dtype —
@@ -999,6 +1009,10 @@ def _fused_adamw_callable(shape, dtype_name, interpret):
     lanes = 128
     rows = max(1, (n + lanes - 1) // lanes)
     pad = rows * lanes - n
+    # a parameter shorter than one block is its own (full-dim) block;
+    # the ragged last block of a longer one is masked by Pallas
+    block_rows = min(rows, _ADAMW_BLOCK_ROWS)
+    blk = pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
 
     def run(p, g, m, v, scalars):
         def flat(a, dt):
@@ -1009,18 +1023,15 @@ def _fused_adamw_callable(shape, dtype_name, interpret):
 
         with _x64_off():
             new_p, new_m, new_v = pl.pallas_call(
-            _adamw_kernel,
-            in_specs=[pl.BlockSpec((rows, lanes), lambda: (0, 0)),
-                      pl.BlockSpec((rows, lanes), lambda: (0, 0)),
-                      pl.BlockSpec((rows, lanes), lambda: (0, 0)),
-                      pl.BlockSpec((rows, lanes), lambda: (0, 0)),
-                      pl.BlockSpec(memory_space=pltpu.SMEM)],
-            out_specs=[pl.BlockSpec((rows, lanes), lambda: (0, 0)),
-                       pl.BlockSpec((rows, lanes), lambda: (0, 0)),
-                       pl.BlockSpec((rows, lanes), lambda: (0, 0))],
-            out_shape=[jax.ShapeDtypeStruct((rows, lanes), dtype),
-                       jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
-                       jax.ShapeDtypeStruct((rows, lanes), jnp.float32)],
+                _adamw_kernel,
+                name="fused_adamw",
+                grid=(pl.cdiv(rows, block_rows),),
+                in_specs=[blk, blk, blk, blk,
+                          pl.BlockSpec(memory_space=pltpu.SMEM)],
+                out_specs=[blk, blk, blk],
+                out_shape=[jax.ShapeDtypeStruct((rows, lanes), dtype),
+                           jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
+                           jax.ShapeDtypeStruct((rows, lanes), jnp.float32)],
                 interpret=interpret,
             )(flat(p, dtype), flat(g, jnp.float32), flat(m, jnp.float32),
               flat(v, jnp.float32), scalars)

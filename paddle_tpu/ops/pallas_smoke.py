@@ -1,111 +1,174 @@
-"""TPU smoke gate for the Pallas kernel tier (r2 verdict item 1b).
+"""TPU smoke gate for the Pallas kernel tier.
 
-Interpret-mode parity tests (tests/test_pallas.py) cannot catch Mosaic
-*lowering* errors — the class of failure that killed BENCH_r02's GPT-2 and
-BERT runs on hardware.  This gate executes every registered Pallas
-override non-interpreted on the real backend at tiny shapes, fwd AND bwd,
-before the kernels are allowed to serve real models.  Any failure flips
-``FLAGS_use_pallas`` off (with a recorded warning) so a broken kernel
-degrades to the lax path instead of crashing the model.
+Interpret-mode parity tests (tests/test_pallas.py,
+tests/test_ragged_attention.py) cannot catch Mosaic *lowering* errors — a
+slice not aligned to the HBM tiling, a block that does not fit VMEM. This
+gate executes every Pallas kernel of the main path non-interpreted on the
+real backend, at the head width the supported model has (Dh = 64) and at
+sizes that cross each kernel's block grid, and compares the result with
+the kernel's plain reference, before the kernels serve a real model.
 
-Reference analog: the reference gates fused kernels behind runtime
-dispatch checks (operators/fused/fused_attention_op.cu input checks);
-here the check is "does it actually compile+run on this chip".
+A kernel that fails its smoke is an ERROR naming the kernel, raised to
+whoever asked (``Model.fit`` / ``ParallelEngine`` / ``GenerationEngine``).
+Nothing here switches ``FLAGS_use_pallas`` off: that flag is the user's
+explicit choice, and a run that silently trained on the lax compositions
+would report numbers for a path nobody asked for.
 """
 from __future__ import annotations
 
-import warnings
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict
 
-from ..framework.flags import flag_value, set_flags
+from ..framework.flags import flag_value
 
-__all__ = ["run_smoke", "ensure", "last_report"]
+__all__ = ["PallasSmokeError", "run_smoke", "ensure"]
 
-_state: Dict[str, Optional[dict]] = {"report": None}
+_state = {"passed": False}
+
+
+class PallasSmokeError(RuntimeError):
+    """A Pallas kernel failed to compile, run or match its reference on
+    this chip."""
+
+
+def _assert_close(name, got, want, rel):
+    """max |got - want| within ``rel`` of the reference's largest
+    magnitude. The bounds are wide enough for the MXU's bf16 passes on
+    f32 operands: a wrong layout or mask is off by the magnitude itself,
+    not by a few percent of it."""
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    err = float(np.max(np.abs(got - want)))
+    tol = rel * float(np.max(np.abs(want))) + 1e-6
+    if not err <= tol:          # also catches NaN
+        raise FloatingPointError(
+            f"{name}: max |kernel - reference| = {err} > {tol}")
+
+
+def _grad_parity(name, kernel, reference, args, loss_rel, grad_rel):
+    """``sum(f(*args) ** 2)`` and its gradients, kernel against the lax
+    composition the kernel overrides (ops/nn_ops.py — the reference of
+    the interpret-mode parity tests too)."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(f):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (f(*a).astype(jnp.float32) ** 2).sum(),
+            argnums=tuple(range(len(args)))))(*args)
+
+    got, want = run(kernel), run(reference)
+    _assert_close(f"{name} loss", got[0], want[0], loss_rel)
+    for i, (g, w) in enumerate(zip(got[1], want[1])):
+        _assert_close(f"{name} d(arg {i})", g, w, grad_rel)
 
 
 def _smoke_flash_attention():
-    import jax
     import jax.numpy as jnp
     import numpy as np
     from .pallas_kernels import flash_attention
+    from .registry import get_op
 
     rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32)
-
-    def loss(q, k, v):
-        return flash_attention(q, k, v, is_causal=True).astype(
-            jnp.float32).sum()
-
-    val, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
-        q, q, q)
-    jax.block_until_ready(grads)
-    if not bool(jnp.isfinite(val)):
-        raise FloatingPointError("flash attention smoke loss not finite")
+    qkv = [jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32)
+           for _ in range(3)]
+    lax_sdpa = get_op("scaled_dot_product_attention").fn
+    _grad_parity("flash attention",
+                 lambda q, k, v: flash_attention(q, k, v, is_causal=True),
+                 lambda q, k, v: lax_sdpa(q, k, v, is_causal=True),
+                 qkv, loss_rel=2e-2, grad_rel=5e-2)
 
 
 def _smoke_fused_layer_norm():
-    import jax
     import jax.numpy as jnp
     import numpy as np
     from .pallas_kernels import fused_layer_norm
+    from .registry import get_op
 
     rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(128, 256), jnp.float32)
-    w = jnp.asarray(rng.randn(256), jnp.float32)
-    b = jnp.asarray(rng.randn(256), jnp.float32)
-
-    def loss(x, w, b):
-        return fused_layer_norm(x, w, b).sum()
-
-    val, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
-        x, w, b)
-    jax.block_until_ready(grads)
-    if not bool(jnp.isfinite(val)):
-        raise FloatingPointError("fused LN smoke loss not finite")
+    args = [jnp.asarray(rng.randn(128, 768), jnp.float32),
+            jnp.asarray(rng.randn(768), jnp.float32),
+            jnp.asarray(rng.randn(768), jnp.float32)]
+    _grad_parity("fused LayerNorm", fused_layer_norm,
+                 get_op("layer_norm").fn, args, loss_rel=1e-3,
+                 grad_rel=1e-2)
 
 
 def _smoke_fused_adamw():
-    import jax
+    """(1000, 768): 6000 rows of 128 lanes — twelve grid steps, the
+    last one ragged."""
     import jax.numpy as jnp
     import numpy as np
     from .pallas_kernels import fused_adamw
 
     rng = np.random.RandomState(0)
-    p = jnp.asarray(rng.randn(300, 7), jnp.float32)
-    g = jnp.asarray(rng.randn(300, 7), jnp.float32)
-    z = jnp.zeros_like(p)
-    new_p, _, _ = fused_adamw(p, g, z, z, 1e-3, 0.9, 0.999, 1e-8, 0.01, 1)
-    jax.block_until_ready(new_p)
-    if not bool(jnp.isfinite(new_p.sum())):
-        raise FloatingPointError("fused AdamW smoke output not finite")
+    p = rng.randn(1000, 768).astype(np.float32)
+    g = rng.randn(1000, 768).astype(np.float32)
+    z = jnp.zeros(p.shape, jnp.float32)
+    lr, b1, b2, eps, wd = 1e-3, 0.9, 0.999, 1e-8, 0.01
+    new_p, new_m, new_v = fused_adamw(
+        jnp.asarray(p), jnp.asarray(g), z, z, lr, b1, b2, eps, wd, 1)
+    m = (1 - b1) * g
+    v = (1 - b2) * g * g
+    want = p - lr * ((m / (1 - b1)) / (np.sqrt(v / (1 - b2)) + eps)
+                     + wd * p)
+    _assert_close("AdamW p", new_p, want, 1e-5)
+    _assert_close("AdamW m", new_m, m, 1e-5)
+    _assert_close("AdamW v", new_v, v, 1e-5)
 
 
 def _smoke_ragged_paged_attention():
-    """Fused serving kernel: a mixed decode + prefill-chunk ragged
-    batch over a tiny block pool, non-interpreted — the lowering gate
-    for the GenerationEngine(attention='fused') path."""
+    """Fused serving kernel at GPT-2's head width (Dh = 64, so the K|V
+    block tile is exactly 128 lanes): a mixed decode + prefill-chunk
+    ragged batch against the numpy oracle, for a float32 and a bfloat16
+    pool at block 16 and an int8 pool at block 32 — the lowering gate for
+    ``GenerationEngine(attention='fused')``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from .ragged_paged_attention import ragged_layout, ragged_paged_attention
+    from .ragged_paged_attention import (ragged_layout,
+                                         ragged_paged_attention,
+                                         reference_ragged_attention)
 
-    rng = np.random.RandomState(0)
-    H, BS, DH, S, T = 2, 16, 64, 2, 2
-    pool = jnp.asarray(rng.randn(1, 2, 6, H, BS, DH), jnp.float32)
-    tables = np.zeros((S, T), np.int32)
-    tables[0, :2] = [1, 3]
-    tables[1, :1] = [4]
-    blk_seq, qstart, pos0, _, _ = ragged_layout([1, 9], [20, 0],
-                                                q_bucket=24)
-    q = jnp.asarray(rng.randn(H, 24, DH), jnp.float32)
-    out = jax.jit(lambda q_, p_: ragged_paged_attention(
-        q_, p_, 0, blk_seq, qstart, pos0, tables,
-        np.zeros(S, np.int32), np.asarray([21, 9], np.int32)))(q, pool)
-    jax.block_until_ready(out)
-    if not bool(jnp.isfinite(out.sum())):
-        raise FloatingPointError(
-            "ragged paged attention smoke output not finite")
+    H, DH, S, T = 3, 64, 2, 2
+    for dtype, bs, tol in (("float32", 16, 2e-2), ("bfloat16", 16, 5e-2),
+                           ("int8", 32, 5e-2)):
+        rng = np.random.RandomState(0)
+        quant = dtype == "int8"
+        if quant:
+            pool = rng.randint(-127, 128, (2, 6, H, bs, 2 * DH)).astype(
+                np.int8)
+            scales = (rng.rand(2, 2, 6, H) / 64).astype(np.float32)
+        else:
+            pool = np.asarray(jnp.asarray(
+                rng.randn(2, 6, H, bs, 2 * DH), dtype).astype(jnp.float32))
+            scales = None
+        qdt = jnp.float32 if dtype == "float32" else jnp.bfloat16
+        tables = np.zeros((S, T), np.int32)
+        tables[0, :2] = [1, 3]
+        tables[1, :1] = [4]
+        # seq 0 decodes one row at position bs + 4 (two blocks); seq 1
+        # is a 9-row prefill chunk from position 0
+        q_lens, pos0s = [1, 9], [bs + 4, 0]
+        blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, pos0s,
+                                                    q_bucket=24)
+        q = np.asarray(jnp.asarray(rng.randn(H, 24, DH), qdt)
+                       .astype(jnp.float32))
+        lo = np.zeros(S, np.int32)
+        kv_len = np.asarray([bs + 5, 9], np.int32)
+        out = jax.jit(lambda q_, p_, s_: ragged_paged_attention(
+            q_, p_, 1, blk_seq, qstart, pos0, tables, lo, kv_len,
+            scales=s_))(jnp.asarray(q, qdt), jnp.asarray(pool, dtype),
+                        None if scales is None else jnp.asarray(scales))
+        rows = [(s, i) for s in range(S) for i in range(q_lens[s])]
+        ref = reference_ragged_attention(
+            np.stack([q[:, qstart[s] + i] for s, i in rows]), pool, 1,
+            [s for s, _ in rows], [pos0s[s] + i for s, i in rows],
+            [list(t) for t in tables], lo, scales=scales)
+        got = np.stack([np.asarray(out.astype(jnp.float32))[:,
+                                                            qstart[s] + i]
+                        for s, i in rows])
+        _assert_close(f"ragged {dtype}", got, ref, tol)
 
 
 _KERNEL_SMOKES: Dict[str, Callable[[], None]] = {
@@ -116,51 +179,30 @@ _KERNEL_SMOKES: Dict[str, Callable[[], None]] = {
 }
 
 
-def run_smoke() -> dict:
-    """Execute every Pallas kernel non-interpreted on the current backend.
-
-    Returns {"ok": bool, "backend": str, "kernels": {name: "ok"|error}}.
-    Does NOT mutate flags — see ``ensure`` for the gate.
-    """
-    import jax
-
-    report = {"backend": jax.default_backend(), "kernels": {}, "ok": True}
+def run_smoke() -> None:
+    """Execute every Pallas kernel on the current backend and compare it
+    with its reference. Raises :class:`PallasSmokeError` naming the
+    first kernel that fails; mutates no flag."""
     for name, fn in _KERNEL_SMOKES.items():
         try:
             fn()
-            report["kernels"][name] = "ok"
-        except Exception as e:  # any compile/runtime failure must gate
-            report["kernels"][name] = f"{type(e).__name__}: {e}"[:500]
-            report["ok"] = False
-    _state["report"] = report
-    return report
+        except Exception as e:
+            raise PallasSmokeError(
+                f"Pallas kernel {name!r} failed its smoke on this chip: "
+                f"{type(e).__name__}: {e}") from e
 
 
 def ensure() -> bool:
-    """Gate: on TPU, smoke all kernels once; on any failure disable the
-    Pallas tier (``FLAGS_use_pallas=False``) with a warning so models fall
-    back to the lax compositions.  Returns True when the Pallas tier is
-    enabled and healthy.  Off-TPU (tests run interpret-mode) this is a
-    no-op returning the flag value.
-    """
+    """Gate: on a TPU, smoke all kernels once per process and raise
+    :class:`PallasSmokeError` if one fails. Returns whether the Pallas
+    tier is enabled (``FLAGS_use_pallas``, the user's choice — never
+    changed here). Off-TPU the kernels only run interpreted (tests), and
+    there is nothing to gate."""
     from .pallas_kernels import _on_tpu
 
     if not flag_value("FLAGS_use_pallas"):
         return False
-    if not _on_tpu():
-        return True
-    if _state["report"] is not None:
-        return _state["report"]["ok"]
-    report = run_smoke()
-    if not report["ok"]:
-        bad = {k: v for k, v in report["kernels"].items() if v != "ok"}
-        set_flags({"FLAGS_use_pallas": False})
-        warnings.warn(
-            f"Pallas TPU smoke gate FAILED — disabling the Pallas kernel "
-            f"tier (FLAGS_use_pallas=False); models use the lax fallback "
-            f"path. Failures: {bad}")
-    return report["ok"]
-
-
-def last_report() -> Optional[dict]:
-    return _state["report"]
+    if _on_tpu() and not _state["passed"]:
+        run_smoke()
+        _state["passed"] = True
+    return True

@@ -6,7 +6,7 @@ layer: every request's WHOLE KV window is copied out of the block pool
 on every decode step, and attention then runs over the padded
 ``table_bucket * block_size`` columns for every slot. This kernel is
 the TPU-native replacement per "Ragged Paged Attention" (PAPERS.md):
-the block pool stays in HBM (``memory_space=ANY``), the kernel walks
+the block pool stays in HBM (``memory_space=pl.ANY``), the kernel walks
 each sequence's page table directly — one async DMA per (KV block,
 head) into VMEM scratch — and streams online softmax over exactly the
 blocks a sequence owns. Nothing is gathered, nothing is padded to the
@@ -28,10 +28,23 @@ Layout contract (the serving engine's fused step builds these):
   history PLUS the causal prefix of its own chunk, whose K/V the fused
   step scatters into the pool before the kernel runs.
 
-Mosaic legality (the BENCH_r02 bug class, enforced by the
-``pallas-block-tiling`` self-lint): q/o blocks are ``(1, block_q, Dh)``
-with ``block_q = 8`` sublane-aligned and ``Dh`` the full array dim; the
-KV scratch is ``(block_size, Dh)`` with ``block_size >= 8`` required.
+Pool layout: ``[L, NB + 1, H, block_size, 2 * Dh]`` — one block of one
+head is a ``(block_size, 2 * Dh)`` tile whose lanes hold K in
+``[0, Dh)`` and V in ``[Dh, 2 * Dh)``. HBM arrays are tiled ``(sublane,
+128)`` on their two minor dims and a DMA slice must cover whole tiles:
+a ``(block_size, Dh)`` tile at ``Dh = 64`` is refused by Mosaic ("slice
+shape must be aligned to tiling (128)") and padded 2x in HBM, while K|V
+folded into the lanes is exactly 128 wide at ``Dh = 64`` (and 256 at
+``Dh = 128``). One DMA per (block, head) brings both K and V. Everything
+that touches the pool — ``serving/paging.py``, the gather decode path,
+``serving/host_tier.py``, the int8 scales, the head-partitioned TP
+shard — reads this one layout.
+
+Mosaic legality (enforced by the ``pallas-block-tiling`` self-lint):
+q/o blocks are ``(1, block_q, Dh)`` with ``block_q = 8`` sublane-aligned
+and ``Dh`` the full array dim; the KV scratch is ``(block_size, 2 * Dh)``
+with ``block_size`` at least the storage dtype's sublane count and, on
+a TPU, ``2 * Dh`` a multiple of 128.
 
 Off-TPU the kernel runs in interpret mode — that is how the tier-1
 parity suite (tests/test_ragged_attention.py) executes the kernel body
@@ -41,7 +54,7 @@ Tensor-parallel use (ISSUE 15): the kernel is head-count agnostic —
 its grid is per-(q block, head), so the sharded serving step
 (``build_sharded_fused_step_fn``) simply launches it inside a
 ``shard_map`` with the LOCAL head count ``H/mp`` against each device's
-own pool shard ``[L, 2, blocks, H/mp, bs, Dh]``. No kernel change: the
+own pool shard ``[L, blocks, H/mp, bs, 2 * Dh]``. No kernel change: the
 page tables and scalar-prefetch metadata are replicated (block indices
 are shard-invariant), the per-head outputs are partial sums of the
 attention projection, and one downstream ``psum`` joins them.
@@ -61,7 +74,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_kernels import _interpret, _x64_off
 
 __all__ = ["ragged_paged_attention", "ragged_layout", "BLOCK_Q",
-           "MIN_KV_BLOCK", "min_kv_block_for"]
+           "MIN_KV_BLOCK", "min_kv_block_for", "check_kv_tile"]
 
 _NEG_INF = -1e30
 
@@ -70,7 +83,7 @@ _NEG_INF = -1e30
 # wastes at most 7 pad rows while a prefill chunk fills whole blocks
 BLOCK_Q = 8
 
-# the KV scratch block is (block_size, Dh): block_size below the
+# the KV scratch block is (block_size, 2 * Dh): block_size below the
 # sublane count has no legal TPU layout
 MIN_KV_BLOCK = 8
 
@@ -86,6 +99,26 @@ def min_kv_block_for(dtype) -> int:
     dtype (the scratch block's sublane count)."""
     return _MIN_KV_BLOCK_BY_DTYPE.get(jnp.dtype(dtype).name,
                                       MIN_KV_BLOCK)
+
+
+def check_kv_tile(dtype, block_size: int, head_dim: int) -> None:
+    """Raise ``ValueError`` unless one (block, head) tile of the pool —
+    ``(block_size, 2 * head_dim)`` of ``dtype`` — is something the
+    kernel can DMA: at least the dtype's sublane count tall and, on a
+    TPU, whole 128-lane tiles wide. The ONE statement of the rule, for
+    the kernel and for the engine's constructor."""
+    name = jnp.dtype(dtype).name
+    need = min_kv_block_for(dtype)
+    if int(block_size) < need:
+        raise ValueError(
+            f"block_size {block_size} < {need}: the {name} KV block tile "
+            f"has no legal (sublane, 128) TPU tiling below the dtype's "
+            f"sublane count")
+    if not _interpret() and (2 * int(head_dim)) % 128:
+        raise ValueError(
+            f"head_dim {head_dim}: the K|V block tile is "
+            f"{2 * int(head_dim)} lanes wide, and a TPU DMA slice must "
+            f"cover whole 128-lane tiles")
 
 
 def _rpa_kernel(blk_seq_ref, qstart_ref, pos0_ref, tables_ref, lo_ref,
@@ -105,11 +138,10 @@ def _rpa_kernel(blk_seq_ref, qstart_ref, pos0_ref, tables_ref, lo_ref,
     materialized as i64 by Mosaic under the framework's global x64 (the
     pallas_kernels idiom; the call sites also trace under _x64_off)."""
     if quantized:
-        (scales_ref, q_ref, pool_ref, o_ref, k_scr, v_scr, k_sem,
-         v_sem) = rest
+        scales_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
     else:
         scales_ref = None
-        q_ref, pool_ref, o_ref, k_scr, v_scr, k_sem, v_sem = rest
+        q_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
     h = pl.program_id(0)
     b = pl.program_id(1)
     seq = blk_seq_ref[b]
@@ -139,19 +171,16 @@ def _rpa_kernel(blk_seq_ref, qstart_ref, pos0_ref, tables_ref, lo_ref,
             m_prev, l_prev, acc = carry
             pid = tables_ref[seq, j]
             # the page-table walk: this sequence's j-th block, this
-            # head, copied HBM -> VMEM — the ONLY KV bytes this grid
-            # step touches (the gather path would have materialized the
-            # whole padded table bucket for every slot)
-            ck = pltpu.make_async_copy(
-                pool_ref.at[layer, 0, pid, h], k_scr, k_sem)
-            cv = pltpu.make_async_copy(
-                pool_ref.at[layer, 1, pid, h], v_scr, v_sem)
-            ck.start()
-            cv.start()
-            ck.wait()
-            cv.wait()
-            k_blk = k_scr[...]                          # [bs, Dh]
-            v_blk = v_scr[...]
+            # head, K|V in one (bs, 2*Dh) tile copied HBM -> VMEM — the
+            # ONLY KV bytes this grid step touches (the gather path
+            # would have materialized the whole padded table bucket for
+            # every slot)
+            cp = pltpu.make_async_copy(
+                pool_ref.at[layer, pid, h], kv_scr, kv_sem)
+            cp.start()
+            cp.wait()
+            k_blk = kv_scr[:, :dh]                      # [bs, Dh]
+            v_blk = kv_scr[:, dh:]
             if quantized:
                 # in-register dequant: the per-(block, head) max-abs
                 # scale rides the scalar-prefetch metadata; HBM moved
@@ -202,9 +231,10 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
 
     * ``q`` — ``[H, Qp, Dh]`` flattened padded query rows (``Qp`` a
       multiple of ``block_q``; per-sequence contiguous, see module doc);
-    * ``pool`` — the FULL block pool ``[L, 2, NB + 1, H, bs, Dh]``; it
-      stays in HBM (``memory_space=ANY``) and ``layer`` is a static int,
-      so no per-layer slice is ever materialized;
+    * ``pool`` — the FULL block pool ``[L, NB + 1, H, bs, 2 * Dh]``
+      (K|V folded into the lanes, see module doc); it stays in HBM
+      (``memory_space=pl.ANY``) and ``layer`` is a static int, so no
+      per-layer slice is ever materialized;
     * ``blk_seq [Qp / block_q]``, ``seq_qstart [S]``, ``seq_pos0 [S]``,
       ``tables [S, T]``, ``lo [S]``, ``kv_len [S]`` — int32
       scalar-prefetch metadata (``ragged_layout`` builds the first
@@ -216,20 +246,16 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
     * returns ``[H, Qp, Dh]`` in ``q``'s dtype.
     """
     h, qp, dh = q.shape
-    L, two, nb1, hp, bs, dhp = pool.shape
+    L, nb1, hp, bs, dh2 = pool.shape
     quantized = pool.dtype.name in ("int8", "float8_e4m3fn")
-    if (hp, dhp) != (h, dh):
+    if (hp, dh2) != (h, 2 * dh):
         raise ValueError(
-            f"pool heads/head_dim {(hp, dhp)} != q {(h, dh)}")
+            f"pool heads/lanes {(hp, dh2)} != q heads / 2*head_dim "
+            f"{(h, 2 * dh)}")
+    check_kv_tile(pool.dtype, bs, dh)
     if qp % block_q:
         raise ValueError(
             f"padded q rows {qp} must be a multiple of block_q {block_q}")
-    min_bs = min_kv_block_for(pool.dtype)
-    if bs < min_bs:
-        raise ValueError(
-            f"block_size {bs} < {min_bs}: the {pool.dtype.name} KV "
-            f"scratch block has no legal (sublane, 128) TPU tiling "
-            f"below the dtype's sublane count")
     if quantized and scales is None:
         raise ValueError(
             f"a {pool.dtype.name} pool is quantized storage: pass the "
@@ -251,14 +277,12 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
         grid=(h, n_qblk),
         in_specs=[
             pl.BlockSpec((1, block_q, dh), lambda hh, b, *_: (hh, b, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
         ],
         out_specs=pl.BlockSpec((1, block_q, dh),
                                lambda hh, b, *_: (hh, b, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bs, dh), pool.dtype),
-            pltpu.VMEM((bs, dh), pool.dtype),
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM((bs, dh2), pool.dtype),
             pltpu.SemaphoreType.DMA,
         ],
     )
@@ -274,6 +298,7 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
     with _x64_off():
         return pl.pallas_call(
             kernel,
+            name="ragged_paged_attention",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((h, qp, dh), q.dtype),
             interpret=_interpret(),
@@ -343,11 +368,13 @@ def reference_ragged_attention(q_rows, pool, layer, row_seq, row_pos,
     through the page table. ``q_rows [N, H, Dh]``, ``row_seq/row_pos
     [N]``; ``scales`` dequantizes an int8 pool (per-block max-abs,
     the kernel's in-register multiply done up front)."""
-    pool = np.asarray(pool, np.float32)
-    if scales is not None:
-        pool = pool * np.asarray(scales, np.float32)[..., None, None]
     q_rows = np.asarray(q_rows, np.float32)
     n, h, dh = q_rows.shape
+    # [L, NB+1, H, bs, 2*Dh] -> K/V planes [L, 2, NB+1, H, bs, Dh]
+    pool = np.asarray(pool, np.float32)
+    pool = np.stack([pool[..., :dh], pool[..., dh:]], axis=1)
+    if scales is not None:
+        pool = pool * np.asarray(scales, np.float32)[..., None, None]
     bs = pool.shape[4]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     out = np.zeros_like(q_rows)

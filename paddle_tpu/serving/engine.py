@@ -306,21 +306,11 @@ class GenerationEngine:
                 "beside the block pool (PagedKVPool.scales); the dense "
                 "slot pool has no block granularity to scale")
         if attention == "fused":
-            from ..ops.ragged_paged_attention import (MIN_KV_BLOCK,
-                                                      min_kv_block_for)
             if kv_layout != "paged":
                 raise ValueError(
                     "attention='fused' is the fused RAGGED PAGED "
                     "attention path — it requires kv_layout='paged' "
                     "(the dense slot pool has no page tables to walk)")
-            need = min_kv_block_for(kv_dtype) if kv_dtype is not None \
-                else MIN_KV_BLOCK
-            if int(block_size) < need:
-                raise ValueError(
-                    f"attention='fused' requires block_size >= {need} "
-                    f"for kv_dtype={kv_dtype or 'float'}: the kernel's "
-                    f"(block_size, head_dim) KV scratch has no legal "
-                    f"TPU tiling below the dtype's sublane count")
         if spec_draft is not None and attention != "fused":
             raise ValueError(
                 "spec_draft (speculative decoding) requires "
@@ -389,6 +379,13 @@ class GenerationEngine:
             dtype = self._params[next(iter(self._params))].dtype
         self._paged = kv_layout == "paged"
         head_dim = cfg.hidden_size // cfg.num_attention_heads
+        if self._fused:
+            # the fused engine either runs the kernel or raises, here:
+            # no other attention path is selected behind its back
+            from ..ops import pallas_smoke
+            from ..ops.ragged_paged_attention import check_kv_tile
+            check_kv_tile(kv_dtype or dtype, block_size, head_dim)
+            pallas_smoke.ensure()
         self._key = jax.random.PRNGKey(int(seed))
         self._eid = _next_engine_id()
         self._prefill_jits = {}           # bucket -> jitted prefill step
@@ -1639,7 +1636,7 @@ class GenerationEngine:
         if self._copy_jit is None:
             if self._pool.quantized:
                 def _copy(pool, scales, dst, src):
-                    return (pool.at[:, :, dst].set(pool[:, :, src]),
+                    return (pool.at[:, dst].set(pool[:, src]),
                             scales.at[:, :, dst].set(scales[:, :, src]))
 
                 self._copy_jit = _registry.aot_site(
@@ -1647,7 +1644,7 @@ class GenerationEngine:
                     donate_argnums=(0, 1))
             else:
                 def _copy(pool, dst, src):
-                    return pool.at[:, :, dst].set(pool[:, :, src])
+                    return pool.at[:, dst].set(pool[:, src])
 
                 self._copy_jit = _registry.aot_site(
                     f"serving/copy#{self._eid}", _copy,

@@ -112,7 +112,7 @@ class PromotionTicket:
     def __init__(self, keys: List[Tuple[int, ...]]):
         self.keys = list(keys)           # requested chain, root-first
         self.staged_keys: List[Tuple[int, ...]] = []
-        self.staged = None               # device [L, 2, n, H, bs, hd]
+        self.staged = None               # device [L, n, H, bs, 2*hd]
         self.staged_scales = None        # device [L, 2, n, H] or None
         self.ready = threading.Event()
         self.failed = False
@@ -221,7 +221,7 @@ class HostBlockPool:
     def spill(self, keys: List[Tuple[int, ...]], blocks_dev,
               scales_dev=None) -> bool:
         """Enqueue a batched demotion: ``blocks_dev`` is the lazy
-        device gather ``[L, 2, len(keys), H, bs, hd]`` the scheduler
+        device gather ``[L, len(keys), H, bs, 2*hd]`` the scheduler
         dispatched (an independent array — NOT the donated pool), and
         ``scales_dev`` its ``[L, 2, len(keys), H]`` companion for
         quantized pools. Never blocks: a full spill queue degrades to
@@ -288,7 +288,7 @@ class HostBlockPool:
                 with self._lock:
                     for i, key in enumerate(keys):
                         self._put_locked(
-                            key, host[:, :, i],
+                            key, host[:, i],
                             None if sca is None else sca[:, :, i])
                 self._update_ledger()
                 dt_ms = (time.perf_counter() - t0) * 1e3
@@ -361,7 +361,7 @@ class HostBlockPool:
                     # compiling a fresh pair on the scheduler thread
                     m = 1 << (len(entries) - 1).bit_length()
                     entries = entries + [entries[-1]] * (m - len(entries))
-                    blocks = np.stack([e[0] for e in entries], axis=2)
+                    blocks = np.stack([e[0] for e in entries], axis=1)
                     tk.staged = jax.device_put(blocks)
                     if entries[0][1] is not None:
                         scales = np.stack([e[1] for e in entries], axis=2)

@@ -5,7 +5,9 @@ The dense :class:`~.kv_pool.KVCachePool` reserves a full
 by WORST-CASE sequence length even when most requests are short — the
 fragmentation problem paged, block-granular KV management solves on TPU
 (the Ragged-Paged-Attention argument, PAPERS.md). Here the device pool
-is ``[layers, 2, num_blocks + 1, heads, block_size, head_dim]``: a
+is ``[layers, num_blocks + 1, heads, block_size, 2 * head_dim]`` (K|V
+folded into the lanes — the ONE layout every reader of the pool uses,
+see ops/ragged_paged_attention.py): a
 request owns only the blocks covering its tokens SO FAR, addressed
 through a per-request page table that maps virtual cache index
 ``i`` to ``(table[i // block_size], i % block_size)``. Physical block 0
@@ -112,8 +114,9 @@ class _TrieNode:
 class PagedKVPool(SlotPoolBase):
     """Block-pooled KV cache + page-table/prefix-cache manager.
 
-    ``data`` is the jnp array ``[layers, 2, num_blocks + 1, heads,
-    block_size, head_dim]`` (index 0 = scratch); the engine threads it
+    ``data`` is the jnp array ``[layers, num_blocks + 1, heads,
+    block_size, 2 * head_dim]`` (block 0 = scratch; a row's lanes hold
+    K then V); the engine threads it
     through the donated paged prefill/decode steps and rebinds it here.
     ``num_slots`` bounds concurrent REQUESTS (the decode batch axis),
     ``num_blocks`` bounds their total KV footprint — with mixed lengths
@@ -172,8 +175,8 @@ class PagedKVPool(SlotPoolBase):
                 f"num_blocks={self.num_blocks} cannot hold even one "
                 f"max-length request ({self.max_table_len} blocks)")
         # +1: physical block 0 is the reserved scratch block
-        self.shape = (self.num_layers, 2, self.num_blocks + 1,
-                      self.num_heads, self.block_size, self.head_dim)
+        self.shape = (self.num_layers, self.num_blocks + 1,
+                      self.num_heads, self.block_size, 2 * self.head_dim)
         self.dtype = jnp.dtype(dtype)
         # tensor-parallel pool: the block array is head-partitioned over
         # a 1-D mp mesh ([.., H/mp, ..] per device) while every host
@@ -236,7 +239,7 @@ class PagedKVPool(SlotPoolBase):
     def _alloc_data(self):
         """Fresh zeroed block array — head-partitioned over the mesh's
         ``mp`` axis when this is a tensor-parallel pool (each device
-        holds ``[L, 2, NB+1, H/mp, bs, Dh]``), a plain single-device
+        holds ``[L, NB+1, H/mp, bs, 2*Dh]``), a plain single-device
         array otherwise."""
         import jax
         import jax.numpy as jnp
@@ -244,7 +247,7 @@ class PagedKVPool(SlotPoolBase):
             return jnp.zeros(self.shape, self.dtype)
         from jax.sharding import NamedSharding, PartitionSpec as P
         sh = NamedSharding(
-            self.mesh, P(None, None, None, self.mp_axis, None, None))
+            self.mesh, P(None, None, self.mp_axis, None, None))
         return jax.device_put(jnp.zeros(self.shape, self.dtype), sh)
 
     # -- request slots (decode batch axis: SlotPoolBase) -------------------
@@ -469,8 +472,8 @@ class PagedKVPool(SlotPoolBase):
         m = 1
         while True:
             ids = np.zeros(m, np.int32)
-            blk = self.data[:, :, ids]                 # demote gather
-            self.data = self.data.at[:, :, ids].set(blk)   # adopt
+            blk = self.data[:, ids]                    # demote gather
+            self.data = self.data.at[:, ids].set(blk)      # adopt
             if self.quantized:
                 sca = self.scales[:, :, ids]
                 self.scales = self.scales.at[:, :, ids].set(sca)
@@ -483,7 +486,7 @@ class PagedKVPool(SlotPoolBase):
         cycle): batch every key that went refcount-0 since the last
         tick and is STILL evictable into ONE lazy device gather, and
         hand it to the tier's spiller thread. The gather
-        ``data[:, :, ids]`` is an independent non-donated array whose
+        ``data[:, ids]`` is an independent non-donated array whose
         value is captured before any later donated step can delete the
         pool storage, so the spiller's blocking copy never races XLA
         donation. Dispatch-only — no device sync on this thread."""
@@ -502,7 +505,7 @@ class PagedKVPool(SlotPoolBase):
         raw = [self._trie[k].block for k in keys]
         m = 1 << (len(raw) - 1).bit_length()
         ids = np.asarray(raw + [raw[-1]] * (m - len(raw)), np.int32)
-        blk = self.data[:, :, ids]        # lazy batched gather
+        blk = self.data[:, ids]           # lazy batched gather
         sca = self.scales[:, :, ids] if self.quantized else None
         tier.spill(keys, blk, sca)
 
@@ -581,12 +584,12 @@ class PagedKVPool(SlotPoolBase):
         # so the result is unchanged. Without this, each distinct chain
         # length would eagerly compile a fresh gather/scatter pair on
         # the scheduler thread, stalling decode for ~100ms a pop.
-        m = int(ticket.staged.shape[2])
+        m = int(ticket.staged.shape[1])
         sel = np.asarray(keep + [keep[-1]] * (m - len(keep)), np.int32)
         idx = np.asarray(ids + [ids[-1]] * (m - len(ids)), np.int32)
-        blk = ticket.staged[:, :, sel]
+        blk = ticket.staged[:, sel]
         sca = ticket.staged_scales
-        self.data = self.data.at[:, :, idx].set(blk)
+        self.data = self.data.at[:, idx].set(blk)
         if self.quantized and sca is not None:
             # adopted blocks carry their ORIGINAL per-block scales —
             # overwrite the zeros _alloc_block just staged

@@ -179,6 +179,13 @@ def test_dtype_device_utilities():
     for place in (paddle.TPUPlace(0), paddle.CUDAPlace(0),
                   paddle.CUDAPinnedPlace(), paddle.NPUPlace(0)):
         assert repr(place)
+    # a place whose platform has no device here RAISES: a request for
+    # the TPU is never answered with the CPU
+    with pytest.raises(RuntimeError, match="no 'tpu' device"):
+        paddle.TPUPlace(0).jax_device()
+    assert paddle.CPUPlace(0).jax_device().platform == "cpu"
+    with pytest.raises(RuntimeError, match="only 8 'cpu' device"):
+        paddle.CPUPlace(8).jax_device()
     x = t(A)
     assert paddle.assign(x).numpy() is not None
     y = x.clone()

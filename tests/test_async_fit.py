@@ -381,54 +381,110 @@ class TestPrefetchInFit:
         assert monitor.stat_get("prefetch_batches") >= 8
 
 
+@pytest.fixture
+def cache_env():
+    """Let a test move JAX_COMPILATION_CACHE_DIR and arm the cache, then
+    put the process back as it was (the import hook leaves a CPU-pinned
+    test process unarmed; jax's 1 s persistence floor)."""
+    import jax
+    from paddle_tpu.framework import compile_cache
+    old = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    was_on = compile_cache.status()["enabled"]
+    yield
+    if old is None:
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    else:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = old
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    if was_on:
+        compile_cache.enable()
+    else:
+        compile_cache.disable()
+
+
 class TestCompileCache:
-    def test_enable_populates_entries(self, tmp_path):
+    def test_env_dir_is_used_and_no_other(self, tmp_path, cache_env):
+        """JAX_COMPILATION_CACHE_DIR set -> the cache is that directory:
+        entries land there, the in-checkout default gains none."""
         import jax
         import jax.numpy as jnp
         from paddle_tpu.framework import compile_cache
 
         d = str(tmp_path / "xla")
-        if not compile_cache.enable(d, min_compile_time_secs=0):
-            pytest.skip(f"no compile-cache support in this jax: "
-                        f"{compile_cache.status()['reason']}")
-        try:
-            # a shape this process has definitely not compiled yet
-            f = jax.jit(lambda a: (a @ a.T).sum() * 3.5)
-            float(f(jnp.ones((13, 7))))
-            n1 = compile_cache.entries(d)
-            assert n1 > 0
-            assert compile_cache.status()["enabled"] is True
-            assert compile_cache.status()["dir"] == d
-            # second build of the same program adds no new entries
-            g = jax.jit(lambda a: (a @ a.T).sum() * 3.5)
-            float(g(jnp.ones((13, 7))))
-            assert compile_cache.entries(d) == n1
-        finally:
-            compile_cache.disable()
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = d
+        fixed = os.path.join(compile_cache.cache_root(), "xla")
+        n_fixed = compile_cache.entries(fixed)
+        assert compile_cache.default_dir() == d
+        assert compile_cache.enable(min_compile_time_secs=0)
+        assert compile_cache.status() == {"enabled": True, "dir": d,
+                                          "reason": None}
+        assert jax.config.jax_compilation_cache_dir == d
+        # a shape this process has definitely not compiled yet
+        f = jax.jit(lambda a: (a @ a.T).sum() * 3.5)
+        float(f(jnp.ones((13, 7))))
+        n1 = compile_cache.entries(d)
+        assert n1 > 0
+        assert compile_cache.entries(fixed) == n_fixed
+        # second build of the same program adds no new entries
+        g = jax.jit(lambda a: (a @ a.T).sum() * 3.5)
+        float(g(jnp.ones((13, 7))))
+        assert compile_cache.entries(d) == n1
 
-    def test_flag_seeded_enable(self, tmp_path):
+    def test_unset_env_means_fixed_in_checkout_dir(self, cache_env):
+        """No JAX_COMPILATION_CACHE_DIR -> <checkout>/.cache/xla: a
+        fixed path (it is part of the cache key), never the home
+        directory, a temp dir, a pid or the time."""
+        import tempfile
+
+        import jax
         from paddle_tpu.framework import compile_cache
-        d = str(tmp_path / "flagged")
-        paddle.set_flags({"FLAGS_compile_cache": True,
-                          "FLAGS_compile_cache_dir": d})
-        try:
-            on = compile_cache.maybe_enable()
-            if not on:
-                pytest.skip("no compile-cache support in this jax")
-            assert compile_cache.status()["dir"] == d
-            assert os.path.isdir(d)
-        finally:
-            compile_cache.disable()
-            paddle.set_flags({"FLAGS_compile_cache": False,
-                              "FLAGS_compile_cache_dir": ""})
 
-    def test_default_dir_under_shared_cache_root(self):
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".cache", "xla")
+        assert compile_cache.default_dir() == want
+        assert compile_cache.enable()
+        assert compile_cache.status()["dir"] == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert os.path.isdir(want)
+        assert not want.startswith(os.path.expanduser("~") + os.sep) \
+            or repo.startswith(os.path.expanduser("~") + os.sep)
+        assert not want.startswith(tempfile.gettempdir() + os.sep)
+
+    def test_import_hook_arms_unless_switched_off_or_cpu_pinned(
+            self, cache_env, monkeypatch):
+        """On by default — except in a process pinned to the CPU, where
+        XLA:CPU warns on every reload and there is little to save."""
+        from paddle_tpu.framework import compile_cache
+        from paddle_tpu.framework.flags import flag_value
+        assert flag_value("FLAGS_compile_cache") is True
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert compile_cache.maybe_enable() is False
+        assert "JAX_PLATFORMS=cpu" in compile_cache.status()["reason"]
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert compile_cache.maybe_enable() is True
+        assert compile_cache.status()["enabled"] is True
+        paddle.set_flags({"FLAGS_compile_cache": False})
+        try:
+            compile_cache.disable()
+            assert compile_cache.maybe_enable() is False
+            assert compile_cache.status()["enabled"] is False
+        finally:
+            paddle.set_flags({"FLAGS_compile_cache": True})
+
+    def test_disable_is_reversible(self, cache_env):
+        import jax
+        from paddle_tpu.framework import compile_cache
+        compile_cache.disable()
+        assert compile_cache.status()["enabled"] is False
+        assert jax.config.jax_enable_compilation_cache is False
+        assert compile_cache.enable()
+        assert jax.config.jax_enable_compilation_cache is True
+
+    def test_autotune_cache_shares_the_root(self):
         from paddle_tpu.framework import compile_cache
         from paddle_tpu.ops import autotune_cache
         root = compile_cache.cache_root()
-        assert compile_cache.default_dir().startswith(root) or \
-            os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        # the autotune cache lives under the SAME root (shared helper)
         if "PADDLE_AUTOTUNE_CACHE_DIR" not in os.environ:
             assert autotune_cache.cache_path().startswith(root)
 

@@ -87,7 +87,7 @@ def _publish(pool, toks, values):
     slot = pool.alloc()
     blocks = pool.admit_fresh(slot, len(toks))
     for b, v in zip(blocks, values):
-        pool.data = pool.data.at[:, :, b].set(v)
+        pool.data = pool.data.at[:, b].set(v)
         if pool.quantized:
             pool.scales = pool.scales.at[:, :, b].set(abs(v) / 127.0)
     pool.register_prefix(slot, toks)
@@ -173,7 +173,7 @@ class TestTierExactness:
                 host, scale = tier.get(toks[:(i + 1) * 8])
                 assert scale is None
                 np.testing.assert_array_equal(
-                    host, np.asarray(pool.data[:, :, b]))
+                    host, np.asarray(pool.data[:, b]))
         finally:
             tier.close()
 
@@ -194,7 +194,7 @@ class TestTierExactness:
             for i, b in enumerate(got):
                 host, _ = tier.get(toks[:(i + 1) * 8])  # host copy kept
                 np.testing.assert_array_equal(
-                    np.asarray(pool.data[:, :, b]), host)
+                    np.asarray(pool.data[:, b]), host)
             assert tier.promoted_blocks == 2
             assert tier.stats()["promotion_ms"]["count"] == 1
         finally:
@@ -205,7 +205,7 @@ class TestTierExactness:
         try:
             toks = tuple(range(40, 56))
             blocks = _publish(pool, toks, (17, 33))
-            want = [(np.asarray(pool.data[:, :, b]),
+            want = [(np.asarray(pool.data[:, b]),
                      np.asarray(pool.scales[:, :, b])) for b in blocks]
             _demote(pool, tier)
             for i in range(2):
@@ -218,7 +218,7 @@ class TestTierExactness:
             got = pool.match_prefix(probe)
             for i, b in enumerate(got):
                 np.testing.assert_array_equal(
-                    np.asarray(pool.data[:, :, b]), want[i][0])
+                    np.asarray(pool.data[:, b]), want[i][0])
                 np.testing.assert_array_equal(
                     np.asarray(pool.scales[:, :, b]), want[i][1])
         finally:
@@ -286,7 +286,7 @@ class TestTierRaces:
             for i, b in enumerate(again):
                 host, _ = tier.get(toks[:(i + 1) * 8])
                 np.testing.assert_array_equal(
-                    host, np.asarray(pool.data[:, :, b]))
+                    host, np.asarray(pool.data[:, b]))
         finally:
             tier._fetch = orig
             tier.close()

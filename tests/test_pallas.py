@@ -241,3 +241,41 @@ class TestStreamingFlashVariant:
         assert pk._fa_supported(
             np.zeros((1, 32768, 4, 128)), np.zeros((1, 32768, 4, 128)),
             None, None, None, 0.0, True)
+
+
+class TestSmokeGate:
+    """``pallas_smoke.ensure()``: a kernel that fails its smoke on the
+    chip is an error naming the kernel — never a silent
+    ``FLAGS_use_pallas=False`` and a run on the lax compositions."""
+
+    def test_failed_smoke_raises_and_leaves_the_flag_alone(self,
+                                                           monkeypatch):
+        from paddle_tpu.framework.flags import flag_value
+        from paddle_tpu.ops import pallas_smoke
+
+        def refused():
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+        monkeypatch.setitem(pallas_smoke._state, "passed", False)
+        monkeypatch.setattr(pallas_smoke, "_KERNEL_SMOKES",
+                            {"ragged_paged_attention": refused})
+        assert flag_value("FLAGS_use_pallas") is True
+        with pytest.raises(pallas_smoke.PallasSmokeError,
+                           match="ragged_paged_attention.*Mosaic failed"):
+            pallas_smoke.ensure()
+        assert flag_value("FLAGS_use_pallas") is True
+        assert pallas_smoke._state["passed"] is False
+
+    def test_off_tpu_and_switched_off_are_not_gated(self, monkeypatch):
+        from paddle_tpu.ops import pallas_smoke
+        monkeypatch.setattr(pallas_smoke, "_KERNEL_SMOKES",
+                            {"boom": lambda: 1 / 0})
+        assert pallas_smoke.ensure() is True          # CPU: nothing to gate
+        monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+        monkeypatch.setitem(pallas_smoke._state, "passed", False)
+        set_flags({"FLAGS_use_pallas": False})        # the user's choice
+        try:
+            assert pallas_smoke.ensure() is False
+        finally:
+            set_flags({"FLAGS_use_pallas": True})

@@ -84,7 +84,7 @@ def _random_ragged_case(rng, *, dtype="float32"):
 
     L, H, BS, DH, S, T = 2, 3, 8, 16, 4, 4
     NB = 24
-    pool = rng.randn(L, 2, NB + 1, H, BS, DH).astype(np.float32)
+    pool = rng.randn(L, NB + 1, H, BS, 2 * DH).astype(np.float32)
     # per-seq: present?, kv_len, q_len (decode=1 or a chunk tail)
     tables = np.zeros((S, T), np.int32)
     q_lens, pos0s, kv_lens = [], [], []
@@ -144,7 +144,7 @@ class TestKernelParity:
         import jax.numpy as jnp
         rng = np.random.RandomState(7)
         H, BS, DH = 2, 8, 16
-        pool = rng.randn(1, 2, 5, H, BS, DH).astype(np.float32)
+        pool = rng.randn(1, 5, H, BS, 2 * DH).astype(np.float32)
         tables = np.array([[1, 2, 3, 4]], np.int32)
         blk_seq, qstart, pos0, last_row, total = ragged_layout([20], [0])
         q = rng.randn(H, len(blk_seq) * 8, DH).astype(np.float32)
@@ -171,7 +171,7 @@ class TestKernelParity:
         with pytest.raises(ValueError, match="cannot hold"):
             ragged_layout([9, 9], [0, 0], q_bucket=16)
         import jax.numpy as jnp
-        pool = jnp.zeros((1, 2, 3, 2, 4, 16))   # block_size 4 < 8
+        pool = jnp.zeros((1, 3, 2, 4, 32))      # block_size 4 < 8
         with pytest.raises(ValueError, match="legal"):
             ragged_paged_attention(
                 jnp.zeros((2, 8, 16)), pool, 0, np.zeros(1, np.int32),

@@ -161,7 +161,7 @@ def test_numerics_host_sync_rule():
 
 
 def test_pallas_block_tiling_rule():
-    """The BENCH_r02 bug class as a standing static check: a literal
+    """The Mosaic block-tiling bug class as a standing static check: a literal
     BlockSpec dim that violates the Mosaic (8, 128) rule is flagged in
     ops/; legal shapes, SMEM specs, shapeless specs, dynamic dims and
     argued suppressions are not."""

@@ -268,18 +268,24 @@ class TestQuantizedBlocks:
         - x| <= scale/2 per element, scale = blockwise max|x|/127."""
         import jax.numpy as jnp
 
-        from paddle_tpu.models.generation import (_dequant_gather,
+        from paddle_tpu.models.generation import (_gather_kv,
                                                   _quant_write_blocks)
         rng = np.random.RandomState(0)
-        vals = rng.randn(3, 2, 8, 4).astype(np.float32) * 2.0  # [Tp,H,bs,Dh]
-        pool = jnp.zeros((1, 2, 5, 2, 8, 4), jnp.int8)
+        # K and V planes of three blocks, [2, Tp, H, bs, Dh]; V is
+        # scaled apart from K so a swapped scale plane would show
+        vals = rng.randn(2, 3, 2, 8, 4).astype(np.float32) * 2.0
+        vals[1] *= 5.0
+        pool = jnp.zeros((1, 5, 2, 8, 8), jnp.int8)    # [L,NB+1,H,bs,2Dh]
         scales = jnp.zeros((1, 2, 5, 2), jnp.float32)
         table = np.array([1, 2, 3], np.int32)
-        pool, scales = _quant_write_blocks(pool, scales, 0, 0, table,
-                                           jnp.asarray(vals), 127.0)
-        deq = np.asarray(_dequant_gather(pool, scales, 0, 0,
-                                         table[None, :]))[0]
-        bound = np.abs(vals).max(axis=(2, 3), keepdims=True) / 127.0
+        pool, scales = _quant_write_blocks(
+            pool, scales, 0, table, jnp.asarray(vals[0]),
+            jnp.asarray(vals[1]), 127.0)
+        # [1, Tp*bs, H, Dh] back to per-block [Tp, H, bs, Dh]
+        deq = np.stack([
+            np.asarray(g)[0].reshape(3, 8, 2, 4).transpose(0, 2, 1, 3)
+            for g in _gather_kv(pool, scales, 0, table[None, :])])
+        bound = np.abs(vals).max(axis=(3, 4), keepdims=True) / 127.0
         assert (np.abs(deq - vals) <= bound * 0.5001 + 1e-7).all()
 
     def test_recycled_block_scale_is_reset(self):
@@ -295,9 +301,10 @@ class TestQuantizedBlocks:
                            min_bucket=8, dtype="int8")
         a = pool.alloc()
         blocks = pool.admit_fresh(a, 16)          # takes both blocks
+        vals = jnp.full((2, 1, 8, 1), 100.0)
         pool.data, pool.scales = _quant_write_blocks(
-            pool.data, pool.scales, 0, 0, np.asarray(blocks, np.int32),
-            jnp.full((2, 1, 8, 1), 100.0), 127.0)
+            pool.data, pool.scales, 0, np.asarray(blocks, np.int32),
+            vals, vals, 127.0)
         assert np.asarray(pool.scales)[0, 0, blocks[1]] > 0.5
         pool.free(a)                              # blocks recycled
         b = pool.alloc()

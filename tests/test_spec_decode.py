@@ -413,7 +413,7 @@ class TestRollbackMachinery:
                              spec_k=0)
         with pytest.raises(ValueError, match="kv_dtype"):
             GenerationEngine(served_model, kv_dtype="int8")
-        with pytest.raises(ValueError, match="block_size >= 32"):
+        with pytest.raises(ValueError, match="block_size 8 < 32"):
             GenerationEngine(served_model, kv_layout="paged",
                              attention="fused", block_size=8,
                              max_len=48, kv_dtype="int8")

@@ -1,0 +1,126 @@
+"""The Pallas kernels of the main path, compiled for a DESCRIBED v5e at
+GPT-2 124M widths — no chip attached, about two seconds each.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+a DMA slice not aligned to the HBM tiling (``ragged_paged_attention`` at
+Dh = 64 before the K|V-in-lanes pool layout), a block that does not fit
+VMEM (``fused_adamw`` before its row grid). The TPU compiler is installed
+here and compiles for a topology that is described and not attached; these
+cases keep that guard on every later PR at no chip time. A compile that
+passes is not a chip run — ``chip_smoke.py`` is.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+from paddle_tpu.ops import pallas_kernels as pk  # noqa: E402
+from paddle_tpu.ops.ragged_paged_attention import (  # noqa: E402
+    ragged_layout, ragged_paged_attention)
+
+H, DH = 12, 64          # GPT-2 124M: 12 heads of 64
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip as a sharding; skips where this jax cannot
+    describe the topology. The persistent compile cache is off around
+    these compiles: an executable for a described device is written to it
+    but cannot be read back without a chip, and the next run would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                               # noqa: BLE001
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # code that asks the backend takes its CPU (interpret) branch in this
+    # process; steer it here, in the test, not through an option
+    patch = pytest.MonkeyPatch()
+    patch.setattr(pk, "_on_tpu", lambda: True)
+    yield SingleDeviceSharding(topo.devices[0])
+    patch.undo()
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *avals):
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _ragged_case(chip, pool_dtype, block_size, q_lens):
+    """A ragged batch over a 65-block pool: ``q_lens`` rows per sequence
+    (1 = a decode row, more = a prefill chunk), 20 tokens of history."""
+    S, T, NB = len(q_lens), 8, 64
+    blk_seq, qstart, pos0, _, _ = ragged_layout(
+        q_lens, [20] * S, q_bucket=64)
+    tables = np.zeros((S, T), np.int32)
+    lo = np.zeros(S, np.int32)
+    kv_len = np.asarray([20 + n for n in q_lens], np.int32)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    avals = [sds((H, 64, DH), jnp.bfloat16),
+             sds((2, NB + 1, H, block_size, 2 * DH), pool_dtype)]
+    if pool_dtype == "int8":
+        avals.append(sds((2, 2, NB + 1, H), jnp.float32))
+
+    def fn(q, pool, scales=None):
+        return ragged_paged_attention(q, pool, 1, blk_seq, qstart, pos0,
+                                      tables, lo, kv_len, scales=scales)
+    return fn, avals
+
+
+@pytest.mark.parametrize("pool_dtype,block_size,q_lens", [
+    ("bfloat16", 16, [1] * 8),            # a decode batch
+    ("bfloat16", 16, [1, 1, 1, 40]),      # decode rows + a prefill chunk
+    ("float32", 16, [1, 1, 1, 40]),
+    ("int8", 32, [1, 1, 1, 40]),
+], ids=["bf16-decode", "bf16-mixed", "f32-mixed", "int8-bs32-mixed"])
+def test_ragged_paged_attention_compiles_at_gpt2_widths(
+        v5e, pool_dtype, block_size, q_lens):
+    fn, avals = _ragged_case(v5e, pool_dtype, block_size, q_lens)
+    assert "ragged_paged_attention" in _compile(fn, *avals)
+
+
+def test_flash_attention_fwd_bwd_compiles_at_gpt2_train_shape(v5e):
+    q = jax.ShapeDtypeStruct((4, 1024, H, DH), jnp.bfloat16, sharding=v5e)
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, is_causal=True).astype(
+            jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"):
+        assert kernel in text
+
+
+def test_fused_layer_norm_fwd_bwd_compiles_at_gpt2_train_shape(v5e):
+    x = jax.ShapeDtypeStruct((4096, 768), jnp.bfloat16, sharding=v5e)
+    w = jax.ShapeDtypeStruct((768,), jnp.float32, sharding=v5e)
+
+    def loss(x, w, b):
+        return pk.fused_layer_norm(x, w, b).astype(jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, w, w)
+    assert "fused_layer_norm_fwd" in text and "fused_layer_norm_bwd" in text
+
+
+@pytest.mark.parametrize("shape", [(50304, 768), (768, 3072)],
+                         ids=["embedding", "mlp"])
+def test_fused_adamw_compiles_within_vmem(v5e, shape):
+    """Both were refused (RESOURCE_EXHAUSTED in VMEM) when the kernel
+    mapped the whole flattened parameter as one block."""
+    a = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)
+    sc = jax.ShapeDtypeStruct((7,), jnp.float32, sharding=v5e)
+    fn = pk._fused_adamw_callable(shape, "float32", False)
+    assert "fused_adamw" in _compile(fn, a, a, a, a, sc)
