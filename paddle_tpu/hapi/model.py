@@ -1150,7 +1150,8 @@ class Model:
         not device compute — the span/histogram pair still localises
         stalls (compiles, H2D, syncs)."""
         t0 = time.perf_counter()
-        with _prof.record("hapi/train_batch", "hapi"):
+        with _prof.record("hapi/train_batch", "hapi",
+                          args={"step": self._step_counter + 1}):
             self._ensure_train_built()
             ins = _as_arrays(inputs)
             lbs = _as_arrays(labels) if labels is not None else []

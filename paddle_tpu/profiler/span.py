@@ -9,11 +9,15 @@ framework itself is instrumented with:
 
 * ``record(name, category)`` — span context manager AND decorator with
   thread-local nesting (each span knows its depth and parent) writing to
-  one lock-guarded global event buffer;
-* ``profile()`` — session context manager arming the buffer; when no
-  session is active every instrumentation point reduces to ONE module-bool
-  check (near-zero cost — the dispatch hot loop stays within the perf-gate
-  budget);
+  one lock-guarded global event buffer. Every span is ALSO a
+  ``jax.profiler.TraceAnnotation(name, **args)``: while a jax trace runs
+  (``jax.profiler.start_trace``, whoever started it) the span lands on the
+  ``/host:CPU`` plane of the XPlane, on the device ops' clock, with its
+  ``args`` as event stats — and is a ~1 us no-op while none runs;
+* ``profile()`` — session context manager arming the Python event buffer;
+  when no session is active a span costs its TraceAnnotation and one
+  module-bool check. The per-eager-op sites (``framework/dispatch.py``)
+  keep their own ``if _prof._active`` guard and build no span at all;
 * exporters — ``export_chrome_trace`` (chrome://tracing / Perfetto JSON),
   ``export_prometheus`` (text exposition of monitor counters, histograms
   and span aggregates), ``span_summary`` (human-readable table with
@@ -39,6 +43,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = ["record", "profile", "enable", "disable", "reset", "is_active",
            "events", "dropped", "add_event", "set_thread_name",
            "thread_names", "export_chrome_trace", "export_prometheus",
@@ -53,7 +59,6 @@ _lock = threading.Lock()
 _events: List[tuple] = []   # (name, cat, t0, t1, tid, depth, parent, args)
 _dropped = 0
 _max_events = 1_000_000
-_jax_bridge = False
 _tls = threading.local()
 # tid -> human label for the trace viewer (real python threads AND the
 # synthetic per-request lanes the serving tracer emits). Survives
@@ -87,37 +92,35 @@ def dropped() -> int:
     return _dropped
 
 
-_enable_stack: List[tuple] = []   # (max_events, jax_bridge) to restore
+_enable_stack: List[int] = []   # max_events to restore
 
 
-def enable(max_events: Optional[int] = None, jax_bridge: bool = False):
+def enable(max_events: Optional[int] = None):
     """Arm the global span buffer (idempotent / reentrant). A nested
-    enable may override the cap or turn the jax bridge on for its window;
-    without explicit arguments it INHERITS the enclosing session's
-    settings, and the matching disable always restores them."""
-    global _active, _active_count, _max_events, _jax_bridge
+    enable may override the cap for its window; without an explicit
+    argument it INHERITS the enclosing session's cap, and the matching
+    disable always restores it."""
+    global _active, _active_count, _max_events
     with _lock:
         nested = _active_count > 0
         _active_count += 1
-        _enable_stack.append((_max_events, _jax_bridge))
+        _enable_stack.append(_max_events)
         if max_events is not None:
             _max_events = int(max_events)
         elif not nested:
             _max_events = int(_flag("FLAGS_profiler_max_events",
                                     _max_events))
-        _jax_bridge = _jax_bridge or jax_bridge
         _active = True
 
 
 def disable():
-    global _active, _active_count, _max_events, _jax_bridge
+    global _active, _active_count, _max_events
     with _lock:
         _active_count = max(0, _active_count - 1)
         if _enable_stack:
-            _max_events, _jax_bridge = _enable_stack.pop()
+            _max_events = _enable_stack.pop()
         if _active_count == 0:
             _active = False
-            _jax_bridge = False
 
 
 _generation = 0   # bumped by reset(): spans begun before a reset are stale
@@ -228,6 +231,13 @@ class _Span:
         self._open = False
 
     def begin(self):
+        # the span in the profiler's own trace (XPlane, /host:CPU, the
+        # device ops' clock): always entered — a TraceMe is a no-op
+        # while no jax trace runs
+        args = self.args
+        self._ann = ann = _TraceAnnotation(self.name, **args) if args \
+            else _TraceAnnotation(self.name)
+        ann.__enter__()
         if not _active:
             return self
         st = _stack()
@@ -236,27 +246,17 @@ class _Span:
         st.append(self.name)
         self._open = True
         self._gen = _generation
-        if _jax_bridge:
-            # guarded bridge: the span also lands in the XLA/XPlane trace
-            # when a jax device trace is running (TensorBoard alignment)
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
         self._t0 = time.perf_counter()
         return self
 
     def end(self):
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
         if not self._open:
             return
         t1 = time.perf_counter()
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(None, None, None)
-            finally:
-                self._ann = None
         st = _stack()
         if st and st[-1] is self.name:
             st.pop()
@@ -290,8 +290,7 @@ class _Span:
 
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            if not _active:           # decoration-time state is irrelevant;
-                return fn(*a, **kw)   # activity is sampled per call
+            # decoration-time state is irrelevant: a fresh span per call
             with _Span(name, category, args):
                 return fn(*a, **kw)
 
@@ -301,8 +300,8 @@ class _Span:
 def record(name: str, category: str = "user",
            args: Optional[dict] = None) -> _Span:
     """Span over a code region: ``with record("op/add", "dispatch"): ...``
-    or ``@record("step", "hapi")``. A no-op (one bool check) when no
-    ``profile()`` session is active."""
+    or ``@record("step", "hapi")``. With no ``profile()`` session active
+    it is its TraceAnnotation and one bool check."""
     return _Span(name, category, args)
 
 
@@ -315,9 +314,8 @@ class _Session:
         sess.export_chrome_trace("trace.json")
     """
 
-    def __init__(self, max_events=None, jax_bridge=False, clear=True):
+    def __init__(self, max_events=None, clear=True):
         self._max_events = max_events
-        self._jax_bridge = jax_bridge
         self._clear = clear
 
     def __enter__(self):
@@ -326,7 +324,7 @@ class _Session:
         # must not wipe the outer session's buffer
         if self._clear and _active_count == 0:
             reset()
-        enable(self._max_events, self._jax_bridge)
+        enable(self._max_events)
         return self
 
     def __exit__(self, *exc):
@@ -347,12 +345,12 @@ class _Session:
         return span_summary()
 
 
-def profile(max_events: Optional[int] = None, jax_bridge: bool = False,
+def profile(max_events: Optional[int] = None,
             clear: bool = True) -> _Session:
     """Profiling session context manager. Entering arms the global span
     buffer (cleared first unless ``clear=False``); leaving disarms it but
     KEEPS the events so the session's exporters still work."""
-    return _Session(max_events, jax_bridge, clear)
+    return _Session(max_events, clear)
 
 
 # ---------------------------------------------------------------------------

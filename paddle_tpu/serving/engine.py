@@ -1289,6 +1289,13 @@ class GenerationEngine:
         T = max(pool.table_bucket(s) for s in row_tokens)
         tables = pool.table_array(T, row_tokens)
         lo = np.zeros(S, np.int32)            # paged virtual floor
+        # every q block of a slot walks that slot's whole context, one
+        # block DMA a step
+        kv_steps = sum(-(-n // BLOCK_Q) * -(-int(kv_len[s]) // bs)
+                       for s, n in enumerate(q_lens) if n)
+        self._sched.note_launch(rows=sum(q_lens), q=Q, t=T,
+                                kv_tokens=int(kv_len.sum()),
+                                kv_steps=kv_steps)
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps)

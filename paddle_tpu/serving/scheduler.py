@@ -469,33 +469,45 @@ class Scheduler:
     def _loop(self) -> None:
         _prof.set_thread_name("serving scheduler")
         while True:
-            with self._cond:
-                while not self._closing and not self._queue \
-                        and not self._slots:
-                    self._cond.wait()
-                if self._closing and not self._queue and not self._slots:
-                    return
+            # the stretch between two cycles: idle until there is work,
+            # or the queue's lock held by a submitter
+            with _prof.record("serving/wait", "serving",
+                              args={"cycle": self._cycle + 1}):
+                with self._cond:
+                    while not self._closing and not self._queue \
+                            and not self._slots:
+                        self._cond.wait()
+                    if self._closing and not self._queue \
+                            and not self._slots:
+                        return
             self._cycle += 1
             t0 = time.perf_counter()
             # the cycle record is ALWAYS captured (bounded ring, host
-            # dicts only) — the spans below additionally land in the
-            # profiler buffer when a profile() session is armed
+            # dicts only). The spans below tile the cycle — each takes
+            # its own bookkeeping in, so the scheduler thread is
+            # between two of them for a few bytecodes only — and each
+            # carries the cycle's number: as TraceAnnotations they land
+            # in any running jax trace, on the device ops' clock, and in
+            # the profiler buffer when a profile() session is armed
             rec = self._rec = {
                 "cycle": self._cycle, "t": t0, "sweep_ms": 0.0,
-                "admit_ms": 0.0, "prefill_ms": 0.0,
+                "admit_ms": 0.0, "prefill_ms": 0.0, "plan_ms": 0.0,
                 "decode_dispatch_ms": 0.0, "fetch_ms": 0.0,
+                "emit_ms": 0.0,
                 "admitted": [], "retired": [], "emitted": 0,
                 "preempts": 0, "active": 0, "occupancy": 0.0,
                 "promo_waits": 0, "promoted_blocks": 0,
             }
             failed = None
+            cyc = {"cycle": self._cycle}     # every span carries it
+            cycle_span = _prof.record("serving/cycle", "serving",
+                                      args=cyc).begin()
             try:
-                with _prof.record("serving/cycle", "serving",
-                                  args={"cycle": self._cycle}):
+                with _prof.record("serving/sweep", "serving", args=cyc):
+                    self._sweep_queue()
                     t = time.perf_counter()
-                    with _prof.record("serving/sweep", "serving"):
-                        self._sweep_queue()
-                    rec["sweep_ms"] = (time.perf_counter() - t) * 1e3
+                    rec["sweep_ms"] = (t - t0) * 1e3
+                with _prof.record("serving/admit", "serving", args=cyc):
                     if self._paged and \
                             getattr(self._pool, "host_tier", None) \
                             is not None:
@@ -507,20 +519,19 @@ class Scheduler:
                         # for the queue FRONT while the decode slots
                         # are still busy (the pending-feed overlap)
                         self._prefetch_promotions()
-                    t = time.perf_counter()
-                    with _prof.record("serving/admit", "serving"):
-                        self._admit()
+                    t = time.perf_counter()     # admit_ms: _admit alone
+                    self._admit()
                     rec["admit_ms"] = (time.perf_counter() - t) * 1e3
-                    if self._slots:
-                        self._decode_cycle()
-                    elif rec["promo_waits"]:
-                        # nothing decoding and the only queued work is
-                        # waiting on in-flight promotions: nap on the
-                        # tier's progress beacon (host Event, ~2ms)
-                        # instead of hot-spinning the admit loop. With
-                        # decode slots active this branch never runs —
-                        # decode cycles never block on a promotion.
-                        self._pool.host_tier.wait_progress(0.002)
+                if self._slots:
+                    self._decode_cycle()
+                elif rec["promo_waits"]:
+                    # nothing decoding and the only queued work is
+                    # waiting on in-flight promotions: nap on the
+                    # tier's progress beacon (host Event, ~2ms)
+                    # instead of hot-spinning the admit loop. With
+                    # decode slots active this branch never runs —
+                    # decode cycles never block on a promotion.
+                    self._pool.host_tier.wait_progress(0.002)
             except Exception as e:                      # noqa: BLE001
                 # a step failure (OOM, bad artifact) poisons the affected
                 # requests, never the loop: fail everything in flight and
@@ -528,22 +539,24 @@ class Scheduler:
                 failed = e
                 self._fail_inflight(e)
             finally:
-                with self._cond:
-                    rec["queue_depth"] = len(self._queue)
-                if self._paged:
-                    rec["blocks_in_use"] = self._pool.blocks_in_use
-                if failed is not None:
-                    rec["failed"] = repr(failed)
-                rec["cycle_ms"] = (time.perf_counter() - t0) * 1e3
-                stat_observe("serving/cycle_ms", rec["cycle_ms"])
-                self.recorder.record_cycle(rec)
-                # HBM watermark per cycle — a host-only stamp
-                # (profiler/memory.py mark: ledger total, NO device
-                # poll — polling belongs to the sampler thread; the
-                # memory-stats-hot-path self-lint rule enforces it)
-                _memory.mark("serving/cycle", cycle=self._cycle,
-                             active=rec["active"])
-                self._rec = None
+                with _prof.record("serving/record", "serving", args=cyc):
+                    with self._cond:
+                        rec["queue_depth"] = len(self._queue)
+                    if self._paged:
+                        rec["blocks_in_use"] = self._pool.blocks_in_use
+                    if failed is not None:
+                        rec["failed"] = repr(failed)
+                    rec["cycle_ms"] = (time.perf_counter() - t0) * 1e3
+                    stat_observe("serving/cycle_ms", rec["cycle_ms"])
+                    self.recorder.record_cycle(rec)
+                    # HBM watermark per cycle — a host-only stamp
+                    # (profiler/memory.py mark: ledger total, NO device
+                    # poll — polling belongs to the sampler thread; the
+                    # memory-stats-hot-path self-lint rule enforces it)
+                    _memory.mark("serving/cycle", cycle=self._cycle,
+                                 active=rec["active"])
+                    self._rec = None
+                cycle_span.end()
                 if failed is not None:
                     # leave the postmortem behind: the profiler is
                     # almost never armed when a production step dies,
@@ -590,6 +603,21 @@ class Scheduler:
         if self._rec is not None:
             self._rec["decode_flops"] = \
                 self._rec.get("decode_flops", 0.0) + float(flops)
+
+    def note_launch(self, rows: int, q: int, t: int, kv_tokens: int,
+                    kv_steps: int) -> None:
+        """Record the shape of the ragged launch built THIS cycle into
+        the live cycle record (called by the engine's
+        ``_ragged_operands``, scheduler thread; host ints only):
+        ``launch_rows`` real query rows inside the ``(launch_q,
+        launch_t)`` program's buckets; ``kv_tokens``, the context
+        tokens the kernel must read (sum of the planned slots'
+        ``kv_len``); ``kv_steps``, the (q block, KV block) pairs it
+        walks per head and layer, one block DMA each."""
+        if self._rec is not None:
+            self._rec.update(launch_rows=int(rows), launch_q=int(q),
+                             launch_t=int(t), kv_tokens=int(kv_tokens),
+                             kv_steps=int(kv_steps))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into
@@ -1094,33 +1122,54 @@ class Scheduler:
         if self._chunked:
             self._chunked_cycle()
             return
-        if self._paged and not self._prepare_paged():
-            return
-        active = dict(self._slots)
-        occupancy = len(active) / self._pool.num_slots
-        stat_observe("serving/active_slots", len(active))
-        stat_observe("serving/batch_occupancy", occupancy)
+        cyc = {"cycle": self._cycle}
         rec = self._rec
-        if rec is not None:
-            rec["active"] = len(active)
-            rec["occupancy"] = occupancy
+        with _prof.record("serving/plan", "serving", args=cyc):
+            t0 = time.perf_counter()
+            active = dict(self._slots) \
+                if not self._paged or self._prepare_paged() else {}
+            if active:
+                occupancy = len(active) / self._pool.num_slots
+                stat_observe("serving/active_slots", len(active))
+                stat_observe("serving/batch_occupancy", occupancy)
+                if rec is not None:
+                    rec["active"] = len(active)
+                    rec["occupancy"] = occupancy
+            t1 = time.perf_counter()
+            if rec is not None:
+                rec["plan_ms"] += (t1 - t0) * 1e3
+        if not active:
+            return
         # dispatch and the windowed host fetch are timed APART: a slow
         # cycle with fat fetch_ms is a host-sync problem, one with fat
         # dispatch_ms is tracing/compile churn — the flight recorder
         # must distinguish them postmortem
-        t0 = time.perf_counter()
         with _prof.record("serving/decode_dispatch", "serving",
-                          args={"active": len(active)}):
+                          args={"cycle": self._cycle,
+                                "active": len(active)}):
             toks_dev = self._do_decode(active)
-        t1 = time.perf_counter()
-        with _prof.record("serving/host_fetch", "serving"):
+            t2 = time.perf_counter()
+        with _prof.record("serving/host_fetch", "serving", args=cyc):
             toks = _fetch(toks_dev)
-        t2 = time.perf_counter()
-        if rec is not None:
-            rec["decode_dispatch_ms"] += (t1 - t0) * 1e3
-            rec["fetch_ms"] += (t2 - t1) * 1e3
+            t3 = time.perf_counter()
+            if rec is not None:
+                rec["decode_dispatch_ms"] += (t2 - t1) * 1e3
+                rec["fetch_ms"] += (t3 - t2) * 1e3
+        with _prof.record("serving/emit", "serving", args=cyc):
+            self._emit_decode(active, toks, t3 - t1)
+            # freed inside the span: freeing a device array lets go of
+            # the GIL, and the stream consumers the loop has just woken
+            # hold it for milliseconds — host time of this cycle that
+            # would otherwise lie in no span
+            del toks_dev
+            if rec is not None:
+                rec["emit_ms"] += (time.perf_counter() - t3) * 1e3
+
+    def _emit_decode(self, active, toks, dt: float) -> None:
+        """The host half of a decode cycle once its tokens are fetched:
+        advance, emit and retire every active slot."""
+        rec = self._rec
         self._note_nonfinite(toks, rec)
-        dt = t2 - t0
         emitted = 0
         now = time.perf_counter()
         for slot, req in active.items():
@@ -1187,38 +1236,54 @@ class Scheduler:
         pool position rolls back over the rejected rows (signed
         ``advance``), and any cache registration the dead rows touched
         is dropped."""
-        plan = self._chunk_plan()
-        spec = self._spec_plan(plan) if self._spec else {}
-        plan = self._prepare_chunked(plan)
-        spec = {s: n for s, n in spec.items() if s in plan}
+        cyc = {"cycle": self._cycle}
+        rec = self._rec
+        with _prof.record("serving/plan", "serving", args=cyc):
+            t0 = time.perf_counter()
+            plan = self._chunk_plan()
+            spec = self._spec_plan(plan) if self._spec else {}
+            plan = self._prepare_chunked(plan)
+            spec = {s: n for s, n in spec.items() if s in plan}
+            if plan:
+                active = {s: self._slots[s] for s in plan}
+                occupancy = len(self._slots) / self._pool.num_slots
+                stat_observe("serving/active_slots", len(self._slots))
+                stat_observe("serving/batch_occupancy", occupancy)
+                if rec is not None:
+                    rec["active"] = len(self._slots)
+                    rec["occupancy"] = occupancy
+                launch = {"cycle": self._cycle, "active": len(active),
+                          "spec_slots": len(spec),
+                          "chunk_rows": sum(n for s, n in plan.items()
+                                            if active[s].pending_feed)}
+            t1 = time.perf_counter()
+            if rec is not None:
+                rec["plan_ms"] += (t1 - t0) * 1e3
         if not plan:
             return
-        active = {s: self._slots[s] for s in plan}
-        occupancy = len(self._slots) / self._pool.num_slots
-        stat_observe("serving/active_slots", len(self._slots))
-        stat_observe("serving/batch_occupancy", occupancy)
-        rec = self._rec
-        if rec is not None:
-            rec["active"] = len(self._slots)
-            rec["occupancy"] = occupancy
-        t0 = time.perf_counter()
         with _prof.record("serving/decode_dispatch", "serving",
-                          args={"active": len(active),
-                                "spec_slots": len(spec),
-                                "chunk_rows": sum(
-                                    n for s, n in plan.items()
-                                    if active[s].pending_feed)}):
+                          args=launch):
             if spec:
                 toks_dev = self._do_spec(active, plan, spec)
             else:
                 toks_dev = self._do_chunked(active, plan)
-        t1 = time.perf_counter()
-        with _prof.record("serving/host_fetch", "serving"):
+            t2 = time.perf_counter()
+        with _prof.record("serving/host_fetch", "serving", args=cyc):
             toks = _fetch(toks_dev)
-        t2 = time.perf_counter()
-        if rec is not None:
-            rec["decode_dispatch_ms"] += (t1 - t0) * 1e3
-            rec["fetch_ms"] += (t2 - t1) * 1e3
+            t3 = time.perf_counter()
+            if rec is not None:
+                rec["decode_dispatch_ms"] += (t2 - t1) * 1e3
+                rec["fetch_ms"] += (t3 - t2) * 1e3
+        with _prof.record("serving/emit", "serving", args=cyc):
+            self._emit_chunked(active, plan, spec, toks, t3 - t1)
+            del toks_dev        # see _decode_cycle: inside the span
+            if rec is not None:
+                rec["emit_ms"] += (time.perf_counter() - t3) * 1e3
+
+    def _emit_chunked(self, active, plan, spec, toks, dt: float) -> None:
+        """The host half of a fused cycle once the launch's tokens are
+        fetched: account chunks and verify outcomes, emit, retire."""
+        rec = self._rec
         S = self._pool.num_slots
         K = self._spec_k
         if spec:
@@ -1231,7 +1296,6 @@ class Scheduler:
             self._note_nonfinite(toks, rec, idx=2 * S + S * K)
         else:
             self._note_nonfinite(toks, rec)
-        dt = t2 - t0
         emitted = 0
         chunks = 0
         chunk_tokens = 0

@@ -1,0 +1,250 @@
+"""The program's own spans in the profiler's trace: every ``record()``
+span is a ``jax.profiler.TraceAnnotation``, so a jax trace taken by
+ANYONE (no ``profile()`` session armed) holds the serving cycle and the
+train step on ``/host:CPU``, with their numbers; and the launch counters
+the engine notes into the cycle record."""
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+import paddle_tpu.nn as nn
+from paddle_tpu.models import GPTConfig, GPTForPretraining
+from paddle_tpu.profiler import span as S
+from paddle_tpu.serving import GenerationEngine
+
+CHILDREN = ["serving/sweep", "serving/admit", "serving/plan",
+            "serving/decode_dispatch", "serving/host_fetch", "serving/emit",
+            "serving/record"]
+# an inactive span is one TraceMe that finds no trace running plus one
+# bool check: 0.6-1 us here. The bound leaves room for a loaded test host
+INACTIVE_SPAN_BOUND_US = 20.0
+
+
+class _JaxTrace:
+    """``with _JaxTrace(dir) as t:`` traces the block the way an outside
+    caller does (``jax.profiler.start_trace``, no ``profile()``);
+    ``t.host_events()`` then gives the ``/host:CPU`` events as
+    ``(start_ns, end_ns, name, stats)``."""
+
+    def __init__(self, trace_dir):
+        self.dir = str(trace_dir)
+
+    def __enter__(self):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0      # the spans, not every call
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        return False
+
+    def host_events(self, prefix):
+        from jax.profiler import ProfileData
+        path, = glob.glob(os.path.join(self.dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        out = []
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(prefix):
+                        s = int(ev.start_ns)
+                        out.append((s, s + int(ev.duration_ns), ev.name,
+                                    dict(ev.stats)))
+        return sorted(out, key=lambda e: (e[0], -e[1]))
+
+
+@pytest.fixture(autouse=True)
+def _no_session():
+    S.reset()
+    assert not S.is_active()
+    yield
+    assert not S.is_active()
+
+
+def test_a_span_lands_in_a_jax_trace_with_no_session_armed(tmp_path):
+    with _JaxTrace(tmp_path) as trace:
+        with S.record("unit/outer", "user", args={"cycle": 7, "who": "me"}):
+            with S.record("unit/inner", "user"):
+                time.sleep(0.001)
+
+        @S.record("unit/decorated", "user")
+        def f():
+            return 3
+
+        assert f() == 3
+    events = trace.host_events("unit/")
+    assert [e[2] for e in events] == ["unit/outer", "unit/inner",
+                                      "unit/decorated"]
+    outer, inner, _ = events
+    assert outer[3] == {"cycle": 7, "who": "me"}
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+    assert inner[1] - inner[0] >= 1_000_000
+    # the Python event buffer is the profile() session's alone
+    assert S.events() == []
+
+
+def test_with_no_trace_running_a_span_buffers_nothing_and_costs_little():
+    n = 20_000
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for i in range(n):
+            with S.record("unit/idle", "user", args={"cycle": i}):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n * 1e6)
+    assert S.events() == [] and S.dropped() == 0
+    assert best < INACTIVE_SPAN_BOUND_US, f"{best:.2f} us per inactive span"
+
+
+def test_a_session_still_buffers_the_same_spans(tmp_path):
+    """Both at once: the armed buffer and the jax trace see one span."""
+    with _JaxTrace(tmp_path) as trace:
+        with S.profile():
+            with S.record("unit/both", "user", args={"k": 1}):
+                pass
+    assert [e["name"] for e in S.events()] == ["unit/both"]
+    assert [e[2:] for e in trace.host_events("unit/")] == \
+        [("unit/both", {"k": 1})]
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    paddle.seed(5)
+    return GPTForPretraining(GPTConfig.tiny())
+
+
+def _prompt(rng, n):
+    return rng.randint(1, 50, size=n).astype(np.int32)
+
+
+def test_serving_cycles_are_in_the_trace_with_their_children_in_order(
+        tiny_lm, tmp_path):
+    eng = GenerationEngine(tiny_lm, num_slots=2, max_len=32,
+                           kv_layout="paged", block_size=8,
+                           attention="fused", prefill_budget=8)
+    rng = np.random.RandomState(0)
+    try:
+        # warm: the traced cycles compile nothing
+        for h in [eng.submit(_prompt(rng, n), max_new_tokens=3)
+                  for n in (12, 3)]:
+            h.result(timeout=300)
+        before = {c["cycle"] for c in
+                  eng.flight_recorder.snapshot()["cycles"]}
+        with _JaxTrace(tmp_path) as trace:
+            for h in [eng.submit(_prompt(rng, n), max_new_tokens=3)
+                      for n in (12, 3)]:
+                h.result(timeout=300)
+        records = {c["cycle"]: c for c in
+                   eng.flight_recorder.snapshot()["cycles"]
+                   if c["cycle"] not in before}
+    finally:
+        eng.close()
+    events = trace.host_events("serving/")
+    cycles = [e for e in events if e[2] == "serving/cycle"]
+    numbers = [e[3]["cycle"] for e in cycles]
+    assert len(numbers) >= 3 and len(set(numbers)) == len(numbers)
+    assert set(numbers) <= set(records)          # one span per cycle run
+    for lo, hi, _, stats in cycles:
+        n = stats["cycle"]
+        inside = [e for e in events if e[3].get("cycle") == n
+                  and e[2] not in ("serving/cycle", "serving/wait")]
+        names = [e[2] for e in inside]
+        if records[n]["active"]:
+            assert names == CHILDREN, (n, names)
+        else:                                    # nothing to decode
+            assert names == ["serving/sweep", "serving/admit",
+                             "serving/record"]
+        assert lo <= inside[0][0] and inside[-1][1] <= hi
+        for (_, end, _, _), (start, _, _, _) in zip(inside, inside[1:]):
+            assert end <= start                  # in order, no overlap
+    # the stretch between two cycles is a span too, before its cycle
+    # (the first traced cycle's wait began before the trace did)
+    waits = {e[3]["cycle"]: e for e in events if e[2] == "serving/wait"}
+    for lo, _, _, stats in cycles[1:]:
+        assert waits[stats["cycle"]][1] <= lo
+    # the one child without a number is found by containment
+    admits = [e for e in events if e[2] == "serving/admit"]
+    prefills = [e for e in events if e[2] == "serving/prefill"]
+    assert len(prefills) == 2
+    for s, e, _, _ in prefills:
+        assert any(lo <= s and e <= hi for lo, hi, _, _ in admits)
+    assert S.events() == []                      # no session, no buffer
+
+
+def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
+    eng = GenerationEngine(tiny_lm, num_slots=2, max_len=32,
+                           kv_layout="paged", block_size=8,
+                           attention="fused", prefill_budget=8)
+    # (cycle, rows, sum of pos + rows, q blocks of 8 x KV blocks of 8)
+    planned = []
+    build = eng._ragged_operands
+
+    def spy(slot_requests, plan, spec=None):
+        live = {s: int(plan[s]) for s in slot_requests if plan.get(s, 0) > 0}
+        ends = {s: eng._pool.slot_pos(s) + n for s, n in live.items()}
+        planned.append((eng._sched._cycle, sum(live.values()),
+                        sum(ends.values()),
+                        sum(-(-n // 8) * -(-ends[s] // 8)
+                            for s, n in live.items())))
+        return build(slot_requests, plan, spec)
+
+    eng._ragged_operands = spy
+    rng = np.random.RandomState(1)
+    try:
+        for h in [eng.submit(_prompt(rng, n), max_new_tokens=4)
+                  for n in (13, 5)]:
+            h.result(timeout=300)
+    finally:
+        eng.close()          # joins the scheduler: the last record is in
+    records = {c["cycle"]: c for c in
+               eng.flight_recorder.snapshot()["cycles"]}
+    assert len(planned) >= 4
+    for cycle, rows, kv, steps in planned:
+        rec = records[cycle]
+        for key in ("plan_ms", "emit_ms", "launch_rows", "launch_q",
+                    "launch_t", "kv_tokens", "kv_steps"):
+            assert key in rec, key
+        assert rec["launch_rows"] == rows
+        assert rec["kv_tokens"] == kv
+        assert rec["kv_steps"] == steps
+        assert rec["launch_rows"] <= rec["launch_q"]
+        assert rec["launch_q"] % 8 == 0 and rec["launch_t"] >= 1
+        assert rec["plan_ms"] > 0 and rec["emit_ms"] > 0
+        # plan, launch, fetch and emit are parts of the cycle
+        assert rec["plan_ms"] + rec["decode_dispatch_ms"] + rec["fetch_ms"] \
+            + rec["emit_ms"] <= rec["cycle_ms"]
+    # chunk cycles and plain decode cycles both passed through
+    assert any(records[c]["chunk_tokens"] for c, *_ in planned)
+    assert any(not records[c]["chunk_tokens"] and r <= 2
+               for c, r, *_ in planned)
+    # a 13-token prompt in chunks of 8: the second chunk's one q block
+    # walks both of the prompt's KV blocks
+    assert any(steps > 1 and r <= 8 for _, r, _, steps in planned)
+
+
+def test_a_train_step_is_in_the_trace_with_its_number(tmp_path):
+    rng = np.random.RandomState(0)
+    net = nn.Sequential(nn.Linear(16, 8), nn.ReLU(), nn.Linear(8, 4))
+    model = paddle.Model(net)
+    model.prepare(paddle.optimizer.Adam(learning_rate=1e-2,
+                                        parameters=net.parameters()),
+                  nn.CrossEntropyLoss())
+    x = rng.randn(8, 16).astype(np.float32)
+    y = rng.randint(0, 4, (8, 1)).astype(np.int64)
+    model.train_batch([x], [y])                  # step 1 builds and compiles
+    with _JaxTrace(tmp_path) as trace:
+        model.train_batch([x], [y])
+        model.train_batch([x], [y])
+    steps = [e for e in trace.host_events("hapi/")
+             if e[2] == "hapi/train_batch"]
+    assert [e[3] for e in steps] == [{"step": 2}, {"step": 3}]
+    assert steps[0][1] <= steps[1][0]
+    assert S.events() == []
