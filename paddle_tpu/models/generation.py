@@ -1301,8 +1301,8 @@ def _mp_fused_tower(gpt, x, pool, write_block, write_off, blk_seq,
                     mp_axis):
     """Per-device fused ragged tower: each device scatters its OWN
     heads' K/V into its pool shard and launches the ragged Pallas
-    kernel over its local head range — the kernel's grid is already
-    per-head, so the per-shard call is the UNMODIFIED kernel on an
+    kernel over its local head range — heads are a batch dimension of
+    the kernel, so the per-shard call is the UNMODIFIED kernel on an
     ``[H/mp, ...]`` slice with the replicated scalar-prefetch metadata.
     Returns ``(ln_f(x), pool)``."""
     import jax.numpy as jnp

@@ -7,11 +7,26 @@ on every decode step, and attention then runs over the padded
 ``table_bucket * block_size`` columns for every slot. This kernel is
 the TPU-native replacement per "Ragged Paged Attention" (PAPERS.md):
 the block pool stays in HBM (``memory_space=pl.ANY``), the kernel walks
-each sequence's page table directly — one async DMA per (KV block,
-head) into VMEM scratch — and streams online softmax over exactly the
-blocks a sequence owns. Nothing is gathered, nothing is padded to the
-table bucket, and a single launch serves a RAGGED batch of mixed
-prefill-chunk and decode rows (the chunked-prefill unlock).
+each sequence's page table directly and streams online softmax over
+exactly the blocks a sequence owns. Nothing is gathered, nothing is
+padded to the table bucket, and a single launch serves a RAGGED batch of
+mixed prefill-chunk and decode rows (the chunked-prefill unlock).
+
+The KV walk (PR 28; the one statement of it): the grid is over q blocks
+only, and one grid step owns a sequence's q block for EVERY head, so
+the page table is walked once, not ``H`` times. A KV block comes in ONE
+async DMA — ``pool[layer, pid]`` whole, every head's K|V, 80 KB at
+GPT-2 large — and ``G`` of them go out together as a group, into one of
+two VMEM buffers: the next group's DMAs are started before this group
+is waited for, so only a grid step's first group is exposed. Compute is
+once a group: per head the scores are ``[block_q, G * block_size]``, a
+full 128 lanes, and the online-softmax update (max, exp, rescale,
+``p . V``) runs on that tile; a block's K|V tile goes to the MXU whole
+(q zero-extended over the V lanes), never split along its lanes. ``G``
+is read from the pool's shape and
+dtype alone (``kv_group_blocks``). Before it the walk was one blocking
+4 KB copy per (block, head) and two 16-lane products a copy: 0.65 us a
+step, 0.86 M steps a decode launch of GPT-2 large (PERF.md section 6).
 
 Layout contract (the serving engine's fused step builds these):
 
@@ -35,26 +50,30 @@ head is a ``(block_size, 2 * Dh)`` tile whose lanes hold K in
 a ``(block_size, Dh)`` tile at ``Dh = 64`` is refused by Mosaic ("slice
 shape must be aligned to tiling (128)") and padded 2x in HBM, while K|V
 folded into the lanes is exactly 128 wide at ``Dh = 64`` (and 256 at
-``Dh = 128``). One DMA per (block, head) brings both K and V. Everything
+``Dh = 128``). ``pool[layer, pid]`` is one contiguous ``[H, bs, 2 * Dh]``
+region, so one DMA brings a block's K and V for every head. Everything
 that touches the pool — ``serving/paging.py``, the gather decode path,
 ``serving/host_tier.py``, the int8 scales, the head-partitioned TP
 shard — reads this one layout.
 
 Mosaic legality (enforced by the ``pallas-block-tiling`` self-lint):
-q/o blocks are ``(1, block_q, Dh)`` with ``block_q = 8`` sublane-aligned
-and ``Dh`` the full array dim; the KV scratch is ``(block_size, 2 * Dh)``
-with ``block_size`` at least the storage dtype's sublane count and, on
-a TPU, ``2 * Dh`` a multiple of 128.
+q/o blocks are ``(H, block_q, Dh)`` with ``block_q = 8`` sublane-aligned
+and ``Dh`` the full array dim; a KV buffer is ``(H, G * block_size,
+2 * Dh)`` and a block's DMA lands in ``block_size`` of its rows, with
+``block_size`` at least the storage dtype's sublane count and, on a TPU,
+``2 * Dh`` a multiple of 128 (``check_kv_tile``; compiled for a described
+v5e at GPT-2 widths in tests/test_tpu_compile.py).
 
 Off-TPU the kernel runs in interpret mode — that is how the tier-1
 parity suite (tests/test_ragged_attention.py) executes the kernel body
 on CPU.
 
 Tensor-parallel use (ISSUE 15): the kernel is head-count agnostic —
-its grid is per-(q block, head), so the sharded serving step
+heads are a batch dimension of one grid step, so the sharded serving step
 (``build_sharded_fused_step_fn``) simply launches it inside a
 ``shard_map`` with the LOCAL head count ``H/mp`` against each device's
-own pool shard ``[L, blocks, H/mp, bs, 2 * Dh]``. No kernel change: the
+own pool shard ``[L, blocks, H/mp, bs, 2 * Dh]`` (a shard's
+``pool[layer, pid]`` is contiguous too). No kernel change: the
 page tables and scalar-prefetch metadata are replicated (block indices
 are shard-invariant), the per-head outputs are partial sums of the
 attention projection, and one downstream ``psum`` joins them.
@@ -74,7 +93,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_kernels import _interpret, _x64_off
 
 __all__ = ["ragged_paged_attention", "ragged_layout", "BLOCK_Q",
-           "MIN_KV_BLOCK", "min_kv_block_for", "check_kv_tile"]
+           "MIN_KV_BLOCK", "KV_VMEM_BUDGET", "min_kv_block_for",
+           "check_kv_tile", "kv_group_blocks"]
 
 _NEG_INF = -1e30
 
@@ -86,6 +106,11 @@ BLOCK_Q = 8
 # the KV scratch block is (block_size, 2 * Dh): block_size below the
 # sublane count has no legal TPU layout
 MIN_KV_BLOCK = 8
+
+# the two group buffers of the KV walk (2 x G whole blocks, every head,
+# K and V) may take this much VMEM: 1.3 MB at gpt2-large (G = 8 blocks of
+# 80 KB), far under it; a pool whose blocks are fatter walks smaller groups
+KV_VMEM_BUDGET = 4 << 20
 
 # QUANTIZED storage needs a taller minimum tile (the Mosaic
 # (sublane, 128) law: int8/fp8 sublane count is 32) — float pools keep
@@ -121,18 +146,49 @@ def check_kv_tile(dtype, block_size: int, head_dim: int) -> None:
             f"cover whole 128-lane tiles")
 
 
-def _rpa_kernel(blk_seq_ref, qstart_ref, pos0_ref, tables_ref, lo_ref,
-                kvlen_ref, *rest, layer, block_q, block_size, scale,
-                quantized=False):
-    """One (head, q-block) grid step: walk the owning sequence's page
-    table, DMA each KV block HBM→VMEM, stream online softmax.
+def kv_group_blocks(heads: int, block_size: int, head_dim: int,
+                    dtype) -> int:
+    """``G``: how many KV blocks the kernel fetches, and computes on, at
+    a time — read from the pool's shape and dtype and from nothing else.
+    As many as fill one 128-lane score tile (``G * block_size = 128``: 8
+    at ``block_size`` 16, 4 at the int8 minimum 32), halved only while
+    the two group buffers of ``G`` whole blocks (every head, K and V)
+    would pass ``KV_VMEM_BUDGET``. The engine's ``kv_fetches`` counter
+    asks here too, so it counts the groups the kernel waits for."""
+    g = max(1, 128 // int(block_size))
+    block_bytes = (int(heads) * int(block_size) * 2 * int(head_dim)
+                   * jnp.dtype(dtype).itemsize)
+    while g > 1 and 2 * g * block_bytes > KV_VMEM_BUDGET:
+        g //= 2
+    return g
 
-    Quantized pools (int8 blocks) ride a 7th scalar-prefetch operand:
-    THIS layer's per-block max-abs scale slice ``[2, NB + 1, H]`` f32 —
-    each DMA'd block is dequantized IN-REGISTER (one scalar multiply
-    per (block, head) after the VMEM read), so the HBM traffic stays at
-    the narrow storage width and nothing quantized ever reaches the
-    MXU.
+
+def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
+                lo_ref, kvlen_ref, *rest, block_q, block_size, group, scale,
+                quantized=False):
+    """One q-block grid step, every head at once: walk the owning
+    sequence's page table ONCE, a group of ``group`` KV blocks at a
+    time — one DMA a block brings ``pool[layer, pid]`` whole (all heads,
+    K|V), the next group's DMAs are started before this group is waited
+    for and computed on (two buffers) — and stream online softmax over
+    ``[block_q, group * block_size]`` score tiles, one update a group.
+
+    Only the blocks the sequence owns are fetched (``j < n_kv <= T``:
+    the table is never read past its width, the scratch block its
+    padding names never fetched). The rest of a partial group's buffer
+    holds whatever an earlier group left there, so those columns — and
+    a last block's rows past ``kv_len`` — are masked out of BOTH
+    products: the K|V rows by ``where`` to 0 before either (a 0 weight
+    does not silence a NaN), the scores by ``where`` (so ``p`` is
+    exactly 0).
+
+    Quantized pools (int8/fp8 blocks) ride an 8th scalar-prefetch
+    operand: THIS layer's per-block max-abs scale slice ``[2, NB + 1,
+    H]`` f32. The stored values go to the MXU as they are (exact in the
+    compute dtype) and the scale of each (block, head) multiplies that
+    block's columns of the f32 scores (K) and of ``p`` (V) — one
+    scalar per (block, head), read in a static loop over heads; HBM
+    traffic stays at the narrow storage width.
 
     i32-typed constants: bare python ints in kernel index math get
     materialized as i64 by Mosaic under the framework's global x64 (the
@@ -142,9 +198,12 @@ def _rpa_kernel(blk_seq_ref, qstart_ref, pos0_ref, tables_ref, lo_ref,
     else:
         scales_ref = None
         q_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
-    h = pl.program_id(0)
-    b = pl.program_id(1)
+    b = pl.program_id(0)
+    layer = layer_ref[0]
     seq = blk_seq_ref[b]
+    n_heads, _, dh = q_ref.shape
+    cols_g = group * block_size             # KV columns of one group
+    t_len = tables_ref.shape[1]
 
     @pl.when(seq < 0)
     def _pad_block():
@@ -154,74 +213,121 @@ def _rpa_kernel(blk_seq_ref, qstart_ref, pos0_ref, tables_ref, lo_ref,
     def _attend():
         _BS = jnp.int32(block_size)
         _BQ = jnp.int32(block_q)
-        q = q_ref[0]                                    # [bq, Dh]
-        bq, dh = q.shape
+        _G = jnp.int32(group)
+        _CG = jnp.int32(cols_g)
+        # a block's K|V tile goes to the MXU whole, never split along
+        # its lanes: q is zero-extended over the V lanes, so q . [K|V]^T
+        # is q . K^T, and p . [K|V] holds p . V in its upper Dh lanes
+        q = q_ref[...]                                  # [H, bq, Dh]
+        q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
         # virtual cache position of each row: rows of a sequence are
         # consecutive tokens starting at seq_pos0 (pad rows past the
-        # real q_len compute masked garbage nobody reads)
+        # real q_len see the whole context and nobody reads them)
         row0 = b * _BQ - qstart_ref[seq]
         qpos = pos0_ref[seq] + row0 + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, 1), 0)                 # [bq, 1]
         lo = lo_ref[seq]
-        n_kv = (kvlen_ref[seq] + _BS - 1) // _BS
+        kv_len = kvlen_ref[seq]
+        n_kv = jnp.minimum((kv_len + _BS - 1) // _BS, jnp.int32(t_len))
+        n_grp = (n_kv + _G - 1) // _G
 
-        def body(j, carry):
-            # running softmax stats stay 2D [bq, 1] (sublane-oriented);
+        def block_copies(grp, slot, act):
+            # the page-table walk: the grp-th group's blocks, each ONE
+            # copy of pool[layer, pid] — every head's (bs, 2*Dh) K|V
+            # tile — into its rows of buffer `slot`; `act` starts or
+            # waits. Blocks past the sequence's last are not touched.
+            j0 = grp * _G
+
+            def one(g, carry):
+                rows = pl.ds(pl.multiple_of(g * _BS, block_size),
+                             block_size)
+                act(pltpu.make_async_copy(
+                    pool_ref.at[layer, tables_ref[seq, j0 + g]],
+                    kv_scr.at[slot, :, rows, :], kv_sem.at[slot, g]))
+                return carry
+
+            jax.lax.fori_loop(jnp.int32(0), jnp.minimum(_G, n_kv - j0),
+                              one, jnp.int32(0))
+
+        def col_scales(grp):
+            # K's and V's [H, 1, G*bs] f32: scales_ref[0|1, pid, h] over
+            # the columns of the group's block holding pid (a block past
+            # the sequence's last reads the last one's: masked columns)
+            blk = jax.lax.broadcasted_iota(
+                jnp.int32, (1, cols_g), 1) // _BS
+            pids = [tables_ref[seq, jnp.minimum(grp * _G + jnp.int32(g),
+                                                n_kv - 1)]
+                    for g in range(group)]
+
+            def over_columns(which, h):
+                row = jnp.zeros((1, cols_g), jnp.float32)
+                for g, pid in enumerate(pids):
+                    row = jnp.where(blk == g, scales_ref[which, pid, h],
+                                    row)
+                return row
+
+            return [jnp.stack([over_columns(which, h)
+                               for h in range(n_heads)])
+                    for which in (0, 1)]
+
+        block_copies(jnp.int32(0), jnp.int32(0), lambda cp: cp.start())
+
+        def body(grp, carry):
+            # running softmax stats stay [H, bq, 1] (sublane-oriented);
             # rank-1 carries would force lane<->sublane relayouts
             m_prev, l_prev, acc = carry
-            pid = tables_ref[seq, j]
-            # the page-table walk: this sequence's j-th block, this
-            # head, K|V in one (bs, 2*Dh) tile copied HBM -> VMEM — the
-            # ONLY KV bytes this grid step touches (the gather path
-            # would have materialized the whole padded table bucket for
-            # every slot)
-            cp = pltpu.make_async_copy(
-                pool_ref.at[layer, pid, h], kv_scr, kv_sem)
-            cp.start()
-            cp.wait()
-            k_blk = kv_scr[:, :dh]                      # [bs, Dh]
-            v_blk = kv_scr[:, dh:]
-            if quantized:
-                # in-register dequant: the per-(block, head) max-abs
-                # scale rides the scalar-prefetch metadata; HBM moved
-                # int8, compute sees floats. scales_ref is THIS
-                # layer's [2, NB+1, H] slice — prefetching all L
-                # layers' scales into SMEM would waste an L-fold
-                # bigger scalar-memory footprint per launch
-                k_blk = (k_blk.astype(jnp.float32)
-                         * scales_ref[0, pid, h]).astype(q.dtype)
-                v_blk = (v_blk.astype(jnp.float32)
-                         * scales_ref[1, pid, h]).astype(q.dtype)
-            # operands in storage dtype, f32 accumulation (MXU contract
-            # shared with the flash kernels)
+            slot = grp % 2
+
+            @pl.when(grp + 1 < n_grp)
+            def _prefetch():
+                block_copies(grp + 1, 1 - slot, lambda cp: cp.start())
+
+            block_copies(grp, slot, lambda cp: cp.wait())
+            # rows no block of this sequence filled, and a last
+            # block's rows past kv_len, go to the MXU as zeros
+            kv_rows = grp * _CG + jax.lax.broadcasted_iota(
+                jnp.int32, (cols_g, 1), 0)
+            kv = kv_scr[slot]                           # [H, G*bs, 2*Dh]
+            kv = jnp.where((kv_rows < kv_len)[None], kv,
+                           jnp.zeros_like(kv)).astype(q.dtype)
+            # operands in storage dtype (a quantized pool's values are
+            # exact in q's), f32 accumulation (MXU contract shared with
+            # the flash kernels)
             s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [bq, bs]
-            cols = j * _BS + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_size), 1)
+                q, kv, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale  # [H, bq, G*bs]
+            if quantized:
+                k_scale, v_scale = col_scales(grp)
+                s = s * k_scale
+            cols = grp * _CG + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, cols_g), 1)
             # f32-typed fill: a bare python float is weak f64 under the
             # framework's global x64
-            s = jnp.where((cols >= lo) & (cols <= qpos), s,
+            s = jnp.where(((cols >= lo) & (cols <= qpos)
+                           & (cols < kv_len))[None], s,
                           jnp.float32(_NEG_INF))
             m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * v_scale
             acc_new = acc * alpha + jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                p.astype(q.dtype), kv, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # [H, bq, 2*Dh]
             return m_new, l_new, acc_new
 
-        m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((block_q, 1), jnp.float32)
-        acc0 = jnp.zeros((block_q, dh), jnp.float32)
+        m0 = jnp.full((n_heads, block_q, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((n_heads, block_q, 1), jnp.float32)
+        acc0 = jnp.zeros((n_heads, block_q, 2 * dh), jnp.float32)
         # i32 bounds: a bare python 0 becomes an i64 induction variable
         # under the framework's global x64, and the interpret-mode body
         # trace happens outside the call site's _x64_off scope
-        _, l, acc = jax.lax.fori_loop(jnp.int32(0), n_kv, body,
+        _, l, acc = jax.lax.fori_loop(jnp.int32(0), n_grp, body,
                                       (m0, l0, acc0))
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc[:, :, dh:]
+                      / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
@@ -233,12 +339,13 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
       multiple of ``block_q``; per-sequence contiguous, see module doc);
     * ``pool`` — the FULL block pool ``[L, NB + 1, H, bs, 2 * Dh]``
       (K|V folded into the lanes, see module doc); it stays in HBM
-      (``memory_space=pl.ANY``) and ``layer`` is a static int, so no
-      per-layer slice is ever materialized;
+      (``memory_space=pl.ANY``) and ``layer`` (a host int) indexes it
+      inside the kernel's DMAs, so no per-layer slice is ever
+      materialized;
     * ``blk_seq [Qp / block_q]``, ``seq_qstart [S]``, ``seq_pos0 [S]``,
       ``tables [S, T]``, ``lo [S]``, ``kv_len [S]`` — int32
       scalar-prefetch metadata (``ragged_layout`` builds the first
-      three);
+      three); ``kv_len[s] <= T * bs``;
     * ``scales`` — REQUIRED for quantized pools (int8/fp8 storage):
       the per-block max-abs scale array ``[L, 2, NB + 1, H]`` f32,
       riding the scalar-prefetch path into SMEM so each DMA'd block
@@ -267,42 +374,59 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
     if not 0 <= int(layer) < L:
         raise ValueError(f"layer {layer} out of range [0, {L})")
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
-    n_qblk = qp // block_q
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    with _x64_off():
+        return _rpa_call(
+            i32([layer]), q, pool, i32(blk_seq), i32(seq_qstart),
+            i32(seq_pos0), i32(tables), i32(lo), i32(kv_len),
+            None if scales is None else jnp.asarray(scales, jnp.float32),
+            scale=scale, block_q=int(block_q), interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "interpret"))
+def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
+              kv_len, scales, *, scale, block_q, interpret):
+    """The Pallas call. ``layer`` is a ``[1]`` int32 scalar-prefetch
+    operand, not a constant of the kernel, and the call is a jitted
+    function of its own: the layers of a step program differ in nothing
+    the trace can see, so the kernel is traced and lowered once a
+    program, not once a layer (36 times at GPT-2 large, in every
+    warm-up, compile cache warm or not)."""
+    h, qp, dh = q.shape
+    bs = pool.shape[3]
     quant = scales is not None
+    group = kv_group_blocks(h, bs, dh, pool.dtype)
     kernel = functools.partial(
-        _rpa_kernel, layer=int(layer), block_q=int(block_q),
-        block_size=int(bs), scale=scale, quantized=quant)
+        _rpa_kernel, block_q=block_q, block_size=int(bs), group=group,
+        scale=scale, quantized=quant)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7 if quant else 6,
-        grid=(h, n_qblk),
+        num_scalar_prefetch=8 if quant else 7,
+        grid=(qp // block_q,),
         in_specs=[
-            pl.BlockSpec((1, block_q, dh), lambda hh, b, *_: (hh, b, 0)),
+            pl.BlockSpec((h, block_q, dh), lambda b, *_: (0, b, 0)),
             pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, block_q, dh),
-                               lambda hh, b, *_: (hh, b, 0)),
+        out_specs=pl.BlockSpec((h, block_q, dh), lambda b, *_: (0, b, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bs, dh2), pool.dtype),
-            pltpu.SemaphoreType.DMA,
+            # two buffers of one group: block g of a group is rows
+            # [g*bs, (g+1)*bs) of every head, so a head's K|V of the
+            # whole group is one [G*bs, 2*Dh] tile stack
+            pltpu.VMEM((2, h, group * bs, 2 * dh), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, group)),
         ],
     )
-    prefetch = [jnp.asarray(blk_seq, jnp.int32),
-                jnp.asarray(seq_qstart, jnp.int32),
-                jnp.asarray(seq_pos0, jnp.int32),
-                jnp.asarray(tables, jnp.int32),
-                jnp.asarray(lo, jnp.int32),
-                jnp.asarray(kv_len, jnp.int32)]
+    prefetch = [layer, blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len]
     if quant:
         # only THIS layer's [2, NB+1, H] scale slice goes to SMEM
-        prefetch.append(jnp.asarray(scales, jnp.float32)[int(layer)])
-    with _x64_off():
-        return pl.pallas_call(
-            kernel,
-            name="ragged_paged_attention",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((h, qp, dh), q.dtype),
-            interpret=_interpret(),
-        )(*prefetch, q, pool)
+        prefetch.append(jax.lax.dynamic_index_in_dim(
+            scales, layer[0], 0, keepdims=False))
+    return pl.pallas_call(
+        kernel,
+        name="ragged_paged_attention",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((h, qp, dh), q.dtype),
+        interpret=interpret,
+    )(*prefetch, q, pool)
 
 
 def ragged_layout(q_lens: Sequence[int], pos0s: Sequence[int], *,

@@ -1240,7 +1240,8 @@ class GenerationEngine:
         contribute their candidate rows with only ``last_token``
         host-known — the draft tokens overlay on the device inside the
         verify program."""
-        from ..ops.ragged_paged_attention import BLOCK_Q, ragged_layout
+        from ..ops.ragged_paged_attention import (BLOCK_Q, kv_group_blocks,
+                                                  ragged_layout)
 
         pool = self._pool
         S = pool.num_slots
@@ -1289,13 +1290,17 @@ class GenerationEngine:
         T = max(pool.table_bucket(s) for s in row_tokens)
         tables = pool.table_array(T, row_tokens)
         lo = np.zeros(S, np.int32)            # paged virtual floor
-        # every q block of a slot walks that slot's whole context, one
-        # block DMA a step
-        kv_steps = sum(-(-n // BLOCK_Q) * -(-int(kv_len[s]) // bs)
-                       for s, n in enumerate(q_lens) if n)
-        self._sched.note_launch(rows=sum(q_lens), q=Q, t=T,
-                                kv_tokens=int(kv_len.sum()),
-                                kv_steps=kv_steps)
+        # every q block of a slot walks that slot's whole context: one
+        # KV block a step, one wait for a group of G blocks a fetch (G
+        # as the kernel reads it from its pool shard's shape)
+        group = kv_group_blocks(pool.num_heads // self._mp, bs,
+                                pool.head_dim, pool.dtype)
+        walks = [(-(-n // BLOCK_Q), -(-int(kv_len[s]) // bs))
+                 for s, n in enumerate(q_lens) if n]
+        self._sched.note_launch(
+            rows=sum(q_lens), q=Q, t=T, kv_tokens=int(kv_len.sum()),
+            kv_steps=sum(qb * kb for qb, kb in walks),
+            kv_fetches=sum(qb * -(-kb // group) for qb, kb in walks))
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps)

@@ -605,7 +605,7 @@ class Scheduler:
                 self._rec.get("decode_flops", 0.0) + float(flops)
 
     def note_launch(self, rows: int, q: int, t: int, kv_tokens: int,
-                    kv_steps: int) -> None:
+                    kv_steps: int, kv_fetches: int) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
@@ -613,11 +613,15 @@ class Scheduler:
         launch_t)`` program's buckets; ``kv_tokens``, the context
         tokens the kernel must read (sum of the planned slots'
         ``kv_len``); ``kv_steps``, the (q block, KV block) pairs it
-        walks per head and layer, one block DMA each."""
+        walks per layer, one DMA of a whole block (every head) each;
+        ``kv_fetches``, the groups of blocks those DMAs go out in, each
+        waited for and computed on once (q blocks x ceil(KV blocks /
+        G))."""
         if self._rec is not None:
             self._rec.update(launch_rows=int(rows), launch_q=int(q),
                              launch_t=int(t), kv_tokens=int(kv_tokens),
-                             kv_steps=int(kv_steps))
+                             kv_steps=int(kv_steps),
+                             kv_fetches=int(kv_fetches))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into

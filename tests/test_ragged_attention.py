@@ -125,7 +125,100 @@ def _random_ragged_case(rng, *, dtype="float32"):
     return got, ref
 
 
+def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
+               dtype="float32", nan_at=(), seed=0):
+    """A hand-built launch for the grouped KV walk: ``seqs`` is a list of
+    ``(q_len, kv_len)`` (the q rows are the context's tail), each
+    sequence's blocks drawn from a shuffled pool, its table padded with
+    block 0 up to ``t_len``. ``dtype`` "int8" quantizes the pool with
+    per-(block, head) scales that all differ. ``nan_at`` fills pool
+    blocks with NaN after the oracle's copy is taken: ``(s, j)`` is
+    sequence ``s``'s ``j``-th block, ``"scratch"`` block 0. Returns ``(got, ref, out)``: real rows
+    ``[N, H, Dh]`` of the kernel and of the oracle, and the kernel's
+    whole output."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    S = len(seqs)
+    need = [-(-kv // bs) for _, kv in seqs]
+    t_len = t_len or max(need)
+    nb = sum(need) + 2
+    quant = dtype == "int8"
+    if quant:
+        pool = rng.randint(-127, 128, (2, nb + 1, heads, bs, 2 * dh)) \
+            .astype(np.int8)
+        scales = (0.2 + rng.rand(2, 2, nb + 1, heads)).astype(np.float32) / 64
+    else:
+        pool = rng.randn(2, nb + 1, heads, bs, 2 * dh).astype(np.float32)
+        scales = None
+    free = list(range(1, nb + 1))
+    rng.shuffle(free)
+    tables = np.zeros((S, t_len), np.int32)
+    for s, n in enumerate(need):
+        tables[s, :n] = [free.pop() for _ in range(n)]
+    q_lens = [q for q, _ in seqs]
+    pos0s = [kv - q for q, kv in seqs]
+    kv_len = np.asarray([kv for _, kv in seqs], np.int32)
+    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, pos0s)
+    q = rng.randn(heads, len(blk_seq) * 8, dh).astype(np.float32)
+    lo = np.zeros(S, np.int32)
+    rows = [(s, i) for s in range(S) for i in range(q_lens[s])]
+    ref = reference_ragged_attention(
+        np.stack([q[:, qstart[s] + i] for s, i in rows]), pool, 1,
+        [s for s, _ in rows], [pos0s[s] + i for s, i in rows],
+        [list(t) for t in tables], lo, scales=scales)
+    for at in nan_at:
+        pool[:, 0 if at == "scratch" else tables[at]] = np.nan
+    qdt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
+    out = np.asarray(ragged_paged_attention(
+        jnp.asarray(q, qdt), jnp.asarray(pool, dtype), 1, blk_seq, qstart,
+        pos0, tables, lo, kv_len,
+        scales=None if scales is None else jnp.asarray(scales))
+        .astype(jnp.float32))
+    got = np.stack([out[:, qstart[s] + i] for s, i in rows])
+    return got, ref, out
+
+
 class TestKernelParity:
+    # block 32 in float32: a group is G = 4 blocks, 128 columns
+    @pytest.mark.parametrize("seqs,kw", [
+        ([(1, 70)], {}),                         # 3 blocks: under one group
+        ([(1, 128)], {}),                        # exactly one group
+        ([(1, 129)], {}),                        # one block past a group
+        ([(1, 135), (9, 263)], {}),              # kv_len ends mid-block
+        ([(1, 160), (1, 33)], {"t_len": 5}),     # the table fills all of T
+        ([(1, 300), (1, 7), (44, 190)], {}),     # decode rows + a chunk
+        ([(1, 300), (1, 7), (44, 190)], {"dtype": "bfloat16"}),
+        ([(1, 200), (12, 140)], {"dtype": "int8"}),   # per-head scales
+        ([(1, 135), (9, 263)], {"heads": 20, "bs": 16}),  # gpt2-large
+        ([(1, 135), (9, 263)], {"heads": 5, "bs": 16}),   # its mp=4 shard
+    ], ids=["under-one-group", "one-group", "one-past-a-group",
+            "kv-len-mid-block", "table-fills-T", "decode-and-chunk",
+            "decode-and-chunk-bf16", "int8-per-head-scales", "H20", "H5"])
+    def test_grouped_walk_matches_oracle(self, seqs, kw):
+        got, ref, _ = _walk_case(seqs, **kw)
+        tol = {"bfloat16": 0.08, "int8": 2e-4}.get(kw.get("dtype"), 2e-5)
+        np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("seqs,nan_at,clean", [
+        # the scratch block the tables pad with is never fetched
+        ([(1, 70), (9, 200)], ["scratch"], (0, 1)),
+        # sequence 0's middle blocks are NaN and stay behind in both
+        # group buffers: the partial groups of 1 and 2 must not see them
+        ([(1, 256), (1, 40), (3, 130)], [(0, j) for j in (2, 3, 4, 5)],
+         (1, 2)),
+    ], ids=["nan-scratch-block", "nan-left-in-the-buffers"])
+    def test_nan_outside_a_sequence_never_reaches_it(self, seqs, nan_at,
+                                                     clean):
+        got, ref, out = _walk_case(seqs, nan_at=nan_at)
+        rows = np.concatenate([[s] * q for s, (q, _) in enumerate(seqs)])
+        keep = np.isin(rows, clean)
+        assert np.isfinite(got[keep]).all()
+        np.testing.assert_allclose(got[keep], ref[keep], rtol=2e-5,
+                                   atol=2e-5)
+        if "scratch" in nan_at:
+            assert np.isfinite(out).all()        # pad rows too
+
     def test_ragged_mixed_batches_match_oracle(self):
         rng = np.random.RandomState(3)
         for _ in range(4):

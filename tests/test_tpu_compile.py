@@ -1,5 +1,6 @@
 """The Pallas kernels of the main path, compiled for a DESCRIBED v5e at
-GPT-2 124M widths — no chip attached, about two seconds each.
+GPT-2 124M widths (the ragged serving kernel at GPT-2 large's too) — no
+chip attached, about two seconds each.
 
 Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 a DMA slice not aligned to the HBM tiling (``ragged_paged_attention`` at
@@ -20,7 +21,7 @@ import pytest  # noqa: E402
 
 from paddle_tpu.ops import pallas_kernels as pk  # noqa: E402
 from paddle_tpu.ops.ragged_paged_attention import (  # noqa: E402
-    ragged_layout, ragged_paged_attention)
+    KV_VMEM_BUDGET, kv_group_blocks, ragged_layout, ragged_paged_attention)
 
 H, DH = 12, 64          # GPT-2 124M: 12 heads of 64
 
@@ -58,20 +59,21 @@ def _compile(fn, *avals):
     return text
 
 
-def _ragged_case(chip, pool_dtype, block_size, q_lens):
+def _ragged_case(chip, pool_dtype, block_size, q_lens, heads=H,
+                 q_bucket=64, table_len=8):
     """A ragged batch over a 65-block pool: ``q_lens`` rows per sequence
     (1 = a decode row, more = a prefill chunk), 20 tokens of history."""
-    S, T, NB = len(q_lens), 8, 64
+    S, T, NB = len(q_lens), table_len, 64
     blk_seq, qstart, pos0, _, _ = ragged_layout(
-        q_lens, [20] * S, q_bucket=64)
+        q_lens, [20] * S, q_bucket=q_bucket)
     tables = np.zeros((S, T), np.int32)
     lo = np.zeros(S, np.int32)
     kv_len = np.asarray([20 + n for n in q_lens], np.int32)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    avals = [sds((H, 64, DH), jnp.bfloat16),
-             sds((2, NB + 1, H, block_size, 2 * DH), pool_dtype)]
+    avals = [sds((heads, q_bucket, DH), jnp.bfloat16),
+             sds((2, NB + 1, heads, block_size, 2 * DH), pool_dtype)]
     if pool_dtype == "int8":
-        avals.append(sds((2, 2, NB + 1, H), jnp.float32))
+        avals.append(sds((2, 2, NB + 1, heads), jnp.float32))
 
     def fn(q, pool, scales=None):
         return ragged_paged_attention(q, pool, 1, blk_seq, qstart, pos0,
@@ -88,6 +90,27 @@ def _ragged_case(chip, pool_dtype, block_size, q_lens):
 def test_ragged_paged_attention_compiles_at_gpt2_widths(
         v5e, pool_dtype, block_size, q_lens):
     fn, avals = _ragged_case(v5e, pool_dtype, block_size, q_lens)
+    assert "ragged_paged_attention" in _compile(fn, *avals)
+
+
+@pytest.mark.parametrize("pool_dtype,block_size,q_lens,q_bucket", [
+    ("bfloat16", 16, [1] * 64, 512),               # the decode program
+    ("bfloat16", 16, [1] * 63 + [456], 1024),      # decode rows + a chunk
+    ("int8", 32, [1] * 63 + [456], 1024),
+], ids=["bf16-512-rows", "bf16-1024-rows", "int8-bs32-1024-rows"])
+def test_ragged_paged_attention_compiles_at_gpt2_large_widths(
+        v5e, pool_dtype, block_size, q_lens, q_bucket):
+    """H = 20 heads of 64: one DMA brings a whole 80 KB block, a group
+    of G of them a buffer. What Mosaic refuses (a DMA slice off the
+    tiling, too much VMEM) shows here, and the two group buffers the
+    call asks for stay inside the budget the module states."""
+    heads = 20
+    group = kv_group_blocks(heads, block_size, DH, pool_dtype)
+    assert group * block_size == 128           # one full-lane score tile
+    assert 2 * group * heads * block_size * 2 * DH \
+        * jnp.dtype(pool_dtype).itemsize <= KV_VMEM_BUDGET
+    fn, avals = _ragged_case(v5e, pool_dtype, block_size, q_lens,
+                             heads=heads, q_bucket=q_bucket, table_len=64)
     assert "ragged_paged_attention" in _compile(fn, *avals)
 
 

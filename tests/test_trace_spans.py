@@ -183,7 +183,8 @@ def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
     eng = GenerationEngine(tiny_lm, num_slots=2, max_len=32,
                            kv_layout="paged", block_size=8,
                            attention="fused", prefill_budget=8)
-    # (cycle, rows, sum of pos + rows, q blocks of 8 x KV blocks of 8)
+    # (cycle, rows, sum of pos + rows, q blocks of 8 x KV blocks of 8,
+    # q blocks of 8: at most 4 KV blocks here, one group of G = 16)
     planned = []
     build = eng._ragged_operands
 
@@ -193,7 +194,8 @@ def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
         planned.append((eng._sched._cycle, sum(live.values()),
                         sum(ends.values()),
                         sum(-(-n // 8) * -(-ends[s] // 8)
-                            for s, n in live.items())))
+                            for s, n in live.items()),
+                        sum(-(-n // 8) for n in live.values())))
         return build(slot_requests, plan, spec)
 
     eng._ragged_operands = spy
@@ -207,14 +209,15 @@ def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
     records = {c["cycle"]: c for c in
                eng.flight_recorder.snapshot()["cycles"]}
     assert len(planned) >= 4
-    for cycle, rows, kv, steps in planned:
+    for cycle, rows, kv, steps, fetches in planned:
         rec = records[cycle]
         for key in ("plan_ms", "emit_ms", "launch_rows", "launch_q",
-                    "launch_t", "kv_tokens", "kv_steps"):
+                    "launch_t", "kv_tokens", "kv_steps", "kv_fetches"):
             assert key in rec, key
         assert rec["launch_rows"] == rows
         assert rec["kv_tokens"] == kv
         assert rec["kv_steps"] == steps
+        assert rec["kv_fetches"] == fetches
         assert rec["launch_rows"] <= rec["launch_q"]
         assert rec["launch_q"] % 8 == 0 and rec["launch_t"] >= 1
         assert rec["plan_ms"] > 0 and rec["emit_ms"] > 0
@@ -227,7 +230,34 @@ def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
                for c, r, *_ in planned)
     # a 13-token prompt in chunks of 8: the second chunk's one q block
     # walks both of the prompt's KV blocks
-    assert any(steps > 1 and r <= 8 for _, r, _, steps in planned)
+    assert any(steps > 1 and r <= 8 for _, r, _, steps, _ in planned)
+
+
+def test_kv_fetches_counts_the_groups_a_launch_waits_for():
+    """A hand-built launch where contexts pass one group: 150 prompt
+    tokens at block 32 (G = 4 blocks a group) in chunks of 64 rows."""
+    from paddle_tpu.ops.ragged_paged_attention import kv_group_blocks
+    paddle.seed(5)
+    cfg = GPTConfig.tiny()
+    cfg.max_position_embeddings = 192
+    eng = GenerationEngine(GPTForPretraining(cfg), num_slots=2, max_len=192,
+                           kv_layout="paged", block_size=32,
+                           attention="fused", prefill_budget=64)
+    heads = cfg.num_attention_heads
+    assert kv_group_blocks(heads, 32, cfg.hidden_size // heads,
+                           "float32") == 4
+    try:
+        eng.submit(_prompt(np.random.RandomState(2), 150),
+                   max_new_tokens=3).result(timeout=300)
+    finally:
+        eng.close()
+    walked = [(c["launch_rows"], c["kv_steps"], c["kv_fetches"])
+              for c in eng.flight_recorder.snapshot()["cycles"]
+              if c.get("launch_rows")]
+    # (rows, q blocks x KV blocks, q blocks x groups of 4 blocks): the
+    # chunks end at 64, 128 and 150 tokens, then decode rows at 151, 152
+    assert walked == [(64, 8 * 2, 8 * 1), (64, 8 * 4, 8 * 1),
+                      (22, 3 * 5, 3 * 2), (1, 5, 2), (1, 5, 2)]
 
 
 def test_a_train_step_is_in_the_trace_with_its_number(tmp_path):
