@@ -634,6 +634,65 @@ def phase_serve(cfg, *, max_len: int, block_size: int, num_slots: int,
 # four chips: tensor-parallel serving, ZeRO data-parallel training
 # ---------------------------------------------------------------------------
 
+def phase_axk1_serve(model: dict, *, dtype: str, max_len: int,
+                     block_size: int, num_slots: int, num_blocks: int,
+                     prefill_budget: int, prompt_lens, new_tokens: int,
+                     limits: dict, width: int, q_block: int) -> dict:
+    """A.X-K1 (latent attention, routed experts; ``model`` is the
+    ``model`` group of a benchmark configuration, cut to a toy DEPTH)
+    through ``GenerationEngine(kv_layout="paged", attention="fused")``:
+    chunked prefill and decode over the latent paged cache, then every
+    served token's logit against the plain reference's best
+    (``benchmark/lib/reference_axk1.py``), held to the configuration's
+    own limits."""
+    import numpy as np
+
+    from benchmark.lib import correct as C
+    from benchmark.lib import family_axk1 as F
+    from benchmark.lib import reference_axk1 as R
+    from paddle_tpu.serving import GenerationEngine
+
+    seed = 2 ** 31 + 29
+    net = F.build_lm(model, seed, dtype)
+    rng = np.random.RandomState(29)
+    prompts = [rng.randint(1, int(model["vocab_size"]), size=n).tolist()
+               for n in prompt_lens]
+    before = site_names()
+    with GenerationEngine(net, kv_layout="paged", attention="fused",
+                          block_size=block_size, max_len=max_len,
+                          num_slots=num_slots, num_blocks=num_blocks,
+                          prefill_budget=prefill_budget) as engine:
+        handles = [engine.submit(p, new_tokens) for p in prompts]
+        served = [[int(t) for t in h.stream()] for h in handles]
+        stats = engine.stats()
+        cycles = engine.flight_recorder.snapshot()["cycles"]
+        text = step_text_report(sites_since(before, "serving/fused["),
+                                ("mla_paged_attention",))
+    log(f"axk1 steps: {text}")
+    del net, engine
+    gc.collect()
+    check(stats["nonfinite_cycles"] == 0, "no non-finite cycle")
+    check(any("moe_pairs" in c for c in cycles),
+          "the routed layers' counters reached the cycle record")
+    B, n = len(prompts), new_tokens
+    ids = np.zeros((B, width), np.int32)
+    pos = np.zeros((B, n), np.int32)
+    for b, (p, o) in enumerate(zip(prompts, served)):
+        check(len(o) == n, f"request {b} is whole ({len(o)} of {n} tokens)")
+        ids[b, :len(p) + n] = p + o
+        pos[b] = len(p) - 1 + np.arange(n)
+    out = R.served_margins(F.Weights(seed, model, dtype), model, ids, pos,
+                           np.asarray(served, np.int32), rows_per_call=B,
+                           q_block=q_block)
+    numbers = C.gap_summary((out["gap"] / out["std"]).reshape(-1))
+    log(f"axk1 served tokens against the reference: {numbers}, limits "
+        f"{limits}")
+    for name, limit in limits.items():
+        check(numbers[name] <= limit,
+              f"axk1 {name} {numbers[name]:.5f} within {limit}")
+    return numbers
+
+
 def _pool_array(cfg, block_size: int, num_blocks: int, sharded: bool):
     """The engine's block pool, found among jax's live arrays by its
     shape ``[L, NB + 1, H, block_size, 2 * Dh]`` (the engine does not
@@ -893,6 +952,22 @@ def main(argv=None) -> int:
                         num_blocks=blocks_for_hbm_share(cfg, 16, 0.5),
                         prompt_lens=(32, 100, 512), prefix=256, tail=40,
                         burst_lens=(48, 200, 400), new_tokens=32)
+        gc.collect()
+        with PhaseMeter("axk1_serve"):
+            # the benchmark's configuration at its published widths, cut
+            # to a toy depth: the dense layer and ONE expert layer
+            here = os.path.dirname(os.path.abspath(__file__))
+            with open(os.path.join(here, "benchmark", "configs",
+                                   "axk1-ep16.json")) as f:
+                axk1 = json.load(f)
+            phase_axk1_serve(
+                dict(axk1["model"], num_hidden_layers=2),
+                dtype=axk1["serving"]["dtype"], max_len=2048,
+                block_size=int(axk1["serving"]["block_size"]), num_slots=8,
+                num_blocks=2048, prefill_budget=512,
+                prompt_lens=(40, 700, 1300), new_tokens=24,
+                limits=axk1["serving"]["check"]["limits"], width=1536,
+                q_block=512)
     n, secs = compile_totals()
     hits, misses = CACHE_EVENTS.values()
     log(f"all phases passed: {n} compiles taking {secs:.1f} s in this "

@@ -1,0 +1,589 @@
+"""A.X-K1 (``model_type: axk1``, skt/A.X-K1 ``config.json``): a decoder
+of latent attention (MLA) and routed experts — the second caller of the
+decoder spec (``models/decoder_spec.py``) beside GPT-2.
+
+Per layer, pre-norm with RMSNorm and residual adds, no biases:
+
+* **MLA.** ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> per head
+  ``[q_nope | q_pe]``; ``[c_kv | k_pe] = x W_kva``, ``c_kv =
+  RMSNorm(c_kv)``; rotary positions (YaRN) on ``q_pe`` and on the ONE
+  ``k_pe`` all heads share; ``[k_nope | v] = c_kv W_kvb`` per head;
+  softmax of ``(q_nope k_nope + q_pe k_pe) * scale`` over the causal
+  prefix, ``out = concat(p v) W_o``. ``scale = (nope + rope)^-1/2 * m^2``,
+  ``m = 0.1 * mscale_all_dim * ln(factor) + 1``.
+  What a token leaves in the cache is ``[c_kv | k_pe]`` (512 + 64 values
+  at the published sizes), one row for all heads. The serving step runs
+  the ABSORBED form against it: ``q_lat = q_nope W_UK^T``, ``s = q_lat
+  c_kv + q_pe k_pe``, ``o_lat = sum p c_kv``, ``o = o_lat W_UV`` (``W_UK``
+  and ``W_UV`` the two halves of ``W_kvb``), so K and V are the same
+  stored bytes. ``forward`` (no cache) runs the naive form.
+* **Dense layer** (the first ``first_k_dense_replace``): SwiGLU,
+  ``down(silu(gate(x)) * up(x))``.
+* **Expert layer**: ``g = sigmoid(x W_g^T)`` in float32 over ALL
+  ``n_routed_experts``; ``T = top-k(g)``; ``w_e = routed_scaling_factor *
+  g_e / sum_{j in T} g_j``; ``y = shared(x) + sum_{e in T} w_e
+  expert_e(x)``. ``topk_method: "none"`` is read as plain top-k: no group
+  limit, no score-correction bias.
+
+**Serving a share** (``experts_held=(lo, hi)``): the layer holds experts
+``lo .. hi - 1`` of an expert-parallel deployment, routes over all of
+them, and adds only ``sum_{e in T, lo <= e < hi} w_e expert_e(x)`` (``w_e``
+normalised over all ``k`` chosen, held or not) to ``shared(x)``. What the
+absent experts would add is left out; nothing stands in for the other
+chips or their exchange. Dropless: the (row, expert) pairs that fall on
+held experts are sorted by expert and go through ``jax.lax.ragged_dot``
+in chunks of ``MOE_PAIR_CHUNK`` pairs or more, as many chunks as the pairs need
+(``lax.while_loop``), so no pair is ever dropped and the work follows
+the pairs, not a capacity.
+
+Parameters are made by ``param_init(name, shape, dtype)``, one call a
+parameter in the order of ``named_parameters()``: a 10 GB model is built
+on a 16 GB chip by making every array ONCE, in its serving dtype.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass, field
+from typing import Callable, Optional, Tuple
+
+import numpy as np
+
+from .. import nn
+from ..framework.tensor import Parameter, Tensor
+from . import decoder_spec as DS
+
+__all__ = ["AXK1Config", "AXK1ForCausalLM", "yarn_inv_freq",
+           "yarn_attention_scale", "route_top_k", "routed_experts"]
+
+# the ops the expert layer's device time is found under, by name, in a
+# profiler trace (benchmark/layer_metrics/moe_*.py)
+MOE_SCOPE = "moe_experts"
+
+# (row, expert) pairs one trip of the grouped products takes, at least:
+# 128 decode rows choosing 8 of 192 experts put 64 +- 8 pairs on the 12
+# held here, and a 128-row tile is what the TPU's ragged dot then walks
+# once a held expert. A launch of Q rows takes Q / 8 where that is more.
+# More pairs than one trip holds take more trips, never a drop.
+MOE_PAIR_CHUNK = 128
+
+
+def _default_rope_scaling() -> dict:
+    return {"type": "yarn", "factor": 32, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096}
+
+
+@dataclass
+class AXK1Config:
+    vocab_size: int = 163840
+    hidden_size: int = 7168
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_hidden_layers: int = 61
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 192
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    first_k_dense_replace: int = 1
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_scaling: dict = field(default_factory=_default_rope_scaling)
+    max_position_embeddings: int = 131072
+    initializer_range: float = 0.02
+    # the share of an expert-parallel deployment this model holds:
+    # experts lo .. hi - 1 of every expert layer (None = all of them)
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.experts_held is None:
+            self.experts_held = (0, int(self.n_routed_experts))
+        lo, hi = (int(v) for v in self.experts_held)
+        if not 0 <= lo < hi <= self.n_routed_experts:
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range inside "
+                f"[0, {self.n_routed_experts})")
+        self.experts_held = (lo, hi)
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        if self.n_shared_experts != 1:
+            raise ValueError("one shared expert is what this layer builds "
+                             f"(n_shared_experts={self.n_shared_experts})")
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes of a cached row: ``kv_lora_rank + qk_rope_head_dim``
+        rounded up to whole 128-lane tiles (576 -> 640; the pad lanes are
+        zeros and nothing reads them into a score)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @classmethod
+    def tiny(cls, **over):  # tests
+        kw = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            moe_intermediate_size=32, num_hidden_layers=3,
+            num_attention_heads=4, q_lora_rank=48, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+            n_routed_experts=16, num_experts_per_tok=4,
+            max_position_embeddings=128,
+            rope_scaling={"type": "yarn", "factor": 4, "beta_fast": 32,
+                          "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                          "original_max_position_embeddings": 32})
+        kw.update(over)
+        return cls(**kw)
+
+
+# ---------------------------------------------------------------------------
+# rotary positions with YaRN (Peng et al. 2023), as the source's family
+# computes them
+# ---------------------------------------------------------------------------
+
+def yarn_inv_freq(dim: int, theta: float, scaling: dict) -> np.ndarray:
+    """``[dim / 2]`` float32 rotary frequencies: per frequency a linear
+    ramp between the interpolated (``f / factor``) and the unscaled
+    frequency over the correction range of ``beta_fast`` / ``beta_slow``."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    base = float(theta)
+    half = dim // 2
+    freq = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(n_rot):
+        return dim * math.log(orig / (n_rot * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(float(scaling["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(scaling["beta_slow"]))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    keep = 1.0 - ramp                     # 1: unscaled, 0: interpolated
+    return (freq / factor * (1.0 - keep) + freq * keep).astype(np.float32)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_attention_scale(cfg: AXK1Config) -> float:
+    """``(nope + rope)^-1/2 * m^2`` with ``m`` from ``mscale_all_dim``."""
+    s = cfg.rope_scaling
+    m = _yarn_mscale(float(s["factor"]), float(s["mscale_all_dim"])) \
+        if s.get("mscale_all_dim") else 1.0
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def yarn_cos_sin_scale(scaling: dict) -> float:
+    f = float(scaling["factor"])
+    return _yarn_mscale(f, float(scaling["mscale"])) \
+        / _yarn_mscale(f, float(scaling["mscale_all_dim"]))
+
+
+def _rope(x, cos, sin):
+    """Rotate the pairs ``(2i, 2i + 1)`` of the last axis by the row's
+    angle: ``x [..., rows, (heads,) dim]`` against ``cos``/``sin`` ``[rows,
+    dim / 2]`` broadcast over heads. float32 inside."""
+    import jax.numpy as jnp
+    xf = x.astype(jnp.float32)
+    xr, xi = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.stack([xr * cos - xi * sin, xr * sin + xi * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rms_norm(x, w, eps):
+    import jax
+    import jax.numpy as jnp
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _mm(x, w):
+    """``x @ w`` in the weights' dtype, float32 accumulation on the MXU."""
+    import jax.numpy as jnp
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _swiglu(x, gate, up, down):
+    """``down(silu(gate(x)) * up(x))``: operands in the weights' dtype,
+    float32 accumulation, the product of the two branches taken in
+    float32 and rounded once. Returns float32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = lambda a, b: jnp.dot(a, b, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(f32(x, gate)) * f32(x, up)).astype(x.dtype)
+    return f32(h, down)
+
+
+# ---------------------------------------------------------------------------
+# the routed expert layer
+# ---------------------------------------------------------------------------
+
+def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True):
+    """Scores, choice and weights of the router: ``x [Q, E]``, ``router_w
+    [n_experts, E]`` -> ``(idx [Q, k] int32, w [Q, k] float32, scores [Q,
+    n_experts] float32)``. The scores are float32: activations and router
+    weights are exact in bfloat16, every product of two of them is exact
+    in float32, and the MXU accumulates in float32, so this IS the
+    float32 score up to the order of the sum."""
+    import jax
+    import jax.numpy as jnp
+    logits = jnp.dot(x, router_w.T, preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    top, idx = jax.lax.top_k(scores, int(top_k))
+    w = top / jnp.sum(top, axis=-1, keepdims=True) if norm else top
+    return idx.astype(jnp.int32), w * jnp.float32(scale), scores
+
+
+def routed_experts(x, valid, idx, w, experts, held, pair_chunk: int):
+    """The held experts' part of the routed sum, dropless.
+
+    ``x [Q, E]``; ``valid [Q]`` bool (pad rows of a ragged batch are not
+    routed); ``idx``/``w [Q, k]`` from :func:`route_top_k`; ``experts =
+    (gate [n, E, I], up [n, E, I], down [n, I, E])`` the ``n = hi - lo``
+    held experts; ``held = (lo, hi)``. Returns ``(y [Q, E] float32,
+    (pairs, experts_hit, rows))`` with ``y = sum over the row's chosen
+    experts that are held of w_e expert_e(x)`` and the three int32
+    counters of REAL rows only.
+
+    Pairs on held experts are sorted by expert (stable, so by row inside
+    an expert); chunk ``c`` of ``M`` sorted pairs gathers its rows, runs
+    the three grouped products with the group sizes clipped to the chunk,
+    and adds its weighted outputs back through a 0/1 row-selection
+    product on the MXU (a scatter-add of ``M`` rows of ``E`` lanes is the
+    slow way to the same sum). ``ceil(pairs / M)`` chunks run."""
+    import jax
+    import jax.numpy as jnp
+    gate, up, down = experts
+    lo, hi = held
+    n = hi - lo
+    Q, k = idx.shape
+    M = int(pair_chunk)
+    on = (idx >= lo) & (idx < hi) & valid[:, None]            # [Q, k]
+    flat_e = jnp.where(on, idx - lo, n).reshape(-1)           # n = "not here"
+    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+    counts = jnp.sum(flat_e[:, None] == jnp.arange(n, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32)                 # [n]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    pairs = ends[-1]
+    flat_w = jnp.where(on, w, 0.0).reshape(-1)
+    # pad so that the last chunk's slice never runs off the end
+    order = jnp.concatenate([order, jnp.zeros(M, jnp.int32)])
+    rows_iota = jnp.arange(Q, dtype=jnp.int32)[:, None]
+
+    def chunk(c, y):
+        a = c * M
+        sel = jax.lax.dynamic_slice(order, (a,), (M,))
+        live = (a + jnp.arange(M, dtype=jnp.int32)) < pairs
+        rows = sel // k
+        sizes = jnp.clip(ends, a, a + M) - jnp.clip(starts, a, a + M)
+        xs = x[rows]                                          # [M, E]
+        rd = lambda l, r: jax.lax.ragged_dot(
+            l, r, sizes, preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(rd(xs, gate)) * rd(xs, up)).astype(x.dtype)
+        o = rd(h, down)                                       # [M, E] f32
+        # a pair past the last one belongs to no group: whatever the
+        # grouped product left in its row is zeroed, not weighted
+        o = jnp.where(live[:, None], o * flat_w[sel][:, None], 0.0)
+        pick = (rows_iota == rows[None, :]) & live[None, :]   # [Q, M] 0/1
+        return y + jnp.dot(pick.astype(x.dtype), o.astype(x.dtype),
+                           preferred_element_type=jnp.float32)
+
+    y = jax.lax.fori_loop(jnp.int32(0), (pairs + M - 1) // M, chunk,
+                          jnp.zeros(x.shape, jnp.float32))
+    counters = (pairs, jnp.sum(counts > 0, dtype=jnp.int32),
+                jnp.sum(valid, dtype=jnp.int32))
+    return y, counters
+
+
+# ---------------------------------------------------------------------------
+# layers
+# ---------------------------------------------------------------------------
+
+def _params(make, prefix):
+    """``p(name, shape)`` -> the Parameter the model's ``param_init`` makes
+    for ``prefix + name`` (a layer calls it while it is built and keeps
+    nothing of it)."""
+    return lambda name, shape: Parameter(make(prefix + name, tuple(shape)))
+
+
+class AXK1Attention(nn.Layer):
+    def __init__(self, cfg: AXK1Config, make, prefix):
+        super().__init__()
+        p = _params(make, prefix)
+        self.cfg = cfg
+        E, H = cfg.hidden_size, cfg.num_attention_heads
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        self.wq_a = p("wq_a", (E, cfg.q_lora_rank))
+        self.q_norm = p("q_norm", (cfg.q_lora_rank,))
+        self.wq_b = p("wq_b", (cfg.q_lora_rank, H * qk))
+        self.wkv_a = p(
+            "wkv_a", (E, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
+        self.kv_norm = p("kv_norm", (cfg.kv_lora_rank,))
+        self.wkv_b = p(
+            "wkv_b", (cfg.kv_lora_rank,
+                      H * (cfg.qk_nope_head_dim + cfg.v_head_dim)))
+        self.wo = p("wo", (H * cfg.v_head_dim, E))
+        self._inv_freq = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta,
+                                       cfg.rope_scaling)
+        self._cs_scale = yarn_cos_sin_scale(cfg.rope_scaling)
+        self.scale = yarn_attention_scale(cfg)
+
+    def _cos_sin(self, positions):
+        import jax.numpy as jnp
+        ang = positions.astype(jnp.float32)[:, None] \
+            * jnp.asarray(self._inv_freq)[None, :]
+        return jnp.cos(ang) * self._cs_scale, jnp.sin(ang) * self._cs_scale
+
+    def _project(self, h, positions):
+        """``h [Q, E]`` (normed) -> ``q_nope [Q, H, nope]``, ``q_pe [Q, H,
+        rope]`` (rotated), ``c_kv [Q, rank]`` (normed), ``k_pe [Q, rope]``
+        (rotated)."""
+        cfg = self.cfg
+        H = cfg.num_attention_heads
+        cos, sin = self._cos_sin(positions)
+        c_q = _rms_norm(_mm(h, self.wq_a._data), self.q_norm._data,
+                        cfg.rms_norm_eps)
+        q = _mm(c_q, self.wq_b._data).reshape(h.shape[0], H, -1)
+        q_nope = q[..., :cfg.qk_nope_head_dim]
+        q_pe = _rope(q[..., cfg.qk_nope_head_dim:], cos[:, None], sin[:, None])
+        kv = _mm(h, self.wkv_a._data)
+        c_kv = _rms_norm(kv[:, :cfg.kv_lora_rank], self.kv_norm._data,
+                         cfg.rms_norm_eps)
+        k_pe = _rope(kv[:, cfg.kv_lora_rank:], cos, sin)
+        return q_nope, q_pe, c_kv, k_pe
+
+    def _w_uk_uv(self):
+        cfg = self.cfg
+        w = self.wkv_b._data.reshape(
+            cfg.kv_lora_rank, cfg.num_attention_heads,
+            cfg.qk_nope_head_dim + cfg.v_head_dim)
+        return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+    def absorbed_in(self, h, positions):
+        """What the serving step hands the kernel and the cache: ``q [Q,
+        H, lanes]`` = ``[q_lat | q_pe | 0]`` and the row ``[Q, lanes]`` =
+        ``[c_kv | k_pe | 0]``."""
+        import jax.numpy as jnp
+        cfg = self.cfg
+        q_nope, q_pe, c_kv, k_pe = self._project(h, positions)
+        w_uk, _ = self._w_uk_uv()                             # [rank,H,nope]
+        q_lat = jnp.einsum("qhd,chd->qhc", q_nope, w_uk,
+                           preferred_element_type=jnp.float32
+                           ).astype(h.dtype)
+        pad = cfg.latent_lanes - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+        Q, H = q_pe.shape[:2]
+        q = jnp.concatenate(
+            [q_lat, q_pe, jnp.zeros((Q, H, pad), h.dtype)], axis=-1)
+        row = jnp.concatenate(
+            [c_kv, k_pe, jnp.zeros((Q, pad), h.dtype)], axis=-1)
+        return q, row
+
+    def absorbed_out(self, o_lat):
+        """``o_lat [Q, H, rank]`` -> ``[Q, E]``: ``W_UV`` per head, then
+        ``W_o``."""
+        import jax.numpy as jnp
+        _, w_uv = self._w_uk_uv()                             # [rank,H,v]
+        o = jnp.einsum("qhc,chd->qhd", o_lat, w_uv,
+                       preferred_element_type=jnp.float32).astype(o_lat.dtype)
+        return _mm(o.reshape(o.shape[0], -1), self.wo._data)
+
+    def naive(self, h, positions):
+        """Causal attention of one whole sequence ``h [S, E]`` in the
+        decompressed form (no cache): every head's K and V are made from
+        ``c_kv``."""
+        import jax
+        import jax.numpy as jnp
+        cfg = self.cfg
+        q_nope, q_pe, c_kv, k_pe = self._project(h, positions)
+        w_uk, w_uv = self._w_uk_uv()
+        k_nope = jnp.einsum("sc,chd->shd", c_kv, w_uk,
+                            preferred_element_type=jnp.float32)
+        v = jnp.einsum("sc,chd->shd", c_kv, w_uv,
+                       preferred_element_type=jnp.float32)
+        s = (jnp.einsum("qhd,khd->hqk", q_nope.astype(jnp.float32), k_nope)
+             + jnp.einsum("qhd,kd->hqk", q_pe.astype(jnp.float32),
+                          k_pe.astype(jnp.float32))) * self.scale
+        S = h.shape[0]
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("hqk,khd->qhd", p, v).astype(h.dtype)
+        return _mm(o.reshape(S, -1), self.wo._data)
+
+
+class AXK1DenseFFN(nn.Layer):
+    kind = DS.DENSE
+
+    def __init__(self, cfg, make, prefix):
+        super().__init__()
+        p = _params(make, prefix)
+        E, I = cfg.hidden_size, cfg.intermediate_size
+        self.gate = p("gate", (E, I))
+        self.up = p("up", (E, I))
+        self.down = p("down", (I, E))
+
+    def apply(self, x, valid):
+        return _swiglu(x, self.gate._data, self.up._data,
+                       self.down._data).astype(x.dtype), None
+
+
+class AXK1RoutedFFN(nn.Layer):
+    kind = DS.ROUTED
+
+    def __init__(self, cfg, make, prefix):
+        super().__init__()
+        p = _params(make, prefix)
+        self.cfg = cfg
+        E, I = cfg.hidden_size, cfg.moe_intermediate_size
+        n = cfg.experts_held[1] - cfg.experts_held[0]
+        self.router = p("router", (cfg.n_routed_experts, E))
+        self.shared_gate = p("shared_gate", (E, I))
+        self.shared_up = p("shared_up", (E, I))
+        self.shared_down = p("shared_down", (I, E))
+        self.experts_gate = p("experts_gate", (n, E, I))
+        self.experts_up = p("experts_up", (n, E, I))
+        self.experts_down = p("experts_down", (n, I, E))
+
+    def apply(self, x, valid):
+        """``x [Q, E]`` -> ``(shared(x) + the held experts' part, counters)``."""
+        import jax
+        import jax.numpy as jnp
+        cfg = self.cfg
+        with jax.named_scope(MOE_SCOPE):
+            idx, w, _ = route_top_k(
+                x, self.router._data, cfg.num_experts_per_tok,
+                cfg.routed_scaling_factor, cfg.norm_topk_prob)
+            y, counters = routed_experts(
+                x, valid, idx, w,
+                (self.experts_gate._data, self.experts_up._data,
+                 self.experts_down._data), cfg.experts_held,
+                max(MOE_PAIR_CHUNK, x.shape[0] // 8))
+            shared = _swiglu(x, self.shared_gate._data, self.shared_up._data,
+                             self.shared_down._data)
+            out = (shared + y).astype(x.dtype)
+        return out, counters
+
+
+class AXK1Layer(nn.Layer):
+    def __init__(self, cfg: AXK1Config, index: int, make):
+        super().__init__()
+        prefix = f"layers.{index}."
+        p = _params(make, prefix)
+        self.cfg = cfg
+        self.attn_norm = p("attn_norm", (cfg.hidden_size,))
+        self.attn = AXK1Attention(cfg, make, prefix + "attn.")
+        self.ffn_norm = p("ffn_norm", (cfg.hidden_size,))
+        ffn = AXK1DenseFFN if index < cfg.first_k_dense_replace \
+            else AXK1RoutedFFN
+        self.ffn = ffn(cfg, make, prefix + "ffn.")
+
+    def _ffn(self, x, valid):
+        y, counters = self.ffn.apply(
+            _rms_norm(x, self.ffn_norm._data, self.cfg.rms_norm_eps), valid)
+        return x + y, counters
+
+    # -- the decoder spec's layer surface (x is a Tensor [1, Q, E]) --------
+    def attn_in(self, x, positions):
+        h = _rms_norm(x._data[0], self.attn_norm._data, self.cfg.rms_norm_eps)
+        return self.attn.absorbed_in(h, positions)
+
+    def attn_out(self, x, o_lat, row_valid):
+        y, counters = self._ffn(x._data[0] + self.attn.absorbed_out(o_lat),
+                                row_valid)
+        return Tensor(y[None], stop_gradient=True), counters
+
+    # -- no cache: one whole sequence [S, E] -------------------------------
+    def full(self, x, positions):
+        import jax.numpy as jnp
+        h = _rms_norm(x, self.attn_norm._data, self.cfg.rms_norm_eps)
+        x = x + self.attn.naive(h, positions)
+        return self._ffn(x, jnp.ones(x.shape[0], bool))[0]
+
+
+class AXK1ForCausalLM(nn.Layer):
+    """A.X-K1 with its untied head. ``forward(input_ids [B, S])`` ->
+    float32 logits ``[B, S, V]`` (no cache, naive attention);
+    ``serving_decoder()`` is what ``GenerationEngine(kv_layout="paged",
+    attention="fused")`` consumes."""
+
+    def __init__(self, cfg: AXK1Config, dtype="float32",
+                 param_init: Optional[Callable] = None):
+        super().__init__()
+        import jax
+        import jax.numpy as jnp
+        self.cfg = cfg
+        dt = jnp.dtype(dtype)
+        if param_init is None:
+            keys = iter(jax.random.split(jax.random.PRNGKey(0), 4096))
+
+            def param_init(name, shape, dtype):
+                if name.endswith("norm"):
+                    return jnp.ones(shape, dtype)
+                return (cfg.initializer_range * jax.random.normal(
+                    next(keys), shape, jnp.float32)).astype(dtype)
+
+        def make(name, shape):
+            arr = param_init(name, shape, dt)
+            if tuple(arr.shape) != tuple(shape) or arr.dtype != dt:
+                raise ValueError(
+                    f"param_init({name!r}) gave {arr.dtype}{tuple(arr.shape)}"
+                    f", the model needs {dt}{tuple(shape)}")
+            return arr
+
+        self.embed = Parameter(make("embed", (cfg.vocab_size, cfg.hidden_size)))
+        self.layers = nn.LayerList(
+            [AXK1Layer(cfg, i, make) for i in range(cfg.num_hidden_layers)])
+        self.norm = Parameter(make("norm", (cfg.hidden_size,)))
+        self.lm_head = Parameter(make("lm_head",
+                                      (cfg.hidden_size, cfg.vocab_size)))
+        cache = DS.CacheSpec(rows=1, lanes=cfg.latent_lanes,
+                             v_aliases_k=True, v_lanes=cfg.kv_lora_rank)
+        self.spec = DS.DecoderSpec(
+            layers=tuple(DS.LayerSpec(DS.LATENT, cache, layer.ffn.kind)
+                         for layer in self.layers),
+            vocab_size=cfg.vocab_size,
+            max_positions=cfg.max_position_embeddings)
+
+    def serving_decoder(self):
+        return self
+
+    @property
+    def attention_scale(self) -> float:
+        return self.layers[0].attn.scale
+
+    # -- the decoder spec's model surface ----------------------------------
+    def embed_tokens(self, token_ids, positions):
+        return Tensor(self.embed._data[token_ids][None], stop_gradient=True)
+
+    def final_norm(self, x):
+        return Tensor(_rms_norm(x._data, self.norm._data,
+                                self.cfg.rms_norm_eps), stop_gradient=True)
+
+    def logits(self, hidden):
+        import jax.numpy as jnp
+        return Tensor(jnp.dot(hidden._data, self.lm_head._data,
+                              preferred_element_type=jnp.float32),
+                      stop_gradient=True)
+
+    def forward(self, input_ids):
+        import jax.numpy as jnp
+        ids = input_ids._data if isinstance(input_ids, Tensor) \
+            else jnp.asarray(input_ids)
+        pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
+        out = []
+        for row in ids:
+            x = self.embed._data[row]
+            for layer in self.layers:
+                x = layer.full(x, pos)
+            out.append(self.logits(self.final_norm(
+                Tensor(x, stop_gradient=True)))._data)
+        return Tensor(jnp.stack(out), stop_gradient=True)

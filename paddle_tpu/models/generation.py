@@ -1082,38 +1082,64 @@ def build_paged_decode_fn(model, num_slots, table_len, block_size,
     return fn
 
 
-def _fused_tower(gpt, x, pool, scales, write_block, write_off, blk_seq,
-                 seq_qstart, seq_pos0, tables, lo, kv_len, quantized,
-                 qmax):
+def _write_latent_rows(pool, li, wb, off, rows):
+    """Write ONE latent row a token into layer ``li`` of a latent pool
+    ``[L, NB + 1, 1, bs, lanes]``: ``rows [N, lanes]`` land at ``(block
+    wb[n], offset off[n])``. Every axis but the lanes is indexed, as in
+    :func:`_write_rows` and for its reason."""
+    return pool.at[li, wb, 0, off, :].set(rows.astype(pool.dtype))
+
+
+def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
+                 blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
+                 quantized, qmax):
     """The fused ragged transformer tower shared by
-    :func:`build_fused_step_fn` and :func:`build_spec_verify_fn`: per
-    layer, scatter every flattened row's K/V through the page table
-    (quantized pools go through :func:`_quant_append`), run the fused
-    ragged-paged-attention Pallas kernel over the block pool, and apply
-    the block tail. Returns ``(ln_f(x), pool, scales)``."""
+    :func:`build_fused_step_fn` and :func:`build_spec_verify_fn`, over
+    the layers of a decoder spec (``models/decoder_spec.py``; ``dec`` is a
+    model's ``serving_decoder()``): per layer, write every flattened
+    row's cache entry through the page table (quantized pools go through
+    :func:`_quant_append`), run the attention kernel of the layer's kind
+    over the block pool — ``full``: the ragged paged attention kernel on
+    per-head K|V rows; ``latent``: the MLA kernel on the one latent row
+    all heads share — and apply the layer's output projection and FFN.
+    A ``routed`` FFN is told which rows are real (pad rows write the
+    scratch block 0) and returns its three counters, summed over the
+    layers here. Returns ``(final_norm(x), pool, scales, counters)``,
+    ``counters`` ``None`` for a model without routed layers."""
     import jax.numpy as jnp
 
+    from ..ops.mla_paged_attention import mla_paged_attention
     from ..ops.ragged_paged_attention import ragged_paged_attention
+    from .decoder_spec import FULL
 
-    for li, block in enumerate(gpt.blocks):
-        q, k, v = block._qkv(x)
-        # per-row scatter through the page table: row i's K/V land at
+    row_valid = write_block > 0
+    counters = None
+    for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
+        q, rows = layer.attn_in(x, positions)
+        # per-row scatter through the page table: row i's entry lands at
         # (write_block[i], write_off[i]) — pad rows hit the scratch
         # block nobody reads
-        if quantized:
-            pool, scales = _quant_append(
-                pool, scales, li, write_block, write_off,
-                k._data[0], v._data[0], qmax)
+        if ls.attention == FULL:
+            if quantized:
+                pool, scales = _quant_append(
+                    pool, scales, li, write_block, write_off, *rows, qmax)
+            else:
+                pool = _write_rows(pool, li, write_block, write_off, *rows)
+            a = ragged_paged_attention(
+                q, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
+                kv_len, scales=scales)
         else:
-            pool = _write_rows(pool, li, write_block, write_off,
-                               k._data[0], v._data[0])
-        qh = jnp.transpose(q._data, (0, 2, 1, 3))[0]
-        a = ragged_paged_attention(
-            qh, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
-            kv_len, scales=scales)
-        a = jnp.transpose(a[None], (0, 2, 1, 3))
-        x = block._tail(x, Tensor(a, stop_gradient=True))
-    return gpt.ln_f(x), pool, scales
+            pool = _write_latent_rows(pool, li, write_block, write_off, rows)
+            a = mla_paged_attention(
+                q, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
+                kv_len, v_lanes=ls.cache.v_lanes, scale=dec.attention_scale)
+        x, c = layer.attn_out(x, a, row_valid)
+        if c is not None:
+            counters = c if counters is None else tuple(
+                u + v for u, v in zip(counters, c))
+    if counters is not None:
+        counters = jnp.stack(counters).astype(jnp.int32)
+    return dec.final_norm(x), pool, scales, counters
 
 
 def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
@@ -1171,8 +1197,9 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     from ..framework import trace_probe as _probe
     from ..nn.layer.layers import functional_state
     from ..ops.ragged_paged_attention import BLOCK_Q
+    from .decoder_spec import serving_decoder
 
-    gpt = model.gpt if hasattr(model, "gpt") else model
+    dec = serving_decoder(model)
     S, Q, T, bs = (int(num_slots), int(q_rows), int(table_len),
                    int(block_size))
     if S < 1:
@@ -1182,7 +1209,7 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
             f"q_rows must be a positive multiple of {BLOCK_Q}, got {Q}")
     if T < 1:
         raise ValueError(f"table_len must be >= 1, got {T}")
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
+    top_k = min(int(top_k), dec.spec.vocab_size)
 
     def fn(params, buffers, pool, *rest):
         (scales, token_ids, qpos, write_block, write_off, blk_seq,
@@ -1197,15 +1224,13 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                 # logical positions == virtual positions (paged
                 # sequences are aligned at virtual 0; lo is the mask
                 # floor, not a pad offset)
-                x = gpt.wte(Tensor(token_ids[None, :],
-                                   stop_gradient=True)) \
-                    + gpt.wpe(Tensor(qpos[None, :]))
-                x, new_pool, new_scales = _fused_tower(
-                    gpt, x, pool, scales, write_block, write_off,
+                x = dec.embed_tokens(token_ids, qpos)
+                x, new_pool, new_scales, counters = _fused_tower(
+                    dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     quantized, qmax)
                 last = x._data[0, last_row]             # [S, E]
-                logits = gpt.logits(
+                logits = dec.logits(
                     Tensor(last[:, None, :]))._data[:, 0].astype(
                         jnp.float32)
                 key, sub = jax.random.split(key)
@@ -1214,6 +1239,10 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                                       temperature[:, None])
                 nxt = jnp.where(sample_mask, sampled, greedy)
                 nxt = _append_nonfinite_flag(nxt, logits)
+                if counters is not None:
+                    # the routed layers' counters ride the same fetch,
+                    # after the sentinel: [S + 1 : S + 4]
+                    nxt = jnp.concatenate([nxt, counters])
         if quantized:
             return new_pool, new_scales, nxt, key
         return new_pool, nxt, key
@@ -1328,7 +1357,7 @@ def _mp_pool_spec(mp_axis):
     return P(None, None, mp_axis, None, None)
 
 
-def _mp_mesh_check(gpt, mesh, mp_axis):
+def _mp_mesh_check(model, mesh, mp_axis):
     """Validate the serving mesh and return its mp degree. The serving
     shard_maps are manual over EVERY mesh axis, so a 1-D mesh is
     required (dp replication belongs to EngineFleet, one engine per
@@ -1341,7 +1370,8 @@ def _mp_mesh_check(gpt, mesh, mp_axis):
             f"serving mesh must be 1-D over {mp_axis!r}, got axes "
             f"{mesh.axis_names} (replicate with EngineFleet instead)")
     mp = int(mesh.shape[mp_axis])
-    H = gpt.cfg.num_attention_heads
+    H = (model.gpt if hasattr(model, "gpt") else model
+         ).cfg.num_attention_heads
     if H % mp:
         raise ValueError(
             f"num_attention_heads {H} not divisible by mesh "
@@ -1665,7 +1695,8 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
     from ..nn.layer.layers import functional_state
     from ..ops.ragged_paged_attention import BLOCK_Q
 
-    gpt = model.gpt if hasattr(model, "gpt") else model
+    from .decoder_spec import serving_decoder
+    dec = serving_decoder(model)
     S, Q, K, T = (int(num_slots), int(q_rows), int(spec_k),
                   int(table_len))
     if S < 1:
@@ -1677,7 +1708,7 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
             f"q_rows must be a positive multiple of {BLOCK_Q}, got {Q}")
     if T < 1:
         raise ValueError(f"table_len must be >= 1, got {T}")
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
+    top_k = min(int(top_k), dec.spec.vocab_size)
 
     def fn(params, buffers, pool, *rest):
         (scales, token_ids, qpos, write_block, write_off, blk_seq,
@@ -1700,11 +1731,9 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
                 safe = jnp.where(ok, rows, Q)         # Q = out of range
                 token_ids = token_ids.at[safe.reshape(-1)].set(
                     draft_toks.reshape(-1), mode="drop")
-                x = gpt.wte(Tensor(token_ids[None, :],
-                                   stop_gradient=True)) \
-                    + gpt.wpe(Tensor(qpos[None, :]))
-                x, new_pool, new_scales = _fused_tower(
-                    gpt, x, pool, scales, write_block, write_off,
+                x = dec.embed_tokens(token_ids, qpos)
+                x, new_pool, new_scales, _ = _fused_tower(
+                    dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     quantized, qmax)
                 # gather the rows whose logits are actually read —
@@ -1718,7 +1747,7 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
                     0, Q - 1)                          # [S, K]
                 sel = x._data[0][jnp.concatenate(
                     [vrows.reshape(-1), last_row])]    # [S*K+S, E]
-                logits = gpt.logits(
+                logits = dec.logits(
                     Tensor(sel[:, None, :]))._data[:, 0].astype(
                         jnp.float32)                   # [S*K+S, V]
                 p = _sample_probs(

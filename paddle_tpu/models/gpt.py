@@ -175,6 +175,11 @@ class GPTModel(nn.Layer):
         """LM head tied to wte (matmul against the embedding table)."""
         return call_op("matmul", hidden, self.wte.weight, transpose_y=True)
 
+    def serving_decoder(self):
+        """What the fused paged serving path consumes
+        (``models/decoder_spec.py``)."""
+        return GPTServingDecoder(self)
+
     # -- static-cache decode path (serving) ---------------------------------
     def init_cache(self, batch, max_len, dtype):
         """Preallocate per-layer K/V buffers: tuple of (k, v) jnp arrays,
@@ -228,6 +233,53 @@ class GPTModel(nn.Layer):
                                           key_valid=key_valid)
             new_caches.append((ck, cv))
         return self.ln_f(x), tuple(new_caches)
+
+
+class _GPTServingLayer:
+    """One ``GPTBlock`` behind the decoder spec's layer surface."""
+
+    def __init__(self, block):
+        self.block = block
+
+    def attn_in(self, x, positions):
+        import jax.numpy as jnp
+        q, k, v = self.block._qkv(x)
+        return jnp.transpose(q._data, (0, 2, 1, 3))[0], \
+            (k._data[0], v._data[0])
+
+    def attn_out(self, x, a, row_valid):
+        import jax.numpy as jnp
+        a = jnp.transpose(a[None], (0, 2, 1, 3))
+        return self.block._tail(x, Tensor(a, stop_gradient=True)), None
+
+
+class GPTServingDecoder:
+    """GPT-2 as the fused serving stack sees it (``models/decoder_spec.py``):
+    full attention over one K|V row a head, dense FFNs, learned positions
+    added at the embedding, a head tied to it."""
+
+    def __init__(self, gpt: "GPTModel"):
+        from . import decoder_spec as DS
+        cfg = gpt.cfg
+        self.gpt = gpt
+        dh = cfg.hidden_size // cfg.num_attention_heads
+        cache = DS.CacheSpec(rows=cfg.num_attention_heads, lanes=2 * dh)
+        self.spec = DS.DecoderSpec(
+            layers=tuple(DS.LayerSpec(DS.FULL, cache, DS.DENSE)
+                         for _ in gpt.blocks),
+            vocab_size=cfg.vocab_size,
+            max_positions=cfg.max_position_embeddings)
+        self.layers = [_GPTServingLayer(b) for b in gpt.blocks]
+
+    def embed_tokens(self, token_ids, positions):
+        return self.gpt.wte(Tensor(token_ids[None, :], stop_gradient=True)) \
+            + self.gpt.wpe(Tensor(positions[None, :]))
+
+    def final_norm(self, x):
+        return self.gpt.ln_f(x)
+
+    def logits(self, hidden):
+        return self.gpt.logits(hidden)
 
 
 def _chunked_lm_loss(hidden, labels, table, n_chunks):
@@ -322,6 +374,9 @@ class GPTForPretraining(nn.Layer):
             call_op("reshape", labels, shape=(-1,)),
             reduction="mean")
         return loss, logits
+
+    def serving_decoder(self):
+        return self.gpt.serving_decoder()
 
     def generate(self, input_ids, **kwargs):
         """Compiled static-cache autoregressive decode; see

@@ -126,37 +126,44 @@ def min_kv_block_for(dtype) -> int:
                                       MIN_KV_BLOCK)
 
 
-def check_kv_tile(dtype, block_size: int, head_dim: int) -> None:
-    """Raise ``ValueError`` unless one (block, head) tile of the pool —
-    ``(block_size, 2 * head_dim)`` of ``dtype`` — is something the
-    kernel can DMA: at least the dtype's sublane count tall and, on a
-    TPU, whole 128-lane tiles wide. The ONE statement of the rule, for
-    the kernel and for the engine's constructor."""
+def check_kv_tile(dtype, block_size: int, head_dim: int = 0, *,
+                  lanes: int = 0) -> None:
+    """Raise ``ValueError`` unless one (block, row) tile of the pool —
+    ``(block_size, lanes)`` of ``dtype``, ``lanes = 2 * head_dim`` for a
+    per-head K|V row unless given (a latent row states its own) — is
+    something a kernel can DMA: at least the dtype's sublane count tall
+    and, on a TPU, whole 128-lane tiles wide. The ONE statement of the
+    rule, for both kernels and for the engine's constructor."""
     name = jnp.dtype(dtype).name
     need = min_kv_block_for(dtype)
+    lanes = int(lanes) or 2 * int(head_dim)
     if int(block_size) < need:
         raise ValueError(
             f"block_size {block_size} < {need}: the {name} KV block tile "
             f"has no legal (sublane, 128) TPU tiling below the dtype's "
             f"sublane count")
-    if not _interpret() and (2 * int(head_dim)) % 128:
+    if not _interpret() and lanes % 128:
         raise ValueError(
             f"head_dim {head_dim}: the K|V block tile is "
-            f"{2 * int(head_dim)} lanes wide, and a TPU DMA slice must "
+            f"{lanes} lanes wide, and a TPU DMA slice must "
             f"cover whole 128-lane tiles")
 
 
 def kv_group_blocks(heads: int, block_size: int, head_dim: int,
-                    dtype) -> int:
+                    dtype, *, lanes: int = 0, columns: int = 128) -> int:
     """``G``: how many KV blocks the kernel fetches, and computes on, at
     a time — read from the pool's shape and dtype and from nothing else.
     As many as fill one 128-lane score tile (``G * block_size = 128``: 8
     at ``block_size`` 16, 4 at the int8 minimum 32), halved only while
     the two group buffers of ``G`` whole blocks (every head, K and V)
     would pass ``KV_VMEM_BUDGET``. The engine's ``kv_fetches`` counter
-    asks here too, so it counts the groups the kernel waits for."""
-    g = max(1, 128 // int(block_size))
-    block_bytes = (int(heads) * int(block_size) * 2 * int(head_dim)
+    asks here too, so it counts the groups the kernel waits for.
+    ``lanes`` (a row's width where it is not ``2 * head_dim``) and
+    ``columns`` (the score tile's width, 128 unless the caller's kernel
+    takes wider ones) are the latent kernel's."""
+    g = max(1, int(columns) // int(block_size))
+    block_bytes = (int(heads) * int(block_size)
+                   * (int(lanes) or 2 * int(head_dim))
                    * jnp.dtype(dtype).itemsize)
     while g > 1 and 2 * g * block_bytes > KV_VMEM_BUDGET:
         g //= 2

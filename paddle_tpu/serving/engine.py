@@ -236,8 +236,47 @@ def _register_engine_telemetry(engine: "GenerationEngine") -> None:
     _metrics.register_collector(f"serving_engine/{engine._eid}", _collect)
 
 
+def _refuse_with_latent(kv_layout, attention, kv_dtype, mesh, spec_draft,
+                        host_tier_bytes) -> None:
+    """A model whose cache is latent (one row a token for all heads,
+    ``models/decoder_spec.py``) is served by the fused paged path alone;
+    each mechanism that has no latent form yet is refused here, by
+    name."""
+    import jax.numpy as jnp
+    if kv_layout != "paged" or attention != "fused":
+        raise ValueError(
+            "a latent-attention model is served with kv_layout='paged', "
+            "attention='fused' only: the dense slot pool and the gather "
+            "decode step lay the cache out a head, and a latent cache has "
+            "ONE row a token for every head")
+    if mesh is not None:
+        raise ValueError(
+            "mesh= (tensor-parallel serving) does not compose with a "
+            "latent pool yet: the mp shards partition the pool's head "
+            "axis, and a latent row has none (the heads share it; TP of "
+            "latent attention shards the up-projections instead)")
+    if spec_draft is not None:
+        raise ValueError(
+            "spec_draft does not compose with a latent pool yet: the "
+            "draft tower and make_draft_model() build GPT blocks, and the "
+            "latent kernel takes a q block's real rows from kv_len, which "
+            "verify rows break")
+    if kv_dtype is not None and jnp.dtype(kv_dtype).name in (
+            "int8", "float8_e4m3fn"):
+        raise ValueError(
+            "int8/fp8 KV blocks do not compose with a latent pool yet: "
+            "the per-block max-abs scales are laid out [layers, 2, "
+            "blocks, heads] for K and V planes, and a latent row is both")
+    if host_tier_bytes is not None:
+        raise ValueError(
+            "host_tier_bytes does not compose with a latent pool yet: "
+            "demotion and promotion copy [layers, 2, heads, ...] K/V "
+            "planes of a block (serving/host_tier.py)")
+
+
 class GenerationEngine:
-    """Continuous-batching autoregressive serving over a GPT-style model.
+    """Continuous-batching autoregressive serving over a decoder the
+    fused stack has a spec of (``models/decoder_spec.py``: GPT-2, A.X-K1).
 
     ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` (anything
     exposing the ``gpt`` prefill/decode surface used by
@@ -354,12 +393,19 @@ class GenerationEngine:
                     "draft tower and verify program have no sharded "
                     "builders — run speculative engines single-device")
         self._fused = attention == "fused"
-        gpt = model.gpt if hasattr(model, "gpt") else model
-        cfg = gpt.cfg
-        max_len = int(max_len or cfg.max_position_embeddings)
+        # everything below sizes itself from the model's decoder spec
+        # (models/decoder_spec.py): layers, cache rows and lanes a token,
+        # vocabulary, positions
+        from ..models import decoder_spec as _ds
+        spec = _ds.serving_decoder(model).spec
+        cache = spec.cache
+        if spec.attention == _ds.LATENT:
+            _refuse_with_latent(kv_layout, attention, kv_dtype, mesh,
+                                spec_draft, host_tier_bytes)
+        max_len = int(max_len or spec.max_positions)
         model.eval()                      # serving is inference-only
         self._model = model
-        self._gpt = gpt
+        self._decoder_spec = spec
         self._pad = int(pad_token_id)
         self._top_k, self._top_p = int(top_k), float(top_p)
         self._mesh = mesh
@@ -368,7 +414,7 @@ class GenerationEngine:
         if mesh is not None:
             from ..models.generation import (_mp_mesh_check,
                                              shard_params_megatron)
-            self._mp = _mp_mesh_check(gpt, mesh, self._mp_axis)
+            self._mp = _mp_mesh_check(model, mesh, self._mp_axis)
             # lay the weights out Megatron-style BEFORE the snapshot:
             # the params tree then holds the sharded arrays and the
             # shard_map'd steps consume their local shards directly
@@ -378,13 +424,15 @@ class GenerationEngine:
         if dtype is None:
             dtype = self._params[next(iter(self._params))].dtype
         self._paged = kv_layout == "paged"
-        head_dim = cfg.hidden_size // cfg.num_attention_heads
+        # a per-head K|V row is two head_dims wide; a latent row has no
+        # head_dim and states its lanes
+        head_dim = 0 if cache.v_aliases_k else cache.lanes // 2
         if self._fused:
             # the fused engine either runs the kernel or raises, here:
             # no other attention path is selected behind its back
             from ..ops import pallas_smoke
             from ..ops.ragged_paged_attention import check_kv_tile
-            check_kv_tile(kv_dtype or dtype, block_size, head_dim)
+            check_kv_tile(kv_dtype or dtype, block_size, lanes=cache.lanes)
             pallas_smoke.ensure()
         self._key = jax.random.PRNGKey(int(seed))
         self._eid = _next_engine_id()
@@ -395,19 +443,20 @@ class GenerationEngine:
             # without this check an oversized max_len would only
             # surface as SILENTLY WRONG tokens (XLA clamps the
             # out-of-range wpe gather at decode positions past mpe)
-            if max_len > cfg.max_position_embeddings:
+            if max_len > spec.max_positions:
                 raise ValueError(
                     f"max_len {max_len} exceeds max_position_embeddings="
-                    f"{cfg.max_position_embeddings}")
+                    f"{spec.max_positions}")
             # prefill scatters WHOLE blocks, so capacity buckets must be
             # block multiples: round the floor up rather than reject it
             mb = -(-max(int(min_bucket), int(block_size))
                    // int(block_size)) * int(block_size)
             self._pool = PagedKVPool(
-                cfg.num_hidden_layers, num_slots, cfg.num_attention_heads,
+                len(spec.layers), num_slots, cache.rows,
                 max_len, head_dim, block_size=block_size,
                 num_blocks=num_blocks, dtype=kv_dtype or dtype,
-                min_bucket=mb, mesh=mesh, mp_axis=mp_axis)
+                min_bucket=mb, mesh=mesh, mp_axis=mp_axis,
+                lanes=cache.lanes)
             self._decode_jit = None       # per-table-bucket instead
             self._decode_jits = {}        # table bucket -> jitted step
             self._fused_jits = {}         # (q bucket, table bucket) -> step
@@ -415,7 +464,7 @@ class GenerationEngine:
             self._copy_jit = None         # lazy COW device block copy
         else:
             self._pool = KVCachePool(
-                cfg.num_hidden_layers, num_slots, cfg.num_attention_heads,
+                len(spec.layers), num_slots, cache.rows,
                 max_len, head_dim, dtype=dtype, min_bucket=min_bucket)
             self._decode_probe = _probe.site(f"serving/decode#{self._eid}")
             # program-registry AOT site (same jit semantics, donated
@@ -854,7 +903,7 @@ class GenerationEngine:
             from ..ops.ragged_paged_attention import BLOCK_Q
             Q, T = max(self._spec_jits)
             K = self._spec_k
-            V = self._gpt.cfg.vocab_size
+            V = self._decoder_spec.vocab_size
             scales = (self._pool.scales,) if self._pool.quantized else ()
             return analysis.analyze(
                 self._spec_step_fn(Q, T), self._params, self._buffers,
@@ -944,7 +993,7 @@ class GenerationEngine:
                 # padded to whole q blocks
                 blocks_per_slot = -(-(K + 1) // BLOCK_Q)
                 Q = self._q_bucket(S * blocks_per_slot * BLOCK_Q)
-                V = self._gpt.cfg.vocab_size
+                V = self._decoder_spec.vocab_size
                 fn = build_spec_verify_fn(
                     self._model, S, Q, K, T, pool.block_size,
                     top_k=self._top_k, top_p=self._top_p,
@@ -1293,14 +1342,20 @@ class GenerationEngine:
         # every q block of a slot walks that slot's whole context: one
         # KV block a step, one wait for a group of G blocks a fetch (G
         # as the kernel reads it from its pool shard's shape)
-        group = kv_group_blocks(pool.num_heads // self._mp, bs,
-                                pool.head_dim, pool.dtype)
+        if self._decoder_spec.cache.v_aliases_k:
+            from ..ops.mla_paged_attention import latent_group_blocks
+            group = latent_group_blocks(bs, pool.lanes, pool.dtype)
+        else:
+            group = kv_group_blocks(pool.num_heads // self._mp, bs,
+                                    pool.head_dim, pool.dtype)
         walks = [(-(-n // BLOCK_Q), -(-int(kv_len[s]) // bs))
                  for s, n in enumerate(q_lens) if n]
         self._sched.note_launch(
             rows=sum(q_lens), q=Q, t=T, kv_tokens=int(kv_len.sum()),
             kv_steps=sum(qb * kb for qb, kb in walks),
-            kv_fetches=sum(qb * -(-kb // group) for qb, kb in walks))
+            kv_fetches=sum(qb * -(-kb // group) for qb, kb in walks),
+            kv_row_tokens=sum(n * pos0s[s] + n * (n + 1) // 2
+                              for s, n in enumerate(q_lens) if n))
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps)
@@ -1385,10 +1440,10 @@ class GenerationEngine:
             spec_draft = make_draft_model(self._model)
         dgpt = spec_draft.gpt if hasattr(spec_draft, "gpt") \
             else spec_draft
-        if dgpt.cfg.vocab_size != self._gpt.cfg.vocab_size:
+        if dgpt.cfg.vocab_size != self._decoder_spec.vocab_size:
             raise ValueError(
                 f"draft vocab {dgpt.cfg.vocab_size} != target vocab "
-                f"{self._gpt.cfg.vocab_size}: rejection sampling "
+                f"{self._decoder_spec.vocab_size}: rejection sampling "
                 f"compares distributions over the SAME vocabulary")
         if max_len > dgpt.cfg.max_position_embeddings:
             raise ValueError(

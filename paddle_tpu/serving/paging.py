@@ -138,7 +138,8 @@ class PagedKVPool(SlotPoolBase):
     def __init__(self, num_layers: int, num_slots: int, num_heads: int,
                  max_len: int, head_dim: int, *, block_size: int = 16,
                  num_blocks: Optional[int] = None, dtype="float32",
-                 min_bucket: int = 8, mesh=None, mp_axis: str = "mp"):
+                 min_bucket: int = 8, mesh=None, mp_axis: str = "mp",
+                 lanes: Optional[int] = None):
         import jax.numpy as jnp
 
         if num_slots < 1:
@@ -160,6 +161,10 @@ class PagedKVPool(SlotPoolBase):
         self.num_heads = int(num_heads)
         self.max_len = int(max_len)
         self.head_dim = int(head_dim)
+        # a cached row's width: K|V side by side for a per-head row, or
+        # what the model's cache descriptor states (a latent pool is
+        # ``num_heads=1, lanes=descriptor's``: models/decoder_spec.py)
+        self.lanes = int(lanes) if lanes else 2 * self.head_dim
         self.block_size = int(block_size)
         self.min_bucket = int(min_bucket)
         # blocks a single request can ever hold (covers [0, max_len))
@@ -176,7 +181,7 @@ class PagedKVPool(SlotPoolBase):
                 f"max-length request ({self.max_table_len} blocks)")
         # +1: physical block 0 is the reserved scratch block
         self.shape = (self.num_layers, self.num_blocks + 1,
-                      self.num_heads, self.block_size, 2 * self.head_dim)
+                      self.num_heads, self.block_size, self.lanes)
         self.dtype = jnp.dtype(dtype)
         # tensor-parallel pool: the block array is head-partitioned over
         # a 1-D mp mesh ([.., H/mp, ..] per device) while every host
@@ -342,7 +347,8 @@ class PagedKVPool(SlotPoolBase):
     @classmethod
     def blocks_within_budget(cls, budget_bytes: int, *, num_layers: int,
                              num_heads: int, block_size: int,
-                             head_dim: int, dtype="float32") -> int:
+                             head_dim: int = 0, dtype="float32",
+                             lanes: Optional[int] = None) -> int:
         """Largest ``num_blocks`` whose pool (scratch block and, for
         quantized dtypes, the per-block scale array included) fits
         ``budget_bytes`` — the same-byte-budget sizing rule the
@@ -352,8 +358,8 @@ class PagedKVPool(SlotPoolBase):
         head_dim)``)."""
         import jax.numpy as jnp
         itemsize = jnp.dtype(dtype).itemsize
-        per_block = num_layers * 2 * num_heads * block_size * head_dim \
-            * itemsize
+        lanes = int(lanes) if lanes else 2 * int(head_dim)
+        per_block = num_layers * num_heads * block_size * lanes * itemsize
         if jnp.dtype(dtype).name in cls._QUANT_QMAX:
             per_block += num_layers * 2 * num_heads * 4
         # num_blocks + 1 physical blocks (scratch) must fit
@@ -451,8 +457,8 @@ class PagedKVPool(SlotPoolBase):
         tensor-parallel pool's demotion gathers the global value, so
         the host entry is shard-agnostic), no scratch, no sharding
         divisor."""
-        return (self.num_layers * 2 * self.num_heads * self.block_size
-                * self.head_dim * self.dtype.itemsize)
+        return (self.num_layers * self.num_heads * self.block_size
+                * self.lanes * self.dtype.itemsize)
 
     @property
     def host_scale_nbytes(self) -> int:

@@ -594,6 +594,22 @@ class Scheduler:
             if rec is not None:
                 rec["nonfinite"] = True
 
+    def _note_routed(self, toks, rec) -> None:
+        """Read a routed model's three launch counters off the fetched
+        token row (elements ``[num_slots + 1 : num_slots + 4]``, after
+        the sentinel; absent from every other model's row) into the
+        cycle record: ``moe_pairs`` (real rows x held experts they
+        chose, summed over the expert layers), ``moe_experts_hit`` (held
+        experts with at least one token, summed over the layers) and
+        ``moe_rows`` (real rows routed, summed over the layers). They
+        ride the cycle's one fetch: no sync of their own."""
+        at = self._pool.num_slots + 1
+        shape = getattr(toks, "shape", None)
+        if rec is not None and shape and shape[0] >= at + 3:
+            rec.update(moe_pairs=int(toks[at]),
+                       moe_experts_hit=int(toks[at + 1]),
+                       moe_rows=int(toks[at + 2]))
+
     def note_decode_flops(self, flops: float) -> None:
         """Record the FLOPs of the decode program dispatched THIS cycle
         into the live cycle record (called by the engine's do_decode,
@@ -605,7 +621,8 @@ class Scheduler:
                 self._rec.get("decode_flops", 0.0) + float(flops)
 
     def note_launch(self, rows: int, q: int, t: int, kv_tokens: int,
-                    kv_steps: int, kv_fetches: int) -> None:
+                    kv_steps: int, kv_fetches: int,
+                    kv_row_tokens: int = 0) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
@@ -616,12 +633,16 @@ class Scheduler:
         walks per layer, one DMA of a whole block (every head) each;
         ``kv_fetches``, the groups of blocks those DMAs go out in, each
         waited for and computed on once (q blocks x ceil(KV blocks /
-        G))."""
+        G)); ``kv_row_tokens``, the (query row, cached token) pairs of
+        the causal mask — a row at position ``p`` sees ``p + 1`` tokens
+        — which is what an attention kernel's products are counted
+        from."""
         if self._rec is not None:
             self._rec.update(launch_rows=int(rows), launch_q=int(q),
                              launch_t=int(t), kv_tokens=int(kv_tokens),
                              kv_steps=int(kv_steps),
-                             kv_fetches=int(kv_fetches))
+                             kv_fetches=int(kv_fetches),
+                             kv_row_tokens=int(kv_row_tokens))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into
@@ -1300,6 +1321,7 @@ class Scheduler:
             self._note_nonfinite(toks, rec, idx=2 * S + S * K)
         else:
             self._note_nonfinite(toks, rec)
+            self._note_routed(toks, rec)
         emitted = 0
         chunks = 0
         chunk_tokens = 0

@@ -80,3 +80,19 @@ def test_check_greedy_accepts_near_ties_only():
     both[:, 0], m[:, 0] = 999, 0.001
     with pytest.raises(AssertionError, match="no sequence matches"):
         chip_smoke.check_greedy(both, top, m, "t")
+
+
+def test_axk1_serve_phase():
+    """The latent-attention / routed-expert phase at toy widths."""
+    import json
+    data = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "tests", "data",
+        "tiny-axk1-config.json")
+    with open(data) as f:
+        cfg = json.load(f)
+    out = chip_smoke.phase_axk1_serve(
+        cfg["model"], dtype="float32", max_len=64, block_size=8,
+        num_slots=2, num_blocks=16, prefill_budget=16,
+        prompt_lens=(5, 21), new_tokens=4,
+        limits=cfg["serving"]["check"]["limits"], width=32, q_block=16)
+    assert out["tokens"] == 8 and out["max_gap"] < 1e-3
