@@ -26,7 +26,15 @@ The model (documented so the cross-check tolerance is auditable):
   whose updated operand is freeable and dies at that eqn reuses the
   operand's buffer for its result (what XLA does with a donated KV
   pool or cache) — charging both would bill every pool twice and
-  refuse any engine whose pool takes more than half the device;
+  refuse any engine whose pool takes more than half the device. The
+  same holds for a ``pallas_call`` output aliased to an operand
+  (``input_output_aliases``: the cache append, ``ops/kv_append.py``),
+  and for a call (pjit) whose inner program makes its result that way
+  from one of its arguments — jit inlines the call, so the caller's
+  dying buffer is the one updated;
+* **a Pallas kernel's body is not walked**: its variables are refs into
+  buffers already counted at the call, VMEM scratch and tiles in
+  registers — nothing in it is an HBM allocation;
 * **sub-jaxprs** (pjit / shard_map / scan / while / cond /
   custom_vjp) are walked recursively: the inner program's peak is
   charged at the calling eqn with the operand/result bytes already
@@ -156,6 +164,34 @@ _IN_PLACE_UPDATES = frozenset({
     "dynamic_update_slice"})
 
 
+def _in_place_pairs(eqn) -> List[tuple]:
+    """``(operand index, result index)`` of every result of ``eqn`` that
+    is written into an operand's buffer: an update primitive's operand
+    0, a ``pallas_call``'s aliased operands, and — through a call with
+    ONE inner program (pjit) — an inner result made that way directly
+    from an inner argument."""
+    name = eqn.primitive.name
+    if name in _IN_PLACE_UPDATES:
+        return [(0, 0)]
+    if name == "pallas_call":
+        return [(int(i), int(o))
+                for i, o in eqn.params.get("input_output_aliases", ())]
+    subs = list(_sub_jaxprs_raw(eqn))
+    if len(subs) != 1 or len(subs[0].invars) != len(eqn.invars) \
+            or len(subs[0].outvars) != len(eqn.outvars):
+        return []
+    sub = subs[0]
+    arg_of = {v: k for k, v in enumerate(sub.invars)}
+    out_of = {v: k for k, v in enumerate(sub.outvars) if not _is_literal(v)}
+    pairs = []
+    for inner in sub.eqns:
+        for i, o in _in_place_pairs(inner):
+            src, dst = inner.invars[i], inner.outvars[o]
+            if not _is_literal(src) and src in arg_of and dst in out_of:
+                pairs.append((arg_of[src], out_of[dst]))
+    return pairs
+
+
 def _walk(jaxpr, donated: Optional[Sequence[bool]], base: int,
           points: List[PeakPoint], depth: int) -> int:
     """Linear liveness scan over one (raw) jaxpr level. ``base`` is the
@@ -197,21 +233,31 @@ def _walk(jaxpr, donated: Optional[Sequence[bool]], base: int,
     for i, eqn in enumerate(eqns):
         out_total = sum(aval_bytes(v.aval) for v in eqn.outvars
                         if not _is_literal(v))
-        if eqn.primitive.name in _IN_PLACE_UPDATES:
-            target = eqn.invars[0]
+        reused = set()                   # operands a result overwrites
+        saved = 0
+        for k, o in _in_place_pairs(eqn):
+            target = eqn.invars[k]
             if not _is_literal(target) and target in live \
-                    and last_use.get(target) == i:
-                out_total -= min(out_total, live[target])
+                    and last_use.get(target) == i and k not in reused:
+                reused.add(k)
+                saved += min(aval_bytes(eqn.outvars[o].aval), live[target])
+        out_total -= min(out_total, saved)
         at_point = base + cur + out_total
-        subs = [x for x in _sub_jaxprs_raw(eqn)]
+        subs = [] if eqn.primitive.name == "pallas_call" \
+            else [x for x in _sub_jaxprs_raw(eqn)]
         inner_peak = 0
         if subs:
             don_inner = eqn.params.get("donated_invars") \
                 if len(subs) == 1 else None
+            if reused and len(subs) == 1:
+                # the inner program may overwrite what dies at the call
+                given = tuple(don_inner or ())
+                don_inner = [k in reused or (k < len(given) and given[k])
+                             for k in range(len(eqn.invars))]
             for sub in subs:
                 io = sum(aval_bytes(v.aval) for v in sub.invars) + \
                      sum(aval_bytes(v.aval) for v in sub.outvars
-                         if not _is_literal(v))
+                         if not _is_literal(v)) - saved
                 inner_base = max(0, at_point - io)
                 p = _walk(sub, don_inner, inner_base, points, depth + 1)
                 inner_peak = max(inner_peak, p)   # exclusive branches: max

@@ -754,6 +754,20 @@ def _append_nonfinite_flag(nxt, logits):
 # (PagedKVPool(dtype="int8")) keep per-block max-abs scales in a parallel
 # [L, 2, NB + 1, H] f32 array (plane 0 = K, 1 = V) — the EQuARX per-chunk
 # scheme of the PR-10 gradient wire, applied to KV storage.
+#
+# Who appends a token's rows how (PR 30):
+# * the fused towers (_fused_tower: the fused step and the speculative
+#   verify step; _mp_fused_tower: its tensor-parallel twin), full-attention
+#   layers, unquantized pool -> the Pallas kernel ops/kv_append.py: only
+#   real rows, a token's block for all heads in one DMA each way;
+# * the gather steps (build_paged_decode_fn and its sharded twin) ->
+#   _write_rows, the XLA scatter. They are the correctness oracle the fused
+#   path is tested against (tests/test_ragged_attention.py), so they stay
+#   independent of the kernel they check;
+# * quantized pools -> _quant_append (a block requantize, then _write_rows);
+#   latent pools -> _write_latent_rows (one row a token: ~0.5 ms a launch
+#   as a scatter, PERF.md finding 29.2). Their needs differ; nothing is
+#   shared by force.
 # ---------------------------------------------------------------------------
 
 def _kv_lanes(k, v):
@@ -767,6 +781,12 @@ def _write_rows(pool, li, wb, off, k_rows, v_rows):
     """Write one K|V row per (token, head) into layer ``li`` of the
     pool: ``k_rows``/``v_rows [N, H, Dh]`` land at ``(block wb[n], head
     h, offset off[n])``.
+
+    The gather steps' append, and the quantized append's last step; the
+    fused towers write through ``ops/kv_append.py`` (the comment above
+    ``_kv_lanes``). As a scatter this is ``N x H`` updates of one row,
+    which XLA's TPU scatter walks one by one, pad rows included (0.86 ms
+    a layer at 512 rows x 20 heads, PERF.md PR 30).
 
     Every axis but the lanes is INDEXED (the head axis by an explicit
     ``arange``), so the scatter's window is one 128-lane row — the
@@ -1096,18 +1116,20 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     """The fused ragged transformer tower shared by
     :func:`build_fused_step_fn` and :func:`build_spec_verify_fn`, over
     the layers of a decoder spec (``models/decoder_spec.py``; ``dec`` is a
-    model's ``serving_decoder()``): per layer, write every flattened
-    row's cache entry through the page table (quantized pools go through
-    :func:`_quant_append`), run the attention kernel of the layer's kind
+    model's ``serving_decoder()``): per layer, write every real flattened
+    row's cache entry through the page table (``ops.kv_append``; quantized
+    pools go through :func:`_quant_append`, latent ones through
+    :func:`_write_latent_rows`), run the attention kernel of the layer's kind
     over the block pool — ``full``: the ragged paged attention kernel on
     per-head K|V rows; ``latent``: the MLA kernel on the one latent row
     all heads share — and apply the layer's output projection and FFN.
-    A ``routed`` FFN is told which rows are real (pad rows write the
+    A ``routed`` FFN is told which rows are real (pad rows name the
     scratch block 0) and returns its three counters, summed over the
     layers here. Returns ``(final_norm(x), pool, scales, counters)``,
     ``counters`` ``None`` for a model without routed layers."""
     import jax.numpy as jnp
 
+    from ..ops.kv_append import kv_append
     from ..ops.mla_paged_attention import mla_paged_attention
     from ..ops.ragged_paged_attention import ragged_paged_attention
     from .decoder_spec import FULL
@@ -1116,15 +1138,16 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     counters = None
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
         q, rows = layer.attn_in(x, positions)
-        # per-row scatter through the page table: row i's entry lands at
-        # (write_block[i], write_off[i]) — pad rows hit the scratch
-        # block nobody reads
+        # row i's entry lands at (write_block[i], write_off[i]) through
+        # the page table. The two XLA scatters send pad rows to the
+        # scratch block nobody reads; kv_append skips them
         if ls.attention == FULL:
             if quantized:
                 pool, scales = _quant_append(
                     pool, scales, li, write_block, write_off, *rows, qmax)
             else:
-                pool = _write_rows(pool, li, write_block, write_off, *rows)
+                pool = kv_append(pool, li, write_block, write_off,
+                                 _kv_lanes(*rows))
             a = ragged_paged_attention(
                 q, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
                 kv_len, scales=scales)
@@ -1166,9 +1189,9 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
       int32 — the flattened padded ragged batch (see
       ``ops.ragged_paged_attention.ragged_layout``): each row's token,
       virtual cache position, and page-table-resolved physical write
-      block/offset (pad rows write the scratch block); every row's K/V
-      are scattered into the pool BEFORE the kernel runs, so a chunk
-      row attends causally to its own chunk prefix;
+      block/offset (pad rows name the scratch block 0); every real
+      row's K/V are appended to the pool BEFORE the attention kernel
+      runs, so a chunk row attends causally to its own chunk prefix;
     * ``blk_seq [q_rows / 8]``, ``seq_qstart``/``seq_pos0``/``lo``/
       ``kv_len`` ``[num_slots]``, ``tables [num_slots, table_len]`` —
       the kernel's scalar-prefetch metadata;
@@ -1328,19 +1351,22 @@ def _mp_tail(block, x, a_local, mp_axis):
 def _mp_fused_tower(gpt, x, pool, write_block, write_off, blk_seq,
                     seq_qstart, seq_pos0, tables, lo, kv_len, mp,
                     mp_axis):
-    """Per-device fused ragged tower: each device scatters its OWN
-    heads' K/V into its pool shard and launches the ragged Pallas
-    kernel over its local head range — heads are a batch dimension of
-    the kernel, so the per-shard call is the UNMODIFIED kernel on an
-    ``[H/mp, ...]`` slice with the replicated scalar-prefetch metadata.
+    """Per-device fused ragged tower: each device appends its OWN
+    heads' K/V to its pool shard (``ops.kv_append``) and launches the
+    ragged Pallas kernel over its local head range — heads are a batch
+    dimension of both kernels, so the per-shard calls are the UNMODIFIED
+    kernels on an ``[H/mp, ...]`` slice with the replicated
+    scalar-prefetch metadata.
     Returns ``(ln_f(x), pool)``."""
     import jax.numpy as jnp
 
+    from ..ops.kv_append import kv_append
     from ..ops.ragged_paged_attention import ragged_paged_attention
 
     for li, block in enumerate(gpt.blocks):
         q, k, v = _mp_qkv(block, x, mp, mp_axis)
-        pool = _write_rows(pool, li, write_block, write_off, k[0], v[0])
+        pool = kv_append(pool, li, write_block, write_off,
+                         _kv_lanes(k[0], v[0]))
         qh = jnp.transpose(q, (0, 2, 1, 3))[0]       # [H/mp, Q, Dh]
         a = ragged_paged_attention(
             qh, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,

@@ -1355,7 +1355,13 @@ class GenerationEngine:
             kv_steps=sum(qb * kb for qb, kb in walks),
             kv_fetches=sum(qb * -(-kb // group) for qb, kb in walks),
             kv_row_tokens=sum(n * pos0s[s] + n * (n + 1) // 2
-                              for s, n in enumerate(q_lens) if n))
+                              for s, n in enumerate(q_lens) if n),
+            # a slot's rows are consecutive positions: the blocks from
+            # its first row's to its last row's, each rewritten once a
+            # layer by ops.kv_append
+            kv_write_blocks=sum(
+                (pos0s[s] + n - 1) // bs - pos0s[s] // bs + 1
+                for s, n in enumerate(q_lens) if n))
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps)

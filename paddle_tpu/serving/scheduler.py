@@ -622,7 +622,8 @@ class Scheduler:
 
     def note_launch(self, rows: int, q: int, t: int, kv_tokens: int,
                     kv_steps: int, kv_fetches: int,
-                    kv_row_tokens: int = 0) -> None:
+                    kv_row_tokens: int = 0,
+                    kv_write_blocks: int = 0) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
@@ -636,13 +637,16 @@ class Scheduler:
         G)); ``kv_row_tokens``, the (query row, cached token) pairs of
         the causal mask — a row at position ``p`` sees ``p + 1`` tokens
         — which is what an attention kernel's products are counted
-        from."""
+        from; ``kv_write_blocks``, the (slot, block) pairs the real rows
+        land in — the blocks the cache append reads, fills in and
+        writes back, once each a layer (``ops/kv_append.py``)."""
         if self._rec is not None:
             self._rec.update(launch_rows=int(rows), launch_q=int(q),
                              launch_t=int(t), kv_tokens=int(kv_tokens),
                              kv_steps=int(kv_steps),
                              kv_fetches=int(kv_fetches),
-                             kv_row_tokens=int(kv_row_tokens))
+                             kv_row_tokens=int(kv_row_tokens),
+                             kv_write_blocks=int(kv_write_blocks))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into

@@ -1024,3 +1024,33 @@ def test_cli_json_and_budget_gate():
                          timeout=300)
     assert res.returncode == 0, res.stdout
     assert "fits" in res.stdout
+
+
+def test_liveness_aliased_kernel_output_is_in_place():
+    """A ``pallas_call`` whose output aliases an operand
+    (``ops/kv_append.py``: the pool, through a jitted call of its own)
+    overwrites a DONATED pool: one pool at the peak, as with the scatter
+    it replaced — two refused GPT-2 large's 13 GB pool on a 16 GB chip.
+    A pool the caller keeps is still charged twice, and the kernel's
+    body (refs, VMEM tiles) is no program point."""
+    import jax.numpy as jnp
+    from paddle_tpu.analysis import liveness
+    from paddle_tpu.ops.kv_append import kv_append
+
+    pool = jnp.zeros((2, 9, 4, 16, 128), jnp.float32)     # 576 KiB
+    rows = jnp.ones((8, 4, 128), jnp.float32)
+    wb = jnp.asarray([3, 0, 0, 0, 0, 0, 0, 0], jnp.int32)
+    nbytes = pool.size * pool.dtype.itemsize
+
+    def two_layers(pool, wb, rows):
+        for li in range(2):
+            pool = kv_append(pool, li, wb, wb * 0, rows)
+        return pool
+
+    kept = liveness.callable_liveness(two_layers, pool, wb, rows)
+    donated = liveness.callable_liveness(two_layers, pool, wb, rows,
+                                         donate_argnums=(0,))
+    assert kept.static_peak_bytes >= 2 * nbytes
+    assert nbytes <= donated.static_peak_bytes < nbytes + 64 * 1024
+    assert not [p for p in donated.timeline
+                if (p.source or "").find("_append_kernel") >= 0]

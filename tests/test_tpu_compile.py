@@ -1,5 +1,5 @@
 """The Pallas kernels of the main path, compiled for a DESCRIBED v5e at
-GPT-2 124M widths (the ragged serving kernel at GPT-2 large's too) — no
+GPT-2 124M widths (the two serving kernels at GPT-2 large's too) — no
 chip attached, about two seconds each.
 
 Interpret mode (every other kernel test) cannot see what Mosaic refuses:
@@ -20,6 +20,8 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from paddle_tpu.ops import pallas_kernels as pk  # noqa: E402
+from paddle_tpu.ops.kv_append import (  # noqa: E402
+    APPEND_VMEM_BUDGET, append_ring_blocks, kv_append)
 from paddle_tpu.ops.ragged_paged_attention import (  # noqa: E402
     KV_VMEM_BUDGET, kv_group_blocks, ragged_layout, ragged_paged_attention)
 
@@ -112,6 +114,75 @@ def test_ragged_paged_attention_compiles_at_gpt2_large_widths(
     fn, avals = _ragged_case(v5e, pool_dtype, block_size, q_lens,
                              heads=heads, q_bucket=q_bucket, table_len=64)
     assert "ragged_paged_attention" in _compile(fn, *avals)
+
+
+def _append_avals(chip, heads, q_bucket, pool_dtype="bfloat16"):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    return [sds((2, 65, heads, 16, 2 * DH), pool_dtype),
+            sds((q_bucket,), jnp.int32), sds((q_bucket,), jnp.int32),
+            sds((q_bucket, heads, 2 * DH), jnp.bfloat16)]
+
+
+@pytest.mark.parametrize("heads,q_bucket,pool_dtype", [
+    (20, 512, "bfloat16"),        # gpt2-large, the decode program
+    (20, 1024, "bfloat16"),       # decode rows + a chunk
+    (12, 512, "bfloat16"),        # 124M
+    (5, 512, "float32"),          # a TP shard of 20 heads over 4 devices
+], ids=["large-q512", "large-q1024", "124m-q512", "tp-shard-f32"])
+def test_kv_append_compiles_at_gpt2_widths(v5e, heads, q_bucket,
+                                           pool_dtype):
+    """What interpret mode cannot see of the append: the block's DMA
+    both ways, the select on packed bf16 rows, the ring inside the
+    budget the module states."""
+    assert append_ring_blocks(heads, 16, DH, pool_dtype) * heads * 16 \
+        * 2 * DH * jnp.dtype(pool_dtype).itemsize <= APPEND_VMEM_BUDGET
+    text = _compile(lambda pool, wb, off, rows:
+                    kv_append(pool, 1, wb, off, rows),
+                    *_append_avals(v5e, heads, q_bucket, pool_dtype))
+    assert "kv_append" in text
+
+
+def test_append_and_attention_tower_updates_the_donated_pool_in_place(v5e):
+    """Two layers of append + ragged kernel at GPT-2 large widths, the
+    pool donated, as ``_fused_tower`` chains them: the compiled module
+    keeps ONE pool (aliased to its output, no temporary of its size, no
+    ``copy`` of its shape) and no XLA scatter — the 36 scatters of 10,240
+    one-row updates were 61% of a decode launch (PERF.md, PR 30)."""
+    heads, q_bucket = 20, 512
+    q_lens = [1] * 64
+    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, [20] * 64,
+                                                q_bucket=q_bucket)
+    tables = np.zeros((64, 64), np.int32)
+    lo = np.zeros(64, np.int32)
+    kv_len = np.full(64, 21, np.int32)
+    pool, wb, off, rows = _append_avals(v5e, heads, q_bucket)
+    q = jax.ShapeDtypeStruct((heads, q_bucket, DH), jnp.bfloat16,
+                             sharding=v5e)
+
+    def tower(pool, wb, off, rows, q):
+        for li in range(2):
+            pool = kv_append(pool, li, wb, off, rows)
+            a = ragged_paged_attention(q, pool, li, blk_seq, qstart, pos0,
+                                       tables, lo, kv_len)
+            q = q + a                   # the next layer waits for this one
+        return pool, q
+
+    compiled = jax.jit(tower, donate_argnums=0).lower(
+        pool, wb, off, rows, q).compile()
+    text = compiled.as_text()
+    calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    assert sum(c.startswith("%kv_append") for c in calls) == 2, calls
+    assert sum(c.startswith("%ragged_paged_attention") for c in calls) == 2
+    assert "scatter" not in text
+    pool_shape = "bf16[%s]" % ",".join(str(d) for d in pool.shape)
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and pool_shape in ln]
+    assert not copies, copies
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 4
 
 
 def test_flash_attention_fwd_bwd_compiles_at_gpt2_train_shape(v5e):

@@ -212,13 +212,16 @@ def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
     for cycle, rows, kv, steps, fetches in planned:
         rec = records[cycle]
         for key in ("plan_ms", "emit_ms", "launch_rows", "launch_q",
-                    "launch_t", "kv_tokens", "kv_steps", "kv_fetches"):
+                    "launch_t", "kv_tokens", "kv_steps", "kv_fetches",
+                    "kv_write_blocks"):
             assert key in rec, key
         assert rec["launch_rows"] == rows
         assert rec["kv_tokens"] == kv
         assert rec["kv_steps"] == steps
         assert rec["kv_fetches"] == fetches
         assert rec["launch_rows"] <= rec["launch_q"]
+        # a slot's rows land in at least one block, at most one a row
+        assert 1 <= rec["kv_write_blocks"] <= rec["launch_rows"]
         assert rec["launch_q"] % 8 == 0 and rec["launch_t"] >= 1
         assert rec["plan_ms"] > 0 and rec["emit_ms"] > 0
         # plan, launch, fetch and emit are parts of the cycle
@@ -251,13 +254,15 @@ def test_kv_fetches_counts_the_groups_a_launch_waits_for():
                    max_new_tokens=3).result(timeout=300)
     finally:
         eng.close()
-    walked = [(c["launch_rows"], c["kv_steps"], c["kv_fetches"])
+    walked = [(c["launch_rows"], c["kv_steps"], c["kv_fetches"],
+               c["kv_write_blocks"])
               for c in eng.flight_recorder.snapshot()["cycles"]
               if c.get("launch_rows")]
-    # (rows, q blocks x KV blocks, q blocks x groups of 4 blocks): the
-    # chunks end at 64, 128 and 150 tokens, then decode rows at 151, 152
-    assert walked == [(64, 8 * 2, 8 * 1), (64, 8 * 4, 8 * 1),
-                      (22, 3 * 5, 3 * 2), (1, 5, 2), (1, 5, 2)]
+    # (rows, q blocks x KV blocks, q blocks x groups of 4 blocks, blocks
+    # the rows land in): the chunks end at 64, 128 and 150 tokens, then
+    # decode rows at 151, 152
+    assert walked == [(64, 8 * 2, 8 * 1, 2), (64, 8 * 4, 8 * 1, 2),
+                      (22, 3 * 5, 3 * 2, 1), (1, 5, 2, 1), (1, 5, 2, 1)]
 
 
 def test_a_train_step_is_in_the_trace_with_its_number(tmp_path):
