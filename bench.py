@@ -557,101 +557,6 @@ def bench_gpt2_decode():
             "device_kind": _device_kind(), **pallas_state}
 
 
-def bench_attn():
-    """Gather-vs-fused paged attention microbench (``--bench-attn``):
-    the same decode workload through GenerationEngine(attention=
-    "gather") and ("fused"), reporting per-decode-step ms (flight-
-    recorder cycle ring: dispatch + fetch of decode-only cycles) and
-    bytes-accessed-per-token (PR-7 program-registry XLA cost analysis
-    of the step that actually served). The fused step must be SELECTED
-    and token-parity with the gather oracle must hold — a fused path
-    that silently fell back or drifted is an error, not a number.
-    Lands in the BENCH artifact so ``--history`` gates the speedup."""
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
-    from paddle_tpu.serving import GenerationEngine
-
-    pallas_state = _setup_pallas()
-    if _smoke() or jax_backend_is_cpu():
-        cfg, slots, prompt, new, reqs = GPTConfig.tiny(), 4, 12, 16, 8
-    else:
-        cfg = GPTConfig.gpt2_small()
-        cfg.hidden_dropout_prob = 0.0
-        cfg.attention_dropout_prob = 0.0
-        slots, prompt, new, reqs = 8, 64, 64, 16
-    paddle.framework.random.seed(0)
-    model = GPTForPretraining(cfg)
-    model.eval()
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(1, cfg.vocab_size, prompt).astype(np.int32)
-               for _ in range(reqs)]
-
-    def run(attention):
-        eng = GenerationEngine(
-            model, num_slots=slots, max_len=prompt + new + 8,
-            kv_layout="paged", block_size=16, attention=attention)
-        # warm with a FULL concurrent wave of the same workload: the
-        # fused engine compiles one program per (q-row, table) bucket
-        # and the concurrent-occupancy q buckets only exist at
-        # concurrency — a single-request warm-up would leave the fused
-        # side paying multi-second compiles inside the timed region
-        # while the gather side (whose buckets depend only on context
-        # length) ran fully warm
-        warm = [eng.submit(p, max_new_tokens=new) for p in prompts]
-        [h.result(timeout=600) for h in warm]
-        warm_snap = eng._sched.recorder.snapshot()
-        warm_last = warm_snap["cycles"][-1]["cycle"] \
-            if warm_snap["cycles"] else 0
-        t0 = time.perf_counter()
-        hs = [eng.submit(p, max_new_tokens=new) for p in prompts]
-        outs = [h.result(timeout=600) for h in hs]
-        wall = time.perf_counter() - t0
-        thr = eng._sched.recorder.cycle_throughput()
-        snap = eng._sched.recorder.snapshot()
-        # decode-step samples from TIMED cycles only (warm cycles carry
-        # the compile wall inside decode_dispatch_ms)
-        decode_ms = [c["decode_dispatch_ms"] + c["fetch_ms"]
-                     for c in snap["cycles"]
-                     if c["cycle"] > warm_last
-                     and c.get("decode_dispatch_ms", 0) > 0
-                     and not c.get("chunk_tokens")]
-        stats = eng.stats()
-        # evidence, not the echoed ctor arg: a fused engine that
-        # actually served compiled fused (q, table)-bucket programs
-        selected = (bool(eng._fused_jits) if attention == "fused"
-                    else not eng._fused_jits)
-        eng.close()
-        return {
-            "outs": outs,
-            "selected": selected,
-            "decode_step_ms": (round(float(np.median(decode_ms)), 3)
-                               if decode_ms else None),
-            "bytes_per_token": stats.get("decode_bytes_per_token"),
-            "tokens_per_sec": round(reqs * new / wall, 1),
-            "emitted": thr["emitted"],
-        }
-
-    gather = run("gather")
-    fused = run("fused")
-    parity = all(np.array_equal(a, b)
-                 for a, b in zip(gather.pop("outs"), fused.pop("outs")))
-    if not fused["selected"] or not parity:
-        raise RuntimeError(
-            f"fused attention bench invalid: selected={fused['selected']} "
-            f"parity={parity}")
-    out = {"metric": "attn_fused_decode_step_ms",
-           "value": fused["decode_step_ms"], "unit": "ms",
-           "fused": fused, "gather": gather, "parity": parity,
-           "batch_requests": reqs, "prompt_len": prompt,
-           "new_tokens": new,
-           "device_kind": _device_kind(), **pallas_state}
-    if gather["decode_step_ms"] and fused["decode_step_ms"]:
-        out["speedup_vs_gather"] = round(
-            gather["decode_step_ms"] / fused["decode_step_ms"], 3)
-    return out
-
-
 def bench_zero():
     """Replicated vs ZeRO-sharded donated train step (``--bench-zero``):
     the same Adam fit through ``fit(zero=0)`` and ``fit(zero=1)`` (plus
@@ -787,7 +692,7 @@ def bench_spec():
     a speculative path that changes greedy output is a bug, not a
     number. Leg 2 — int8 blocks: a same-byte-budget capacity ratio
     (``blocks_within_budget``) plus an int8-vs-fp32 token-agreement
-    drift check through the gather engine. Lands in the BENCH artifact
+    drift check through the engine. Lands in the BENCH artifact
     so ``--history`` gates accept rate, tokens/step and capacity from
     round 1."""
     import numpy as np
@@ -814,9 +719,7 @@ def bench_spec():
 
     def run(spec_draft, kv_dtype=None, block_size=16):
         eng = GenerationEngine(
-            model, num_slots=slots, max_len=max_len, kv_layout="paged",
-            block_size=block_size, attention="fused",
-            kv_dtype=kv_dtype, spec_draft=spec_draft, spec_k=spec_k)
+            model, num_slots=slots, max_len=max_len, block_size=block_size, kv_dtype=kv_dtype, spec_draft=spec_draft, spec_k=spec_k)
         warm = [eng.submit(p, max_new_tokens=new) for p in prompts]
         [h.result(timeout=600) for h in warm]
         warm_snap = eng._sched.recorder.snapshot()
@@ -882,17 +785,18 @@ def bench_spec():
                                                 **fp_pool_kw)
     capacity_ratio = round(q_blocks / fp_blocks, 3)
 
-    def run_gather(kv_dtype):
+    def run_pool(kv_dtype):
+        # 32-token blocks: what an int8 tile needs
         eng = GenerationEngine(
-            model, num_slots=slots, max_len=max_len, kv_layout="paged",
-            block_size=16, kv_dtype=kv_dtype)
+            model, num_slots=slots, max_len=max_len, block_size=32,
+            kv_dtype=kv_dtype)
         hs = [eng.submit(p, max_new_tokens=new) for p in prompts]
         outs = [h.result(timeout=600) for h in hs]
         eng.close()
         return outs
 
-    fp_outs = run_gather(None)
-    q_outs = run_gather("int8")
+    fp_outs = run_pool(None)
+    q_outs = run_pool("int8")
     gen = np.concatenate([o[prompt:] for o in fp_outs])
     qgen = np.concatenate([o[prompt:] for o in q_outs])
     token_agreement = round(float((gen == qgen).mean()), 4)
@@ -973,8 +877,7 @@ def bench_mp():
         model = GPTForPretraining(cfg)
         model.eval()
         eng = GenerationEngine(
-            model, num_slots=slots, max_len=max_len, kv_layout="paged",
-            block_size=16, attention="fused", mesh=mesh)
+            model, num_slots=slots, max_len=max_len, block_size=16, mesh=mesh)
         warm = [eng.submit(p, max_new_tokens=new) for p in prompts]
         [h.result(timeout=600) for h in warm]
         warm_snap = eng._sched.recorder.snapshot()
@@ -1046,656 +949,8 @@ BENCHES = {"gpt2": bench_gpt2, "resnet50": bench_resnet50,
            "gpt2_fp32": lambda: bench_gpt2(amp_o2=False),
            "resnet50_pipeline": bench_resnet50_pipeline,
            "eager": bench_eager, "serve": bench_serve,
-           "gpt2_decode": bench_gpt2_decode, "attn": bench_attn,
+           "gpt2_decode": bench_gpt2_decode,
            "zero": bench_zero, "spec": bench_spec, "mp": bench_mp}
-
-
-# ---------------------------------------------------------------------------
-# open-loop serving load harness (--serve-load)
-# ---------------------------------------------------------------------------
-
-def _load_schedule(seed, n, rate, system, vocab):
-    """Seeded OPEN-arrival schedule: Poisson arrivals at ``rate`` req/s
-    (exponential inter-arrival gaps, submitted on the clock regardless
-    of completions — the open-loop discipline that actually exposes
-    queueing collapse) with a mixed prompt/max_new distribution. ~40%
-    of prompts are the block-aligned system prefix plus a SHORT tail
-    (paged prefix-hit candidates), ~20% the prefix plus a long tail
-    (fresh prefill, shared blocks), the rest fully fresh. Lengths are
-    chosen so every request is feasible for BOTH engines at max_len=64:
-    dense needs bucket(prompt) + max_new <= 64 (prompt <= 31 -> bucket
-    32, max_new <= 16), paged needs prompt + max_new <= 64 and a
-    worst-re-admission bucket <= 64."""
-    import numpy as np
-    rng = np.random.RandomState(seed)
-    offsets = np.cumsum(rng.exponential(1.0 / rate, n))
-    schedule = []
-    for i in range(n):
-        kind = rng.rand()
-        if kind < 0.4:
-            tail = rng.randint(1, 8)       # fits one min_bucket: a hit
-        elif kind < 0.6:
-            tail = rng.randint(9, 16)      # too long: fresh prefill
-        else:
-            tail = None
-        if tail is not None:
-            ids = np.concatenate(
-                [system, rng.randint(1, vocab, tail)]).astype(np.int32)
-        else:
-            ids = rng.randint(1, vocab,
-                              rng.randint(3, 29)).astype(np.int32)
-        schedule.append((float(offsets[i]), ids,
-                         int(rng.randint(4, 17))))
-    return schedule
-
-
-def _tiered_schedule(seed, n, rate, systems, vocab):
-    """Rotating-prefix schedule for ``--serve-load --tiered``: EVERY
-    request is a prefix-hit candidate over ``len(systems)`` distinct
-    2-block system preambles, visited round-robin with short fresh
-    tails. The prefix working set (all preambles together) is sized to
-    EXCEED the device block pool, so an HBM-only engine keeps evicting
-    exactly the blocks the next arrival needs, while the tiered engine
-    re-serves them from host DRAM through async promotions."""
-    import numpy as np
-    rng = np.random.RandomState(seed)
-    offsets = np.cumsum(rng.exponential(1.0 / rate, n))
-    schedule = []
-    for i in range(n):
-        sysp = systems[i % len(systems)]
-        tail = 1     # one fresh token (the one-shot-query-against-a-
-        # shared-system-prompt shape): the hit's first decode step IS
-        # the first-token step, so the win from skipping the preamble
-        # prefill is not given back one replayed token at a time
-        ids = np.concatenate(
-            [sysp, rng.randint(1, vocab, tail)]).astype(np.int32)
-        schedule.append((float(offsets[i]), ids,
-                         int(rng.randint(4, 9))))
-    return schedule
-
-
-def _run_serve_load(engine, schedule, slo_ms):
-    """Drive one engine with the schedule; returns (summary, handles).
-    TTFT/TPOT come from each handle's RequestTrace — per-request,
-    per-engine, no process-global histogram involved. Goodput is the
-    SLO-metric that matters: completed requests whose TTFT met the
-    latency SLO, per second of wall clock."""
-    from paddle_tpu.framework.monitor import _percentile
-    from paddle_tpu.serving import QueueFullError
-
-    t_start = time.perf_counter()
-    handles, shed, failed = [], 0, 0
-    for off, ids, max_new in schedule:
-        delay = t_start + off - time.perf_counter()
-        if delay > 0:
-            time.sleep(delay)
-        try:
-            handles.append(engine.submit(ids, max_new_tokens=max_new))
-        except QueueFullError:
-            shed += 1                      # open loop: the caller sheds
-    for h in handles:
-        try:
-            h.result(timeout=600)
-        except Exception:                  # noqa: BLE001
-            failed += 1
-    wall = time.perf_counter() - t_start
-    traces = [h.trace for h in handles]
-    ttft = sorted(t.ttft_ms for t in traces if t.ttft_ms is not None)
-    tpot = sorted(t.tpot_ms for t in traces if t.tpot_ms is not None)
-
-    def pct(vals):
-        return {"p50": round(_percentile(vals, 0.5), 2),
-                "p95": round(_percentile(vals, 0.95), 2),
-                "p99": round(_percentile(vals, 0.99), 2),
-                "count": len(vals)}
-
-    good = sum(1 for t in traces
-               if t.t("finish") is not None and t.ttft_ms is not None
-               and t.ttft_ms <= slo_ms)
-    summary = {
-        "requests": len(schedule), "shed": shed, "failed": failed,
-        "completed": sum(1 for t in traces if t.t("finish") is not None),
-        "wall_sec": round(wall, 3),
-        "tokens": int(sum(len(t.token_times) for t in traces)),
-        "ttft_ms": pct(ttft), "tpot_ms": pct(tpot),
-        "slo_ms": slo_ms,
-        "slo_attainment": round(good / max(1, len(schedule)), 4),
-        "goodput_rps": round(good / wall, 2),
-    }
-    return summary, handles
-
-
-def _serve_load_engine(kind, model, schedule, slo_ms, num_slots=8,
-                       engine_kw=None, outputs_sink=None, warm=None):
-    """One engine's leg of the load run: drive it, then fold in the
-    per-engine stats()/flight-recorder view and the zero-retrace check
-    (every serving trace-probe site of THIS engine compiled exactly
-    once — a retrace storm under load is the bug class the pow2 bucket
-    discipline exists to prevent).
-
-    The run also exercises the SLO plane end to end over the WIRE: an
-    SLOTracker observes every retired trace, an OpsServer serves the
-    registry on an ephemeral port, and the attainment recomputed from
-    the HTTP-scraped histogram buckets must bracket the in-process
-    value within one bucket of resolution (the acceptance gate)."""
-    import urllib.request
-
-    from paddle_tpu.framework import trace_probe
-    from paddle_tpu.framework.metrics import parse_prometheus
-    from paddle_tpu.serving import (GenerationEngine, OpsServer,
-                                    SLOTracker)
-    from paddle_tpu.serving.slo import attainment_from_buckets
-
-    import numpy as np
-
-    paged_like = kind != "dense"        # "paged", "tiered"
-    kw = dict(num_slots=num_slots, max_len=64, min_bucket=8)
-    if paged_like:
-        kw.update(kv_layout="paged", block_size=8)
-    kw.update(engine_kw or {})
-    eng = GenerationEngine(model, **kw)
-    # warm the compile caches BEFORE the clock starts: one request per
-    # prefill bucket the schedule can touch (8/16/32, plus the paged
-    # engine's deeper page-table buckets) — the measured TTFT curve
-    # must reflect serving behavior, not XLA cold compiles
-    if warm is None:
-        warm = [(4, 2), (12, 2), (28, 2)]
-        if paged_like:
-            warm.append((40, 14))        # grows the table to bucket 8
-    for plen, mnew in warm:
-        eng.submit(np.full(plen, 1, np.int32),
-                   max_new_tokens=mnew).result(timeout=600)
-    if kind == "tiered":
-        # pay the tier's one-time eager compiles (pow2 demotion
-        # gather, promotion gather + scatter) before the clock: churn
-        # the device pool until the first warm prefix is evicted —
-        # its blocks demoted the moment they went refcount-0 — then
-        # re-hit it so one full promotion lands end to end. Constant-
-        # value prompts never collide with the measured schedule's
-        # arange preambles.
-        for v in (2, 3, 4, 5):
-            eng.submit(np.full(120, v, np.int32),
-                       max_new_tokens=4).result(timeout=600)
-        eng._pool.host_tier.drain()
-        eng.submit(np.full(120, 1, np.int32),
-                   max_new_tokens=4).result(timeout=600)
-        eng._pool.host_tier.drain()
-    # SLO plane attached AFTER warm-up, so the objectives score only
-    # the measured traffic (warm TTFTs contain XLA compile time)
-    obj_name = f"ttft_{kind}"
-    slo = SLOTracker(name=f"serve_load_{kind}")
-    slo.add_objective(obj_name, metric="ttft_ms", target_ms=slo_ms,
-                      goal=0.95)
-    replica = slo.attach_engine(eng)
-    srv = OpsServer(target=eng, slo=slo).start()
-    summary, handles = _run_serve_load(eng, schedule, slo_ms)
-    if outputs_sink is not None:
-        # greedy outputs for the tiered-vs-HBM-only parity gate; a
-        # failed handle contributes None (caught by the failed count)
-        for h in handles:
-            try:
-                outputs_sink.append(np.asarray(h.result(timeout=1)))
-            except Exception:              # noqa: BLE001
-                outputs_sink.append(None)
-    # scrape over real HTTP while the engine is live, then close the
-    # equivalence loop: exact in-process attainment must lie inside the
-    # bucket-resolution bracket recomputed from the scraped histogram
-    text = urllib.request.urlopen(
-        srv.url + "/metrics", timeout=60).read().decode()
-    healthz_ok = urllib.request.urlopen(
-        srv.url + "/healthz", timeout=60).getcode() == 200
-    parsed = parse_prometheus(text)
-    pairs = []
-    for (name, labels), v in parsed["samples"].items():
-        lab = dict(labels)
-        if name == "slo_latency_ms_bucket" \
-                and lab.get("objective") == obj_name:
-            le = lab.get("le", "")
-            pairs.append((float("inf") if le == "+Inf" else float(le),
-                          v))
-    att_lo, att_hi = attainment_from_buckets(pairs, slo_ms)
-    slo_rep = slo.report()["objectives"][obj_name]
-    att = slo_rep["attainment"]
-    scrape_equiv = (att is not None and att_lo is not None
-                    and att_lo - 1e-9 <= att <= att_hi + 1e-9)
-    goodput_http = parsed["samples"].get(
-        ("goodput_rps", (("engine", replica),)))
-    stats = eng.stats()
-    recorder = eng.dump_flight_recorder()
-    srv.close()
-    slo.close()
-    eng.close()
-    sites = {k: v for k, v in trace_probe.snapshot().items()
-             if k.startswith("serving/")
-             and k.endswith(f"#{eng._eid}")}   # suffix: #1 isn't #12
-    summary["zero_decode_retraces"] = bool(sites) and all(
-        s["traces"] == 1 and not s["causes"] for s in sites.values())
-    summary["preempts"] = stats["preempts"]
-    summary["preempt_rate"] = round(
-        stats["preempts"] / max(1, summary["requests"]), 4)
-    # per-engine compute figures (ISSUE-7): decode-step cost analysis
-    # from the program registry, throughput from the engine's own ring
-    for k in ("model_flops_per_token", "decode_bytes_per_token",
-              "decode_tokens_per_sec", "serving_mfu"):
-        if stats.get(k) is not None:
-            summary[k] = round(stats[k], 4)
-    # NOTE: the summary's ttft_ms/tpot_ms percentiles come from the
-    # MEASURED handles' traces only; engine.stats() latency is not
-    # republished here because its reservoirs also hold the warm-up
-    # requests (whose TTFT contains XLA compile time)
-    summary["flight_recorder_cycles"] = recorder["cycles_recorded"]
-    # the HTTP-measured SLO surface: attainment recomputed from scraped
-    # buckets (upper edge of the bracket) + the scraped goodput gauge —
-    # these land in the artifact so --history gates the WIRE path, not
-    # just the in-process arithmetic
-    summary["slo_attainment_http"] = \
-        round(att_hi, 4) if att_hi is not None else None
-    summary["goodput_rps_http"] = \
-        round(goodput_http, 2) if goodput_http is not None else None
-    summary["slo"] = {
-        "objective": obj_name,
-        "attainment": att,
-        "attainment_http_bracket": [att_lo, att_hi],
-        "scrape_equiv": scrape_equiv,
-        "healthz_ok": healthz_ok,
-        "burn_rate": slo_rep["burn_rate"],
-        "observed": slo_rep["total"],
-        "violations": stats.get("slo_violations"),
-    }
-    if paged_like:
-        summary["prefix_hits"] = stats["prefix_hits"]
-        summary["prefix_hit_ratio"] = round(stats["prefix_hit_ratio"], 4)
-        summary["prefill_tokens_saved"] = stats["prefill_tokens_saved"]
-        summary["prefix_evictions"] = stats["prefix_evictions"]
-        summary["tier_hits"] = stats.get("tier_hits")
-        for k in ("prefix_hit_hbm", "prefix_hit_host", "prefix_miss"):
-            if stats.get(k) is not None:
-                summary[k] = round(stats[k], 4)
-    if kind == "tiered":
-        ht = stats.get("host_tier") or {}
-        summary["host_tier"] = {
-            k: ht.get(k) for k in
-            ("demoted_blocks", "promoted_blocks", "tier_evictions",
-             "dropped_blocks", "promo_shed", "promotion_ms",
-             "demotion_ms")}
-    return summary
-
-
-def _serve_load_http(model, schedule, slo_ms, num_slots=8):
-    """``--serve-load --http``: the front-door leg — the SAME seeded
-    interactive schedule, but every request rides REAL sockets through
-    ``FrontDoor`` (OpenAI-style /v1/completions, SSE streaming), twice:
-
-    * **baseline** — the interactive tenant alone; wire-side TTFT is
-      the stamp of the FIRST SSE chunk arriving at the client;
-    * **flood** — the same schedule again while closed-loop batch
-      tenants hammer the batch lane and an over-budget tenant draws
-      429s off its token bucket.
-
-    The gates: greedy tokens over HTTP byte-identical to an in-process
-    submit, interactive SLO attainment under flood within tolerance of
-    the no-flood baseline with batch throughput > 0 (the weighted-fair
-    admission claim, measured at the socket), per-tenant 429 shed
-    counted in the artifact, and zero decode retraces."""
-    import threading
-    import urllib.error
-    import urllib.request
-
-    import numpy as np
-
-    from paddle_tpu.framework import trace_probe
-    from paddle_tpu.framework.monitor import _percentile
-    from paddle_tpu.serving import FrontDoor, GenerationEngine
-
-    eng = GenerationEngine(model, num_slots=num_slots, max_len=64,
-                           min_bucket=8, kv_layout="paged", block_size=8)
-    # warm every bucket the schedule can touch before the clock starts
-    # (same discipline as the in-process legs)
-    for plen, mnew in ((4, 2), (12, 2), (28, 2), (40, 14)):
-        eng.submit(np.full(plen, 1, np.int32),
-                   max_new_tokens=mnew).result(timeout=600)
-    # no global rate limit — only the deliberately starved tenant sheds
-    door = FrontDoor(eng, tenant_limits={"starved": (10.0, 40.0)})
-    srv = door.start()
-    base = srv.url
-
-    def post(doc, tenant, timeout=600):
-        req = urllib.request.Request(
-            base + "/v1/completions", data=json.dumps(doc).encode(),
-            headers={"Content-Type": "application/json",
-                     "X-Tenant": tenant})
-        try:
-            with urllib.request.urlopen(req, timeout=timeout) as r:
-                return r.status, json.loads(r.read())
-        except urllib.error.HTTPError as e:
-            return e.code, json.loads(e.read())
-
-    def stream_request(doc, tenant, out, timeout=600):
-        """POST stream=true; record wire TTFT (first SSE chunk) and the
-        token ids — the client-side view of the lane."""
-        req = urllib.request.Request(
-            base + "/v1/completions",
-            data=json.dumps(dict(doc, stream=True)).encode(),
-            headers={"Content-Type": "application/json",
-                     "X-Tenant": tenant})
-        t0 = time.perf_counter()
-        try:
-            with urllib.request.urlopen(req, timeout=timeout) as r:
-                t_first, toks, fin = None, [], None
-                for line in r:
-                    if not line.startswith(b"data: "):
-                        continue
-                    payload = line[len(b"data: "):].strip()
-                    if payload == b"[DONE]":
-                        break
-                    if t_first is None:
-                        t_first = time.perf_counter()
-                    chunk = json.loads(payload)["choices"][0]
-                    if chunk["token_id"] is not None:
-                        toks.append(chunk["token_id"])
-                    fin = fin or chunk["finish_reason"]
-            out.append({"ttft_ms": None if t_first is None
-                        else (t_first - t0) * 1e3,
-                        "tokens": toks, "finish": fin})
-        except Exception as e:                           # noqa: BLE001
-            out.append({"error": repr(e)})
-
-    def run_phase(flood: bool):
-        """Drive the interactive schedule open-loop over the wire;
-        with ``flood``, closed-loop batch clients run concurrently."""
-        results, threads = [], []
-        stop = threading.Event()
-        batch_done = [0]
-
-        def batch_client():
-            rng = np.random.RandomState(99)
-            while not stop.is_set():
-                st, _doc = post(
-                    {"prompt": [int(t) for t in
-                                rng.randint(1, 200, 12)],
-                     "max_tokens": 12, "lane": "batch"}, "bulk-corp")
-                if st == 200:
-                    batch_done[0] += 1
-
-        floods = []
-        if flood:
-            floods = [threading.Thread(target=batch_client, daemon=True)
-                      for _ in range(3)]
-            for t in floods:
-                t.start()
-        t_start = time.perf_counter()
-        for off, ids, max_new in schedule:
-            delay = t_start + off - time.perf_counter()
-            if delay > 0:
-                time.sleep(delay)
-            th = threading.Thread(
-                target=stream_request,
-                args=({"prompt": [int(t) for t in ids],
-                       "max_tokens": max_new, "lane": "interactive"},
-                      "alice", results), daemon=True)
-            th.start()
-            threads.append(th)
-        for th in threads:
-            th.join(timeout=600)
-        stop.set()
-        for t in floods:
-            t.join(timeout=600)
-        wall = time.perf_counter() - t_start
-        ok = [r for r in results if "error" not in r
-              and r["ttft_ms"] is not None]
-        ttft = sorted(r["ttft_ms"] for r in ok)
-        good = sum(1 for r in ok if r["ttft_ms"] <= slo_ms)
-        return {"completed": len(ok), "failed": len(results) - len(ok),
-                "wall_sec": round(wall, 3),
-                "ttft_ms": {"p50": round(_percentile(ttft, 0.5), 2),
-                            "p95": round(_percentile(ttft, 0.95), 2),
-                            "count": len(ttft)} if ttft else None,
-                "slo_attainment": round(good / max(1, len(schedule)), 4),
-                "goodput_rps": round(good / wall, 2),
-                "batch_completed": batch_done[0]}
-
-    baseline = run_phase(flood=False)
-    flood = run_phase(flood=True)
-
-    # per-tenant 429 shed: the starved tenant's bucket admits ~1 of
-    # these 40-token requests, the rest draw 429 + Retry-After
-    shed_429 = 0
-    retry_after_ok = True
-    for _ in range(6):
-        st, doc = post({"prompt": [7] * 20, "max_tokens": 20},
-                       "starved")
-        if st == 429:
-            shed_429 += 1
-            retry_after_ok = retry_after_ok and \
-                doc["error"].get("retry_after_s", 0) > 0
-
-    # greedy parity, quiesced: the wire answer IS the in-process answer
-    parity = True
-    for _off, ids, max_new in schedule[:3]:
-        st, doc = post({"prompt": [int(t) for t in ids],
-                        "max_tokens": max_new}, "alice")
-        h = eng.submit(ids, max_new_tokens=max_new, tenant="alice")
-        inproc = [int(t) for t in h.stream()]
-        parity = parity and st == 200 \
-            and doc["choices"][0]["token_ids"] == inproc
-
-    stats = eng.stats()
-    door_stats = door.stats()
-    srv.close()
-    door.close()
-    eng.close()
-    sites = {k: v for k, v in trace_probe.snapshot().items()
-             if k.startswith("serving/")
-             and k.endswith(f"#{eng._eid}")}
-    tol = 0.15                       # shared-box attainment jitter
-    return {
-        "requests": len(schedule),
-        "completed": flood["completed"],
-        "failed": flood["failed"] + baseline["failed"],
-        "shed": shed_429,            # artifact-shape parity with legs
-        "shed_429_per_tenant": door_stats["shed"],
-        "retry_after_present": retry_after_ok,
-        "slo_ms": slo_ms,
-        "baseline": baseline,
-        "flood": flood,
-        "ttft_ms": flood["ttft_ms"],
-        "slo_attainment": flood["slo_attainment"],
-        "goodput_rps": flood["goodput_rps"],
-        "batch_completed": flood["batch_completed"],
-        "wdrr_holds": flood["slo_attainment"]
-        >= baseline["slo_attainment"] - tol
-        and flood["batch_completed"] > 0,
-        "parity": parity,
-        "zero_decode_retraces": bool(sites) and all(
-            s["traces"] == 1 and not s["causes"] for s in sites.values()),
-        "tenants": stats.get("tenants"),
-        "frontdoor": door_stats,
-    }
-
-
-def serve_load():
-    """``bench.py --serve-load``: the serving SLO load harness
-    (OPEN-loop — arrivals follow the seeded clock, never the responses,
-    so queueing collapse shows instead of self-throttling).
-
-    Drives the SAME seeded open-arrival trace (Poisson arrivals, mixed
-    prompt/max_new lengths, a shared system prefix) against a dense and
-    a paged engine over a tiny GPT and writes the measured curve —
-    TTFT/TPOT p50/p95/p99, goodput at the stated latency SLO,
-    preemption/eviction/prefix-hit rates, zero-retrace check — into
-    ``BENCH_serve_load.json``. This is the measurement every future
-    serving claim ("paged admits more", "spec decode is faster")
-    reports against; ROADMAP "Production front door + load harness".
-
-    ``--http`` reroutes the same seeded schedule through the
-    :class:`~paddle_tpu.serving.FrontDoor` over REAL sockets instead —
-    interactive SSE clients racing a batch-lane flood and a
-    rate-limited tenant drawing 429s — and gates on greedy wire/
-    in-process token parity, flood-proof interactive attainment
-    (weighted-fair admission), per-tenant shed counts and zero decode
-    retraces."""
-    import argparse
-
-    import numpy as np
-
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--serve-load", action="store_true")
-    ap.add_argument("--tiered", action="store_true",
-                    help="hierarchical-KV scenario: a rotating-prefix "
-                         "working set that EXCEEDS the device block "
-                         "pool, driven against dense (no cache), "
-                         "HBM-only paged, and tiered (host-DRAM spill) "
-                         "engines — gates on the tiered engine beating "
-                         "both on TTFT p50 and prefill tokens saved at "
-                         "held goodput, with token parity")
-    ap.add_argument("--http", action="store_true",
-                    help="drive the schedule through the HTTP front "
-                         "door over real sockets (mixed-tenant: "
-                         "interactive SSE clients vs a batch-lane "
-                         "flood vs a rate-limited 429 tenant)")
-    ap.add_argument("--rate", type=float, default=32.0,
-                    help="mean arrival rate, requests/sec")
-    ap.add_argument("--requests", type=int, default=48)
-    ap.add_argument("--slo-ms", type=float, default=250.0,
-                    help="TTFT SLO the goodput figure is stated at")
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--slots", type=int, default=8)
-    ap.add_argument("--out", default=os.path.join(
-        HERE, "BENCH_serve_load.json"))
-    args = ap.parse_args()
-
-    import paddle_tpu as paddle
-    from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
-
-    paddle.framework.random.seed(0)
-    cfg = GPTConfig.tiny()
-    model = GPTForPretraining(cfg)
-    model.eval()
-    # two full 8-token blocks: the shareable system preamble
-    system = np.arange(2, 18, dtype=np.int32)
-    schedule = _load_schedule(args.seed, args.requests, args.rate,
-                              system, cfg.vocab_size)
-    out = {"metric": "serve_load_goodput_rps", "unit": "req/s@SLO",
-           "rate_rps": args.rate, "requests": args.requests,
-           "slo_ms": args.slo_ms, "seed": args.seed,
-           "num_slots": args.slots, "engines": {}}
-    try:
-        out["device_kind"] = _device_kind()
-    except Exception:                                  # noqa: BLE001
-        out["device_kind"] = "unknown"
-    if args.tiered:
-        # the working-set-exceeds-HBM scenario (PR 20): 6 rotating
-        # 2-block system preambles = a 12-block prefix working set vs a
-        # 24-block device pool that must also hold the active page
-        # tables — HBM-only churns, tiered spills/promotes
-        out["metric"] = "serve_load_tiered_goodput_rps"
-        if args.out == os.path.join(HERE, "BENCH_serve_load.json"):
-            args.out = os.path.join(HERE, "BENCH_serve_load_tiered.json")
-        # a heavier model than tiny(): recomputing a missed 14-block
-        # system prefix must cost real prefill COMPUTE (a bucket-128
-        # forward), or there is nothing for the hit (HBM or host) to
-        # win back against a few promotion-wait scheduler cycles —
-        # the hit path costs ~3 cycles (request the copy, land it,
-        # emit) regardless of how much prefill it skips, so the
-        # preamble must be long enough that the skipped forward
-        # clearly exceeds that floor
-        paddle.framework.random.seed(0)
-        cfg = GPTConfig(vocab_size=96, hidden_size=512,
-                        num_hidden_layers=6, num_attention_heads=8,
-                        intermediate_size=1024,
-                        max_position_embeddings=160,
-                        hidden_dropout_prob=0.0,
-                        attention_dropout_prob=0.0)
-        model = GPTForPretraining(cfg)
-        model.eval()
-        # 6 rotating 14-block (112-token) preambles = an 84-block
-        # prefix working set against a 64-block device pool: a system
-        # re-appears only after 5 other 14-block chains (70 blocks,
-        # plus the active slots) have churned through, so HBM-only
-        # keeps recomputing the bucket-128 prefill a hit skips.
-        # Shifted mod-94 ramps keep every id inside the vocab while
-        # making all six chains distinct from their first block.
-        systems = [((np.arange(112) + 7 * j) % 94 + 2).astype(np.int32)
-                   for j in range(6)]
-        schedule = _tiered_schedule(args.seed, args.requests, args.rate,
-                                    systems, cfg.vocab_size)
-        # warm the buckets THIS schedule touches: tail-only prefills
-        # (bucket 8), the full-preamble miss (bucket 128) and decode
-        # growth into the deepest page-table bucket
-        tiered_warm = [(4, 2), (120, 8)]
-        legs = {
-            "dense": {"engine_kw": {"max_len": 160}},
-            "paged": {"engine_kw": {"max_len": 160, "num_blocks": 64}},
-            "tiered": {"engine_kw": {"max_len": 160, "num_blocks": 64,
-                                     "host_tier_bytes": 256 << 20}},
-        }
-        outputs = {}
-        for kind, extra in legs.items():
-            sink = outputs.setdefault(kind, [])
-            out["engines"][kind] = _serve_load_engine(
-                kind, model, schedule, args.slo_ms,
-                num_slots=args.slots, outputs_sink=sink,
-                warm=tiered_warm, **extra)
-        t = out["engines"]["tiered"]
-        p = out["engines"]["paged"]
-        d = out["engines"]["dense"]
-        parity = (len(outputs["tiered"]) == len(outputs["paged"])
-                  and all(a is not None and b is not None
-                          and np.array_equal(a, b)
-                          for a, b in zip(outputs["tiered"],
-                                          outputs["paged"])))
-        gates = {
-            "all_served": all(
-                e["completed"] + e["shed"] == e["requests"]
-                and e["failed"] == 0
-                for e in out["engines"].values()),
-            "host_tier_served":
-                (t.get("tier_hits") or {}).get("host", 0) > 0
-                and (t["host_tier"]["promoted_blocks"] or 0) > 0,
-            "tiered_beats_hbm_ttft_p50":
-                t["ttft_ms"]["p50"] < p["ttft_ms"]["p50"],
-            "tiered_beats_dense_ttft_p50":
-                t["ttft_ms"]["p50"] < d["ttft_ms"]["p50"],
-            "tiered_saves_more_prefill":
-                t["prefill_tokens_saved"] > p["prefill_tokens_saved"],
-            "goodput_held":
-                t["goodput_rps"] >= 0.9 * max(p["goodput_rps"],
-                                              d["goodput_rps"]),
-            "token_parity": parity,
-            "zero_decode_retraces": t["zero_decode_retraces"],
-        }
-        out["gates"] = gates
-        out["value"] = t["goodput_rps"]
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=2)
-        print(json.dumps(out), flush=True)
-        sys.exit(0 if all(gates.values()) else 1)
-    if args.http:
-        # the front-door leg subsumes the wire path: the whole seeded
-        # schedule goes through real sockets, mixed-tenant
-        out["engines"]["http"] = _serve_load_http(
-            model, schedule, args.slo_ms, num_slots=args.slots)
-        out["value"] = out["engines"]["http"]["goodput_rps"]
-        h = out["engines"]["http"]
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=2)
-        print(json.dumps(out), flush=True)
-        ok = (h["parity"] and h["wdrr_holds"] and h["shed"] > 0
-              and h["retry_after_present"] and h["completed"] > 0
-              and h["zero_decode_retraces"])
-        sys.exit(0 if ok else 1)
-    for kind in ("dense", "paged"):
-        out["engines"][kind] = _serve_load_engine(
-            kind, model, schedule, args.slo_ms, num_slots=args.slots)
-    out["value"] = out["engines"]["paged"]["goodput_rps"]
-    with open(args.out, "w") as f:
-        json.dump(out, f, indent=2)
-    print(json.dumps(out), flush=True)
-    ok = all(e["completed"] + e["shed"] == e["requests"]
-             and e["failed"] == 0 and e["zero_decode_retraces"]
-             and e["slo"]["scrape_equiv"] and e["slo"]["healthz_ok"]
-             for e in out["engines"].values())
-    sys.exit(0 if ok else 1)
 
 
 # ---------------------------------------------------------------------------
@@ -1723,7 +978,7 @@ PER_METRIC_TOLERANCE = {
 
 def _tolerance_for(name, tolerances, default):
     """Exact name first, then the structural-noise classes: latency
-    PERCENTILES (serve-load '{kind}.ttft_ms.p95' etc.) jitter on shared
+    PERCENTILES jitter on shared
     boxes far beyond the throughput default."""
     if name in tolerances:
         return tolerances[name]
@@ -1734,7 +989,7 @@ def _tolerance_for(name, tolerances, default):
 
 def _load_bench_doc(path):
     """Load a bench artifact: the aggregate JSON line (--dry-run /
-    _emit output saved to a file), a BENCH_serve_load.json document, or
+    _emit output saved to a file) or
     a driver wrapper ({"tail": "<stdout>"} — the artifact is the last
     parseable JSON line of the tail)."""
     with open(path) as f:
@@ -1751,8 +1006,7 @@ def _load_bench_doc(path):
                 continue
         if doc is None:
             raise ValueError(f"{path}: no parseable JSON document")
-    if isinstance(doc, dict) and "tail" in doc and "extras" not in doc \
-            and "engines" not in doc:
+    if isinstance(doc, dict) and "tail" in doc and "extras" not in doc:
         for line in reversed(str(doc["tail"]).strip().splitlines()):
             try:
                 cand = json.loads(line)
@@ -1788,25 +1042,6 @@ def _flatten_bench_doc(doc):
                                      "unit": "ms",
                                      "metric": f"{name}.p95_ms"}
 
-    if isinstance(doc.get("engines"), dict):          # serve-load shape
-        for kind, e in doc["engines"].items():
-            if not isinstance(e, dict):
-                continue
-            for key, unit in (("goodput_rps", "req/s"),
-                              ("slo_attainment", "ratio"),
-                              ("goodput_rps_http", "req/s"),
-                              ("slo_attainment_http", "ratio")):
-                if isinstance(e.get(key), (int, float)):
-                    out[f"{kind}.{key}"] = {
-                        "value": float(e[key]), "unit": unit,
-                        "metric": f"serve_load.{kind}.{key}"}
-            for lat in ("ttft_ms", "tpot_ms"):
-                p95 = (e.get(lat) or {}).get("p95")
-                if isinstance(p95, (int, float)):
-                    out[f"{kind}.{lat}.p95"] = {
-                        "value": float(p95), "unit": "ms",
-                        "metric": f"serve_load.{kind}.{lat}.p95"}
-        return out
     extras = doc.get("extras")
     if isinstance(extras, dict):
         for name, rec in sorted(extras.items()):
@@ -2071,8 +1306,6 @@ def main():
             ("serve", 60, 180.0),
             # compiled static-cache decode throughput
             ("gpt2_decode", 90, child_cap),
-            # gather-vs-fused ragged paged attention (serving decode step)
-            ("attn", 90, child_cap),
             # replicated-vs-ZeRO donated train step + per-replica
             # train-state bytes (needs four chips)
             ("zero", 90, child_cap),
@@ -2119,20 +1352,17 @@ def dry_run():
     a ResNet-class donated train step are ``analyze()``d and must report
     ZERO error-severity findings, the repo self-lint (AST rules over
     paddle_tpu/) must be clean, and the ``analysis/*`` +
-    ``dispatch/retrace_cause`` counters must be populated. PR-4
-    addition: a short continuous-batching serve over the tiny GPT
-    (paddle_tpu/serving/) must complete every request with live
-    ``serving/ttft_ms``/``serving/tokens_per_sec`` metrics, a
-    zero-error ``analyze()`` bill on the decode step, and exactly one
-    trace per capacity bucket. PR-5 addition: the same contract for the
-    PAGED engine (block pool + page tables + prefix cache) — mixed
-    lengths all complete, a repeated system prompt scores
-    ``serving/prefix_hit`` with prefill tokens saved, and each
-    prefill/table bucket traces once. ISSUE-6 addition: a seeded mini
-    serve-load run through the --serve-load harness helpers — request
-    traces complete in lifecycle order with derived TTFT/TPOT,
-    ``serving/tpot_ms`` live, per-engine stats() latency present, the
-    always-on flight recorder non-empty, zero decode retraces. ISSUE-10
+    ``dispatch/retrace_cause`` counters must be populated. The serving
+    canary: a short continuous-batching serve over the tiny GPT
+    (paddle_tpu/serving/) must complete every request token-identical
+    to ``models.generate`` with live
+    ``serving/ttft_ms``/``serving/tokens_per_sec``/``serving/tpot_ms``
+    metrics, a repeated system prompt scoring ``serving/prefix_hit``
+    with prefill tokens saved, a long prompt fed in chunks, a
+    zero-error ``analyze()`` bill on the fused step, exactly one
+    trace per (q, table) bucket, request traces complete in lifecycle
+    order with derived TTFT/TPOT, per-engine stats() latency present
+    and the always-on flight recorder non-empty. ISSUE-10
     addition: the training numerics canary — a clean
     ``fit(numerics='record')`` leaves ``hapi/grad_norm``/
     ``hapi/grad_clip_ratio`` live with ZERO extra compiled programs on
@@ -2245,137 +1475,122 @@ def dry_run():
         gpt_report, resnet_report = _zoo_reports()
         lint_findings = analysis.lint_repo()
 
-        # serving canary (PR-4): a short continuous-batching run over a
-        # tiny GPT — every request completes, the serving/* metrics are
-        # live, the decode step carries a ZERO-error analysis bill
-        # (donation-safe, host-sync-free), and each capacity bucket
-        # traced exactly once (no retrace churn in the serve loop).
+        # serving canary: mixed-length requests through GenerationEngine
+        # — a shared two-block system prompt (prefix hits) and a
+        # 40-token prompt fed in chunks under an 8-token budget. Every
+        # request completes token-identical to per-request
+        # models.generate; the serving/* metrics, the request traces and
+        # the flight recorder are live; the fused step analyzes clean
+        # (donation-safe, host-sync-free) and every (q, table) bucket
+        # traced exactly once. The SLO tracker and the zero-dependency
+        # ops HTTP server (PR 16) ride the same engine. Counts and
+        # parity only: what a cycle costs is benchmark/run.py's, on the
+        # chip.
         def _serving_canary():
+            import urllib.error
+            import urllib.request
+
             from paddle_tpu.framework import trace_probe
+            from paddle_tpu.framework.metrics import parse_prometheus
+            from paddle_tpu.models import generate
             from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
-            from paddle_tpu.serving import GenerationEngine
+            from paddle_tpu.serving import (GenerationEngine, OpsServer,
+                                            SLOTracker)
 
             paddle.framework.random.seed(0)
             model = GPTForPretraining(GPTConfig.tiny())
             model.eval()
-            eng = GenerationEngine(model, num_slots=4, max_len=48,
-                                   min_bucket=8)
-            prompts = [np.arange(1, 1 + n, dtype=np.int32)
-                       for n in (3, 9, 5, 12, 7, 4)]
-            handles = [eng.submit(p, max_new_tokens=5) for p in prompts]
-            done = [h.result(timeout=300) for h in handles]
-            report = eng.analyze()
-            eng.close()
-            sites = {k: v for k, v in trace_probe.snapshot().items()
-                     if k.startswith("serving/")}
-            one_trace = bool(sites) and all(
-                s["traces"] == 1 and not s["causes"]
-                for s in sites.values())
-            # snapshot the process-global serving counters BEFORE the
-            # paged canary adds its own requests to them
-            return (len(done), report, one_trace,
-                    monitor.stat_get("serving/completed"),
-                    monitor.stat_get("serving/requests"))
-
-        (served, serving_report, serving_one_trace, served_completed,
-         served_requests) = _serving_canary()
-
-        # paged canary (PR-5): mixed-length requests through a PAGED
-        # engine — all complete, a repeated system prompt scores prefix
-        # hits (prefill skipped, tokens saved), the paged decode step
-        # analyzes clean, and every prefill/table bucket traced exactly
-        # once (sites are per-engine, filtered by its id).
-        def _paged_canary():
-            from paddle_tpu.framework import trace_probe
-            from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
-            from paddle_tpu.serving import GenerationEngine
-
-            paddle.framework.random.seed(0)
-            model = GPTForPretraining(GPTConfig.tiny())
-            model.eval()
-            eng = GenerationEngine(model, num_slots=4, max_len=48,
-                                   min_bucket=8, kv_layout="paged",
-                                   block_size=8)
+            eng = GenerationEngine(model, num_slots=4, max_len=64,
+                                   block_size=8, prefill_budget=8)
+            slo = SLOTracker(name="dryrun_slo")
+            # CPU-scale SLO: the canary asserts the measurement works,
+            # not that an untuned CPU backend meets a production SLO
+            slo.add_objective("ttft_canary", metric="ttft_ms",
+                              target_ms=60_000.0, goal=0.95)
+            slo.attach_engine(eng)
+            srv = OpsServer(target=eng, slo=slo).start()
             system = np.arange(2, 18, dtype=np.int32)     # two full blocks
-            # the system prompt's blocks are computed once...
-            eng.submit(np.concatenate([system, [30]]),
-                       max_new_tokens=4).result(timeout=300)
-            # ...then served from the prefix cache under mixed lengths
-            prompts = [np.concatenate([system,
-                                       np.arange(40, 40 + n,
-                                                 dtype=np.int32)])
-                       for n in (1, 5, 9, 2)] \
-                + [np.arange(1, 1 + n, dtype=np.int32) for n in (3, 7)]
-            handles = [eng.submit(p, max_new_tokens=5) for p in prompts]
-            done = [h.result(timeout=300) for h in handles]
+            prompts = [np.concatenate([system, [30]])] \
+                + [np.concatenate([system, np.arange(40, 40 + n,
+                                                     dtype=np.int32)])
+                   for n in (1, 5, 9, 2)] \
+                + [np.arange(1, 1 + n, dtype=np.int32) for n in (3, 7)] \
+                + [np.arange(2, 42, dtype=np.int32)]   # chunks at budget 8
+            # the system prompt's blocks are computed once, then served
+            # from the prefix cache under mixed lengths
+            handles = [eng.submit(prompts[0], max_new_tokens=5)]
+            handles[0].result(timeout=300)
+            handles += [eng.submit(p, max_new_tokens=5)
+                        for p in prompts[1:]]
+            outs = [h.result(timeout=300) for h in handles]
+            n = len(prompts)
+            prom_samples = parse_prometheus(urllib.request.urlopen(
+                srv.url + "/metrics", timeout=30).read().decode())["samples"]
+            slo_live = any(name == "slo_attainment"
+                           for name, _labels in prom_samples)
+            healthz_live = urllib.request.urlopen(
+                srv.url + "/healthz", timeout=30).status == 200
+            tracez = json.loads(urllib.request.urlopen(
+                srv.url + "/tracez", timeout=30).read().decode())
+            tail = next(iter(tracez["engines"].values()))
+            tracez_ok = (len(tail["recent"]) == n
+                         and tracez["slo"]["objectives"]
+                         ["ttft_canary"]["total"] == n)
             report = eng.analyze()
+            recorder = eng.dump_flight_recorder()
             stats = eng.stats()
             eng.close()
+            # a closed engine flips /healthz to 503 while the server
+            # itself (and /statusz) stays up
+            try:
+                urllib.request.urlopen(srv.url + "/healthz", timeout=30)
+                healthz_flips = False
+            except urllib.error.HTTPError as e:
+                healthz_flips = e.code == 503
+            srv.close()
+            slo.close()
             sites = {k: v for k, v in trace_probe.snapshot().items()
                      if k.startswith("serving/")
                      and k.endswith(f"#{eng._eid}")}
-            one_trace = bool(sites) and all(
-                s["traces"] == 1 and not s["causes"]
-                for s in sites.values())
-            return len(done), report, one_trace, stats
-
-        paged_served, paged_report, paged_one_trace, paged_stats = \
-            _paged_canary()
-
-        # fused canary (ISSUE 8): the SAME mixed-length prompts through
-        # GenerationEngine(attention="fused") — the fused ragged-paged-
-        # attention Pallas step (interpret mode on this CPU backend)
-        # must be SELECTED, produce token-identical output to the
-        # gather engine (the correctness oracle), chunk a long prompt
-        # under a tight prefill budget, analyze clean, and trace once
-        # per (q, table) bucket.
-        def _fused_canary():
-            from paddle_tpu.framework import trace_probe
-            from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
-            from paddle_tpu.serving import GenerationEngine
-
-            paddle.framework.random.seed(0)
-            model = GPTForPretraining(GPTConfig.tiny())
-            model.eval()
-            prompts = [np.arange(1, 1 + n, dtype=np.int32)
-                       for n in (3, 9, 17, 5)] \
-                + [np.arange(2, 42, dtype=np.int32)]   # chunks at budget 8
-            outs = {}
-            for kind in ("gather", "fused"):
-                eng = GenerationEngine(model, num_slots=4, max_len=64,
-                                       min_bucket=8, kv_layout="paged",
-                                       block_size=8, attention=kind,
-                                       prefill_budget=8)
-                handles = [eng.submit(p, max_new_tokens=5)
-                           for p in prompts]
-                outs[kind] = [h.result(timeout=300) for h in handles]
-                if kind == "fused":
-                    report = eng.analyze()
-                    stats = eng.stats()
-                    sites = {k: v
-                             for k, v in trace_probe.snapshot().items()
-                             if k.startswith("serving/fused")
-                             and k.endswith(f"#{eng._eid}")}
-                eng.close()
-            parity = all(np.array_equal(a, b) for a, b in
-                         zip(outs["gather"], outs["fused"]))
-            one_trace = bool(sites) and all(
-                s["traces"] == 1 and not s["causes"]
-                for s in sites.values())
             return {
-                "parity": parity,
-                # evidence of the fused path actually serving: fused
-                # (q, table)-bucket probe sites recorded traces (the
-                # stats()["attention"] field merely echoes the ctor arg)
-                "selected": bool(sites) and all(
-                    s["traces"] >= 1 for s in sites.values()),
+                "requests": n,
+                "completed": monitor.stat_get("serving/completed"),
+                "submitted": monitor.stat_get("serving/requests"),
+                "parity": all(
+                    np.array_equal(o, generate(
+                        model, p[None, :], max_new_tokens=5).numpy()[0])
+                    for p, o in zip(prompts, outs)),
                 "report": report,
-                "one_trace": one_trace,
-                "prefill_chunks": stats["prefill_chunks"],
-                "chunk_tokens": stats["chunked_prefill_tokens"],
+                # the fused (q, table) programs are the ONLY serving
+                # programs, each traced once
+                "one_trace": bool(sites) and all(
+                    k.startswith("serving/fused[")
+                    and s["traces"] == 1 and not s["causes"]
+                    for k, s in sites.items()),
+                "stats": stats,
+                "traces_complete": all(
+                    h.trace.completed
+                    and h.trace.t("submit") <= h.trace.t("admitted")
+                    <= h.trace.t("first_token") <= h.trace.finished_at
+                    and h.trace.ttft_ms is not None
+                    for h in handles),
+                "engine_latency_present":
+                    stats["ttft_ms"] is not None
+                    and stats["tpot_ms"] is not None
+                    and stats["ttft_ms"]["count"] == n,
+                "flight_recorder_nonempty":
+                    len(recorder["cycles"]) > 0
+                    and len(recorder["events"]) > 0,
+                # PR-16 ops surface: live scrape over HTTP carried the
+                # SLO series, health answered 200 then flipped 503 on
+                # close, tracez served the tail-sampled traces
+                "ops_scrape": len(prom_samples) > 0 and slo_live,
+                "ops_healthz": healthz_live and healthz_flips,
+                "ops_tracez": tracez_ok,
+                "ops_goodput": (stats.get("goodput_rps") or 0) > 0,
             }
 
-        fused_canary = _fused_canary()
+        serving_canary = _serving_canary()
 
         # ISSUE-12 speculative-decoding canary: the same greedy
         # workload through the plain fused engine and a speculating one
@@ -2398,8 +1613,7 @@ def dry_run():
             accept_before = monitor.stat_get("serving/spec_accept")
             for kind in ("plain", "spec"):
                 eng = GenerationEngine(
-                    model, num_slots=4, max_len=64, kv_layout="paged",
-                    block_size=8, attention="fused", prefill_budget=16,
+                    model, num_slots=4, max_len=64, block_size=8, prefill_budget=16,
                     spec_draft=model if kind == "spec" else None,
                     spec_k=3)
                 handles = [eng.submit(p, max_new_tokens=6)
@@ -2422,10 +1636,9 @@ def dry_run():
                         k: v for k, v in sites.items()
                         if k.startswith("serving/spec[")}
                 eng.close()
-            # int8 blocks over the same prompts (gather path: no
-            # block-size floor), vs the plain outputs
+            # int8 blocks over the same prompts (at the block size
+            # their tile needs), vs the plain outputs
             eng = GenerationEngine(model, num_slots=4, max_len=64,
-                                   kv_layout="paged", block_size=8,
                                    kv_dtype="int8")
             handles = [eng.submit(p, max_new_tokens=6) for p in prompts]
             int8_outs = [h.result(timeout=300) for h in handles]
@@ -2458,105 +1671,6 @@ def dry_run():
             }
 
         spec_canary = _spec_canary()
-
-        # serve-load canary (ISSUE 6): a seeded mini open-arrival run
-        # through the SAME harness --serve-load uses — every trace
-        # completes in lifecycle order, TTFT/TPOT derive per request,
-        # the serving/tpot_ms histogram is live, the flight recorder's
-        # rings are non-empty and the engine's decode never retraced.
-        def _serve_load_canary():
-            import urllib.error
-            import urllib.request
-
-            from paddle_tpu.framework import trace_probe
-            from paddle_tpu.framework.metrics import parse_prometheus
-            from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
-            from paddle_tpu.serving import (GenerationEngine, OpsServer,
-                                            SLOTracker)
-
-            paddle.framework.random.seed(0)
-            cfg = GPTConfig.tiny()
-            m = GPTForPretraining(cfg)
-            m.eval()
-            system = np.arange(2, 18, dtype=np.int32)
-            schedule = _load_schedule(seed=7, n=6, rate=200.0,
-                                      system=system, vocab=cfg.vocab_size)
-            eng = GenerationEngine(m, num_slots=4, max_len=64,
-                                   min_bucket=8)
-            # ops-surface canary (PR 16): the SLO tracker observes the
-            # canary traffic, the zero-dependency HTTP server boots on
-            # an ephemeral port and serves a live scrape + health
-            slo = SLOTracker(name="dryrun_slo")
-            slo.add_objective("ttft_canary", metric="ttft_ms",
-                              target_ms=60_000.0, goal=0.95)
-            slo.attach_engine(eng)
-            srv = OpsServer(target=eng, slo=slo).start()
-            # CPU-scale SLO: the canary asserts the measurement works,
-            # not that an untuned CPU backend meets a production SLO
-            summary, handles = _run_serve_load(eng, schedule,
-                                               slo_ms=60_000.0)
-            prom_text = urllib.request.urlopen(
-                srv.url + "/metrics", timeout=30).read().decode()
-            prom_samples = parse_prometheus(prom_text)["samples"]
-            slo_live = any(n == "slo_attainment"
-                           for n, _labels in prom_samples)
-            healthz_live = urllib.request.urlopen(
-                srv.url + "/healthz", timeout=30).status == 200
-            tracez = json.loads(urllib.request.urlopen(
-                srv.url + "/tracez", timeout=30).read().decode())
-            tail = next(iter(tracez["engines"].values()))
-            tracez_ok = (len(tail["recent"]) == len(schedule)
-                         and tracez["slo"]["objectives"]
-                         ["ttft_canary"]["total"] == len(schedule))
-            recorder = eng.dump_flight_recorder()
-            stats = eng.stats()
-            eng.close()
-            # a closed engine flips /healthz to 503 while the server
-            # itself (and /statusz) stays up
-            try:
-                urllib.request.urlopen(srv.url + "/healthz", timeout=30)
-                healthz_flips = False
-            except urllib.error.HTTPError as e:
-                healthz_flips = e.code == 503
-            srv.close()
-            slo.close()
-            sites = {k: v for k, v in trace_probe.snapshot().items()
-                     if k.startswith("serving/")
-                     and k.endswith(f"#{eng._eid}")}
-            traces_ok = summary["completed"] == len(schedule) and all(
-                h.trace.completed
-                and h.trace.t("submit") <= h.trace.t("admitted")
-                <= h.trace.t("first_token") <= h.trace.finished_at
-                and h.trace.ttft_ms is not None
-                for h in handles)
-            return {
-                "traces_complete": traces_ok,
-                "summary": summary,
-                # ISSUE-7: per-engine compute figures derived from the
-                # decode step's program-registry cost analysis
-                "flops_per_token": stats.get("model_flops_per_token"),
-                "bytes_per_token": stats.get("decode_bytes_per_token"),
-                "serving_mfu": stats.get("serving_mfu"),
-                "engine_latency_present":
-                    stats["ttft_ms"] is not None
-                    and stats["tpot_ms"] is not None
-                    and stats["ttft_ms"]["count"] == len(schedule),
-                "flight_recorder_nonempty":
-                    len(recorder["cycles"]) > 0
-                    and len(recorder["events"]) > 0,
-                "zero_retraces": bool(sites) and all(
-                    s["traces"] == 1 and not s["causes"]
-                    for s in sites.values()),
-                # PR-16 ops surface: live scrape over HTTP carried the
-                # SLO series, health answered 200 then flipped 503 on
-                # close, tracez served the tail-sampled traces
-                "ops_scrape": len(prom_samples) > 0 and slo_live,
-                "ops_healthz": healthz_live and healthz_flips,
-                "ops_tracez": tracez_ok,
-                "ops_goodput": (stats.get("goodput_rps") or 0) > 0,
-            }
-
-        serve_load_canary = _serve_load_canary()
 
         # front-door canary (PR 19): the OpenAI-style /v1/completions
         # surface on an ephemeral port — one non-streamed request whose
@@ -2660,7 +1774,7 @@ def dry_run():
                 m.eval()
                 eng = GenerationEngine(
                     m, num_slots=2, max_len=48, min_bucket=8,
-                    kv_layout="paged", block_size=8, num_blocks=8,
+                    block_size=8, num_blocks=8,
                     host_tier_bytes=tier_bytes)
                 system = np.arange(2, 18, dtype=np.int32)  # 2 blocks
                 outs = [eng.submit(np.concatenate([system, [40]]),
@@ -2863,8 +1977,8 @@ def dry_run():
                 m = GPTForPretraining(cfg)
                 m.eval()
                 eng = GenerationEngine(m, num_slots=2, max_len=48,
-                                       kv_layout="paged", block_size=8,
-                                       attention="fused", mesh=mesh)
+                                       block_size=8,
+                                       mesh=mesh)
                 hs = [eng.submit(p, max_new_tokens=8) for p in prompts]
                 outs = [h.result(timeout=600) for h in hs]
                 blocks = eng.stats()["kv_bytes"]["blocks"]
@@ -3027,8 +2141,7 @@ def dry_run():
             gate = {"raised": False, "peak_point": None, "plan": None}
             try:
                 GenerationEngine(m2, num_slots=4, max_len=48,
-                                 min_bucket=8, kv_layout="paged",
-                                 block_size=8,
+                                 min_bucket=8, block_size=8,
                                  hbm_budget_bytes=64 * 1024)
             except PlanError as e:
                 gate = {"raised": True,
@@ -3040,8 +2153,7 @@ def dry_run():
             gate_extra_compiles = monitor.stat_get("compile/count") - c0
 
             eng = GenerationEngine(m2, num_slots=4, max_len=48,
-                                   min_bucket=8, kv_layout="paged",
-                                   block_size=8,
+                                   min_bucket=8, block_size=8,
                                    hbm_budget_bytes=1 << 33)
             generous_plan = eng._plan
             eng.close()
@@ -3137,39 +2249,28 @@ def dry_run():
         "retrace_cause_recorded":
             monitor.stat_get("dispatch/retrace_cause") > 0,
         "selflint_clean": not lint_findings,
-        # PR-4 serving surface: the continuous batcher completed every
-        # canary request, its metrics are live, its decode step analyzes
-        # clean and each capacity bucket traced exactly once
-        "serving_completed": served == 6 and served_completed == 6,
+        # serving surface: every canary request completed token-identical
+        # to models.generate, the serving/* metrics are live, the
+        # repeated system prompt hit the prefix cache (whole blocks of
+        # tokens saved), the 40-token prompt chunked under the 8-token
+        # budget (>= 5 launches), the fused step analyzes clean and every
+        # (q, table) bucket traced once
+        "serving_completed":
+            serving_canary["completed"] == serving_canary["requests"],
+        "serving_parity": serving_canary["parity"],
         "serving_counters_live":
             monitor.stat_histogram("serving/ttft_ms") is not None
             and monitor.stat_histogram("serving/tokens_per_sec")
             is not None
-            and served_requests == 6,
-        "serving_decode_clean": serving_report.ok(),
-        "serving_one_trace_per_bucket": serving_one_trace,
-        # PR-5 paged surface: mixed lengths through the paged engine all
-        # complete, the repeated system prompt hits the prefix cache
-        # (prefill skipped, whole blocks of tokens saved), the paged
-        # decode step analyzes clean and every bucket traced once
-        "paged_completed": paged_served == 6,
-        "paged_prefix_hit":
-            monitor.stat_get("serving/prefix_hit") > 0
-            and paged_stats["prefill_tokens_saved"] > 0
-            and paged_stats["prefix_hit_ratio"] > 0,
-        "paged_decode_clean": paged_report.ok(),
-        "paged_one_trace_per_bucket": paged_one_trace,
-        # ISSUE-8 fused surface: the fused ragged-paged-attention step
-        # was SELECTED (not silently fallen back), its greedy output is
-        # token-identical to the gather oracle, a long prompt chunked
-        # under the 8-token budget (>= 5 launches), the fused step
-        # analyzes clean, and every (q, table) bucket traced once
-        "fused_selected": fused_canary["selected"],
-        "fused_parity": fused_canary["parity"],
-        "fused_chunked_prefill": fused_canary["prefill_chunks"] >= 5
-        and fused_canary["chunk_tokens"] >= 40,
-        "fused_step_clean": fused_canary["report"].ok(),
-        "fused_one_trace_per_bucket": fused_canary["one_trace"],
+            and serving_canary["submitted"] == serving_canary["requests"],
+        "serving_prefix_hit":
+            serving_canary["stats"]["prefix_hits"] >= 4
+            and serving_canary["stats"]["prefill_tokens_saved"] >= 4 * 16,
+        "serving_chunked_prefill":
+            serving_canary["stats"]["prefill_chunks"] >= 5
+            and serving_canary["stats"]["chunked_prefill_tokens"] >= 40,
+        "serving_step_clean": serving_canary["report"].ok(),
+        "serving_one_trace_per_bucket": serving_canary["one_trace"],
         # ISSUE-12 speculative decoding + int8 KV blocks: greedy spec
         # output token-identical to the plain fused engine (cold AND
         # warm waves), serving/spec_accept live with tokens/cycle > 1
@@ -3189,28 +2290,26 @@ def dry_run():
         # tests/test_serving_paging.py::TestQuantizedBlocks
         "spec_int8_agrees": spec_canary["int8_dtype"] == "int8"
         and spec_canary["int8_token_agreement"] >= 0.75,
-        # ISSUE-6 serving observability: the mini serve-load run's
-        # traces all completed in lifecycle order, the per-token decode
-        # cadence histogram is live, per-engine stats() latency derives
-        # from the engine's own traces, and the always-on flight
-        # recorder captured cycles + events without the profiler
-        "serve_load_traces_complete":
-            serve_load_canary["traces_complete"],
-        "serve_load_tpot_live":
+        # ISSUE-6 serving observability: the canary's request traces all
+        # completed in lifecycle order, the per-token decode cadence
+        # histogram is live, per-engine stats() latency derives from the
+        # engine's own traces, and the always-on flight recorder
+        # captured cycles + events without the profiler
+        "serving_traces_complete": serving_canary["traces_complete"],
+        "serving_tpot_live":
             monitor.stat_histogram("serving/tpot_ms") is not None
-            and serve_load_canary["engine_latency_present"],
-        "serve_load_flight_recorder":
-            serve_load_canary["flight_recorder_nonempty"],
-        "serve_load_zero_retraces": serve_load_canary["zero_retraces"],
+            and serving_canary["engine_latency_present"],
+        "serving_flight_recorder":
+            serving_canary["flight_recorder_nonempty"],
         # PR-16 SLO plane: the ops HTTP server booted on an ephemeral
         # port and served a live Prometheus scrape carrying the SLO
         # series, /healthz answered 200 live and flipped 503 once the
         # engine closed, /tracez served the tail-sampled traces + SLO
         # report, and the engine published SLO-gated goodput
-        "ops_server_scrape": serve_load_canary["ops_scrape"],
-        "ops_server_healthz": serve_load_canary["ops_healthz"],
-        "ops_server_tracez": serve_load_canary["ops_tracez"],
-        "ops_server_goodput": serve_load_canary["ops_goodput"],
+        "ops_server_scrape": serving_canary["ops_scrape"],
+        "ops_server_healthz": serving_canary["ops_healthz"],
+        "ops_server_tracez": serving_canary["ops_tracez"],
+        "ops_server_goodput": serving_canary["ops_goodput"],
         # PR-19 HTTP front door: the non-streamed wire answer is
         # byte-identical to the in-process greedy submit, the SSE frame
         # sequence is well-formed and token-exact, the rate-limited
@@ -3238,8 +2337,7 @@ def dry_run():
             monitor.stat_histogram("hapi/flops_per_sec") is not None
             and monitor.stat_histogram("hapi/mfu") is not None,
         "serving_flops_per_token":
-            (serve_load_canary.get("flops_per_token") or 0) > 0
-            and paged_stats.get("model_flops_per_token", 0) > 0,
+            serving_canary["stats"].get("model_flops_per_token", 0) > 0,
         "memory_ledger_live":
             sum(mem_ledger.values()) > 0
             and any(k.startswith("hapi/state") and k.endswith("/params")
@@ -3313,12 +2411,8 @@ def dry_run():
     if not gpt_report.ok() or not resnet_report.ok():
         print(gpt_report.table(), file=sys.stderr)
         print(resnet_report.table(), file=sys.stderr)
-    if not serving_report.ok():
-        print(serving_report.table(), file=sys.stderr)
-    if not paged_report.ok():
-        print(paged_report.table(), file=sys.stderr)
-    if not fused_canary["report"].ok():
-        print(fused_canary["report"].table(), file=sys.stderr)
+    if not serving_canary["report"].ok():
+        print(serving_canary["report"].table(), file=sys.stderr)
     if not planner_canary["crosscheck_ok"]:
         for site, cc in planner_canary["crosschecks"].items():
             print(f"PLANNER {'ok ' if cc['ok'] else 'FAIL'} {site}: "
@@ -3340,18 +2434,15 @@ def dry_run():
                           for k, v in counters.items()
                           if k.startswith("dispatch/retrace_cause/")},
                       "selflint_findings": len(lint_findings),
-                      "serving_requests": served_requests,
-                      "paged_prefix_hits":
-                          monitor.stat_get("serving/prefix_hit"),
-                      "paged_tokens_saved":
-                          monitor.stat_get("serving/prefill_tokens_saved"),
-                      "fused_prefill_chunks":
-                          fused_canary["prefill_chunks"],
-                      "fused_chunk_tokens": fused_canary["chunk_tokens"],
+                      "serving": {
+                          "requests": serving_canary["requests"],
+                          **{k: serving_canary["stats"][k] for k in
+                             ("prefix_hits", "prefill_tokens_saved",
+                              "prefill_chunks", "chunked_prefill_tokens",
+                              "model_flops_per_token")}},
                       "spec": {k: spec_canary[k] for k in
                                ("accept_rate", "tokens_per_cycle",
                                 "int8_token_agreement")},
-                      "serve_load": serve_load_canary["summary"],
                       "frontdoor": frontdoor_canary["stats"],
                       "tiered": {k: tiered_canary[k] for k in
                                  ("host_hits", "demoted", "promoted",
@@ -3387,10 +2478,6 @@ def dry_run():
                           int(monitor.stat_get("compile/count")),
                       "hapi_mfu": (monitor.stat_histogram("hapi/mfu")
                                    or {}).get("p50"),
-                      "serving_flops_per_token":
-                          serve_load_canary.get("flops_per_token"),
-                      "paged_flops_per_token":
-                          paged_stats.get("model_flops_per_token"),
                       "memory_ledger_bytes": sum(mem_ledger.values()),
                       "compare_gate_rc": {"self": rc_self,
                                           "regression": rc_regress},
@@ -3406,12 +2493,6 @@ if __name__ == "__main__":
         run_compare(sys.argv[1:])
     elif "--history" in sys.argv[1:]:
         run_history(sys.argv[1:])
-    elif "--serve-load" in sys.argv[1:]:
-        serve_load()
-    elif "--bench-attn" in sys.argv[1:]:
-        # standalone gather-vs-fused microbench: one JSON line, same
-        # schema as the child result that lands in the round artifact
-        print("RESULT " + json.dumps(bench_attn()))
     elif "--bench-zero" in sys.argv[1:]:
         # standalone replicated-vs-ZeRO microbench (same child schema);
         # needs >= 4 devices — on CPU run under

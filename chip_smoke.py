@@ -18,9 +18,8 @@ calls, at GPT-2 124M (``GPTConfig.gpt2_small()``: 12 layers, hidden 768,
 * **eager** — README-quickstart steps (``loss.backward(); opt.step();
   opt.clear_grad()``) on the full model: what runs ``fused_adamw`` on the
   (50304, 768) embedding.
-* **serve** — ``FrontDoor(GenerationEngine(kv_layout="paged",
-  attention="fused"))`` with a pool that takes a real share of HBM, real
-  HTTP on loopback (plain and SSE ``/v1/completions``, two prompts sharing
+* **serve** — ``FrontDoor(GenerationEngine(...))`` with a pool that takes
+  a real share of HBM, real HTTP on loopback (plain and SSE ``/v1/completions``, two prompts sharing
   a 256-token prefix, ``/metrics``), every completion compared with a
   plain full-sequence forward + argmax that shares neither the engine's
   kernel nor its cache.
@@ -514,7 +513,7 @@ def phase_serve(cfg, *, max_len: int, block_size: int, num_slots: int,
     before = site_names()
     m0 = memory_stats()
     engine = GenerationEngine(
-        model, kv_layout="paged", attention="fused", block_size=block_size,
+        model, block_size=block_size,
         max_len=max_len, num_slots=num_slots, num_blocks=num_blocks)
     m1 = memory_stats()
     st = engine.stats()
@@ -640,7 +639,7 @@ def phase_axk1_serve(model: dict, *, dtype: str, max_len: int,
                      limits: dict, width: int, q_block: int) -> dict:
     """A.X-K1 (latent attention, routed experts; ``model`` is the
     ``model`` group of a benchmark configuration, cut to a toy DEPTH)
-    through ``GenerationEngine(kv_layout="paged", attention="fused")``:
+    through ``GenerationEngine``:
     chunked prefill and decode over the latent paged cache, then every
     served token's logit against the plain reference's best
     (``benchmark/lib/reference_axk1.py``), held to the configuration's
@@ -658,8 +657,7 @@ def phase_axk1_serve(model: dict, *, dtype: str, max_len: int,
     prompts = [rng.randint(1, int(model["vocab_size"]), size=n).tolist()
                for n in prompt_lens]
     before = site_names()
-    with GenerationEngine(net, kv_layout="paged", attention="fused",
-                          block_size=block_size, max_len=max_len,
+    with GenerationEngine(net, block_size=block_size, max_len=max_len,
                           num_slots=num_slots, num_blocks=num_blocks,
                           prefill_budget=prefill_budget) as engine:
         handles = [engine.submit(p, new_tokens) for p in prompts]
@@ -736,8 +734,7 @@ def phase_tp_serve(cfg, devices, *, max_len: int, block_size: int,
     model = GPTForPretraining(cfg)
     model.eval()
     prompts = _seeded_prompts(cfg, prompt_lens, 400)
-    geometry = dict(kv_layout="paged", attention="fused",
-                    block_size=block_size, max_len=max_len,
+    geometry = dict(block_size=block_size, max_len=max_len,
                     num_slots=num_slots, num_blocks=num_blocks)
 
     single = GenerationEngine(model, **geometry)

@@ -44,7 +44,7 @@ def main():
     cfg = GPTConfig.tiny()
     model = GPTForPretraining(cfg)
     model.eval()
-    eng = GenerationEngine(model, num_slots=4, max_len=64, min_bucket=8)
+    eng = GenerationEngine(model, num_slots=4, max_len=64)
 
     # the SLO plane: objectives are latency targets + attainment goals;
     # CPU-demo targets are generous — the point is the measurement

@@ -7,33 +7,30 @@ budgets. With ``--mp N`` the whole engine serves TENSOR-PARALLEL
 (``GenerationEngine(mesh=)``): Megatron weight layout, the paged KV
 pool head-partitioned over an N-way mesh, every step a shard_map — so
 each device holds 1/N of the KV bytes (the per-device pool stats line
-at the end shows it; implies ``--paged``). Each client streams its tokens as they are produced; the demo
+at the end shows it). Each client streams its tokens as they are produced; the demo
 prints per-client time-to-first-token and the engine-wide throughput —
 the two serving numbers that matter, straight from the monitor
 histograms the engine maintains (``serving/ttft_ms``,
 ``serving/tokens_per_sec``).
 
 Why this beats gather-and-run batching for generation: requests join
-and leave the in-flight batch EVERY decode step (continuous batching
-over a slot-based KV pool), so a client asking for 4 tokens is never
-held hostage by one asking for 48.
+and leave the in-flight batch EVERY cycle (continuous batching over a
+paged KV pool, one fused ragged launch a cycle), so a client asking for
+4 tokens is never held hostage by one asking for 48.
 
-With ``--paged`` the engine swaps the dense per-slot KV stripes for the
-block-granular paged pool: every client shares the same block-aligned
-system preamble, so after the first request prefills it, clients whose
-own prompt fits one prefill bucket are PREFIX-CACHE HITS that skip
-prefill entirely (a longer tail prefills fresh — replay costs a decode
-cycle per token, see serving/engine.py) — watch ``prefix_hit_ratio``
-and ``prefill_tokens_saved`` in the end-of-run ``engine.stats()``
-report.
+Every client shares the same block-aligned system preamble, so after
+the first request has fed it, the others are PREFIX-CACHE HITS that
+adopt its blocks and feed only their own tail, in chunks mixed into the
+decode launches — watch ``prefix_hit_ratio``, ``prefill_tokens_saved``
+and the chunk counters in the end-of-run ``engine.stats()`` report.
 
-With ``--spec`` (implies ``--fused``) a 2-layer draft sharing the
+With ``--spec`` a 2-layer draft sharing the
 target's embeddings proposes ``--spec-k`` tokens per slot per cycle and
 the target verifies them all in ONE fused ragged launch — watch the
 ``spec accept rate`` and ``tokens/cycle`` lines: an agreeing draft
 multiplies decode throughput without changing a single output token
 (greedy speculative output is token-identical by construction). With
-``--kv-dtype int8`` the paged pool stores quantized blocks with
+``--kv-dtype int8`` the pool stores quantized blocks with
 per-block max-abs scales, so the same device byte budget admits ~4x
 the blocks — the ``block capacity`` line shows the same-budget
 comparison against fp32.
@@ -45,8 +42,7 @@ surface — the operational view every flag above feeds.
 
 Usage:
     python examples/serve_gpt2.py [--clients 12] [--slots 8] [--mp 2]
-                                  [--paged] [--fused] [--spec]
-                                  [--kv-dtype int8]
+                                  [--spec] [--kv-dtype int8]
                                   [--statusz] [--prom metrics.prom]
 """
 import argparse
@@ -125,21 +121,15 @@ def main():
     ap.add_argument("--mp", type=int, default=1,
                     help="tensor-parallel ways (<= visible devices)")
     ap.add_argument("--train-steps", type=int, default=40)
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV blocks + prefix cache instead of "
-                         "dense per-slot stripes")
-    ap.add_argument("--fused", action="store_true",
-                    help="fused ragged-paged-attention Pallas step + "
-                         "chunked prefill (implies --paged)")
     ap.add_argument("--spec", action="store_true",
                     help="speculative decoding: a 2-layer draft sharing "
                          "the target's embeddings proposes --spec-k "
                          "tokens per cycle, verified in one fused "
-                         "ragged launch (implies --fused)")
+                         "ragged launch")
     ap.add_argument("--spec-k", type=int, default=4)
     ap.add_argument("--kv-dtype", default=None,
                     choices=["float32", "int8"],
-                    help="paged KV block storage dtype; int8 stores "
+                    help="KV block storage dtype; int8 stores "
                          "quantized blocks with per-block max-abs "
                          "scales (~4x blocks per byte budget)")
     ap.add_argument("--statusz", action="store_true",
@@ -152,59 +142,31 @@ def main():
                          "whole metrics surface (registry + monitor "
                          "bridge) to FILE after the run")
     args = ap.parse_args()
-    if args.spec:
-        args.fused = True
-    if args.fused:
-        args.paged = True
     if args.mp > 1:
-        # the tensor-parallel engine serves from the head-sharded
-        # paged pool — dense stripes have no sharded step builders,
-        # and the spec/int8 compositions are not sharded yet
-        args.paged = True
+        # the spec/int8 compositions are not sharded yet
         if args.spec:
             ap.error("--mp does not compose with --spec yet (no "
                      "sharded draft/verify builders)")
         if args.kv_dtype == "int8":
             ap.error("--mp does not compose with --kv-dtype int8 yet "
                      "(block scales have no head-sharded layout)")
-    if args.kv_dtype and not args.paged:
-        ap.error("--kv-dtype requires --paged/--fused/--spec (quantized "
-                 "blocks live in the paged pool)")
-
     paddle.seed(0)
     model = build_model(args.train_steps)
     mesh = make_mesh(args.mp)
 
-    if args.paged:
-        # min_bucket 16 also bounds the prefix-hit replay: a hit is
-        # taken when a prompt's uncovered tail fits one min_bucket.
-        # max_len 128 keeps the pow2 bucket ladder (16..128) feasible
-        # for every prompt/max_new the clients draw — on the 16/32/64
-        # ladder a worst re-admission feed past 64 tokens would have
-        # no bucket and submit() would reject it.
-        # int8 on the FUSED path needs block_size >= 32 (the Mosaic
-        # int8 sublane count of the kernel's KV scratch); the gather
-        # path has no such floor
-        block_size = 32 if (args.kv_dtype == "int8" and args.fused) \
-            else 8
-        engine = GenerationEngine(
-            model, num_slots=args.slots, max_len=128,
-            min_bucket=max(16, block_size),
-            kv_layout="paged", block_size=block_size,
-            attention="fused" if args.fused else "gather",
-            kv_dtype=args.kv_dtype,
-            spec_draft="auto" if args.spec else None,
-            spec_k=args.spec_k, mesh=mesh)
-    else:
-        engine = GenerationEngine(model, num_slots=args.slots, max_len=96,
-                                  min_bucket=8)
+    # 8-token blocks where the kernel takes them; an int8 tile needs 32
+    engine = GenerationEngine(
+        model, num_slots=args.slots, max_len=128,
+        block_size=32 if args.kv_dtype == "int8" else 8,
+        kv_dtype=args.kv_dtype,
+        spec_draft="auto" if args.spec else None,
+        spec_k=args.spec_k, mesh=mesh)
     # a shared system preamble every client prepends — exactly three
-    # full 8-token blocks, so on the paged engine it is computed once
-    # and then served whole from the prefix cache
+    # full 8-token blocks, so it is computed once and then served whole
+    # from the prefix cache
     system = np.frombuffer(b"the quick brown fox jump", np.uint8) \
-        .astype(np.int32) if args.paged else None
-    print(f"\nserving with {args.slots} slots "
-          f"({'paged' if args.paged else 'dense'} KV), "
+        .astype(np.int32)
+    print(f"\nserving with {args.slots} slots, "
           f"{args.clients} concurrent clients (mixed lengths):")
 
     lines, lock = [], threading.Lock()
@@ -213,8 +175,7 @@ def main():
         rng = np.random.RandomState(i)
         text = PROMPTS[i % len(PROMPTS)]
         ids = np.frombuffer(text, np.uint8).astype(np.int32)
-        if system is not None:
-            ids = np.concatenate([system, ids])
+        ids = np.concatenate([system, ids])
         max_new = int(rng.randint(4, 25))
         t0 = time.perf_counter()
         ttft, toks = None, []
@@ -267,50 +228,45 @@ def main():
           f"p95 {tpot.get('p95', 0):.2f} ms")
     # the operator snapshot: one call instead of scraping serving/*
     # monitor counters by prefix
-    print(f"engine.stats(): layout={stats['kv_layout']} "
-          f"queue={stats['queue_depth']} "
+    print(f"engine.stats(): queue={stats['queue_depth']} "
           f"active={stats['active_requests']} "
           f"slots={stats['slots_in_use']}/{stats['num_slots']} "
           f"preempts={stats['preempts']}")
-    if args.paged:
-        print(f"  paged: blocks {stats['kv_blocks_in_use']}"
-              f"/{stats['num_blocks']} x{stats['block_size']}, "
-              f"cached {stats['cached_blocks']}, "
-              f"prefix hit ratio {stats['prefix_hit_ratio']:.2f} "
-              f"({stats['prefix_hits']} hit / "
-              f"{stats['prefix_misses']} miss), "
-              f"prefill tokens saved {stats['prefill_tokens_saved']}")
+    print(f"  paged: blocks {stats['kv_blocks_in_use']}"
+          f"/{stats['num_blocks']} x{stats['block_size']}, "
+          f"cached {stats['cached_blocks']}, "
+          f"prefix hit ratio {stats['prefix_hit_ratio']:.2f} "
+          f"({stats['prefix_hits']} hit / "
+          f"{stats['prefix_misses']} miss), "
+          f"prefill tokens saved {stats['prefill_tokens_saved']}")
     if stats.get("mp"):
         print(f"  tensor-parallel: mp={stats['mp']} "
               f"('{stats['mp_axis']}' axis), per-device KV pool "
               f"{stats['kv_bytes_per_device'] // 1024} KiB "
               f"(1/{stats['mp']} of the single-device bytes)")
-    if args.fused:
-        print(f"  fused: attention={stats['attention']}, "
-              f"prefill chunks {stats['prefill_chunks']} "
-              f"({stats['chunked_prefill_tokens']} tokens chunked)")
+    print(f"  fused: prefill chunks {stats['prefill_chunks']} "
+          f"({stats['chunked_prefill_tokens']} tokens chunked)")
     if args.spec:
         print(f"  spec: accept rate {stats['spec_accept_rate']:.2f} "
               f"({stats['spec_accepted']}/{stats['spec_proposed']} "
               f"draft tokens), "
               f"tokens/cycle {stats.get('spec_tokens_per_cycle', 1.0):.2f} "
               f"(k={stats['spec_k']}, draft {stats['draft_layers']}L)")
-    if args.paged:
-        # same-byte-budget capacity: how many blocks THIS pool's budget
-        # would buy at fp32 vs its actual dtype — the quantized-KV
-        # "more requests per pool" line
-        from paddle_tpu.serving import PagedKVPool
-        budget = stats["kv_pool_capacity_bytes"]
-        pool = engine._pool
-        fp32_blocks = PagedKVPool.blocks_within_budget(
-            budget, num_layers=pool.num_layers,
-            num_heads=pool.num_heads, block_size=pool.block_size,
-            head_dim=pool.head_dim, dtype="float32")
-        print(f"  block capacity: {stats['num_blocks']} x "
-              f"{stats['block_size']}-token {stats['kv_dtype']} blocks "
-              f"in {budget // 1024} KiB "
-              f"(same budget at fp32: {fp32_blocks} blocks, "
-              f"{stats['num_blocks'] / max(1, fp32_blocks):.1f}x)")
+    # same-byte-budget capacity: how many blocks THIS pool's budget
+    # would buy at fp32 vs its actual dtype — the quantized-KV
+    # "more requests per pool" line
+    from paddle_tpu.serving import PagedKVPool
+    budget = stats["kv_pool_capacity_bytes"]
+    pool = engine._pool
+    fp32_blocks = PagedKVPool.blocks_within_budget(
+        budget, num_layers=pool.num_layers,
+        num_heads=pool.num_heads, block_size=pool.block_size,
+        head_dim=pool.head_dim, dtype="float32")
+    print(f"  block capacity: {stats['num_blocks']} x "
+          f"{stats['block_size']}-token {stats['kv_dtype']} blocks "
+          f"in {budget // 1024} KiB "
+          f"(same budget at fp32: {fp32_blocks} blocks, "
+          f"{stats['num_blocks'] / max(1, fp32_blocks):.1f}x)")
 
 
 if __name__ == "__main__":

@@ -50,9 +50,12 @@ def main():
     cfg = GPTConfig.tiny()
     model = GPTForPretraining(cfg)
     model.eval()
-    eng = GenerationEngine(model, num_slots=4, max_len=64, min_bucket=8)
+    eng = GenerationEngine(model, num_slots=4, max_len=64)
 
-    door = FrontDoor(eng, tenant_limits={"starved": (5.0, 15.0)})
+    # 15 tokens of burst cover ONE of the starved tenant's requests; at
+    # half a token a second the bucket does not refill another within
+    # the run, however slow the (interpreted, on a CPU) kernels are
+    door = FrontDoor(eng, tenant_limits={"starved": (0.5, 15.0)})
     srv = door.start()
     print(f"front door live at {srv.url}  "
           f"(POST /v1/completions beside GET /metrics)")

@@ -98,9 +98,8 @@ class FusedMultiHeadAttention(Layer):
         at [t_b, t_b+S) with a per-row causal horizon, so sequences at
         DIFFERENT positions decode in one batch. This is the
         CacheKV-layout counterpart of the continuous-batching serving
-        engine's decode step (models/generation.py
-        ``build_slot_decode_fn``, which applies the same contract over
-        the pooled 6-D ``serving.KVCachePool`` layout) — the engine does
+        engine's step (models/generation.py ``build_fused_step_fn``,
+        over the paged ``serving.PagedKVPool``) — the engine does
         NOT call through here; both are pinned to ``generate()``'s
         semantics by their own parity tests. Queries attend
         causally to slots <= their own, intersected with any caller
@@ -170,8 +169,8 @@ class FusedMultiHeadAttention(Layer):
         <= starts[b]+i. One trace serves every position mix (starts is
         traced), which is what lets a continuous batcher decode
         sequences of different lengths in one program. (The serving
-        engine itself implements this contract over its pooled layout in
-        ``build_slot_decode_fn``; this is the incubate-API twin.)"""
+        engine's own step is ``build_fused_step_fn`` over a paged pool;
+        this is the incubate API's dense-cache form.)"""
         import jax.numpy as jnp
 
         from ....framework.tensor import Tensor

@@ -512,8 +512,7 @@ class AXK1Layer(nn.Layer):
 class AXK1ForCausalLM(nn.Layer):
     """A.X-K1 with its untied head. ``forward(input_ids [B, S])`` ->
     float32 logits ``[B, S, V]`` (no cache, naive attention);
-    ``serving_decoder()`` is what ``GenerationEngine(kv_layout="paged",
-    attention="fused")`` consumes."""
+    ``serving_decoder()`` is what ``GenerationEngine`` consumes."""
 
     def __init__(self, cfg: AXK1Config, dtype="float32",
                  param_init: Optional[Callable] = None):

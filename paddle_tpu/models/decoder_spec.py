@@ -1,7 +1,7 @@
 """The decoder spec: what the fused serving stack needs to know of a
 decoder-only model, and nothing else (ROADMAP D2).
 
-``GenerationEngine(kv_layout="paged", attention="fused")``, the fused
+``GenerationEngine``, the fused
 tower (``models/generation.py:_fused_tower``), ``PagedKVPool`` and the
 engine's memory planner consume THIS, not ``model.gpt``: a model is
 served by the fused paged path if ``serving_decoder()`` returns an

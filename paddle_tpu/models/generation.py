@@ -29,14 +29,8 @@ from ..framework.tensor import Tensor, no_grad_guard
 
 __all__ = ["GenerationConfig", "generate", "save_for_serving",
            "shard_params_megatron", "megatron_param_specs",
-           "build_slot_prefill_fn",
-           "build_slot_decode_fn", "build_paged_prefill_fn",
-           "build_paged_decode_fn", "build_fused_step_fn",
-           "build_sharded_paged_prefill_fn",
-           "build_sharded_paged_decode_fn",
-           "build_sharded_fused_step_fn",
-           "build_draft_prefill_fn", "build_draft_propose_fn",
-           "build_draft_propose_scan_fn",
+           "build_fused_step_fn", "build_sharded_fused_step_fn",
+           "build_draft_prefill_fn", "build_draft_propose_scan_fn",
            "build_spec_verify_fn", "make_draft_model"]
 
 
@@ -562,179 +556,11 @@ def _build_beam_fn(model, batch, prompt_len, static_key):
 
 
 # ---------------------------------------------------------------------------
-# slot-pool step functions (the continuous-batching serving decode path;
-# consumed by paddle_tpu/serving/ — see serving/engine.py)
+# the serving steps (consumed by paddle_tpu/serving/engine.py)
 # ---------------------------------------------------------------------------
 
-def build_slot_prefill_fn(model, bucket_len, max_len, top_k=0, top_p=1.0,
-                          probe=None):
-    """Build the per-bucket prefill step of the slot-based serving engine.
-
-    Returns ``fn(params, buffers, pool, ids, key_valid, slot, sample,
-    temperature, key) -> (pool, first_token, key)``:
-
-    * ``pool`` — the shared KV pool ``[layers, 2, slots, heads, max_len,
-      head_dim]`` (``serving.KVCachePool.data``); the new prompt's K/V
-      are written into row ``slot`` at time indices ``[0, bucket_len)``
-      with one ``dynamic_update_slice`` per layer (``slot`` is traced, so
-      ONE trace serves every slot);
-    * ``ids`` ``[1, bucket_len]`` int32 — the prompt LEFT-padded to the
-      capacity bucket; ``key_valid`` ``[1, bucket_len]`` bool marks the
-      real tokens (the exact ragged-prompt contract of ``generate``);
-    * ``sample``/``temperature`` are traced scalars: greedy and sampled
-      first-token picks share the single compiled program;
-    * the caller jits with ``donate_argnums`` on ``pool`` so the update
-      is in place.
-
-    ``probe`` is an optional ``framework.trace_probe`` site recorded at
-    trace time (the dispatch/retrace_cause idiom): one trace per
-    capacity bucket is this function's whole point, and the probe makes
-    a violation visible in the counters.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ..framework import trace_probe as _probe
-    from ..nn.layer.layers import functional_state
-
-    gpt = model.gpt if hasattr(model, "gpt") else model
-    Lb = int(bucket_len)
-    if Lb < 1:
-        raise ValueError(f"bucket_len must be >= 1, got {Lb}")
-    if Lb > int(max_len):
-        raise ValueError(f"bucket_len {Lb} exceeds pool max_len {max_len}")
-    if Lb > gpt.cfg.max_position_embeddings:
-        raise ValueError(
-            f"bucket_len {Lb} exceeds max_position_embeddings="
-            f"{gpt.cfg.max_position_embeddings}")
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
-
-    def fn(params, buffers, pool, ids, key_valid, slot, sample,
-           temperature, key):
-        if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, ids, key_valid]),
-                         {"bucket": Lb})
-        with functional_state(model, params, buffers):
-            with no_grad_guard():
-                caches = gpt.init_cache(1, Lb, pool.dtype)
-                hidden, caches = gpt.prefill(
-                    Tensor(ids, stop_gradient=True), caches,
-                    key_valid=key_valid)
-                logits = gpt.logits(hidden)._data[:, 0].astype(jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p, 1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature)
-                first = jnp.where(sample, sampled, greedy)
-                z = jnp.int32(0)
-                s = jnp.asarray(slot, jnp.int32).reshape(())
-                new_pool = pool
-                for li, (ck, cv) in enumerate(caches):
-                    # ck/cv [1, Lb, H, Dh] -> the pool's [H, Lb, Dh] rows
-                    kvb = jnp.stack([jnp.swapaxes(ck[0], 0, 1),
-                                     jnp.swapaxes(cv[0], 0, 1)])
-                    new_pool = lax.dynamic_update_slice(
-                        new_pool, kvb[None, :, None].astype(new_pool.dtype),
-                        (jnp.int32(li), z, s, z, z, z))
-        return new_pool, first, key
-
-    return fn
-
-
-def build_slot_decode_fn(model, num_slots, max_len, top_k=0, top_p=1.0,
-                         probe=None):
-    """Build THE decode step of the slot-based serving engine: one jitted
-    program advancing every pool slot by one token per call.
-
-    Returns ``fn(params, buffers, pool, tokens, pos, lo, sample_mask,
-    temperature, key) -> (pool, next_tokens, key)`` over the shared KV
-    pool ``[layers, 2, slots, heads, max_len, head_dim]``
-    (``next_tokens`` is ``[slots + 1]``: the per-slot tokens plus the
-    logits-finite sentinel of :func:`_append_nonfinite_flag`):
-
-    * ``tokens`` ``[slots]`` int32 — each slot's last emitted token; its
-      K/V are written at cache index ``pos[slot]`` with a per-slot
-      scatter (slots at DIFFERENT positions decode together — the
-      continuous-batching core, the Ragged-Paged-Attention shape);
-    * ``lo`` ``[slots]`` int32 — first valid cache index per slot (the
-      left-pad offset of its capacity bucket): attention sees exactly
-      ``[lo, pos]``, and position embeddings count logical tokens
-      ``pos - lo``, matching ``generate``'s ragged-prompt semantics
-      token for token;
-    * ``sample_mask``/``temperature`` ``[slots]`` are traced, so mixed
-      greedy/sampled request batches share the ONE compiled program
-      (sampling reuses :func:`_pick_token`); inactive slots compute
-      garbage that the scheduler ignores and the next prefill
-      overwrites.
-
-    The caller jits with ``donate_argnums`` on ``pool``; the engine's
-    ``analyze()`` must report this program donation-safe and
-    host-sync-free (the PR-3 clean-bill contract).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..framework import trace_probe as _probe
-    from ..nn import functional as F
-    from ..nn.layer.layers import functional_state
-
-    gpt = model.gpt if hasattr(model, "gpt") else model
-    S = int(num_slots)
-    L = int(max_len)
-    if S < 1:
-        raise ValueError(f"num_slots must be >= 1, got {S}")
-    if L > gpt.cfg.max_position_embeddings:
-        raise ValueError(
-            f"max_len {L} exceeds max_position_embeddings="
-            f"{gpt.cfg.max_position_embeddings}")
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
-
-    def fn(params, buffers, pool, tokens, pos, lo, sample_mask,
-           temperature, key):
-        if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, tokens, pos, lo,
-                                        temperature]), {"slots": S})
-        with functional_state(model, params, buffers):
-            with no_grad_guard():
-                logical = (pos - lo)[:, None]
-                x = gpt.wte(Tensor(tokens[:, None], stop_gradient=True)) \
-                    + gpt.wpe(Tensor(logical))
-                r = jnp.arange(L)
-                key_valid = (r[None, :] >= lo[:, None]) \
-                    & (r[None, :] <= pos[:, None])
-                mask = Tensor(key_valid[:, None, None, :])
-                sl = jnp.arange(S)
-                new_pool = pool
-                for li, block in enumerate(gpt.blocks):
-                    q, k, v = block._qkv(x)
-                    kh = k._data[:, 0].astype(new_pool.dtype)  # [S, H, Dh]
-                    vh = v._data[:, 0].astype(new_pool.dtype)
-                    # per-slot scatter: slot i's row at time index pos[i]
-                    new_pool = new_pool.at[li, 0, sl, :, pos, :].set(kh)
-                    new_pool = new_pool.at[li, 1, sl, :, pos, :].set(vh)
-                    k_full = Tensor(jnp.swapaxes(new_pool[li, 0], 1, 2),
-                                    stop_gradient=True)  # [S, L, H, Dh]
-                    v_full = Tensor(jnp.swapaxes(new_pool[li, 1], 1, 2),
-                                    stop_gradient=True)
-                    a = F.scaled_dot_product_attention(
-                        q, k_full, v_full, attn_mask=mask)
-                    x = block._tail(x, a)
-                x = gpt.ln_f(x)
-                logits = gpt.logits(x)._data[:, 0].astype(jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p, 1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature[:, None])
-                nxt = jnp.where(sample_mask, sampled, greedy)
-                nxt = _append_nonfinite_flag(nxt, logits)
-        return new_pool, nxt, key
-
-    return fn
-
-
 def _append_nonfinite_flag(nxt, logits):
-    """Append the per-cycle logits-finite sentinel to the decode step's
+    """Append the per-cycle logits-finite sentinel to the fused step's
     token row: element ``[num_slots]`` is 1 when ANY logit this cycle is
     NaN/Inf, else 0. It rides the scheduler's existing one-per-cycle
     ``_fetch`` (the token indexing ``toks[slot]`` never reaches it), so
@@ -760,11 +586,9 @@ def _append_nonfinite_flag(nxt, logits):
 #   verify step; _mp_fused_tower: its tensor-parallel twin), full-attention
 #   layers, unquantized pool -> the Pallas kernel ops/kv_append.py: only
 #   real rows, a token's block for all heads in one DMA each way;
-# * the gather steps (build_paged_decode_fn and its sharded twin) ->
-#   _write_rows, the XLA scatter. They are the correctness oracle the fused
-#   path is tested against (tests/test_ragged_attention.py), so they stay
-#   independent of the kernel they check;
-# * quantized pools -> _quant_append (a block requantize, then _write_rows);
+# * quantized pools -> _quant_append (a block requantize, then _write_rows,
+#   the XLA scatter — which is also the reference ops/kv_append.py is
+#   tested against, bit for bit: tests/test_kv_append.py);
 #   latent pools -> _write_latent_rows (one row a token: ~0.5 ms a launch
 #   as a scatter, PERF.md finding 29.2). Their needs differ; nothing is
 #   shared by force.
@@ -782,9 +606,9 @@ def _write_rows(pool, li, wb, off, k_rows, v_rows):
     pool: ``k_rows``/``v_rows [N, H, Dh]`` land at ``(block wb[n], head
     h, offset off[n])``.
 
-    The gather steps' append, and the quantized append's last step; the
-    fused towers write through ``ops/kv_append.py`` (the comment above
-    ``_kv_lanes``). As a scatter this is ``N x H`` updates of one row,
+    The quantized append's last step, and the reference of
+    ``ops/kv_append.py``, which the fused towers write through (the
+    comment above ``_kv_lanes``). As a scatter this is ``N x H`` updates of one row,
     which XLA's TPU scatter walks one by one, pad rows included (0.86 ms
     a layer at 512 rows x 20 heads, PERF.md PR 30).
 
@@ -809,23 +633,6 @@ def _scale_lanes(sc, dh):
     scale over the V lanes)."""
     import jax.numpy as jnp
     return jnp.repeat(jnp.moveaxis(sc, 0, -1), dh, axis=-1)
-
-
-def _gather_kv(pool, scales, li, tables):
-    """Gather-path read of the pool: materialize the virtual cache of
-    layer ``li`` through the page table, ``tables [S, T]`` -> ``(k, v)``
-    each ``[S, T * bs, H, Dh]``. A quantized pool (``scales`` given) is
-    dequantized AFTER the pool read — the per-block scales are
-    multiplied back in, f32 out."""
-    import jax.numpy as jnp
-    g = pool[li][tables]                          # [S, T, H, bs, 2*Dh]
-    S, T, H, bs, dh2 = g.shape
-    dh = dh2 // 2
-    if scales is not None:
-        g = g.astype(jnp.float32) * _scale_lanes(
-            scales[li][:, tables], dh)[..., None, :]
-    g = jnp.transpose(g, (0, 1, 3, 2, 4)).reshape(S, T * bs, H, dh2)
-    return g[..., :dh], g[..., dh:]
 
 
 def _quant_append(pool, scales, li, wb, off, k_rows, v_rows, qmax):
@@ -862,244 +669,6 @@ def _quant_append(pool, scales, li, wb, off, k_rows, v_rows, qmax):
                     -qmax, qmax).astype(pool.dtype)
     pool = _write_rows(pool, li, wb, off, qrow[0], qrow[1])
     return pool, scales.at[li].set(new)
-
-
-def _quant_write_blocks(pool, scales, li, table, k_vals, v_vals, qmax):
-    """Whole-block quantized write (the paged prefill path):
-    ``k_vals``/``v_vals [Tp, H, bs, Dh]`` replace the blocks named by
-    ``table [Tp]``, each with a fresh per-(block, head) max-abs scale —
-    freshly allocated blocks have no prior content worth rescaling.
-    Returns ``(pool, scales)``."""
-    import jax.numpy as jnp
-    vals = jnp.stack([k_vals, v_vals]).astype(jnp.float32)
-    sc = jnp.max(jnp.abs(vals), axis=(-2, -1)) / qmax      # [2, Tp, H]
-    denom = jnp.maximum(sc, 1e-30)[..., None, None]
-    q = jnp.clip(jnp.round(jnp.where(sc[..., None, None] > 0,
-                                     vals / denom, 0.0)),
-                 -qmax, qmax).astype(pool.dtype)
-    pool = pool.at[li, table].set(_kv_lanes(q[0], q[1]))
-    return pool, scales.at[li].set(scales[li].at[:, table].set(sc))
-
-
-# ---------------------------------------------------------------------------
-# paged step functions (block-pooled KV with page tables and prefix reuse;
-# consumed by paddle_tpu/serving/paging.py — see serving/engine.py)
-# ---------------------------------------------------------------------------
-
-def build_paged_prefill_fn(model, bucket_len, block_size, top_k=0,
-                           top_p=1.0, probe=None, quantized=False,
-                           qmax=127.0):
-    """Build the per-bucket prefill step of the PAGED serving engine.
-
-    Returns ``fn(params, buffers, pool, ids, key_valid, table, plen,
-    sample, temperature, key) -> (pool, first_token, key)`` — with
-    ``quantized=True`` (``PagedKVPool(dtype="int8")``) the per-block
-    scale array is threaded alongside the pool: ``fn(params, buffers,
-    pool, scales, ids, ...) -> (pool, scales, first_token, key)``, the
-    K/V computed in the model dtype and written through
-    :func:`_quant_write_blocks`:
-
-    * ``pool`` — the block pool ``[layers, num_blocks + 1, heads,
-      block_size, 2 * head_dim]`` (``serving.PagedKVPool.data``); the
-      prompt's K/V are scattered block-wise through ``table``
-      ``[bucket_len // block_size]`` int32 (physical block per virtual
-      block; 0 = the scratch block for entries past the allocation);
-    * ``ids`` ``[1, bucket_len]`` int32 — the prompt RIGHT-padded to
-      the capacity bucket (paged sequences are aligned at virtual
-      index 0, the property that makes blocks shareable across
-      requests); ``key_valid`` ``[1, bucket_len]`` bool marks real
-      tokens; ``plen`` is the TRACED real length — the first-token
-      logits come from hidden position ``plen - 1``, so one trace
-      serves every prompt length in the bucket;
-    * ``sample``/``temperature`` are traced scalars, exactly the
-      slot-prefill contract; the caller jits with ``donate_argnums``
-      on ``pool``.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ..framework import trace_probe as _probe
-    from ..nn.layer.layers import functional_state
-
-    gpt = model.gpt if hasattr(model, "gpt") else model
-    Lb, bs = int(bucket_len), int(block_size)
-    if Lb < 1:
-        raise ValueError(f"bucket_len must be >= 1, got {Lb}")
-    if bs < 1 or Lb % bs:
-        raise ValueError(
-            f"bucket_len {Lb} must be a positive multiple of "
-            f"block_size {bs}")
-    if Lb > gpt.cfg.max_position_embeddings:
-        raise ValueError(
-            f"bucket_len {Lb} exceeds max_position_embeddings="
-            f"{gpt.cfg.max_position_embeddings}")
-    Tp = Lb // bs
-    H = gpt.cfg.num_attention_heads
-    Dh = gpt.cfg.hidden_size // H
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
-
-    def fn(params, buffers, pool, *rest):
-        (scales, ids, key_valid, table, plen, sample, temperature,
-         key) = rest if quantized else (None,) + rest
-        if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, ids, key_valid, table]),
-                         {"bucket": Lb, "table": Tp})
-        with functional_state(model, params, buffers):
-            with no_grad_guard():
-                # right-padded: reals count 0,1,2,..., pads repeat the
-                # last real position (their K/V are masked garbage that
-                # lands in the scratch block or gets overwritten by the
-                # decode steps that reach those virtual indices)
-                pos_ids = Tensor(jnp.maximum(
-                    jnp.cumsum(key_valid.astype(jnp.int32), axis=1) - 1,
-                    0))
-                x = gpt.wte(Tensor(ids, stop_gradient=True)) \
-                    + gpt.wpe(pos_ids)
-                # quantized pools keep the layer-local K/V in the model
-                # dtype; quantization happens at block-write time
-                cdt = x._data.dtype if quantized else pool.dtype
-                new_pool, new_scales = pool, scales
-                for li, block in enumerate(gpt.blocks):
-                    ck = jnp.zeros((1, Lb, H, Dh), cdt)
-                    cv = jnp.zeros((1, Lb, H, Dh), cdt)
-                    x, ck, cv = block.prefill(x, ck, cv,
-                                              key_valid=key_valid)
-                    # [1, Lb, H, Dh] -> per-block [Tp, H, bs, Dh] rows
-                    kb = jnp.transpose(ck[0].reshape(Tp, bs, H, Dh),
-                                       (0, 2, 1, 3))
-                    vb = jnp.transpose(cv[0].reshape(Tp, bs, H, Dh),
-                                       (0, 2, 1, 3))
-                    if quantized:
-                        new_pool, new_scales = _quant_write_blocks(
-                            new_pool, new_scales, li, table, kb, vb,
-                            qmax)
-                    else:
-                        new_pool = new_pool.at[li, table].set(
-                            _kv_lanes(kb, vb))
-                x = gpt.ln_f(x)
-                z = jnp.int32(0)
-                p = jnp.asarray(plen, jnp.int32).reshape(())
-                last = lax.dynamic_slice(
-                    x._data, (z, p - 1, z), (1, 1, x._data.shape[-1]))
-                logits = gpt.logits(Tensor(last))._data[:, 0].astype(
-                    jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p, 1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature)
-                first = jnp.where(sample, sampled, greedy)
-        if quantized:
-            return new_pool, new_scales, first, key
-        return new_pool, first, key
-
-    return fn
-
-
-def build_paged_decode_fn(model, num_slots, table_len, block_size,
-                          top_k=0, top_p=1.0, probe=None,
-                          quantized=False, qmax=127.0,
-                          debug_logits=False):
-    """Build the per-table-bucket decode step of the PAGED serving
-    engine: gather-based paged attention over the block table.
-
-    Returns ``fn(params, buffers, pool, tokens, pos, lo, tables,
-    sample_mask, temperature, key) -> (pool, next_tokens, key)`` over
-    the block pool ``[layers, num_blocks + 1, heads, block_size,
-    2 * head_dim]`` (``next_tokens`` ``[slots + 1]`` — the last element
-    is the logits-finite sentinel, see :func:`_append_nonfinite_flag`):
-
-    * ``tables`` ``[slots, table_len]`` int32 — each slot's page table
-      padded with 0 (the scratch block) to the pow2 table bucket; the
-      new token's K/V are scattered at physical block
-      ``tables[s, pos[s] // block_size]``, offset ``pos[s] %
-      block_size`` (the per-slot scatter of the dense step, routed
-      through the page table);
-    * attention runs over the GATHERED virtual cache
-      ``pool[li][tables]`` reshaped to ``[slots, table_len *
-      block_size, heads, head_dim]`` with the ``[lo, pos]`` mask and
-      logical positions ``pos - lo`` unchanged from the dense step —
-      scratch-block garbage is masked, never NaN;
-    * ``sample_mask``/``temperature`` are traced (one program serves
-      mixed greedy/sampled batches via :func:`_pick_token`); the
-      caller jits with ``donate_argnums`` on ``pool``, and the
-      engine's ``analyze()`` must report the program donation-safe and
-      host-sync-free;
-    * ``quantized=True`` (``PagedKVPool(dtype="int8")``) threads the
-      per-block scale array beside the pool (``fn(params, buffers,
-      pool, scales, tokens, ...) -> (pool, scales, next_tokens,
-      key)``): appends go through :func:`_quant_append` and the
-      gathered virtual cache is dequantized by :func:`_gather_kv`
-      — the ``[lo, pos]`` mask, sentinel and sampling are unchanged.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..framework import trace_probe as _probe
-    from ..nn import functional as F
-    from ..nn.layer.layers import functional_state
-
-    gpt = model.gpt if hasattr(model, "gpt") else model
-    S, T, bs = int(num_slots), int(table_len), int(block_size)
-    if S < 1:
-        raise ValueError(f"num_slots must be >= 1, got {S}")
-    if T < 1:
-        raise ValueError(f"table_len must be >= 1, got {T}")
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
-
-    def fn(params, buffers, pool, *rest):
-        (scales, tokens, pos, lo, tables, sample_mask, temperature,
-         key) = rest if quantized else (None,) + rest
-        if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, tokens, pos, lo, tables,
-                                        temperature]),
-                         {"slots": S, "table": T})
-        with functional_state(model, params, buffers):
-            with no_grad_guard():
-                logical = (pos - lo)[:, None]
-                x = gpt.wte(Tensor(tokens[:, None], stop_gradient=True)) \
-                    + gpt.wpe(Tensor(logical))
-                r = jnp.arange(T * bs)
-                key_valid = (r[None, :] >= lo[:, None]) \
-                    & (r[None, :] <= pos[:, None])
-                mask = Tensor(key_valid[:, None, None, :])
-                sl = jnp.arange(S)
-                wb = tables[sl, pos // bs]        # write block per slot
-                off = pos % bs
-                new_pool, new_scales = pool, scales
-                for li, block in enumerate(gpt.blocks):
-                    q, k, v = block._qkv(x)
-                    if quantized:
-                        new_pool, new_scales = _quant_append(
-                            new_pool, new_scales, li, wb, off,
-                            k._data[:, 0], v._data[:, 0], qmax)
-                    else:
-                        new_pool = _write_rows(
-                            new_pool, li, wb, off, k._data[:, 0],
-                            v._data[:, 0])
-                    # gather the virtual cache through the page table
-                    kf, vf = _gather_kv(new_pool, new_scales, li, tables)
-                    if quantized:
-                        kf = kf.astype(k._data.dtype)
-                        vf = vf.astype(v._data.dtype)
-                    a = F.scaled_dot_product_attention(
-                        q, Tensor(kf, stop_gradient=True),
-                        Tensor(vf, stop_gradient=True), attn_mask=mask)
-                    x = block._tail(x, a)
-                x = gpt.ln_f(x)
-                logits = gpt.logits(x)._data[:, 0].astype(jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p, 1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature[:, None])
-                nxt = jnp.where(sample_mask, sampled, greedy)
-                nxt = _append_nonfinite_flag(nxt, logits)
-        extra = (logits,) if debug_logits else ()
-        if quantized:
-            return (new_pool, new_scales, nxt) + extra + (key,)
-        return (new_pool, nxt) + extra + (key,)
-
-    return fn
 
 
 def _write_latent_rows(pool, li, wb, off, rows):
@@ -1172,10 +741,9 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     advances a RAGGED batch of mixed prefill-chunk and decode rows
     through every layer with the fused paged-attention Pallas kernel
     (ops/ragged_paged_attention.py) — no gathered KV window, the kernel
-    walks each sequence's page table directly in HBM. This is the
-    ``GenerationEngine(attention="fused")`` decode/chunk step; the
-    gather-based :func:`build_paged_decode_fn` stays as the correctness
-    oracle.
+    walks each sequence's page table directly in HBM. This is
+    ``GenerationEngine``'s step; ``models.generate`` is the oracle its
+    greedy output is held to, token for token.
 
     Returns ``fn(params, buffers, pool, token_ids, qpos, write_block,
     write_off, blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
@@ -1206,8 +774,7 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
       with ``donate_argnums`` on ``pool`` and the engine's ``analyze()``
       must report the program donation-safe and host-sync-free.
 
-    One trace per ``(q_rows bucket, table bucket)`` — the fused twin of
-    the prefill/table pow2 bucket discipline, watched by ``probe``.
+    One trace per ``(q_rows bucket, table bucket)``, watched by ``probe``.
     ``quantized=True`` threads the per-block scale array beside the
     pool (``fn(params, buffers, pool, scales, token_ids, ...) ->
     (pool, scales, next_tokens, key)``): rows scatter through
@@ -1403,197 +970,6 @@ def _mp_mesh_check(model, mesh, mp_axis):
             f"num_attention_heads {H} not divisible by mesh "
             f"{mp_axis}={mp}")
     return mp
-
-
-def build_sharded_paged_prefill_fn(model, bucket_len, block_size, mesh,
-                                   mp_axis="mp", top_k=0, top_p=1.0,
-                                   probe=None):
-    """Tensor-parallel :func:`build_paged_prefill_fn` (non-quantized):
-    the SAME ``fn(params, buffers, pool, ids, key_valid, table, plen,
-    sample, temperature, key) -> (pool, first_token, key)`` signature,
-    with the body wrapped in ``shard_map`` over the 1-D ``mp`` mesh.
-    ``pool`` is the head-partitioned global array; each device writes
-    its own heads' K/V blocks and attends over its local heads, the
-    row-parallel projections psum per layer, and the first-token pick
-    runs on replicated logits (identical on every device). Donation of
-    the global pool flows through the shard_map boundary unchanged."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-
-    from ..framework import trace_probe as _probe
-    from ..nn import functional as F
-    from ..nn.layer.layers import functional_state
-
-    gpt = model.gpt if hasattr(model, "gpt") else model
-    Lb, bs = int(bucket_len), int(block_size)
-    if Lb < 1:
-        raise ValueError(f"bucket_len must be >= 1, got {Lb}")
-    if bs < 1 or Lb % bs:
-        raise ValueError(
-            f"bucket_len {Lb} must be a positive multiple of "
-            f"block_size {bs}")
-    if Lb > gpt.cfg.max_position_embeddings:
-        raise ValueError(
-            f"bucket_len {Lb} exceeds max_position_embeddings="
-            f"{gpt.cfg.max_position_embeddings}")
-    Tp = Lb // bs
-    mp = _mp_mesh_check(gpt, mesh, mp_axis)
-    H = gpt.cfg.num_attention_heads
-    Hl = H // mp
-    Dh = gpt.cfg.hidden_size // H
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
-
-    def body(params, buffers, pool, ids, key_valid, table, plen, sample,
-             temperature, key):
-        with functional_state(model, params, buffers):
-            with no_grad_guard():
-                pos_ids = Tensor(jnp.maximum(
-                    jnp.cumsum(key_valid.astype(jnp.int32), axis=1) - 1,
-                    0))
-                x = gpt.wte(Tensor(ids, stop_gradient=True)) \
-                    + gpt.wpe(pos_ids)
-                mask = Tensor(key_valid[:, None, None, :])
-                new_pool = pool
-                for li, block in enumerate(gpt.blocks):
-                    q, k, v = _mp_qkv(block, x, mp, mp_axis)
-                    # the single-device prefill attends over the CACHE
-                    # (pool-dtype values); cast before attention so the
-                    # sharded engine sees bit-identical K/V
-                    kc = k.astype(new_pool.dtype)
-                    vc = v.astype(new_pool.dtype)
-                    kb = jnp.transpose(kc[0].reshape(Tp, bs, Hl, Dh),
-                                       (0, 2, 1, 3))
-                    vb = jnp.transpose(vc[0].reshape(Tp, bs, Hl, Dh),
-                                       (0, 2, 1, 3))
-                    new_pool = new_pool.at[li, table].set(
-                        _kv_lanes(kb, vb))
-                    a = F.scaled_dot_product_attention(
-                        Tensor(q, stop_gradient=True),
-                        Tensor(kc, stop_gradient=True),
-                        Tensor(vc, stop_gradient=True),
-                        attn_mask=mask, is_causal=True)
-                    x = _mp_tail(block, x, a._data, mp_axis)
-                x = gpt.ln_f(x)
-                z = jnp.int32(0)
-                p = jnp.asarray(plen, jnp.int32).reshape(())
-                last = lax.dynamic_slice(
-                    x._data, (z, p - 1, z), (1, 1, x._data.shape[-1]))
-                logits = gpt.logits(Tensor(last))._data[:, 0].astype(
-                    jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p,
-                                     1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature)
-                first = jnp.where(sample, sampled, greedy)
-        return new_pool, first, key
-
-    rep = P()
-    sm = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(megatron_param_specs(model, mp_axis), rep,
-                  _mp_pool_spec(mp_axis)) + (rep,) * 7,
-        out_specs=(_mp_pool_spec(mp_axis), rep, rep), check_vma=False)
-
-    def fn(params, buffers, pool, ids, key_valid, table, plen, sample,
-           temperature, key):
-        if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, ids, key_valid, table]),
-                         {"bucket": Lb, "table": Tp, "mp": mp})
-        return sm(params, buffers, pool, ids, key_valid, table, plen,
-                  sample, temperature, key)
-
-    return fn
-
-
-def build_sharded_paged_decode_fn(model, num_slots, table_len,
-                                  block_size, mesh, mp_axis="mp",
-                                  top_k=0, top_p=1.0, probe=None,
-                                  debug_logits=False):
-    """Tensor-parallel :func:`build_paged_decode_fn` (non-quantized):
-    the gather-based paged-attention oracle under ``shard_map``. Each
-    device scatters its heads' K/V through the replicated page table
-    into its pool shard, gathers ITS OWN virtual cache window, runs
-    SDPA over the local heads, and the row-parallel tail psums — the
-    sampled token is computed from replicated logits, identical on
-    every device. Same signature/donation contract as the single-device
-    builder."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from ..framework import trace_probe as _probe
-    from ..nn import functional as F
-    from ..nn.layer.layers import functional_state
-
-    gpt = model.gpt if hasattr(model, "gpt") else model
-    S, T, bs = int(num_slots), int(table_len), int(block_size)
-    if S < 1:
-        raise ValueError(f"num_slots must be >= 1, got {S}")
-    if T < 1:
-        raise ValueError(f"table_len must be >= 1, got {T}")
-    mp = _mp_mesh_check(gpt, mesh, mp_axis)
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
-
-    def body(params, buffers, pool, tokens, pos, lo, tables,
-             sample_mask, temperature, key):
-        with functional_state(model, params, buffers):
-            with no_grad_guard():
-                logical = (pos - lo)[:, None]
-                x = gpt.wte(Tensor(tokens[:, None], stop_gradient=True)) \
-                    + gpt.wpe(Tensor(logical))
-                r = jnp.arange(T * bs)
-                key_valid = (r[None, :] >= lo[:, None]) \
-                    & (r[None, :] <= pos[:, None])
-                mask = Tensor(key_valid[:, None, None, :])
-                sl = jnp.arange(S)
-                wb = tables[sl, pos // bs]
-                off = pos % bs
-                new_pool = pool
-                for li, block in enumerate(gpt.blocks):
-                    q, k, v = _mp_qkv(block, x, mp, mp_axis)
-                    # [S, H/mp] rows into this device's shard
-                    new_pool = _write_rows(new_pool, li, wb, off,
-                                           k[:, 0], v[:, 0])
-                    kf, vf = _gather_kv(new_pool, None, li, tables)
-                    a = F.scaled_dot_product_attention(
-                        Tensor(q, stop_gradient=True),
-                        Tensor(kf, stop_gradient=True),
-                        Tensor(vf, stop_gradient=True), attn_mask=mask)
-                    x = _mp_tail(block, x, a._data, mp_axis)
-                x = gpt.ln_f(x)
-                logits = gpt.logits(x)._data[:, 0].astype(jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p,
-                                     1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature[:, None])
-                nxt = jnp.where(sample_mask, sampled, greedy)
-                nxt = _append_nonfinite_flag(nxt, logits)
-        extra = (logits,) if debug_logits else ()
-        return (new_pool, nxt) + extra + (key,)
-
-    rep = P()
-    extra_specs = (rep,) if debug_logits else ()
-    sm = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(megatron_param_specs(model, mp_axis), rep,
-                  _mp_pool_spec(mp_axis)) + (rep,) * 7,
-        out_specs=(_mp_pool_spec(mp_axis), rep) + extra_specs + (rep,),
-        check_vma=False)
-
-    def fn(params, buffers, pool, tokens, pos, lo, tables, sample_mask,
-           temperature, key):
-        if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, tokens, pos, lo, tables,
-                                        temperature]),
-                         {"slots": S, "table": T, "mp": mp})
-        return sm(params, buffers, pool, tokens, pos, lo, tables,
-                  sample_mask, temperature, key)
-
-    return fn
 
 
 def build_sharded_fused_step_fn(model, num_slots, q_rows, table_len,
@@ -1809,7 +1185,7 @@ def build_draft_prefill_fn(model, bucket_len, max_len, probe=None):
     Returns ``fn(params, buffers, pool, ids, key_valid, slot) ->
     pool`` over the draft pool ``[draft_layers, 2, slots, draft_heads,
     max_len, draft_head_dim]``; no token is sampled — proposals come
-    from the :func:`build_draft_propose_fn` loop that follows. The
+    from the :func:`build_draft_propose_scan_fn` program that follows. The
     caller jits with ``donate_argnums`` on ``pool``.
     """
     import jax
@@ -1854,94 +1230,13 @@ def build_draft_prefill_fn(model, bucket_len, max_len, probe=None):
     return fn
 
 
-def build_draft_propose_fn(model, num_slots, max_len, top_k=0, top_p=1.0,
-                           probe=None):
-    """One autoregressive DRAFT proposal step (speculative decoding):
-    the engine runs ``spec_k`` of these back to back, feeding each
-    step's proposal into the next, all device-side — the host never
-    fetches a draft token (they echo back through the verify launch's
-    one fetch).
-
-    Returns ``fn(params, buffers, pool, feed_tok, pos, lo, sample_mask,
-    temperature, key) -> (pool, proposal, probs, key)``:
-
-    * ``feed_tok [S]`` int32 — the token each slot feeds this step (the
-      slot's last accepted token on step 0 — a host array — or the
-      previous step's device-side ``proposal``);
-    * ``proposal [S]`` int32 — drawn from the draft's own sampling
-      distribution (greedy slots: the argmax, deterministically);
-    * ``probs [S, V]`` f32 — THE proposal distribution ``q`` (one-hot
-      for greedy slots), consumed by the verify launch's rejection
-      sampling;
-    * the caller jits with ``donate_argnums`` on ``pool``.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..framework import trace_probe as _probe
-    from ..nn import functional as F
-    from ..nn.layer.layers import functional_state
-
-    gpt = model.gpt if hasattr(model, "gpt") else model
-    S = int(num_slots)
-    L = int(max_len)
-    if S < 1:
-        raise ValueError(f"num_slots must be >= 1, got {S}")
-    if L > gpt.cfg.max_position_embeddings:
-        raise ValueError(
-            f"max_len {L} exceeds max_position_embeddings="
-            f"{gpt.cfg.max_position_embeddings}")
-    top_k = min(int(top_k), gpt.cfg.vocab_size)
-
-    def fn(params, buffers, pool, feed_tok, pos, lo, sample_mask,
-           temperature, key):
-        if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, feed_tok, pos, lo,
-                                        temperature]), {"slots": S})
-        with functional_state(model, params, buffers):
-            with no_grad_guard():
-                logical = (pos - lo)[:, None]
-                x = gpt.wte(Tensor(feed_tok[:, None],
-                                   stop_gradient=True)) \
-                    + gpt.wpe(Tensor(logical))
-                r = jnp.arange(L)
-                key_valid = (r[None, :] >= lo[:, None]) \
-                    & (r[None, :] <= pos[:, None])
-                mask = Tensor(key_valid[:, None, None, :])
-                sl = jnp.arange(S)
-                new_pool = pool
-                for li, block in enumerate(gpt.blocks):
-                    q, k, v = block._qkv(x)
-                    kh = k._data[:, 0].astype(new_pool.dtype)
-                    vh = v._data[:, 0].astype(new_pool.dtype)
-                    new_pool = new_pool.at[li, 0, sl, :, pos, :].set(kh)
-                    new_pool = new_pool.at[li, 1, sl, :, pos, :].set(vh)
-                    k_full = Tensor(jnp.swapaxes(new_pool[li, 0], 1, 2),
-                                    stop_gradient=True)
-                    v_full = Tensor(jnp.swapaxes(new_pool[li, 1], 1, 2),
-                                    stop_gradient=True)
-                    a = F.scaled_dot_product_attention(
-                        q, k_full, v_full, attn_mask=mask)
-                    x = block._tail(x, a)
-                x = gpt.ln_f(x)
-                logits = gpt.logits(x)._data[:, 0].astype(jnp.float32)
-                probs = _sample_probs(logits, sample_mask, top_k, top_p,
-                                      temperature)
-                key, sub = jax.random.split(key)
-                prop = _categorical_probs(sub, probs)
-        return new_pool, prop, probs, key
-
-    return fn
-
-
 def build_draft_propose_scan_fn(model, num_slots, max_len, spec_k,
                                 top_k=0, top_p=1.0, probe=None):
     """The WHOLE draft proposal loop as one compiled program:
-    ``lax.scan`` over :func:`build_draft_propose_fn`'s step body —
-    ``spec_k`` sequential small launches per decode cycle become ONE
-    dispatch, with the step's key-split/draw order preserved exactly so
-    greedy proposals (and the sampled key chain) are token-identical to
-    the unrolled loop.
+    ``lax.scan`` over a one-token draft step (feed the previous proposal,
+    write its K/V at the slot's next position of the draft's dense
+    per-slot pool, attend over ``[lo, pos]``, pick) — ONE dispatch a
+    cycle for ``spec_k`` proposals.
 
     Returns ``fn(params, buffers, pool, feed_tok, pos, lo, sample_mask,
     temperature, key) -> (pool, proposals [S, spec_k],
@@ -1950,8 +1245,7 @@ def build_draft_propose_scan_fn(model, num_slots, max_len, spec_k,
     * ``feed_tok [S]`` int32 — each slot's last accepted token (the
       loop's step-0 feed); later steps feed the previous step's
       device-side proposal through the scan carry;
-    * step ``j`` writes at position ``min(pos + j, max_len - 1)`` — the
-      same host-side clamp the unrolled loop applied, now in-trace;
+    * step ``j`` writes at position ``min(pos + j, max_len - 1)``;
     * the caller jits with ``donate_argnums`` on ``pool``.
     """
     import jax

@@ -58,8 +58,8 @@ interpret mode (tests/test_kv_append.py, against ``_write_rows``).
 
 Who calls it: the unquantized full-attention layers of the fused step,
 of the speculative verify step and of the tensor-parallel fused step.
-The gather steps (the correctness oracle), the quantized append and the
-latent append keep their XLA writes (models/generation.py, the comment
+The quantized append and the latent append keep their XLA writes
+(models/generation.py, the comment
 above ``_kv_lanes``).
 """
 from __future__ import annotations

@@ -122,7 +122,7 @@ def _smoke_ragged_paged_attention():
     block tile is exactly 128 lanes): a mixed decode + prefill-chunk
     ragged batch against the numpy oracle, for a float32 and a bfloat16
     pool at block 16 and an int8 pool at block 32 — the lowering gate for
-    ``GenerationEngine(attention='fused')``."""
+    ``GenerationEngine``."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -1,11 +1,11 @@
 """Ragged paged attention — the fused Pallas TPU serving kernel.
 
-The gather-based paged decode step (models/generation.py
-``build_paged_decode_fn``) materializes ``pool[li, :, tables]`` per
+A gather-based paged decode step (what this engine had before the
+kernel) materializes ``pool[li, :, tables]`` per
 layer: every request's WHOLE KV window is copied out of the block pool
 on every decode step, and attention then runs over the padded
 ``table_bucket * block_size`` columns for every slot. This kernel is
-the TPU-native replacement per "Ragged Paged Attention" (PAPERS.md):
+the TPU-native form per "Ragged Paged Attention" (PAPERS.md):
 the block pool stays in HBM (``memory_space=pl.ANY``), the kernel walks
 each sequence's page table directly and streams online softmax over
 exactly the blocks a sequence owns. Nothing is gathered, nothing is
@@ -52,7 +52,7 @@ shape must be aligned to tiling (128)") and padded 2x in HBM, while K|V
 folded into the lanes is exactly 128 wide at ``Dh = 64`` (and 256 at
 ``Dh = 128``). ``pool[layer, pid]`` is one contiguous ``[H, bs, 2 * Dh]``
 region, so one DMA brings a block's K and V for every head. Everything
-that touches the pool — ``serving/paging.py``, the gather decode path,
+that touches the pool — ``serving/paging.py``, ``ops/kv_append.py``,
 ``serving/host_tier.py``, the int8 scales, the head-partitioned TP
 shard — reads this one layout.
 

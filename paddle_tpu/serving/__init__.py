@@ -3,10 +3,10 @@
 The autoregressive counterpart of ``inference.BatchingEngine``: where
 that engine gathers fixed-shape ``predictor.run`` calls, this one serves
 many concurrent ``generate``-style requests through ONE jitted, donated
-decode step over a slot-based KV-cache pool. Requests join and leave
-the in-flight batch EVERY step (continuous batching) instead of waiting
-for a whole generation to drain — a long request never stalls a short
-one, and a retired slot's capacity is reused mid-flight.
+fused step over a paged KV-cache pool. Requests join and leave the
+in-flight batch EVERY step (continuous batching) instead of waiting for
+a whole generation to drain — a long request never stalls a short one,
+and a retired request's blocks are reused mid-flight.
 
 Reference analog: the reference serves decoder LMs through
 fused_multi_transformer's fixed-capacity CacheKV
@@ -26,14 +26,15 @@ Attention shape, PAPERS.md) with XLA-donated in-place updates.
         ...
     engine.close()                  # drains in-flight work
 
-Two KV layouts share the surface: the dense slot pool above, and
-``GenerationEngine(kv_layout="paged", block_size=...)`` — block-granular
-KV management (:mod:`.paging`) with per-request page tables, ref-counted
-block sharing and a prefix cache, so admission gates on FREE BLOCKS
-instead of worst-case slot stripes and a repeated system prompt skips
-prefill entirely. ``kv_dtype="int8"`` stores the blocks QUANTIZED with
+One serving path: block-granular KV management (:mod:`.paging`) with
+per-request page tables, ref-counted block sharing and a prefix cache —
+admission gates on FREE BLOCKS, not on worst-case sequence length, and a
+repeated system prompt feeds only its uncovered tail — under ONE fused
+ragged launch a cycle (``models.generation.build_fused_step_fn``) that
+mixes budgeted prompt chunks with every decode row.
+``kv_dtype="int8"`` stores the blocks QUANTIZED with
 per-block max-abs scales (~4x blocks per byte budget, ~2x+ concurrent
-requests), and ``spec_draft=`` + ``spec_k=`` (fused engines) adds
+requests), and ``spec_draft=`` + ``spec_k=`` adds
 draft-model SPECULATIVE DECODING — k candidate tokens verified per
 slot per cycle in one fused ragged launch, exact greedy parity,
 ``stats()['spec_tokens_per_cycle']`` > 1 on agreeing workloads.
@@ -44,15 +45,13 @@ derived per-request TTFT/TPOT — the scheduler keeps an always-on
 bounded :class:`~.flight_recorder.FlightRecorder`
 (``engine.dump_flight_recorder()``, auto-dumped on step failure), and
 ``engine.stats()`` reports per-ENGINE TTFT/TPOT percentiles from its
-own retired traces. ``bench.py --serve-load`` drives seeded
-open-arrival traffic against both KV layouts and writes the
-TTFT/TPOT/goodput curve into a BENCH json.
+own retired traces. What it does on the chip is measured by
+``benchmark/run.py``.
 
-Modules: :mod:`.kv_pool` (the pooled cache + slot allocator +
-capacity buckets), :mod:`.paging` (the paged block pool: free-list
+Modules: :mod:`.paging` (THE pool: request slots, free-list block
 allocator, page tables, refcounts/copy-on-write, prefix-cache trie +
 LRU eviction), :mod:`.scheduler` (admission queue, backpressure,
-prefill-budget policy, block-pressure preemption, the decode loop),
+the per-cycle chunk budget, block-pressure preemption, the cycle),
 :mod:`.tracing` (per-request lifecycle traces + chrome-trace lanes),
 :mod:`.flight_recorder` (bounded postmortem rings + per-engine latency
 reservoirs + tail-sampled traces), :mod:`.engine` (the thread-safe
@@ -78,7 +77,6 @@ from .flight_recorder import FlightRecorder  # noqa: F401
 from .frontdoor import FrontDoor, TokenBucket  # noqa: F401
 from .host_tier import (HostBlockPool, HostTierError,  # noqa: F401
                         HostTierFullError, PromotionTicket)
-from .kv_pool import KVCachePool  # noqa: F401
 from .opsserver import OpsServer  # noqa: F401
 from .paging import (BlockError, PagedKVPool,  # noqa: F401
                      PoolCapacityError, PoolExhaustedError)
@@ -88,7 +86,7 @@ from .slo import SLOObjective, SLOTracker  # noqa: F401
 from .slo import attainment_from_buckets  # noqa: F401
 from .tracing import RequestTrace  # noqa: F401
 
-__all__ = ["GenerationEngine", "PlanError", "EngineFleet", "KVCachePool",
+__all__ = ["GenerationEngine", "PlanError", "EngineFleet",
            "PagedKVPool", "GenerationRequest", "Scheduler",
            "QueueFullError", "DeadlineExceeded", "RequestCancelled",
            "PoolCapacityError", "PoolExhaustedError", "BlockError",

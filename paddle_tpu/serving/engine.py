@@ -2,47 +2,43 @@
 LLM server.
 
 Many concurrent ``submit(prompt_ids, ...)`` calls are served by ONE
-jitted, pool-donated decode step over a slot-based KV-cache pool
-(:mod:`.kv_pool`), driven by the prefill/decode scheduler
-(:mod:`.scheduler`). The serving-side twin of the PR-2 donated training
-loop: buffers are donated and rebound, the hot loop never syncs except
-the one windowed token fetch, and every step program must pass the PR-3
-analyzer clean (``engine.analyze()``).
+jitted, pool-donated fused step (``models.generation.build_fused_step_fn``)
+over the paged KV-cache pool (:mod:`.paging`), driven by the scheduler's
+cycle (:mod:`.scheduler`). The serving-side twin of the PR-2 donated
+training loop: buffers are donated and rebound, the hot loop never syncs
+except the one windowed token fetch, and every step program must pass
+the PR-3 analyzer clean (``engine.analyze()``).
 
-Two KV layouts share this surface (``kv_layout=``):
+One path: a request owns only the blocks covering its tokens so far,
+addressed through its page table; admission gates on FREE BLOCKS, memory
+pressure preempts the youngest request (requeued, fed again) instead of
+deadlocking, and full prompt blocks are shared across requests through
+the prefix cache — a repeated system prompt feeds only its uncovered
+tail. Each cycle is ONE fused ragged launch mixing ``prefill_budget``
+tokens of prompt chunks with every decode row; the first generated token
+comes out of the launch that fed the final chunk. Greedy output is
+token-identical to ``models.generate`` run per request
+(tests/test_serving_engine.py).
 
-* ``"dense"`` — one ``[heads, max_len, head_dim]`` stripe per slot
-  (:mod:`.kv_pool`): simplest, but concurrency is capped by worst-case
-  sequence length;
-* ``"paged"`` — a block pool addressed through per-request page tables
-  (:mod:`.paging`): a request owns only the blocks covering its tokens
-  so far, admission gates on FREE BLOCKS instead of free slots, memory
-  pressure preempts the youngest request (requeued, replayed) instead
-  of deadlocking, and full prompt blocks are shared across requests
-  through the prefix cache — a repeated system prompt skips prefill
-  entirely. Greedy paged output is token-identical to the dense slot
-  engine (tests/test_serving_paging.py).
-
-Compile discipline: the dense decode step traces ONCE per engine (the
-paged one once per pow2 TABLE bucket), and prefill traces once per
-CAPACITY BUCKET (pow2 prompt lengths) — all watched by
-``framework.trace_probe`` sites (``serving/decode#N``,
-``serving/decode[tT]#N``, ``serving/prefill[B]#N``), so a retrace shows
-up in the ``dispatch/retrace_cause`` counters exactly like
-training-loop churn.
+Compile discipline: one trace per (pow2 q-row bucket, pow2 page-table
+bucket), watched by ``framework.trace_probe`` sites
+(``serving/fused[qQ,tT]#N``; ``serving/spec[...]``,
+``serving/spec_draft[kK]``, ``serving/spec_prefill[B]`` with a draft),
+so a retrace shows up in the ``dispatch/retrace_cause`` counters exactly
+like training-loop churn.
 
 Observability (PR-1 wiring + the ISSUE-6 SLO spine): counters
 ``serving/requests``, ``serving/completed``, ``serving/tokens``,
 ``serving/preempt``, ``serving/queue_full``, ``serving/cancelled``,
 ``serving/deadline_exceeded``, ``serving/prefix_hit``/``prefix_miss``/
-``prefill_tokens_saved``/``prefix_evict`` (paged); histograms
+``prefill_tokens_saved``/``prefix_evict``; histograms
 ``serving/queue_depth``, ``serving/active_slots``,
 ``serving/batch_occupancy``, ``serving/cycle_ms``, ``serving/ttft_ms``,
 ``serving/tpot_ms``, ``serving/tokens_per_sec``,
-``serving/kv_blocks_in_use`` (paged); spans ``serving/cycle`` with
-nested sweep/admit/prefill/decode_dispatch/host_fetch children, plus a
-chrome-trace LANE per finished request (``serving/tracing.py``). Every
-request handle carries ``handle.trace`` (a
+``serving/kv_blocks_in_use``; spans ``serving/cycle`` with
+nested sweep/admit/prefill/plan/decode_dispatch/host_fetch/emit children,
+plus a chrome-trace LANE per finished request (``serving/tracing.py``).
+Every request handle carries ``handle.trace`` (a
 :class:`~.tracing.RequestTrace` with derived TTFT/TPOT), the scheduler
 keeps an always-on bounded flight recorder
 (:meth:`GenerationEngine.dump_flight_recorder`, auto-dumped when a
@@ -64,9 +60,8 @@ from ..framework import program_registry as _registry
 from ..framework import trace_probe as _probe
 from ..framework.monitor import stat_add
 from ..profiler import memory as _memory
-from .kv_pool import KVCachePool
 from .paging import PagedKVPool, PoolCapacityError
-from .scheduler import (GenerationRequest, Scheduler, _fetch)
+from .scheduler import GenerationRequest, Scheduler
 
 __all__ = ["GenerationEngine", "PlanError"]
 
@@ -132,15 +127,11 @@ def _engine_section() -> str:
     for e in sorted(engines, key=lambda e: e._eid):
         try:
             s = e.stats()
-            head = (f"engine #{e._eid} [{s['kv_layout']}/"
-                    f"{s['attention']}] queue={s['queue_depth']} "
+            head = (f"engine #{e._eid} queue={s['queue_depth']} "
                     f"active={s['active_requests']} "
-                    f"slots={s['slots_in_use']}/{s['num_slots']}")
-            if "kv_blocks_in_use" in s:
-                head += (f" blocks={s['kv_blocks_in_use']}/"
-                         f"{s['num_blocks']}")
-            if "prefix_hit_ratio" in s:
-                head += f" prefix_hit={s['prefix_hit_ratio']:.2f}"
+                    f"slots={s['slots_in_use']}/{s['num_slots']}"
+                    f" blocks={s['kv_blocks_in_use']}/{s['num_blocks']}"
+                    f" prefix_hit={s['prefix_hit_ratio']:.2f}")
             if "host_tier" in s:
                 ht = s["host_tier"]
                 head += (f" host_tier={ht['blocks']}/"
@@ -202,17 +193,16 @@ def _register_engine_telemetry(engine: "GenerationEngine") -> None:
                 s["requests_retired"]),
                ("counter", "serving_preempts", labels, s["preempts"]),
                ("counter", "serving_nonfinite_cycles", labels,
-                s["nonfinite_cycles"])]
-        if "kv_blocks_in_use" in s:
-            out.append(("gauge", "serving_kv_blocks_in_use", labels,
-                        s["kv_blocks_in_use"]))
-            out.append(("gauge", "serving_prefix_hit_ratio", labels,
-                        s["prefix_hit_ratio"]))
-            # tiered hit split: one {engine, tier} counter series per
-            # tier so dashboards can stack hbm/host/miss admissions
-            for tier, n in (s.get("tier_hits") or {}).items():
-                out.append(("counter", "serving_tier_hit",
-                            dict(labels, tier=str(tier)), n))
+                s["nonfinite_cycles"]),
+               ("gauge", "serving_kv_blocks_in_use", labels,
+                s["kv_blocks_in_use"]),
+               ("gauge", "serving_prefix_hit_ratio", labels,
+                s["prefix_hit_ratio"])]
+        # tiered hit split: one {engine, tier} counter series per
+        # tier so dashboards can stack hbm/host/miss admissions
+        for tier, n in s["tier_hits"].items():
+            out.append(("counter", "serving_tier_hit",
+                        dict(labels, tier=str(tier)), n))
         ht = s.get("host_tier")
         if ht is not None:
             out.append(("gauge", "serving_host_tier_bytes_in_use",
@@ -236,19 +226,12 @@ def _register_engine_telemetry(engine: "GenerationEngine") -> None:
     _metrics.register_collector(f"serving_engine/{engine._eid}", _collect)
 
 
-def _refuse_with_latent(kv_layout, attention, kv_dtype, mesh, spec_draft,
+def _refuse_with_latent(kv_dtype, mesh, spec_draft,
                         host_tier_bytes) -> None:
     """A model whose cache is latent (one row a token for all heads,
-    ``models/decoder_spec.py``) is served by the fused paged path alone;
-    each mechanism that has no latent form yet is refused here, by
-    name."""
+    ``models/decoder_spec.py``): each mechanism that has no latent form
+    yet is refused here, by name."""
     import jax.numpy as jnp
-    if kv_layout != "paged" or attention != "fused":
-        raise ValueError(
-            "a latent-attention model is served with kv_layout='paged', "
-            "attention='fused' only: the dense slot pool and the gather "
-            "decode step lay the cache out a head, and a latent cache has "
-            "ONE row a token for every head")
     if mesh is not None:
         raise ValueError(
             "mesh= (tensor-parallel serving) does not compose with a "
@@ -278,40 +261,41 @@ class GenerationEngine:
     """Continuous-batching autoregressive serving over a decoder the
     fused stack has a spec of (``models/decoder_spec.py``: GPT-2, A.X-K1).
 
-    ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` (anything
-    exposing the ``gpt`` prefill/decode surface used by
-    ``models.generate``); its parameters are snapshotted at construction
-    (sharded parameters serve sharded — jit follows the placement).
+    ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` /
+    ``AXK1ForCausalLM`` (anything ``serving_decoder`` has a spec of);
+    its parameters are snapshotted at construction (sharded parameters
+    serve sharded — jit follows the placement).
 
-    * ``num_slots`` — concurrent in-flight requests (the pool's batch);
-    * ``max_len`` — per-slot cache capacity; a dense request needs
-      ``bucket(prompt) + max_new_tokens <= max_len``, a paged one only
-      ``prompt + max_new_tokens <= max_len`` (no left-pad tax);
+    * ``num_slots`` — concurrent in-flight requests (the launch's batch);
+    * ``max_len`` — a request's virtual capacity:
+      ``prompt + max_new_tokens <= max_len``;
     * ``top_k``/``top_p`` — the sampled path's truncation, STATIC per
-      engine (part of the single decode trace); per-request
+      engine (part of the step's trace); per-request
       ``do_sample``/``temperature`` are traced values;
-    * ``max_queue``/``prefill_budget`` — backpressure and the
-      anti-starvation admission policy (see :mod:`.scheduler`);
-    * ``kv_layout``/``block_size``/``num_blocks`` — ``"paged"`` swaps
-      the dense pool for the block-granular :class:`~.paging.PagedKVPool`
-      (``num_blocks`` defaults to the dense-equivalent device budget;
-      shrink it to realise the capacity win — admission then gates on
-      blocks, pressure preempts, and full prompt blocks are shared
-      through the prefix cache);
-    * ``attention`` — ``"gather"`` (default) keeps the gather-based
-      paged decode step (the correctness oracle); ``"fused"`` (paged
-      only, ``block_size >= 8``) serves every cycle with ONE fused
-      ragged-paged-attention Pallas launch
-      (``ops/ragged_paged_attention.py``): no materialized KV gather,
-      and CHUNKED PREFILL — prompts feed in ``prefill_budget``-token
-      chunks mixed into decode launches, so a prompt burst can no
-      longer monopolize a cycle, and the first generated token comes
-      out of the same launch that fed the final chunk. One trace per
-      (pow2 q-row bucket, pow2 table bucket).
+    * ``max_queue``/``prefill_budget`` — backpressure, and the prompt
+      tokens fed per cycle next to the decode rows (see
+      :mod:`.scheduler`): a prompt burst cannot monopolize a cycle;
+    * ``block_size``/``num_blocks`` — the :class:`~.paging.PagedKVPool`.
+      ``block_size`` defaults to ``max(16, the kernel's floor for the
+      pool's dtype)`` (16 for bf16/float32, 32 for int8/fp8); an
+      explicit value under the floor raises. ``num_blocks`` defaults to
+      the worst case (every slot at ``max_len``); shrink it to what the
+      device holds — admission then gates on blocks, pressure preempts,
+      and full prompt blocks are shared through the prefix cache;
+    * ``kv_dtype`` — ``"int8"``/``"float8_e4m3fn"`` stores the blocks
+      quantized with per-block max-abs scales;
+    * ``spec_draft``/``spec_k`` — speculative decoding: a small draft
+      proposes ``spec_k`` tokens a decode slot a cycle, verified in the
+      same fused launch (``min_bucket`` floors the draft's pow2 prefill
+      buckets and does nothing else);
+    * ``mesh``/``mp_axis`` — tensor-parallel serving over a 1-D mesh;
+    * ``host_tier_bytes`` — a host-DRAM tier behind the prefix cache;
+    * ``kv_layout``/``attention`` — removed in PR 31: there is one
+      serving path. They accept ``"paged"`` / ``"fused"`` (what
+      ``benchmark/`` still passes) and select nothing.
 
     Greedy engine output is token-identical to ``models.generate`` run
-    per request (the parity contract, tests/test_serving_engine.py and
-    tests/test_serving_paging.py).
+    per request (the parity contract, tests/test_serving_engine.py).
     """
 
     def __init__(self, model, num_slots: int = 8,
@@ -319,9 +303,10 @@ class GenerationEngine:
                  top_p: float = 1.0, pad_token_id: int = 0,
                  max_queue: int = 128, prefill_budget: Optional[int] = None,
                  min_bucket: int = 8, seed: int = 0, dtype=None,
-                 kv_layout: str = "dense", block_size: int = 16,
+                 kv_layout: str = "paged",
+                 block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
-                 attention: str = "gather", kv_dtype=None,
+                 attention: str = "fused", kv_dtype=None,
                  spec_draft=None, spec_k: int = 4,
                  mesh=None, mp_axis: str = "mp",
                  hbm_budget_bytes: Optional[int] = None,
@@ -329,58 +314,33 @@ class GenerationEngine:
                  host_tier_bytes: Optional[int] = None):
         import jax
 
-        from ..models.generation import build_slot_decode_fn
         from ..nn.layer.layers import get_buffers_tree, get_params_tree
+        from ..ops import pallas_smoke
+        from ..ops.ragged_paged_attention import (check_kv_tile,
+                                                  min_kv_block_for)
 
-        if kv_layout not in ("dense", "paged"):
-            raise ValueError(
-                f"kv_layout must be 'dense' or 'paged', got {kv_layout!r}")
-        if attention not in ("gather", "fused"):
-            raise ValueError(
-                f"attention must be 'gather' or 'fused', got {attention!r}")
-        if kv_dtype is not None and kv_layout != "paged":
-            raise ValueError(
-                "kv_dtype (quantized KV blocks) requires "
-                "kv_layout='paged': the per-block max-abs scales live "
-                "beside the block pool (PagedKVPool.scales); the dense "
-                "slot pool has no block granularity to scale")
-        if attention == "fused":
-            if kv_layout != "paged":
+        for name, got, only in (("kv_layout", kv_layout, "paged"),
+                                ("attention", attention, "fused")):
+            if got != only:
                 raise ValueError(
-                    "attention='fused' is the fused RAGGED PAGED "
-                    "attention path — it requires kv_layout='paged' "
-                    "(the dense slot pool has no page tables to walk)")
-        if spec_draft is not None and attention != "fused":
-            raise ValueError(
-                "spec_draft (speculative decoding) requires "
-                "attention='fused': the k-token verify IS one fused "
-                "ragged launch — each slot's candidate tokens are extra "
-                "ragged rows, exactly like a prefill chunk")
-        if host_tier_bytes is not None:
-            if kv_layout != "paged":
-                raise ValueError(
-                    "host_tier_bytes (hierarchical KV cache) requires "
-                    "kv_layout='paged': the host tier stores demoted "
-                    "prefix-cache BLOCKS; the dense slot pool has no "
-                    "block granularity to demote")
-            if mesh is not None:
+                    f"{name}={got!r}: the option was removed in PR 31 — "
+                    f"the paged pool and the fused step are the one "
+                    f"serving path ({name}={only!r} is still accepted "
+                    f"and selects nothing)")
+        if min_bucket < 1:
+            raise ValueError(f"min_bucket must be >= 1, got {min_bucket}")
+        if mesh is not None:
+            # tensor-parallel serving (ISSUE 15): the paged pool is a
+            # head-partitioned GSPMD array and every step is a
+            # shard_map over mp_axis — scale-UP, vs EngineFleet's
+            # scale-OUT replicas
+            if host_tier_bytes is not None:
                 raise ValueError(
                     "host_tier_bytes does not compose with mesh= yet: "
                     "demotion/promotion copies would need per-shard "
                     "gathers against the head-partitioned pool — run "
                     "tiered engines single-device (or per EngineFleet "
                     "replica)")
-        if mesh is not None:
-            # tensor-parallel serving (ISSUE 15): the paged pool is a
-            # head-partitioned GSPMD array and every step is a
-            # shard_map over mp_axis — scale-UP, vs EngineFleet's
-            # scale-OUT replicas
-            if kv_layout != "paged":
-                raise ValueError(
-                    "mesh= (tensor-parallel serving) requires "
-                    "kv_layout='paged': the mp shards partition the "
-                    "block pool's head axis; the dense slot pool has "
-                    "no sharded step builders")
             if kv_dtype is not None:
                 raise ValueError(
                     "mesh= does not compose with kv_dtype= yet: the "
@@ -392,7 +352,6 @@ class GenerationEngine:
                     "mesh= does not compose with spec_draft= yet: the "
                     "draft tower and verify program have no sharded "
                     "builders — run speculative engines single-device")
-        self._fused = attention == "fused"
         # everything below sizes itself from the model's decoder spec
         # (models/decoder_spec.py): layers, cache rows and lanes a token,
         # vocabulary, positions
@@ -400,9 +359,16 @@ class GenerationEngine:
         spec = _ds.serving_decoder(model).spec
         cache = spec.cache
         if spec.attention == _ds.LATENT:
-            _refuse_with_latent(kv_layout, attention, kv_dtype, mesh,
-                                spec_draft, host_tier_bytes)
+            _refuse_with_latent(kv_dtype, mesh, spec_draft,
+                                host_tier_bytes)
         max_len = int(max_len or spec.max_positions)
+        # every jit is deferred, so without this check an oversized
+        # max_len would only surface as SILENTLY WRONG tokens (XLA clamps
+        # the out-of-range position gather past max_positions)
+        if max_len > spec.max_positions:
+            raise ValueError(
+                f"max_len {max_len} exceeds max_position_embeddings="
+                f"{spec.max_positions}")
         model.eval()                      # serving is inference-only
         self._model = model
         self._decoder_spec = spec
@@ -423,60 +389,27 @@ class GenerationEngine:
         self._buffers = get_buffers_tree(model)
         if dtype is None:
             dtype = self._params[next(iter(self._params))].dtype
-        self._paged = kv_layout == "paged"
         # a per-head K|V row is two head_dims wide; a latent row has no
         # head_dim and states its lanes
         head_dim = 0 if cache.v_aliases_k else cache.lanes // 2
-        if self._fused:
-            # the fused engine either runs the kernel or raises, here:
-            # no other attention path is selected behind its back
-            from ..ops import pallas_smoke
-            from ..ops.ragged_paged_attention import check_kv_tile
-            check_kv_tile(kv_dtype or dtype, block_size, lanes=cache.lanes)
-            pallas_smoke.ensure()
+        # the engine either runs the kernel or raises, here: its block
+        # floor is the engine's (derived from the pool's dtype), and no
+        # other attention path is selected behind its back
+        if block_size is None:
+            block_size = max(16, min_kv_block_for(kv_dtype or dtype))
+        check_kv_tile(kv_dtype or dtype, block_size, lanes=cache.lanes)
+        pallas_smoke.ensure()
         self._key = jax.random.PRNGKey(int(seed))
         self._eid = _next_engine_id()
-        self._prefill_jits = {}           # bucket -> jitted prefill step
-        if self._paged:
-            # the dense layout fails this at construction inside
-            # build_slot_decode_fn; every paged jit is deferred, so
-            # without this check an oversized max_len would only
-            # surface as SILENTLY WRONG tokens (XLA clamps the
-            # out-of-range wpe gather at decode positions past mpe)
-            if max_len > spec.max_positions:
-                raise ValueError(
-                    f"max_len {max_len} exceeds max_position_embeddings="
-                    f"{spec.max_positions}")
-            # prefill scatters WHOLE blocks, so capacity buckets must be
-            # block multiples: round the floor up rather than reject it
-            mb = -(-max(int(min_bucket), int(block_size))
-                   // int(block_size)) * int(block_size)
-            self._pool = PagedKVPool(
-                len(spec.layers), num_slots, cache.rows,
-                max_len, head_dim, block_size=block_size,
-                num_blocks=num_blocks, dtype=kv_dtype or dtype,
-                min_bucket=mb, mesh=mesh, mp_axis=mp_axis,
-                lanes=cache.lanes)
-            self._decode_jit = None       # per-table-bucket instead
-            self._decode_jits = {}        # table bucket -> jitted step
-            self._fused_jits = {}         # (q bucket, table bucket) -> step
-            self._spec_jits = {}          # (q, table) -> spec verify step
-            self._copy_jit = None         # lazy COW device block copy
-        else:
-            self._pool = KVCachePool(
-                len(spec.layers), num_slots, cache.rows,
-                max_len, head_dim, dtype=dtype, min_bucket=min_bucket)
-            self._decode_probe = _probe.site(f"serving/decode#{self._eid}")
-            # program-registry AOT site (same jit semantics, donated
-            # pool): THE decode step's compile ms + XLA cost analysis
-            # land under this name — stats() derives flops-per-token
-            # and serving MFU from its record
-            self._decode_jit = _registry.aot_site(
-                f"serving/decode#{self._eid}",
-                build_slot_decode_fn(model, self._pool.num_slots, max_len,
-                                     top_k=self._top_k, top_p=self._top_p,
-                                     probe=self._decode_probe),
-                donate_argnums=(2,))
+        self._min_bucket = int(min_bucket)    # the draft's prefill ladder
+        self._pool = PagedKVPool(
+            len(spec.layers), num_slots, cache.rows,
+            max_len, head_dim, block_size=block_size,
+            num_blocks=num_blocks, dtype=kv_dtype or dtype,
+            mesh=mesh, mp_axis=mp_axis, lanes=cache.lanes)
+        self._fused_jits = {}         # (q bucket, table bucket) -> step
+        self._spec_jits = {}          # (q, table) -> spec verify step
+        self._copy_jit = None         # lazy COW device block copy
         # hierarchical KV cache (ISSUE 20): a bounded host-DRAM block
         # store behind the device prefix cache — LRU-evicted
         # refcount-0 blocks demote instead of dying, and a hit on a
@@ -493,7 +426,7 @@ class GenerationEngine:
             self._pool.attach_host_tier(self._host_tier)
         self._closed = False
         self._close_lock = threading.Lock()
-        # speculative decoding (fused engines only): a small draft
+        # speculative decoding: a small draft
         # model proposes spec_k tokens per decode slot per cycle; the
         # target verifies all of them in ONE fused ragged launch
         self._spec = spec_draft is not None
@@ -515,17 +448,16 @@ class GenerationEngine:
         if self._hbm_budget_bytes is not None:
             self._plan = self.plan_replica(self._hbm_budget_bytes)
         # per-engine compute accounting (scheduler-thread writes, host
-        # ints): FLOPs of the decode programs actually DISPATCHED — a
-        # paged engine runs different table-bucket programs with very
-        # different costs, so stats() must average what ran, not bill
-        # the largest bucket to every cycle
+        # ints): FLOPs of the step programs actually DISPATCHED — the
+        # (q, table)-bucket programs differ widely in cost, so stats()
+        # must average what ran, not bill the largest bucket to every
+        # cycle
         self._decode_flops_dispatched = 0.0
         self._decode_dispatches = 0
         self._sched = Scheduler(
-            self._pool, self._run_prefill, self._run_decode,
+            self._pool, self._run_admit, self._run_fused_step,
             max_queue=max_queue, prefill_budget=prefill_budget,
-            do_copy=self._run_copy if self._paged else None,
-            do_chunked_step=self._run_fused_step if self._fused else None,
+            do_copy=self._run_copy,
             do_spec_step=self._run_spec_step if self._spec else None,
             spec_k=self._spec_k, lane_weights=lane_weights)
         # telemetry spine wiring (ISSUE 13): the engine joins the
@@ -552,7 +484,7 @@ class GenerationEngine:
         ``QueueFullError`` here, synchronously.
 
         ``do_sample``/``temperature`` are per-request (traced values of
-        the shared decode program). ``top_k``/``top_p`` are NOT: they
+        the shared step program). ``top_k``/``top_p`` are NOT: they
         are static truncation structure baked into the engine's compile
         key at construction, so a differing per-request value here is
         rejected with :class:`ValueError` instead of silently retracing
@@ -588,44 +520,14 @@ class GenerationEngine:
         if max_new_tokens < 1:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        if self._paged:
-            # paged sequences are aligned at virtual 0 — no left-pad tax,
-            # only the true footprint counts (this is the capacity win)
-            if ids.size + int(max_new_tokens) > self._pool.max_len:
-                raise PoolCapacityError(
-                    f"prompt {ids.size} + max_new_tokens {max_new_tokens} "
-                    f"exceeds the pool's virtual capacity "
-                    f"{self._pool.max_len}; shorten the request or build "
-                    f"the engine with a larger max_len")
-            # bucket feasibility, incl. the WORST re-admission: a
-            # preempted request re-prefills prompt + generated-so-far
-            # (up to max_new - 1 tokens), and that feed's pow2 bucket
-            # must exist — without this gate a bucket ladder that
-            # overshoots max_len (non-pow2 max_len / large min_bucket)
-            # admits a request whose prefill can never trace, and the
-            # scheduler-thread crash poisons every in-flight request.
-            # FUSED engines have no prefill buckets at all — any feed
-            # up to max_len chunks through the ragged step, so the
-            # ladder constraint simply does not exist there.
-            worst = ids.size + int(max_new_tokens) - 1
-            if not self._fused \
-                    and self._pool.bucket_for(worst) > self._pool.max_len:
-                raise PoolCapacityError(
-                    f"no prefill bucket fits this request: prompt "
-                    f"{ids.size} (+ up to {int(max_new_tokens) - 1} "
-                    f"replayed tokens after a preemption) needs bucket "
-                    f"{self._pool.bucket_for(worst)} > max_len "
-                    f"{self._pool.max_len}; shorten the request or build "
-                    f"the engine with a larger max_len / smaller "
-                    f"min_bucket")
-        else:
-            bucket = self._pool.bucket_for(ids.size)
-            if bucket + int(max_new_tokens) > self._pool.max_len:
-                raise ValueError(
-                    f"prompt bucket {bucket} + max_new_tokens "
-                    f"{max_new_tokens} exceeds the pool capacity "
-                    f"{self._pool.max_len}; shorten the request or build "
-                    f"the engine with a larger max_len")
+        # sequences are aligned at virtual 0: only the true footprint
+        # counts, and any feed up to max_len chunks through the step
+        if ids.size + int(max_new_tokens) > self._pool.max_len:
+            raise PoolCapacityError(
+                f"prompt {ids.size} + max_new_tokens {max_new_tokens} "
+                f"exceeds the pool's virtual capacity "
+                f"{self._pool.max_len}; shorten the request or build "
+                f"the engine with a larger max_len")
         req = GenerationRequest(
             ids, max_new_tokens, do_sample=do_sample,
             temperature=temperature, eos_token_id=eos_token_id,
@@ -698,8 +600,6 @@ class GenerationEngine:
         Host bookkeeping only: never blocks on the device."""
         pool = self._pool
         s = {
-            "kv_layout": "paged" if self._paged else "dense",
-            "attention": "fused" if self._fused else "gather",
             "queue_depth": self._sched.queue_depth,
             "active_requests": self._sched.active,
             "num_slots": pool.num_slots,
@@ -708,7 +608,7 @@ class GenerationEngine:
             "preempts": self._sched.preempts,
             "requests_retired": self._sched.recorder.retired,
             # serving numerics sentinel (scheduler._note_nonfinite):
-            # decode cycles whose logits carried a NaN/Inf — the flag
+            # cycles whose logits carried a NaN/Inf — the flag
             # rides the existing per-cycle token fetch, zero extra syncs
             "nonfinite_cycles": self._sched.nonfinite_cycles,
         }
@@ -740,64 +640,62 @@ class GenerationEngine:
             f"{pool.ledger_key}/capacity", pool.capacity_bytes)
         s["kv_bytes_in_use"] = led.get(
             f"{pool.ledger_key}/in_use", pool.bytes_in_use)
-        if self._paged:
-            hits, misses = pool.prefix_hits, pool.prefix_misses
-            # tiered hit split (MIGRATION.md "prefix-hit split"): the
-            # aggregate prefix_hit_ratio stays for dashboards; the
-            # split keys say WHICH tier served each admission — hbm
-            # (device trie), host (served through a promotion), miss.
-            # Present tier or no tier (host is just 0 untiered).
-            th = pool.tier_hits
-            denom = max(1, th["hbm"] + th["host"] + th["miss"])
-            s.update({
-                "block_size": pool.block_size,
-                "num_blocks": pool.num_blocks,
-                "kv_blocks_in_use": pool.blocks_in_use,
-                "block_utilization": pool.blocks_in_use / pool.num_blocks,
-                "cached_blocks": pool.cached_blocks,
-                "prefix_hits": hits,
-                "prefix_misses": misses,
-                "prefix_hit_ratio": hits / max(1, hits + misses),
-                "tier_hits": dict(th),
-                "prefix_hit_hbm": th["hbm"] / denom,
-                "prefix_hit_host": th["host"] / denom,
-                "prefix_miss": th["miss"] / denom,
-                "prefill_tokens_saved": pool.tokens_saved,
-                "prefix_evictions": pool.evictions,
-                # tiered KV bytes: block storage vs the scale side-array
-                # (zero for float pools) — int8 blocks are the whole
-                # point of the ~2x-requests-per-budget win, so the
-                # operator view must show where the bytes went
-                "kv_dtype": pool.dtype.name,
-                # block_storage_bytes is PER DEVICE (a sharded pool
-                # divides its head axis over mp shards); on a
-                # single-device pool shards == 1 and this is the total
-                "kv_bytes": {
-                    "blocks": pool.block_storage_bytes,
-                    "scales": pool.scales_bytes,
-                },
-            })
-            if self._host_tier is not None:
-                # hierarchical tier snapshot: host capacity/occupancy,
-                # demotion/promotion volumes, and the end-to-end
-                # promotion latency (ticket creation -> adoption) —
-                # the "did the second tier pay for itself" numbers
-                s["host_tier"] = self._host_tier.stats()
-            if self._mp > 1:
-                s["mp"] = self._mp
-                s["mp_axis"] = self._mp_axis
-                s["kv_bytes_per_device"] = pool.block_storage_bytes
-        if self._fused:
-            # chunked-prefill observability: lifetime chunk counters
-            # plus ring-window chunk token throughput, so the "long
-            # prompts no longer monopolize a cycle" win is measurable
-            # (ONE ring pass serves the spec figures below too)
-            s["prefill_chunks"] = self._sched.prefill_chunks
-            s["chunked_prefill_tokens"] = self._sched.chunk_tokens
-            thr = self._sched.recorder.cycle_throughput()
-            if thr["cycle_secs"] > 0 and thr["chunk_tokens"] > 0:
-                s["chunked_prefill_tokens_per_sec"] = \
-                    thr["chunk_tokens"] / thr["cycle_secs"]
+        hits, misses = pool.prefix_hits, pool.prefix_misses
+        # tiered hit split (MIGRATION.md "prefix-hit split"): the
+        # aggregate prefix_hit_ratio stays for dashboards; the
+        # split keys say WHICH tier served each admission — hbm
+        # (device trie), host (served through a promotion), miss.
+        # Present tier or no tier (host is just 0 untiered).
+        th = pool.tier_hits
+        denom = max(1, th["hbm"] + th["host"] + th["miss"])
+        s.update({
+            "block_size": pool.block_size,
+            "num_blocks": pool.num_blocks,
+            "kv_blocks_in_use": pool.blocks_in_use,
+            "block_utilization": pool.blocks_in_use / pool.num_blocks,
+            "cached_blocks": pool.cached_blocks,
+            "prefix_hits": hits,
+            "prefix_misses": misses,
+            "prefix_hit_ratio": hits / max(1, hits + misses),
+            "tier_hits": dict(th),
+            "prefix_hit_hbm": th["hbm"] / denom,
+            "prefix_hit_host": th["host"] / denom,
+            "prefix_miss": th["miss"] / denom,
+            "prefill_tokens_saved": pool.tokens_saved,
+            "prefix_evictions": pool.evictions,
+            # tiered KV bytes: block storage vs the scale side-array
+            # (zero for float pools) — int8 blocks are the whole
+            # point of the ~2x-requests-per-budget win, so the
+            # operator view must show where the bytes went
+            "kv_dtype": pool.dtype.name,
+            # block_storage_bytes is PER DEVICE (a sharded pool
+            # divides its head axis over mp shards); on a
+            # single-device pool shards == 1 and this is the total
+            "kv_bytes": {
+                "blocks": pool.block_storage_bytes,
+                "scales": pool.scales_bytes,
+            },
+        })
+        if self._host_tier is not None:
+            # hierarchical tier snapshot: host capacity/occupancy,
+            # demotion/promotion volumes, and the end-to-end
+            # promotion latency (ticket creation -> adoption) —
+            # the "did the second tier pay for itself" numbers
+            s["host_tier"] = self._host_tier.stats()
+        if self._mp > 1:
+            s["mp"] = self._mp
+            s["mp_axis"] = self._mp_axis
+            s["kv_bytes_per_device"] = pool.block_storage_bytes
+        # chunked-prefill observability: lifetime chunk counters
+        # plus ring-window chunk token throughput, so the "long
+        # prompts no longer monopolize a cycle" win is measurable
+        # (ONE ring pass serves the spec figures below too)
+        s["prefill_chunks"] = self._sched.prefill_chunks
+        s["chunked_prefill_tokens"] = self._sched.chunk_tokens
+        thr = self._sched.recorder.cycle_throughput()
+        if thr["cycle_secs"] > 0 and thr["chunk_tokens"] > 0:
+            s["chunked_prefill_tokens_per_sec"] = \
+                thr["chunk_tokens"] / thr["cycle_secs"]
         if self._spec:
             # the two numbers that prove (or disprove) the multiplier:
             # how often the draft agrees, and how many tokens a decode
@@ -813,16 +711,15 @@ class GenerationEngine:
                     thr["spec_emitted"] / thr["spec_slots"]
             s["draft_layers"] = \
                 self._draft_gpt.cfg.num_hidden_layers
-            if self._paged:
-                s["kv_bytes"]["draft"] = \
-                    int(np.prod(self._draft_shape)) \
-                    * np.dtype(self._draft_dtype).itemsize
+            s["kv_bytes"]["draft"] = \
+                int(np.prod(self._draft_shape)) \
+                * np.dtype(self._draft_dtype).itemsize
         return s
 
     def _compute_stats(self) -> dict:
-        """Model-FLOPs-per-token and serving MFU, from the decode
-        step's program-registry cost analysis (``serving/decode*`` AOT
-        sites). One decode step advances EVERY slot one token, so
+        """Model-FLOPs-per-token and serving MFU, from the fused
+        step's program-registry cost analysis (``serving/fused[...]`` AOT
+        sites). One decode launch advances EVERY slot one token, so
         flops-per-token = step FLOPs / num_slots (the full-batch cost —
         a partially occupied batch still pays it, which is exactly what
         an operator sizing capacity wants to see). Throughput comes
@@ -839,14 +736,8 @@ class GenerationEngine:
         S = self._pool.num_slots
         out["model_flops_per_token"] = mean_step_flops / S
         rec = None
-        if self._fused:
-            if self._fused_jits:
-                rec = self._fused_jits[max(self._fused_jits)].record
-        elif self._paged:
-            if self._decode_jits:
-                rec = self._decode_jits[max(self._decode_jits)].record
-        elif self._decode_jit is not None:
-            rec = getattr(self._decode_jit, "record", None)
+        if self._fused_jits:
+            rec = self._fused_jits[max(self._fused_jits)].record
         if rec is not None and rec.bytes_accessed and rec.flops:
             # scale the largest bucket's bytes by the mean-cost ratio so
             # bytes-per-token tracks what actually ran, like the FLOPs
@@ -883,16 +774,15 @@ class GenerationEngine:
                                                       self.stats()})
 
     def analyze(self, passes=None):
-        """PR-3 pre-flight of THE decode step: trace the jitted program
+        """PR-3 pre-flight of THE step: trace the jitted program
         (donation contract auto-read from the pjit eqn) and run the
         analysis pipeline. The clean-bill contract is zero
         error-severity findings — donation-safe, no host sync in the
         hot loop; asserted by ``bench.py --dry-run`` and the tier-1
         tests. Tracing hits jit's signature cache, so this never
-        retraces (the probe counters stay honest). A paged engine
-        analyzes its LARGEST built table bucket (the step that actually
-        served), falling back to the one-block bucket on a fresh
-        engine."""
+        retraces (the probe counters stay honest). Analyzes the LARGEST
+        built (q, table) bucket (the step that actually served), falling
+        back to the smallest on a fresh engine."""
         from .. import analysis
 
         S = self._pool.num_slots
@@ -917,53 +807,32 @@ class GenerationEngine:
                 np.zeros((S, K, V), np.float32), np.zeros(S, bool),
                 np.ones(S, np.float32), self._key, passes=passes,
                 name=f"serving.spec_verify[{S} slots, k{K}, q{Q}, t{T}]")
-        if self._fused:
-            # largest built fused bucket (the step that actually
-            # served), falling back to the smallest on a fresh engine.
-            # Zeroed metadata is a legal no-op launch: blk_seq 0 maps
-            # every q block to slot 0 with kv_len 0, so the KV walk
-            # runs zero iterations.
-            from ..ops.ragged_paged_attention import BLOCK_Q
-            Q, T = max(self._fused_jits) if self._fused_jits \
-                else (BLOCK_Q, 1)
-            scales = (self._pool.scales,) if self._pool.quantized else ()
-            return analysis.analyze(
-                self._fused_step_fn(Q, T), self._params, self._buffers,
-                self._pool.data, *scales, np.zeros(Q, np.int32),
-                np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                np.zeros(Q, np.int32), np.zeros(Q // BLOCK_Q, np.int32),
-                np.zeros(S, np.int32), np.zeros(S, np.int32),
-                np.zeros((S, T), np.int32), np.zeros(S, np.int32),
-                np.zeros(S, np.int32), np.zeros(S, np.int32),
-                np.zeros(S, bool), np.ones(S, np.float32), self._key,
-                passes=passes,
-                name=f"serving.fused_step[{S} slots, q{Q}, t{T}]")
-        if self._paged:
-            T = max(self._decode_jits) if self._decode_jits else 1
-            scales = (self._pool.scales,) if self._pool.quantized else ()
-            return analysis.analyze(
-                self._paged_decode_fn(T), self._params, self._buffers,
-                self._pool.data, *scales, np.zeros(S, np.int32),
-                np.zeros(S, np.int32), np.zeros(S, np.int32),
-                np.zeros((S, T), np.int32), np.zeros(S, bool),
-                np.ones(S, np.float32), self._key, passes=passes,
-                name=f"serving.paged_decode[{S} slots, {T}-block tables]")
+        # zeroed metadata is a legal no-op launch: blk_seq 0 maps every
+        # q block to slot 0 with kv_len 0, so the KV walk runs zero
+        # iterations
+        from ..ops.ragged_paged_attention import BLOCK_Q
+        Q, T = max(self._fused_jits) if self._fused_jits \
+            else (BLOCK_Q, 1)
+        scales = (self._pool.scales,) if self._pool.quantized else ()
         return analysis.analyze(
-            self._decode_jit, self._params, self._buffers, self._pool.data,
+            self._fused_step_fn(Q, T), self._params, self._buffers,
+            self._pool.data, *scales, np.zeros(Q, np.int32),
+            np.zeros(Q, np.int32), np.zeros(Q, np.int32),
+            np.zeros(Q, np.int32), np.zeros(Q // BLOCK_Q, np.int32),
             np.zeros(S, np.int32), np.zeros(S, np.int32),
-            np.zeros(S, np.int32), np.zeros(S, bool),
-            np.ones(S, np.float32), self._key,
-            passes=passes, name=f"serving.decode[{S} slots]")
+            np.zeros((S, T), np.int32), np.zeros(S, np.int32),
+            np.zeros(S, np.int32), np.zeros(S, np.int32),
+            np.zeros(S, bool), np.ones(S, np.float32), self._key,
+            passes=passes,
+            name=f"serving.fused_step[{S} slots, q{Q}, t{T}]")
 
     def plan_replica(self, hbm_budget_bytes: Optional[int] = None,
                      top_k: int = 4) -> dict:
         """Static fit-before-compile HBM plan of this replica's worst
         case (ISSUE 18): donation-aware liveness
-        (``analysis/liveness.py``) over the LARGEST decode-path bucket
-        this engine can dispatch — the spec-verify / fused step at the
-        full-slot q bucket and max table bucket, the gather decode at
-        the max table bucket, or THE dense decode step — with the
-        pool+scales ledger bytes attributed PER DEVICE (a head-sharded
+        (``analysis/liveness.py``) over the LARGEST bucket this engine
+        can dispatch — the spec-verify / fused step at the full-slot q
+        bucket and max table bucket — with the pool+scales ledger bytes attributed PER DEVICE (a head-sharded
         pool's global-shape operand is swapped for its per-device
         ``capacity_bytes``). Trace-only: the RAW step builder goes
         through ``jax.make_jaxpr`` with no AotSite, no probe and no
@@ -979,95 +848,60 @@ class GenerationEngine:
         S = self._pool.num_slots
         params, buffers = self._params, self._buffers
         pool = self._pool
-        scales = ()
-        if self._paged and pool.quantized:
-            scales = (pool.scales,)
+        scales = (pool.scales,) if pool.quantized else ()
 
-        if self._fused:
-            from ..ops.ragged_paged_attention import BLOCK_Q
-            T = pool.max_table_len
-            if self._spec:
-                from ..models.generation import build_spec_verify_fn
-                K = self._spec_k
-                # each speculating slot contributes k+1 ragged rows,
-                # padded to whole q blocks
-                blocks_per_slot = -(-(K + 1) // BLOCK_Q)
-                Q = self._q_bucket(S * blocks_per_slot * BLOCK_Q)
-                V = self._decoder_spec.vocab_size
-                fn = build_spec_verify_fn(
-                    self._model, S, Q, K, T, pool.block_size,
-                    top_k=self._top_k, top_p=self._top_p,
-                    quantized=pool.quantized, qmax=pool.qmax or 127.0)
-                args = (params, buffers, pool.data, *scales,
-                        np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                        np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                        np.zeros(Q // BLOCK_Q, np.int32),
-                        np.zeros(S, np.int32), np.zeros(S, np.int32),
-                        np.zeros((S, T), np.int32), np.zeros(S, np.int32),
-                        np.zeros(S, np.int32), np.zeros(S, np.int32),
-                        np.zeros(S, np.int32), np.zeros((S, K), np.int32),
-                        np.zeros((S, K, V), np.float32),
-                        np.zeros(S, bool), np.ones(S, np.float32),
-                        self._key)
-                flavor, site = "spec", f"spec_verify[q{Q},t{T}]"
-            else:
-                Q = self._q_bucket(S * BLOCK_Q)
-                if self._mesh is not None:
-                    from ..models.generation import \
-                        build_sharded_fused_step_fn
-                    fn = build_sharded_fused_step_fn(
-                        self._model, S, Q, T, pool.block_size,
-                        self._mesh, mp_axis=self._mp_axis,
-                        top_k=self._top_k, top_p=self._top_p)
-                else:
-                    from ..models.generation import build_fused_step_fn
-                    fn = build_fused_step_fn(
-                        self._model, S, Q, T, pool.block_size,
-                        top_k=self._top_k, top_p=self._top_p,
-                        quantized=pool.quantized, qmax=pool.qmax or 127.0)
-                args = (params, buffers, pool.data, *scales,
-                        np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                        np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                        np.zeros(Q // BLOCK_Q, np.int32),
-                        np.zeros(S, np.int32), np.zeros(S, np.int32),
-                        np.zeros((S, T), np.int32), np.zeros(S, np.int32),
-                        np.zeros(S, np.int32), np.zeros(S, np.int32),
-                        np.zeros(S, bool), np.ones(S, np.float32),
-                        self._key)
-                flavor, site = "fused", f"fused_step[q{Q},t{T}]"
-            donate = (2, 3) if pool.quantized else (2,)
-        elif self._paged:
-            T = pool.max_table_len
-            Q = None
+        from ..ops.ragged_paged_attention import BLOCK_Q
+        T = pool.max_table_len
+        if self._spec:
+            from ..models.generation import build_spec_verify_fn
+            K = self._spec_k
+            # each speculating slot contributes k+1 ragged rows,
+            # padded to whole q blocks
+            blocks_per_slot = -(-(K + 1) // BLOCK_Q)
+            Q = self._q_bucket(S * blocks_per_slot * BLOCK_Q)
+            V = self._decoder_spec.vocab_size
+            fn = build_spec_verify_fn(
+                self._model, S, Q, K, T, pool.block_size,
+                top_k=self._top_k, top_p=self._top_p,
+                quantized=pool.quantized, qmax=pool.qmax or 127.0)
+            args = (params, buffers, pool.data, *scales,
+                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
+                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
+                    np.zeros(Q // BLOCK_Q, np.int32),
+                    np.zeros(S, np.int32), np.zeros(S, np.int32),
+                    np.zeros((S, T), np.int32), np.zeros(S, np.int32),
+                    np.zeros(S, np.int32), np.zeros(S, np.int32),
+                    np.zeros(S, np.int32), np.zeros((S, K), np.int32),
+                    np.zeros((S, K, V), np.float32),
+                    np.zeros(S, bool), np.ones(S, np.float32),
+                    self._key)
+            flavor, site = "spec", f"spec_verify[q{Q},t{T}]"
+        else:
+            Q = self._q_bucket(S * BLOCK_Q)
             if self._mesh is not None:
                 from ..models.generation import \
-                    build_sharded_paged_decode_fn
-                fn = build_sharded_paged_decode_fn(
-                    self._model, S, T, pool.block_size, self._mesh,
-                    mp_axis=self._mp_axis, top_k=self._top_k,
-                    top_p=self._top_p)
+                    build_sharded_fused_step_fn
+                fn = build_sharded_fused_step_fn(
+                    self._model, S, Q, T, pool.block_size,
+                    self._mesh, mp_axis=self._mp_axis,
+                    top_k=self._top_k, top_p=self._top_p)
             else:
-                from ..models.generation import build_paged_decode_fn
-                fn = build_paged_decode_fn(
-                    self._model, S, T, pool.block_size,
+                from ..models.generation import build_fused_step_fn
+                fn = build_fused_step_fn(
+                    self._model, S, Q, T, pool.block_size,
                     top_k=self._top_k, top_p=self._top_p,
                     quantized=pool.quantized, qmax=pool.qmax or 127.0)
             args = (params, buffers, pool.data, *scales,
+                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
+                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
+                    np.zeros(Q // BLOCK_Q, np.int32),
                     np.zeros(S, np.int32), np.zeros(S, np.int32),
-                    np.zeros(S, np.int32), np.zeros((S, T), np.int32),
-                    np.zeros(S, bool), np.ones(S, np.float32), self._key)
-            donate = (2, 3) if pool.quantized else (2,)
-            flavor, site = "paged", f"paged_decode[t{T}]"
-        else:
-            T = Q = None
-            fn = self._decode_jit       # tracer-transparent AotSite
-            args = (params, buffers, pool.data,
+                    np.zeros((S, T), np.int32), np.zeros(S, np.int32),
                     np.zeros(S, np.int32), np.zeros(S, np.int32),
-                    np.zeros(S, np.int32), np.zeros(S, bool),
-                    np.ones(S, np.float32), self._key)
-            donate = (2,)
-            flavor, site = "dense", "decode"
-
+                    np.zeros(S, bool), np.ones(S, np.float32),
+                    self._key)
+            flavor, site = "fused", f"fused_step[q{Q},t{T}]"
+        donate = (2, 3) if pool.quantized else (2,)
         rep = liveness.callable_liveness(fn, *args, donate_argnums=donate,
                                          top_k=top_k)
 
@@ -1078,8 +912,7 @@ class GenerationEngine:
             return int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
 
         operand_pool = _nbytes(pool.data) + sum(_nbytes(s) for s in scales)
-        per_device_pool = pool.capacity_bytes if self._paged \
-            else operand_pool
+        per_device_pool = pool.capacity_bytes
         total = rep.static_peak_bytes - operand_pool + per_device_pool
 
         pk = rep.peak
@@ -1104,8 +937,7 @@ class GenerationEngine:
         if plan["fits"] is False:
             raise PlanError(
                 f"replica does not fit: static peak {total:,} B "
-                f"(largest {flavor} bucket"
-                f"{f' q{Q}' if Q else ''}{f' t{T}' if T else ''} + "
+                f"(largest {flavor} bucket q{Q} t{T} + "
                 f"pool ledger {per_device_pool:,} B) exceeds "
                 f"hbm_budget_bytes={budget:,} — fattest program point: "
                 f"{pk.primitive if pk else 'n/a'} with "
@@ -1115,146 +947,14 @@ class GenerationEngine:
         return plan
 
     # -- device side (called from the scheduler thread only) ---------------
-    def _prefill_fn(self, bucket: int):
-        fn = self._prefill_jits.get(bucket)
-        if fn is None:
-            from ..models.generation import (
-                build_paged_prefill_fn, build_sharded_paged_prefill_fn,
-                build_slot_prefill_fn)
-            probe = _probe.site(f"serving/prefill[{bucket}]#{self._eid}")
-            donate = (2,)
-            if self._mesh is not None:
-                built = build_sharded_paged_prefill_fn(
-                    self._model, bucket, self._pool.block_size,
-                    self._mesh, mp_axis=self._mp_axis,
-                    top_k=self._top_k, top_p=self._top_p, probe=probe)
-            elif self._paged:
-                built = build_paged_prefill_fn(
-                    self._model, bucket, self._pool.block_size,
-                    top_k=self._top_k, top_p=self._top_p, probe=probe,
-                    quantized=self._pool.quantized,
-                    qmax=self._pool.qmax or 127.0)
-                if self._pool.quantized:
-                    donate = (2, 3)       # pool AND its scale array
-            else:
-                built = build_slot_prefill_fn(
-                    self._model, bucket, self._pool.max_len,
-                    top_k=self._top_k, top_p=self._top_p, probe=probe)
-            fn = _registry.aot_site(
-                f"serving/prefill[{bucket}]#{self._eid}", built,
-                donate_argnums=donate)
-            self._prefill_jits[bucket] = fn
-        return fn
-
-    def _paged_decode_fn(self, table_len: int):
-        fn = self._decode_jits.get(table_len)
-        if fn is None:
-            from ..models.generation import (build_paged_decode_fn,
-                                             build_sharded_paged_decode_fn)
-            probe = _probe.site(f"serving/decode[t{table_len}]#{self._eid}")
-            if self._mesh is not None:
-                built = build_sharded_paged_decode_fn(
-                    self._model, self._pool.num_slots, table_len,
-                    self._pool.block_size, self._mesh,
-                    mp_axis=self._mp_axis, top_k=self._top_k,
-                    top_p=self._top_p, probe=probe)
-            else:
-                built = build_paged_decode_fn(
-                    self._model, self._pool.num_slots, table_len,
-                    self._pool.block_size, top_k=self._top_k,
-                    top_p=self._top_p, probe=probe,
-                    quantized=self._pool.quantized,
-                    qmax=self._pool.qmax or 127.0)
-            fn = _registry.aot_site(
-                f"serving/decode[t{table_len}]#{self._eid}", built,
-                donate_argnums=(2, 3) if self._pool.quantized else (2,))
-            self._decode_jits[table_len] = fn
-        return fn
-
-    def _run_prefill(self, req: GenerationRequest, slot: int,
-                     bucket: int) -> Optional[int]:
-        if self._fused:
-            return self._run_fused_admit(req, slot)
-        if self._paged:
-            return self._run_paged_prefill(req, slot, bucket)
-        ids = np.full((1, bucket), self._pad, np.int32)
-        ids[0, bucket - req.prompt.size:] = req.prompt
-        key_valid = np.zeros((1, bucket), bool)
-        key_valid[0, bucket - req.prompt.size:] = True
-        self._pool.data, first, self._key = self._prefill_fn(bucket)(
-            self._params, self._buffers, self._pool.data, ids, key_valid,
-            np.int32(slot), np.bool_(req.do_sample),
-            np.float32(req.temperature), self._key)
-        return int(_fetch(first)[0])
-
-    def _run_paged_prefill(self, req: GenerationRequest, slot: int,
-                           bucket: int) -> Optional[int]:
-        """Admit one request into the paged pool. On a prefix-cache hit
-        the matched blocks are adopted and prefill is SKIPPED entirely —
-        the uncovered tail (plus, after a preemption, the request's own
-        generated history) replays through the shared decode step, one
-        token per cycle, predictions discarded until the replay drains.
-        Replay costs one decode cycle PER TOKEN, so the hit is only
-        taken when the tail fits one ``min_bucket`` (a smallest
-        prefill's worth); a longer tail prefills the whole feed fresh
-        instead — one prefill call beats a tail-long replay, and the
-        shared blocks are still deduplicated in the cache. On a miss
-        the whole feed prefills into freshly allocated blocks and its
-        full token blocks are published to the prefix cache."""
-        pool = self._pool
-        # a re-admitted (preempted) request replays prompt + everything
-        # it already generated; a fresh request's feed IS its prompt
-        feed = np.concatenate(
-            [req.prompt, np.asarray(req.tokens, np.int32)])
-        cached = pool.match_prefix(feed)
-        if cached and feed.size - len(cached) * pool.block_size \
-                > pool.min_bucket:
-            cached = []                   # tail too long: prefill wins
-        if cached:
-            pool.admit_cached(slot, cached)
-            # tier split: a hit served through a just-landed promotion
-            # is a HOST-tier hit; a plain trie hit never left HBM
-            pool.note_tier_hit(
-                "host" if req._tier_promoted else "hbm")
-            m = len(cached) * pool.block_size
-            pool.set_slot(slot, pos=m, lo=0)
-            req.last_token = int(feed[m])
-            req.replay = [int(t) for t in feed[m + 1:]]
-            req.trace.mark("prefix_hit", tokens_saved=m,
-                           replay=len(req.replay))
-            return None
-        pool.note_tier_hit("miss")
-        blocks = pool.admit_fresh(slot, feed.size)
-        table = np.zeros(bucket // pool.block_size, np.int32)
-        table[:len(blocks)] = blocks      # padding -> the scratch block
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :feed.size] = feed         # RIGHT-padded: virtual index 0
-        key_valid = np.zeros((1, bucket), bool)
-        key_valid[0, :feed.size] = True
-        args = (ids, key_valid, table, np.int32(feed.size),
-                np.bool_(req.do_sample), np.float32(req.temperature),
-                self._key)
-        if pool.quantized:
-            pool.data, pool.scales, first, self._key = \
-                self._prefill_fn(bucket)(self._params, self._buffers,
-                                         pool.data, pool.scales, *args)
-        else:
-            pool.data, first, self._key = self._prefill_fn(bucket)(
-                self._params, self._buffers, pool.data, *args)
-        pool.set_slot(slot, pos=feed.size, lo=0)
-        pool.register_prefix(slot, feed)
-        req.replay = []
-        return int(_fetch(first)[0])
-
-    def _run_fused_admit(self, req: GenerationRequest,
-                         slot: int) -> None:
-        """Admit one request into the FUSED engine: pure host
+    def _run_admit(self, req: GenerationRequest, slot: int) -> None:
+        """Admit one request (the scheduler's ``do_prefill``): pure host
         bookkeeping, no prefill program. Blocks covering the whole feed
         are reserved, a prefix-cache match adopts its blocks (ANY tail
-        length — chunks drain a long tail in budgeted launches, so the
-        gather path's one-``min_bucket`` decline heuristic is obsolete
-        here), and the remaining tokens arm ``req.pending_feed`` for
-        the per-cycle chunk plan."""
+        length — chunks drain a long tail in budgeted launches), and the
+        remaining tokens arm ``req.pending_feed`` for the per-cycle
+        chunk plan. A re-admitted (preempted) request's feed is its
+        prompt plus everything it already generated."""
         pool = self._pool
         if self._spec:
             # this slot's previous occupant's draft cache is stale: the
@@ -1278,8 +978,6 @@ class GenerationEngine:
             # position 0 is where the first pending token's K/V land
             pool.set_slot(slot, pos=0, lo=0)
             req.pending_feed = [int(t) for t in feed]
-        req.replay = []
-        return None
 
     def _ragged_operands(self, slot_requests, plan, spec=None):
         """Host-side flattened ragged-row operands shared by the fused
@@ -1367,8 +1065,8 @@ class GenerationEngine:
                 n_spec, sample_mask, temps)
 
     def _run_fused_step(self, slot_requests, plan):
-        """Dispatch ONE fused ragged launch (the chunked-mode
-        do_chunked_step): budgeted prompt chunks + decode rows,
+        """Dispatch ONE fused ragged launch (the scheduler's
+        ``do_chunked_step``): budgeted prompt chunks + decode rows,
         flattened into the padded row layout of
         ``ops.ragged_paged_attention`` and served by the
         ``build_fused_step_fn`` program for this (q bucket, table
@@ -1390,8 +1088,7 @@ class GenerationEngine:
 
     def _q_bucket(self, rows: int) -> int:
         """pow2 bucket over the launch's padded q rows — one fused
-        trace per (q bucket, table bucket), the ragged twin of the
-        prefill-bucket discipline."""
+        trace per (q bucket, table bucket)."""
         from ..ops.ragged_paged_attention import BLOCK_Q
         b = BLOCK_Q
         while b < rows:
@@ -1483,10 +1180,11 @@ class GenerationEngine:
         self._draft_synced[:] = False
 
     def _draft_bucket(self, n: int) -> int:
-        """pow2 context bucket for the draft prefill, capped at the
+        """pow2 context bucket (from ``min_bucket``) for the draft
+        prefill, capped at the
         draft pool's max_len (the cap is reachable because a slot's
         context is always < max_len)."""
-        b = 8
+        b = self._min_bucket
         while b < n:
             b *= 2
         return min(b, self._draft_max_len)
@@ -1643,44 +1341,6 @@ class GenerationEngine:
         self._note_decode_dispatch(step)
         return out
 
-    def _run_decode(self, slot_requests):
-        """Dispatch ONE decode step; returns the next-token DEVICE
-        array — the scheduler performs the windowed ``_fetch`` itself so
-        its cycle telemetry can time dispatch and host-fetch apart."""
-        S = self._pool.num_slots
-        tokens = np.zeros(S, np.int32)
-        sample_mask = np.zeros(S, bool)
-        temps = np.ones(S, np.float32)
-        for slot, req in slot_requests.items():
-            tokens[slot] = req.last_token
-            sample_mask[slot] = req.do_sample
-            temps[slot] = req.temperature
-        pos, lo = self._pool.position_arrays()
-        if self._paged:
-            # the cohort decodes at the largest member's pow2 table
-            # bucket (shorter tables pad with the scratch block) — one
-            # trace per bucket, exactly the prefill-bucket discipline
-            T = max(self._pool.table_bucket(s) for s in slot_requests)
-            tables = self._pool.table_array(T, slot_requests)
-            step = self._paged_decode_fn(T)
-            if self._pool.quantized:
-                (self._pool.data, self._pool.scales, nxt,
-                 self._key) = step(
-                    self._params, self._buffers, self._pool.data,
-                    self._pool.scales, tokens, pos, lo, tables,
-                    sample_mask, temps, self._key)
-            else:
-                self._pool.data, nxt, self._key = step(
-                    self._params, self._buffers, self._pool.data, tokens,
-                    pos, lo, tables, sample_mask, temps, self._key)
-            self._note_decode_dispatch(step)
-            return nxt
-        self._pool.data, nxt, self._key = self._decode_jit(
-            self._params, self._buffers, self._pool.data, tokens, pos, lo,
-            sample_mask, temps, self._key)
-        self._note_decode_dispatch(self._decode_jit)
-        return nxt
-
     def _note_decode_dispatch(self, step) -> None:
         """Account the FLOPs of the decode program that actually ran
         this cycle (host arithmetic only): lifetime counters for the
@@ -1699,8 +1359,8 @@ class GenerationEngine:
 
     def _run_copy(self, dst: int, src: int) -> None:
         """Copy-on-write append support: device-copy block ``src`` over
-        block ``dst`` across every layer/kv plane before the decode step
-        scatters into ``dst`` — a quantized pool copies the block's
+        block ``dst`` across every layer/kv plane before the step
+        writes into ``dst`` — a quantized pool copies the block's
         per-(layer, kv, head) scales in the same program, so the clone
         dequantizes identically. Block ids are traced scalars — ONE
         trace serves every copy — and the pool (and scale array) is

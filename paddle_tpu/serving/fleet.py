@@ -18,7 +18,7 @@ the error propagate). Two opt-in policies land on top of the same
 spill machinery (``route=``):
 
 * ``"load"`` — rank replicas by MOST FREE BLOCKS from the per-replica
-  health gauges (free slots as the dense fallback), unhealthy last,
+  health gauges (free slots as the tie-breaker), unhealthy last,
   round-robin rotation breaking ties so equal replicas still share
   admissions;
 * ``"affinity"`` — the prompt's block-aligned prefix (the exact unit
@@ -98,7 +98,7 @@ class EngineFleet:
         self._name = name or f"fleet{next(_fleet_seq)}"
         self._route = route
         # affinity prefix granularity: explicit, else the replicas' own
-        # paged block_size (read lazily from stats), else one min-bucket
+        # block_size (read lazily from stats), else 8 tokens
         self._affinity_block = (int(affinity_block)
                                 if affinity_block is not None else None)
         # prefix-hash -> replica index (host dict, lock-guarded); the
@@ -146,8 +146,8 @@ class EngineFleet:
 
     def _load_order(self) -> List[int]:
         """Rotation order re-ranked by load: healthy replicas first,
-        MOST free blocks first (free slots as the dense tie-breaker /
-        fallback), the round-robin rotation breaking exact ties — a
+        MOST free blocks first (free slots as the tie-breaker), the
+        round-robin rotation breaking exact ties — a
         stable sort over the rotated list, so equally-loaded replicas
         still take turns."""
         reps = {r["replica"]: r for r in self._replica_stats()}

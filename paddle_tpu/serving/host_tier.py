@@ -75,7 +75,7 @@ __all__ = ["HostBlockPool", "PromotionTicket", "HostTierError",
            "HostTierFullError"]
 
 # process-wide tier numbering for the host ledger keys (mirrors the
-# pool-ledger discipline in kv_pool.py)
+# pool-ledger discipline in paging.py)
 _tier_ids = itertools.count(1)
 
 _END = object()                      # queue sentinel (io.device_prefetch)
@@ -94,7 +94,7 @@ class HostTierFullError(HostTierError):
 
 def _drop_tier_ledger(ledger_key: str) -> None:
     """weakref.finalize target — module function so the finalizer holds
-    no reference to the tier (kv_pool.py idiom)."""
+    no reference to the tier (paging.py idiom)."""
     _memory.ledger_drop(f"{ledger_key}/capacity")
     _memory.ledger_drop(f"{ledger_key}/in_use")
 

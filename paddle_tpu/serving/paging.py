@@ -1,75 +1,83 @@
 """Paged KV-cache memory manager: block-granular pooling + prefix cache.
 
-The dense :class:`~.kv_pool.KVCachePool` reserves a full
-``[heads, max_len, head_dim]`` stripe per slot, so concurrency is capped
-by WORST-CASE sequence length even when most requests are short — the
-fragmentation problem paged, block-granular KV management solves on TPU
-(the Ragged-Paged-Attention argument, PAPERS.md). Here the device pool
-is ``[layers, num_blocks + 1, heads, block_size, 2 * head_dim]`` (K|V
-folded into the lanes — the ONE layout every reader of the pool uses,
-see ops/ragged_paged_attention.py): a
-request owns only the blocks covering its tokens SO FAR, addressed
-through a per-request page table that maps virtual cache index
-``i`` to ``(table[i // block_size], i % block_size)``. Physical block 0
-is a reserved SCRATCH block — page-table padding points at it, prefill
-pad-position garbage lands in it, and nothing ever reads it through an
-unmasked position.
+THE pool of the serving engine. The device array is ``[layers,
+num_blocks + 1, heads, block_size, 2 * head_dim]`` (K|V folded into the
+lanes — the ONE layout every reader of the pool uses, see
+ops/ragged_paged_attention.py): a request owns only the blocks covering
+its tokens SO FAR, addressed through a per-request page table that maps
+virtual cache index ``i`` to ``(table[i // block_size], i %
+block_size)``, so concurrency is bounded by the tokens in flight and not
+by worst-case sequence length (the Ragged-Paged-Attention argument,
+PAPERS.md). Physical block 0 is a reserved SCRATCH block — page-table
+padding points at it, pad rows' garbage lands in it, and nothing ever
+reads it through an unmasked position.
 
 Host-side manager (this module, scheduler-thread-owned):
 
+* **request slots** — the launch's batch axis: deterministic
+  lowest-index allocation, per-slot ``pos`` (cache index of the next
+  write) and ``lo`` (first valid index; 0 — paged sequences are aligned
+  at virtual index 0, so block contents depend only on the token prefix,
+  which is what makes them shareable across requests);
 * **free-list block allocator** — blocks move between the free list,
   request page tables (refcounted), and the prefix cache's LRU of
   released-but-reusable blocks;
-* **page tables in pow2 buckets** — the decode step's table width is
-  the next power of two over the blocks a request holds (capped at
-  ``max_table_len``), so there is ONE decode trace per table bucket,
-  never one per table length — the serving twin of the dense engine's
-  pow2 prompt buckets;
+* **page tables in pow2 buckets** — a launch's table width is the next
+  power of two over the blocks its longest request holds (capped at
+  ``max_table_len``), so there is one program per table bucket, never
+  one per table length;
 * **refcounts + copy-on-write** — a block reachable from several page
   tables (prefix sharing) is never written through; the manager's
-  ``ensure_writable`` hands the engine a ``(dst, src)`` copy order and
-  swaps the table entry, so appends always hit a refcount-1 block. By
-  construction shared blocks sit strictly below every sharer's write
+  ``ensure_writable_range`` hands the engine ``(dst, src)`` copy orders
+  and swaps the table entries, so appends always hit a refcount-1 block.
+  By construction shared blocks sit strictly below every sharer's write
   position (reuse is capped at ``(len - 1) // block_size`` full
   blocks), so COW is a guard rail, not a hot path;
 * **prefix-cache trie** — full token blocks are registered under their
   token-prefix key (the dict key IS the exact prefix tuple, so "hash"
   collisions cannot alias two different prefixes); a later request
-  whose prompt starts with the same full blocks reuses their K/V and
-  skips prefill entirely (the remaining tokens are replayed through the
-  shared decode step, one per cycle — which is why the ENGINE only
-  takes the hit when the uncovered tail fits one ``min_bucket``; a
-  longer tail prefills fresh instead). Released cached blocks wait in
-  an LRU; allocation pressure evicts the oldest refcount-0 entry (and
-  unregisters its now-unreachable descendants) before giving up.
-
-Virtual layout note: unlike the dense pool's left-padded capacity
-buckets, paged sequences are aligned at virtual index 0 (``lo == 0``) —
-block contents then depend only on the token prefix, which is what
-makes them shareable across requests and prompt lengths.
+  whose prompt starts with the same full blocks adopts their K/V and
+  feeds only the uncovered tail, in chunks, through the fused step.
+  Released cached blocks wait in an LRU; allocation pressure evicts the
+  oldest refcount-0 entry (and unregisters its now-unreachable
+  descendants) before giving up.
 
 Monitor wiring (PR-1): ``serving/kv_blocks_in_use`` histogram,
 ``serving/prefix_hit`` / ``serving/prefix_miss`` /
 ``serving/prefill_tokens_saved`` / ``serving/prefix_evict`` counters
 (``serving/preempt`` is counted by the scheduler's preemption path).
 
-Threading contract: exactly the dense pool's — the manager is owned by
-the scheduler thread; ``data`` is rebound by the engine after every
-donated step.
+Threading contract: the manager is owned by the scheduler thread;
+``alloc`` / ``free`` / ``set_slot`` are only called from it. ``data`` is
+rebound by the engine after every donated step (the old array is deleted
+by XLA — donation — so nothing else may hold it).
 """
 from __future__ import annotations
 
 import heapq
+import itertools
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..framework.monitor import stat_add, stat_observe
-from .kv_pool import SlotPoolBase
+from ..profiler import memory as _memory
 
 __all__ = ["PagedKVPool", "PoolCapacityError", "PoolExhaustedError",
            "BlockError"]
+
+
+# process-wide pool numbering for the HBM ledger keys (two engines in
+# one process must not alias each other's ledger entries)
+_pool_ids = itertools.count(1)
+
+
+def _drop_pool_ledger(ledger_key: str) -> None:
+    """weakref.finalize target for a pool's ledger entries — a module
+    function so the finalizer holds no reference to the pool."""
+    _memory.ledger_drop(f"{ledger_key}/capacity")
+    _memory.ledger_drop(f"{ledger_key}/in_use")
 
 
 class PoolCapacityError(ValueError):
@@ -111,24 +119,17 @@ class _TrieNode:
         self.children: set = set()      # child keys (one block longer)
 
 
-class PagedKVPool(SlotPoolBase):
-    """Block-pooled KV cache + page-table/prefix-cache manager.
+class PagedKVPool:
+    """Block-pooled KV cache + slot/page-table/prefix-cache manager.
 
     ``data`` is the jnp array ``[layers, num_blocks + 1, heads,
     block_size, 2 * head_dim]`` (block 0 = scratch; a row's lanes hold
-    K then V); the engine threads it
-    through the donated paged prefill/decode steps and rebinds it here.
-    ``num_slots`` bounds concurrent REQUESTS (the decode batch axis),
-    ``num_blocks`` bounds their total KV footprint — with mixed lengths
-    the block budget, not the slot count, is what fills first, and a
-    same-device-budget paged pool admits strictly more concurrent
-    requests than the dense pool (tests/test_serving_paging.py).
+    K then V); the engine threads it through the donated fused step and
+    rebinds it here. ``num_slots`` bounds concurrent REQUESTS (the
+    launch's batch axis), ``num_blocks`` bounds their total KV footprint
+    — with mixed lengths the block budget, not the slot count, is what
+    fills first.
     """
-
-    is_paged = True
-    _slot_cls = _PagedSlot
-    _capacity_noun = "virtual capacity"
-    _admission_law = "prompt + max_new <= max_len"
 
     #: storage dtypes quantized with per-block max-abs scales (the
     #: EQuARX per-chunk scheme of the PR-10 gradient wire, applied to
@@ -138,7 +139,7 @@ class PagedKVPool(SlotPoolBase):
     def __init__(self, num_layers: int, num_slots: int, num_heads: int,
                  max_len: int, head_dim: int, *, block_size: int = 16,
                  num_blocks: Optional[int] = None, dtype="float32",
-                 min_bucket: int = 8, mesh=None, mp_axis: str = "mp",
+                 mesh=None, mp_axis: str = "mp",
                  lanes: Optional[int] = None):
         import jax.numpy as jnp
 
@@ -147,15 +148,8 @@ class PagedKVPool(SlotPoolBase):
         if block_size < 1 or (block_size & (block_size - 1)):
             raise ValueError(
                 f"block_size must be a power of two, got {block_size}")
-        if min_bucket < block_size or min_bucket % block_size:
-            raise ValueError(
-                f"min_bucket={min_bucket} must be a multiple of "
-                f"block_size={block_size} (prefill buckets scatter whole "
-                f"blocks)")
-        if max_len < min_bucket:
-            raise ValueError(
-                f"max_len={max_len} is below min_bucket={min_bucket}: no "
-                f"prompt could ever be admitted")
+        if max_len < 1:
+            raise ValueError(f"max_len must be >= 1, got {max_len}")
         self.num_layers = int(num_layers)
         self.num_slots = int(num_slots)
         self.num_heads = int(num_heads)
@@ -166,13 +160,12 @@ class PagedKVPool(SlotPoolBase):
         # ``num_heads=1, lanes=descriptor's``: models/decoder_spec.py)
         self.lanes = int(lanes) if lanes else 2 * self.head_dim
         self.block_size = int(block_size)
-        self.min_bucket = int(min_bucket)
         # blocks a single request can ever hold (covers [0, max_len))
         self.max_table_len = -(-self.max_len // self.block_size)
         if num_blocks is None:
-            # dense-equivalent device budget: every slot could still go
-            # the full max_len (callers shrink this to realise the
-            # capacity win; see README "paged vs dense")
+            # worst-case budget: every slot could still go the full
+            # max_len (callers shrink this to what the device holds —
+            # admission then gates on blocks and pressure preempts)
             num_blocks = self.num_slots * self.max_table_len
         self.num_blocks = int(num_blocks)
         if self.num_blocks < self.max_table_len:
@@ -213,18 +206,30 @@ class PagedKVPool(SlotPoolBase):
                        if self.quantized else None)
         self.data = self._alloc_data()
         # min-heap: deterministic lowest-id allocation at O(log n) —
-        # unlike the base slot list (num_slots entries), num_blocks is
+        # unlike the slot list (num_slots entries), num_blocks is
         # production-large and a min()+remove() scan per block would
-        # sit on the per-decode-cycle hot path
+        # sit on the per-cycle hot path
         self._free: List[int] = list(range(1, self.num_blocks + 1))
         self._ref: Dict[int, int] = {}            # block -> request refs
         # prefix cache: exact-prefix-keyed trie + LRU of released blocks
-        # (before _init_slots: the base ctor publishes the HBM ledger
-        # entry, whose in-use figure reads blocks_in_use -> _lru)
+        # (before the ledger entry below is published: its in-use figure
+        # reads blocks_in_use -> _lru)
         self._trie: Dict[Tuple[int, ...], _TrieNode] = {}
         self._block_key: Dict[int, Tuple[int, ...]] = {}
         self._lru: "OrderedDict[Tuple[int, ...], _TrieNode]" = OrderedDict()
-        self._init_slots()                        # request slots (base)
+        # request slots: lowest-index-first keeps slot assignment
+        # deterministic (tests and trace/debug output stay stable
+        # across runs)
+        import weakref
+        self._free_slots: List[int] = list(range(self.num_slots))
+        self._slots: Dict[int, _PagedSlot] = {}
+        self.ledger_key = f"serving/kv_pool#{next(_pool_ids)}"
+        # a pool dropped WITHOUT engine.close() (exception paths, tests
+        # building pools directly) must not haunt crosscheck()/OOM
+        # postmortems with phantom KV bytes — same finalizer discipline
+        # as the hapi train-state ledger keys
+        weakref.finalize(self, _drop_pool_ledger, self.ledger_key)
+        self._update_ledger()
         # pool-local prefix stats (engine.stats() reads these without
         # scraping process-global monitor counters)
         self.prefix_hits = 0
@@ -255,14 +260,103 @@ class PagedKVPool(SlotPoolBase):
             self.mesh, P(None, None, self.mp_axis, None, None))
         return jax.device_put(jnp.zeros(self.shape, self.dtype), sh)
 
-    # -- request slots (decode batch axis: SlotPoolBase) -------------------
-    def _slot_freed(self, st: _PagedSlot) -> None:
-        """free() teardown: unref every block in the slot's page table.
-        Refcount-0 cached blocks stay in the prefix cache (LRU,
-        evictable); uncached ones return to the free list."""
+    # -- HBM ledger (profiler/memory.py) -----------------------------------
+    def _update_ledger(self) -> None:
+        """Publish capacity + in-use bytes into the process HBM ledger
+        (the 'what we think is live' side of the ledger-vs-device
+        crosscheck). Host dict stores only — called from alloc/free and
+        the block hooks, all scheduler-thread, all sync-free."""
+        _memory.ledger_set(f"{self.ledger_key}/capacity",
+                           self.capacity_bytes)
+        _memory.ledger_set(f"{self.ledger_key}/in_use", self.bytes_in_use)
+
+    def drop_ledger(self) -> None:
+        """Remove this pool's ledger entries (engine close): the pool
+        array may outlive the engine object briefly, but a closed
+        engine's pool is no longer an accounted owner."""
+        _drop_pool_ledger(self.ledger_key)
+
+    # -- request slots (the launch's batch axis) ---------------------------
+    def alloc(self) -> Optional[int]:
+        """Claim the lowest free slot, or None when the pool is full."""
+        if not self._free_slots:
+            return None
+        slot = min(self._free_slots)
+        self._free_slots.remove(slot)
+        self._slots[slot] = _PagedSlot()
+        self._update_ledger()
+        _memory.mark("kv/alloc", pool=self.ledger_key, slot=slot,
+                     in_use=self.bytes_in_use)
+        return slot
+
+    def free(self, slot: int) -> None:
+        """Return ``slot`` to the free list and unref every block in its
+        page table: refcount-0 cached blocks stay in the prefix cache
+        (LRU, evictable), uncached ones return to the free list. Device
+        rows are NOT cleared — attention never looks past ``pos``, so
+        stale K/V are unreachable by construction."""
+        if slot not in self._slots:
+            raise ValueError(f"slot {slot} is not allocated")
+        st = self._slots.pop(slot)
         for b in st.table:
             self._unref(b)
         self._observe()
+        self._free_slots.append(slot)
+        self._update_ledger()
+        _memory.mark("kv/free", pool=self.ledger_key, slot=slot,
+                     in_use=self.bytes_in_use)
+
+    def is_allocated(self, slot: int) -> bool:
+        return slot in self._slots
+
+    @property
+    def n_active(self) -> int:
+        return len(self._slots)
+
+    @property
+    def n_free(self) -> int:
+        return len(self._free_slots)
+
+    def active_slots(self) -> List[int]:
+        return sorted(self._slots)
+
+    def set_slot(self, slot: int, *, pos: int, lo: int) -> None:
+        st = self._slots[slot]
+        if not 0 <= lo <= pos < self.max_len:
+            raise ValueError(
+                f"slot {slot}: bad position state lo={lo} pos={pos} "
+                f"(max_len={self.max_len})")
+        st.pos = int(pos)
+        st.lo = int(lo)
+
+    def advance(self, slot: int, n: int = 1) -> int:
+        """``n`` tokens landed (a decode row, or one prefill chunk of
+        the fused ragged step): the slot's write position moves ``n``
+        cache indices later. ``n`` is a SIGNED delta — the
+        speculative-decoding scheduler rolls back the rows a rejected
+        draft wrote with a negative ``n`` (page tables address by
+        ``pos``, so rollback is pure bookkeeping: the stale K/V beyond
+        the new ``pos`` are masked out of attention and overwritten by
+        the next append). Returns the new ``pos``."""
+        if n == 0:
+            raise ValueError("advance needs n != 0")
+        st = self._slots[slot]
+        new_pos = st.pos + int(n)        # validate BEFORE mutating: a
+        if new_pos >= self.max_len:      # rejected advance must leave
+            raise RuntimeError(          # the slot state untouched
+                f"slot {slot} overran the virtual capacity "
+                f"{self.max_len} — the admission check "
+                f"(prompt + max_new <= max_len) is broken")
+        if new_pos < st.lo:
+            raise RuntimeError(
+                f"slot {slot}: rollback below the slot's floor "
+                f"(pos={new_pos} < lo={st.lo}) — a speculative rollback "
+                f"may only unwind rows written this cycle")
+        st.pos = new_pos
+        return st.pos
+
+    def slot_pos(self, slot: int) -> int:
+        return self._slots[slot].pos
 
     def reset_data(self) -> None:
         """Reallocate the (donated, possibly already-deleted) device
@@ -287,9 +381,6 @@ class PagedKVPool(SlotPoolBase):
         # (content is a pure function of the prefix key)
         self._tier_pending.clear()
         self._observe()
-
-    # (per-slot position tracking and the pow2 capacity buckets are the
-    # SlotPoolBase implementations, shared verbatim with the dense pool)
 
     # -- block bookkeeping -------------------------------------------------
     def blocks_for(self, n_tokens: int) -> int:
@@ -367,8 +458,8 @@ class PagedKVPool(SlotPoolBase):
 
     @property
     def bytes_in_use(self) -> int:
-        """Block-granular override of the base's whole-slot accounting:
-        only blocks referenced by live page tables count."""
+        """Bytes claimed by live requests: only blocks referenced by
+        page tables count."""
         return self.blocks_in_use * self.block_bytes
 
     def can_admit(self, n_tokens: int) -> bool:
@@ -720,21 +811,11 @@ class PagedKVPool(SlotPoolBase):
             if key is not None:
                 self._drop_node(key)
 
-    # -- decode-time growth + copy-on-write --------------------------------
-    def ensure_writable(self, slot: int) -> Optional[Tuple[int, int]]:
-        """Guarantee the block holding virtual index ``pos`` exists and
-        is exclusively owned before the decode step scatters into it.
-        Returns ``(dst, src)`` when the engine must device-copy a
-        shared block first (copy-on-write append), else None. May raise
-        :class:`PoolExhaustedError` — the scheduler's preemption
-        trigger."""
-        st = self._require(slot)
-        return self._ensure_block(slot, st, st.pos // self.block_size)
-
+    # -- growth + copy-on-write --------------------------------------------
     def ensure_writable_range(self, slot: int,
                               last_pos: int) -> List[Tuple[int, int]]:
-        """Chunked-prefill variant: guarantee EVERY block covering
-        virtual indices ``[pos, last_pos]`` exists and is exclusively
+        """Before a launch writes a slot's rows: guarantee EVERY block
+        covering virtual indices ``[pos, last_pos]`` exists and is exclusively
         owned (a chunk scatters a run of positions in one fused
         launch). Returns the copy-on-write ``(dst, src)`` orders, in
         virtual-block order. May raise :class:`PoolExhaustedError`

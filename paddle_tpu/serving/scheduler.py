@@ -1,35 +1,27 @@
-"""Continuous-batching scheduler: admit, decode, retire — every step.
+"""Continuous-batching scheduler: admit, launch, retire — every cycle.
 
 The loop at the heart of ``GenerationEngine``. Unlike the gather-and-run
 ``inference.BatchingEngine`` (whole batch enters and leaves together),
-membership of the in-flight batch changes EVERY step:
+membership of the in-flight batch changes EVERY cycle:
 
 * **admit** — pop from the bounded admission queue into free pool
   slots under WEIGHTED-FAIR scheduling: queued requests are classed by
   (lane, tenant) and served by weighted deficit-round-robin (priority
   lanes — ``interactive`` outweighs ``batch`` 4:1 by default, so a
   batch prompt flood cannot starve interactive TTFT while idle
-  capacity still flows to batch; one queued class degenerates to the
-  old FCFS exactly); one prefill per admitted request, under a
-  PREFILL BUDGET
-  (tokens per cycle): a burst of long prompts may not starve the slots
-  already decoding — when the budget is spent the remaining queue waits
-  one decode step (counted as ``serving/preempt``);
-* **decode** — ONE jitted, pool-donated step advances every active slot
-  by one token (inactive slots compute garbage nobody reads); the
-  single host fetch per cycle delivers each new token to its stream;
+  capacity still flows to batch; one queued class degenerates to
+  FCFS exactly). Admission is pure host bookkeeping: blocks reserved
+  (a prefix-cache match adopted), ``req.pending_feed`` armed;
+* **launch** — ONE fused ragged, pool-donated launch a cycle mixes
+  ``prefill_budget`` tokens of prompt chunks with every decode row.
+  Decode is never budget-charged, so a prompt burst cannot monopolize a
+  cycle, and the first generated token emits from the launch that feeds
+  the final chunk (``serving/prefill_chunks``/``serving/chunk_tokens``,
+  per-cycle ``chunk_tokens`` in the flight recorder); the single host
+  fetch per cycle delivers each new token to its stream;
 * **retire** — finished (EOS / token budget), cancelled and
   deadline-expired slots are freed IMMEDIATELY, so their capacity is
   reused by the very next admit — mid-flight, not at batch end.
-
-CHUNKED mode (the fused ragged engine, ``do_chunked_step``): admission
-becomes pure host bookkeeping (blocks reserved, ``req.pending_feed``
-armed) and each cycle runs ONE fused ragged launch mixing
-``prefill_budget`` tokens of prompt chunks with every decode row —
-decode is never budget-charged, so a prompt burst cannot monopolize a
-cycle, and the first generated token emits from the launch that feeds
-the final chunk (``serving/prefill_chunks``/``serving/chunk_tokens``,
-per-cycle ``chunk_tokens`` in the flight recorder).
 
 Backpressure is explicit: a full queue raises :class:`QueueFullError`
 in ``submit`` (the caller sheds load, nothing queues unboundedly), and
@@ -42,7 +34,7 @@ Observability (the serving SLO spine, ISSUE 6): every request carries a
 finish/cancel/deadline, plus preemptions and prefix hits), from which
 TTFT and TPOT derive per request; every CYCLE writes a record into the
 always-on bounded :class:`~.flight_recorder.FlightRecorder` (sweep /
-admit / prefill / decode-dispatch / host-fetch breakdown, occupancy,
+admit / plan / decode-dispatch / host-fetch / emit breakdown, occupancy,
 queue depth) so a scheduler stall is debuggable postmortem without the
 profiler armed. When a ``profiler.profile()`` session IS armed, the
 same phases additionally emit nested ``serving/cycle`` spans and each
@@ -117,8 +109,8 @@ _DONE = object()          # stream terminator sentinel
 
 
 def _fetch(device_array):
-    """THE one device→host sync of the serving loop: one fetch per decode
-    cycle (a batch of tokens), one per prefill (the first token). Every
+    """THE one device→host sync of the serving loop: one fetch per
+    cycle (the launch's batch of tokens). Every
     other transfer in this package is host→device and async. The rule
     below is the package-wide lint (analysis/selflint.py
     ``serving-host-sync``); this call site is the argued exception."""
@@ -154,8 +146,8 @@ class GenerationRequest:
         # one class, which degenerates to the old FCFS order exactly
         self.tenant = str(tenant)
         self.lane = str(lane)
-        self._preempted = False     # replay victims outrank the queue
-        # hierarchical-KV promotion state (paged engines with a host
+        self._preempted = False     # preemption victims outrank the queue
+        # hierarchical-KV promotion state (engines with a host
         # tier): the in-flight PromotionTicket this request waits on,
         # and whether its admission was served through a promotion
         # (engine classifies the hit as tier=host)
@@ -168,16 +160,12 @@ class GenerationRequest:
         self.tokens: List[int] = []     # generated so far (incl. EOS)
         self.emitted = 0
         self.last_token: Optional[int] = None
-        # paged engines only: prompt/generated tokens still to be fed
-        # through the decode step WITHOUT emitting (prefix-cache hits
-        # skip prefill; preempted requests replay their own history on
-        # re-admission). Rebuilt at every admission.
-        self.replay: List[int] = []
-        # fused (chunked-prefill) engines only: the not-yet-fed feed
-        # tokens — drained in token-budget chunks through the fused
-        # ragged step, mixed into decode launches. Rebuilt at every
-        # admission; the first generated token emits from the launch
-        # that feeds the final chunk.
+        # the not-yet-fed feed tokens (the prompt past a prefix-cache
+        # hit; after a preemption, the request's own history too) —
+        # drained in token-budget chunks through the fused ragged step,
+        # mixed into decode launches. Rebuilt at every admission; the
+        # first generated token emits from the launch that feeds the
+        # final chunk.
         self.pending_feed: List[int] = []
         self.first_token_at: Optional[float] = None
         self._last_token_at: Optional[float] = None
@@ -205,7 +193,7 @@ class GenerationRequest:
 
     def stream(self):
         """Iterator of generated token ids, yielded as each is produced
-        (the first right after prefill). Raises the terminal error
+        (the first from the launch that fed the prompt's last chunk). Raises the terminal error
         (:class:`RequestCancelled` / :class:`DeadlineExceeded`) after
         any tokens produced before it."""
         _prof.set_thread_name(
@@ -252,7 +240,7 @@ class GenerationRequest:
                 self._recorder.record_event(self.id, "first_token", t=now)
         else:
             # the streaming cadence: one inter-token sample per decoded
-            # token after the first (replayed tokens never land here)
+            # token after the first (re-fed tokens never land here)
             stat_observe("serving/tpot_ms",
                          (now - self._last_token_at) * 1e3)
         self._last_token_at = now
@@ -289,26 +277,35 @@ class GenerationRequest:
 
 
 class Scheduler:
-    """The continuous-batching loop over a :class:`~.kv_pool.KVCachePool`.
+    """The continuous-batching loop over a :class:`~.paging.PagedKVPool`.
 
-    Device work is delegated to two engine-provided callables so the
+    Device work is delegated to engine-provided callables so the
     policy here stays host-pure and unit-testable:
 
-    * ``do_prefill(request, slot, bucket) -> first_token`` — run the
-      bucket's prefill step, write the slot, return the first token;
-    * ``do_decode(slot_requests) -> [num_slots] token array`` — DISPATCH
-      the shared decode step and return its result UN-fetched (a device
-      array; plain numpy passes through): the scheduler performs the
-      windowed ``_fetch`` itself so the cycle telemetry can time
-      dispatch and host-fetch apart — a do_decode that syncs internally
-      would hide the fetch inside ``decode_dispatch_ms``. Every slot
-      gets a token (garbage for inactive slots).
+    * ``do_prefill(request, slot)`` — the admission hook: reserve the
+      feed's blocks (adopting a prefix-cache match), set the slot's
+      position, arm ``request.pending_feed``. No program runs;
+    * ``do_chunked_step(slot_requests, plan) -> token array`` —
+      DISPATCH the cycle's ONE ragged launch (``plan``: rows a slot —
+      budgeted prompt chunks and the decode rows) and return its result
+      UN-fetched (a device array; plain numpy passes through): the
+      scheduler performs the windowed ``_fetch`` itself so the cycle
+      telemetry can time dispatch and host-fetch apart — a step that
+      syncs internally would hide the fetch inside
+      ``decode_dispatch_ms``. Every slot gets a token (garbage for
+      inactive and mid-feed slots);
+    * ``do_copy(dst, src)`` — device block copy (copy-on-write append);
+    * ``do_spec_step(slot_requests, plan, spec)`` — the speculative
+      verify launch, see below.
+
+    ``prefill_budget`` is the per-cycle CHUNK token budget: decode rows
+    are never charged, so a prompt burst cannot monopolize a cycle.
     """
 
-    def __init__(self, pool, do_prefill: Callable, do_decode: Callable, *,
+    def __init__(self, pool, do_prefill: Callable,
+                 do_chunked_step: Callable, *,
                  max_queue: int = 128, prefill_budget: Optional[int] = None,
                  do_copy: Optional[Callable] = None,
-                 do_chunked_step: Optional[Callable] = None,
                  do_spec_step: Optional[Callable] = None,
                  spec_k: int = 0,
                  recorder: Optional[FlightRecorder] = None,
@@ -317,20 +314,10 @@ class Scheduler:
             raise ValueError(f"max_queue must be >= 1, got {max_queue}")
         self._pool = pool
         self._do_prefill = do_prefill
-        self._do_decode = do_decode
-        # chunked-prefill mode (the fused ragged engine): prefill is no
-        # longer a per-bucket program at admission — admission only
-        # allocates blocks and arms ``req.pending_feed``, and
-        # ``do_chunked_step(slot_requests, plan) -> token array`` runs
-        # ONE ragged launch per cycle mixing budgeted prompt chunks
-        # with the decode rows. The prefill budget becomes the per-
-        # cycle CHUNK token budget: decode rows are never charged, so a
-        # prompt burst can no longer monopolize a cycle.
         self._do_chunked = do_chunked_step
-        self._chunked = do_chunked_step is not None
         self.prefill_chunks = 0          # chunk launches fed (slot-cycles)
         self.chunk_tokens = 0            # prompt tokens fed via chunks
-        # speculative decoding (fused engines): ``do_spec_step(active,
+        # speculative decoding: ``do_spec_step(active,
         # plan, spec) -> [2S + S*spec_k + 1] device array`` — per slot
         # the accepted-prefix length, the corrected/sampled token, the
         # echoed draft tokens (the host never saw the device-side
@@ -340,16 +327,12 @@ class Scheduler:
         self._do_spec = do_spec_step
         self._spec = do_spec_step is not None
         self._spec_k = int(spec_k)
-        if self._spec and not self._chunked:
-            raise ValueError(
-                "do_spec_step requires do_chunked_step: speculative "
-                "verify rows ride the fused ragged launch")
         if self._spec and self._spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
         self.spec_cycles = 0             # cycles that verified >= 1 slot
         self.spec_proposed = 0           # draft tokens verified
         self.spec_accepted = 0           # draft tokens accepted
-        # serving numerics sentinel: decode steps append a logits-finite
+        # serving numerics sentinel: the step appends a logits-finite
         # flag past the token row (models/generation.py), riding the one
         # windowed _fetch — cycles whose logits went NaN/Inf are counted
         # here and flagged in the flight-recorder cycle record
@@ -360,16 +343,11 @@ class Scheduler:
             else FlightRecorder()
         self._cycle = 0
         self._rec: Optional[dict] = None   # current cycle's record
-        # paged pools bring block-granular admission, growth and
-        # preemption into the loop; the dense path is untouched
-        self._paged = bool(getattr(pool, "is_paged", False))
         self._do_copy = do_copy          # device block copy (COW append)
         self.preempts = 0                # requests evicted mid-flight
         self._max_queue = int(max_queue)
-        # tokens of prefill allowed per cycle WHILE slots are decoding
-        # (with an idle pool admission is unthrottled — there is nothing
-        # to starve). A budget below the head's bucket cannot deadlock:
-        # once the active slots drain, the idle-pool path admits it.
+        # prompt tokens fed per cycle, split over the feeding slots
+        # (_chunk_plan)
         self._prefill_budget = int(prefill_budget or pool.max_len)
         if self._prefill_budget < 1:
             raise ValueError(
@@ -381,7 +359,7 @@ class Scheduler:
         # tokens of deficit and the class at the rotation head admits
         # while its deficit covers its head-of-line request's feed
         # cost. With ONE class queued the selector short-circuits to
-        # plain FCFS — the legacy single-tenant order, byte for byte.
+        # plain FCFS.
         # Deficits are capped so an idle class cannot bank unbounded
         # credit and then monopolize admission for whole seconds.
         self._lane_weights: Dict[str, float] = {
@@ -508,9 +486,7 @@ class Scheduler:
                     t = time.perf_counter()
                     rec["sweep_ms"] = (t - t0) * 1e3
                 with _prof.record("serving/admit", "serving", args=cyc):
-                    if self._paged and \
-                            getattr(self._pool, "host_tier", None) \
-                            is not None:
+                    if self._pool.host_tier is not None:
                         # demotion pump: blocks freed by LAST cycle's
                         # retirements spill before THIS cycle's
                         # admissions can evict them (dispatch-only)
@@ -523,7 +499,7 @@ class Scheduler:
                     self._admit()
                     rec["admit_ms"] = (time.perf_counter() - t) * 1e3
                 if self._slots:
-                    self._decode_cycle()
+                    self._chunked_cycle()
                 elif rec["promo_waits"]:
                     # nothing decoding and the only queued work is
                     # waiting on in-flight promotions: nap on the
@@ -542,8 +518,7 @@ class Scheduler:
                 with _prof.record("serving/record", "serving", args=cyc):
                     with self._cond:
                         rec["queue_depth"] = len(self._queue)
-                    if self._paged:
-                        rec["blocks_in_use"] = self._pool.blocks_in_use
+                    rec["blocks_in_use"] = self._pool.blocks_in_use
                     if failed is not None:
                         rec["failed"] = repr(failed)
                     rec["cycle_ms"] = (time.perf_counter() - t0) * 1e3
@@ -577,10 +552,10 @@ class Scheduler:
 
     def _note_nonfinite(self, toks, rec, idx: Optional[int] = None) \
             -> None:
-        """Read the decode step's logits-finite sentinel off the fetched
+        """Read the step's logits-finite sentinel off the fetched
         token row (element ``[num_slots]`` — or ``idx`` for layouts
         like the speculative verify output whose sentinel sits past the
-        draft echo; absent from mock/legacy decodes that return exactly
+        draft echo; absent from a mock step that returns exactly
         ``num_slots`` tokens). A tripped flag marks the cycle record
         and counts ``serving/nonfinite_cycles`` — the tokens themselves
         still flow (an argmax over NaN logits is garbage, not a crash),
@@ -612,7 +587,7 @@ class Scheduler:
 
     def note_decode_flops(self, flops: float) -> None:
         """Record the FLOPs of the decode program dispatched THIS cycle
-        into the live cycle record (called by the engine's do_decode,
+        into the live cycle record (called by the engine's step,
         scheduler thread). cycle_throughput sums it alongside emitted,
         keeping stats() achieved-FLOP/s on the same ring window as its
         wall-time denominator."""
@@ -707,11 +682,10 @@ class Scheduler:
         (promotion-waiters this cycle) are invisible to the rotation;
         returns -1 when nothing else is queued.
 
-        Preempted replay victims outrank everything (they predate every
+        Preemption victims outrank everything (they predate every
         queued arrival and their history is hot). A single queued class
-        short-circuits to its FCFS head — identical to the old bare
-        FCFS, so untagged traffic and idle-capacity batch flow are
-        untouched. With several classes, each rotation credits the
+        short-circuits to its FCFS head, so untagged traffic and
+        idle-capacity batch flow are plain FCFS. With several classes, each rotation credits the
         rotation head ``quantum x lane weight`` tokens of deficit and a
         class admits while its deficit covers its head request's feed
         cost — an interactive lane at weight 4 admits ~4x the token
@@ -765,9 +739,8 @@ class Scheduler:
         if tk is None:
             return
         req._promo_ticket = None
-        tier = getattr(self._pool, "host_tier", None)
-        if tier is not None:
-            tier.ticket_done(tk)
+        if self._pool.host_tier is not None:
+            self._pool.host_tier.ticket_done(tk)
 
     def _prefetch_promotions(self) -> None:
         """Overlap promotion with decode (scheduler thread, right
@@ -795,9 +768,8 @@ class Scheduler:
                          adopt: bool = True) -> str:
         """Drive ``req``'s host-tier promotion state machine (caller
         holds ``_cond``; scheduler thread). Returns ``"go"`` — admit
-        now (no host-resident prefix, the engine would decline the hit
-        anyway, the tier degraded to a plain miss, or the staged blocks
-        were just adopted) — or ``"wait"`` — an H2D copy is in flight,
+        now (no host-resident prefix, the tier degraded to a plain
+        miss, or the staged blocks were just adopted) — or ``"wait"`` — an H2D copy is in flight,
         skip this request until it lands."""
         pool = self._pool
         tk = req._promo_ticket
@@ -814,13 +786,8 @@ class Scheduler:
             return "go"                  # failed ticket = plain miss
         feed = req.prompt if not req.tokens else np.concatenate(
             [req.prompt, np.asarray(req.tokens, np.int32)])
-        host_keys, covered = pool.tier_match(feed)
+        host_keys, _ = pool.tier_match(feed)
         if not host_keys:
-            return "go"
-        if not self._chunked and feed.size - covered > pool.min_bucket:
-            # mirror the engine's hit heuristic: with an uncovered tail
-            # past one min_bucket the engine prefills fresh regardless,
-            # so waiting on a promotion would only add latency
             return "go"
         tk = pool.host_tier.request_promotion(host_keys)
         if tk is None:
@@ -829,12 +796,9 @@ class Scheduler:
         return "wait"
 
     # admission: weighted-fair over (lane, tenant) classes — FCFS
-    # within a class and when only one class is queued — under a
-    # prefill budget (the loop sweeps the queue under its own
-    # span/timer right before calling this)
+    # within a class and when only one class is queued (the loop sweeps
+    # the queue under its own span/timer right before calling this)
     def _admit(self) -> None:
-        decode_waiting = bool(self._slots)
-        budget = self._prefill_budget
         skip: set = set()       # promotion-waiters sit out this cycle
         while True:
             with self._cond:
@@ -842,11 +806,10 @@ class Scheduler:
                     return
                 # a promotion whose H2D copy has LANDED admits ahead
                 # of the fair rotation: landing it is a block adoption
-                # plus a short replay — no prefill program runs — so
-                # the jump costs the queue almost nothing, while
-                # making the waiter sit through one more fresh
-                # bucket-64 prefill would hand back most of the
-                # latency the tier just saved
+                # plus the uncovered tail's chunks, so the jump costs
+                # the queue almost nothing, while making the waiter sit
+                # behind one more fresh prompt's feed would hand back
+                # most of the latency the tier just saved
                 idx = -1
                 for i, r in enumerate(self._queue):
                     tk = r._promo_ticket
@@ -855,7 +818,7 @@ class Scheduler:
                     # _tier_promoted with no ticket = the chain was
                     # adopted on an earlier pass that then bounced off
                     # a capacity gate: its blocks sit refcount-0 and
-                    # evictable, so admit it before any fresh prefill
+                    # evictable, so admit it before any fresh admission
                     # can steal them back
                     if (tk is not None and tk.ready.is_set()) \
                             or (tk is None and r._tier_promoted):
@@ -891,8 +854,7 @@ class Scheduler:
                 # the blocks land. Meanwhile the rotation moves on to
                 # other queued work, so a copy in flight never blocks a
                 # decode cycle or a promotion-free admission.
-                if self._paged and \
-                        getattr(self._pool, "host_tier", None) is not None \
+                if self._pool.host_tier is not None \
                         and self._promotion_state(req) == "wait":
                     if self._rec is not None:
                         self._rec["promo_waits"] += 1
@@ -901,8 +863,8 @@ class Scheduler:
                             time.perf_counter() - tk.created_at < 0.05:
                         # hold the admission line while the copy is
                         # YOUNG: it lands within a cycle or two, and
-                        # letting a later-arriving prefill overtake now
-                        # would occupy the stream for exactly the time
+                        # letting a later-arriving prompt overtake now
+                        # would occupy the launches for exactly the time
                         # the hit was about to save (decode slots keep
                         # running — only fresh admissions wait). The
                         # age bound keeps a wedged promoter from
@@ -911,33 +873,18 @@ class Scheduler:
                         return
                     skip.add(req.id)
                     continue
-                # paged re-admission (preemption) replays the request's
-                # own generated tokens, so the "prompt" being fed is the
-                # whole sequence so far
-                feed_len = len(req.prompt) + len(req.tokens) \
-                    if self._paged else len(req.prompt)
-                bucket = self._pool.bucket_for(feed_len)
-                if self._paged and not self._pool.can_admit(feed_len):
+                # re-admission after a preemption feeds the request's
+                # own generated tokens again, so the "prompt" being fed
+                # is the whole sequence so far
+                if not self._pool.can_admit(
+                        len(req.prompt) + len(req.tokens)):
                     # block pressure: wait for retirements (the head
                     # keeps its FCFS place; submit-time capacity checks
                     # guarantee it fits an idle pool, so no deadlock)
                     return
-                if not self._chunked and decode_waiting and budget < bucket \
-                        and not req._tier_promoted:
-                    # (an adopted promotion is a guaranteed prefix hit:
-                    # no prefill program will run, so the budget gate
-                    # that throttles prefill latency does not apply)
-                    # budget spent: decode the active slots first; the
-                    # queue keeps its place (FCFS) and is retried next
-                    # cycle. This is the anti-starvation preemption.
-                    # (Chunked mode has no per-admission prefill program
-                    # to budget — admission is host bookkeeping, and the
-                    # budget throttles the per-cycle chunk feed instead.)
-                    stat_add("serving/preempt")
-                    return
                 slot = self._pool.alloc()
                 if slot is None:
-                    return              # pool full: decode will retire
+                    return              # pool full: a cycle will retire
                 self._queue.pop(idx)
                 req._preempted = False
                 # admission-rate EWMA: the evidence behind est_wait_s
@@ -950,7 +897,7 @@ class Scheduler:
                 self._admit_stamp = now
                 stat_observe("serving/queue_depth", len(self._queue))
             try:
-                prefilled = self._prefill(req, slot, bucket)
+                self._prefill(req, slot)
             except Exception as exc:                    # noqa: BLE001
                 # at this point the request is in neither queue nor
                 # slots: fail it HERE (or its caller hangs forever) and
@@ -964,67 +911,32 @@ class Scheduler:
                         f"serving step failed for request {req.id}: "
                         f"{exc!r}"))
                 raise
-            if prefilled:
-                # a prefix-cache hit skipped prefill entirely, so it
-                # costs the cycle's prefill budget nothing — charging
-                # the bucket anyway would throttle exactly the
-                # admissions the cache made cheap
-                budget -= bucket
 
-    def _prefill(self, req: GenerationRequest, slot: int,
-                 bucket: int) -> bool:
-        """Admit ``req`` into ``slot``. Returns whether a prefill
-        program actually ran (False = paged prefix-cache hit)."""
+    def _prefill(self, req: GenerationRequest, slot: int) -> None:
+        """Admit ``req`` into ``slot``: the engine's admission hook
+        reserves its blocks and arms ``req.pending_feed``; the feed
+        itself rides the cycles' launches."""
         # admission wait: submit -> this admission (a re-admission after
         # preemption restarts nothing — the client has been waiting the
         # whole time, so the wall clock since submit IS the lane wait)
         wait_ms = (time.perf_counter() - req.submitted_at) * 1e3
         stat_observe("serving/lane_wait_ms", wait_ms)
-        self._event(req, "admitted", slot=slot, bucket=bucket,
+        self._event(req, "admitted", slot=slot,
                     feed=len(req.prompt) + len(req.tokens),
                     tenant=req.tenant, lane=req.lane,
                     wait_ms=round(wait_ms, 3))
         if self._rec is not None:
             self._rec["admitted"].append(req.id)
-        req.trace.mark("prefill_start", bucket=bucket)
+        req.trace.mark("prefill_start")
         t0 = time.perf_counter()
         with _prof.record("serving/prefill", "serving",
-                          args={"bucket": bucket, "slot": slot}):
-            first = self._do_prefill(req, slot, bucket)
+                          args={"slot": slot}):
+            self._do_prefill(req, slot)
         dt_ms = (time.perf_counter() - t0) * 1e3
         if self._rec is not None:
             self._rec["prefill_ms"] += dt_ms
-        # ran=False marks a paged prefix-cache hit: the engine skipped
-        # the prefill program and stamped prefix_hit with tokens saved
-        req.trace.mark("prefill_end", bucket=bucket,
-                       ran=not (self._paged and first is None))
-        if self._paged:
-            # the engine set the slot's page table and positions; a
-            # None first token means a prefix-cache hit — prefill was
-            # skipped entirely and the remaining tokens arrive through
-            # the replay path of the decode cycles
-            self._slots[slot] = req
-            if first is None:
-                return False
-            stat_add("serving/prefill_tokens", bucket)
-            first = int(first)
-            req._emit(first)
-            stat_add("serving/tokens")
-            if self._finished(req, first):
-                self._retire(slot)
-            return True
-        first = int(first)
-        stat_add("serving/prefill_tokens", bucket)
-        # first generated token sits at cache index `bucket`; the slot's
-        # valid keys start past the bucket's left pad
-        self._pool.set_slot(slot, pos=bucket,
-                            lo=bucket - len(req.prompt))
+        req.trace.mark("prefill_end")
         self._slots[slot] = req
-        req._emit(first)
-        stat_add("serving/tokens")
-        if self._finished(req, first):
-            self._retire(slot)
-        return True
 
     def _event(self, req: GenerationRequest, name: str, **meta) -> None:
         """One lifecycle event, stamped once into both the request's
@@ -1047,20 +959,19 @@ class Scheduler:
             self._rec["retired"].append(req.id)
         req._finish(error)
 
-    # -- paged memory pressure: growth, copy-on-write, preemption ----------
+    # -- memory pressure: growth, copy-on-write, preemption ----------------
     def _preempt_youngest(self) -> bool:
         """Evict the youngest active request to free its blocks: the
         request is failed OUT of the pool but not failed to its caller
         — it re-enters the queue at the head (it predates everything
-        queued) and replays its own history on re-admission. Returns
-        False when nothing is active to evict."""
+        queued) and feeds its own history again on re-admission.
+        Returns False when nothing is active to evict."""
         if not self._slots:
             return False
         slot = max(self._slots, key=lambda s: self._slots[s].id)
         req = self._slots.pop(slot)
         self._pool.free(slot)
-        req.replay = []                  # rebuilt at re-admission
-        req.pending_feed = []            # ditto (fused chunked feed)
+        req.pending_feed = []            # rebuilt at re-admission
         req._preempted = True            # outranks WDRR selection
         req._tier_promoted = False       # re-classified at re-admission
         self.preempts += 1
@@ -1074,30 +985,7 @@ class Scheduler:
             self._cond.notify_all()
         return True
 
-    def _prepare_paged(self) -> bool:
-        """Before a paged decode step: every active slot must own a
-        writable block at its position — grow tables, resolve
-        copy-on-write appends, and answer exhaustion by preempting the
-        youngest request (oldest-first order makes the youngest the
-        victim, never the beneficiary). Returns False when no slots
-        survive."""
-        for slot in sorted(self._slots,
-                           key=lambda s: self._slots[s].id):
-            while slot in self._slots:
-                try:
-                    cow = self._pool.ensure_writable(slot)
-                except PoolExhaustedError:
-                    # slot itself is active, so there is always a
-                    # youngest to evict — possibly slot itself, which
-                    # the while re-check then skips
-                    self._preempt_youngest()
-                    continue
-                if cow is not None and self._do_copy is not None:
-                    self._do_copy(*cow)
-                break
-        return bool(self._slots)
-
-    # -- chunked prefill (the fused ragged engine) -------------------------
+    # -- the cycle: plan, launch, emit -------------------------------------
     def _chunk_plan(self) -> Dict[int, int]:
         """Per-cycle row plan: how many query rows each active slot
         contributes to the fused ragged launch. Decode slots (feed
@@ -1121,10 +1009,12 @@ class Scheduler:
         return plan
 
     def _prepare_chunked(self, plan: Dict[int, int]) -> Dict[int, int]:
-        """Chunked-mode twin of :meth:`_prepare_paged`: every planned
-        slot must own writable blocks for its WHOLE row range this
-        cycle (a chunk scatters ``[pos, pos + n)``). Exhaustion preempts
-        the youngest request; evicted slots drop out of the plan."""
+        """Before the launch: every planned slot must own writable
+        blocks for its WHOLE row range this cycle (a chunk writes
+        ``[pos, pos + n)``) — grow tables, resolve copy-on-write
+        appends, and answer exhaustion by preempting the youngest
+        request (oldest-first order makes the youngest the victim,
+        never the beneficiary); evicted slots drop out of the plan."""
         for slot in sorted(plan, key=lambda s: self._slots[s].id
                            if s in self._slots else -1):
             while slot in self._slots and slot in plan:
@@ -1146,95 +1036,6 @@ class Scheduler:
                         self._do_copy(*cow)
                 break
         return {s: n for s, n in plan.items() if s in self._slots}
-
-    def _decode_cycle(self) -> None:
-        if self._chunked:
-            self._chunked_cycle()
-            return
-        cyc = {"cycle": self._cycle}
-        rec = self._rec
-        with _prof.record("serving/plan", "serving", args=cyc):
-            t0 = time.perf_counter()
-            active = dict(self._slots) \
-                if not self._paged or self._prepare_paged() else {}
-            if active:
-                occupancy = len(active) / self._pool.num_slots
-                stat_observe("serving/active_slots", len(active))
-                stat_observe("serving/batch_occupancy", occupancy)
-                if rec is not None:
-                    rec["active"] = len(active)
-                    rec["occupancy"] = occupancy
-            t1 = time.perf_counter()
-            if rec is not None:
-                rec["plan_ms"] += (t1 - t0) * 1e3
-        if not active:
-            return
-        # dispatch and the windowed host fetch are timed APART: a slow
-        # cycle with fat fetch_ms is a host-sync problem, one with fat
-        # dispatch_ms is tracing/compile churn — the flight recorder
-        # must distinguish them postmortem
-        with _prof.record("serving/decode_dispatch", "serving",
-                          args={"cycle": self._cycle,
-                                "active": len(active)}):
-            toks_dev = self._do_decode(active)
-            t2 = time.perf_counter()
-        with _prof.record("serving/host_fetch", "serving", args=cyc):
-            toks = _fetch(toks_dev)
-            t3 = time.perf_counter()
-            if rec is not None:
-                rec["decode_dispatch_ms"] += (t2 - t1) * 1e3
-                rec["fetch_ms"] += (t3 - t2) * 1e3
-        with _prof.record("serving/emit", "serving", args=cyc):
-            self._emit_decode(active, toks, t3 - t1)
-            # freed inside the span: freeing a device array lets go of
-            # the GIL, and the stream consumers the loop has just woken
-            # hold it for milliseconds — host time of this cycle that
-            # would otherwise lie in no span
-            del toks_dev
-            if rec is not None:
-                rec["emit_ms"] += (time.perf_counter() - t3) * 1e3
-
-    def _emit_decode(self, active, toks, dt: float) -> None:
-        """The host half of a decode cycle once its tokens are fetched:
-        advance, emit and retire every active slot."""
-        rec = self._rec
-        self._note_nonfinite(toks, rec)
-        emitted = 0
-        now = time.perf_counter()
-        for slot, req in active.items():
-            self._pool.advance(slot)
-            if req.cancelled:
-                stat_add("serving/cancelled")
-                self._retire(slot, RequestCancelled(
-                    f"request {req.id} cancelled mid-generation"))
-                continue
-            if req.expired(now):
-                stat_add("serving/deadline_exceeded")
-                self._retire(slot, DeadlineExceeded(
-                    f"request {req.id} exceeded its deadline after "
-                    f"{req.emitted} token(s)",
-                    queue_depth=len(self._queue),
-                    est_wait_s=self._est_wait_s(len(self._queue))))
-                continue
-            if req.replay:
-                # paged prefix-hit / re-admission: this cycle fed one
-                # known token; the model's prediction is discarded and
-                # the next known token queued — nothing reaches the
-                # caller until the replay drains
-                req.last_token = req.replay.pop(0)
-                if not req.replay:
-                    req.trace.mark("replay_done", emitted=req.emitted)
-                continue
-            tok = int(toks[slot])
-            req._emit(tok)
-            emitted += 1
-            if self._finished(req, tok):
-                self._retire(slot)
-        stat_add("serving/tokens", emitted)
-        if rec is not None:
-            rec["emitted"] += emitted
-        if dt > 0:
-            stat_observe("serving/tokens_per_sec", emitted / dt)
 
     def _spec_plan(self, plan: Dict[int, int]) -> Dict[int, int]:
         """Speculative row plan: every DECODE slot (feed drained)
@@ -1290,6 +1091,10 @@ class Scheduler:
                 rec["plan_ms"] += (t1 - t0) * 1e3
         if not plan:
             return
+        # dispatch and the windowed host fetch are timed APART: a slow
+        # cycle with fat fetch_ms is a host-sync problem, one with fat
+        # dispatch_ms is tracing/compile churn — the flight recorder
+        # must distinguish them postmortem
         with _prof.record("serving/decode_dispatch", "serving",
                           args=launch):
             if spec:
@@ -1305,7 +1110,11 @@ class Scheduler:
                 rec["fetch_ms"] += (t3 - t2) * 1e3
         with _prof.record("serving/emit", "serving", args=cyc):
             self._emit_chunked(active, plan, spec, toks, t3 - t1)
-            del toks_dev        # see _decode_cycle: inside the span
+            # freed inside the span: freeing a device array lets go of
+            # the GIL, and the stream consumers the loop has just woken
+            # hold it for milliseconds — host time of this cycle that
+            # would otherwise lie in no span
+            del toks_dev
             if rec is not None:
                 rec["emit_ms"] += (time.perf_counter() - t3) * 1e3
 

@@ -34,9 +34,9 @@ registry collector, so everything rides the one scrape:
 * a ``slo_latency_ms{objective=}`` histogram written at observe time
   (collectors cannot emit histograms), so a remote scraper can
   recompute attainment from cumulative bucket counts —
-  :func:`attainment_from_buckets` bounds it to bucket resolution, and
-  ``bench.py --serve-load`` asserts the HTTP-scraped value brackets
-  the in-process one.
+  :func:`attainment_from_buckets` bounds it to bucket resolution
+  (tests/test_ops_server.py holds the HTTP-scraped value to the
+  in-process one).
 
 Host-purity: everything here is host arithmetic over host stamps —
 no device fetches, no scheduler blocking (the ``ops-handler-sync``

@@ -2,8 +2,8 @@
 
 Every :class:`~.scheduler.GenerationRequest` carries a
 :class:`RequestTrace` — an append-only list of timestamped lifecycle
-events (submit, admitted, prefill start/end with its bucket, prefix hit
-with tokens saved, each preemption, replay, first token, finish/cancel/
+events (submit, admitted, prefill start/end, prefix hit with tokens
+saved, each prefill chunk, each preemption, first token, finish/cancel/
 deadline/error) plus a per-token decode stamp for every emitted token.
 From those stamps the trace DERIVES the two serving latencies that
 matter:
@@ -199,7 +199,7 @@ class RequestTrace:
                                     pending_ps, ts, tid=tid, depth=1,
                                     parent=name, args=meta)
                     pending_ps = None
-            elif n in ("preempt", "prefix_hit", "replay_done"):
+            elif n in ("preempt", "prefix_hit"):
                 _prof.add_event(n, "serving/request", ts, ts, tid=tid,
                                 depth=1, parent=name, args=meta)
         if self.token_times:

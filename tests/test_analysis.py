@@ -932,8 +932,8 @@ def test_spec_verify_bucket_analyzes_clean():
     model = GPTForPretraining(GPTConfig.tiny())
     model.eval()
     eng = GenerationEngine(model, num_slots=4, max_len=64,
-                           kv_layout="paged", block_size=8,
-                           attention="fused", spec_draft=model, spec_k=3)
+                           block_size=8,
+                           spec_draft=model, spec_k=3)
     try:
         eng._spec_step_fn(BLOCK_Q, 2)     # seed one verify bucket
         r = eng.analyze()
@@ -960,8 +960,8 @@ def test_sharded_fused_step_analyzes_clean():
     model = GPTForPretraining(GPTConfig.tiny())
     model.eval()
     eng = GenerationEngine(model, num_slots=4, max_len=64,
-                           kv_layout="paged", block_size=8,
-                           attention="fused", mesh=mesh)
+                           block_size=8,
+                           mesh=mesh)
     try:
         r = eng.analyze()
         assert "fused_step" in r.target

@@ -237,8 +237,7 @@ def test_chunked_prefill_then_decode_agrees_with_the_reference(
     reference's first choice by its own logits (gap under 1e-4 of the
     row's spread: float32 against float32)."""
     prompts = [_ids(1, n, seed=n)[0].tolist() for n in (5, 19, 33, 41)]
-    eng = GenerationEngine(net, num_slots=4, max_len=64, kv_layout="paged",
-                           attention="fused", block_size=8,
+    eng = GenerationEngine(net, num_slots=4, max_len=64, block_size=8,
                            prefill_budget=16)
     handles = [eng.submit(p, 12) for p in prompts]
     outs = [[int(t) for t in h.stream()] for h in handles]
@@ -262,8 +261,7 @@ def test_a_preempted_request_resumes_and_still_agrees(net, make, model):
     preempted, re-admitted and replayed through chunks; both stay the
     reference's own text."""
     pa, pb = _ids(1, 6, seed=61)[0].tolist(), _ids(1, 7, seed=62)[0].tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=32, kv_layout="paged",
-                           attention="fused", block_size=8, num_blocks=4,
+    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8, num_blocks=4,
                            prefill_budget=16)
     ha, hb = eng.submit(pa, 22), eng.submit(pb, 22)
     oa = [int(t) for t in ha.stream()]
@@ -280,8 +278,7 @@ def test_a_cow_copy_moves_a_latent_block_in_every_layer(net):
     """Paging, COW and the prefix trie work on block ids: the engine's
     copy program clones block ``src`` over ``dst`` across every layer of
     the latent pool ``[L, NB + 1, 1, bs, lanes]`` as of any other."""
-    eng = GenerationEngine(net, num_slots=2, max_len=32, kv_layout="paged",
-                           attention="fused", block_size=8)
+    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8)
     pool = eng._pool
     assert pool.shape == (3, pool.num_blocks + 1, 1, 8, 128)
     list(eng.submit(_ids(1, 20, seed=8)[0].tolist(), 2).stream())
@@ -298,8 +295,7 @@ def test_a_cow_copy_moves_a_latent_block_in_every_layer(net):
 
 def test_a_shared_prefix_is_served_from_the_trie(net, make, model):
     pre = _ids(1, 24, seed=9)[0].tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=48, kv_layout="paged",
-                           attention="fused", block_size=8,
+    eng = GenerationEngine(net, num_slots=2, max_len=48, block_size=8,
                            prefill_budget=16)
     first = [int(t) for t in eng.submit(pre + [5, 6], 4).stream()]
     again = [int(t) for t in eng.submit(pre + [7, 8, 9], 4).stream()]
@@ -331,8 +327,6 @@ def test_the_decoder_spec_describes_both_models(net):
 
 
 @pytest.mark.parametrize("kwargs,match", [
-    (dict(kv_layout="dense", attention="gather"), "kv_layout='paged'"),
-    (dict(attention="gather"), "attention='fused'"),
     (dict(mesh="a mesh"), "tensor-parallel"),
     (dict(spec_draft="auto"), "spec_draft"),
     (dict(kv_dtype="int8"), "int8/fp8 KV blocks"),
@@ -340,8 +334,7 @@ def test_the_decoder_spec_describes_both_models(net):
 ])
 def test_what_a_latent_pool_cannot_do_yet_is_refused_by_name(net, kwargs,
                                                              match):
-    kw = dict(num_slots=2, max_len=32, kv_layout="paged", attention="fused",
-              block_size=8)
+    kw = dict(num_slots=2, max_len=32, block_size=8)
     kw.update(kwargs)
     with pytest.raises(ValueError, match=match):
         GenerationEngine(net, **kw)

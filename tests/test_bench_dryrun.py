@@ -53,43 +53,30 @@ def test_dry_run_emits_metrics_summary():
     assert out["selflint_findings"] == 0, out
     assert "analysis/findings" in res.stderr
     assert "dispatch/retrace_cause" in res.stderr
-    # PR-4 serving surface: the continuous-batching canary completed,
-    # its metrics are live, the decode step analyzed clean and each
-    # capacity bucket traced exactly once
-    assert out["serving_requests"] == 6, out
+    # serving surface: the continuous-batching canary completed every
+    # request token-identical to models.generate, its metrics are live,
+    # the repeated system prompt hit the prefix cache (whole blocks
+    # skipped), a 40-token prompt chunked under the 8-token prefill
+    # budget, the fused step analyzed clean (donation-safe,
+    # host-sync-free — the Pallas call included) and every (q, table)
+    # bucket traced exactly once — plus the serving-host-sync self-lint
+    # staying green (selflint_findings == 0 above walks the whole package)
+    assert out["serving"]["requests"] == 8, out
     assert out["checks"]["serving_completed"] is True, out
+    assert out["checks"]["serving_parity"] is True, out
     assert out["checks"]["serving_counters_live"] is True, out
-    assert out["checks"]["serving_decode_clean"] is True, out
+    assert out["checks"]["serving_prefix_hit"] is True, out
+    assert out["checks"]["serving_chunked_prefill"] is True, out
+    assert out["checks"]["serving_step_clean"] is True, out
     assert out["checks"]["serving_one_trace_per_bucket"] is True, out
+    assert out["serving"]["prefix_hits"] >= 4, out
+    assert out["serving"]["prefill_tokens_saved"] >= 64, out
+    assert out["serving"]["prefill_chunks"] >= 5, out
+    assert out["serving"]["chunked_prefill_tokens"] >= 40, out
     assert "serving/ttft_ms" in res.stderr
     assert "serving/tokens_per_sec" in res.stderr
-    # PR-5 paged surface: mixed lengths through the paged engine all
-    # complete, the repeated system prompt hit the prefix cache (whole
-    # prefill blocks skipped), the paged decode step analyzed clean and
-    # every prefill/table bucket traced exactly once — plus the
-    # serving-host-sync self-lint staying green covers serving/paging.py
-    # (selflint_findings == 0 above already walks the whole package)
-    assert out["checks"]["paged_completed"] is True, out
-    assert out["checks"]["paged_prefix_hit"] is True, out
-    assert out["checks"]["paged_decode_clean"] is True, out
-    assert out["checks"]["paged_one_trace_per_bucket"] is True, out
-    assert out["paged_prefix_hits"] > 0, out
-    assert out["paged_tokens_saved"] > 0, out
     assert "serving/kv_blocks_in_use" in res.stderr
     assert "serving/prefix_hit" in res.stderr
-    # ISSUE-8 fused ragged-paged-attention surface: the fused Pallas
-    # step was selected (no silent fallback), token-parity with the
-    # gather oracle held, a 40-token prompt chunked under the 8-token
-    # prefill budget, the fused step analyzed clean (donation-safe,
-    # host-sync-free — the Pallas call included) and every (q, table)
-    # bucket traced exactly once
-    assert out["checks"]["fused_selected"] is True, out
-    assert out["checks"]["fused_parity"] is True, out
-    assert out["checks"]["fused_chunked_prefill"] is True, out
-    assert out["checks"]["fused_step_clean"] is True, out
-    assert out["checks"]["fused_one_trace_per_bucket"] is True, out
-    assert out["fused_prefill_chunks"] >= 5, out
-    assert out["fused_chunk_tokens"] >= 40, out
     assert "serving/prefill_chunks" in res.stderr
     assert "serving/chunk_tokens" in res.stderr
     # ISSUE-12 speculative decoding + int8 KV blocks: greedy spec
@@ -110,25 +97,18 @@ def test_dry_run_emits_metrics_summary():
     assert out["spec"]["int8_token_agreement"] >= 0.75, out
     assert "serving/spec_accept" in res.stderr
     assert "serving/spec_tokens_per_cycle" in res.stderr
-    # ISSUE-6 serving SLO observability: the seeded mini serve-load run
-    # completed every request with lifecycle-ordered traces, derived
-    # TTFT/TPOT percentiles in the summary, a live serving/tpot_ms
-    # histogram, a non-empty always-on flight recorder and zero decode
-    # retraces during the run
-    assert out["checks"]["serve_load_traces_complete"] is True, out
-    assert out["checks"]["serve_load_tpot_live"] is True, out
-    assert out["checks"]["serve_load_flight_recorder"] is True, out
-    assert out["checks"]["serve_load_zero_retraces"] is True, out
-    sl = out["serve_load"]
-    assert sl["completed"] == sl["requests"] and sl["failed"] == 0, sl
-    assert sl["ttft_ms"]["count"] == sl["requests"], sl
-    assert sl["tpot_ms"]["p50"] > 0, sl
-    assert "goodput_rps" in sl and "slo_attainment" in sl, sl
+    # ISSUE-6 serving SLO observability: the canary completed every
+    # request with lifecycle-ordered traces, per-engine TTFT/TPOT in
+    # stats(), a live serving/tpot_ms histogram and a non-empty
+    # always-on flight recorder
+    assert out["checks"]["serving_traces_complete"] is True, out
+    assert out["checks"]["serving_tpot_live"] is True, out
+    assert out["checks"]["serving_flight_recorder"] is True, out
     assert "serving/tpot_ms" in res.stderr
     assert "serving/cycle_ms" in res.stderr
     assert "serving/batch_occupancy" in res.stderr
     # PR-16 SLO plane / ops surface: the zero-dependency ops HTTP
-    # server booted on an ephemeral port during the serve-load canary,
+    # server booted on an ephemeral port during the serving canary,
     # a live GET /metrics parsed back non-empty WITH the slo_attainment
     # series, /healthz answered 200 while serving and flipped to 503
     # after engine close, /tracez carried the tail-sampled traces and
@@ -165,8 +145,7 @@ def test_dry_run_emits_metrics_summary():
     assert out["checks"]["bench_compare_gate"] is True, out
     assert out["compile_count"] > 0, out
     assert out["hapi_mfu"] is not None and out["hapi_mfu"] > 0, out
-    assert out["serving_flops_per_token"] > 0, out
-    assert out["paged_flops_per_token"] > 0, out
+    assert out["serving"]["model_flops_per_token"] > 0, out
     assert out["memory_ledger_bytes"] > 0, out
     assert out["compare_gate_rc"] == {"self": 0, "regression": 1}, out
     assert "compile/ms" in res.stderr

@@ -61,8 +61,7 @@ class _StubEngine:
     def stats(self):
         if self._fail_stats:
             raise RuntimeError("scheduler thread is dead")
-        s = {"kv_layout": "dense", "attention": "gather",
-             "queue_depth": self._queue, "active_requests": 1,
+        s = {"queue_depth": self._queue, "active_requests": 1,
              "num_slots": self._slots[1], "slots_in_use": self._slots[0],
              "slot_utilization": self._slots[0] / self._slots[1],
              "preempts": 1, "requests_retired": self._retired,
@@ -319,7 +318,7 @@ class TestRoutedDispatch:
         f.close()
 
     def test_affinity_explicit_block_override(self):
-        e1, e2 = _StubEngine(), _StubEngine()    # dense: no block_size
+        e1, e2 = _StubEngine(), _StubEngine()    # stubs report no block_size
         f = EngineFleet([e1, e2], route="affinity", affinity_block=4)
         for _ in range(3):
             f.submit([1, 2, 3, 4, 5])
@@ -336,10 +335,8 @@ class TestRoutedDispatch:
 
 class TestRealFleet:
     def test_two_replica_fleet_parity_and_stats(self, tiny_model):
-        e1 = GenerationEngine(tiny_model, num_slots=2, max_len=48,
-                              min_bucket=8)
-        e2 = GenerationEngine(tiny_model, num_slots=2, max_len=48,
-                              min_bucket=8)
+        e1 = GenerationEngine(tiny_model, num_slots=2, max_len=48)
+        e2 = GenerationEngine(tiny_model, num_slots=2, max_len=48)
         with EngineFleet([e1, e2], name="t13") as fleet:
             prompts = [np.arange(1, 1 + n, dtype=np.int32)
                        for n in (3, 5, 7, 4)]

@@ -47,16 +47,8 @@ def test_serve_gpt2_example(tmp_path):
     assert "ttft p50" in out
     assert "tpot p50" in out                 # per-engine decode cadence
     assert "engine.stats():" in out          # the operator snapshot
-
-
-def test_serve_gpt2_example_paged(tmp_path):
-    out = _run([os.path.join(REPO, "examples", "serve_gpt2.py"),
-                "--clients", "8", "--slots", "4", "--train-steps", "20",
-                "--paged"],
-               tmp_path, timeout=600)
-    assert "served 8 requests" in out
-    assert "paged KV" in out
-    assert "prefix hit ratio" in out         # stats() paged section
+    assert "prefix hit ratio" in out         # the shared preamble's hits
+    assert "prefill chunks" in out           # fed through the fused step
 
 
 def test_serve_gpt2_example_mp(tmp_path):
@@ -75,7 +67,7 @@ def test_serve_gpt2_example_mp(tmp_path):
     assert "tensor-parallel: mp=2" in out
     assert "per-device KV pool" in out
     assert "1/2 of the single-device bytes" in out
-    assert "prefix hit ratio" in out         # --mp implies --paged
+    assert "prefix hit ratio" in out
 
 
 def test_serve_gpt2_example_spec_int8(tmp_path):

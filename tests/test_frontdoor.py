@@ -27,11 +27,11 @@ import numpy as np
 import pytest
 
 from paddle_tpu.serving.frontdoor import LANES, FrontDoor, TokenBucket
-from paddle_tpu.serving.kv_pool import KVCachePool
 from paddle_tpu.serving.scheduler import (DeadlineExceeded,
                                           GenerationRequest,
-                                          QueueFullError, RequestCancelled,
-                                          Scheduler)
+                                          QueueFullError, RequestCancelled)
+
+from _mock_serving import MockDevice, mock_pool
 
 
 # ---------------------------------------------------------------------------
@@ -339,33 +339,25 @@ class TestTokenBucket:
 # weighted-fair admission (mock-device scheduler)
 # ---------------------------------------------------------------------------
 
-def _mock_pool(slots=1, max_len=64):
-    return KVCachePool(num_layers=1, num_slots=slots, num_heads=1,
-                       max_len=max_len, head_dim=1, min_bucket=8)
-
-
-class _GatedDevice:
-    """First prefill blocks on ``gate`` so a test can stage the queue
+class _GatedDevice(MockDevice):
+    """First admission blocks on ``gate`` so a test can stage the queue
     before any admission decisions happen; admission order is then read
-    back from ``prefills``."""
+    back from ``order``."""
 
     def __init__(self, pool, gate=None):
-        self.pool = pool
+        super().__init__(pool)
         self.gate = gate
-        self.entered = threading.Event()   # first prefill reached
+        self.entered = threading.Event()   # first admission reached
         self._first = True
-        self.prefills = []
+        self.order = []
 
-    def do_prefill(self, req, slot, bucket):
+    def do_prefill(self, req, slot):
         if self._first and self.gate is not None:
             self._first = False
             self.entered.set()
             self.gate.wait(timeout=30)
-        self.prefills.append(req.id)
-        return 1
-
-    def do_decode(self, slot_requests):
-        return np.full(self.pool.num_slots, 2, np.int32)
+        self.order.append(req.id)
+        super().do_prefill(req, slot)
 
 
 def _req(prompt_len, max_new=1, **kw):
@@ -375,15 +367,15 @@ def _req(prompt_len, max_new=1, **kw):
 class TestWeightedFairAdmission:
     def test_single_class_is_fcfs(self):
         gate = threading.Event()
-        pool = _mock_pool(slots=1)
+        pool = mock_pool(slots=1)
         dev = _GatedDevice(pool, gate)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        sched = dev.scheduler()
         reqs = [sched.submit(_req(4)) for _ in range(6)]
         gate.set()
         for r in reqs:
             r.result(timeout=30)
         sched.close()
-        assert dev.prefills == [r.id for r in reqs]
+        assert dev.order == [r.id for r in reqs]
 
     def test_interactive_lane_outranks_batch_backlog(self):
         """6 batch requests queued FIRST, then 2 interactive: with the
@@ -391,9 +383,9 @@ class TestWeightedFairAdmission:
         32-token quantum, the interactive pair admits right behind the
         first batch request instead of waiting out the backlog."""
         gate = threading.Event()
-        pool = _mock_pool(slots=1)
+        pool = mock_pool(slots=1)
         dev = _GatedDevice(pool, gate)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        sched = dev.scheduler()
         head = sched.submit(_req(4))            # occupies the one slot
         assert dev.entered.wait(timeout=30)     # head is OUT of the queue
         batch = [sched.submit(_req(24, tenant="bulk", lane="batch"))
@@ -405,7 +397,7 @@ class TestWeightedFairAdmission:
         for r in [head] + batch + inter:
             r.result(timeout=30)
         sched.close()
-        order = dev.prefills[1:]                # drop the gate request
+        order = dev.order[1:]                # drop the gate request
         pos = {rid: i for i, rid in enumerate(order)}
         worst_inter = max(pos[r.id] for r in inter)
         # both interactive requests land in the first three admissions
@@ -415,13 +407,11 @@ class TestWeightedFairAdmission:
         assert sorted(order) == sorted(r.id for r in batch + inter)
 
     def test_custom_lane_weights_validated(self):
-        pool = _mock_pool()
+        pool = mock_pool(slots=1)
         with pytest.raises(ValueError):
-            Scheduler(pool, lambda *a: 1, lambda *a: None,
-                      lane_weights={"batch": 0})
-        sched = Scheduler(pool, lambda r, s, b: 1,
-                          lambda sr: np.full(pool.num_slots, 2, np.int32),
-                          lane_weights={"batch": 2.5, "bulk": 1.0})
+            MockDevice(pool).scheduler(lane_weights={"batch": 0})
+        sched = MockDevice(pool).scheduler(
+            lane_weights={"batch": 2.5, "bulk": 1.0})
         assert sched._lane_weights["batch"] == 2.5
         assert sched._lane_weights["interactive"] == 4.0
         sched.close()
@@ -440,10 +430,9 @@ class TestWeightedFairAdmission:
 class TestShedMetadata:
     def test_queue_full_carries_depth_and_estimate(self):
         gate = threading.Event()
-        pool = _mock_pool(slots=1)
+        pool = mock_pool(slots=1)
         dev = _GatedDevice(pool, gate)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode,
-                          max_queue=2)
+        sched = dev.scheduler(max_queue=2)
         head = sched.submit(_req(4))
         assert dev.entered.wait(timeout=30)     # head is OUT of the queue
         queued = [sched.submit(_req(4)) for _ in range(2)]
@@ -463,9 +452,9 @@ class TestShedMetadata:
 
     def test_deadline_in_queue_carries_depth(self):
         gate = threading.Event()
-        pool = _mock_pool(slots=1)
+        pool = mock_pool(slots=1)
         dev = _GatedDevice(pool, gate)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        sched = dev.scheduler()
         head = sched.submit(_req(4))
         doomed = sched.submit(_req(4, timeout=0.01))
         time.sleep(0.05)
@@ -497,9 +486,8 @@ class TestShedMetadata:
             d.close()
 
     def test_lanes_constant_matches_scheduler_defaults(self):
-        pool = _mock_pool()
-        sched = Scheduler(pool, lambda r, s, b: 1,
-                          lambda sr: np.full(pool.num_slots, 2, np.int32))
+        pool = mock_pool(slots=1)
+        sched = MockDevice(pool).scheduler()
         assert set(LANES) == set(sched._lane_weights)
         sched.close()
 

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.models import GPTConfig, GPTForPretraining
+from paddle_tpu.models import GPTConfig, GPTForPretraining, generate
 from paddle_tpu.serving import (GenerationEngine, HostBlockPool,
                                 HostTierError, HostTierFullError,
                                 PagedKVPool, PromotionTicket)
@@ -241,8 +241,7 @@ class TestTierExactness:
             assert pool._ref[shared] == 2
             # writer appends into the shared tail block -> COW
             pool.set_slot(a, pos=8, lo=0)
-            cow = pool.ensure_writable(a)
-            assert cow is not None
+            (cow,) = pool.ensure_writable_range(a, 8)
             dst, src = cow
             assert src == shared and dst != shared
             assert pool.slot_table(a)[1] == dst
@@ -418,8 +417,6 @@ def _wait_for(cond, timeout):
 def _mk_engine(model, **kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_len", 48)
-    kw.setdefault("min_bucket", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("block_size", 8)
     kw.setdefault("num_blocks", 8)
     return GenerationEngine(model, **kw)
@@ -440,18 +437,19 @@ def _seed_host_prefix(eng):
         tier.drain()
 
 
-def _churn_outputs(eng):
-    outs = [eng.submit(np.concatenate([_SYSTEM, [40]]),
+def _churn_outputs(eng, system=_SYSTEM, fillers=None):
+    if fillers is None:
+        fillers = [np.arange(60 + 20 * j, 76 + 20 * j, dtype=np.int32)
+                   for j in range(3)]
+    outs = [eng.submit(np.concatenate([system, [40]]),
                        max_new_tokens=4).result(timeout=300)]
-    for j in range(3):
-        outs.append(eng.submit(
-            np.arange(60 + 20 * j, 76 + 20 * j, dtype=np.int32),
-            max_new_tokens=4).result(timeout=300))
+    for f in fillers:
+        outs.append(eng.submit(f, max_new_tokens=4).result(timeout=300))
     tier = getattr(eng._pool, "host_tier", None)
     if tier is not None:
         eng._pool.tier_tick()
         tier.drain()
-    outs.append(eng.submit(np.concatenate([_SYSTEM, [40]]),
+    outs.append(eng.submit(np.concatenate([system, [40]]),
                            max_new_tokens=4).result(timeout=300))
     return outs
 
@@ -483,20 +481,56 @@ class TestTieredEngine:
             np.testing.assert_array_equal(g, w)
 
     def test_int8_tiered_parity(self, served_model):
-        tiered = _mk_engine(served_model, kv_dtype="int8",
-                            host_tier_bytes=4 << 20)
+        # int8 blocks at the size their tile needs (32 tokens): a
+        # one-block system prompt, three two-block fillers through a
+        # three-block pool — the system block is evicted, demoted with
+        # its scales, and promoted back for the repeat
+        kw = dict(kv_dtype="int8", block_size=32, max_len=64, num_blocks=3)
+        system = np.arange(2, 34, dtype=np.int32)
+        fillers = [((np.arange(33) * 3 + 7 + 11 * j) % 90 + 1)
+                   .astype(np.int32) for j in range(3)]
+        tiered = _mk_engine(served_model, host_tier_bytes=4 << 20, **kw)
         try:
-            got = _churn_outputs(tiered)
+            got = _churn_outputs(tiered, system, fillers)
             assert tiered.stats()["tier_hits"]["host"] >= 1
         finally:
             tiered.close()
-        untiered = _mk_engine(served_model, kv_dtype="int8")
+        untiered = _mk_engine(served_model, **kw)
         try:
-            want = _churn_outputs(untiered)
+            want = _churn_outputs(untiered, system, fillers)
         finally:
             untiered.close()
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
+
+    def test_a_promotion_lands_into_a_chunked_feed(self, served_model):
+        """The host tier over the chunked cycle: a request whose prefix
+        is host-resident waits for the H2D copy, adopts the promoted
+        blocks as a prefix hit, and its uncovered tail — three times the
+        chunk budget — goes in as chunks of the cycles' launches. The
+        hit is classed ``host`` and the output matches ``generate``."""
+        eng = _mk_engine(served_model, host_tier_bytes=4 << 20,
+                         prefill_budget=4)
+        try:
+            _seed_host_prefix(eng)
+            assert eng._pool.match_prefix(list(_SYSTEM) + [1]) == []
+            host0 = eng.stats()["tier_hits"]["host"]
+            prompt = np.concatenate(
+                [_SYSTEM, np.arange(30, 42, dtype=np.int32)])
+            h = eng.submit(prompt, max_new_tokens=4)
+            out = h.result(timeout=300)
+            s = eng.stats()
+        finally:
+            eng.close()
+        assert s["tier_hits"]["host"] == host0 + 1
+        assert s["host_tier"]["promoted_blocks"] >= 2
+        hit = [m for n, _, m in h.trace.events if n == "prefix_hit"]
+        assert hit == [{"tokens_saved": 16, "pending": 12}]
+        chunks = [m["tokens"] for n, _, m in h.trace.events
+                  if n == "prefill_chunk"]
+        assert chunks == [4, 4, 4]
+        ref = generate(served_model, prompt[None, :], max_new_tokens=4)
+        np.testing.assert_array_equal(out, ref.numpy()[0])
 
     def test_decode_never_blocks_on_inflight_promotion(self, served_model):
         eng = _mk_engine(served_model, host_tier_bytes=4 << 20)
@@ -544,10 +578,10 @@ class TestTieredEngine:
         assert not tier._promoter.is_alive()
         assert tier.demoted_blocks >= 2
 
-    def test_host_tier_requires_paged_layout_and_no_mesh(self, served_model):
-        with pytest.raises(ValueError):
+    def test_host_tier_requires_no_mesh(self, served_model):
+        with pytest.raises(ValueError, match="does not compose with mesh"):
             GenerationEngine(served_model, num_slots=2, max_len=48,
-                             host_tier_bytes=1 << 20)
+                             mesh="a mesh", host_tier_bytes=1 << 20)
 
     def test_ledger_splits_host_bytes_out_of_device_crosscheck(
             self, served_model):

@@ -124,7 +124,7 @@ class TestSchedulerOomIntegration:
         postmortems behind: the flight recorder's and the memory
         tracker's (pointing at the recorder dump), without killing the
         loop or masking the request error."""
-        from paddle_tpu.serving.kv_pool import KVCachePool
+        from _mock_serving import MockDevice, mock_pool
         from paddle_tpu.serving.scheduler import (GenerationRequest,
                                                   Scheduler)
 
@@ -138,17 +138,13 @@ class TestSchedulerOomIntegration:
             return p
 
         monkeypatch.setattr(memory.tracker(), "oom_postmortem", capture)
-        pool = KVCachePool(num_layers=1, num_slots=2, num_heads=1,
-                           max_len=32, head_dim=1, min_bucket=8)
+        pool = mock_pool(slots=2, max_len=32)
 
-        def prefill(req, slot, bucket):
-            return 1
-
-        def decode(slot_requests):
+        def step(slot_requests, plan):
             raise RuntimeError(
                 "RESOURCE_EXHAUSTED: Out of memory allocating KV block")
 
-        sched = Scheduler(pool, prefill, decode)
+        sched = Scheduler(pool, MockDevice(pool).do_prefill, step)
         req = sched.submit(GenerationRequest(np.ones(4, np.int32), 3))
         try:
             req.result(timeout=60)
@@ -168,18 +164,21 @@ class TestSchedulerOomIntegration:
 
 
 class TestPoolLedgerIntegration:
-    def test_dense_pool_publishes_bytes(self):
-        from paddle_tpu.serving.kv_pool import KVCachePool
+    def test_pool_publishes_bytes(self):
+        from paddle_tpu.serving.paging import PagedKVPool
 
-        pool = KVCachePool(num_layers=2, num_slots=4, num_heads=2,
-                           max_len=16, head_dim=4, dtype="float32",
-                           min_bucket=8)
+        # 4 slots x 16 tokens at 8 a block: 8 blocks + the scratch one
+        pool = PagedKVPool(num_layers=2, num_slots=4, num_heads=2,
+                           max_len=16, head_dim=4, block_size=8,
+                           dtype="float32")
         led = memory.ledger()
         cap = led[f"{pool.ledger_key}/capacity"]
-        assert cap == pool.capacity_bytes == 2 * 2 * 4 * 2 * 16 * 4 * 4
+        assert cap == pool.capacity_bytes == 2 * 9 * 2 * 8 * (2 * 4) * 4
         assert led[f"{pool.ledger_key}/in_use"] == 0
         s = pool.alloc()
-        assert memory.ledger()[f"{pool.ledger_key}/in_use"] == cap // 4
+        pool.admit_fresh(s, 16)              # a slot's worst case: 2 blocks
+        assert memory.ledger()[f"{pool.ledger_key}/in_use"] \
+            == 2 * (cap // 9)
         pool.free(s)
         assert memory.ledger()[f"{pool.ledger_key}/in_use"] == 0
         # alloc/free left labeled watermarks behind
@@ -193,7 +192,7 @@ class TestPoolLedgerIntegration:
 
         pool = PagedKVPool(num_layers=1, num_slots=2, num_heads=1,
                            max_len=32, head_dim=2, block_size=8,
-                           num_blocks=8, dtype="float32", min_bucket=8)
+                           num_blocks=8, dtype="float32")
         assert pool.block_bytes == 1 * 2 * 1 * 8 * 2 * 4
         slot = pool.alloc()
         pool.admit_fresh(slot, 12)           # 2 blocks

@@ -479,51 +479,33 @@ class TestRecorderBounds:
 
 class TestServingSentinel:
     def test_injected_bad_decode_trips_flag_and_loop_survives(self):
-        from paddle_tpu.serving.kv_pool import KVCachePool
-        from paddle_tpu.serving.scheduler import (GenerationRequest,
-                                                  Scheduler)
+        from _mock_serving import MockDevice, mock_pool
+        from paddle_tpu.serving.scheduler import GenerationRequest
 
-        pool = KVCachePool(num_layers=1, num_slots=2, num_heads=1,
-                           max_len=64, head_dim=1, min_bucket=8)
-        bad_cycles = []
-
-        def do_prefill(req, slot, bucket):
-            return 1
-
-        def do_decode(slot_requests):
-            # the decode step's token row with the sentinel element
-            # tripped — exactly what a NaN-logits program emits
-            toks = np.full(pool.num_slots + 1, 2, np.int32)
-            toks[-1] = 1
-            bad_cycles.append(1)
-            return toks
-
+        # the step's token row with the sentinel element tripped —
+        # exactly what a NaN-logits program emits
+        dev = MockDevice(mock_pool(slots=2), tail=(1,))
         before = monitor.stat_get("serving/nonfinite_cycles")
-        sched = Scheduler(pool, do_prefill, do_decode)
+        sched = dev.scheduler()
         handles = [sched.submit(GenerationRequest(
             np.ones(4, np.int32), 3)) for _ in range(2)]
         for h in handles:
             out = h.result(timeout=60)           # loop survives: tokens
             assert out.shape == (4 + 3,)         # still flow to callers
-        assert sched.nonfinite_cycles == len(bad_cycles) > 0
+        assert sched.nonfinite_cycles == len(dev.launches) > 0
         assert monitor.stat_get("serving/nonfinite_cycles") - before \
-            == len(bad_cycles)
+            == len(dev.launches)
         cycles = sched.recorder.snapshot()["cycles"]
         assert any(c.get("nonfinite") for c in cycles)
         sched.close()
 
     def test_legacy_mock_decode_without_flag_still_works(self):
-        # mock/legacy do_decode returning exactly [num_slots] tokens:
-        # no sentinel, no false nonfinite count
-        from paddle_tpu.serving.kv_pool import KVCachePool
-        from paddle_tpu.serving.scheduler import (GenerationRequest,
-                                                  Scheduler)
+        # a mock step returning exactly [num_slots] tokens: no
+        # sentinel, no false nonfinite count
+        from _mock_serving import MockDevice, mock_pool
+        from paddle_tpu.serving.scheduler import GenerationRequest
 
-        pool = KVCachePool(num_layers=1, num_slots=2, num_heads=1,
-                           max_len=64, head_dim=1, min_bucket=8)
-        sched = Scheduler(pool, lambda req, slot, bucket: 1,
-                          lambda actives: np.full(pool.num_slots, 2,
-                                                  np.int32))
+        sched = MockDevice(mock_pool(slots=2)).scheduler()
         h = sched.submit(GenerationRequest(np.ones(4, np.int32), 3))
         h.result(timeout=60)
         assert sched.nonfinite_cycles == 0

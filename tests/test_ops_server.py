@@ -71,8 +71,7 @@ class _StubEngine:
     def stats(self):
         if self._fail_stats:
             raise RuntimeError("scheduler thread is dead")
-        return {"kv_layout": "dense", "attention": "gather",
-                "queue_depth": 0, "active_requests": 0, "num_slots": 4,
+        return {"queue_depth": 0, "active_requests": 0, "num_slots": 4,
                 "slots_in_use": 1, "slot_utilization": 0.25,
                 "preempts": 0, "requests_retired": 3,
                 "nonfinite_cycles": 0, "kv_pool_capacity_bytes": 1000,

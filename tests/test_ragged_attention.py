@@ -1,5 +1,6 @@
 """Fused ragged paged attention (ops/ragged_paged_attention.py) and the
-chunked-prefill serving path (GenerationEngine(attention="fused")).
+chunked-prefill serving path over it (``GenerationEngine``, at the
+kernel's smallest block size here).
 
 Four layers of guarantees:
 
@@ -7,8 +8,8 @@ Four layers of guarantees:
   kernel BODY executes under tier-1) matches a full-precision numpy
   oracle on ragged mixed prefill+decode batches over randomized page
   tables, including multi-block chunks and bf16 storage;
-* **engine parity** — greedy FUSED engine output is token-identical to
-  the gather-based paged engine AND to per-request ``models.generate``
+* **engine parity** — greedy engine output is token-identical to
+  per-request ``models.generate``
   under mixed concurrent churn, prefix-cache adoption, COW and
   block-pressure preemption — with ZERO retraces during the storm and a
   clean ``analyze()`` bill on the fused step (donation-safe,
@@ -18,8 +19,8 @@ Four layers of guarantees:
   counters are observable in ``stats()``/the flight recorder, and the
   policy test shows decode rows advancing in the SAME cycles that chunk
   a long prompt (no cycle spends its whole budget on one prompt);
-* **validation** — fused requires the paged layout and a
-  Mosaic-tileable block size, fail-fast at construction.
+* **validation** — a Mosaic-tileable block size, fail-fast at
+  construction; the removed options refused by name.
 """
 import threading
 
@@ -32,8 +33,9 @@ from paddle_tpu.models import GPTConfig, GPTForPretraining, generate
 from paddle_tpu.ops.ragged_paged_attention import (
     ragged_layout, ragged_paged_attention, reference_ragged_attention)
 from paddle_tpu.serving import GenerationEngine
-from paddle_tpu.serving.paging import PagedKVPool
-from paddle_tpu.serving.scheduler import GenerationRequest, Scheduler
+from paddle_tpu.serving.scheduler import GenerationRequest
+
+from _mock_serving import MockDevice, mock_pool
 
 VOCAB = 96
 
@@ -42,8 +44,8 @@ VOCAB = 96
 def served_model():
     """A tiny char GPT trained for a few steps: trained logits have
     clear argmax margins, so greedy parity between the fused (ragged
-    Pallas kernel) and gather (materialized window) attention programs
-    cannot flake on numeric noise."""
+    Pallas kernel) step and ``generate``'s loop cannot flake on numeric
+    noise."""
     paddle.seed(11)
     cfg = GPTConfig(vocab_size=VOCAB, hidden_size=64, num_hidden_layers=2,
                     num_attention_heads=4, intermediate_size=128,
@@ -274,30 +276,28 @@ class TestKernelParity:
 
 
 # ---------------------------------------------------------------------------
-# fused engine parity: fused == gather == generate, zero retraces, clean
+# fused engine parity: engine == generate, one trace a bucket, clean
 # analysis — the acceptance criterion
 # ---------------------------------------------------------------------------
 
 class TestFusedEngineParity:
     def test_single_request_matches_generate(self, served_model):
         eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               kv_layout="paged", block_size=8,
-                               attention="fused")
+                               block_size=8)
         p = _prompt(np.random.RandomState(1), 7)
         out = eng.submit(p, max_new_tokens=8).result(timeout=300)
         ref = generate(served_model, p[None, :], max_new_tokens=8)
         np.testing.assert_array_equal(out, ref.numpy()[0])
-        assert eng.stats()["attention"] == "fused"
         eng.close()
 
-    def test_32_mixed_requests_fused_equals_gather_equals_generate(
+    def test_32_mixed_requests_match_generate_and_analyze_clean(
             self, served_model):
-        """The fused acceptance criterion: the same 32 mixed-length
-        concurrent greedy requests through the GATHER paged engine (the
-        correctness oracle) and the FUSED engine produce token-identical
-        output, each matching per-request ``generate``; the storm causes
-        ZERO retraces on the fused engine (one trace per (q, table)
-        bucket) and the fused step analyzes clean."""
+        """The fused acceptance criterion at the kernel's smallest
+        block: 32 mixed-length concurrent greedy requests, a sample of
+        them held to per-request ``generate`` (all 32 are, at the
+        default block size, in ``test_serving_engine.py``); every (q,
+        table) bucket traces once, the fused step analyzes clean and no
+        block leaks."""
         rng = np.random.RandomState(2)
         specs = [(_prompt(rng, int(rng.randint(2, 21))),
                   int(rng.randint(1, 9))) for _ in range(32)]
@@ -317,15 +317,8 @@ class TestFusedEngineParity:
                 t.join()
             return [h.result(timeout=600) for h in outs]
 
-        gather = GenerationEngine(served_model, num_slots=8, max_len=48,
-                                  min_bucket=8, kv_layout="paged",
-                                  block_size=8)
-        gather_outs = storm(gather)
-        gather.close()
-
         eng = GenerationEngine(served_model, num_slots=8, max_len=48,
-                               min_bucket=8, kv_layout="paged",
-                               block_size=8, attention="fused")
+                               block_size=8)
         # no warmup: the storm compiles its own (q, table) buckets, and
         # the discipline assertion below is per-site trace counts (a
         # deterministic zero-retrace check lives in
@@ -337,13 +330,9 @@ class TestFusedEngineParity:
         stats = eng.stats()
         eng.close()
 
-        # fused == gather for ALL 32 (the oracle contract; gather ==
-        # generate over this same spec distribution is already pinned
-        # by tests/test_serving_paging.py), plus generate() spot checks
-        # so a correlated fused+gather drift cannot hide
-        for (p, n), gout, fout in zip(specs, gather_outs, fused_outs):
-            np.testing.assert_array_equal(fout, gout)
-        for i in (0, 9, 17, 31):
+        for (p, n), fout in zip(specs, fused_outs):
+            assert fout.shape == (p.size + n,)
+        for i in (0, 5, 9, 13, 17, 22, 26, 31):
             p, n = specs[i]
             ref = generate(served_model, p[None, :], max_new_tokens=n)
             np.testing.assert_array_equal(fused_outs[i], ref.numpy()[0])
@@ -371,8 +360,7 @@ class TestFusedEngineParity:
         ref = generate(served_model, p[None, :], max_new_tokens=8,
                        eos_token_id=eos, pad_token_id=0)
         eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               kv_layout="paged", block_size=8,
-                               attention="fused")
+                               block_size=8)
         out = eng.submit(p, max_new_tokens=8, eos_token_id=eos) \
                  .result(timeout=300)
         eng.close()
@@ -386,8 +374,8 @@ class TestFusedEngineParity:
         a halved block budget preempts the youngest, and every output
         stays token-exact."""
         eng = GenerationEngine(served_model, num_slots=4, max_len=32,
-                               kv_layout="paged", block_size=8,
-                               num_blocks=8, attention="fused")
+                               block_size=8,
+                               num_blocks=8)
         rng = np.random.RandomState(5)
         system = _prompt(rng, 16)        # two full cacheable blocks
         tails = [_prompt(rng, n) for n in (3, 1, 6, 10)]
@@ -398,8 +386,7 @@ class TestFusedEngineParity:
         outs = [h.result(timeout=600) for h in handles]
         stats = eng.stats()
         eng.close()
-        # the 10-token tail would have been DECLINED by the gather
-        # engine (> min_bucket); fused adopts every hit
+        # every hit is adopted, the 10-token tail's too
         assert eng._pool.prefix_hits >= 3
         assert stats["prefill_tokens_saved"] >= 3 * 16
         for p, out in zip(prompts, [first] + outs):
@@ -408,8 +395,8 @@ class TestFusedEngineParity:
 
     def test_block_pressure_preempts_and_stays_exact(self, served_model):
         eng = GenerationEngine(served_model, num_slots=2, max_len=32,
-                               kv_layout="paged", block_size=8,
-                               num_blocks=4, attention="fused")
+                               block_size=8,
+                               num_blocks=4)
         pa = _prompt(np.random.RandomState(6), 4)
         pb = _prompt(np.random.RandomState(7), 4)
         ha = eng.submit(pa, max_new_tokens=24)
@@ -432,8 +419,7 @@ class TestFusedEngineParity:
         table) bucket program — no new trace anywhere, and the
         dispatch/retrace_cause counters stay untouched."""
         eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               kv_layout="paged", block_size=8,
-                               attention="fused")
+                               block_size=8)
         rng = np.random.RandomState(4)
         eng.submit(_prompt(rng, 7), max_new_tokens=8).result(timeout=300)
         retrace0 = monitor.stat_get("dispatch/retrace_cause")
@@ -453,8 +439,7 @@ class TestFusedEngineParity:
 
     def test_sampled_and_greedy_share_one_bucket_trace(self, served_model):
         eng = GenerationEngine(served_model, num_slots=4, max_len=48,
-                               kv_layout="paged", block_size=8,
-                               attention="fused")
+                               block_size=8)
         rng = np.random.RandomState(8)
         g = eng.submit(_prompt(rng, 6), max_new_tokens=5)
         s = eng.submit(_prompt(rng, 6), max_new_tokens=5, do_sample=True,
@@ -478,8 +463,7 @@ class TestChunkedPrefill:
     def test_long_prompt_chunks_within_budget_and_stays_exact(
             self, served_model):
         eng = GenerationEngine(served_model, num_slots=4, max_len=64,
-                               kv_layout="paged", block_size=8,
-                               attention="fused", prefill_budget=8)
+                               block_size=8, prefill_budget=8)
         p = _prompt(np.random.RandomState(9), 40)
         h = eng.submit(p, max_new_tokens=4)
         out = h.result(timeout=600)
@@ -506,11 +490,10 @@ class TestChunkedPrefill:
         """The anti-starvation policy: while a 40-token prompt is being
         chunk-fed at an 8-token budget, the already-decoding request
         keeps emitting IN THE SAME cycles — no cycle spends its whole
-        budget on the prompt alone (the prompt-burst monopoly the
-        gather engine's whole-bucket prefill could not avoid)."""
+        budget on the prompt alone (the prompt-burst monopoly a
+        whole-prompt prefill at admission could not avoid)."""
         eng = GenerationEngine(served_model, num_slots=4, max_len=64,
-                               kv_layout="paged", block_size=8,
-                               attention="fused", prefill_budget=8)
+                               block_size=8, prefill_budget=8)
         short = eng.submit(_prompt(np.random.RandomState(10), 4),
                            max_new_tokens=40)
         it = short.stream()
@@ -535,25 +518,8 @@ class TestChunkedPrefill:
         """Deterministic mock-device policy check (no model): the chunk
         plan gives every decode slot its row unconditionally and splits
         the token budget FCFS among feeding slots."""
-        pool = PagedKVPool(num_layers=1, num_slots=4, num_heads=1,
-                           max_len=64, head_dim=1, block_size=8,
-                           min_bucket=8)
-        launches = []
-
-        def do_prefill(req, slot, bucket):
-            feed = np.concatenate([req.prompt,
-                                   np.asarray(req.tokens, np.int32)])
-            pool.admit_fresh(slot, feed.size)
-            pool.set_slot(slot, pos=0, lo=0)
-            req.pending_feed = [int(t) for t in feed]
-            return None
-
-        def do_chunked(slot_requests, plan):
-            launches.append(dict(plan))
-            return np.full(pool.num_slots, 7, np.int32)
-
-        sched = Scheduler(pool, do_prefill, lambda *_: None,
-                          do_chunked_step=do_chunked, prefill_budget=6)
+        dev = MockDevice(mock_pool(slots=4), token=7)
+        sched = dev.scheduler(prefill_budget=6)
         a = sched.submit(GenerationRequest(np.ones(4, np.int32), 8))
         a.result(timeout=60)
         b = sched.submit(GenerationRequest(np.ones(20, np.int32), 1))
@@ -565,7 +531,7 @@ class TestChunkedPrefill:
         assert sched.chunk_tokens == 44
         # no launch ever fed more than the budget, and whenever a
         # decode row existed it was in the launch too
-        for plan in launches:
+        for plan in dev.launches:
             fed = sum(n for n in plan.values() if n > 1)
             assert fed <= 6
         # FCFS: b (older) finished its feed no later than c
@@ -582,28 +548,26 @@ class TestFusedValidation:
     def test_fused_requires_paged_layout(self, served_model):
         with pytest.raises(ValueError, match="paged"):
             GenerationEngine(served_model, num_slots=2, max_len=32,
-                             attention="fused")
+                             kv_layout="dense")
 
     def test_fused_requires_tileable_block_size(self, served_model):
         with pytest.raises(ValueError, match="block_size"):
             GenerationEngine(served_model, num_slots=2, max_len=32,
-                             kv_layout="paged", block_size=4,
-                             attention="fused")
+                             block_size=4)
 
     def test_unknown_attention_rejected(self, served_model):
         with pytest.raises(ValueError, match="attention"):
             GenerationEngine(served_model, num_slots=2, max_len=32,
-                             kv_layout="paged", block_size=8,
+                             block_size=8,
                              attention="flash")
 
     def test_fused_admits_prompts_the_bucket_ladder_rejects(
             self, served_model):
-        """No prefill buckets in fused mode: a feed whose pow2 bucket
-        would overshoot a non-pow2 max_len (rejected by the gather
-        engine at submit) chunks through the ragged step instead."""
+        """No prefill buckets: a feed whose pow2 bucket would overshoot
+        a non-pow2 max_len chunks through the ragged step like any
+        other; ``prompt + max_new <= max_len`` is the only bound."""
         eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               kv_layout="paged", block_size=8,
-                               attention="fused")
+                               block_size=8)
         out = eng.submit(np.ones(33, np.int32), max_new_tokens=1) \
                  .result(timeout=300)
         assert out.shape == (34,)

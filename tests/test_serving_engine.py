@@ -4,11 +4,11 @@ Three layers of guarantees:
 
 * **parity** — greedy engine output is token-identical to a reference
   ``models.generate`` run per request, under any admission interleaving
-  (the slot pool + ragged left-pad bucket math must be EXACTLY the
-  compiled generate loop's semantics);
-* **compile discipline** — one decode trace per engine, one prefill
-  trace per capacity bucket, asserted via the ``trace_probe`` /
-  ``dispatch/retrace_cause`` counters (the acceptance criterion);
+  (the paged pool + chunked feed + fused ragged step must be EXACTLY
+  the compiled generate loop's semantics);
+* **compile discipline** — one trace per fused ``(Q, T)`` program,
+  greedy and sampled rows in the same one, asserted via the
+  ``trace_probe`` / ``dispatch/retrace_cause`` counters;
 * **scheduler policy** — churn (join/leave/cancel/timeout in any
   order), slot reuse without leaks, queue-full backpressure, deadline
   errors and graceful drain, fuzzed over a real engine plus
@@ -24,8 +24,10 @@ import paddle_tpu as paddle
 from paddle_tpu.framework import monitor, trace_probe
 from paddle_tpu.models import GPTConfig, GPTForPretraining, generate
 from paddle_tpu.serving import (DeadlineExceeded, GenerationEngine,
-                                GenerationRequest, KVCachePool,
-                                QueueFullError, RequestCancelled, Scheduler)
+                                GenerationRequest, QueueFullError,
+                                RequestCancelled, Scheduler)
+
+from _mock_serving import MockDevice, mock_pool
 
 VOCAB = 96
 
@@ -81,20 +83,12 @@ class TestParity:
             self, served_model):
         """The acceptance criterion: 8 slots, 32 concurrent mixed-length
         requests — all complete, outputs match per-request greedy
-        generate, and the retrace counters show exactly one trace per
-        capacity bucket."""
-        eng = GenerationEngine(served_model, num_slots=8, max_len=48,
-                               min_bucket=8)
+        generate, and every fused (Q, T) program the storm reached was
+        traced exactly once, with no retrace cause on record."""
+        eng = GenerationEngine(served_model, num_slots=8, max_len=48)
         rng = np.random.RandomState(2)
         specs = [(_prompt(rng, int(rng.randint(2, 21))),
                   int(rng.randint(1, 9))) for _ in range(32)]
-        # warm every capacity bucket + the decode step once (max_new=2
-        # forces a decode cycle), then assert the 32-request storm
-        # causes ZERO further traces anywhere
-        for bucket in (8, 16, 32):
-            eng.submit(_prompt(rng, bucket - 1), max_new_tokens=2) \
-               .result(timeout=300)
-        retrace0 = monitor.stat_get("dispatch/retrace_cause")
 
         handles = [None] * len(specs)
 
@@ -110,25 +104,72 @@ class TestParity:
             t.join()
         outs = [h.result(timeout=300) for h in handles]
         eng.close()
-        # compile discipline: nothing retraced during the storm itself
-        # (measured BEFORE the reference generate() runs below, which
-        # trace their own fresh programs)
-        retrace_after_storm = monitor.stat_get("dispatch/retrace_cause")
 
         for (p, n), out in zip(specs, outs):
             ref = generate(served_model, p[None, :], max_new_tokens=n)
             np.testing.assert_array_equal(out, ref.numpy()[0])
-        assert retrace_after_storm == retrace0
+        # compile discipline: which (Q, T) buckets a storm reaches
+        # depends on scheduling, but the fused step is the ONLY serving
+        # program and every bucket traces EXACTLY ONCE (traces > 1 would
+        # be the retrace-storm bug class); the ladder is bounded by the
+        # pow2 products — q in {8..128} x table in {1, 2, 3} here
         sites = {k: v for k, v in trace_probe.snapshot().items()
                  if k.startswith("serving/") and f"#{eng._eid}" in k}
         assert sites, "serving probe sites missing"
-        assert set(sites) == {f"serving/decode#{eng._eid}",
-                              f"serving/prefill[8]#{eng._eid}",
-                              f"serving/prefill[16]#{eng._eid}",
-                              f"serving/prefill[32]#{eng._eid}"}
+        assert all(k.startswith("serving/fused[q") for k in sites), \
+            sorted(sites)
+        assert len(sites) <= 15, sorted(sites)
         for name, rec in sites.items():
             assert rec["traces"] == 1, (name, rec)
             assert not rec["causes"], (name, rec)
+
+    def test_an_engine_built_with_no_options_serves_the_fused_step(
+            self, served_model):
+        """One serving path: nothing has to be asked for. The only
+        serving program a bare engine traces is the fused (Q, T) step,
+        the prompt goes in as chunks of the cycles' launches, and the
+        snapshot no longer reports a layout or an attention kind."""
+        eng = GenerationEngine(served_model, num_slots=2, max_len=48,
+                               prefill_budget=4)
+        p = _prompt(np.random.RandomState(21), 11)
+        out = eng.submit(p, max_new_tokens=3).result(timeout=300)
+        stats = eng.stats()
+        cycles = eng.flight_recorder.snapshot()["cycles"]
+        eng.close()
+        ref = generate(served_model, p[None, :], max_new_tokens=3)
+        np.testing.assert_array_equal(out, ref.numpy()[0])
+        sites = [k for k in trace_probe.snapshot()
+                 if k.startswith("serving/") and f"#{eng._eid}" in k]
+        assert sites and all(k.startswith("serving/fused[q")
+                             for k in sites), sites
+        # 11 prompt tokens at 4 a cycle: three chunk launches, the first
+        # token out of the third
+        fed = [c["chunk_tokens"] for c in cycles if c.get("chunk_tokens")]
+        assert fed == [4, 4, 3], cycles
+        assert all("launch_q" in c and "kv_tokens" in c
+                   for c in cycles if c.get("chunk_tokens"))
+        assert stats["prefill_chunks"] == 3
+        assert stats["chunked_prefill_tokens"] == 11
+        assert stats["block_size"] == 16 and stats["num_blocks"] == 6
+        assert "kv_layout" not in stats and "attention" not in stats
+
+    @pytest.mark.parametrize("kwargs,match", [
+        (dict(kv_layout="dense"), "kv_layout='dense'.*removed in PR 31"),
+        (dict(attention="gather"), "attention='gather'.*removed in PR 31"),
+        (dict(kv_layout="paged", attention="fused"), None),
+    ])
+    def test_the_two_removed_options_accept_one_literal_each(
+            self, served_model, kwargs, match):
+        """``benchmark/`` still passes ``kv_layout="paged",
+        attention="fused"``: those construct (and select nothing); every
+        other value is refused by the option's name."""
+        if match is None:
+            GenerationEngine(served_model, num_slots=1, max_len=16,
+                             **kwargs).close()
+            return
+        with pytest.raises(ValueError, match=match):
+            GenerationEngine(served_model, num_slots=1, max_len=16,
+                             **kwargs)
 
     def test_eos_early_stop_matches_generate(self, served_model):
         p = _prompt(np.random.RandomState(3), 6)
@@ -162,8 +203,14 @@ class TestParity:
         eng.close()
         assert o1.shape == o2.shape == (11,)
         assert ((0 <= o2) & (o2 < VOCAB)).all()
-        site = trace_probe.snapshot()[f"serving/decode#{eng._eid}"]
-        assert site["traces"] == 1, site   # mixed sampling, one program
+        # mixed sampling, one program a (Q, T) bucket: do_sample and
+        # temperature are traced values of the fused step
+        sites = {k: v for k, v in trace_probe.snapshot().items()
+                 if k.startswith("serving/") and f"#{eng._eid}" in k}
+        assert sites and all(k.startswith("serving/fused[q")
+                             for k in sites), sorted(sites)
+        for name, rec in sites.items():
+            assert rec["traces"] == 1, (name, rec)
 
     def test_analyze_clean_bill(self, served_model):
         eng = GenerationEngine(served_model, num_slots=2, max_len=32)
@@ -302,43 +349,12 @@ class TestChurn:
 # scheduler policy (deterministic, mock device steps)
 # ---------------------------------------------------------------------------
 
-def _mock_pool(slots=2, max_len=64):
-    return KVCachePool(num_layers=1, num_slots=slots, num_heads=1,
-                       max_len=max_len, head_dim=1, min_bucket=8)
-
-
-class _MockDevice:
-    """Deterministic stand-in for the engine's device steps."""
-
-    def __init__(self, pool, prefill_delay=0.0, decode_delay=0.0):
-        self.pool = pool
-        self.prefill_delay = prefill_delay
-        self.decode_delay = decode_delay
-        self.prefill_gate = threading.Event()
-        self.prefill_gate.set()
-        self.prefills = []
-        self.decodes = 0
-
-    def do_prefill(self, req, slot, bucket):
-        self.prefill_gate.wait()
-        if self.prefill_delay:
-            time.sleep(self.prefill_delay)
-        self.prefills.append((req.id, slot, bucket))
-        return 1
-
-    def do_decode(self, slot_requests):
-        if self.decode_delay:
-            time.sleep(self.decode_delay)
-        self.decodes += 1
-        return np.full(self.pool.num_slots, 2, np.int32)
-
-
 class TestSchedulerPolicy:
     def test_queue_full_raises_synchronously(self):
-        pool = _mock_pool(slots=1)
-        dev = _MockDevice(pool)
+        pool = mock_pool(slots=1)
+        dev = MockDevice(pool)
         dev.prefill_gate.clear()        # scheduler blocks inside prefill
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode, max_queue=2)
+        sched = dev.scheduler(max_queue=2)
         sched.submit(GenerationRequest(np.ones(4, np.int32), 2))
         for _ in range(50):             # wait until the head is claimed
             if sched.queue_depth == 0:
@@ -352,10 +368,10 @@ class TestSchedulerPolicy:
         sched.close()
 
     def test_deadline_exceeded_while_queued(self):
-        pool = _mock_pool(slots=1)
-        dev = _MockDevice(pool)
+        pool = mock_pool(slots=1)
+        dev = MockDevice(pool)
         dev.prefill_gate.clear()
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        sched = dev.scheduler()
         a = sched.submit(GenerationRequest(np.ones(4, np.int32), 2))
         b = sched.submit(GenerationRequest(np.ones(4, np.int32), 2,
                                            timeout=0.03))
@@ -370,9 +386,9 @@ class TestSchedulerPolicy:
         """A dead request BEHIND a slot-starved head must fail promptly
         (queue sweep), not when its turn finally comes — and must stop
         holding queue capacity meanwhile."""
-        pool = _mock_pool(slots=1)
-        dev = _MockDevice(pool, decode_delay=0.05)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool(slots=1)
+        dev = MockDevice(pool, decode_delay=0.05)
+        sched = dev.scheduler()
         # occupies the single slot for >= 50 * 0.05 = 2.5s
         long = sched.submit(GenerationRequest(np.ones(4, np.int32), 50))
         for _ in range(200):
@@ -393,9 +409,9 @@ class TestSchedulerPolicy:
         sched.close()
 
     def test_deadline_exceeded_mid_generation(self):
-        pool = _mock_pool(slots=1)
-        dev = _MockDevice(pool, decode_delay=0.03)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool(slots=1)
+        dev = MockDevice(pool, decode_delay=0.03)
+        sched = dev.scheduler()
         h = sched.submit(GenerationRequest(np.ones(4, np.int32), 1000,
                                            timeout=0.15))
         with pytest.raises(DeadlineExceeded):
@@ -404,42 +420,54 @@ class TestSchedulerPolicy:
         assert pool.n_active == 0       # and the slot was reclaimed
         sched.close()
 
-    def test_prefill_budget_preempts_in_favor_of_decode(self):
-        """With slots decoding, admission stops at the budget: long
-        admit bursts may not starve in-flight decode (counted as
-        serving/preempt), yet everything still completes."""
-        pool = _mock_pool(slots=4, max_len=64)
-        dev = _MockDevice(pool, prefill_delay=0.005, decode_delay=0.01)
-        before = monitor.stat_get("serving/preempt")
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode,
-                          prefill_budget=8)   # one 8-bucket per cycle
-        first = sched.submit(
-            GenerationRequest(np.ones(4, np.int32), 30))
-        for _ in range(100):
-            if sched.active:
-                break
-            time.sleep(0.005)
-        rest = [sched.submit(GenerationRequest(np.ones(4, np.int32), 3))
-                for _ in range(6)]
-        for h in [first] + rest:
-            h.result(timeout=30)
+    def test_admission_is_not_charged_the_chunk_budget(self):
+        """Admission is host bookkeeping, so the per-cycle token budget
+        does not gate it: three queued requests enter the SAME cycle
+        into a pool with room for them, and the budget then bounds what
+        each launch feeds of their prompts."""
+        pool = mock_pool(slots=4)
+        dev = MockDevice(pool)
+        dev.prefill_gate.clear()        # hold the first admission...
+        sched = dev.scheduler(prefill_budget=2)
+        hs = [sched.submit(GenerationRequest(np.ones(5, np.int32), 1))
+              for _ in range(3)]        # ...until all three are queued
+        dev.prefill_gate.set()
+        for h in hs:
+            assert h.result(timeout=30).shape == (6,)
         sched.close()
-        assert monitor.stat_get("serving/preempt") > before
+        cycles = sched.recorder.snapshot()["cycles"]
+        first = next(c for c in cycles if c["admitted"])
+        assert first["admitted"] == [h.id for h in hs]
+        fed = [sum(n for n in plan.values()) for plan in dev.launches]
+        assert max(fed) <= 2 and sum(fed) == 15     # 3 prompts of 5
+
+    def test_the_scheduler_has_one_step_callable(self):
+        """One cycle: the third positional argument is the fused step,
+        and there is no ``do_decode`` to pass beside it."""
+        import inspect
+        params = list(inspect.signature(Scheduler.__init__).parameters)
+        assert params[:4] == ["self", "pool", "do_prefill",
+                              "do_chunked_step"]
+        assert "do_decode" not in params
+        pool = mock_pool()
+        dev = MockDevice(pool)
+        with pytest.raises(TypeError):
+            Scheduler(pool, dev.do_prefill, dev.do_step, dev.do_step)
 
     def test_step_failure_poisons_requests_not_the_loop(self):
-        pool = _mock_pool(slots=2)
-        dev = _MockDevice(pool)
+        pool = mock_pool(slots=2)
+        dev = MockDevice(pool)
         boom = {"armed": True}
 
-        def bad_decode(slot_requests):
+        def bad_step(slot_requests, plan):
             if boom["armed"]:
                 # a real failed donated step leaves pool.data DELETED —
                 # reproduce that, not just the exception
                 pool.data.delete()
                 raise RuntimeError("device fell over")
-            return dev.do_decode(slot_requests)
+            return dev.do_step(slot_requests, plan)
 
-        sched = Scheduler(pool, dev.do_prefill, bad_decode)
+        sched = Scheduler(pool, dev.do_prefill, bad_step)
         h = sched.submit(GenerationRequest(np.ones(4, np.int32), 5))
         with pytest.raises(RuntimeError, match="serving step failed"):
             h.result(timeout=10)
@@ -452,21 +480,21 @@ class TestSchedulerPolicy:
         sched.close()
 
     def test_prefill_failure_fails_only_that_request(self):
-        """A prefill exception must fail ITS caller (not hang it), free
-        the slot, and leave the loop serving — the request is in
+        """An admission-hook exception must fail ITS caller (not hang it),
+        free the slot, and leave the loop serving — the request is in
         neither queue nor slots when it fails, so it needs its own
         failure path."""
-        pool = _mock_pool(slots=2)
-        dev = _MockDevice(pool)
+        pool = mock_pool(slots=2)
+        dev = MockDevice(pool)
         boom = {"armed": True}
 
-        def bad_prefill(req, slot, bucket):
+        def bad_prefill(req, slot):
             if boom["armed"]:
                 boom["armed"] = False
                 raise RuntimeError("prefill fell over")
-            return dev.do_prefill(req, slot, bucket)
+            return dev.do_prefill(req, slot)
 
-        sched = Scheduler(pool, bad_prefill, dev.do_decode)
+        sched = Scheduler(pool, bad_prefill, dev.do_step)
         h = sched.submit(GenerationRequest(np.ones(4, np.int32), 2))
         with pytest.raises(RuntimeError, match="serving step failed"):
             h.result(timeout=10)        # failed, not hung
@@ -481,11 +509,8 @@ class TestSchedulerPolicy:
 # ---------------------------------------------------------------------------
 
 class TestPoolAndValidation:
-    def test_pool_alloc_free_and_buckets(self):
-        pool = _mock_pool(slots=3, max_len=64)
-        assert pool.buckets() == [8, 16, 32, 64]
-        assert pool.bucket_for(1) == 8
-        assert pool.bucket_for(9) == 16
+    def test_pool_alloc_free(self):
+        pool = mock_pool(slots=3, max_len=64)
         a, b = pool.alloc(), pool.alloc()
         assert (a, b) == (0, 1)
         pool.free(a)
@@ -495,15 +520,48 @@ class TestPoolAndValidation:
         assert pool.n_active == 2 and pool.n_free == 1
 
     def test_pool_position_tracking(self):
-        pool = _mock_pool(slots=2, max_len=16)
+        pool = mock_pool(slots=2, max_len=16)
         s = pool.alloc()
         pool.set_slot(s, pos=8, lo=3)
         assert pool.advance(s) == 9
-        pos, lo = pool.position_arrays()
-        np.testing.assert_array_equal(pos, [9, 0])
-        np.testing.assert_array_equal(lo, [3, 0])
+        assert pool.slot_pos(s) == 9
+        with pytest.raises(RuntimeError, match="rollback below"):
+            pool.advance(s, -7)         # under the slot's floor lo=3
+        assert pool.slot_pos(s) == 9    # a refused advance changes nothing
         with pytest.raises(ValueError, match="bad position"):
             pool.set_slot(s, pos=16, lo=0)
+
+    @pytest.mark.parametrize("kwargs,want", [
+        (dict(), 16),
+        (dict(kv_dtype="int8"), 32),
+        (dict(kv_dtype="int8", block_size=16), "block_size 16 < 32"),
+    ])
+    def test_block_size_defaults_to_the_kernels_floor_for_the_dtype(
+            self, served_model, kwargs, want):
+        """The fused kernel's block floor is the engine's: nobody has to
+        know that an int8 tile needs 32 rows to build an int8 pool, and
+        an explicit value under the floor still raises."""
+        if isinstance(want, str):
+            with pytest.raises(ValueError, match=want):
+                GenerationEngine(served_model, num_slots=1, max_len=64,
+                                 **kwargs)
+            return
+        eng = GenerationEngine(served_model, num_slots=1, max_len=64,
+                               **kwargs)
+        stats = eng.stats()
+        eng.close()
+        assert stats["block_size"] == want
+        assert stats["num_blocks"] == 64 // want
+
+    def test_statusz_row_names_no_layout(self, served_model):
+        from paddle_tpu.framework import metrics
+        eng = GenerationEngine(served_model, num_slots=2, max_len=32)
+        row = next(ln for ln in metrics.statusz().splitlines()
+                   if ln.startswith(f"engine #{eng._eid} "))
+        eng.close()
+        assert row.startswith(
+            f"engine #{eng._eid} queue=0 active=0 slots=0/2 blocks=0/4 "
+            f"prefix_hit=0.00"), row
 
     def test_submit_validation(self, served_model):
         eng = GenerationEngine(served_model, num_slots=1, max_len=16,

@@ -7,12 +7,11 @@ The head-sharded engine must be a DROP-IN for the single-device one:
   early stop, mixed lengths) through the mp=2 sharded FUSED engine are
   token-identical to the single-device fused engine, with ZERO
   retraces once the buckets are warm and a clean ``analyze()`` bill on
-  the shard_map'd fused step; the gather oracle path holds the same
-  parity;
+  the shard_map'd fused step;
 * **memory** — stats() and the HBM ledger bill per-device KV block
   bytes at exactly 1/mp of the single-device pool (the scale-out
   claim: mp devices pool mp x the KV budget);
-* **policy** — block-pressure preemption (requeue + replay) rides the
+* **policy** — block-pressure preemption (requeue + feed again) rides the
   sharded pool unchanged, still token-exact vs ``generate``.
 
 Runs on the CPU mesh the tier-1 conftest forces
@@ -151,9 +150,7 @@ class TestShardedFusedParity:
 
         def mk_engine(model, mesh):
             return GenerationEngine(model, num_slots=8, max_len=48,
-                                    min_bucket=8, kv_layout="paged",
-                                    block_size=8, attention="fused",
-                                    mesh=mesh)
+                                    min_bucket=8, block_size=8, mesh=mesh)
 
         single = mk_engine(single_model, None)
         _warm(single, specs)
@@ -203,28 +200,6 @@ class TestShardedFusedParity:
         assert stats["active_requests"] == 0
         assert stats["kv_blocks_in_use"] == 0
 
-    def test_gather_path_parity(self, make_model):
-        """The gather oracle under shard_map holds the same parity as
-        the fused path (the ISSUE-15 'fused AND gather' clause), on a
-        smaller mix."""
-        rng = np.random.RandomState(5)
-        specs = [[_prompt(rng, int(rng.randint(2, 15))),
-                  int(rng.randint(2, 7)), None] for _ in range(8)]
-
-        def mk_engine(model, mesh):
-            return GenerationEngine(model, num_slots=4, max_len=48,
-                                    min_bucket=8, kv_layout="paged",
-                                    block_size=8, mesh=mesh)
-
-        single = mk_engine(make_model(), None)
-        single_outs = _storm(single, specs)
-        single.close()
-        eng = mk_engine(make_model(), _mesh())
-        sharded_outs = _storm(eng, specs)
-        eng.close()
-        for sout, shout in zip(single_outs, sharded_outs):
-            np.testing.assert_array_equal(shout, sout)
-
 
 # ---------------------------------------------------------------------------
 # scheduler policy under block pressure: preemption rides the shards
@@ -239,9 +214,8 @@ class TestShardedPreemption:
         produce the exact ``generate`` sequence."""
         model = make_model()
         eng = GenerationEngine(model, num_slots=2, max_len=32,
-                               kv_layout="paged", block_size=8,
-                               num_blocks=4, attention="fused",
-                               mesh=_mesh())
+                               block_size=8,
+                               num_blocks=4, mesh=_mesh())
         pa = _prompt(np.random.RandomState(6), 4)
         pb = _prompt(np.random.RandomState(7), 4)
         ha = eng.submit(pa, max_new_tokens=24)
@@ -264,15 +238,10 @@ class TestShardedPreemption:
 # ---------------------------------------------------------------------------
 
 class TestShardedValidation:
-    def test_mesh_requires_paged_layout(self, make_model):
-        with pytest.raises(ValueError, match="paged"):
-            GenerationEngine(make_model(), num_slots=2, max_len=32,
-                             mesh=_mesh())
-
     def test_mesh_rejects_quantized_blocks(self, make_model):
         with pytest.raises(ValueError, match="int8|quantiz"):
             GenerationEngine(make_model(), num_slots=2, max_len=32,
-                             kv_layout="paged", block_size=8,
+                             block_size=8,
                              kv_dtype="int8", mesh=_mesh())
 
     def test_mesh_axis_must_divide_heads(self, make_model):
@@ -282,5 +251,5 @@ class TestShardedValidation:
         mesh3 = Mesh(np.array(jax.devices()[:3]).reshape(3), ("mp",))
         with pytest.raises(ValueError, match="head"):
             GenerationEngine(make_model(), num_slots=2, max_len=32,
-                             kv_layout="paged", block_size=8,
+                             block_size=8,
                              mesh=mesh3)

@@ -24,58 +24,10 @@ import pytest
 from paddle_tpu import profiler
 from paddle_tpu.framework import monitor
 from paddle_tpu.serving.flight_recorder import FlightRecorder
-from paddle_tpu.serving.kv_pool import KVCachePool
-from paddle_tpu.serving.paging import PagedKVPool
 from paddle_tpu.serving.scheduler import GenerationRequest, Scheduler
 from paddle_tpu.serving.tracing import TERMINAL_EVENTS
 
-
-def _mock_pool(slots=2, max_len=64):
-    return KVCachePool(num_layers=1, num_slots=slots, num_heads=1,
-                       max_len=max_len, head_dim=1, min_bucket=8)
-
-
-class _MockDevice:
-    """Deterministic stand-in for the engine's device steps."""
-
-    def __init__(self, pool, prefill_delay=0.0, decode_delay=0.0):
-        self.pool = pool
-        self.prefill_delay = prefill_delay
-        self.decode_delay = decode_delay
-        self.prefills = []
-        self.decodes = 0
-
-    def do_prefill(self, req, slot, bucket):
-        if self.prefill_delay:
-            time.sleep(self.prefill_delay)
-        self.prefills.append((req.id, slot, bucket))
-        return 1
-
-    def do_decode(self, slot_requests):
-        if self.decode_delay:
-            time.sleep(self.decode_delay)
-        self.decodes += 1
-        return np.full(self.pool.num_slots, 2, np.int32)
-
-
-class _PagedMockDevice:
-    """Mock device steps doing the engine's PAGED pool bookkeeping
-    (fresh-prefill only — no prefix cache — so freed blocks return to
-    the free list and pressure must be answered by preemption)."""
-
-    def __init__(self, pool):
-        self.pool = pool
-
-    def do_prefill(self, req, slot, bucket):
-        feed = np.concatenate([req.prompt,
-                               np.asarray(req.tokens, np.int32)])
-        self.pool.admit_fresh(slot, feed.size)
-        self.pool.set_slot(slot, pos=feed.size, lo=0)
-        req.replay = []
-        return 100 + feed.size
-
-    def do_decode(self, slot_requests):
-        return np.full(self.pool.num_slots, 7, np.int32)
+from _mock_serving import MockDevice, mock_pool
 
 
 def _submit(sched, prompt_len=4, max_new=3, **kw):
@@ -85,9 +37,9 @@ def _submit(sched, prompt_len=4, max_new=3, **kw):
 
 class TestRequestTrace:
     def test_event_ordering_and_derived_metrics(self):
-        pool = _mock_pool(slots=2)
-        dev = _MockDevice(pool, decode_delay=0.002)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool(slots=2)
+        dev = MockDevice(pool, decode_delay=0.002)
+        sched = dev.scheduler()
         handles = [_submit(sched, prompt_len=4 + i, max_new=4)
                    for i in range(3)]
         for h in handles:
@@ -114,9 +66,9 @@ class TestRequestTrace:
             json.dumps(tl)
 
     def test_terminal_event_names_cancel_and_deadline(self):
-        pool = _mock_pool(slots=1)
-        dev = _MockDevice(pool, decode_delay=0.01)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool(slots=1)
+        dev = MockDevice(pool, decode_delay=0.01)
+        sched = dev.scheduler()
         a = _submit(sched, max_new=50)
         b = _submit(sched, max_new=50, timeout=0.05)
         time.sleep(0.03)
@@ -129,9 +81,9 @@ class TestRequestTrace:
         assert b.trace.t("deadline") is not None
 
     def test_tpot_none_for_single_token_request(self):
-        pool = _mock_pool()
-        dev = _MockDevice(pool)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool()
+        dev = MockDevice(pool)
+        sched = dev.scheduler()
         h = _submit(sched, max_new=1)
         h.result(timeout=60)
         sched.close()
@@ -141,9 +93,9 @@ class TestRequestTrace:
 
     def test_tpot_histogram_live(self):
         monitor.stat_reset("serving/tpot_ms")
-        pool = _mock_pool()
-        dev = _MockDevice(pool, decode_delay=0.001)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool()
+        dev = MockDevice(pool, decode_delay=0.001)
+        sched = dev.scheduler()
         _submit(sched, max_new=5).result(timeout=60)
         sched.close()
         h = monitor.stat_histogram("serving/tpot_ms")
@@ -155,13 +107,11 @@ class TestPreemptionReplayTrace:
     def test_preempt_and_readmission_appear_in_trace(self):
         # 4 usable blocks of 8, two requests that each want 3 blocks:
         # growth exhausts the pool mid-decode, the youngest (B) is
-        # preempted, replays through re-admission, and still finishes
+        # preempted, feeds again after re-admission, and still finishes
         # with the full token budget
-        pool = PagedKVPool(num_layers=1, num_slots=2, num_heads=1,
-                           max_len=32, head_dim=1, block_size=8,
-                           num_blocks=4, min_bucket=8)
-        dev = _PagedMockDevice(pool)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool(slots=2, max_len=32, num_blocks=4)
+        dev = MockDevice(pool)
+        sched = dev.scheduler()
         a = _submit(sched, prompt_len=8, max_new=12)
         b = _submit(sched, prompt_len=8, max_new=12)
         ra = a.result(timeout=60)
@@ -183,10 +133,9 @@ class TestPreemptionReplayTrace:
 class TestFlightRecorder:
     def test_ring_buffer_bounds_hold(self):
         rec = FlightRecorder(max_cycles=4, max_events=10)
-        pool = _mock_pool(slots=2)
-        dev = _MockDevice(pool)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode,
-                          recorder=rec)
+        pool = mock_pool(slots=2)
+        dev = MockDevice(pool)
+        sched = dev.scheduler(recorder=rec)
         for _ in range(8):
             _submit(sched, max_new=4).result(timeout=60)
         sched.close()
@@ -199,9 +148,9 @@ class TestFlightRecorder:
         assert snap["requests_retired"] == 8
 
     def test_cycle_records_breakdown(self):
-        pool = _mock_pool(slots=2)
-        dev = _MockDevice(pool, prefill_delay=0.002, decode_delay=0.002)
-        sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+        pool = mock_pool(slots=2)
+        dev = MockDevice(pool, prefill_delay=0.002, decode_delay=0.002)
+        sched = dev.scheduler()
         _submit(sched, max_new=3).result(timeout=60)
         sched.close()
         cycles = sched.recorder.snapshot()["cycles"]
@@ -221,15 +170,15 @@ class TestFlightRecorder:
         assert monitor.stat_histogram("serving/cycle_ms") is not None
 
     def test_step_failure_auto_dumps(self):
-        pool = _mock_pool(slots=2)
-        dev = _MockDevice(pool)
+        pool = mock_pool(slots=2)
+        dev = MockDevice(pool)
         boom = {"armed": False}
 
-        def bad_decode(slot_requests):
+        def bad_step(slot_requests, plan):
             boom["armed"] = True
             raise RuntimeError("injected device failure")
 
-        sched = Scheduler(pool, dev.do_prefill, bad_decode)
+        sched = Scheduler(pool, dev.do_prefill, bad_step)
         h = _submit(sched, max_new=4)
         with pytest.raises(RuntimeError):
             h.result(timeout=60)
@@ -247,12 +196,9 @@ class TestFlightRecorder:
     def test_per_engine_latency_isolation(self):
         # two schedulers in one process: each recorder's percentiles
         # come from its own retired traces only
-        fast_pool, slow_pool = _mock_pool(), _mock_pool()
-        fast = Scheduler(fast_pool, _MockDevice(fast_pool).do_prefill,
-                         _MockDevice(fast_pool).do_decode)
-        slow_dev = _MockDevice(slow_pool, decode_delay=0.02)
-        slow = Scheduler(slow_pool, slow_dev.do_prefill,
-                         slow_dev.do_decode)
+        fast_pool, slow_pool = mock_pool(), mock_pool()
+        fast = MockDevice(fast_pool).scheduler()
+        slow = MockDevice(slow_pool, decode_delay=0.02).scheduler()
         for s in (fast, slow):
             for _ in range(3):
                 _submit(s, max_new=4).result(timeout=60)
@@ -270,10 +216,10 @@ class TestFlightRecorder:
 
 class TestChromeTraceExport:
     def test_request_lanes_and_thread_names(self, tmp_path):
-        pool = _mock_pool(slots=2)
-        dev = _MockDevice(pool, decode_delay=0.001)
+        pool = mock_pool(slots=2)
+        dev = MockDevice(pool, decode_delay=0.001)
         with profiler.profile() as sess:
-            sched = Scheduler(pool, dev.do_prefill, dev.do_decode)
+            sched = dev.scheduler()
             hs = [_submit(sched, max_new=3) for _ in range(2)]
             # consume on a separate thread so the submitter and the
             # stream-consumer labels land on distinct lanes

@@ -95,8 +95,7 @@ class TestGreedyParity:
         def run(spec_draft):
             eng = GenerationEngine(
                 served_model, num_slots=8, max_len=48,
-                kv_layout="paged", block_size=8, attention="fused",
-                spec_draft=spec_draft, spec_k=4, prefill_budget=16)
+                block_size=8, spec_draft=spec_draft, spec_k=4, prefill_budget=16)
             hs = [eng.submit(p, max_new_tokens=n, eos_token_id=3)
                   for p, n in specs]
             outs = [h.result(timeout=600) for h in hs]
@@ -129,8 +128,7 @@ class TestGreedyParity:
         assert not retraced, f"warm buckets retraced: {retraced}"
         # and the plain fused engine agrees too (no-spec oracle)
         eng2 = GenerationEngine(
-            served_model, num_slots=8, max_len=48, kv_layout="paged",
-            block_size=8, attention="fused", prefill_budget=16)
+            served_model, num_slots=8, max_len=48, block_size=8, prefill_budget=16)
         hs = [eng2.submit(p, max_new_tokens=n, eos_token_id=3)
               for p, n in specs]
         outs3 = [h.result(timeout=600) for h in hs]
@@ -150,8 +148,7 @@ class TestGreedyParity:
         refs = [generate(served_model, p[None, :],
                          max_new_tokens=10).numpy()[0] for p in prompts]
         eng = GenerationEngine(
-            served_model, num_slots=4, max_len=48, kv_layout="paged",
-            block_size=8, attention="fused", spec_draft=served_model,
+            served_model, num_slots=4, max_len=48, block_size=8, spec_draft=served_model,
             spec_k=4, prefill_budget=16)
         hs = [eng.submit(p, max_new_tokens=10) for p in prompts]
         outs = [h.result(timeout=600) for h in hs]
@@ -176,8 +173,7 @@ class TestGreedyParity:
         refs = [generate(served_model, p[None, :],
                          max_new_tokens=10).numpy()[0] for p in prompts]
         eng = GenerationEngine(
-            served_model, num_slots=4, max_len=48, kv_layout="paged",
-            block_size=8, attention="fused", spec_draft=weak_draft,
+            served_model, num_slots=4, max_len=48, block_size=8, spec_draft=weak_draft,
             spec_k=4, prefill_budget=16)
         hs = [eng.submit(p, max_new_tokens=10) for p in prompts]
         outs = [h.result(timeout=600) for h in hs]
@@ -200,8 +196,7 @@ class TestGreedyParity:
         refs = [generate(served_model, p[None, :],
                          max_new_tokens=8).numpy()[0] for p in prompts]
         eng = GenerationEngine(
-            served_model, num_slots=4, max_len=64, kv_layout="paged",
-            block_size=32, attention="fused", kv_dtype="int8",
+            served_model, num_slots=4, max_len=64, block_size=32, kv_dtype="int8",
             spec_draft=served_model, spec_k=4, prefill_budget=16)
         hs = [eng.submit(p, max_new_tokens=8) for p in prompts]
         outs = [h.result(timeout=600) for h in hs]
@@ -301,8 +296,7 @@ class TestRejectionSamplingIdentity:
         rng = np.random.RandomState(5)
         prompts = [_prompt(rng, n) for n in (4, 9, 6, 3)]
         eng = GenerationEngine(
-            served_model, num_slots=4, max_len=48, kv_layout="paged",
-            block_size=8, attention="fused", spec_draft=weak_draft,
+            served_model, num_slots=4, max_len=48, block_size=8, spec_draft=weak_draft,
             spec_k=3, prefill_budget=16)
         hs = [eng.submit(p, max_new_tokens=6, do_sample=bool(i % 2),
                          temperature=0.9)
@@ -374,9 +368,7 @@ class TestRollbackMachinery:
         refs = [generate(served_model, p[None, :],
                          max_new_tokens=12).numpy()[0] for p in prompts]
         eng = GenerationEngine(
-            served_model, num_slots=3, max_len=64, kv_layout="paged",
-            block_size=8, num_blocks=12, attention="fused",
-            spec_draft=served_model, spec_k=4, prefill_budget=16)
+            served_model, num_slots=3, max_len=64, block_size=8, num_blocks=12, spec_draft=served_model, spec_k=4, prefill_budget=16)
         hs = [eng.submit(p, max_new_tokens=12) for p in prompts]
         outs = [h.result(timeout=600) for h in hs]
         stats = eng.stats()
@@ -402,20 +394,27 @@ class TestRollbackMachinery:
         with pytest.raises(ValueError, match="num_layers"):
             make_draft_model(served_model, num_layers=9)
 
+    def test_min_bucket_floors_the_drafts_prefill_ladder(self,
+                                                        served_model):
+        """What is left of the bucket ladder: the draft's context sync
+        is a bucketed prefill, pow2 from ``min_bucket`` to ``max_len``."""
+        eng = GenerationEngine(served_model, max_len=48, block_size=8,
+                               spec_draft=served_model, min_bucket=16)
+        try:
+            assert [eng._draft_bucket(n) for n in (1, 16, 17, 33, 47)] \
+                == [16, 16, 32, 48, 48]
+        finally:
+            eng.close()
+        with pytest.raises(ValueError, match="min_bucket"):
+            GenerationEngine(served_model, max_len=48, min_bucket=0)
+
     def test_construction_validation(self, served_model):
-        with pytest.raises(ValueError, match="attention='fused'"):
-            GenerationEngine(served_model, kv_layout="paged",
-                             spec_draft=served_model)
         with pytest.raises(ValueError, match="spec_k"):
-            GenerationEngine(served_model, kv_layout="paged",
-                             attention="fused", block_size=8,
+            GenerationEngine(served_model, block_size=8,
                              max_len=48, spec_draft=served_model,
                              spec_k=0)
-        with pytest.raises(ValueError, match="kv_dtype"):
-            GenerationEngine(served_model, kv_dtype="int8")
         with pytest.raises(ValueError, match="block_size 8 < 32"):
-            GenerationEngine(served_model, kv_layout="paged",
-                             attention="fused", block_size=8,
+            GenerationEngine(served_model, block_size=8,
                              max_len=48, kv_dtype="int8")
         # draft vocab mismatch
         other = GPTForPretraining(GPTConfig(
@@ -423,6 +422,5 @@ class TestRollbackMachinery:
             num_attention_heads=2, intermediate_size=64,
             max_position_embeddings=64))
         with pytest.raises(ValueError, match="vocab"):
-            GenerationEngine(served_model, kv_layout="paged",
-                             attention="fused", block_size=8,
+            GenerationEngine(served_model, block_size=8,
                              max_len=48, spec_draft=other)

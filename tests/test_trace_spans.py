@@ -128,8 +128,8 @@ def _prompt(rng, n):
 def test_serving_cycles_are_in_the_trace_with_their_children_in_order(
         tiny_lm, tmp_path):
     eng = GenerationEngine(tiny_lm, num_slots=2, max_len=32,
-                           kv_layout="paged", block_size=8,
-                           attention="fused", prefill_budget=8)
+                           block_size=8,
+                           prefill_budget=8)
     rng = np.random.RandomState(0)
     try:
         # warm: the traced cycles compile nothing
@@ -181,8 +181,8 @@ def test_serving_cycles_are_in_the_trace_with_their_children_in_order(
 
 def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
     eng = GenerationEngine(tiny_lm, num_slots=2, max_len=32,
-                           kv_layout="paged", block_size=8,
-                           attention="fused", prefill_budget=8)
+                           block_size=8,
+                           prefill_budget=8)
     # (cycle, rows, sum of pos + rows, q blocks of 8 x KV blocks of 8,
     # q blocks of 8: at most 4 KV blocks here, one group of G = 16)
     planned = []
@@ -244,8 +244,8 @@ def test_kv_fetches_counts_the_groups_a_launch_waits_for():
     cfg = GPTConfig.tiny()
     cfg.max_position_embeddings = 192
     eng = GenerationEngine(GPTForPretraining(cfg), num_slots=2, max_len=192,
-                           kv_layout="paged", block_size=32,
-                           attention="fused", prefill_budget=64)
+                           block_size=32,
+                           prefill_budget=64)
     heads = cfg.num_attention_heads
     assert kv_group_blocks(heads, 32, cfg.hidden_size // heads,
                            "float32") == 4
