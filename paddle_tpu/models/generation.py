@@ -734,6 +734,18 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     return dec.final_norm(x), pool, scales, counters
 
 
+def _tokens_from_prev(token_ids, prev_tokens, token_src):
+    """The fused step's input tokens with the rows the host could not
+    fill: row ``i`` takes ``prev_tokens[token_src[i]]`` — the token the
+    launch before this one picked for that slot, still un-fetched —
+    where ``token_src[i] >= 0``, and keeps ``token_ids[i]`` elsewhere.
+    It is what lets the scheduler dispatch a launch before it has
+    fetched the one in flight (``serving/scheduler.py``)."""
+    import jax.numpy as jnp
+    return jnp.where(token_src >= 0,
+                     prev_tokens[jnp.maximum(token_src, 0)], token_ids)
+
+
 def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                         top_k=0, top_p=1.0, probe=None, quantized=False,
                         qmax=127.0):
@@ -747,11 +759,12 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
 
     Returns ``fn(params, buffers, pool, token_ids, qpos, write_block,
     write_off, blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-    last_row, sample_mask, temperature, key) -> (pool, next_tokens,
-    key)`` over the block pool ``[layers, num_blocks + 1, heads,
-    block_size, 2 * head_dim]`` (``next_tokens`` ``[num_slots + 1]`` —
-    the last element is the logits-finite sentinel of
-    :func:`_append_nonfinite_flag`):
+    last_row, prev_tokens, token_src, sample_mask, temperature, key) ->
+    (pool, next_tokens, key)`` over the block pool ``[layers,
+    num_blocks + 1, heads, block_size, 2 * head_dim]`` (``next_tokens``
+    ``[num_slots + 1]`` — the last element is the logits-finite sentinel
+    of :func:`_append_nonfinite_flag`; a routed model appends its three
+    counters):
 
     * ``token_ids``/``qpos``/``write_block``/``write_off`` ``[q_rows]``
       int32 — the flattened padded ragged batch (see
@@ -769,6 +782,11 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
       cycle gets its first generated token from the SAME launch that
       prefilled the tail (rows of slots mid-chunk or absent produce
       garbage the scheduler ignores);
+    * ``prev_tokens`` — the ``next_tokens`` of the launch before this
+      one, handed back UN-fetched (zeros when none is in flight) — and
+      ``token_src [q_rows]`` int32: a decode row whose input token is
+      still on the device names its slot there, every other row says -1
+      and keeps its ``token_ids`` (:func:`_tokens_from_prev`);
     * ``sample_mask``/``temperature`` ``[num_slots]`` are traced (one
       program serves mixed greedy/sampled batches); the caller jits
       with ``donate_argnums`` on ``pool`` and the engine's ``analyze()``
@@ -804,13 +822,15 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     def fn(params, buffers, pool, *rest):
         (scales, token_ids, qpos, write_block, write_off, blk_seq,
          seq_qstart, seq_pos0, tables, lo, kv_len, last_row,
-         sample_mask, temperature, key) = \
+         prev_tokens, token_src, sample_mask, temperature, key) = \
             rest if quantized else (None,) + rest
         if probe is not None:  # runs at trace time only (jit caches)
             probe.record(_probe.sig_of([pool, token_ids, tables]),
                          {"q": Q, "table": T})
         with functional_state(model, params, buffers):
             with no_grad_guard():
+                token_ids = _tokens_from_prev(token_ids, prev_tokens,
+                                              token_src)
                 # logical positions == virtual positions (paged
                 # sequences are aligned at virtual 0; lo is the mask
                 # floor, not a pad offset)
@@ -983,7 +1003,8 @@ def build_sharded_fused_step_fn(model, num_slots, q_rows, table_len,
     projections contribute the only collectives — one psum per
     out-proj/MLP-out joining attention outputs before the replicated
     LM head feeds :func:`_pick_token`, so the picked token is identical
-    on every device. Signature, bucket discipline and the
+    on every device (and so is ``prev_tokens``, the replicated result
+    handed back). Signature, bucket discipline and the
     ``donate_argnums`` contract on the (now head-partitioned GLOBAL)
     pool are unchanged from the single-device builder — the donated
     pool stays donated through the shard_map boundary."""
@@ -1010,9 +1031,12 @@ def build_sharded_fused_step_fn(model, num_slots, q_rows, table_len,
 
     def body(params, buffers, pool, token_ids, qpos, write_block,
              write_off, blk_seq, seq_qstart, seq_pos0, tables, lo,
-             kv_len, last_row, sample_mask, temperature, key):
+             kv_len, last_row, prev_tokens, token_src, sample_mask,
+             temperature, key):
         with functional_state(model, params, buffers):
             with no_grad_guard():
+                token_ids = _tokens_from_prev(token_ids, prev_tokens,
+                                              token_src)
                 x = gpt.wte(Tensor(token_ids[None, :],
                                    stop_gradient=True)) \
                     + gpt.wpe(Tensor(qpos[None, :]))
@@ -1037,18 +1061,20 @@ def build_sharded_fused_step_fn(model, num_slots, q_rows, table_len,
     sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(megatron_param_specs(model, mp_axis), rep,
-                  _mp_pool_spec(mp_axis)) + (rep,) * 14,
+                  _mp_pool_spec(mp_axis)) + (rep,) * 16,
         out_specs=(_mp_pool_spec(mp_axis), rep, rep), check_vma=False)
 
     def fn(params, buffers, pool, token_ids, qpos, write_block,
            write_off, blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-           last_row, sample_mask, temperature, key):
+           last_row, prev_tokens, token_src, sample_mask, temperature,
+           key):
         if probe is not None:  # runs at trace time only (jit caches)
             probe.record(_probe.sig_of([pool, token_ids, tables]),
                          {"q": Q, "table": T, "mp": mp})
         return sm(params, buffers, pool, token_ids, qpos, write_block,
                   write_off, blk_seq, seq_qstart, seq_pos0, tables, lo,
-                  kv_len, last_row, sample_mask, temperature, key)
+                  kv_len, last_row, prev_tokens, token_src, sample_mask,
+                  temperature, key)
 
     return fn
 

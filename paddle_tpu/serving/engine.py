@@ -14,9 +14,13 @@ addressed through its page table; admission gates on FREE BLOCKS, memory
 pressure preempts the youngest request (requeued, fed again) instead of
 deadlocking, and full prompt blocks are shared across requests through
 the prefix cache — a repeated system prompt feeds only its uncovered
-tail. Each cycle is ONE fused ragged launch mixing ``prefill_budget``
-tokens of prompt chunks with every decode row; the first generated token
-comes out of the launch that fed the final chunk. Greedy output is
+tail. Each turn of the scheduler dispatches ONE fused ragged launch
+mixing ``prefill_budget`` tokens of prompt chunks with every decode row;
+the first generated token comes out of the launch that fed the final
+chunk. Two launches are in flight: a launch goes out before the one
+before it is fetched, and a decode row whose input token the host has
+not seen yet reads it from that launch's un-fetched result, on the
+device (``_run_fused_step``'s ``prev``). Greedy output is
 token-identical to ``models.generate`` run per request
 (tests/test_serving_engine.py).
 
@@ -29,6 +33,7 @@ like training-loop churn.
 
 Observability (PR-1 wiring + the ISSUE-6 SLO spine): counters
 ``serving/requests``, ``serving/completed``, ``serving/tokens``,
+``serving/launch_overlapped``, ``serving/late_rows``,
 ``serving/preempt``, ``serving/queue_full``, ``serving/cancelled``,
 ``serving/deadline_exceeded``, ``serving/prefix_hit``/``prefix_miss``/
 ``prefill_tokens_saved``/``prefix_evict``; histograms
@@ -408,6 +413,19 @@ class GenerationEngine:
             num_blocks=num_blocks, dtype=kv_dtype or dtype,
             mesh=mesh, mp_axis=mp_axis, lanes=cache.lanes)
         self._fused_jits = {}         # (q bucket, table bucket) -> step
+        # the fused step's "previous result" operand while no launch is
+        # in flight: the shape of its own result ([slots | sentinel |
+        # a routed model's three counters]), never read (token_src -1)
+        from ..models.decoder_spec import ROUTED
+        self._no_prev = np.zeros(
+            num_slots + 1 + 3 * any(ls.ffn == ROUTED for ls in spec.layers),
+            np.int32)
+        if mesh is not None:
+            # replicated over the mesh, as the result it stands in for
+            # is: one operand type, so one trace a (q, table) bucket
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._no_prev = jax.device_put(
+                self._no_prev, NamedSharding(mesh, PartitionSpec()))
         self._spec_jits = {}          # (q, table) -> spec verify step
         self._copy_jit = None         # lazy COW device block copy
         # hierarchical KV cache (ISSUE 20): a bounded host-DRAM block
@@ -822,6 +840,7 @@ class GenerationEngine:
             np.zeros(S, np.int32), np.zeros(S, np.int32),
             np.zeros((S, T), np.int32), np.zeros(S, np.int32),
             np.zeros(S, np.int32), np.zeros(S, np.int32),
+            self._no_prev, np.full(Q, -1, np.int32),
             np.zeros(S, bool), np.ones(S, np.float32), self._key,
             passes=passes,
             name=f"serving.fused_step[{S} slots, q{Q}, t{T}]")
@@ -898,6 +917,7 @@ class GenerationEngine:
                     np.zeros(S, np.int32), np.zeros(S, np.int32),
                     np.zeros((S, T), np.int32), np.zeros(S, np.int32),
                     np.zeros(S, np.int32), np.zeros(S, np.int32),
+                    self._no_prev, np.full(Q, -1, np.int32),
                     np.zeros(S, bool), np.ones(S, np.float32),
                     self._key)
             flavor, site = "fused", f"fused_step[q{Q},t{T}]"
@@ -979,14 +999,18 @@ class GenerationEngine:
             pool.set_slot(slot, pos=0, lo=0)
             req.pending_feed = [int(t) for t in feed]
 
-    def _ragged_operands(self, slot_requests, plan, spec=None):
+    def _ragged_operands(self, slot_requests, plan, spec=None,
+                         from_prev=()):
         """Host-side flattened ragged-row operands shared by the fused
         step and the speculative verify launch: per-slot contiguous
         padded rows, page-table-resolved write targets, and the
         scalar-prefetch metadata. Speculating slots (``spec``)
         contribute their candidate rows with only ``last_token``
         host-known — the draft tokens overlay on the device inside the
-        verify program."""
+        verify program. The decode rows of the slots in ``from_prev``
+        have no host token yet (it is in the un-fetched result of the
+        launch in flight): ``token_src`` names the slot at such a row
+        and -1 everywhere else."""
         from ..ops.ragged_paged_attention import (BLOCK_Q, kv_group_blocks,
                                                   ragged_layout)
 
@@ -1013,6 +1037,8 @@ class GenerationEngine:
             if spec and slot in spec:
                 n_spec[slot] = n
                 row_tokens[slot] = [req.last_token]
+            elif slot in from_prev:
+                row_tokens[slot] = []
             else:
                 row_tokens[slot] = (req.pending_feed[:n]
                                     if req.pending_feed
@@ -1025,6 +1051,9 @@ class GenerationEngine:
         qpos = np.zeros(Q, np.int32)
         write_block = np.zeros(Q, np.int32)   # pad rows -> scratch block
         write_off = np.zeros(Q, np.int32)
+        token_src = np.full(Q, -1, np.int32)
+        for slot in from_prev:
+            token_src[int(qstart[slot])] = slot
         for slot, toks in row_tokens.items():
             r0, p0 = int(qstart[slot]), int(pos0[slot])
             table = pool.slot_table(slot)
@@ -1062,20 +1091,27 @@ class GenerationEngine:
                 for s, n in enumerate(q_lens) if n))
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
-                n_spec, sample_mask, temps)
+                n_spec, sample_mask, temps, token_src)
 
-    def _run_fused_step(self, slot_requests, plan):
+    def _run_fused_step(self, slot_requests, plan, prev=None):
         """Dispatch ONE fused ragged launch (the scheduler's
         ``do_chunked_step``): budgeted prompt chunks + decode rows,
         flattened into the padded row layout of
         ``ops.ragged_paged_attention`` and served by the
         ``build_fused_step_fn`` program for this (q bucket, table
-        bucket). Returns the next-token DEVICE array un-fetched."""
+        bucket). ``prev`` is ``(result, slots)`` when the launch in
+        flight holds the input token of these slots' decode rows: its
+        un-fetched result goes back in as an operand and the token never
+        visits the host. Returns the next-token DEVICE array
+        un-fetched."""
         pool = self._pool
-        Q, T, ops, _, sample_mask, temps = self._ragged_operands(
-            slot_requests, plan)
+        prev_toks, from_prev = prev if prev is not None \
+            else (self._no_prev, ())
+        Q, T, ops, _, sample_mask, temps, token_src = \
+            self._ragged_operands(slot_requests, plan,
+                                  from_prev=from_prev)
         step = self._fused_step_fn(Q, T)
-        args = ops + (sample_mask, temps, self._key)
+        args = ops + (prev_toks, token_src, sample_mask, temps, self._key)
         if pool.quantized:
             pool.data, pool.scales, nxt, self._key = step(
                 self._params, self._buffers, pool.data, pool.scales,
@@ -1326,7 +1362,7 @@ class GenerationEngine:
             d_dev = jnp.pad(d_dev, ((0, 0), (0, K - kmax)))
             q_dev = jnp.pad(q_dev, ((0, 0), (0, K - kmax), (0, 0)))
         # --- the fused verify launch ---------------------------------
-        Q, T, ops, n_spec, sample_mask, temps = self._ragged_operands(
+        Q, T, ops, n_spec, sample_mask, temps, _ = self._ragged_operands(
             slot_requests, plan, spec=spec)
         step = self._spec_step_fn(Q, T)
         args = ops + (n_spec, d_dev, q_dev, sample_mask, temps,

@@ -101,6 +101,18 @@ class _OpsHandler(BaseHTTPRequestHandler):
         self._dispatch("POST")
 
 
+class _Server(ThreadingHTTPServer):
+    """The stdlib's listen backlog is 5: when a fleet of clients
+    connects at once (64 at a time is one benchmark cell's ramp) the
+    kernel drops the SYNs that find the accept queue full, and each
+    such client comes back 1, 3, 7, 15 s later — whole seconds of a
+    cold start that no thread of this process ever sees. The accept
+    loop shares the GIL with a scheduler that no longer sleeps through
+    half of every turn, so the queue has to hold a burst."""
+    request_queue_size = 1024
+    daemon_threads = True
+
+
 class OpsServer:
     """One process, one ops surface: a threaded stdlib HTTP server over
     the metrics registry, optionally bound to an engine or fleet for
@@ -198,9 +210,7 @@ class OpsServer:
     def start(self) -> "OpsServer":
         if self._httpd is not None:
             return self
-        httpd = ThreadingHTTPServer((self._host, self._port),
-                                    _OpsHandler)
-        httpd.daemon_threads = True
+        httpd = _Server((self._host, self._port), _OpsHandler)
         httpd.ops = self                                 # type: ignore
         self._httpd = httpd
         self._thread = threading.Thread(

@@ -1,8 +1,8 @@
-"""Continuous-batching scheduler: admit, launch, retire — every cycle.
+"""Continuous-batching scheduler: admit, launch, retire — every turn.
 
 The loop at the heart of ``GenerationEngine``. Unlike the gather-and-run
 ``inference.BatchingEngine`` (whole batch enters and leaves together),
-membership of the in-flight batch changes EVERY cycle:
+membership of the in-flight batch changes EVERY turn:
 
 * **admit** — pop from the bounded admission queue into free pool
   slots under WEIGHTED-FAIR scheduling: queued requests are classed by
@@ -12,16 +12,31 @@ membership of the in-flight batch changes EVERY cycle:
   capacity still flows to batch; one queued class degenerates to
   FCFS exactly). Admission is pure host bookkeeping: blocks reserved
   (a prefix-cache match adopted), ``req.pending_feed`` armed;
-* **launch** — ONE fused ragged, pool-donated launch a cycle mixes
+* **launch** — ONE fused ragged, pool-donated launch a turn mixes
   ``prefill_budget`` tokens of prompt chunks with every decode row.
   Decode is never budget-charged, so a prompt burst cannot monopolize a
-  cycle, and the first generated token emits from the launch that feeds
+  launch, and the first generated token emits from the launch that feeds
   the final chunk (``serving/prefill_chunks``/``serving/chunk_tokens``,
-  per-cycle ``chunk_tokens`` in the flight recorder); the single host
-  fetch per cycle delivers each new token to its stream;
+  per-launch ``chunk_tokens`` in the flight recorder);
+* **land** — TWO LAUNCHES ARE IN FLIGHT: a turn dispatches launch N+1
+  and only then fetches and emits launch N, so the device goes from one
+  launch to the next with no host in between and the host's half of a
+  launch (the single fetch, each new token to its stream, the handler
+  threads that wakes) runs in the device's shadow. What the next plan
+  needs — positions, how much of a feed is drained, who reaches
+  ``max_new_tokens`` — is host arithmetic applied at DISPATCH; the one
+  thing it cannot know, a decode row's input token, the step reads from
+  the previous launch's un-fetched result on the device
+  (``GenerationRequest.in_flight``). The pipeline drains where the turn
+  sees it must (speculative mode, pool pressure, an empty plan, the
+  launch that opens a busy stretch): the serial cycle is this loop
+  drained every turn, not a second loop;
 * **retire** — finished (EOS / token budget), cancelled and
-  deadline-expired slots are freed IMMEDIATELY, so their capacity is
-  reused by the very next admit — mid-flight, not at batch end.
+  deadline-expired slots are freed at the emit that finds them, so
+  their capacity is reused by the next admit — mid-flight, not at batch
+  end. EOS, a cancel and a deadline are learned one launch late: the
+  row the request already holds in the launch after is dropped when
+  that launch lands (``late_rows``).
 
 Backpressure is explicit: a full queue raises :class:`QueueFullError`
 in ``submit`` (the caller sheds load, nothing queues unboundedly), and
@@ -32,17 +47,19 @@ Observability (the serving SLO spine, ISSUE 6): every request carries a
 :class:`~.tracing.RequestTrace` of timestamped lifecycle events
 (submit → admitted → prefill → first token → per-token stamps →
 finish/cancel/deadline, plus preemptions and prefix hits), from which
-TTFT and TPOT derive per request; every CYCLE writes a record into the
+TTFT and TPOT derive per request; every LAUNCH writes a record into the
 always-on bounded :class:`~.flight_recorder.FlightRecorder` (sweep /
-admit / plan / decode-dispatch / host-fetch / emit breakdown, occupancy,
-queue depth) so a scheduler stall is debuggable postmortem without the
-profiler armed. When a ``profiler.profile()`` session IS armed, the
-same phases additionally emit nested ``serving/cycle`` spans and each
-finished request exports a chrome-trace lane.
+admit / plan / decode-dispatch stamped in the turn that dispatched it,
+host-fetch / emit in the turn that landed it; occupancy, queue depth,
+``overlapped``, ``late_rows``) so a scheduler stall is debuggable
+postmortem without the profiler armed. When a ``profiler.profile()``
+session IS armed, the same phases additionally emit ``serving/cycle``
+spans (one a turn) with children that each carry their LAUNCH's number,
+and each finished request exports a chrome-trace lane.
 
 Threading contract: ``submit``/``cancel`` may be called from any
 thread; the loop body, the pool, and all slot state belong to the
-scheduler thread alone (trace marks and cycle records included — all
+scheduler thread alone (trace marks and launch records included — all
 host stamps, taken outside every traced fn). The ONLY device→host sync
 in the loop is :func:`_fetch` below — everything else stays async
 (enforced by the ``serving-host-sync`` self-lint rule over this
@@ -160,6 +177,12 @@ class GenerationRequest:
         self.tokens: List[int] = []     # generated so far (incl. EOS)
         self.emitted = 0
         self.last_token: Optional[int] = None
+        # generated tokens DISPATCHED but not yet fetched: a launch whose
+        # row produces this request's next token raises it, the emit of
+        # that launch lowers it. While it is nonzero the newest token
+        # exists only in the un-fetched result of the launch in flight —
+        # the next launch reads it there, on the device
+        self.in_flight = 0
         # the not-yet-fed feed tokens (the prompt past a prefix-cache
         # hit; after a preemption, the request's own history too) —
         # drained in token-budget chunks through the fused ragged step,
@@ -285,21 +308,27 @@ class Scheduler:
     * ``do_prefill(request, slot)`` — the admission hook: reserve the
       feed's blocks (adopting a prefix-cache match), set the slot's
       position, arm ``request.pending_feed``. No program runs;
-    * ``do_chunked_step(slot_requests, plan) -> token array`` —
-      DISPATCH the cycle's ONE ragged launch (``plan``: rows a slot —
+    * ``do_chunked_step(slot_requests, plan, prev) -> token array`` —
+      DISPATCH the turn's ONE ragged launch (``plan``: rows a slot —
       budgeted prompt chunks and the decode rows) and return its result
-      UN-fetched (a device array; plain numpy passes through): the
-      scheduler performs the windowed ``_fetch`` itself so the cycle
-      telemetry can time dispatch and host-fetch apart — a step that
-      syncs internally would hide the fetch inside
-      ``decode_dispatch_ms``. Every slot gets a token (garbage for
-      inactive and mid-feed slots);
+      UN-fetched (a device array; plain numpy passes through). It is
+      called with positions and ``pending_feed`` as they stand BEFORE
+      this launch (the scheduler advances them right after the call).
+      ``prev`` is ``None``, or ``(result, slots)``: the un-fetched
+      result of the launch dispatched before this one and the slots
+      whose decode row must take its input token from it, because the
+      host has not seen that token yet (``request.last_token`` is one
+      behind for them). The result is fetched a turn LATER, after the
+      next launch has been dispatched, by the scheduler's one windowed
+      ``_fetch`` — a step that syncs internally would serialize the
+      pipeline and hide the fetch inside ``decode_dispatch_ms``. Every
+      slot gets a token (garbage for inactive and mid-feed slots);
     * ``do_copy(dst, src)`` — device block copy (copy-on-write append);
     * ``do_spec_step(slot_requests, plan, spec)`` — the speculative
-      verify launch, see below.
+      verify launch, see below; fetched in the turn that dispatched it.
 
-    ``prefill_budget`` is the per-cycle CHUNK token budget: decode rows
-    are never charged, so a prompt burst cannot monopolize a cycle.
+    ``prefill_budget`` is the per-launch CHUNK token budget: decode rows
+    are never charged, so a prompt burst cannot monopolize a launch.
     """
 
     def __init__(self, pool, do_prefill: Callable,
@@ -342,9 +371,16 @@ class Scheduler:
         self.recorder = recorder if recorder is not None \
             else FlightRecorder()
         self._cycle = 0
-        self._rec: Optional[dict] = None   # current cycle's record
+        self._rec: Optional[dict] = None   # the record being stamped
+        # the pipeline: the launch dispatched but not yet fetched (its
+        # record, rows and un-fetched result — see _dispatch), and the
+        # records of the launches that landed in the current turn
+        self._inflight: Optional[dict] = None
+        self._landed: List[dict] = []
+        self._landed_at = 0.0
         self._do_copy = do_copy          # device block copy (COW append)
         self.preempts = 0                # requests evicted mid-flight
+        self.late_rows = 0               # rows launched for ended requests
         self._max_queue = int(max_queue)
         # prompt tokens fed per cycle, split over the feeding slots
         # (_chunk_plan)
@@ -447,24 +483,28 @@ class Scheduler:
     def _loop(self) -> None:
         _prof.set_thread_name("serving scheduler")
         while True:
-            # the stretch between two cycles: idle until there is work,
+            # the stretch between two turns: idle until there is work,
             # or the queue's lock held by a submitter
             with _prof.record("serving/wait", "serving",
                               args={"cycle": self._cycle + 1}):
                 with self._cond:
                     while not self._closing and not self._queue \
-                            and not self._slots:
+                            and not self._slots and self._inflight is None:
                         self._cond.wait()
                     if self._closing and not self._queue \
-                            and not self._slots:
+                            and not self._slots and self._inflight is None:
                         return
             self._cycle += 1
             t0 = time.perf_counter()
-            # the cycle record is ALWAYS captured (bounded ring, host
-            # dicts only). The spans below tile the cycle — each takes
-            # its own bookkeeping in, so the scheduler thread is
-            # between two of them for a few bytecodes only — and each
-            # carries the cycle's number: as TraceAnnotations they land
+            # the record is ALWAYS captured (bounded ring, host dicts
+            # only) and describes a LAUNCH: the one this turn dispatches
+            # (sweep, admit, plan and dispatch are stamped now; fetch and
+            # emit when the launch lands, a turn later — the record
+            # enters the ring then). A turn that dispatches nothing
+            # records itself at once. The spans below tile the turn —
+            # each takes its own bookkeeping in, so the scheduler thread
+            # is between two of them for a few bytecodes only — and each
+            # carries its launch's number: as TraceAnnotations they land
             # in any running jax trace, on the device ops' clock, and in
             # the profiler buffer when a profile() session is armed
             rec = self._rec = {
@@ -475,9 +515,13 @@ class Scheduler:
                 "admitted": [], "retired": [], "emitted": 0,
                 "preempts": 0, "active": 0, "occupancy": 0.0,
                 "promo_waits": 0, "promoted_blocks": 0,
+                "overlapped": False, "late_rows": 0,
             }
             failed = None
-            cyc = {"cycle": self._cycle}     # every span carries it
+            # a turn that begins with nothing in the pool opens a busy
+            # stretch (see _chunked_cycle: its launch is not left in flight)
+            cold = not self._slots and self._inflight is None
+            cyc = {"cycle": self._cycle}     # this turn's launch
             cycle_span = _prof.record("serving/cycle", "serving",
                                       args=cyc).begin()
             try:
@@ -487,8 +531,8 @@ class Scheduler:
                     rec["sweep_ms"] = (t - t0) * 1e3
                 with _prof.record("serving/admit", "serving", args=cyc):
                     if self._pool.host_tier is not None:
-                        # demotion pump: blocks freed by LAST cycle's
-                        # retirements spill before THIS cycle's
+                        # demotion pump: blocks freed by LAST turn's
+                        # retirements spill before THIS turn's
                         # admissions can evict them (dispatch-only)
                         self._pool.tier_tick()
                         # promotion prefetch: start/land H2D copies
@@ -498,15 +542,15 @@ class Scheduler:
                     t = time.perf_counter()     # admit_ms: _admit alone
                     self._admit()
                     rec["admit_ms"] = (time.perf_counter() - t) * 1e3
-                if self._slots:
-                    self._chunked_cycle()
+                if self._slots or self._inflight is not None:
+                    self._chunked_cycle(cold)
                 elif rec["promo_waits"]:
                     # nothing decoding and the only queued work is
                     # waiting on in-flight promotions: nap on the
                     # tier's progress beacon (host Event, ~2ms)
                     # instead of hot-spinning the admit loop. With
                     # decode slots active this branch never runs —
-                    # decode cycles never block on a promotion.
+                    # decode turns never block on a promotion.
                     self._pool.host_tier.wait_progress(0.002)
             except Exception as e:                      # noqa: BLE001
                 # a step failure (OOM, bad artifact) poisons the affected
@@ -523,8 +567,16 @@ class Scheduler:
                         rec["failed"] = repr(failed)
                     rec["cycle_ms"] = (time.perf_counter() - t0) * 1e3
                     stat_observe("serving/cycle_ms", rec["cycle_ms"])
-                    self.recorder.record_cycle(rec)
-                    # HBM watermark per cycle — a host-only stamp
+                    # into the ring, oldest launch first: what landed
+                    # this turn, then this turn's own record unless it
+                    # rides a launch still in flight
+                    done, self._landed = self._landed, []
+                    if self._inflight is None \
+                            or self._inflight["rec"] is not rec:
+                        done.append(rec)
+                    for r in done:
+                        self.recorder.record_cycle(r)
+                    # HBM watermark per turn — a host-only stamp
                     # (profiler/memory.py mark: ledger total, NO device
                     # poll — polling belongs to the sampler thread; the
                     # memory-stats-hot-path self-lint rule enforces it)
@@ -535,7 +587,7 @@ class Scheduler:
                 if failed is not None:
                     # leave the postmortem behind: the profiler is
                     # almost never armed when a production step dies,
-                    # but the recorder's rings (this poisoned cycle
+                    # but the recorder's rings (this poisoned turn
                     # included) hold what led here
                     self.recorder.auto_dump(reason=repr(failed))
                     if _memory.is_resource_exhausted(failed):
@@ -634,9 +686,18 @@ class Scheduler:
                 self._rec.get("spec_draft_dispatches", 0) + int(n)
 
     def _fail_inflight(self, error: BaseException) -> None:
+        # let go of the launch in flight: its result is never fetched
+        # (every request it carried fails below), its record still
+        # enters the ring, marked
+        launch, self._inflight = self._inflight, None
+        if launch is not None:
+            launch["rec"]["failed"] = repr(error)
+            if launch["rec"] is not self._rec:  # the turn records its own
+                self._landed.append(launch["rec"])
         for slot in list(self._slots):
             req = self._slots.pop(slot)
             self._pool.free(slot)
+            req.in_flight = 0
             req._finish(RuntimeError(
                 f"serving step failed for request {req.id}: {error!r}"))
         # the steps DONATE the pool buffer, so a step that failed at XLA
@@ -985,14 +1046,17 @@ class Scheduler:
             self._cond.notify_all()
         return True
 
-    # -- the cycle: plan, launch, emit -------------------------------------
+    # -- the turn: plan, dispatch, land ------------------------------------
     def _chunk_plan(self) -> Dict[int, int]:
-        """Per-cycle row plan: how many query rows each active slot
+        """Per-launch row plan: how many query rows each active slot
         contributes to the fused ragged launch. Decode slots (feed
         drained) always get their 1 row — decode is NEVER budget-
-        charged, which is the anti-starvation guarantee. Feeding slots
-        split the prefill TOKEN budget FCFS by request age; a slot
-        whose share hits 0 simply waits a cycle (its blocks are already
+        charged, which is the anti-starvation guarantee — unless the
+        token that completes ``max_new_tokens`` is already in flight:
+        that request waits for its emit with no row (the count does not
+        depend on the token's value, so nothing is wasted). Feeding
+        slots split the prefill TOKEN budget FCFS by request age; a slot
+        whose share hits 0 simply waits a launch (its blocks are already
         reserved)."""
         budget = self._prefill_budget
         plan: Dict[int, int] = {}
@@ -1004,17 +1068,22 @@ class Scheduler:
                 budget -= n
                 if n > 0:
                     plan[slot] = n
-            else:
+            elif req.emitted + req.in_flight < req.max_new_tokens:
                 plan[slot] = 1
         return plan
 
     def _prepare_chunked(self, plan: Dict[int, int]) -> Dict[int, int]:
         """Before the launch: every planned slot must own writable
-        blocks for its WHOLE row range this cycle (a chunk writes
-        ``[pos, pos + n)``) — grow tables, resolve copy-on-write
-        appends, and answer exhaustion by preempting the youngest
-        request (oldest-first order makes the youngest the victim,
-        never the beneficiary); evicted slots drop out of the plan."""
+        blocks for its WHOLE row range (a chunk writes ``[pos, pos +
+        n)``) — grow tables, resolve copy-on-write appends, and answer
+        exhaustion by preempting the youngest request (oldest-first
+        order makes the youngest the victim, never the beneficiary);
+        evicted slots drop out of the plan. Pool pressure DRAINS the
+        pipeline first: a copy-on-write or an exhausted pool lands the
+        launch in flight before anything else, so a victim's history is
+        whole when it is preempted (its newest token emitted, not on the
+        device) and the retirements of that landing may make the
+        preemption unnecessary."""
         for slot in sorted(plan, key=lambda s: self._slots[s].id
                            if s in self._slots else -1):
             while slot in self._slots and slot in plan:
@@ -1022,6 +1091,7 @@ class Scheduler:
                     cows = self._pool.ensure_writable_range(
                         slot, self._pool.slot_pos(slot) + plan[slot] - 1)
                 except PoolExhaustedError as e:
+                    drained = self._drain()
                     # COW table swaps before the failure are already in
                     # place — their device copies must happen NOW (the
                     # retry sees a refcount-1 block and would never
@@ -1029,11 +1099,14 @@ class Scheduler:
                     if self._do_copy is not None:
                         for cow in getattr(e, "partial_cows", ()):
                             self._do_copy(*cow)
-                    self._preempt_youngest()
+                    if not drained:
+                        self._preempt_youngest()
                     continue
-                if self._do_copy is not None:
-                    for cow in cows:
-                        self._do_copy(*cow)
+                if cows:
+                    self._drain()
+                    if self._do_copy is not None:
+                        for cow in cows:
+                            self._do_copy(*cow)
                 break
         return {s: n for s, n in plan.items() if s in self._slots}
 
@@ -1054,74 +1127,162 @@ class Scheduler:
             plan[slot] = spec[slot] = max(1, k)
         return spec
 
-    def _chunked_cycle(self) -> None:
-        """One fused ragged launch: budgeted prompt chunks mixed with
-        every decode row. The launch's next-token array is real for
-        decode slots AND for slots whose final feed chunk landed this
-        cycle (their first generated token comes out of the same
-        launch); mid-feed slots' rows are ignored. In SPECULATIVE mode
-        decode slots contribute their draft-candidate rows instead and
-        the launch returns ``[accepted | corrected | draft echo |
-        sentinel]`` — accepted candidates emit host-side, the slot's
-        pool position rolls back over the rejected rows (signed
-        ``advance``), and any cache registration the dead rows touched
-        is dropped."""
-        cyc = {"cycle": self._cycle}
+    def _chunked_cycle(self, cold: bool = False) -> None:
+        """One turn of the pipeline: plan launch N+1, dispatch it, THEN
+        fetch and emit launch N — so N+1 is queued on the device behind
+        N before the host blocks on N's tokens, and the emit of N (the
+        handler threads it wakes, the result's free) runs while N+1
+        computes. A launch is one fused ragged program: budgeted prompt
+        chunks mixed with every decode row; its next-token array is real
+        for decode slots AND for slots whose final feed chunk rode it
+        (their first generated token comes out of the same launch);
+        mid-feed slots' rows are ignored.
+
+        The depth adapts to what the turn sees, and the serial cycle is
+        this loop with the pipeline drained every turn: SPECULATIVE mode
+        lands each verify launch in the turn that dispatched it (the
+        accepted count decides the next positions — its launch returns
+        ``[accepted | corrected | draft echo | sentinel]``, accepted
+        candidates emit host-side, the slot's pool position rolls back
+        over the rejected rows, and any cache registration the dead rows
+        touched is dropped); a plan that must preempt or copy-on-write
+        lands the launch in flight first (``_prepare_chunked``); a plan
+        with no rows (every live request waits for its last token, or
+        the batch has just ended) only lands; and the launch of a
+        ``cold`` turn — the pool was empty when the turn began, so this
+        is the first arrival after idleness and as a rule the head of a
+        burst — lands in its own turn: while the host blocks on it the
+        rest of the burst arrives and the next launch carries it whole,
+        where a launch sent out at once would carry the one or two
+        requests that happened to be through the door (and, on a chip,
+        as likely as not an odd-sized program nobody has compiled). The
+        pipeline fills from the launch after."""
         rec = self._rec
-        with _prof.record("serving/plan", "serving", args=cyc):
+        with _prof.record("serving/plan", "serving",
+                          args={"cycle": self._cycle}):
             t0 = time.perf_counter()
             plan = self._chunk_plan()
             spec = self._spec_plan(plan) if self._spec else {}
             plan = self._prepare_chunked(plan)
             spec = {s: n for s, n in spec.items() if s in plan}
             if plan:
-                active = {s: self._slots[s] for s in plan}
                 occupancy = len(self._slots) / self._pool.num_slots
                 stat_observe("serving/active_slots", len(self._slots))
                 stat_observe("serving/batch_occupancy", occupancy)
-                if rec is not None:
-                    rec["active"] = len(self._slots)
-                    rec["occupancy"] = occupancy
-                launch = {"cycle": self._cycle, "active": len(active),
-                          "spec_slots": len(spec),
-                          "chunk_rows": sum(n for s, n in plan.items()
-                                            if active[s].pending_feed)}
-            t1 = time.perf_counter()
-            if rec is not None:
-                rec["plan_ms"] += (t1 - t0) * 1e3
+                rec["active"] = len(self._slots)
+                rec["occupancy"] = occupancy
+            rec["plan_ms"] += (time.perf_counter() - t0) * 1e3
         if not plan:
+            self._drain()
             return
+        prev = self._inflight
+        self._inflight = self._dispatch(plan, spec, prev)
+        if prev is not None:
+            self._land(prev)
+        if self._spec or cold:
+            self._drain()
+
+    def _dispatch(self, plan, spec, prev) -> dict:
+        """Dispatch this turn's launch behind ``prev`` (the launch in
+        flight, or None) and apply what the host already knows of its
+        outcome: positions advance, ``pending_feed`` drains and each
+        request that gets a token out of it counts one ``in_flight`` —
+        the next plan then needs nothing the fetch would tell it. A
+        decode row whose request's newest token is still in ``prev``'s
+        un-fetched result is named to the step, which reads the token
+        there, on the device. Returns the launch: its record, rows and
+        un-fetched result, to be handed to ``_land``."""
+        rec = self._rec
+        active = {s: self._slots[s] for s in plan}
+        from_prev = {s for s, r in active.items() if r.in_flight}
+        rec["overlapped"] = prev is not None
+        if prev is not None:
+            stat_add("serving/launch_overlapped")
         # dispatch and the windowed host fetch are timed APART: a slow
-        # cycle with fat fetch_ms is a host-sync problem, one with fat
+        # launch with fat fetch_ms is a host-sync problem, one with fat
         # dispatch_ms is tracing/compile churn — the flight recorder
         # must distinguish them postmortem
-        with _prof.record("serving/decode_dispatch", "serving",
-                          args=launch):
+        with _prof.record("serving/decode_dispatch", "serving", args={
+                "cycle": self._cycle, "active": len(active),
+                "spec_slots": len(spec),
+                "chunk_rows": sum(n for s, n in plan.items()
+                                  if active[s].pending_feed)}):
+            t1 = time.perf_counter()
             if spec:
                 toks_dev = self._do_spec(active, plan, spec)
             else:
-                toks_dev = self._do_chunked(active, plan)
-            t2 = time.perf_counter()
-        with _prof.record("serving/host_fetch", "serving", args=cyc):
-            toks = _fetch(toks_dev)
-            t3 = time.perf_counter()
-            if rec is not None:
-                rec["decode_dispatch_ms"] += (t2 - t1) * 1e3
-                rec["fetch_ms"] += (t3 - t2) * 1e3
-        with _prof.record("serving/emit", "serving", args=cyc):
-            self._emit_chunked(active, plan, spec, toks, t3 - t1)
-            # freed inside the span: freeing a device array lets go of
-            # the GIL, and the stream consumers the loop has just woken
-            # hold it for milliseconds — host time of this cycle that
-            # would otherwise lie in no span
-            del toks_dev
-            if rec is not None:
-                rec["emit_ms"] += (time.perf_counter() - t3) * 1e3
+                toks_dev = self._do_chunked(
+                    active, plan,
+                    (prev["toks"], from_prev) if from_prev else None)
+            fed: Dict[int, int] = {}    # slot -> feed left after its chunk
+            for slot, req in active.items():
+                self._pool.advance(slot, plan[slot])
+                if req.pending_feed:
+                    del req.pending_feed[:plan[slot]]
+                    fed[slot] = len(req.pending_feed)
+                if not req.pending_feed:
+                    req.in_flight += 1
+            rec["decode_dispatch_ms"] += (time.perf_counter() - t1) * 1e3
+        return {"cycle": self._cycle, "rec": rec, "active": active,
+                "plan": plan, "spec": spec, "fed": fed, "toks": toks_dev,
+                "t": t1}
 
-    def _emit_chunked(self, active, plan, spec, toks, dt: float) -> None:
-        """The host half of a fused cycle once the launch's tokens are
-        fetched: account chunks and verify outcomes, emit, retire."""
-        rec = self._rec
+    def _drain(self) -> bool:
+        """Land the launch in flight, if any: the pipeline is empty
+        afterwards. Returns whether there was one."""
+        launch, self._inflight = self._inflight, None
+        if launch is not None:
+            self._land(launch)
+        return launch is not None
+
+    def _land(self, launch: dict) -> None:
+        """Fetch ``launch``'s tokens — the loop's one device→host sync —
+        and do the host half of it: emit, retire. Spans and stamps carry
+        the LAUNCH's number and go into its record, whichever turn this
+        is; the record enters the ring at this turn's end."""
+        rec = launch["rec"]
+        cyc = {"cycle": launch["cycle"]}
+        turn_rec, self._rec = self._rec, rec    # _retire stamps the launch
+        if rec is not turn_rec:                 # the turn records its own
+            self._landed.append(rec)
+        try:
+            with _prof.record("serving/host_fetch", "serving", args=cyc):
+                t2 = time.perf_counter()
+                toks = _fetch(launch["toks"])
+                t3 = time.perf_counter()
+                rec["fetch_ms"] += (t3 - t2) * 1e3
+            with _prof.record("serving/emit", "serving", args=cyc):
+                # tokens over the launch's own stretch of the device:
+                # from its dispatch, or the landing before it if later
+                dt = t3 - max(launch["t"], self._landed_at)
+                self._landed_at = t3
+                self._emit_chunked(launch, toks, dt)
+                # freed inside the span: freeing a device array lets go
+                # of the GIL, and the stream consumers the loop has just
+                # woken hold it for milliseconds — host time of this
+                # launch that would otherwise lie in no span
+                launch["toks"] = None
+                rec["emit_ms"] += (time.perf_counter() - t3) * 1e3
+        except Exception as e:                          # noqa: BLE001
+            rec["failed"] = repr(e)
+            raise
+        finally:
+            self._rec = turn_rec
+
+    def _emit_chunked(self, launch: dict, toks, dt: float) -> None:
+        """The host half of a launch once its tokens are fetched:
+        account chunks and verify outcomes, emit, retire. What the host
+        learns here it learns one launch late — EOS, ``cancel()``, a
+        deadline: a request that ends now may already hold a row in the
+        launch dispatched since. That row is a LATE row: when its launch
+        lands the request is found done, the row's token is dropped and
+        counted, and nothing of the slot is touched — it was freed here
+        (after the later launch's dispatch, so a new owner's writes are
+        ordered behind the dead row's on the device) and may have a new
+        owner."""
+        rec = launch["rec"]
+        active, plan, spec = launch["active"], launch["plan"], launch["spec"]
+        fed = launch["fed"]
         S = self._pool.num_slots
         K = self._spec_k
         if spec:
@@ -1138,27 +1299,30 @@ class Scheduler:
         emitted = 0
         chunks = 0
         chunk_tokens = 0
+        late_rows = 0
         spec_accepted = 0
         spec_proposed = 0
         spec_emitted = 0
         now = time.perf_counter()
         for slot, req in active.items():
             n = plan[slot]
-            feeding = bool(req.pending_feed)
-            self._pool.advance(slot, n)
+            feeding = slot in fed
             if feeding:
                 # the feed tokens' K/V are in the pool now: account the
                 # chunk BEFORE the terminal checks so a cancel mid-feed
                 # still leaves honest chunk telemetry behind
-                del req.pending_feed[:n]
                 chunks += 1
                 chunk_tokens += n
                 self.prefill_chunks += 1
                 self.chunk_tokens += n
                 stat_add("serving/prefill_chunks")
                 stat_add("serving/chunk_tokens", n)
+            if req.done():
+                late_rows += n          # ended at the landing before
+                continue
+            if feeding:
                 req.trace.mark("prefill_chunk", tokens=n,
-                               remaining=len(req.pending_feed))
+                               remaining=fed[slot])
             elif slot in spec:
                 # verify outcome: the longest agreeing candidate prefix
                 # is kept plus (on a rejection) one corrected token;
@@ -1179,6 +1343,8 @@ class Scheduler:
                 stat_add("serving/spec_proposed", n)
                 stat_add("serving/spec_accept", a)
                 req.trace.mark("spec_verify", proposed=n, accepted=a)
+            if not fed.get(slot):
+                req.in_flight -= 1      # this launch's token lands now
             if req.cancelled:
                 stat_add("serving/cancelled")
                 self._retire(slot, RequestCancelled(
@@ -1193,7 +1359,7 @@ class Scheduler:
                     est_wait_s=self._est_wait_s(len(self._queue))))
                 continue
             if feeding:
-                if req.pending_feed:
+                if fed[slot]:
                     continue             # mid-feed: row output ignored
                 # final chunk landed: publish the fully-written feed
                 # blocks to the prefix cache, then emit the first
@@ -1228,15 +1394,17 @@ class Scheduler:
             self.spec_cycles += 1
             stat_add("serving/spec_cycles")
         stat_add("serving/tokens", emitted)
-        if rec is not None:
-            rec["emitted"] += emitted
-            rec["prefill_chunks"] = rec.get("prefill_chunks", 0) + chunks
-            rec["chunk_tokens"] = rec.get("chunk_tokens", 0) \
-                + chunk_tokens
-            if spec:
-                rec["spec_proposed"] = spec_proposed
-                rec["spec_accepted"] = spec_accepted
-                rec["spec_emitted"] = spec_emitted
-                rec["spec_slots"] = len(spec)
+        if late_rows:
+            self.late_rows += late_rows
+            stat_add("serving/late_rows", late_rows)
+        rec["emitted"] += emitted
+        rec["late_rows"] += late_rows
+        rec["prefill_chunks"] = rec.get("prefill_chunks", 0) + chunks
+        rec["chunk_tokens"] = rec.get("chunk_tokens", 0) + chunk_tokens
+        if spec:
+            rec["spec_proposed"] = spec_proposed
+            rec["spec_accepted"] = spec_accepted
+            rec["spec_emitted"] = spec_emitted
+            rec["spec_slots"] = len(spec)
         if dt > 0 and emitted:
             stat_observe("serving/tokens_per_sec", emitted / dt)

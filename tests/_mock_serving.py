@@ -21,6 +21,23 @@ def mock_pool(slots=2, max_len=64, block_size=8, **kw):
                        **kw)
 
 
+class _Pending:
+    """An un-fetched launch result: ``values`` are there at once (the
+    next launch may read them, as on the device), the HOST gets them at
+    ``ready_at`` — ``np.asarray`` of it, which is what the scheduler's
+    one fetch does, waits until then."""
+
+    def __init__(self, values, ready_at):
+        self.values, self.ready_at = values, ready_at
+
+    def __getitem__(self, i):
+        return self.values[i]
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(max(0.0, self.ready_at - time.perf_counter()))
+        return self.values
+
+
 class MockDevice:
     """Deterministic stand-in for the engine's device side.
 
@@ -29,18 +46,48 @@ class MockDevice:
     pressure must be answered by preemption); ``do_step`` answers every
     slot with ``token`` (then ``tail``, e.g. the logits-finite sentinel).
     ``prefill_gate`` holds the scheduler inside an admission while clear.
+    ``decode_delay`` is device time, not host time: a launch returns at
+    once and its result is ready that long after the launch before it
+    (or after its own dispatch, if later), so two launches in flight
+    behave as they do on a device's queue.
+
+    With ``chain=True`` a slot's answer depends on the row's input token
+    as a model's does: ``next_token(t)`` of the last token the slot fed
+    this launch — a chunk's last token, a decode row's ``last_token``,
+    or, for the slots the scheduler names in ``prev``, that slot's entry
+    of the previous launch's result, which the mock kept as the engine's
+    device does. A request's whole output then follows from its prompt
+    (``expected``), whichever launches carried it.
     """
 
+    VOCAB = 97
+
     def __init__(self, pool, prefill_delay=0.0, decode_delay=0.0, token=2,
-                 tail=()):
+                 tail=(), chain=False):
         self.pool = pool
         self.prefill_delay = prefill_delay
         self.decode_delay = decode_delay
         self.token = token
         self.tail = tuple(tail)
+        self.chain = chain
         self.prefill_gate = threading.Event()
         self.prefill_gate.set()
         self.launches = []              # the plan of each launch
+        self.from_prev = []             # the slots each launch read from prev
+        self._busy_until = 0.0          # when the device's queue runs dry
+
+    @classmethod
+    def next_token(cls, tok):
+        return (int(tok) * 31 + 7) % cls.VOCAB
+
+    @classmethod
+    def expected(cls, prompt, n):
+        """The ``n`` tokens a chained mock generates after ``prompt``."""
+        out, tok = [], prompt[-1]
+        for _ in range(n):
+            tok = cls.next_token(tok)
+            out.append(tok)
+        return out
 
     def do_prefill(self, req, slot):
         self.prefill_gate.wait()
@@ -52,12 +99,26 @@ class MockDevice:
         self.pool.set_slot(slot, pos=0, lo=0)
         req.pending_feed = [int(t) for t in feed]
 
-    def do_step(self, slot_requests, plan):
-        if self.decode_delay:
-            time.sleep(self.decode_delay)
+    def do_step(self, slot_requests, plan, prev=None):
         self.launches.append(dict(plan))
-        return np.asarray(
-            [self.token] * self.pool.num_slots + list(self.tail), np.int32)
+        prev_toks, from_prev = prev if prev is not None else (None, ())
+        self.from_prev.append(sorted(from_prev))
+        toks = [self.token] * self.pool.num_slots
+        if self.chain:
+            for slot, req in slot_requests.items():
+                if slot in from_prev:
+                    fed = prev_toks[slot]
+                elif req.pending_feed:
+                    fed = req.pending_feed[plan[slot] - 1]
+                else:
+                    fed = req.last_token
+                toks[slot] = self.next_token(fed)
+        toks = np.asarray(toks + list(self.tail), np.int32)
+        if not self.decode_delay:
+            return toks
+        self._busy_until = max(self._busy_until, time.perf_counter()) \
+            + self.decode_delay
+        return _Pending(toks, self._busy_until)
 
     def scheduler(self, **kw):
         return Scheduler(self.pool, self.do_prefill, self.do_step, **kw)

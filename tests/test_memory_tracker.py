@@ -140,7 +140,7 @@ class TestSchedulerOomIntegration:
         monkeypatch.setattr(memory.tracker(), "oom_postmortem", capture)
         pool = mock_pool(slots=2, max_len=32)
 
-        def step(slot_requests, plan):
+        def step(slot_requests, plan, prev=None):
             raise RuntimeError(
                 "RESOURCE_EXHAUSTED: Out of memory allocating KV block")
 

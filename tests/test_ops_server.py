@@ -269,6 +269,24 @@ class TestEndpoints:
             assert json.loads(_get(srv.url + "/tracez")[1]) == \
                 {"engines": {}}
 
+    def test_a_burst_of_clients_connects_without_a_dropped_syn(self):
+        """The stdlib's listen backlog of 5 drops the SYNs of a burst
+        (each comes back whole seconds later): 64 clients connect
+        before the accept loop gets one turn, and none waits."""
+        import socket
+        import time
+        with OpsServer() as srv:
+            assert srv._httpd.request_queue_size >= 256
+            host, port = srv._httpd.server_address[:2]
+            srv._httpd.shutdown()           # nobody accepts: the queue holds
+            t0 = time.perf_counter()
+            socks = [socket.create_connection((host, port), timeout=10)
+                     for _ in range(64)]
+            took = time.perf_counter() - t0
+            for s in socks:
+                s.close()
+        assert took < 0.9, f"{took:.2f} s: a SYN was retransmitted"
+
     def test_timeline_serves_trace_doc(self):
         with OpsServer() as srv:
             code, body = _get(srv.url + "/timeline")

@@ -174,7 +174,7 @@ class TestFlightRecorder:
         dev = MockDevice(pool)
         boom = {"armed": False}
 
-        def bad_step(slot_requests, plan):
+        def bad_step(slot_requests, plan, prev=None):
             boom["armed"] = True
             raise RuntimeError("injected device failure")
 
@@ -260,3 +260,128 @@ class TestChromeTraceExport:
         assert {"serving/cycle", "serving/sweep", "serving/admit",
                 "serving/decode_dispatch",
                 "serving/host_fetch"} <= cats
+
+
+class TestARecordIsALaunch:
+    """Two launches in flight: launch n's plan and dispatch lie in one
+    turn of the loop, its fetch and emit in the next. Record n and every
+    span with ``cycle=n`` still describe launch n alone."""
+
+    def _run(self, **sched_kw):
+        pool = mock_pool(slots=3, max_len=64)
+        dev = MockDevice(pool, decode_delay=0.002, chain=True)
+        sides = []      # per launch: what its plan says each side will see
+
+        def step(slot_requests, plan, prev=None):
+            feeding = {s for s, r in slot_requests.items() if r.pending_feed}
+            sides.append({
+                "rows": sum(plan.values()),
+                "chunk_tokens": sum(plan[s] for s in feeding),
+                "emitted": sum(
+                    1 for s, r in slot_requests.items() if s not in feeding
+                    or len(r.pending_feed) == plan[s])})
+            # the dispatch-side keys, as the engine's operand builder
+            # stamps them
+            sched.note_launch(rows=sides[-1]["rows"], q=8 * len(plan), t=1,
+                              kv_tokens=0, kv_steps=0, kv_fetches=0)
+            return dev.do_step(slot_requests, plan, prev)
+
+        with profiler.profile() as sess:
+            sched = Scheduler(pool, dev.do_prefill, step, prefill_budget=6,
+                              **sched_kw)
+            hs = [_submit(sched, prompt_len=n, max_new=m)
+                  for n, m in ((10, 4), (3, 6), (5, 3))]
+            for h in hs:
+                h.result(timeout=60)
+            sched.close()
+        spans = {}
+        for e in sess.events():
+            if e["name"].startswith("serving/") \
+                    and "cycle" in (e["args"] or {}):
+                spans.setdefault(e["name"], {}).setdefault(
+                    e["args"]["cycle"], []).append(
+                        (e["ts"], e["ts"] + e["dur"]))
+        launches = [c for c in sched.recorder.snapshot()["cycles"]
+                    if c["decode_dispatch_ms"] > 0]
+        return sched, sides, launches, spans
+
+    def test_both_sides_of_a_record_belong_to_one_launch(self):
+        sched, sides, launches, _ = self._run()
+        assert len(launches) == len(sides) >= 6
+        assert [c["cycle"] for c in launches] == \
+            sorted(c["cycle"] for c in launches)
+        for c, side in zip(launches, sides):
+            # written at dispatch ... and at emit, a turn later
+            assert c["launch_rows"] == side["rows"], (c, side)
+            assert c.get("chunk_tokens", 0) == side["chunk_tokens"], (c, side)
+            assert c["emitted"] == side["emitted"], (c, side)
+            assert c["fetch_ms"] > 0 and c["late_rows"] == 0
+        # the mix differs from launch to launch, so a record that joined
+        # its neighbour's emit side would have been caught
+        assert len({(s["rows"], s["chunk_tokens"], s["emitted"])
+                    for s in sides}) >= 3
+
+    def test_spans_carry_their_launchs_number(self):
+        _, _, launches, spans = self._run()
+        for name in ("serving/plan", "serving/decode_dispatch",
+                     "serving/host_fetch", "serving/emit"):
+            for c in launches:
+                assert len(spans[name][c["cycle"]]) == 1, (name, c["cycle"])
+        turn = {n: iv[0] for n, iv in spans["serving/cycle"].items()}
+        for c in launches:
+            n = c["cycle"]
+            dispatch = spans["serving/decode_dispatch"][n][0]
+            fetch = spans["serving/host_fetch"][n][0]
+            emit = spans["serving/emit"][n][0]
+            assert dispatch[1] <= fetch[0] <= fetch[1] <= emit[0]
+            # the turn is numbered by the launch it DISPATCHES
+            assert turn[n][0] <= dispatch[0] and dispatch[1] <= turn[n][1]
+            if c["overlapped"]:
+                # ... and fetches the launch before it after that
+                before = spans["serving/host_fetch"][n - 1][0]
+                assert dispatch[1] <= before[0] and before[1] <= turn[n][1]
+        assert sum(c["overlapped"] for c in launches) >= 4
+
+    def test_overlapped_from_the_third_launch_of_a_busy_stretch_on(self):
+        """The launch that opens a busy stretch (the pool was empty)
+        lands in its own turn, so that the burst behind the first
+        arrival is in the queue when the next launch is planned; that
+        next launch finds nothing in flight; from the third on, two
+        are."""
+        monitor.stat_reset("serving/launch_overlapped")
+        sched, _, launches, _ = self._run()
+        assert [c["overlapped"] for c in launches] == \
+            [False, False] + [True] * (len(launches) - 2)
+        assert monitor.stat_get("serving/launch_overlapped") == \
+            len(launches) - 2
+        # a second stretch starts with an empty pipeline again
+        pool = mock_pool(slots=1)
+        sched = MockDevice(pool).scheduler()
+        for _ in range(2):
+            _submit(sched, max_new=3).result(timeout=60)
+            time.sleep(0.05)
+        sched.close()
+        flags = [c["overlapped"] for c in sched.recorder.snapshot()["cycles"]
+                 if c["decode_dispatch_ms"] > 0]
+        assert flags == [False, False, True] * 2
+
+    def test_speculative_mode_never_overlaps(self):
+        """The accepted count decides the next positions: a verify
+        launch is fetched in the turn that dispatched it."""
+        S, K = 3, 2
+
+        def spec_step(slot_requests, plan, spec):
+            # every draft rejected: one corrected token a slot a launch
+            out = np.zeros(2 * S + S * K + 1, np.int32)
+            out[S:2 * S] = 5
+            return out
+
+        sched, sides, launches, spans = self._run(
+            do_spec_step=spec_step, spec_k=K)
+        assert launches and not any(c["overlapped"] for c in launches)
+        assert sched.late_rows == 0
+        for c in launches:
+            n = c["cycle"]
+            turn = spans["serving/cycle"][n][0]
+            fetch = spans["serving/host_fetch"][n][0]
+            assert turn[0] <= fetch[0] and fetch[1] <= turn[1]
