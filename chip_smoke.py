@@ -691,6 +691,71 @@ def phase_axk1_serve(model: dict, *, dtype: str, max_len: int,
     return numbers
 
 
+def phase_sdar_serve(model: dict, *, dtype: str, max_len: int,
+                     block_size: int, num_slots: int, num_blocks: int,
+                     prefill_budget: int, prompt_lens, new_tokens: int,
+                     limits: dict, width: int, states: int,
+                     q_block: int) -> dict:
+    """SDAR-MoE (grouped-query heads in the ragged kernel, softmax-routed
+    experts all held, generation by diffusion over blocks; ``model`` is
+    the ``model`` group of a benchmark configuration, cut to a toy DEPTH)
+    through ``GenerationEngine``: chunked prefill, then denoising and
+    commit passes over the paged cache, then the plain reference
+    TEACHER-FORCED on the states the program saw
+    (``benchmark/lib/reference_sdar.py``): every served token's logit
+    against the reference's best at the pass that fixed it, and every
+    pass's position against the reference's most confident, held to the
+    configuration's own limits."""
+    import numpy as np
+
+    from benchmark.drivers import serve_backlog_blocks as D
+    from benchmark.lib import family_sdar as F
+    from benchmark.lib import reference_sdar as R
+    from paddle_tpu.serving import GenerationEngine
+
+    seed = 2 ** 31 + 31
+    net = F.build_lm(model, seed, dtype)
+    rng = np.random.RandomState(31)
+    prompts = [rng.randint(1, int(model["vocab_size"]), size=n).tolist()
+               for n in prompt_lens]
+    before = site_names()
+    with GenerationEngine(net, block_size=block_size, max_len=max_len,
+                          num_slots=num_slots, num_blocks=num_blocks,
+                          prefill_budget=prefill_budget) as engine:
+        handles = [engine.submit(p, new_tokens) for p in prompts]
+        served = [[int(t) for t in h.stream()] for h in handles]
+        stats = engine.stats()
+        cycles = engine.flight_recorder.snapshot()["cycles"]
+        text = step_text_report(sites_since(before, "serving/fused["),
+                                ("ragged_paged_attention", "kv_append"))
+    log(f"sdar steps: {text}")
+    del net, engine
+    gc.collect()
+    check(stats["nonfinite_cycles"] == 0, "no non-finite cycle")
+    check(any(c.get("denoise_slots") for c in cycles)
+          and any(c.get("commit_slots") for c in cycles),
+          "denoising and commit passes reached the cycle record")
+    check(any("moe_pairs" in c for c in cycles),
+          "the routed layers' counters reached the cycle record")
+    requests = []
+    for b, (p, o, h) in enumerate(zip(prompts, served, handles)):
+        check(len(o) == new_tokens and len(h.trace.token_passes) == len(o),
+              f"request {b} is whole ({len(o)} of {new_tokens} tokens, "
+              f"each with the pass that fixed it)")
+        check(int(model["mask_token_id"]) not in o,
+              f"request {b} never chose the mask id")
+        requests.append((p, o, list(h.trace.token_passes)))
+    out = R.served_margins(F.Weights(seed, model, dtype), model, requests,
+                           width=width, states=states, q_block=q_block)
+    numbers = D.summary(out["gap"] / out["std"], out["order_gap"])
+    log(f"sdar served tokens against the reference: {numbers}, limits "
+        f"{limits}")
+    for name, limit in limits.items():
+        check(numbers[name] <= limit,
+              f"sdar {name} {numbers[name]:.5f} within {limit}")
+    return numbers
+
+
 def _pool_array(cfg, block_size: int, num_blocks: int, sharded: bool):
     """The engine's block pool, found among jax's live arrays by its
     shape ``[L, NB + 1, H, block_size, 2 * Dh]`` (the engine does not
@@ -965,6 +1030,17 @@ def main(argv=None) -> int:
                 prompt_lens=(40, 700, 1300), new_tokens=24,
                 limits=axk1["serving"]["check"]["limits"], width=1536,
                 q_block=512)
+            with open(os.path.join(here, "benchmark", "configs",
+                                   "sdar-30b-a3b-pp8.json")) as f:
+                sdar = json.load(f)
+            phase_sdar_serve(
+                dict(sdar["model"], num_hidden_layers=2),
+                dtype=sdar["serving"]["dtype"], max_len=2048,
+                block_size=int(sdar["serving"]["block_size"]), num_slots=8,
+                num_blocks=1024, prefill_budget=512,
+                prompt_lens=(41, 702, 1303), new_tokens=30,
+                limits=sdar["serving"]["check"]["limits"], width=1536,
+                states=64, q_block=512)
     n, secs = compile_totals()
     hits, misses = CACHE_EVENTS.values()
     log(f"all phases passed: {n} compiles taking {secs:.1f} s in this "

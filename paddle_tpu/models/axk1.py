@@ -227,17 +227,24 @@ def _swiglu(x, gate, up, down):
 # the routed expert layer
 # ---------------------------------------------------------------------------
 
-def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True):
+def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True,
+                scoring: str = "sigmoid"):
     """Scores, choice and weights of the router: ``x [Q, E]``, ``router_w
     [n_experts, E]`` -> ``(idx [Q, k] int32, w [Q, k] float32, scores [Q,
-    n_experts] float32)``. The scores are float32: activations and router
-    weights are exact in bfloat16, every product of two of them is exact
-    in float32, and the MXU accumulates in float32, so this IS the
-    float32 score up to the order of the sum."""
+    n_experts] float32)``. ``scoring`` is ``"sigmoid"`` (A.X-K1) or
+    ``"softmax"`` over all experts (SDAR). The scores are float32:
+    activations and router weights are exact in bfloat16, every product
+    of two of them is exact in float32, and the MXU accumulates in
+    float32, so this IS the float32 score up to the order of the sum."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(x, router_w.T, preferred_element_type=jnp.float32)
-    scores = jax.nn.sigmoid(logits)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"router scoring {scoring!r}: sigmoid or softmax")
     top, idx = jax.lax.top_k(scores, int(top_k))
     w = top / jnp.sum(top, axis=-1, keepdims=True) if norm else top
     return idx.astype(jnp.int32), w * jnp.float32(scale), scores
@@ -314,6 +321,35 @@ def _params(make, prefix):
     for ``prefix + name`` (a layer calls it while it is built and keeps
     nothing of it)."""
     return lambda name, shape: Parameter(make(prefix + name, tuple(shape)))
+
+
+def _param_maker(dtype, param_init: Optional[Callable],
+                 initializer_range: float):
+    """``make(name, shape)`` for a model built ONE parameter at a time in
+    its serving dtype: ``param_init(name, shape, dtype)`` if given (its
+    result checked), else norm gains of 1 and ``N(0, initializer_range^2)``
+    elsewhere."""
+    import jax
+    import jax.numpy as jnp
+    dt = jnp.dtype(dtype)
+    if param_init is None:
+        keys = iter(jax.random.split(jax.random.PRNGKey(0), 4096))
+
+        def param_init(name, shape, dtype):
+            if name.endswith("norm"):
+                return jnp.ones(shape, dtype)
+            return (initializer_range * jax.random.normal(
+                next(keys), shape, jnp.float32)).astype(dtype)
+
+    def make(name, shape):
+        arr = param_init(name, shape, dt)
+        if tuple(arr.shape) != tuple(shape) or arr.dtype != dt:
+            raise ValueError(
+                f"param_init({name!r}) gave {arr.dtype}{tuple(arr.shape)}"
+                f", the model needs {dt}{tuple(shape)}")
+        return arr
+
+    return make
 
 
 class AXK1Attention(nn.Layer):
@@ -517,27 +553,8 @@ class AXK1ForCausalLM(nn.Layer):
     def __init__(self, cfg: AXK1Config, dtype="float32",
                  param_init: Optional[Callable] = None):
         super().__init__()
-        import jax
-        import jax.numpy as jnp
         self.cfg = cfg
-        dt = jnp.dtype(dtype)
-        if param_init is None:
-            keys = iter(jax.random.split(jax.random.PRNGKey(0), 4096))
-
-            def param_init(name, shape, dtype):
-                if name.endswith("norm"):
-                    return jnp.ones(shape, dtype)
-                return (cfg.initializer_range * jax.random.normal(
-                    next(keys), shape, jnp.float32)).astype(dtype)
-
-        def make(name, shape):
-            arr = param_init(name, shape, dt)
-            if tuple(arr.shape) != tuple(shape) or arr.dtype != dt:
-                raise ValueError(
-                    f"param_init({name!r}) gave {arr.dtype}{tuple(arr.shape)}"
-                    f", the model needs {dt}{tuple(shape)}")
-            return arr
-
+        make = _param_maker(dtype, param_init, cfg.initializer_range)
         self.embed = Parameter(make("embed", (cfg.vocab_size, cfg.hidden_size)))
         self.layers = nn.LayerList(
             [AXK1Layer(cfg, i, make) for i in range(cfg.num_hidden_layers)])

@@ -719,7 +719,8 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                                  _kv_lanes(*rows))
             a = ragged_paged_attention(
                 q, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
-                kv_len, scales=scales)
+                kv_len, scales=scales,
+                mask_block=dec.spec.generation.block_length)
         else:
             pool = _write_latent_rows(pool, li, write_block, write_off, rows)
             a = mla_paged_attention(
@@ -744,6 +745,139 @@ def _tokens_from_prev(token_ids, prev_tokens, token_src):
     import jax.numpy as jnp
     return jnp.where(token_src >= 0,
                      prev_tokens[jnp.maximum(token_src, 0)], token_ids)
+
+
+# the block state of generation by diffusion over blocks
+# (models/decoder_spec.py GenerationRule): beside a block's token ids, the
+# pass in which each position was fixed — BLOCK_GIVEN for a position the
+# prompt filled, BLOCK_UNFIXED while it is open. Whether a position is
+# fixed is read from HERE, never by comparing an id with the mask id (a
+# prompt may hold that id)
+BLOCK_UNFIXED, BLOCK_GIVEN = -2, -1
+
+# the named scope of the step's last stage in a block-generation program:
+# the head on the blocks' rows, argmax and confidence, the choice of the
+# positions to fix (benchmark/layer_metrics/unmask_step_ms.py)
+UNMASK_SCOPE = "unmask"
+
+
+def block_result_layout(num_slots: int, block_length: int, routed: bool):
+    """Where a block-generation step's result holds what: ``(fixed_at,
+    sentinel_at, counters_at, tokens_at, passes_at, size)`` into the one
+    int32 array a launch returns — ``[S]`` the position each slot fixed
+    last this pass (-1: none), the logits-finite sentinel, a routed
+    model's three counters (the same places as in a one-token step's
+    result, so the scheduler's readers of those are one), then the block
+    state: ``[S * B]`` token ids and ``[S * B]`` the pass each position
+    was fixed in."""
+    S, B = int(num_slots), int(block_length)
+    tokens_at = S + 1 + (3 if routed else 0)
+    return (0, S, S + 1, tokens_at, tokens_at + S * B,
+            tokens_at + 2 * S * B)
+
+
+def _unmask(dec, x, seq_qstart, blk_tok, blk_pass, pass_idx, rule):
+    """The last stage of a block-generation step, on the device: logits
+    on the B rows of every slot's block, argmax and its softmax
+    probability (the confidence) at every position, and for each slot in
+    a denoising pass (``pass_idx >= 0``) the ``fixed_per_pass`` most
+    confident UNFIXED positions take their argmax and are stamped with
+    the pass. The mask id is never chosen: its logit is out of the argmax
+    and of the softmax alike. Ties go to the lowest position. Returns
+    ``(blk_tok, blk_pass, fixed_pos [S], logits_bad)``."""
+    import jax
+    import jax.numpy as jnp
+    S, B = blk_tok.shape
+    with jax.named_scope(UNMASK_SCOPE):
+        rows = (seq_qstart[:, None]
+                + jnp.arange(B, dtype=jnp.int32)[None, :]).reshape(-1)
+        logits = dec.logits(Tensor(x._data[0, rows][:, None, :]))._data[
+            :, 0].astype(jnp.float32)                     # [S * B, V]
+        vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        logits = jnp.where(vocab == rule.mask_token_id, -jnp.inf, logits)
+        top = jnp.max(logits, axis=-1)
+        best = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(S, B)
+        denom = jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
+        conf = (1.0 / denom).reshape(S, B)        # softmax prob of argmax
+        bad = jnp.any(~jnp.isfinite(top)) | jnp.any(~jnp.isfinite(denom))
+        denoise = (pass_idx >= 0)[:, None]
+        fixed_pos = jnp.full((S,), -1, jnp.int32)
+        cols = jnp.arange(B, dtype=jnp.int32)[None, :]
+        for _ in range(rule.fixed_per_pass):
+            open_ = (blk_pass == BLOCK_UNFIXED) & denoise
+            c = jnp.where(open_, conf, -1.0)
+            j = jnp.argmax(c, axis=-1).astype(jnp.int32)          # [S]
+            take = (cols == j[:, None]) & open_
+            blk_tok = jnp.where(take, best, blk_tok)
+            blk_pass = jnp.where(take, pass_idx[:, None], blk_pass)
+            fixed_pos = jnp.where(jnp.any(take, axis=-1), j, fixed_pos)
+    return blk_tok, blk_pass, fixed_pos, bad.astype(jnp.int32)
+
+
+def _build_block_step_fn(model, dec, S, Q, T, probe):
+    """:func:`build_fused_step_fn` for a spec whose generation rule has
+    ``block_length`` B > 1. A decode slot contributes the B rows of its
+    current block, at the block's positions: the step embeds the block's
+    state (fixed ids, the mask id elsewhere), appends the rows' K/V over
+    the block's previous ones, runs the tower and :func:`_unmask`. A
+    commit pass and a prompt chunk are the same program: their slots say
+    ``pass_idx`` -1 and their state passes through.
+
+    ``fn(params, buffers, pool, token_ids, qpos, write_block, write_off,
+    blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len, prev_result,
+    state_src [S], blk_tok [S, B], blk_pass [S, B], row_blk [Q],
+    pass_idx [S], key) -> (pool, result, key)``: ``row_blk`` names, for a
+    row of a block, ``slot * B + position in the block`` (-1: a chunk
+    row, which keeps its ``token_ids``); ``state_src[s] >= 0`` takes slot
+    ``s``'s block state from ``prev_result`` — the launch before this
+    one, UN-fetched — in place of ``blk_tok``/``blk_pass``; ``result`` is
+    laid out by :func:`block_result_layout`."""
+    import jax.numpy as jnp
+
+    from ..framework import trace_probe as _probe
+    from ..nn.layer.layers import functional_state
+    from .decoder_spec import ROUTED
+
+    rule = dec.spec.generation
+    B = int(rule.block_length)
+    routed = any(ls.ffn == ROUTED for ls in dec.spec.layers)
+    _, _, _, tokens_at, passes_at, size = block_result_layout(S, B, routed)
+
+    def fn(params, buffers, pool, token_ids, qpos, write_block, write_off,
+           blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len, prev_result,
+           state_src, blk_tok, blk_pass, row_blk, pass_idx, key):
+        if probe is not None:  # runs at trace time only (jit caches)
+            probe.record(_probe.sig_of([pool, token_ids, tables]),
+                         {"q": Q, "table": T})
+        with functional_state(model, params, buffers):
+            with no_grad_guard():
+                from_prev = (state_src >= 0)[:, None]
+                blk_tok = jnp.where(
+                    from_prev,
+                    prev_result[tokens_at:passes_at].reshape(S, B), blk_tok)
+                blk_pass = jnp.where(
+                    from_prev, prev_result[passes_at:size].reshape(S, B),
+                    blk_pass)
+                shown = jnp.where(blk_pass == BLOCK_UNFIXED,
+                                  jnp.int32(rule.mask_token_id),
+                                  blk_tok).reshape(-1)
+                token_ids = jnp.where(
+                    row_blk >= 0, shown[jnp.maximum(row_blk, 0)], token_ids)
+                x = dec.embed_tokens(token_ids, qpos)
+                x, new_pool, _, counters = _fused_tower(
+                    dec, x, qpos, pool, None, write_block, write_off,
+                    blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
+                    False, 0.0)
+                blk_tok, blk_pass, fixed_pos, bad = _unmask(
+                    dec, x, seq_qstart, blk_tok, blk_pass, pass_idx, rule)
+                parts = [fixed_pos, bad[None]]
+                if counters is not None:
+                    parts.append(counters)
+                parts += [blk_tok.reshape(-1), blk_pass.reshape(-1)]
+                result = jnp.concatenate(parts).astype(jnp.int32)
+        return new_pool, result, key
+
+    return fn
 
 
 def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
@@ -792,6 +926,11 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
       with ``donate_argnums`` on ``pool`` and the engine's ``analyze()``
       must report the program donation-safe and host-sync-free.
 
+    A model whose generation rule has ``block_length`` > 1 (generation by
+    diffusion over blocks) gets the step of :func:`_build_block_step_fn`:
+    the same tower over the same operands, its last stage ``_unmask`` in
+    place of the one argmax a slot.
+
     One trace per ``(q_rows bucket, table bucket)``, watched by ``probe``.
     ``quantized=True`` threads the per-block scale array beside the
     pool (``fn(params, buffers, pool, scales, token_ids, ...) ->
@@ -818,6 +957,11 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     if T < 1:
         raise ValueError(f"table_len must be >= 1, got {T}")
     top_k = min(int(top_k), dec.spec.vocab_size)
+    if dec.spec.generation.block_length > 1:
+        if quantized:
+            raise ValueError(
+                "block generation over int8/fp8 blocks is not built")
+        return _build_block_step_fn(model, dec, S, Q, T, probe)
 
     def fn(params, buffers, pool, *rest):
         (scales, token_ids, qpos, write_block, write_off, blk_seq,
@@ -1137,6 +1281,11 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
     if T < 1:
         raise ValueError(f"table_len must be >= 1, got {T}")
     top_k = min(int(top_k), dec.spec.vocab_size)
+    if dec.spec.generation.block_length > 1:
+        if quantized:
+            raise ValueError(
+                "block generation over int8/fp8 blocks is not built")
+        return _build_block_step_fn(model, dec, S, Q, T, probe)
 
     def fn(params, buffers, pool, *rest):
         (scales, token_ids, qpos, write_block, write_off, blk_seq,

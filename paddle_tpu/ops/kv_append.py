@@ -27,7 +27,9 @@ Layout contract (the fused step's own, ``engine._ragged_operands`` and
 first, and no two sequences write one block. So a q block's rows name at
 most two blocks (``block_size >= BLOCK_Q``), all rows of a launch that
 land in one block are consecutive rows, and a block is rewritten ONCE a
-launch: a rewrite opened by one q block stays open in VMEM while the next
+launch (under block generation the SAME rows of a block are rewritten by
+every pass of it, one launch each; a diffusion block never straddles a
+cache block, so its rows are one rewrite): a rewrite opened by one q block stays open in VMEM while the next
 q block's first rows land in the same block (two q blocks of 8 rows share
 a KV block of 16; a chunk that starts mid-block), and is written back when
 the row after its last names another block. No block is read again while

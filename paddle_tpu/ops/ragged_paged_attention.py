@@ -41,7 +41,23 @@ Layout contract (the serving engine's fused step builds these):
   and ``lo`` the valid-window floor (always 0 for paged sequences);
 * a row at position ``p`` attends to cache columns ``[lo, p]`` — the
   history PLUS the causal prefix of its own chunk, whose K/V the fused
-  step scatters into the pool before the kernel runs.
+  step scatters into the pool before the kernel runs. With a static
+  ``mask_block`` B > 1 (generation by diffusion over blocks,
+  ``models/decoder_spec.py``) the row sees columns up to the END of its
+  block of B, ``p // B * B + B - 1``, bounded by ``kv_len`` as ever: the
+  block's rows were appended before the kernel runs too. A diffusion
+  block never straddles a cache block (the engine requires ``block_size
+  % B == 0``), so the rows of one are one DMA.
+
+Grouped-query heads: the pool holds ``Hkv`` KV heads and ``q`` has ``H =
+g * Hkv`` query heads, query head ``j`` reading KV head ``j // g``. A KV
+head's group of query heads is FOLDED INTO THE ROWS of the products: the
+q block of a grid step is ``[Hkv, block_q * g, Dh]`` (row ``r`` is query
+row ``r // g``, head ``r % g`` of the group: 8 x 8 = 64 MXU rows a KV
+tile at ``g`` 8), so a block is still fetched once for all its readers
+and the walk is unchanged. The fold and its inverse are two transposes
+in the wrapper, which XLA joins with the caller's own. With ``g`` 1 and
+B 1 the kernel compiles to what it was.
 
 Pool layout: ``[L, NB + 1, H, block_size, 2 * Dh]`` — one block of one
 head is a ``(block_size, 2 * Dh)`` tile whose lanes hold K in
@@ -172,7 +188,7 @@ def kv_group_blocks(heads: int, block_size: int, head_dim: int,
 
 def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
                 lo_ref, kvlen_ref, *rest, block_q, block_size, group, scale,
-                quantized=False):
+                quantized=False, q_group=1, mask_block=1):
     """One q-block grid step, every head at once: walk the owning
     sequence's page table ONCE, a group of ``group`` KV blocks at a
     time — one DMA a block brings ``pool[layer, pid]`` whole (all heads,
@@ -208,7 +224,10 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
     b = pl.program_id(0)
     layer = layer_ref[0]
     seq = blk_seq_ref[b]
-    n_heads, _, dh = q_ref.shape
+    n_heads, q_rows, dh = q_ref.shape       # q_rows = block_q * q_group
+    # K and V lanes are whole 128-lane tiles each: slicing them apart is
+    # free, and neither product then runs over the other's lanes
+    split = dh % 128 == 0
     cols_g = group * block_size             # KV columns of one group
     t_len = tables_ref.shape[1]
 
@@ -226,13 +245,21 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         # its lanes: q is zero-extended over the V lanes, so q . [K|V]^T
         # is q . K^T, and p . [K|V] holds p . V in its upper Dh lanes
         q = q_ref[...]                                  # [H, bq, Dh]
-        q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+        if not split:
+            q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
         # virtual cache position of each row: rows of a sequence are
         # consecutive tokens starting at seq_pos0 (pad rows past the
-        # real q_len see the whole context and nobody reads them)
+        # real q_len see the whole context and nobody reads them); a
+        # folded row r is query row r // q_group
         row0 = b * _BQ - qstart_ref[seq]
-        qpos = pos0_ref[seq] + row0 + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)                 # [bq, 1]
+        q_row = jax.lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0)
+        if q_group > 1:
+            q_row = q_row // jnp.int32(q_group)
+        qpos = pos0_ref[seq] + row0 + q_row             # [bq * g, 1]
+        # the last column a row sees: its own, or its block's last
+        q_last = qpos if mask_block == 1 else \
+            qpos // jnp.int32(mask_block) * jnp.int32(mask_block) \
+            + jnp.int32(mask_block - 1)
         lo = lo_ref[seq]
         kv_len = kvlen_ref[seq]
         n_kv = jnp.minimum((kv_len + _BS - 1) // _BS, jnp.int32(t_len))
@@ -301,16 +328,17 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
             # exact in q's), f32 accumulation (MXU contract shared with
             # the flash kernels)
             s = jax.lax.dot_general(
-                q, kv, (((2,), (2,)), ((0,), (0,))),
+                q, kv[:, :, :dh] if split else kv,
+                (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) * scale  # [H, bq, G*bs]
             if quantized:
                 k_scale, v_scale = col_scales(grp)
                 s = s * k_scale
             cols = grp * _CG + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, cols_g), 1)
+                jnp.int32, (q_rows, cols_g), 1)
             # f32-typed fill: a bare python float is weak f64 under the
             # framework's global x64
-            s = jnp.where(((cols >= lo) & (cols <= qpos)
+            s = jnp.where(((cols >= lo) & (cols <= q_last)
                            & (cols < kv_len))[None], s,
                           jnp.float32(_NEG_INF))
             m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -321,31 +349,34 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
             if quantized:
                 p = p * v_scale
             acc_new = acc * alpha + jax.lax.dot_general(
-                p.astype(q.dtype), kv, (((2,), (1,)), ((0,), (0,))),
+                p.astype(q.dtype), kv[:, :, dh:] if split else kv,
+                (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)     # [H, bq, 2*Dh]
             return m_new, l_new, acc_new
 
-        m0 = jnp.full((n_heads, block_q, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((n_heads, block_q, 1), jnp.float32)
-        acc0 = jnp.zeros((n_heads, block_q, 2 * dh), jnp.float32)
+        m0 = jnp.full((n_heads, q_rows, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((n_heads, q_rows, 1), jnp.float32)
+        acc0 = jnp.zeros((n_heads, q_rows, dh if split else 2 * dh),
+                         jnp.float32)
         # i32 bounds: a bare python 0 becomes an i64 induction variable
         # under the framework's global x64, and the interpret-mode body
         # trace happens outside the call site's _x64_off scope
         _, l, acc = jax.lax.fori_loop(jnp.int32(0), n_grp, body,
                                       (m0, l0, acc0))
-        o_ref[...] = (acc[:, :, dh:]
+        o_ref[...] = ((acc if split else acc[:, :, dh:])
                       / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
                            tables, lo, kv_len, *, scales=None, scale=None,
-                           block_q: int = BLOCK_Q):
+                           block_q: int = BLOCK_Q, mask_block: int = 1):
     """Fused paged attention over one layer of the serving block pool.
 
     * ``q`` — ``[H, Qp, Dh]`` flattened padded query rows (``Qp`` a
       multiple of ``block_q``; per-sequence contiguous, see module doc);
-    * ``pool`` — the FULL block pool ``[L, NB + 1, H, bs, 2 * Dh]``
-      (K|V folded into the lanes, see module doc); it stays in HBM
+    * ``pool`` — the FULL block pool ``[L, NB + 1, Hkv, bs, 2 * Dh]``
+      (K|V folded into the lanes, see module doc; ``H`` a multiple of
+      ``Hkv``: grouped-query heads); it stays in HBM
       (``memory_space=pl.ANY``) and ``layer`` (a host int) indexes it
       inside the kernel's DMAs, so no per-layer slice is ever
       materialized;
@@ -357,15 +388,26 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
       the per-block max-abs scale array ``[L, 2, NB + 1, H]`` f32,
       riding the scalar-prefetch path into SMEM so each DMA'd block
       dequantizes in-register;
+    * ``mask_block`` — static B: a row sees columns up to the end of its
+      block of B (1: causal; module doc);
     * returns ``[H, Qp, Dh]`` in ``q``'s dtype.
     """
     h, qp, dh = q.shape
     L, nb1, hp, bs, dh2 = pool.shape
     quantized = pool.dtype.name in ("int8", "float8_e4m3fn")
-    if (hp, dh2) != (h, 2 * dh):
+    if h % hp or dh2 != 2 * dh:
         raise ValueError(
-            f"pool heads/lanes {(hp, dh2)} != q heads / 2*head_dim "
-            f"{(h, 2 * dh)}")
+            f"pool KV heads/lanes {(hp, dh2)} do not fit q heads / "
+            f"2*head_dim {(h, 2 * dh)}: the query heads must be a "
+            f"multiple of the pool's KV heads")
+    if int(mask_block) < 1 or bs % int(mask_block):
+        raise ValueError(
+            f"mask_block {mask_block} must divide block_size {bs}: a "
+            f"diffusion block never straddles a cache block")
+    if quantized and h != hp:
+        raise ValueError(
+            "grouped-query heads over int8/fp8 blocks are not built: the "
+            "per-block scales are read per query head")
     check_kv_tile(pool.dtype, bs, dh)
     if qp % block_q:
         raise ValueError(
@@ -374,10 +416,10 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
         raise ValueError(
             f"a {pool.dtype.name} pool is quantized storage: pass the "
             f"per-block scale array (PagedKVPool.scales)")
-    if scales is not None and tuple(scales.shape) != (L, 2, nb1, h):
+    if scales is not None and tuple(scales.shape) != (L, 2, nb1, hp):
         raise ValueError(
             f"scales shape {tuple(scales.shape)} != per-block layout "
-            f"{(L, 2, nb1, h)}")
+            f"{(L, 2, nb1, hp)}")
     if not 0 <= int(layer) < L:
         raise ValueError(f"layer {layer} out of range [0, {L})")
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
@@ -387,12 +429,14 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
             i32([layer]), q, pool, i32(blk_seq), i32(seq_qstart),
             i32(seq_pos0), i32(tables), i32(lo), i32(kv_len),
             None if scales is None else jnp.asarray(scales, jnp.float32),
-            scale=scale, block_q=int(block_q), interpret=_interpret())
+            scale=scale, block_q=int(block_q), interpret=_interpret(),
+            mask_block=int(mask_block))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "interpret",
+                                             "mask_block"))
 def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
-              kv_len, scales, *, scale, block_q, interpret):
+              kv_len, scales, *, scale, block_q, interpret, mask_block=1):
     """The Pallas call. ``layer`` is a ``[1]`` int32 scalar-prefetch
     operand, not a constant of the kernel, and the call is a jitted
     function of its own: the layers of a step program differ in nothing
@@ -400,25 +444,32 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
     program, not once a layer (36 times at GPT-2 large, in every
     warm-up, compile cache warm or not)."""
     h, qp, dh = q.shape
-    bs = pool.shape[3]
+    hkv, bs = pool.shape[2], pool.shape[3]
     quant = scales is not None
-    group = kv_group_blocks(h, bs, dh, pool.dtype)
+    g = h // hkv
+    if g > 1:
+        # fold a KV head's group of query heads into the rows: [Hkv, g,
+        # Qp, Dh] -> [Hkv, Qp * g, Dh], row r = query row r // g
+        q = jnp.swapaxes(q.reshape(hkv, g, qp, dh), 1, 2).reshape(
+            hkv, qp * g, dh)
+    group = kv_group_blocks(hkv, bs, dh, pool.dtype)
     kernel = functools.partial(
         _rpa_kernel, block_q=block_q, block_size=int(bs), group=group,
-        scale=scale, quantized=quant)
+        scale=scale, quantized=quant, q_group=g, mask_block=mask_block)
+    q_rows = block_q * g
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8 if quant else 7,
         grid=(qp // block_q,),
         in_specs=[
-            pl.BlockSpec((h, block_q, dh), lambda b, *_: (0, b, 0)),
+            pl.BlockSpec((hkv, q_rows, dh), lambda b, *_: (0, b, 0)),
             pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
         ],
-        out_specs=pl.BlockSpec((h, block_q, dh), lambda b, *_: (0, b, 0)),
+        out_specs=pl.BlockSpec((hkv, q_rows, dh), lambda b, *_: (0, b, 0)),
         scratch_shapes=[
             # two buffers of one group: block g of a group is rows
             # [g*bs, (g+1)*bs) of every head, so a head's K|V of the
             # whole group is one [G*bs, 2*Dh] tile stack
-            pltpu.VMEM((2, h, group * bs, 2 * dh), pool.dtype),
+            pltpu.VMEM((2, hkv, group * bs, 2 * dh), pool.dtype),
             pltpu.SemaphoreType.DMA((2, group)),
         ],
     )
@@ -427,13 +478,17 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
         # only THIS layer's [2, NB+1, H] scale slice goes to SMEM
         prefetch.append(jax.lax.dynamic_index_in_dim(
             scales, layer[0], 0, keepdims=False))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((h, qp, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hkv, qp * g, dh), q.dtype),
         interpret=interpret,
     )(*prefetch, q, pool)
+    if g > 1:
+        out = jnp.swapaxes(out.reshape(hkv, qp, g, dh), 1, 2).reshape(
+            h, qp, dh)
+    return out
 
 
 def ragged_layout(q_lens: Sequence[int], pos0s: Sequence[int], *,
@@ -493,12 +548,16 @@ def ragged_layout(q_lens: Sequence[int], pos0s: Sequence[int], *,
 
 
 def reference_ragged_attention(q_rows, pool, layer, row_seq, row_pos,
-                               tables, lo, scale=None, scales=None):
+                               tables, lo, scale=None, scales=None,
+                               mask_block=1, kv_len=None):
     """Numpy oracle for the kernel (tests): per-row full-precision
     softmax attention over the row's ``[lo, pos]`` window gathered
     through the page table. ``q_rows [N, H, Dh]``, ``row_seq/row_pos
     [N]``; ``scales`` dequantizes an int8 pool (per-block max-abs,
-    the kernel's in-register multiply done up front)."""
+    the kernel's in-register multiply done up front). Query head ``j``
+    reads the pool's KV head ``j // (H / Hkv)``; with ``mask_block`` B the
+    window ends at the row's block's last column, bounded by
+    ``kv_len[seq]``."""
     q_rows = np.asarray(q_rows, np.float32)
     n, h, dh = q_rows.shape
     # [L, NB+1, H, bs, 2*Dh] -> K/V planes [L, 2, NB+1, H, bs, Dh]
@@ -509,17 +568,21 @@ def reference_ragged_attention(q_rows, pool, layer, row_seq, row_pos,
     bs = pool.shape[4]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     out = np.zeros_like(q_rows)
+    g = h // pool.shape[3]
+    B = int(mask_block)
     for i in range(n):
         s = int(row_seq[i])
         p = int(row_pos[i])
+        if B > 1:
+            p = min(p // B * B + B - 1, int(kv_len[s]) - 1)
         cols = np.arange(int(lo[s]), p + 1)
         k = np.stack([pool[layer, 0, tables[s][c // bs], :, c % bs, :]
                       for c in cols])                    # [ctx, H, Dh]
         v = np.stack([pool[layer, 1, tables[s][c // bs], :, c % bs, :]
                       for c in cols])
         for hh in range(h):
-            logits = (k[:, hh] @ q_rows[i, hh]) * scale
+            logits = (k[:, hh // g] @ q_rows[i, hh]) * scale
             w = np.exp(logits - logits.max())
             w /= w.sum()
-            out[i, hh] = w @ v[:, hh]
+            out[i, hh] = w @ v[:, hh // g]
     return out

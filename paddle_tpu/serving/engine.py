@@ -262,12 +262,45 @@ def _refuse_with_latent(kv_dtype, mesh, spec_draft,
             "planes of a block (serving/host_tier.py)")
 
 
+def _refuse_with_blocks(kv_dtype, mesh, spec_draft,
+                        host_tier_bytes) -> None:
+    """A model generated by diffusion over blocks (``block_length`` > 1
+    in its decoder spec's generation rule): each mechanism that has no
+    block form yet is refused here, by name."""
+    import jax.numpy as jnp
+    if spec_draft is not None:
+        raise ValueError(
+            "spec_draft does not compose with block generation yet: a "
+            "draft proposes the NEXT tokens of a causal model, and a "
+            "block's positions are fixed in order of confidence")
+    if host_tier_bytes is not None:
+        raise ValueError(
+            "host_tier_bytes does not compose with block generation yet: "
+            "demotion and promotion copy [layers, 2, heads, ...] K/V "
+            "planes a QUERY head (serving/host_tier.py), and this pool "
+            "holds KV heads")
+    if kv_dtype is not None and jnp.dtype(kv_dtype).name in (
+            "int8", "float8_e4m3fn"):
+        raise ValueError(
+            "int8/fp8 KV blocks do not compose with block generation "
+            "yet: a block's rows are rewritten every pass, and each "
+            "rewrite would requantize the cache block around them")
+    if mesh is not None:
+        raise ValueError(
+            "mesh= (tensor-parallel serving) does not compose with block "
+            "generation yet: the sharded step has no unmask stage, and "
+            "grouped heads under mesh= are not built")
+
+
 class GenerationEngine:
-    """Continuous-batching autoregressive serving over a decoder the
-    fused stack has a spec of (``models/decoder_spec.py``: GPT-2, A.X-K1).
+    """Continuous-batching serving over a decoder the fused stack has a
+    spec of (``models/decoder_spec.py``: GPT-2, A.X-K1, SDAR) — one token
+    a sequence a step, or a block of them by diffusion, as the spec's
+    generation rule says.
 
     ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` /
-    ``AXK1ForCausalLM`` (anything ``serving_decoder`` has a spec of);
+    ``AXK1ForCausalLM`` / ``SDARForCausalLM`` (anything
+    ``serving_decoder`` has a spec of);
     its parameters are snapshotted at construction (sharded parameters
     serve sharded — jit follows the placement).
 
@@ -366,6 +399,9 @@ class GenerationEngine:
         if spec.attention == _ds.LATENT:
             _refuse_with_latent(kv_dtype, mesh, spec_draft,
                                 host_tier_bytes)
+        if spec.generation.block_length > 1:
+            _refuse_with_blocks(kv_dtype, mesh, spec_draft,
+                                host_tier_bytes)
         max_len = int(max_len or spec.max_positions)
         # every jit is deferred, so without this check an oversized
         # max_len would only surface as SILENTLY WRONG tokens (XLA clamps
@@ -403,6 +439,13 @@ class GenerationEngine:
         if block_size is None:
             block_size = max(16, min_kv_block_for(kv_dtype or dtype))
         check_kv_tile(kv_dtype or dtype, block_size, lanes=cache.lanes)
+        if int(block_size) % spec.generation.block_length:
+            raise ValueError(
+                f"block_size {block_size} is no multiple of the model's "
+                f"block_length {spec.generation.block_length}: a "
+                f"diffusion block never straddles a cache block (its rows "
+                f"are one rewrite of ops/kv_append.py and one DMA of the "
+                f"attention kernel)")
         pallas_smoke.ensure()
         self._key = jax.random.PRNGKey(int(seed))
         self._eid = _next_engine_id()
@@ -416,9 +459,14 @@ class GenerationEngine:
         # the fused step's "previous result" operand while no launch is
         # in flight: the shape of its own result ([slots | sentinel |
         # a routed model's three counters]), never read (token_src -1)
+        # (a block-generation step's result holds the block state too)
         from ..models.decoder_spec import ROUTED
+        from ..models.generation import block_result_layout
+        routed = any(ls.ffn == ROUTED for ls in spec.layers)
         self._no_prev = np.zeros(
-            num_slots + 1 + 3 * any(ls.ffn == ROUTED for ls in spec.layers),
+            num_slots + 1 + 3 * routed
+            if spec.generation.block_length == 1 else block_result_layout(
+                num_slots, spec.generation.block_length, routed)[-1],
             np.int32)
         if mesh is not None:
             # replicated over the mesh, as the result it stands in for
@@ -477,7 +525,8 @@ class GenerationEngine:
             max_queue=max_queue, prefill_budget=prefill_budget,
             do_copy=self._run_copy,
             do_spec_step=self._run_spec_step if self._spec else None,
-            spec_k=self._spec_k, lane_weights=lane_weights)
+            spec_k=self._spec_k, lane_weights=lane_weights,
+            generation=spec.generation)
         # telemetry spine wiring (ISSUE 13): the engine joins the
         # statusz console and publishes its stats() island through the
         # labeled metrics registry ({engine=<id>} gauges/counters)
@@ -532,6 +581,11 @@ class GenerationEngine:
                 f"step's compile key — build a GenerationEngine("
                 f"top_p={top_p}) instead of risking one retrace per "
                 f"sampling mix")
+        if do_sample and self._decoder_spec.generation.block_length > 1:
+            raise ValueError(
+                "do_sample=True with block generation is not built: the "
+                "step fixes a position's argmax, its confidence the "
+                "argmax's probability (greedy, temperature 0)")
         ids = np.asarray(prompt_ids, np.int32).reshape(-1)
         if ids.size < 1:
             raise ValueError("prompt_ids must contain at least one token")
@@ -834,16 +888,28 @@ class GenerationEngine:
         scales = (self._pool.scales,) if self._pool.quantized else ()
         return analysis.analyze(
             self._fused_step_fn(Q, T), self._params, self._buffers,
-            self._pool.data, *scales, np.zeros(Q, np.int32),
-            np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-            np.zeros(Q, np.int32), np.zeros(Q // BLOCK_Q, np.int32),
-            np.zeros(S, np.int32), np.zeros(S, np.int32),
-            np.zeros((S, T), np.int32), np.zeros(S, np.int32),
-            np.zeros(S, np.int32), np.zeros(S, np.int32),
-            self._no_prev, np.full(Q, -1, np.int32),
-            np.zeros(S, bool), np.ones(S, np.float32), self._key,
+            self._pool.data, *scales, *self._null_step_operands(Q, T),
             passes=passes,
             name=f"serving.fused_step[{S} slots, q{Q}, t{T}]")
+
+    def _null_step_operands(self, Q: int, T: int) -> tuple:
+        """The fused step's operands after the pool, zeroed: a legal
+        no-op launch of the (Q, T) program (``analyze``, the plan gate)."""
+        from ..ops.ragged_paged_attention import BLOCK_Q
+        S = self._pool.num_slots
+        i32 = lambda *shape: np.zeros(shape, np.int32)
+        head = (i32(Q), i32(Q), i32(Q), i32(Q), i32(Q // BLOCK_Q), i32(S),
+                i32(S), i32(S, T), i32(S), i32(S))
+        B = self._decoder_spec.generation.block_length
+        if B > 1:
+            from ..models.generation import BLOCK_UNFIXED
+            return head + (
+                self._no_prev, np.full(S, -1, np.int32), i32(S, B),
+                np.full((S, B), BLOCK_UNFIXED, np.int32),
+                np.full(Q, -1, np.int32), np.full(S, -1, np.int32),
+                self._key)
+        return head + (i32(S), self._no_prev, np.full(Q, -1, np.int32),
+                       np.zeros(S, bool), np.ones(S, np.float32), self._key)
 
     def plan_replica(self, hbm_budget_bytes: Optional[int] = None,
                      top_k: int = 4) -> dict:
@@ -911,15 +977,7 @@ class GenerationEngine:
                     top_k=self._top_k, top_p=self._top_p,
                     quantized=pool.quantized, qmax=pool.qmax or 127.0)
             args = (params, buffers, pool.data, *scales,
-                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                    np.zeros(Q // BLOCK_Q, np.int32),
-                    np.zeros(S, np.int32), np.zeros(S, np.int32),
-                    np.zeros((S, T), np.int32), np.zeros(S, np.int32),
-                    np.zeros(S, np.int32), np.zeros(S, np.int32),
-                    self._no_prev, np.full(Q, -1, np.int32),
-                    np.zeros(S, bool), np.ones(S, np.float32),
-                    self._key)
+                    *self._null_step_operands(Q, T))
             flavor, site = "fused", f"fused_step[q{Q},t{T}]"
         donate = (2, 3) if pool.quantized else (2,)
         rep = liveness.callable_liveness(fn, *args, donate_argnums=donate,
@@ -982,14 +1040,26 @@ class GenerationEngine:
             self._draft_synced[slot] = False
         feed = np.concatenate(
             [req.prompt, np.asarray(req.tokens, np.int32)])
+        # block generation prefills WHOLE blocks: the feed is cut at the
+        # last block boundary and what is left over opens the first
+        # block, already fixed (a block in the middle of its passes at a
+        # preemption starts again all masked: only emitted tokens are
+        # fed). With block_length 1 nothing is left over
+        B = self._decoder_spec.generation.block_length
+        cut = feed.size // B * B
+        req.block_given = [int(t) for t in feed[cut:]]
+        req.block_pass, req.block_state = 0, None
         cached = pool.match_prefix(feed)
         if cached:
             pool.admit_cached(slot, cached)
             pool.note_tier_hit(
                 "host" if req._tier_promoted else "hbm")
+            # whole cache blocks, so whole diffusion blocks (block_size
+            # is a multiple of B), and under the block mask their K/V
+            # depend on nothing past them
             m = len(cached) * pool.block_size
             pool.set_slot(slot, pos=m, lo=0)
-            req.pending_feed = [int(t) for t in feed[m:]]
+            req.pending_feed = [int(t) for t in feed[m:cut]]
             req.trace.mark("prefix_hit", tokens_saved=m,
                            pending=len(req.pending_feed))
         else:
@@ -997,7 +1067,7 @@ class GenerationEngine:
             pool.admit_fresh(slot, feed.size)
             # position 0 is where the first pending token's K/V land
             pool.set_slot(slot, pos=0, lo=0)
-            req.pending_feed = [int(t) for t in feed]
+            req.pending_feed = [int(t) for t in feed[:cut]]
 
     def _ragged_operands(self, slot_requests, plan, spec=None,
                          from_prev=()):
@@ -1010,13 +1080,23 @@ class GenerationEngine:
         verify program. The decode rows of the slots in ``from_prev``
         have no host token yet (it is in the un-fetched result of the
         launch in flight): ``token_src`` names the slot at such a row
-        and -1 everywhere else."""
+        and -1 everywhere else.
+
+        Under block generation (the spec's ``block_length`` B > 1) a
+        decode slot's rows are the B rows of its current block at the
+        block's positions, every pass of it; what the rows hold is the
+        block's state, which the step reads from the launch in flight
+        (``from_prev``) or from the ``block`` operands returned last:
+        ``(state_src [S], blk_tok [S, B], blk_pass [S, B], row_blk [Q],
+        pass_idx [S])`` (``models/generation.py _build_block_step_fn``)."""
         from ..ops.ragged_paged_attention import (BLOCK_Q, kv_group_blocks,
                                                   ragged_layout)
 
         pool = self._pool
         S = pool.num_slots
         bs = pool.block_size
+        gen = self._decoder_spec.generation
+        B = gen.block_length
         q_lens = [0] * S
         pos0s = [0] * S
         row_tokens = {}
@@ -1037,7 +1117,7 @@ class GenerationEngine:
             if spec and slot in spec:
                 n_spec[slot] = n
                 row_tokens[slot] = [req.last_token]
-            elif slot in from_prev:
+            elif slot in from_prev or (B > 1 and not req.pending_feed):
                 row_tokens[slot] = []
             else:
                 row_tokens[slot] = (req.pending_feed[:n]
@@ -1052,8 +1132,29 @@ class GenerationEngine:
         write_block = np.zeros(Q, np.int32)   # pad rows -> scratch block
         write_off = np.zeros(Q, np.int32)
         token_src = np.full(Q, -1, np.int32)
-        for slot in from_prev:
-            token_src[int(qstart[slot])] = slot
+        block = None
+        if B > 1:
+            from ..models.generation import BLOCK_UNFIXED
+            state_src = np.full(S, -1, np.int32)
+            blk_tok = np.zeros((S, B), np.int32)
+            blk_pass = np.full((S, B), BLOCK_UNFIXED, np.int32)
+            row_blk = np.full(Q, -1, np.int32)
+            pass_idx = np.full(S, -1, np.int32)
+            for slot, req in slot_requests.items():
+                if not q_lens[slot] or req.pending_feed:
+                    continue
+                r0 = int(qstart[slot])
+                row_blk[r0:r0 + B] = slot * B + np.arange(B)
+                if not req.block_commits_next(gen):
+                    pass_idx[slot] = req.block_pass
+                if slot in from_prev:
+                    state_src[slot] = slot
+                else:
+                    blk_tok[slot], blk_pass[slot] = req.block_input(B)
+            block = (state_src, blk_tok, blk_pass, row_blk, pass_idx)
+        else:
+            for slot in from_prev:
+                token_src[int(qstart[slot])] = slot
         for slot, toks in row_tokens.items():
             r0, p0 = int(qstart[slot]), int(pos0[slot])
             table = pool.slot_table(slot)
@@ -1081,8 +1182,12 @@ class GenerationEngine:
             rows=sum(q_lens), q=Q, t=T, kv_tokens=int(kv_len.sum()),
             kv_steps=sum(qb * kb for qb, kb in walks),
             kv_fetches=sum(qb * -(-kb // group) for qb, kb in walks),
-            kv_row_tokens=sum(n * pos0s[s] + n * (n + 1) // 2
-                              for s, n in enumerate(q_lens) if n),
+            # under the block mask a row sees to the end of its block
+            kv_row_tokens=sum(
+                n * pos0s[s] + n * (n + 1) // 2 if B == 1 else int(
+                    np.minimum(((pos0s[s] + np.arange(n)) // B + 1) * B,
+                               pos0s[s] + n).sum())
+                for s, n in enumerate(q_lens) if n),
             # a slot's rows are consecutive positions: the blocks from
             # its first row's to its last row's, each rewritten once a
             # layer by ops.kv_append
@@ -1091,7 +1196,7 @@ class GenerationEngine:
                 for s, n in enumerate(q_lens) if n))
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
-                n_spec, sample_mask, temps, token_src)
+                n_spec, sample_mask, temps, token_src, block)
 
     def _run_fused_step(self, slot_requests, plan, prev=None):
         """Dispatch ONE fused ragged launch (the scheduler's
@@ -1107,11 +1212,17 @@ class GenerationEngine:
         pool = self._pool
         prev_toks, from_prev = prev if prev is not None \
             else (self._no_prev, ())
-        Q, T, ops, _, sample_mask, temps, token_src = \
+        Q, T, ops, _, sample_mask, temps, token_src, block = \
             self._ragged_operands(slot_requests, plan,
                                   from_prev=from_prev)
         step = self._fused_step_fn(Q, T)
-        args = ops + (prev_toks, token_src, sample_mask, temps, self._key)
+        if block is not None:
+            # the block step takes the block state where the one-token
+            # step takes last_row and the sampling operands
+            args = ops[:-1] + (prev_toks,) + block + (self._key,)
+        else:
+            args = ops + (prev_toks, token_src, sample_mask, temps,
+                          self._key)
         if pool.quantized:
             pool.data, pool.scales, nxt, self._key = step(
                 self._params, self._buffers, pool.data, pool.scales,
@@ -1362,7 +1473,7 @@ class GenerationEngine:
             d_dev = jnp.pad(d_dev, ((0, 0), (0, K - kmax)))
             q_dev = jnp.pad(q_dev, ((0, 0), (0, K - kmax), (0, 0)))
         # --- the fused verify launch ---------------------------------
-        Q, T, ops, n_spec, sample_mask, temps, _ = self._ragged_operands(
+        Q, T, ops, n_spec, sample_mask, temps, _, _ = self._ragged_operands(
             slot_requests, plan, spec=spec)
         step = self._spec_step_fn(Q, T)
         args = ops + (n_spec, d_dev, q_dev, sample_mask, temps,

@@ -337,7 +337,12 @@ class PagedKVPool:
         draft wrote with a negative ``n`` (page tables address by
         ``pos``, so rollback is pure bookkeeping: the stale K/V beyond
         the new ``pos`` are masked out of attention and overwritten by
-        the next append). Returns the new ``pos``."""
+        the next append). Under block generation
+        (``models/decoder_spec.py``) the scheduler calls this at a block's
+        COMMIT pass only, with the block's length: a denoising pass leaves
+        ``pos`` where it is, and the block's rows ``[pos, pos + B)`` stay
+        writable (``ensure_writable_range``) and are rewritten every pass.
+        Returns the new ``pos``."""
         if n == 0:
             raise ValueError("advance needs n != 0")
         st = self._slots[slot]

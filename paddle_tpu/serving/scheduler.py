@@ -38,6 +38,18 @@ membership of the in-flight batch changes EVERY turn:
   row the request already holds in the launch after is dropped when
   that launch lands (``late_rows``).
 
+Generation by diffusion over blocks (a model whose decoder spec says
+``block_length`` B > 1, ``models/decoder_spec.py``) runs through the SAME
+turn: a decode slot's rows are the B rows of its current block, a launch
+is one PASS over them — a denoising pass fixes positions and keeps
+nothing in the cache, so the pool's length stays; the commit pass that
+follows the last of them advances it by B — and the block's tokens are
+emitted together, in position order, when its last denoising pass lands.
+The schedule is static (``low_confidence_static``), so which pass a slot
+is in is host arithmetic (``GenerationRequest.block_pass``) and two
+launches stay in flight: what the next pass cannot know, the block's
+state, the step reads from the un-fetched result of the launch before.
+
 Backpressure is explicit: a full queue raises :class:`QueueFullError`
 in ``submit`` (the caller sheds load, nothing queues unboundedly), and
 a per-request deadline turns into :class:`DeadlineExceeded` whether the
@@ -190,6 +202,14 @@ class GenerationRequest:
         # first generated token emits from the launch that feeds the
         # final chunk.
         self.pending_feed: List[int] = []
+        # generation by diffusion over blocks (module doc): passes of
+        # the current block DISPATCHED so far; the feed's tokens past
+        # its last block boundary, which open the first block already
+        # fixed; and the block's state as the newest LANDED pass left it
+        # (token ids [B], the pass each position was fixed in [B])
+        self.block_pass = 0
+        self.block_given: List[int] = []
+        self.block_state: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self.first_token_at: Optional[float] = None
         self._last_token_at: Optional[float] = None
         # lifecycle trace (host stamps; the scheduler marks events, the
@@ -252,8 +272,33 @@ class GenerationRequest:
         return self.deadline is not None \
             and (now or time.perf_counter()) > self.deadline
 
-    def _emit(self, tok: int) -> None:
-        now = time.perf_counter()
+    def block_input(self, block_length: int):
+        """The block's state as the host knows it before the pass about
+        to be dispatched: ``(token ids [B], fixed-in-pass [B])`` — a new
+        block holds the feed's leftover tokens, given, and is open
+        elsewhere; a block between passes is as its last landed pass
+        left it. (The state of a pass still in flight is on the device:
+        the step reads it there.)"""
+        from ..models.generation import BLOCK_GIVEN, BLOCK_UNFIXED
+        if self.block_pass and self.block_state is not None:
+            return self.block_state
+        n = len(self.block_given)
+        tok = np.zeros(block_length, np.int32)
+        tok[:n] = self.block_given
+        fixed = np.full(block_length, BLOCK_UNFIXED, np.int32)
+        fixed[:n] = BLOCK_GIVEN
+        return tok, fixed
+
+    def block_commits_next(self, rule) -> bool:
+        """Whether the block's next pass is its commit: every denoising
+        pass the positions the prompt did not give take
+        (``GenerationRule.passes``) has been dispatched."""
+        return self.block_pass == rule.passes(
+            rule.block_length - len(self.block_given))
+
+    def _emit(self, tok: int, fixed_pass: Optional[int] = None,
+              now: Optional[float] = None) -> None:
+        now = time.perf_counter() if now is None else now
         if self.first_token_at is None:
             self.first_token_at = now
             stat_observe("serving/ttft_ms",
@@ -267,7 +312,7 @@ class GenerationRequest:
             stat_observe("serving/tpot_ms",
                          (now - self._last_token_at) * 1e3)
         self._last_token_at = now
-        self.trace.stamp_token(now)
+        self.trace.stamp_token(now, tok, fixed_pass)
         self.tokens.append(tok)
         self.emitted += 1
         self.last_token = tok
@@ -338,7 +383,8 @@ class Scheduler:
                  do_spec_step: Optional[Callable] = None,
                  spec_k: int = 0,
                  recorder: Optional[FlightRecorder] = None,
-                 lane_weights: Optional[Dict[str, float]] = None):
+                 lane_weights: Optional[Dict[str, float]] = None,
+                 generation=None):
         if max_queue < 1:
             raise ValueError(f"max_queue must be >= 1, got {max_queue}")
         self._pool = pool
@@ -358,6 +404,16 @@ class Scheduler:
         self._spec_k = int(spec_k)
         if self._spec and self._spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
+        # the served model's generation rule (models/decoder_spec.py):
+        # one token a sequence a step unless it says block_length > 1
+        from ..models.decoder_spec import GenerationRule
+        self._gen = generation if generation is not None \
+            else GenerationRule()
+        self._block = int(self._gen.block_length)
+        if self._spec and self._block > 1:
+            raise ValueError(
+                "speculative decoding does not compose with block "
+                "generation")
         self.spec_cycles = 0             # cycles that verified >= 1 slot
         self.spec_proposed = 0           # draft tokens verified
         self.spec_accepted = 0           # draft tokens accepted
@@ -1033,6 +1089,10 @@ class Scheduler:
         req = self._slots.pop(slot)
         self._pool.free(slot)
         req.pending_feed = []            # rebuilt at re-admission
+        # a block in the middle of its passes starts again all masked:
+        # only emitted tokens are fed (the pipeline was drained before
+        # this, so every dispatched pass has landed)
+        req.block_pass, req.block_state = 0, None
         req._preempted = True            # outranks WDRR selection
         req._tier_promoted = False       # re-classified at re-admission
         self.preempts += 1
@@ -1057,19 +1117,23 @@ class Scheduler:
         depend on the token's value, so nothing is wasted). Feeding
         slots split the prefill TOKEN budget FCFS by request age; a slot
         whose share hits 0 simply waits a launch (its blocks are already
-        reserved)."""
+        reserved). Under block generation a decode slot's rows are the B
+        rows of its block, every pass of it, and a chunk ends on a block
+        boundary (the feed is whole blocks: ``engine._run_admit``)."""
         budget = self._prefill_budget
+        B = self._block
         plan: Dict[int, int] = {}
         for slot in sorted(self._slots,
                            key=lambda s: self._slots[s].id):
             req = self._slots[slot]
             if req.pending_feed:
                 n = min(len(req.pending_feed), budget)
+                n -= n % B
                 budget -= n
                 if n > 0:
                     plan[slot] = n
             elif req.emitted + req.in_flight < req.max_new_tokens:
-                plan[slot] = 1
+                plan[slot] = B
         return plan
 
     def _prepare_chunked(self, plan: Dict[int, int]) -> Dict[int, int]:
@@ -1194,7 +1258,14 @@ class Scheduler:
         un-fetched result, to be handed to ``_land``."""
         rec = self._rec
         active = {s: self._slots[s] for s in plan}
-        from_prev = {s for s, r in active.items() if r.in_flight}
+        if self._block > 1:
+            # a block between two passes whose newest is un-fetched
+            from_prev = set() if prev is None else {
+                s for s, r in active.items()
+                if r.block_pass and prev["active"].get(s) is r
+                and prev["passes"].get(s, (-1, 0))[0] >= 0}
+        else:
+            from_prev = {s for s, r in active.items() if r.in_flight}
         rec["overlapped"] = prev is not None
         if prev is not None:
             stat_add("serving/launch_overlapped")
@@ -1215,17 +1286,51 @@ class Scheduler:
                     active, plan,
                     (prev["toks"], from_prev) if from_prev else None)
             fed: Dict[int, int] = {}    # slot -> feed left after its chunk
+            # block generation: slot -> (its denoising pass, or -1 for a
+            # commit; the tokens its landing emits)
+            passes: Dict[int, Tuple[int, int]] = {}
             for slot, req in active.items():
+                if self._block > 1 and not req.pending_feed:
+                    passes[slot] = self._advance_block(slot, req)
+                    continue
                 self._pool.advance(slot, plan[slot])
                 if req.pending_feed:
                     del req.pending_feed[:plan[slot]]
                     fed[slot] = len(req.pending_feed)
-                if not req.pending_feed:
+                if not req.pending_feed and self._block == 1:
                     req.in_flight += 1
             rec["decode_dispatch_ms"] += (time.perf_counter() - t1) * 1e3
         return {"cycle": self._cycle, "rec": rec, "active": active,
                 "plan": plan, "spec": spec, "fed": fed, "toks": toks_dev,
-                "t": t1}
+                "passes": passes, "t": t1}
+
+    def _advance_block(self, slot: int,
+                       req: GenerationRequest) -> Tuple[int, int]:
+        """What the host knows of a block-generation decode slot once the
+        pass it rides is dispatched. A denoising pass ``k`` leaves the
+        pool's length alone (its K/V are not kept: the block's rows stay
+        writable and the next pass rewrites them); the LAST one of a
+        block raises ``in_flight`` by the tokens the block emits — the
+        positions the prompt did not give, short of ``max_new_tokens``
+        (the surplus of a last block is denoised and dropped). The commit
+        pass that follows advances the pool by the block and opens the
+        next one, all masked. A request's last block takes no commit: the
+        plan gives it no row once its tokens are all in flight, and
+        nobody reads that block's K/V. Returns ``(k, tokens its landing
+        emits)``, ``k`` -1 for the commit."""
+        B = self._block
+        if req.block_commits_next(self._gen):
+            self._pool.advance(slot, B)
+            req.block_pass, req.block_given = 0, []
+            return -1, 0
+        k = req.block_pass
+        req.block_pass = k + 1
+        emits = 0
+        if req.block_commits_next(self._gen):       # the last denoising pass
+            emits = min(B - len(req.block_given),
+                        req.max_new_tokens - req.emitted - req.in_flight)
+            req.in_flight += emits
+        return k, emits
 
     def _drain(self) -> bool:
         """Land the launch in flight, if any: the pipeline is empty
@@ -1282,7 +1387,10 @@ class Scheduler:
         owner."""
         rec = launch["rec"]
         active, plan, spec = launch["active"], launch["plan"], launch["spec"]
-        fed = launch["fed"]
+        fed, passes = launch["fed"], launch["passes"]
+        B = self._block
+        if B > 1:
+            rec.update(denoise_slots=0, commit_slots=0, tokens_fixed=0)
         S = self._pool.num_slots
         K = self._spec_k
         if spec:
@@ -1343,7 +1451,9 @@ class Scheduler:
                 stat_add("serving/spec_proposed", n)
                 stat_add("serving/spec_accept", a)
                 req.trace.mark("spec_verify", proposed=n, accepted=a)
-            if not fed.get(slot):
+            if B > 1:
+                req.in_flight -= passes.get(slot, (-1, 0))[1]
+            elif not fed.get(slot):
                 req.in_flight -= 1      # this launch's token lands now
             if req.cancelled:
                 stat_add("serving/cancelled")
@@ -1363,11 +1473,21 @@ class Scheduler:
                     continue             # mid-feed: row output ignored
                 # final chunk landed: publish the fully-written feed
                 # blocks to the prefix cache, then emit the first
-                # generated token — produced by this same launch
-                self._pool.register_prefix(slot, np.concatenate(
-                    [req.prompt, np.asarray(req.tokens, np.int32)]))
+                # generated token — produced by this same launch. (Block
+                # generation fed whole blocks only: the trie is never
+                # handed a cache block that holds an uncommitted
+                # diffusion block, and a prefill yields no token)
+                feed = np.concatenate(
+                    [req.prompt, np.asarray(req.tokens, np.int32)])
+                self._pool.register_prefix(slot, feed[:feed.size // B * B])
                 req.trace.mark("chunked_prefill_done",
                                emitted=req.emitted)
+                if B > 1:
+                    continue
+            if B > 1:
+                emitted += self._land_block_pass(slot, req, passes[slot],
+                                                 toks, rec)
+                continue
             if slot in spec and not feeding:
                 a = min(int(acc_row[slot]), n)
                 emit = [int(t) for t in draft_rows[slot, :a]]
@@ -1408,3 +1528,42 @@ class Scheduler:
             rec["spec_slots"] = len(spec)
         if dt > 0 and emitted:
             stat_observe("serving/tokens_per_sec", emitted / dt)
+
+    def _land_block_pass(self, slot: int, req: GenerationRequest,
+                         pass_emits: Tuple[int, int], toks, rec) -> int:
+        """The host half of one slot's pass of a block: keep the block's
+        state as the pass left it (the next pass takes it from here if
+        the pipeline is drained by then), count the pass, and if it was
+        the block's last denoising pass emit the block — the positions
+        the passes fixed, in position order, each with the pass that
+        fixed it — retiring the request where it ends. Returns the
+        tokens emitted."""
+        k, emits = pass_emits
+        if k < 0:
+            rec["commit_slots"] += 1
+            stat_add("serving/commit_passes")
+            return 0
+        S, B = self._pool.num_slots, self._block
+        rec["denoise_slots"] += 1
+        stat_add("serving/denoise_passes")
+        # the block state closes the step's result: [S * B] token ids,
+        # [S * B] the pass each position was fixed in
+        # (models/generation.py block_result_layout)
+        at = len(toks) - 2 * S * B + slot * B
+        tok = np.array(toks[at:at + B])
+        fixed = np.array(toks[at + S * B:at + S * B + B])
+        req.block_state = (tok, fixed)
+        rec["tokens_fixed"] += int(np.sum(fixed == k))
+        out = 0
+        now = time.perf_counter()
+        for j in range(B):
+            if out == emits:
+                break               # the surplus of a last block
+            if fixed[j] < 0:
+                continue            # given by the prompt
+            req._emit(int(tok[j]), fixed_pass=int(fixed[j]), now=now)
+            out += 1
+            if self._finished(req, int(tok[j])):
+                self._retire(slot)
+                break
+        return out

@@ -55,7 +55,8 @@ class RequestTrace:
     returns — the terminal mark happens-before ``_done`` is set.
     """
 
-    __slots__ = ("request_id", "events", "token_times", "tenant", "lane")
+    __slots__ = ("request_id", "events", "token_times", "token_ids",
+                 "token_passes", "tenant", "lane")
 
     def __init__(self, request_id: int, t_submit: Optional[float] = None,
                  tenant: Optional[str] = None, lane: Optional[str] = None):
@@ -70,14 +71,25 @@ class RequestTrace:
             ("submit", t_submit if t_submit is not None
              else time.perf_counter(), None)]
         self.token_times: List[float] = []   # one host stamp per token
+        # generation by diffusion over blocks: per emitted token its id
+        # and the pass of its block in which it was fixed — the ORDER a
+        # block was filled in, which the token stamps (a block's tokens
+        # are emitted together, in position order) do not show. Empty
+        # for a model that yields one token a step
+        self.token_ids: List[int] = []
+        self.token_passes: List[int] = []
 
     # -- writers (scheduler thread) ----------------------------------------
     def mark(self, name: str, t: Optional[float] = None, **meta) -> None:
         self.events.append((name, t if t is not None
                             else time.perf_counter(), meta or None))
 
-    def stamp_token(self, t: float) -> None:
+    def stamp_token(self, t: float, token: Optional[int] = None,
+                    fixed_pass: Optional[int] = None) -> None:
         self.token_times.append(t)
+        if fixed_pass is not None:
+            self.token_ids.append(int(token))
+            self.token_passes.append(int(fixed_pass))
 
     # -- readers -----------------------------------------------------------
     def t(self, name: str) -> Optional[float]:
@@ -159,6 +171,8 @@ class RequestTrace:
                 "ttft_ms": self.ttft_ms,
                 "tpot_ms": self.tpot_ms,
                 "tokens": len(self.token_times),
+                **({"token_passes": list(self.token_passes)}
+                   if self.token_passes else {}),
                 "preempts": self.count("preempt"),
                 "prefix_hits": self.count("prefix_hit"),
                 "timeline": self.timeline()}
@@ -207,6 +221,20 @@ class RequestTrace:
                             self.token_times[0], t1, tid=tid, depth=1,
                             parent=name,
                             args={"tokens": len(self.token_times)})
+        # block generation: one span a block, from the block before it to
+        # the stamp its tokens share, with the passes that fixed them
+        start, i = self.t("prefill_end") or t0, 0
+        while i < len(self.token_passes):
+            j = i
+            while j < len(self.token_passes) \
+                    and self.token_times[j] == self.token_times[i]:
+                j += 1
+            _prof.add_event("block", "serving/request", start,
+                            self.token_times[i], tid=tid, depth=2,
+                            parent="decode",
+                            args={"tokens": j - i,
+                                  "fixed_in_pass": self.token_passes[i:j]})
+            start, i = self.token_times[i], j
 
     def __repr__(self):
         return (f"<RequestTrace #{self.request_id} events="

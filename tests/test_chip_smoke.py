@@ -96,3 +96,24 @@ def test_axk1_serve_phase():
         prompt_lens=(5, 21), new_tokens=4,
         limits=cfg["serving"]["check"]["limits"], width=32, q_block=16)
     assert out["tokens"] == 8 and out["max_gap"] < 1e-3
+
+
+def test_sdar_serve_phase():
+    """The grouped-head / block-generation phase at toy widths."""
+    import json
+    data = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "tests", "data",
+        "tiny-sdar-config.json")
+    with open(data) as f:
+        cfg = json.load(f)
+    out = chip_smoke.phase_sdar_serve(
+        cfg["model"], dtype="float32", max_len=64, block_size=8,
+        num_slots=2, num_blocks=16, prefill_budget=16,
+        prompt_lens=(5, 22), new_tokens=10,
+        limits=cfg["serving"]["check"]["limits"], width=32, states=16,
+        q_block=16)
+    # the first request's last block (positions 12-15) ends in a surplus
+    # position: 5 + 10 = 15; the second's text ends on a block boundary
+    assert out["tokens"] == 7 + 10 and out["passes"] == 17
+    assert out["max_gap"] < 1e-3 and out["max_order_gap"] < 1e-3
+

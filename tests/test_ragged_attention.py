@@ -128,7 +128,8 @@ def _random_ragged_case(rng, *, dtype="float32"):
 
 
 def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
-               dtype="float32", nan_at=(), seed=0):
+               dtype="float32", nan_at=(), seed=0, q_heads=None,
+               mask_block=1):
     """A hand-built launch for the grouped KV walk: ``seqs`` is a list of
     ``(q_len, kv_len)`` (the q rows are the context's tail), each
     sequence's blocks drawn from a shuffled pool, its table padded with
@@ -137,8 +138,11 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
     blocks with NaN after the oracle's copy is taken: ``(s, j)`` is
     sequence ``s``'s ``j``-th block, ``"scratch"`` block 0. Returns ``(got, ref, out)``: real rows
     ``[N, H, Dh]`` of the kernel and of the oracle, and the kernel's
-    whole output."""
+    whole output. ``heads`` are the pool's KV heads; ``q_heads`` (a
+    multiple of them: grouped-query attention) defaults to the same;
+    ``mask_block`` B lets a row see to the end of its block of B."""
     import jax.numpy as jnp
+    q_heads = q_heads or heads
 
     rng = np.random.RandomState(seed)
     S = len(seqs)
@@ -162,21 +166,22 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
     pos0s = [kv - q for q, kv in seqs]
     kv_len = np.asarray([kv for _, kv in seqs], np.int32)
     blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, pos0s)
-    q = rng.randn(heads, len(blk_seq) * 8, dh).astype(np.float32)
+    q = rng.randn(q_heads, len(blk_seq) * 8, dh).astype(np.float32)
     lo = np.zeros(S, np.int32)
     rows = [(s, i) for s in range(S) for i in range(q_lens[s])]
     ref = reference_ragged_attention(
         np.stack([q[:, qstart[s] + i] for s, i in rows]), pool, 1,
         [s for s, _ in rows], [pos0s[s] + i for s, i in rows],
-        [list(t) for t in tables], lo, scales=scales)
+        [list(t) for t in tables], lo, scales=scales,
+        mask_block=mask_block, kv_len=kv_len)
     for at in nan_at:
         pool[:, 0 if at == "scratch" else tables[at]] = np.nan
     qdt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     out = np.asarray(ragged_paged_attention(
         jnp.asarray(q, qdt), jnp.asarray(pool, dtype), 1, blk_seq, qstart,
         pos0, tables, lo, kv_len,
-        scales=None if scales is None else jnp.asarray(scales))
-        .astype(jnp.float32))
+        scales=None if scales is None else jnp.asarray(scales),
+        mask_block=mask_block).astype(jnp.float32))
     got = np.stack([out[:, qstart[s] + i] for s, i in rows])
     return got, ref, out
 
@@ -194,9 +199,28 @@ class TestKernelParity:
         ([(1, 200), (12, 140)], {"dtype": "int8"}),   # per-head scales
         ([(1, 135), (9, 263)], {"heads": 20, "bs": 16}),  # gpt2-large
         ([(1, 135), (9, 263)], {"heads": 5, "bs": 16}),   # its mp=4 shard
+        # grouped-query heads (8 query heads a KV head, folded into the
+        # rows) under the block mask of 4: a denoising block, a commit
+        # beside the next block's rows, a block that kv_len cuts short,
+        # a prefill chunk of whole blocks
+        ([(4, 68), (8, 40), (1, 33), (44, 192)],
+         {"heads": 2, "q_heads": 16, "bs": 16, "mask_block": 4}),
+        ([(4, 68), (44, 192)],
+         {"heads": 2, "q_heads": 16, "bs": 16, "mask_block": 4,
+          "dtype": "bfloat16"}),
+        # grouped heads, causal mask; one head a group, block mask
+        ([(1, 135), (9, 263)], {"heads": 3, "q_heads": 6, "bs": 16}),
+        ([(4, 136), (12, 264)], {"bs": 16, "mask_block": 4}),
+        # Dh 128: K and V lanes are whole tiles, the products take each
+        # apart (sdar-30b-a3b's shape: 4 KV heads, 8 query heads each)
+        ([(4, 36), (1, 17)], {"heads": 4, "q_heads": 32, "bs": 16,
+                              "dh": 128, "mask_block": 4}),
+        ([(1, 40), (9, 30)], {"heads": 2, "bs": 16, "dh": 128}),
     ], ids=["under-one-group", "one-group", "one-past-a-group",
             "kv-len-mid-block", "table-fills-T", "decode-and-chunk",
-            "decode-and-chunk-bf16", "int8-per-head-scales", "H20", "H5"])
+            "decode-and-chunk-bf16", "int8-per-head-scales", "H20", "H5",
+            "gqa8-mask4", "gqa8-mask4-bf16", "gqa2-causal", "mha-mask4",
+            "gqa8-mask4-dh128", "mha-causal-dh128"])
     def test_grouped_walk_matches_oracle(self, seqs, kw):
         got, ref, _ = _walk_case(seqs, **kw)
         tol = {"bfloat16": 0.08, "int8": 2e-4}.get(kw.get("dtype"), 2e-5)

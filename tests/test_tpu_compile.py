@@ -116,6 +116,44 @@ def test_ragged_paged_attention_compiles_at_gpt2_large_widths(
     assert "ragged_paged_attention" in _compile(fn, *avals)
 
 
+@pytest.mark.parametrize("q_lens,q_bucket", [
+    ([4] * 128, 1024),                   # 128 blocks of 4: the plain launch
+    ([4] * 64 + [1000], 2048),           # blocks beside a prompt chunk
+], ids=["blocks-q1024", "blocks-and-chunk-q2048"])
+def test_ragged_paged_attention_compiles_for_grouped_heads_of_128(
+        v5e, q_lens, q_bucket):
+    """SDAR-30B-A3B's shape: 32 query heads on 4 KV heads of 128, the
+    block mask of 4. A KV head's 8 query heads fold into the rows (64 a
+    q block), K and V are whole lane tiles taken apart in the kernel, a
+    block is 32 KB and the walk still fetches 8 at a time."""
+    S, T, NB, hq, hkv, dh, bs = len(q_lens), 192, 64, 32, 4, 128, 16
+    assert kv_group_blocks(hkv, bs, dh, "bfloat16") * bs == 128
+    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, [20] * S,
+                                                q_bucket=q_bucket)
+    tables, lo = np.zeros((S, T), np.int32), np.zeros(S, np.int32)
+    kv_len = np.asarray([20 + n for n in q_lens], np.int32)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    def fn(q, pool):
+        return ragged_paged_attention(q, pool, 1, blk_seq, qstart, pos0,
+                                      tables, lo, kv_len, mask_block=4)
+
+    text = _compile(fn, sds((hq, q_bucket, dh), jnp.bfloat16),
+                    sds((2, NB + 1, hkv, bs, 2 * dh), jnp.bfloat16))
+    assert "ragged_paged_attention" in text
+
+
+def test_kv_append_compiles_for_four_kv_heads_of_128(v5e):
+    """The same model's cache write: 4 KV heads, 256 lanes a row."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    text = _compile(lambda pool, wb, off, rows:
+                    kv_append(pool, 1, wb, off, rows),
+                    sds((2, 65, 4, 16, 256), jnp.bfloat16),
+                    sds((1024,), jnp.int32), sds((1024,), jnp.int32),
+                    sds((1024, 4, 256), jnp.bfloat16))
+    assert "kv_append" in text
+
+
 def _append_avals(chip, heads, q_bucket, pool_dtype="bfloat16"):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     return [sds((2, 65, heads, 16, 2 * DH), pool_dtype),
