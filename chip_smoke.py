@@ -699,8 +699,9 @@ def phase_sdar_serve(model: dict, *, dtype: str, max_len: int,
     """SDAR-MoE (grouped-query heads in the ragged kernel, softmax-routed
     experts all held, generation by diffusion over blocks; ``model`` is
     the ``model`` group of a benchmark configuration, cut to a toy DEPTH)
-    through ``GenerationEngine``: chunked prefill, then denoising and
-    commit passes over the paged cache, then the plain reference
+    through ``GenerationEngine``: chunked prefill, then denoising passes
+    over the paged cache, a finished block's commit riding with the next
+    block's first pass, then the plain reference
     TEACHER-FORCED on the states the program saw
     (``benchmark/lib/reference_sdar.py``): every served token's logit
     against the reference's best at the pass that fixed it, and every
@@ -733,8 +734,10 @@ def phase_sdar_serve(model: dict, *, dtype: str, max_len: int,
     gc.collect()
     check(stats["nonfinite_cycles"] == 0, "no non-finite cycle")
     check(any(c.get("denoise_slots") for c in cycles)
-          and any(c.get("commit_slots") for c in cycles),
-          "denoising and commit passes reached the cycle record")
+          and any(c.get("ride_slots") for c in cycles)
+          and not any(c.get("commit_slots") for c in cycles),
+          "denoising passes and commits that rode with one (none alone) "
+          "reached the cycle record")
     check(any("moe_pairs" in c for c in cycles),
           "the routed layers' counters reached the cycle record")
     requests = []

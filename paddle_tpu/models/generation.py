@@ -776,20 +776,22 @@ def block_result_layout(num_slots: int, block_length: int, routed: bool):
             tokens_at + 2 * S * B)
 
 
-def _unmask(dec, x, seq_qstart, blk_tok, blk_pass, pass_idx, rule):
+def _unmask(dec, x, blk_row0, blk_tok, blk_pass, pass_idx, rule):
     """The last stage of a block-generation step, on the device: logits
-    on the B rows of every slot's block, argmax and its softmax
-    probability (the confidence) at every position, and for each slot in
-    a denoising pass (``pass_idx >= 0``) the ``fixed_per_pass`` most
-    confident UNFIXED positions take their argmax and are stamped with
-    the pass. The mask id is never chosen: its logit is out of the argmax
-    and of the softmax alike. Ties go to the lowest position. Returns
-    ``(blk_tok, blk_pass, fixed_pos [S], logits_bad)``."""
+    on the B rows of every slot's block (from row ``blk_row0[s]`` on: the
+    head never runs on the commit rows a riding slot holds before them),
+    argmax and its softmax probability (the confidence) at every
+    position, and for each slot in a denoising pass (``pass_idx >= 0``)
+    the ``fixed_per_pass`` most confident UNFIXED positions take their
+    argmax and are stamped with the pass. The mask id is never chosen:
+    its logit is out of the argmax and of the softmax alike. Ties go to
+    the lowest position. Returns ``(blk_tok, blk_pass, fixed_pos [S],
+    logits_bad)``."""
     import jax
     import jax.numpy as jnp
     S, B = blk_tok.shape
     with jax.named_scope(UNMASK_SCOPE):
-        rows = (seq_qstart[:, None]
+        rows = (blk_row0[:, None]
                 + jnp.arange(B, dtype=jnp.int32)[None, :]).reshape(-1)
         logits = dec.logits(Tensor(x._data[0, rows][:, None, :]))._data[
             :, 0].astype(jnp.float32)                     # [S * B, V]
@@ -820,18 +822,31 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
     current block, at the block's positions: the step embeds the block's
     state (fixed ids, the mask id elsewhere), appends the rows' K/V over
     the block's previous ones, runs the tower and :func:`_unmask`. A
-    commit pass and a prompt chunk are the same program: their slots say
-    ``pass_idx`` -1 and their state passes through.
+    prompt chunk is the same program: its slot says ``pass_idx`` -1 and
+    its state passes through.
+
+    So is the COMMIT of a finished block, which RIDES with the next
+    block's first denoising pass: the slot says ``ride`` 1 and holds 2 B
+    rows at consecutive positions — the finished block's, which show its
+    final tokens (their K/V, written under the block mask over the
+    committed text, are the ones the cache keeps), then the next
+    block's, all the mask id (the host fills them in: ``row_blk`` -1).
+    The state the slot names is the FINISHED block's; the one
+    :func:`_unmask` works on, B rows further down, and the result holds
+    is the next block's, all open before this pass. A finished block
+    whose slot holds its B rows only (``ride`` 0, ``pass_idx`` -1) is the
+    same ride with no next rows: a commit alone.
 
     ``fn(params, buffers, pool, token_ids, qpos, write_block, write_off,
     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len, prev_result,
     state_src [S], blk_tok [S, B], blk_pass [S, B], row_blk [Q],
-    pass_idx [S], key) -> (pool, result, key)``: ``row_blk`` names, for a
-    row of a block, ``slot * B + position in the block`` (-1: a chunk
-    row, which keeps its ``token_ids``); ``state_src[s] >= 0`` takes slot
-    ``s``'s block state from ``prev_result`` — the launch before this
-    one, UN-fetched — in place of ``blk_tok``/``blk_pass``; ``result`` is
-    laid out by :func:`block_result_layout`."""
+    pass_idx [S], ride [S], key) -> (pool, result, key)``: ``row_blk``
+    names, for a row that shows the slot's state, ``slot * B + position
+    in the block`` (-1: the row keeps its ``token_ids``);
+    ``state_src[s] >= 0`` takes slot ``s``'s block state from
+    ``prev_result`` — the launch before this one, UN-fetched — in place
+    of ``blk_tok``/``blk_pass``; ``result`` is laid out by
+    :func:`block_result_layout`."""
     import jax.numpy as jnp
 
     from ..framework import trace_probe as _probe
@@ -845,7 +860,7 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
 
     def fn(params, buffers, pool, token_ids, qpos, write_block, write_off,
            blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len, prev_result,
-           state_src, blk_tok, blk_pass, row_blk, pass_idx, key):
+           state_src, blk_tok, blk_pass, row_blk, pass_idx, ride, key):
         if probe is not None:  # runs at trace time only (jit caches)
             probe.record(_probe.sig_of([pool, token_ids, tables]),
                          {"q": Q, "table": T})
@@ -868,8 +883,14 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
                     dec, x, qpos, pool, None, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     False, 0.0)
+                # a riding slot's rows have shown its finished block;
+                # the block it denoises is the next, B rows down, all open
+                riding = (ride > 0)[:, None]
+                blk_tok = jnp.where(riding, 0, blk_tok)
+                blk_pass = jnp.where(riding, BLOCK_UNFIXED, blk_pass)
                 blk_tok, blk_pass, fixed_pos, bad = _unmask(
-                    dec, x, seq_qstart, blk_tok, blk_pass, pass_idx, rule)
+                    dec, x, seq_qstart + B * ride, blk_tok, blk_pass,
+                    pass_idx, rule)
                 parts = [fixed_pos, bad[None]]
                 if counters is not None:
                     parts.append(counters)

@@ -200,9 +200,13 @@ class SDARRoutedFFN(nn.Layer):
     def apply(self, x, valid):
         """``x [Q, E]`` -> ``(the held experts' part of the routed sum,
         counters)``. Every (row, expert) pair of a plain launch goes
-        through the grouped products in ONE trip: half the rows of a
-        launch are real at most (a block of 4 in a q block of 8), so
-        ``Q * k / 2`` pairs hold them all."""
+        through the grouped products in ONE trip, sized from the shapes:
+        a slot's q block of 8 holds its block of 4, or 8 rows where a
+        finished block's commit rides with the next block's first pass
+        (one pass in four of a slot: 5/8 of a steady launch's rows are
+        real), so three quarters of ``Q * k`` pairs hold them with room.
+        (``Q * k`` itself read 0.2 ms a launch slower on the chip: a
+        trip pays for its rows. PERF.md, PR 34.)"""
         import jax
         cfg = self.cfg
         k = cfg.num_experts_per_tok
@@ -213,7 +217,7 @@ class SDARRoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                max(MOE_PAIR_CHUNK, x.shape[0] * k // 2))
+                max(MOE_PAIR_CHUNK, x.shape[0] * k * 3 // 4))
         return y.astype(x.dtype), counters
 
 

@@ -906,7 +906,7 @@ class GenerationEngine:
             return head + (
                 self._no_prev, np.full(S, -1, np.int32), i32(S, B),
                 np.full((S, B), BLOCK_UNFIXED, np.int32),
-                np.full(Q, -1, np.int32), np.full(S, -1, np.int32),
+                np.full(Q, -1, np.int32), np.full(S, -1, np.int32), i32(S),
                 self._key)
         return head + (i32(S), self._no_prev, np.full(Q, -1, np.int32),
                        np.zeros(S, bool), np.ones(S, np.float32), self._key)
@@ -962,7 +962,11 @@ class GenerationEngine:
                     self._key)
             flavor, site = "spec", f"spec_verify[q{Q},t{T}]"
         else:
-            Q = self._q_bucket(S * BLOCK_Q)
+            # a slot's widest plan: one row, or a block's commit riding
+            # with the next block (2 B rows), in whole q blocks
+            B = self._decoder_spec.generation.block_length
+            rows = 2 * B if B > 1 else 1
+            Q = self._q_bucket(S * -(-rows // BLOCK_Q) * BLOCK_Q)
             if self._mesh is not None:
                 from ..models.generation import \
                     build_sharded_fused_step_fn
@@ -1088,7 +1092,13 @@ class GenerationEngine:
         block's state, which the step reads from the launch in flight
         (``from_prev``) or from the ``block`` operands returned last:
         ``(state_src [S], blk_tok [S, B], blk_pass [S, B], row_blk [Q],
-        pass_idx [S])`` (``models/generation.py _build_block_step_fn``)."""
+        pass_idx [S], ride [S])`` (``models/generation.py
+        _build_block_step_fn``). A slot whose block is finished and whose
+        plan is 2 B rows RIDES: the finished block's rows, which show its
+        final tokens (the commit), then the next block's at the B
+        positions after them, all the mask id, in its pass 0 — one q
+        block of the kernel where 2 B is ``BLOCK_Q``, ``kv_len`` to the
+        new block's end. With B rows it commits alone."""
         from ..ops.ragged_paged_attention import (BLOCK_Q, kv_group_blocks,
                                                   ragged_layout)
 
@@ -1117,7 +1127,11 @@ class GenerationEngine:
             if spec and slot in spec:
                 n_spec[slot] = n
                 row_tokens[slot] = [req.last_token]
-            elif slot in from_prev or (B > 1 and not req.pending_feed):
+            elif B > 1 and not req.pending_feed:
+                # the block's rows show its state (filled in on the
+                # device); rows past them open the next block, all masked
+                row_tokens[slot] = [0] * B + [gen.mask_token_id] * (n - B)
+            elif slot in from_prev:
                 row_tokens[slot] = []
             else:
                 row_tokens[slot] = (req.pending_feed[:n]
@@ -1140,6 +1154,7 @@ class GenerationEngine:
             blk_pass = np.full((S, B), BLOCK_UNFIXED, np.int32)
             row_blk = np.full(Q, -1, np.int32)
             pass_idx = np.full(S, -1, np.int32)
+            ride = np.zeros(S, np.int32)
             for slot, req in slot_requests.items():
                 if not q_lens[slot] or req.pending_feed:
                     continue
@@ -1147,11 +1162,13 @@ class GenerationEngine:
                 row_blk[r0:r0 + B] = slot * B + np.arange(B)
                 if not req.block_commits_next(gen):
                     pass_idx[slot] = req.block_pass
+                elif q_lens[slot] > B:
+                    ride[slot], pass_idx[slot] = 1, 0
                 if slot in from_prev:
                     state_src[slot] = slot
                 else:
                     blk_tok[slot], blk_pass[slot] = req.block_input(B)
-            block = (state_src, blk_tok, blk_pass, row_blk, pass_idx)
+            block = (state_src, blk_tok, blk_pass, row_blk, pass_idx, ride)
         else:
             for slot in from_prev:
                 token_src[int(qstart[slot])] = slot

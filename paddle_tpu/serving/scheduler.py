@@ -42,13 +42,22 @@ Generation by diffusion over blocks (a model whose decoder spec says
 ``block_length`` B > 1, ``models/decoder_spec.py``) runs through the SAME
 turn: a decode slot's rows are the B rows of its current block, a launch
 is one PASS over them — a denoising pass fixes positions and keeps
-nothing in the cache, so the pool's length stays; the commit pass that
-follows the last of them advances it by B — and the block's tokens are
-emitted together, in position order, when its last denoising pass lands.
-The schedule is static (``low_confidence_static``), so which pass a slot
-is in is host arithmetic (``GenerationRequest.block_pass``) and two
-launches stay in flight: what the next pass cannot know, the block's
-state, the step reads from the un-fetched result of the launch before.
+nothing in the cache, so the pool's length stays — and the block's
+tokens are emitted together, in position order, when its last denoising
+pass lands. The COMMIT of a finished block — its rows once more with
+their final tokens, whose K/V the cache keeps; the pool's length moves by
+B — RIDES with the next block's first denoising pass: the slot gets 2 B
+rows in that launch, the finished block's and then the next block's, so
+a block of B costs its slot as many launches as it has denoising passes
+and no launch is spent on rows nobody reads. A request's last block
+takes no commit at all; a commit alone (B rows, no token) is the same
+ride for a slot whose plan holds no rows of a next block, which
+``_chunk_plan`` never hands out. The schedule is static
+(``low_confidence_static``), so which pass a slot is in is host
+arithmetic (``GenerationRequest.block_pass``) and two launches stay in
+flight: what the next pass cannot know, the block's state — for a ride
+the finished block's final tokens — the step reads from the un-fetched
+result of the launch before.
 
 Backpressure is explicit: a full queue raises :class:`QueueFullError`
 in ``submit`` (the caller sheds load, nothing queues unboundedly), and
@@ -135,6 +144,7 @@ class RequestCancelled(RuntimeError):
 
 
 _DONE = object()          # stream terminator sentinel
+_NO_PASS = (-1, 0, False)  # a slot a launch gave no pass of a block
 
 
 def _fetch(device_array):
@@ -290,9 +300,9 @@ class GenerationRequest:
         return tok, fixed
 
     def block_commits_next(self, rule) -> bool:
-        """Whether the block's next pass is its commit: every denoising
-        pass the positions the prompt did not give take
-        (``GenerationRule.passes``) has been dispatched."""
+        """Whether the block is finished and its commit is what comes
+        next: every denoising pass the positions the prompt did not give
+        take (``GenerationRule.passes``) has been dispatched."""
         return self.block_pass == rule.passes(
             rule.block_length - len(self.block_given))
 
@@ -1118,8 +1128,10 @@ class Scheduler:
         slots split the prefill TOKEN budget FCFS by request age; a slot
         whose share hits 0 simply waits a launch (its blocks are already
         reserved). Under block generation a decode slot's rows are the B
-        rows of its block, every pass of it, and a chunk ends on a block
-        boundary (the feed is whole blocks: ``engine._run_admit``)."""
+        rows of its block, every pass of it — 2 B where the block is
+        finished: its commit rides with the next block's first pass
+        (module doc) — and a chunk ends on a block boundary (the feed is
+        whole blocks: ``engine._run_admit``)."""
         budget = self._prefill_budget
         B = self._block
         plan: Dict[int, int] = {}
@@ -1133,7 +1145,8 @@ class Scheduler:
                 if n > 0:
                     plan[slot] = n
             elif req.emitted + req.in_flight < req.max_new_tokens:
-                plan[slot] = B
+                plan[slot] = 2 * B if B > 1 and req.block_commits_next(
+                    self._gen) else B
         return plan
 
     def _prepare_chunked(self, plan: Dict[int, int]) -> Dict[int, int]:
@@ -1263,7 +1276,7 @@ class Scheduler:
             from_prev = set() if prev is None else {
                 s for s, r in active.items()
                 if r.block_pass and prev["active"].get(s) is r
-                and prev["passes"].get(s, (-1, 0))[0] >= 0}
+                and prev["passes"].get(s, _NO_PASS)[0] >= 0}
         else:
             from_prev = {s for s, r in active.items() if r.in_flight}
         rec["overlapped"] = prev is not None
@@ -1287,11 +1300,13 @@ class Scheduler:
                     (prev["toks"], from_prev) if from_prev else None)
             fed: Dict[int, int] = {}    # slot -> feed left after its chunk
             # block generation: slot -> (its denoising pass, or -1 for a
-            # commit; the tokens its landing emits)
-            passes: Dict[int, Tuple[int, int]] = {}
+            # commit alone; the tokens its landing emits; whether a
+            # commit rode with the pass)
+            passes: Dict[int, Tuple[int, int, bool]] = {}
             for slot, req in active.items():
                 if self._block > 1 and not req.pending_feed:
-                    passes[slot] = self._advance_block(slot, req)
+                    passes[slot] = self._advance_block(slot, req,
+                                                       plan[slot])
                     continue
                 self._pool.advance(slot, plan[slot])
                 if req.pending_feed:
@@ -1304,25 +1319,31 @@ class Scheduler:
                 "plan": plan, "spec": spec, "fed": fed, "toks": toks_dev,
                 "passes": passes, "t": t1}
 
-    def _advance_block(self, slot: int,
-                       req: GenerationRequest) -> Tuple[int, int]:
+    def _advance_block(self, slot: int, req: GenerationRequest,
+                       rows: int) -> Tuple[int, int, bool]:
         """What the host knows of a block-generation decode slot once the
-        pass it rides is dispatched. A denoising pass ``k`` leaves the
-        pool's length alone (its K/V are not kept: the block's rows stay
-        writable and the next pass rewrites them); the LAST one of a
-        block raises ``in_flight`` by the tokens the block emits — the
-        positions the prompt did not give, short of ``max_new_tokens``
-        (the surplus of a last block is denoised and dropped). The commit
-        pass that follows advances the pool by the block and opens the
-        next one, all masked. A request's last block takes no commit: the
-        plan gives it no row once its tokens are all in flight, and
-        nobody reads that block's K/V. Returns ``(k, tokens its landing
-        emits)``, ``k`` -1 for the commit."""
+        launch that holds its ``rows`` is dispatched. A denoising pass
+        ``k`` leaves the pool's length alone (its K/V are not kept: the
+        block's rows stay writable and the next pass rewrites them); the
+        LAST one of a block raises ``in_flight`` by the tokens the block
+        emits — the positions the prompt did not give, short of
+        ``max_new_tokens`` (the surplus of a last block is denoised and
+        dropped). The launch after it holds the finished block's rows
+        once more, the COMMIT: the pool advances by the block and the
+        next one opens, all masked — and the rows past the first B are
+        that next block in its pass 0, the ride, so the slot is in pass 1
+        of the NEW block from here on. With B rows only the commit is
+        alone. A request's last block takes no commit: the plan gives it
+        no row once its tokens are all in flight, and nobody reads that
+        block's K/V. Returns ``(k, tokens its landing emits, whether a
+        commit rode with it)``, ``k`` -1 for a commit alone."""
         B = self._block
-        if req.block_commits_next(self._gen):
+        rode = req.block_commits_next(self._gen)
+        if rode:
             self._pool.advance(slot, B)
             req.block_pass, req.block_given = 0, []
-            return -1, 0
+            if rows <= B:
+                return -1, 0, False
         k = req.block_pass
         req.block_pass = k + 1
         emits = 0
@@ -1330,7 +1351,7 @@ class Scheduler:
             emits = min(B - len(req.block_given),
                         req.max_new_tokens - req.emitted - req.in_flight)
             req.in_flight += emits
-        return k, emits
+        return k, emits, rode
 
     def _drain(self) -> bool:
         """Land the launch in flight, if any: the pipeline is empty
@@ -1390,7 +1411,8 @@ class Scheduler:
         fed, passes = launch["fed"], launch["passes"]
         B = self._block
         if B > 1:
-            rec.update(denoise_slots=0, commit_slots=0, tokens_fixed=0)
+            rec.update(denoise_slots=0, commit_slots=0, ride_slots=0,
+                       tokens_fixed=0)
         S = self._pool.num_slots
         K = self._spec_k
         if spec:
@@ -1452,7 +1474,7 @@ class Scheduler:
                 stat_add("serving/spec_accept", a)
                 req.trace.mark("spec_verify", proposed=n, accepted=a)
             if B > 1:
-                req.in_flight -= passes.get(slot, (-1, 0))[1]
+                req.in_flight -= passes.get(slot, _NO_PASS)[1]
             elif not fed.get(slot):
                 req.in_flight -= 1      # this launch's token lands now
             if req.cancelled:
@@ -1530,15 +1552,17 @@ class Scheduler:
             stat_observe("serving/tokens_per_sec", emitted / dt)
 
     def _land_block_pass(self, slot: int, req: GenerationRequest,
-                         pass_emits: Tuple[int, int], toks, rec) -> int:
+                         landed: Tuple[int, int, bool], toks, rec) -> int:
         """The host half of one slot's pass of a block: keep the block's
         state as the pass left it (the next pass takes it from here if
-        the pipeline is drained by then), count the pass, and if it was
-        the block's last denoising pass emit the block — the positions
-        the passes fixed, in position order, each with the pass that
-        fixed it — retiring the request where it ends. Returns the
-        tokens emitted."""
-        k, emits = pass_emits
+        the pipeline is drained by then; after a ride it is the NEW
+        block's), count the pass — a riding slot-pass once, as a
+        denoising pass, and in ``ride_slots``; ``commit_slots`` counts
+        commits that rode alone — and if it was the block's last
+        denoising pass emit the block — the positions the passes fixed,
+        in position order, each with the pass that fixed it — retiring
+        the request where it ends. Returns the tokens emitted."""
+        k, emits, rode = landed
         if k < 0:
             rec["commit_slots"] += 1
             stat_add("serving/commit_passes")
@@ -1546,6 +1570,9 @@ class Scheduler:
         S, B = self._pool.num_slots, self._block
         rec["denoise_slots"] += 1
         stat_add("serving/denoise_passes")
+        if rode:
+            rec["ride_slots"] += 1
+            stat_add("serving/commit_rides")
         # the block state closes the step's result: [S * B] token ids,
         # [S * B] the pass each position was fixed in
         # (models/generation.py block_result_layout)

@@ -57,6 +57,12 @@ def make(model):
     return F.Weights(SEED, model, "float32")
 
 
+def _blocks(p, n):
+    """Blocks of 4 a request of ``p`` prompt tokens and ``n`` new ones
+    denoises: all but the last are committed."""
+    return -(-(p + n) // 4) - p // 4
+
+
 def _ids(rows, length, seed=0):
     return np.random.default_rng(seed).integers(
         1, 250, size=(rows, length)).astype(np.int32)
@@ -179,10 +185,32 @@ def _serial(monkeypatch):
                         lambda self, cold=False: real(self, True))
 
 
+def _spy_dispatches(eng):
+    """Record every launch the scheduler dispatches: ``[(plan, the slots
+    whose state the step is told to take from the launch in flight)]``."""
+    seen, real = [], eng._sched._do_chunked
+
+    def spy(active, plan, prev):
+        seen.append((dict(plan), set() if prev is None else set(prev[1])))
+        return real(active, plan, prev)
+
+    eng._sched._do_chunked = spy
+    return seen
+
+
+def _launches(eng):
+    """Close the engine and return its launches' records: the last one
+    enters the ring at the END of the turn that woke the client."""
+    eng.close()
+    jax.effects_barrier()
+    return [c for c in eng.flight_recorder.snapshot()["cycles"]
+            if c.get("launch_q")]
+
+
 def _served(net, prompt, n, **kw):
     """One request through an engine of its own with the head's output of
     every launch recorded: ``(tokens, passes, [logits of the block's rows
-    a denoising launch], cycle records)``."""
+    a denoising launch], cycle records, dispatches)``."""
     seen = []
     real = net.logits
 
@@ -196,18 +224,16 @@ def _served(net, prompt, n, **kw):
         kw = dict(dict(num_slots=1, max_len=64, block_size=8,
                        prefill_budget=12), **kw)
         eng = GenerationEngine(net, **kw)
+        dispatches = _spy_dispatches(eng)
         h = eng.submit(prompt, n)
         toks = [int(t) for t in h.stream()]
-        eng.close()       # the last launch's record enters the ring at
-        jax.effects_barrier()   # the END of the turn that retired h
-        cycles = [c for c in eng.flight_recorder.snapshot()["cycles"]
-                  if c.get("launch_q")]
+        cycles = _launches(eng)
     finally:
         del net.logits
-    assert len(seen) == len(cycles)
+    assert len(seen) == len(cycles) == len(dispatches)
     denoise = [lg[:, 0] for lg, c in zip(seen, cycles)
                if c.get("denoise_slots")]
-    return toks, list(h.trace.token_passes), denoise, cycles
+    return toks, list(h.trace.token_passes), denoise, cycles, dispatches
 
 
 @pytest.mark.parametrize("in_flight", [1, 2])
@@ -221,11 +247,14 @@ def test_every_pass_through_the_paged_cache_is_the_references(
     8, outputs that are and are not multiples of 4: the logits of every
     denoising pass, the tokens and the pass each was fixed in equal the
     reference's generation loop — with one and with two launches in
-    flight."""
+    flight. A block costs its slot one launch a denoising pass: its
+    commit rides with the next block's first pass, and with two launches
+    in flight the ride reads the finished block's tokens from the
+    un-fetched result of the launch before it."""
     if in_flight == 1:
         _serial(monkeypatch)
     prompt = _ids(1, p, seed=p)[0].tolist()
-    toks, passes, logits, cycles = _served(net, prompt, n)
+    toks, passes, logits, cycles, dispatches = _served(net, prompt, n)
     want = R.generate(make, model, prompt, n)
     assert toks == want["tokens"] and passes == want["passes"]
     assert model["mask_token_id"] not in toks
@@ -236,12 +265,35 @@ def test_every_pass_through_the_paged_cache_is_the_references(
         np.testing.assert_allclose(got, ref, atol=ORDER_OF_SUM)
     overlapped = [c["overlapped"] for c in cycles]
     assert any(overlapped) == (in_flight == 2)
-    # the launches' counters: a pass fixes one position, a block of four
-    # takes a commit unless it is the request's last
-    assert sum(c.get("tokens_fixed", 0) for c in cycles) == len(want["logits"])
-    blocks = -(-(p + n) // 4) - p // 4
-    assert sum(c.get("commit_slots", 0) for c in cycles) == blocks - 1
+    # the launches' counters: a pass fixes one position; a block of four
+    # takes FOUR launches of its slot (less the passes the prompt's
+    # leftover tokens save), a request of n blocks 4 n; every block but
+    # the last is committed, by a ride and never alone
+    n_passes = len(want["logits"])
+    blocks = _blocks(p, n)
+    assert n_passes == 4 * blocks - p % 4
+    assert sum(c.get("tokens_fixed", 0) for c in cycles) == n_passes
+    decode = [c for c in cycles if not c.get("chunk_tokens")]
+    assert len(decode) == n_passes
+    assert all(c["denoise_slots"] == 1 and c["commit_slots"] == 0
+               for c in decode)
+    rides = [c for c in decode if c["ride_slots"]]
+    assert len(rides) == blocks - 1
+    assert all(c["launch_rows"] == (8 if c["ride_slots"] else 4)
+               for c in decode)
     assert all(c["moe_rows"] == 2 * c["launch_rows"] for c in cycles)
+    # ... and the last block takes no commit: nothing follows its passes
+    assert not decode[-1]["ride_slots"] and decode[-1]["emitted"]
+    # where the finished block's tokens came from: the un-fetched result
+    # of the launch in flight, whenever there is one (the launch that
+    # opens a busy stretch lands in its own turn, so from the third on)
+    ride_src = [(src, c) for (plan, src), c in zip(dispatches, cycles)
+                if plan.get(0) == 8]
+    assert [c for _, c in ride_src] == rides
+    assert all(src == ({0} if c["overlapped"] else set())
+               for src, c in ride_src)
+    assert all(c["overlapped"] == (in_flight == 2)
+               for c in rides if c["cycle"] > cycles[1]["cycle"])
 
 
 def test_two_fixed_a_pass_follows_the_references_loop(make, monkeypatch):
@@ -275,7 +327,7 @@ def test_a_batch_of_mixed_requests_agrees_and_the_trie_holds_prompts_only(
     handles = [eng.submit(p, n) for p, n in reqs]
     outs = [[int(t) for t in h.stream()] for h in handles]
     keys = list(eng._pool._trie)
-    eng.close()
+    cycles = _launches(eng)
     for (p, n), h, got in zip(reqs, handles, outs):
         want = R.generate(make, model, p, n)
         assert got == want["tokens"]
@@ -283,6 +335,47 @@ def test_a_batch_of_mixed_requests_agrees_and_the_trie_holds_prompts_only(
     assert keys and all(len(k) % 8 == 0 for k in keys)
     prompts = [tuple(p) for p, _ in reqs]
     assert all(any(k == p[:len(k)] for p in prompts) for k in keys)
+    # the records add up: a pass fixes a position, so the slot-passes are
+    # the positions the prompts left open; all are emitted but the
+    # surplus of the last blocks; a ride is counted once, as a denoising
+    # pass, and stands for every block but a request's last
+    total = lambda key: sum(c.get(key, 0) for c in cycles)
+    opened = sum(-(-(len(p) + n) // 4) * 4 - len(p) for p, n in reqs)
+    surplus = sum(-(len(p) + n) % 4 for p, n in reqs)
+    assert total("denoise_slots") == total("tokens_fixed") == opened
+    assert total("emitted") == sum(n for _, n in reqs) == opened - surplus
+    assert total("ride_slots") == sum(_blocks(len(p), n) - 1 for p, n in reqs)
+    assert total("commit_slots") == 0 and total("late_rows") == 0
+    assert all(c["ride_slots"] <= c["denoise_slots"] for c in cycles
+               if "denoise_slots" in c)
+
+
+def test_a_commit_with_no_next_rows_in_its_launch_rides_alone(
+        net, make, model, monkeypatch):
+    """The degenerate ride: were a finished block's slot handed its B rows
+    only, the launch commits alone (no token, ``commit_slots``), the next
+    block opens a launch later and the text is still the reference's —
+    the same operands and the same step, five launches a block."""
+    real = scheduler.Scheduler._chunk_plan
+    monkeypatch.setattr(
+        scheduler.Scheduler, "_chunk_plan",
+        lambda self: {s: min(n, 4) if not self._slots[s].pending_feed else n
+                      for s, n in real(self).items()})
+    reqs = [(_ids(1, p, seed=p)[0].tolist(), n) for p, n in [(13, 9), (6, 8)]]
+    eng = GenerationEngine(net, num_slots=2, max_len=64, block_size=8,
+                           prefill_budget=12)
+    handles = [eng.submit(p, n) for p, n in reqs]
+    outs = [[int(t) for t in h.stream()] for h in handles]
+    cycles = _launches(eng)
+    for (p, n), h, got in zip(reqs, handles, outs):
+        want = R.generate(make, model, p, n)
+        assert got == want["tokens"]
+        assert list(h.trace.token_passes) == want["passes"]
+    total = lambda key: sum(c.get(key, 0) for c in cycles)
+    assert total("ride_slots") == 0
+    assert total("commit_slots") == 4 == sum(_blocks(len(p), n) - 1
+                                             for p, n in reqs)
+    assert total("emitted") == 17
 
 
 def test_a_request_preempted_inside_a_block_resumes_and_still_agrees(
@@ -304,6 +397,113 @@ def test_a_request_preempted_inside_a_block_resumes_and_still_agrees(
         want = R.generate(make, model, p, 22)
         assert o == want["tokens"]
         assert list(h.trace.token_passes) == want["passes"]
+    assert eng._pool.blocks_in_use == 0
+
+
+def test_a_request_preempted_at_a_ride_resumes_and_still_agrees(
+        net, make, model, monkeypatch):
+    """The younger of two requests is preempted in the turn that planned
+    its ride (its block finished and emitted, not committed): the pipeline
+    is drained, the request fed again from its emitted tokens, and both
+    texts and orders stay the reference's."""
+    real = scheduler.Scheduler._prepare_chunked
+    state = {}
+
+    def prepare(self, plan):
+        young = max(self._slots, key=lambda s: self._slots[s].id)
+        req = self._slots[young]
+        if not state and len(self._slots) == 2 and plan.get(young) == 8 \
+                and not req.pending_feed:
+            self._drain()
+            state.update(emitted=req.emitted, passes=req.block_pass)
+            self._preempt_youngest()
+            plan = {s: n for s, n in plan.items() if s in self._slots}
+        return real(self, plan)
+
+    monkeypatch.setattr(scheduler.Scheduler, "_prepare_chunked", prepare)
+    pa, pb = _ids(1, 6, seed=71)[0].tolist(), _ids(1, 9, seed=72)[0].tolist()
+    eng = GenerationEngine(net, num_slots=2, max_len=48, block_size=8,
+                           prefill_budget=16)
+    ha, hb = eng.submit(pa, 14), eng.submit(pb, 13)
+    oa = [int(t) for t in ha.stream()]
+    ob = [int(t) for t in hb.stream()]
+    assert eng.stats()["preempts"] == 1
+    cycles = _launches(eng)
+    # preempted with its first block (positions 9-11) whole and emitted
+    assert state == {"emitted": 3, "passes": 3}
+    for p, o, h in ((pa, oa, ha), (pb, ob, hb)):
+        want = R.generate(make, model, p, len(o))
+        assert o == want["tokens"]
+        assert list(h.trace.token_passes) == want["passes"]
+    assert (len(oa), len(ob)) == (14, 13)
+    assert eng._pool.blocks_in_use == 0
+    assert sum(c.get("commit_slots", 0) for c in cycles) == 0
+
+
+@pytest.mark.parametrize("in_flight", [1, 2])
+def test_a_request_cancelled_at_a_ride_retires_and_its_rows_are_late(
+        net, make, model, monkeypatch, in_flight):
+    """``cancel()`` as the ride is dispatched. With two launches in flight
+    the launch before it lands afterwards, finds the cancel and retires
+    the request (the finished block is not emitted); the ride's 2 B rows
+    land for a request that has ended: late rows, dropped and counted,
+    and the slot serves the next request. With one launch in flight the
+    ride itself lands the cancel, and nothing is late."""
+    if in_flight == 1:
+        _serial(monkeypatch)
+    eng = GenerationEngine(net, num_slots=1, max_len=48, block_size=8)
+    real = eng._sched._do_chunked
+    prompt = _ids(1, 8, seed=81)[0].tolist()
+
+    def cancelling(active, plan, prev):
+        if plan.get(0) == 8 and not active[0].pending_feed:
+            active[0].cancel()
+        return real(active, plan, prev)
+
+    eng._sched._do_chunked = cancelling
+    h = eng.submit(prompt, 12)
+    with pytest.raises(scheduler.RequestCancelled):
+        got = []
+        for t in h.stream():
+            got.append(int(t))
+    eng._sched._do_chunked = real
+    again = [int(t) for t in eng.submit(prompt, 12).stream()]
+    cycles = _launches(eng)
+    want = R.generate(make, model, prompt, 12)["tokens"]
+    # one launch in flight: the block was emitted a turn before the ride
+    assert got == ([] if in_flight == 2 else want[:4])
+    assert again == want
+    assert sum(c["late_rows"] for c in cycles) == (8 if in_flight == 2 else 0)
+    assert eng._sched.late_rows == (8 if in_flight == 2 else 0)
+    assert eng._pool.blocks_in_use == 0
+
+
+@pytest.mark.parametrize("in_flight", [1, 2])
+def test_a_request_that_ends_inside_a_block_takes_no_ride_after_it(
+        net, make, model, monkeypatch, in_flight):
+    """``max_new_tokens`` ends inside the second block: its surplus is
+    denoised and dropped, no commit and no ride follow it, nothing is
+    late. An EOS in the first block is learned a launch late: with two
+    launches in flight the ride behind that block has been dispatched and
+    its 2 B rows are late rows."""
+    if in_flight == 1:
+        _serial(monkeypatch)
+    prompt = _ids(1, 12, seed=91)[0].tolist()
+    want = R.generate(make, model, prompt, 10)["tokens"]
+    eng = GenerationEngine(net, num_slots=1, max_len=48, block_size=8)
+    toks = [int(t) for t in eng.submit(prompt, 6).stream()]
+    ended = eng._sched.late_rows
+    at = max(i for i in range(4) if want[i] not in want[:i])
+    eos = [int(t) for t in eng.submit(prompt, 10,
+                                      eos_token_id=want[at]).stream()]
+    cycles = _launches(eng)
+    assert toks == want[:6] and ended == 0
+    assert eos == want[:at + 1]
+    first = [c for c in cycles if not c.get("chunk_tokens")][:8]
+    assert [c["ride_slots"] for c in first] == [0, 0, 0, 0, 1, 0, 0, 0]
+    assert [c["emitted"] for c in first] == [0, 0, 0, 4, 0, 0, 0, 2]
+    assert sum(c["tokens_fixed"] for c in first) == 8
+    assert eng._sched.late_rows == (8 if in_flight == 2 else 0)
     assert eng._pool.blocks_in_use == 0
 
 
