@@ -759,6 +759,83 @@ def phase_sdar_serve(model: dict, *, dtype: str, max_len: int,
     return numbers
 
 
+def phase_mimo_serve(model: dict, *, dtype: str, max_len: int,
+                     block_size: int, num_slots: int, num_blocks: int,
+                     prefill_budget: int, prompt_lens, new_tokens: int,
+                     limits: dict, width: int, q_block: int) -> dict:
+    """MiMo-V2-Flash (window and global layers in two cache groups, a
+    sliding window with sink logits and K 192 | V 128 lanes in the ragged
+    kernel, a bias-corrected top-k; ``model`` is the ``model`` group of a
+    benchmark configuration, cut to a toy DEPTH) through
+    ``GenerationEngine``: chunked prefill and decode over contexts longer
+    than two windows, so the window group frees blocks behind every slot
+    and the windowed walk starts past them — a freed block read on the
+    real kernel would show in the gaps — then every served token's logit
+    against the plain reference's best
+    (``benchmark/lib/reference_mimo.py``), held to the configuration's
+    own limits."""
+    import numpy as np
+
+    from benchmark.lib import correct as C
+    from benchmark.lib import family_mimo as F
+    from benchmark.lib import reference_mimo as R
+    from paddle_tpu.serving import GenerationEngine
+
+    seed = 2 ** 31 + 35
+    net = F.build_lm(model, seed, dtype)
+    rng = np.random.RandomState(35)
+    prompts = [rng.randint(1, int(model["vocab_size"]), size=n).tolist()
+               for n in prompt_lens]
+    before = site_names()
+    with GenerationEngine(net, block_size=block_size, max_len=max_len,
+                          num_slots=num_slots, num_blocks=num_blocks,
+                          prefill_budget=prefill_budget) as engine:
+        handles = [engine.submit(p, new_tokens) for p in prompts]
+        served = [[int(t) for t in h.stream()] for h in handles]
+        stats = engine.stats()
+        cycles = engine.flight_recorder.snapshot()["cycles"]
+        text = step_text_report(
+            sites_since(before, "serving/fused["),
+            ("ragged_paged_attention", "ragged_paged_attention_window",
+             "kv_append"))
+    log(f"mimo steps: {text}")
+    del net, engine
+    gc.collect()
+    check(stats["nonfinite_cycles"] == 0, "no non-finite cycle")
+    groups = stats["cache_groups"]
+    log(f"mimo cache groups: {groups}; window blocks freed "
+        f"{stats['window_blocks_freed']}")
+    check(len(groups) == 2 and groups[0]["window"] == 0
+          and groups[1]["window"] == int(model["sliding_window"]),
+          "a global and a window cache group")
+    window = int(model["sliding_window"])
+    check(max(len(p) for p in prompts) + new_tokens > 2 * window
+          and stats["window_blocks_freed"] > 0,
+          f"a context longer than two windows freed "
+          f"{stats['window_blocks_freed']} blocks behind the window")
+    check(any("kv_tokens_window" in c and "kv_live_bytes" in c
+              for c in cycles) and any("moe_pairs" in c for c in cycles),
+          "the window's and the routed layers' counters reached the cycle "
+          "record")
+    B, n = len(prompts), new_tokens
+    ids = np.zeros((B, width), np.int32)
+    pos = np.zeros((B, n), np.int32)
+    for b, (p, o) in enumerate(zip(prompts, served)):
+        check(len(o) == n, f"request {b} is whole ({len(o)} of {n} tokens)")
+        ids[b, :len(p) + n] = p + o
+        pos[b] = len(p) - 1 + np.arange(n)
+    out = R.served_margins(F.Weights(seed, model, dtype), model, ids, pos,
+                           np.asarray(served, np.int32), rows_per_call=B,
+                           q_block=q_block)
+    numbers = C.gap_summary((out["gap"] / out["std"]).reshape(-1))
+    log(f"mimo served tokens against the reference: {numbers}, limits "
+        f"{limits}")
+    for name, limit in limits.items():
+        check(numbers[name] <= limit,
+              f"mimo {name} {numbers[name]:.5f} within {limit}")
+    return numbers
+
+
 def _pool_array(cfg, block_size: int, num_blocks: int, sharded: bool):
     """The engine's block pool, found among jax's live arrays by its
     shape ``[L, NB + 1, H, block_size, 2 * Dh]`` (the engine does not
@@ -1044,6 +1121,19 @@ def main(argv=None) -> int:
                 prompt_lens=(41, 702, 1303), new_tokens=30,
                 limits=sdar["serving"]["check"]["limits"], width=1536,
                 states=64, q_block=512)
+            # the dense global layer and two window expert layers: both
+            # cache groups, contexts of up to six windows
+            with open(os.path.join(here, "benchmark", "configs",
+                                   "mimo-v2-flash-ep16.json")) as f:
+                mimo = json.load(f)
+            phase_mimo_serve(
+                dict(mimo["model"], num_hidden_layers=3),
+                dtype=mimo["serving"]["dtype"], max_len=2048,
+                block_size=int(mimo["serving"]["block_size"]), num_slots=8,
+                num_blocks=1024, prefill_budget=512,
+                prompt_lens=(40, 300, 700), new_tokens=24,
+                limits=mimo["serving"]["check"]["limits"], width=1024,
+                q_block=512)
     n, secs = compile_totals()
     hits, misses = CACHE_EVENTS.values()
     log(f"all phases passed: {n} compiles taking {secs:.1f} s in this "

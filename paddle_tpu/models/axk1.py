@@ -228,11 +228,15 @@ def _swiglu(x, gate, up, down):
 # ---------------------------------------------------------------------------
 
 def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True,
-                scoring: str = "sigmoid"):
+                scoring: str = "sigmoid", select_bias=None):
     """Scores, choice and weights of the router: ``x [Q, E]``, ``router_w
     [n_experts, E]`` -> ``(idx [Q, k] int32, w [Q, k] float32, scores [Q,
     n_experts] float32)``. ``scoring`` is ``"sigmoid"`` (A.X-K1) or
-    ``"softmax"`` over all experts (SDAR). The scores are float32:
+    ``"softmax"`` over all experts (SDAR). ``select_bias [n_experts]``
+    (``topk_method`` ``noaux_tc``, MiMo-V2-Flash): the choice is the top-k
+    of ``scores + select_bias``, the weights are the chosen experts'
+    SCORES (None: the choice is the top-k of the scores, as it was). The
+    scores are float32:
     activations and router weights are exact in bfloat16, every product
     of two of them is exact in float32, and the MXU accumulates in
     float32, so this IS the float32 score up to the order of the sum."""
@@ -245,7 +249,12 @@ def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True,
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"router scoring {scoring!r}: sigmoid or softmax")
-    top, idx = jax.lax.top_k(scores, int(top_k))
+    if select_bias is None:
+        top, idx = jax.lax.top_k(scores, int(top_k))
+    else:
+        _, idx = jax.lax.top_k(
+            scores + select_bias.astype(jnp.float32)[None, :], int(top_k))
+        top = jnp.take_along_axis(scores, idx, axis=-1)
     w = top / jnp.sum(top, axis=-1, keepdims=True) if norm else top
     return idx.astype(jnp.int32), w * jnp.float32(scale), scores
 

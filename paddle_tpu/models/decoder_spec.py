@@ -8,8 +8,8 @@ served by the fused paged path if ``serving_decoder()`` returns an
 object with
 
 * ``spec`` — a :class:`DecoderSpec`: per layer the attention kind with
-  its cache descriptor and the FFN kind, plus the vocabulary and the
-  most positions the model takes;
+  its cache descriptor, its window and its sinks, and the FFN kind, plus
+  the vocabulary and the most positions the model takes;
 * ``embed_tokens(token_ids, positions)`` -> ``Tensor [1, Q, E]`` (positions
   that the model adds at the embedding, as GPT does, are added here;
   a rotary model ignores them here and reads them in ``attn_in``);
@@ -22,9 +22,21 @@ object with
   or the routed layer's three int32 scalars);
 * ``final_norm(x)`` and ``logits(hidden)``.
 
-Two attention kinds, two FFN kinds, one generation rule, three callers
-(``models/gpt.py``, ``models/axk1.py``, ``models/sdar.py``). Nothing else
-is described here.
+Two attention kinds, two FFN kinds, one generation rule, four callers
+(``models/gpt.py``, ``models/axk1.py``, ``models/sdar.py``,
+``models/mimo.py``). Nothing else is described here.
+
+**Cache groups.** The layers of one model need not share a cache
+descriptor: the spec derives its CACHE GROUPS — the layers with equal
+``(attention, cache, window)``, in order of first appearance — and the
+paged pool holds one block array and one page table a request FOR EACH
+(``serving/paging.py``). A layer's ``window`` W > 0 says its rows see the
+last W positions only (a row at ``p`` sees ``[p - W + 1, p]``): the
+group's blocks wholly behind the window are freed as a sequence moves
+on. ``sinks`` says the layer's softmax has a learned logit a query head
+in its denominator (the layer object's ``sinks`` attribute holds the
+``[H]`` float32 array). GPT-2, A.X-K1 and SDAR have one group, window 0
+and no sinks.
 
 **KV heads apart from query heads.** ``CacheSpec.rows`` is the number of
 KV heads a token leaves in a ``full`` cache; ``attn_in`` may return more
@@ -45,10 +57,12 @@ runs the block's final tokens, whose K/V the cache keeps.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import cached_property
 from typing import Optional, Tuple
 
 __all__ = ["CacheSpec", "LayerSpec", "DecoderSpec", "GenerationRule",
-           "serving_decoder", "FULL", "LATENT", "DENSE", "ROUTED"]
+           "CacheGroup", "serving_decoder", "FULL", "LATENT", "DENSE",
+           "ROUTED"]
 
 FULL, LATENT = "full", "latent"        # attention kinds
 DENSE, ROUTED = "dense", "routed"      # FFN kinds
@@ -58,13 +72,34 @@ DENSE, ROUTED = "dense", "routed"      # FFN kinds
 class CacheSpec:
     """What one token holds in one layer's paged cache: ``rows`` rows of
     ``lanes`` values (a block of the pool is ``[rows, block_size,
-    lanes]``). ``full``: one row a head, K in lanes ``[0, Dh)`` and V in
-    ``[Dh, 2 * Dh)``. ``latent``: ONE row for every head, and V is the
-    first ``v_lanes`` lanes of the same stored row (``v_aliases_k``)."""
+    lanes]``). ``full``: one row a KV head, K in the FIRST ``k_lanes``
+    lanes and V in the LAST ``v_lanes`` (both 0: ``lanes / 2`` each, K in
+    ``[0, Dh)`` and V in ``[Dh, 2 * Dh)``; stated apart where a K head is
+    wider than a V head, and ``lanes`` may then hold padding between the
+    two so that each side is whole 128-lane tiles: 192 | 128 is stored
+    384 wide, V from lane 256). ``latent``: ONE row for every head, and V
+    is the first ``v_lanes`` lanes of the same stored row
+    (``v_aliases_k``)."""
     rows: int
     lanes: int
     v_aliases_k: bool = False
     v_lanes: int = 0
+    k_lanes: int = 0
+
+    def __post_init__(self):
+        if self.k_lanes and not self.v_aliases_k and not (
+                0 < self.v_lanes and self.k_lanes + self.v_lanes
+                <= self.lanes):
+            raise ValueError(
+                f"K lanes {self.k_lanes} and V lanes {self.v_lanes} do not "
+                f"fit a stored row of {self.lanes} lanes")
+
+    @property
+    def kv_lanes(self):
+        """``(K lanes, V lanes)`` of a ``full`` row."""
+        if self.k_lanes:
+            return self.k_lanes, self.v_lanes
+        return self.lanes // 2, self.lanes // 2
 
 
 @dataclass(frozen=True)
@@ -72,8 +107,16 @@ class LayerSpec:
     attention: str
     cache: CacheSpec
     ffn: str
+    window: int = 0          # 0: a row sees all of the context
+    sinks: bool = False      # a learned logit a query head in the softmax
 
     def __post_init__(self):
+        if self.window < 0:
+            raise ValueError(f"window {self.window} must be >= 0")
+        if (self.window or self.sinks) and self.attention != FULL:
+            raise ValueError(
+                "a sliding window and sink logits are built for the full "
+                "attention kind only (the latent kernel has neither)")
         if self.attention not in (FULL, LATENT):
             raise ValueError(f"attention kind {self.attention!r}: the fused "
                              f"path knows {FULL!r} and {LATENT!r}")
@@ -132,12 +175,14 @@ class DecoderSpec:
             raise ValueError(
                 "block generation is built for the full attention kind "
                 "only (the latent kernel's mask is causal)")
-        if len({(ls.attention, ls.cache) for ls in self.layers}) != 1:
+        groups = self.cache_groups
+        if len(groups) > 1 and (
+                self.generation.block_length > 1
+                or any(g.attention != FULL for g in groups)):
             raise ValueError(
-                "every layer of one model shares one attention kind and "
-                "one cache descriptor: the paged pool is ONE array "
-                "[layers, blocks, rows, block_size, lanes] (window and "
-                "global layers in one cache manager are not built yet)")
+                "more than one cache group is built for full attention "
+                "generated one token a step only: a latent group beside "
+                "another, and block generation over a window, are not")
 
     @property
     def attention(self) -> str:
@@ -145,7 +190,42 @@ class DecoderSpec:
 
     @property
     def cache(self) -> CacheSpec:
+        """The FIRST group's descriptor (the only one, for a model whose
+        layers share it)."""
         return self.layers[0].cache
+
+    @cached_property
+    def cache_groups(self) -> Tuple["CacheGroup", ...]:
+        """The layers with equal ``(attention, cache, window)``, in order
+        of first appearance: one pool array and one page table a request
+        each (module doc)."""
+        keys, members = [], {}
+        for i, ls in enumerate(self.layers):
+            key = (ls.attention, ls.cache, ls.window)
+            if key not in members:
+                keys.append(key)
+                members[key] = []
+            members[key].append(i)
+        return tuple(CacheGroup(a, c, w, tuple(members[(a, c, w)]))
+                     for a, c, w in keys)
+
+    def layer_group(self, layer: int) -> Tuple[int, int]:
+        """``(group, index inside the group's pool array)`` of a layer."""
+        for g, grp in enumerate(self.cache_groups):
+            if layer in grp.layers:
+                return g, grp.layers.index(layer)
+        raise IndexError(f"layer {layer} out of range")
+
+
+@dataclass(frozen=True)
+class CacheGroup:
+    """One cache group of a spec: its layers (indices into
+    ``DecoderSpec.layers``) share the attention kind, the cache
+    descriptor and the window."""
+    attention: str
+    cache: CacheSpec
+    window: int
+    layers: Tuple[int, ...]
 
 
 def serving_decoder(model):
@@ -156,5 +236,5 @@ def serving_decoder(model):
             f"{type(model).__name__} exposes no serving_decoder(): the "
             f"fused serving stack consumes a decoder spec "
             f"(models/decoder_spec.py), which models/gpt.py, "
-            f"models/axk1.py and models/sdar.py provide")
+            f"models/axk1.py, models/sdar.py and models/mimo.py provide")
     return make()

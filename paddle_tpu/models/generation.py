@@ -576,7 +576,11 @@ def _append_nonfinite_flag(nxt, logits):
 # the paged block pool's row layout: [L, NB + 1, H, bs, 2 * Dh] — K in
 # lanes [0, Dh), V in lanes [Dh, 2 * Dh) of one (bs, 2 * Dh) tile per
 # (block, head), so the tile is 128 lanes wide at Dh = 64 (what the fused
-# kernel's DMA needs, ops/ragged_paged_attention.py). Quantized pools
+# kernel's DMA needs, ops/ragged_paged_attention.py). A model whose K
+# heads are wider than its V heads states both in its cache descriptor
+# and stores K | zeros | V in whole tiles (_kv_lanes); a model of window
+# and global layers has one such array A CACHE GROUP, L then being the
+# group's layers (models/decoder_spec.py, serving/paging.py). Quantized pools
 # (PagedKVPool(dtype="int8")) keep per-block max-abs scales in a parallel
 # [L, 2, NB + 1, H] f32 array (plane 0 = K, 1 = V) — the EQuARX per-chunk
 # scheme of the PR-10 gradient wire, applied to KV storage.
@@ -594,10 +598,16 @@ def _append_nonfinite_flag(nxt, logits):
 #   shared by force.
 # ---------------------------------------------------------------------------
 
-def _kv_lanes(k, v):
+def _kv_lanes(k, v, lanes=0):
     """K|V folded into the lanes: two ``[..., Dh]`` arrays -> one
-    ``[..., 2 * Dh]`` pool row."""
+    ``[..., 2 * Dh]`` pool row. A cache descriptor whose stored row is
+    wider than K and V together (``lanes``: K 192 | V 128 in 384) gets
+    zeros between them — K first, V last."""
     import jax.numpy as jnp
+    gap = int(lanes) - k.shape[-1] - v.shape[-1]
+    if gap > 0:
+        return jnp.concatenate(
+            [k, jnp.zeros(k.shape[:-1] + (gap,), k.dtype), v], axis=-1)
     return jnp.concatenate([k, v], axis=-1)
 
 
@@ -692,6 +702,10 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     over the block pool — ``full``: the ragged paged attention kernel on
     per-head K|V rows; ``latent``: the MLA kernel on the one latent row
     all heads share — and apply the layer's output projection and FFN.
+    A spec with more than one CACHE GROUP hands ``pool``, ``write_block``,
+    ``tables`` and ``lo`` over as tuples, one entry a group, and each
+    layer gets its group's (its window and sinks from its ``LayerSpec``);
+    the pools come back as a tuple too.
     A ``routed`` FFN is told which rows are real (pad rows name the
     scratch block 0) and returns its three counters, summed over the
     layers here. Returns ``(final_norm(x), pool, scales, counters)``,
@@ -703,36 +717,47 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     from ..ops.ragged_paged_attention import ragged_paged_attention
     from .decoder_spec import FULL
 
-    row_valid = write_block > 0
+    grouped = isinstance(pool, tuple)
+    pools = list(pool) if grouped else [pool]
+    wbs, tabs, los = (write_block, tables, lo) if grouped \
+        else ((write_block,), (tables,), (lo,))
+    row_valid = wbs[0] > 0
     counters = None
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
+        # the layer's cache group, and its place in the group's array
+        g, gi = dec.spec.layer_group(li)
         q, rows = layer.attn_in(x, positions)
         # row i's entry lands at (write_block[i], write_off[i]) through
         # the page table. The two XLA scatters send pad rows to the
         # scratch block nobody reads; kv_append skips them
         if ls.attention == FULL:
             if quantized:
-                pool, scales = _quant_append(
-                    pool, scales, li, write_block, write_off, *rows, qmax)
+                pools[g], scales = _quant_append(
+                    pools[g], scales, gi, wbs[g], write_off, *rows, qmax)
             else:
-                pool = kv_append(pool, li, write_block, write_off,
-                                 _kv_lanes(*rows))
+                pools[g] = kv_append(pools[g], gi, wbs[g], write_off,
+                                     _kv_lanes(*rows, ls.cache.lanes))
             a = ragged_paged_attention(
-                q, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
-                kv_len, scales=scales,
-                mask_block=dec.spec.generation.block_length)
+                q, pools[g], gi, blk_seq, seq_qstart, seq_pos0, tabs[g],
+                los[g], kv_len, scales=scales,
+                mask_block=dec.spec.generation.block_length,
+                window=ls.window, sinks=layer.sinks if ls.sinks else None,
+                v_lanes=ls.cache.v_lanes if ls.cache.k_lanes else 0)
         else:
-            pool = _write_latent_rows(pool, li, write_block, write_off, rows)
+            pools[g] = _write_latent_rows(pools[g], gi, wbs[g], write_off,
+                                          rows)
             a = mla_paged_attention(
-                q, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
-                kv_len, v_lanes=ls.cache.v_lanes, scale=dec.attention_scale)
+                q, pools[g], gi, blk_seq, seq_qstart, seq_pos0, tabs[g],
+                los[g], kv_len, v_lanes=ls.cache.v_lanes,
+                scale=dec.attention_scale)
         x, c = layer.attn_out(x, a, row_valid)
         if c is not None:
             counters = c if counters is None else tuple(
                 u + v for u, v in zip(counters, c))
     if counters is not None:
         counters = jnp.stack(counters).astype(jnp.int32)
-    return dec.final_norm(x), pool, scales, counters
+    return (dec.final_norm(x), tuple(pools) if grouped else pools[0],
+            scales, counters)
 
 
 def _tokens_from_prev(token_ids, prev_tokens, token_src):
@@ -990,8 +1015,8 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
          prev_tokens, token_src, sample_mask, temperature, key) = \
             rest if quantized else (None,) + rest
         if probe is not None:  # runs at trace time only (jit caches)
-            probe.record(_probe.sig_of([pool, token_ids, tables]),
-                         {"q": Q, "table": T})
+            probe.record(_probe.sig_of(jax.tree_util.tree_leaves(
+                [pool, token_ids, tables])), {"q": Q, "table": T})
         with functional_state(model, params, buffers):
             with no_grad_guard():
                 token_ids = _tokens_from_prev(token_ids, prev_tokens,

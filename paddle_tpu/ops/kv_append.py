@@ -14,8 +14,12 @@ rows for every head in one block-sized DMA each way.
 
 A single row cannot be DMA'd (a bf16 pool packs two rows a sublane:
 Mosaic refuses a one-row slice as off the tiling), so the unit is the
-token's whole block ``pool[layer, wb]`` — ``[H, bs, 2 * Dh]``, one
-contiguous region, 80 KB at GPT-2 large. A REWRITE of a block is: DMA it
+token's whole block ``pool[layer, wb]`` — ``[H, bs, lanes]`` (``2 * Dh``
+lanes, or what the cache descriptor stores: K 192 | V 128 in 384), one
+contiguous region, 80 KB at GPT-2 large. ``pool`` is ONE cache group's
+array and ``layer`` the layer's place in it (``models/decoder_spec.py``):
+a model of window and global layers appends through this kernel once a
+layer, into its group's array with its group's write targets. A REWRITE of a block is: DMA it
 into VMEM, replace the rows this launch writes (a select on an iota of
 the block's rows, every head at once), DMA it back. A pad row
 (``write_block == 0``) starts no DMA and the scratch block is never

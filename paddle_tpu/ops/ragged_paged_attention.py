@@ -38,7 +38,9 @@ Layout contract (the serving engine's fused step builds these):
   ``blk_seq`` names the sequence of each q block (−1 = pad block),
   ``seq_qstart``/``seq_pos0`` recover every row's virtual cache
   position, ``tables`` is the page table, ``kv_len`` bounds the KV walk
-  and ``lo`` the valid-window floor (always 0 for paged sequences);
+  and ``lo`` the valid-window floor (the first position the sequence
+  still holds: 0 unless the pool has freed blocks behind a sliding
+  window, ``serving/paging.py``);
 * a row at position ``p`` attends to cache columns ``[lo, p]`` — the
   history PLUS the causal prefix of its own chunk, whose K/V the fused
   step scatters into the pool before the kernel runs. With a static
@@ -48,6 +50,19 @@ Layout contract (the serving engine's fused step builds these):
   block's rows were appended before the kernel runs too. A diffusion
   block never straddles a cache block (the engine requires ``block_size
   % B == 0``), so the rows of one are one DMA.
+
+A static ``window`` W > 0 (a sliding-window layer): the row sees
+``[max(lo, p - W + 1), p]``, W keys with its own, and a q block's walk
+STARTS at the block of its first row's ``p - W + 1`` and ends at its last
+row's block — a decode row walks at most ``ceil(W / block_size) + 1``
+blocks whatever the context, and the table entries of the blocks before
+(freed, and pointing at the scratch block) are never read. ``sinks [H]``
+float32: a learned logit a query head joins the softmax's denominator
+and adds no value — the running max STARTS at the sink and the running
+sum at 1, which is ``exp(s_h - m)`` kept current by the same rescale as
+every other term. The window form carries its own kernel name
+(``ragged_paged_attention_window``) so that a device trace tells the two
+apart.
 
 Grouped-query heads: the pool holds ``Hkv`` KV heads and ``q`` has ``H =
 g * Hkv`` query heads, query head ``j`` reading KV head ``j // g``. A KV
@@ -59,14 +74,19 @@ and the walk is unchanged. The fold and its inverse are two transposes
 in the wrapper, which XLA joins with the caller's own. With ``g`` 1 and
 B 1 the kernel compiles to what it was.
 
-Pool layout: ``[L, NB + 1, H, block_size, 2 * Dh]`` — one block of one
-head is a ``(block_size, 2 * Dh)`` tile whose lanes hold K in
-``[0, Dh)`` and V in ``[Dh, 2 * Dh)``. HBM arrays are tiled ``(sublane,
+Pool layout: ``[L, NB + 1, H, block_size, lanes]`` — one block of one
+head is a ``(block_size, lanes)`` tile whose lanes hold K in the first
+``Dk`` and V in the LAST ``Dv``: ``lanes = 2 * Dh``, K in ``[0, Dh)`` and V
+in ``[Dh, 2 * Dh)`` where the two are equally wide (``v_lanes`` 0), and
+where they are not (``v_lanes`` given: K 192 | V 128 is stored 384 wide,
+K in ``[0, 192)``, zeros to 256, V in ``[256, 384)``) the score product
+runs over the K side and the value product over the V lanes, each whole
+128-lane tiles. HBM arrays are tiled ``(sublane,
 128)`` on their two minor dims and a DMA slice must cover whole tiles:
 a ``(block_size, Dh)`` tile at ``Dh = 64`` is refused by Mosaic ("slice
 shape must be aligned to tiling (128)") and padded 2x in HBM, while K|V
 folded into the lanes is exactly 128 wide at ``Dh = 64`` (and 256 at
-``Dh = 128``). ``pool[layer, pid]`` is one contiguous ``[H, bs, 2 * Dh]``
+``Dh = 128``). ``pool[layer, pid]`` is one contiguous ``[H, bs, lanes]``
 region, so one DMA brings a block's K and V for every head. Everything
 that touches the pool — ``serving/paging.py``, ``ops/kv_append.py``,
 ``serving/host_tier.py``, the int8 scales, the head-partitioned TP
@@ -188,7 +208,8 @@ def kv_group_blocks(heads: int, block_size: int, head_dim: int,
 
 def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
                 lo_ref, kvlen_ref, *rest, block_q, block_size, group, scale,
-                quantized=False, q_group=1, mask_block=1):
+                quantized=False, q_group=1, mask_block=1, window=0,
+                sinks=False):
     """One q-block grid step, every head at once: walk the owning
     sequence's page table ONCE, a group of ``group`` KV blocks at a
     time — one DMA a block brings ``pool[layer, pid]`` whole (all heads,
@@ -216,18 +237,25 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
     i32-typed constants: bare python ints in kernel index math get
     materialized as i64 by Mosaic under the framework's global x64 (the
     pallas_kernels idiom; the call sites also trace under _x64_off)."""
+    scales_ref = sinks_ref = None
     if quantized:
         scales_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
+    elif sinks:
+        q_ref, sinks_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
     else:
-        scales_ref = None
         q_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
     b = pl.program_id(0)
     layer = layer_ref[0]
     seq = blk_seq_ref[b]
     n_heads, q_rows, dh = q_ref.shape       # q_rows = block_q * q_group
+    # V is the last dv lanes of a stored row, K what q multiplies before
+    # them (the wrapper zero-extends q to the first V lane where the two
+    # sides are whole tiles)
+    dv = o_ref.shape[-1]
+    v0 = kv_scr.shape[-1] - dv
     # K and V lanes are whole 128-lane tiles each: slicing them apart is
     # free, and neither product then runs over the other's lanes
-    split = dh % 128 == 0
+    split = v0 % 128 == 0 and dv % 128 == 0
     cols_g = group * block_size             # KV columns of one group
     t_len = tables_ref.shape[1]
 
@@ -246,7 +274,9 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         # is q . K^T, and p . [K|V] holds p . V in its upper Dh lanes
         q = q_ref[...]                                  # [H, bq, Dh]
         if not split:
-            q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+            q = jnp.concatenate(
+                [q, jnp.zeros(q.shape[:-1] + (v0 + dv - dh,), q.dtype)],
+                axis=-1)
         # virtual cache position of each row: rows of a sequence are
         # consecutive tokens starting at seq_pos0 (pad rows past the
         # real q_len see the whole context and nobody reads them); a
@@ -263,14 +293,28 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         lo = lo_ref[seq]
         kv_len = kvlen_ref[seq]
         n_kv = jnp.minimum((kv_len + _BS - 1) // _BS, jnp.int32(t_len))
-        n_grp = (n_kv + _G - 1) // _G
+        if window:
+            # the walk starts at the block of the q block's first row's
+            # p - W + 1 and ends at its last row's block: the entries
+            # before name freed blocks, the ones after are all masked
+            p_first = pos0_ref[seq] + row0
+            j_first = jnp.maximum(
+                jnp.maximum(p_first - jnp.int32(window - 1), lo), 0) // _BS
+            n_kv = jnp.minimum(n_kv, (p_first + _BQ - 1) // _BS + 1)
+            n_grp = (n_kv - j_first + _G - 1) // _G
+            col0 = j_first * _BS
+            # rows of the buffers past the walk's last block hold what an
+            # earlier walk left there
+            kv_end = jnp.minimum(kv_len, n_kv * _BS)
+        else:
+            n_grp = (n_kv + _G - 1) // _G
 
         def block_copies(grp, slot, act):
             # the page-table walk: the grp-th group's blocks, each ONE
             # copy of pool[layer, pid] — every head's (bs, 2*Dh) K|V
             # tile — into its rows of buffer `slot`; `act` starts or
             # waits. Blocks past the sequence's last are not touched.
-            j0 = grp * _G
+            j0 = grp * _G + j_first if window else grp * _G
 
             def one(g, carry):
                 rows = pl.ds(pl.multiple_of(g * _BS, block_size),
@@ -321,14 +365,17 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
             # block's rows past kv_len, go to the MXU as zeros
             kv_rows = grp * _CG + jax.lax.broadcasted_iota(
                 jnp.int32, (cols_g, 1), 0)
+            if window:
+                kv_rows = kv_rows + col0
             kv = kv_scr[slot]                           # [H, G*bs, 2*Dh]
-            kv = jnp.where((kv_rows < kv_len)[None], kv,
-                           jnp.zeros_like(kv)).astype(q.dtype)
+            kv = jnp.where(
+                (kv_rows < (kv_end if window else kv_len))[None], kv,
+                jnp.zeros_like(kv)).astype(q.dtype)
             # operands in storage dtype (a quantized pool's values are
             # exact in q's), f32 accumulation (MXU contract shared with
             # the flash kernels)
             s = jax.lax.dot_general(
-                q, kv[:, :, :dh] if split else kv,
+                q, kv[:, :, :v0] if split else kv,
                 (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) * scale  # [H, bq, G*bs]
             if quantized:
@@ -336,11 +383,14 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
                 s = s * k_scale
             cols = grp * _CG + jax.lax.broadcasted_iota(
                 jnp.int32, (q_rows, cols_g), 1)
+            if window:
+                cols = cols + col0
+            seen = (cols >= lo) & (cols <= q_last) & (cols < kv_len)
+            if window:
+                seen = seen & (cols > qpos - jnp.int32(window))
             # f32-typed fill: a bare python float is weak f64 under the
             # framework's global x64
-            s = jnp.where(((cols >= lo) & (cols <= q_last)
-                           & (cols < kv_len))[None], s,
-                          jnp.float32(_NEG_INF))
+            s = jnp.where(seen[None], s, jnp.float32(_NEG_INF))
             m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             p = jnp.exp(s - m_new)
@@ -349,33 +399,43 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
             if quantized:
                 p = p * v_scale
             acc_new = acc * alpha + jax.lax.dot_general(
-                p.astype(q.dtype), kv[:, :, dh:] if split else kv,
+                p.astype(q.dtype), kv[:, :, v0:] if split else kv,
                 (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)     # [H, bq, 2*Dh]
             return m_new, l_new, acc_new
 
-        m0 = jnp.full((n_heads, q_rows, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((n_heads, q_rows, 1), jnp.float32)
-        acc0 = jnp.zeros((n_heads, q_rows, dh if split else 2 * dh),
+        if sinks:
+            # the sink's term of the denominator: exp(s_h - m) with the
+            # running max starting AT the sink — 1, rescaled from here on
+            # like every other term; it adds nothing to acc
+            m0 = sinks_ref[...]
+            l0 = jnp.ones((n_heads, q_rows, 1), jnp.float32)
+        else:
+            m0 = jnp.full((n_heads, q_rows, 1), _NEG_INF, jnp.float32)
+            l0 = jnp.zeros((n_heads, q_rows, 1), jnp.float32)
+        acc0 = jnp.zeros((n_heads, q_rows, dv if split else v0 + dv),
                          jnp.float32)
         # i32 bounds: a bare python 0 becomes an i64 induction variable
         # under the framework's global x64, and the interpret-mode body
         # trace happens outside the call site's _x64_off scope
         _, l, acc = jax.lax.fori_loop(jnp.int32(0), n_grp, body,
                                       (m0, l0, acc0))
-        o_ref[...] = ((acc if split else acc[:, :, dh:])
+        o_ref[...] = ((acc if split else acc[:, :, v0:])
                       / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
                            tables, lo, kv_len, *, scales=None, scale=None,
-                           block_q: int = BLOCK_Q, mask_block: int = 1):
+                           block_q: int = BLOCK_Q, mask_block: int = 1,
+                           window: int = 0, sinks=None, v_lanes: int = 0):
     """Fused paged attention over one layer of the serving block pool.
 
     * ``q`` — ``[H, Qp, Dh]`` flattened padded query rows (``Qp`` a
       multiple of ``block_q``; per-sequence contiguous, see module doc);
-    * ``pool`` — the FULL block pool ``[L, NB + 1, Hkv, bs, 2 * Dh]``
-      (K|V folded into the lanes, see module doc; ``H`` a multiple of
+    * ``pool`` — the FULL block pool ``[L, NB + 1, Hkv, bs, lanes]``
+      (K|V folded into the lanes, see module doc: ``lanes = 2 * Dh``
+      unless ``v_lanes`` says that V is the last ``v_lanes`` of them and
+      K the first ``Dh``; ``H`` a multiple of
       ``Hkv``: grouped-query heads); it stays in HBM
       (``memory_space=pl.ANY``) and ``layer`` (a host int) indexes it
       inside the kernel's DMAs, so no per-layer slice is ever
@@ -390,16 +450,33 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
       dequantizes in-register;
     * ``mask_block`` — static B: a row sees columns up to the end of its
       block of B (1: causal; module doc);
-    * returns ``[H, Qp, Dh]`` in ``q``'s dtype.
+    * ``window`` — static W: a row sees its last W columns only (0: all
+      of ``[lo, p]``), and the walk starts at the window (module doc);
+    * ``sinks`` — ``[H]`` float32, a logit a query head in the softmax's
+      denominator (None: none);
+    * returns ``[H, Qp, Dv]`` in ``q``'s dtype (``Dv = v_lanes or Dh``).
     """
     h, qp, dh = q.shape
     L, nb1, hp, bs, dh2 = pool.shape
     quantized = pool.dtype.name in ("int8", "float8_e4m3fn")
-    if h % hp or dh2 != 2 * dh:
+    dv = int(v_lanes) or dh
+    if h % hp or (dh + dv > dh2 if v_lanes else dh2 != 2 * dh):
         raise ValueError(
             f"pool KV heads/lanes {(hp, dh2)} do not fit q heads / "
-            f"2*head_dim {(h, 2 * dh)}: the query heads must be a "
+            f"K + V lanes {(h, dh + dv)}: the query heads must be a "
             f"multiple of the pool's KV heads")
+    if int(window) < 0 or (window and int(mask_block) != 1):
+        raise ValueError(
+            f"window {window} with mask_block {mask_block}: a sliding "
+            f"window is built for the causal mask only")
+    if quantized and (window or sinks is not None or v_lanes):
+        raise ValueError(
+            "a sliding window, sink logits and split K|V lanes over "
+            "int8/fp8 blocks are not built")
+    if sinks is not None and tuple(sinks.shape) != (h,):
+        raise ValueError(
+            f"sinks shape {tuple(sinks.shape)} != one logit a query head "
+            f"{(h,)}")
     if int(mask_block) < 1 or bs % int(mask_block):
         raise ValueError(
             f"mask_block {mask_block} must divide block_size {bs}: a "
@@ -408,7 +485,7 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
         raise ValueError(
             "grouped-query heads over int8/fp8 blocks are not built: the "
             "per-block scales are read per query head")
-    check_kv_tile(pool.dtype, bs, dh)
+    check_kv_tile(pool.dtype, bs, lanes=dh2)
     if qp % block_q:
         raise ValueError(
             f"padded q rows {qp} must be a multiple of block_q {block_q}")
@@ -430,13 +507,17 @@ def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
             i32(seq_pos0), i32(tables), i32(lo), i32(kv_len),
             None if scales is None else jnp.asarray(scales, jnp.float32),
             scale=scale, block_q=int(block_q), interpret=_interpret(),
-            mask_block=int(mask_block))
+            mask_block=int(mask_block), window=int(window),
+            sinks=None if sinks is None else jnp.asarray(sinks, jnp.float32),
+            v_lanes=dv)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_q", "interpret",
-                                             "mask_block"))
+                                             "mask_block", "window",
+                                             "v_lanes"))
 def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
-              kv_len, scales, *, scale, block_q, interpret, mask_block=1):
+              kv_len, scales, *, scale, block_q, interpret, mask_block=1,
+              window=0, sinks=None, v_lanes=0):
     """The Pallas call. ``layer`` is a ``[1]`` int32 scalar-prefetch
     operand, not a constant of the kernel, and the call is a jitted
     function of its own: the layers of a step program differ in nothing
@@ -444,32 +525,53 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
     program, not once a layer (36 times at GPT-2 large, in every
     warm-up, compile cache warm or not)."""
     h, qp, dh = q.shape
-    hkv, bs = pool.shape[2], pool.shape[3]
+    hkv, bs, lanes = pool.shape[2:]
     quant = scales is not None
     g = h // hkv
+    dv = v_lanes or dh
+    v0 = lanes - dv
+    if dh < v0 and v0 % 128 == 0 and dv % 128 == 0:
+        # K and V sides are whole tiles with padding between them: q is
+        # zero-extended to the first V lane here, so that the kernel's
+        # score product is over aligned lanes (192 -> 256)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, v0 - dh)))
+        dh = v0
     if g > 1:
         # fold a KV head's group of query heads into the rows: [Hkv, g,
         # Qp, Dh] -> [Hkv, Qp * g, Dh], row r = query row r // g
         q = jnp.swapaxes(q.reshape(hkv, g, qp, dh), 1, 2).reshape(
             hkv, qp * g, dh)
-    group = kv_group_blocks(hkv, bs, dh, pool.dtype)
+    group = kv_group_blocks(hkv, bs, 0, pool.dtype, lanes=lanes)
     kernel = functools.partial(
         _rpa_kernel, block_q=block_q, block_size=int(bs), group=group,
-        scale=scale, quantized=quant, q_group=g, mask_block=mask_block)
+        scale=scale, quantized=quant, q_group=g, mask_block=mask_block,
+        window=window, sinks=sinks is not None)
     q_rows = block_q * g
+    operands, sink_specs = [q], []
+    if sinks is not None:
+        # folded row r is head r % g of its KV head's group
+        operands.append(jnp.tile(sinks.reshape(hkv, 1, g),
+                                 (1, block_q, 1)).reshape(hkv, q_rows, 1))
+        # the whole [Hkv, block_q * g, 1] operand is the block: its last
+        # dim EQUALS the array's (compiled for a described v5e in
+        # tests/test_tpu_compile.py), the layout of the kernel's running
+        # max and sum
+        sink_specs.append(pl.BlockSpec(  # lint: ok
+            (hkv, q_rows, 1), lambda b, *_: (0, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8 if quant else 7,
         grid=(qp // block_q,),
         in_specs=[
             pl.BlockSpec((hkv, q_rows, dh), lambda b, *_: (0, b, 0)),
+            *sink_specs,
             pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
         ],
-        out_specs=pl.BlockSpec((hkv, q_rows, dh), lambda b, *_: (0, b, 0)),
+        out_specs=pl.BlockSpec((hkv, q_rows, dv), lambda b, *_: (0, b, 0)),
         scratch_shapes=[
             # two buffers of one group: block g of a group is rows
             # [g*bs, (g+1)*bs) of every head, so a head's K|V of the
             # whole group is one [G*bs, 2*Dh] tile stack
-            pltpu.VMEM((2, hkv, group * bs, 2 * dh), pool.dtype),
+            pltpu.VMEM((2, hkv, group * bs, lanes), pool.dtype),
             pltpu.SemaphoreType.DMA((2, group)),
         ],
     )
@@ -480,14 +582,15 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
             scales, layer[0], 0, keepdims=False))
     out = pl.pallas_call(
         kernel,
-        name="ragged_paged_attention",
+        name="ragged_paged_attention_window" if window
+        else "ragged_paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, qp * g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hkv, qp * g, dv), q.dtype),
         interpret=interpret,
-    )(*prefetch, q, pool)
+    )(*prefetch, *operands, pool)
     if g > 1:
-        out = jnp.swapaxes(out.reshape(hkv, qp, g, dh), 1, 2).reshape(
-            h, qp, dh)
+        out = jnp.swapaxes(out.reshape(hkv, qp, g, dv), 1, 2).reshape(
+            h, qp, dv)
     return out
 
 
@@ -549,7 +652,8 @@ def ragged_layout(q_lens: Sequence[int], pos0s: Sequence[int], *,
 
 def reference_ragged_attention(q_rows, pool, layer, row_seq, row_pos,
                                tables, lo, scale=None, scales=None,
-                               mask_block=1, kv_len=None):
+                               mask_block=1, kv_len=None, window=0,
+                               sinks=None, v_lanes=0):
     """Numpy oracle for the kernel (tests): per-row full-precision
     softmax attention over the row's ``[lo, pos]`` window gathered
     through the page table. ``q_rows [N, H, Dh]``, ``row_seq/row_pos
@@ -557,32 +661,43 @@ def reference_ragged_attention(q_rows, pool, layer, row_seq, row_pos,
     the kernel's in-register multiply done up front). Query head ``j``
     reads the pool's KV head ``j // (H / Hkv)``; with ``mask_block`` B the
     window ends at the row's block's last column, bounded by
-    ``kv_len[seq]``."""
+    ``kv_len[seq]``. With ``window`` W the row sees its last W columns
+    only, with ``sinks [H]`` a head's logit joins the denominator, with
+    ``v_lanes`` V is the last ``v_lanes`` lanes of a row and K the first
+    ``Dh``. Returns ``[N, H, Dv]``."""
     q_rows = np.asarray(q_rows, np.float32)
     n, h, dh = q_rows.shape
-    # [L, NB+1, H, bs, 2*Dh] -> K/V planes [L, 2, NB+1, H, bs, Dh]
+    dv = int(v_lanes) or dh
+    # [L, NB+1, H, bs, lanes] -> K and V planes [L, NB+1, H, bs, Dk | Dv]
     pool = np.asarray(pool, np.float32)
-    pool = np.stack([pool[..., :dh], pool[..., dh:]], axis=1)
+    planes = [pool[..., :dh], pool[..., pool.shape[-1] - dv:]]
     if scales is not None:
-        pool = pool * np.asarray(scales, np.float32)[..., None, None]
-    bs = pool.shape[4]
+        planes = [pl_ * np.asarray(scales, np.float32)[:, i, ..., None, None]
+                  for i, pl_ in enumerate(planes)]
+    bs = pool.shape[3]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
-    out = np.zeros_like(q_rows)
-    g = h // pool.shape[3]
+    out = np.zeros((n, h, dv), np.float32)
+    g = h // pool.shape[2]
     B = int(mask_block)
     for i in range(n):
         s = int(row_seq[i])
         p = int(row_pos[i])
         if B > 1:
             p = min(p // B * B + B - 1, int(kv_len[s]) - 1)
-        cols = np.arange(int(lo[s]), p + 1)
-        k = np.stack([pool[layer, 0, tables[s][c // bs], :, c % bs, :]
+        first = int(lo[s])
+        if window:
+            first = max(first, int(row_pos[i]) - int(window) + 1)
+        cols = np.arange(first, p + 1)
+        k = np.stack([planes[0][layer, tables[s][c // bs], :, c % bs, :]
                       for c in cols])                    # [ctx, H, Dh]
-        v = np.stack([pool[layer, 1, tables[s][c // bs], :, c % bs, :]
+        v = np.stack([planes[1][layer, tables[s][c // bs], :, c % bs, :]
                       for c in cols])
         for hh in range(h):
             logits = (k[:, hh // g] @ q_rows[i, hh]) * scale
-            w = np.exp(logits - logits.max())
-            w /= w.sum()
+            m = logits.max() if sinks is None \
+                else max(logits.max(), float(sinks[hh]))
+            w = np.exp(logits - m)
+            w /= w.sum() + (0.0 if sinks is None
+                            else np.exp(float(sinks[hh]) - m))
             out[i, hh] = w @ v[:, hh // g]
     return out

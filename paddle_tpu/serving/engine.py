@@ -292,14 +292,80 @@ def _refuse_with_blocks(kv_dtype, mesh, spec_draft,
             "grouped heads under mesh= are not built")
 
 
+def _refuse_with_groups(kv_dtype, mesh, spec_draft,
+                        host_tier_bytes) -> None:
+    """A model whose layers form more than one cache group (window and
+    global layers, ``models/decoder_spec.py``): each mechanism that is not
+    built over several block arrays and page tables a request is refused
+    here, by name."""
+    import jax.numpy as jnp
+    if spec_draft is not None:
+        raise ValueError(
+            "spec_draft does not compose with more than one cache group "
+            "yet: a rejected draft rolls the pool's position back, and a "
+            "window group has by then freed the blocks behind it")
+    if host_tier_bytes is not None:
+        raise ValueError(
+            "host_tier_bytes does not compose with more than one cache "
+            "group yet: the tier demotes and promotes the blocks of the "
+            "prefix cache, and nothing is offered to it here (a window "
+            "group keeps no prefix)")
+    if kv_dtype is not None and jnp.dtype(kv_dtype).name in (
+            "int8", "float8_e4m3fn"):
+        raise ValueError(
+            "int8/fp8 KV blocks do not compose with more than one cache "
+            "group yet: the per-block scales are ONE array [layers, 2, "
+            "blocks, heads], and the groups differ in blocks and heads")
+    if mesh is not None:
+        raise ValueError(
+            "mesh= (tensor-parallel serving) does not compose with more "
+            "than one cache group yet: the sharded step hands every layer "
+            "one head-partitioned pool and one table")
+
+
+def _group_block_counts(groups, num_slots, max_len, block_size, num_blocks,
+                        prefill_budget):
+    """Blocks a cache group's array holds. One group: ``num_blocks`` as
+    given. More: ``num_blocks`` (default: every slot at ``max_len``) is
+    what a cache held UNIFORMLY would hold of every group, and its bytes
+    are shared out by the rule of the spec — a WINDOW group gets what
+    its slots can hold at all, ``num_slots x (ceil(W / block_size) + 2)``
+    (a window's blocks, one it straddles into, one a chunk's first rows
+    straddle into) plus the blocks of ``prefill_budget`` rows; the bytes
+    that saves go to the window-0 groups, up to every slot at
+    ``max_len``."""
+    if len(groups) == 1:
+        return [num_blocks]
+    cdiv = lambda a, b: -(-int(a) // int(b))
+    worst = num_slots * cdiv(max_len, block_size)
+    uniform = worst if num_blocks is None else int(num_blocks)
+    values = [len(g.layers) * g.cache.rows * g.cache.lanes for g in groups]
+    counts, saved = [], 0
+    for g, v in zip(groups, values):
+        n = uniform
+        if g.window:
+            n = min(uniform, num_slots * (cdiv(g.window, block_size) + 2)
+                    + cdiv(prefill_budget or max_len, block_size))
+            saved += (uniform - n) * v
+        counts.append(n)
+    whole = [i for i, g in enumerate(groups) if not g.window]
+    if whole:
+        more = saved // sum(values[i] for i in whole)
+        for i in whole:
+            counts[i] = min(worst, uniform + more)
+    return counts
+
+
 class GenerationEngine:
     """Continuous-batching serving over a decoder the fused stack has a
-    spec of (``models/decoder_spec.py``: GPT-2, A.X-K1, SDAR) — one token
+    spec of (``models/decoder_spec.py``: GPT-2, A.X-K1, SDAR,
+    MiMo-V2-Flash) — one token
     a sequence a step, or a block of them by diffusion, as the spec's
     generation rule says.
 
     ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` /
-    ``AXK1ForCausalLM`` / ``SDARForCausalLM`` (anything
+    ``AXK1ForCausalLM`` / ``SDARForCausalLM`` / ``MiMoV2ForCausalLM``
+    (anything
     ``serving_decoder`` has a spec of);
     its parameters are snapshotted at construction (sharded parameters
     serve sharded — jit follows the placement).
@@ -319,7 +385,13 @@ class GenerationEngine:
       explicit value under the floor raises. ``num_blocks`` defaults to
       the worst case (every slot at ``max_len``); shrink it to what the
       device holds — admission then gates on blocks, pressure preempts,
-      and full prompt blocks are shared through the prefix cache;
+      and full prompt blocks are shared through the prefix cache. A model
+      whose spec has more than one CACHE GROUP (window and global
+      layers) gets a block array and a page table a request for each:
+      ``num_blocks`` is then what a cache held uniformly in every layer
+      would hold, and its bytes are shared out by the spec's rule
+      (``_group_block_counts``: the window group what its slots can hold
+      at all, the rest to the layers that keep the whole context);
     * ``kv_dtype`` — ``"int8"``/``"float8_e4m3fn"`` stores the blocks
       quantized with per-block max-abs scales;
     * ``spec_draft``/``spec_k`` — speculative decoding: a small draft
@@ -402,6 +474,10 @@ class GenerationEngine:
         if spec.generation.block_length > 1:
             _refuse_with_blocks(kv_dtype, mesh, spec_draft,
                                 host_tier_bytes)
+        groups = spec.cache_groups
+        if len(groups) > 1:
+            _refuse_with_groups(kv_dtype, mesh, spec_draft,
+                                host_tier_bytes)
         max_len = int(max_len or spec.max_positions)
         # every jit is deferred, so without this check an oversized
         # max_len would only surface as SILENTLY WRONG tokens (XLA clamps
@@ -438,7 +514,9 @@ class GenerationEngine:
         # other attention path is selected behind its back
         if block_size is None:
             block_size = max(16, min_kv_block_for(kv_dtype or dtype))
-        check_kv_tile(kv_dtype or dtype, block_size, lanes=cache.lanes)
+        for grp in groups:
+            check_kv_tile(kv_dtype or dtype, block_size,
+                          lanes=grp.cache.lanes)
         if int(block_size) % spec.generation.block_length:
             raise ValueError(
                 f"block_size {block_size} is no multiple of the model's "
@@ -450,11 +528,23 @@ class GenerationEngine:
         self._key = jax.random.PRNGKey(int(seed))
         self._eid = _next_engine_id()
         self._min_bucket = int(min_bucket)    # the draft's prefill ladder
+        # one block array and one page table a request for each cache
+        # group of the spec; how many blocks each holds is the spec's to
+        # say, not a knob (_group_block_counts)
+        counts = _group_block_counts(groups, num_slots, max_len,
+                                     block_size, num_blocks, prefill_budget)
         self._pool = PagedKVPool(
-            len(spec.layers), num_slots, cache.rows,
+            len(groups[0].layers), num_slots, cache.rows,
             max_len, head_dim, block_size=block_size,
-            num_blocks=num_blocks, dtype=kv_dtype or dtype,
-            mesh=mesh, mp_axis=mp_axis, lanes=cache.lanes)
+            num_blocks=counts[0], dtype=kv_dtype or dtype,
+            mesh=mesh, mp_axis=mp_axis, lanes=cache.lanes,
+            window=groups[0].window, more_groups=[
+                dict(num_layers=len(g.layers), num_heads=g.cache.rows,
+                     lanes=g.cache.lanes, window=g.window, num_blocks=n)
+                for g, n in zip(groups[1:], counts[1:])])
+        # the first window group's W (0: none): what the launch counters
+        # of the windowed walk are counted from
+        self._window = next((g.window for g in groups if g.window), 0)
         self._fused_jits = {}         # (q bucket, table bucket) -> step
         # the fused step's "previous result" operand while no launch is
         # in flight: the shape of its own result ([slots | sentinel |
@@ -748,6 +838,15 @@ class GenerationEngine:
                 "scales": pool.scales_bytes,
             },
         })
+        if len(pool.groups) > 1:
+            s["cache_groups"] = [
+                {"layers": len(g.layers), "window": g.window,
+                 "rows": g.cache.rows, "lanes": g.cache.lanes,
+                 "num_blocks": pool.groups[i].num_blocks,
+                 "blocks_in_use": pool.group_blocks_in_use(i),
+                 "block_bytes": pool.group_block_bytes(i)}
+                for i, g in enumerate(self._decoder_spec.cache_groups)]
+            s["window_blocks_freed"] = pool.window_blocks_freed
         if self._host_tier is not None:
             # hierarchical tier snapshot: host capacity/occupancy,
             # demotion/promotion volumes, and the end-to-end
@@ -888,9 +987,16 @@ class GenerationEngine:
         scales = (self._pool.scales,) if self._pool.quantized else ()
         return analysis.analyze(
             self._fused_step_fn(Q, T), self._params, self._buffers,
-            self._pool.data, *scales, *self._null_step_operands(Q, T),
+            self._pool_operand(), *scales,
+            *self._null_step_operands(Q, T),
             passes=passes,
             name=f"serving.fused_step[{S} slots, q{Q}, t{T}]")
+
+    def _pool_operand(self):
+        """The step's pool operand: the block array, or every cache
+        group's as a tuple where the spec has more than one."""
+        pool = self._pool
+        return pool.data if len(pool.groups) == 1 else pool.group_data
 
     def _null_step_operands(self, Q: int, T: int) -> tuple:
         """The fused step's operands after the pool, zeroed: a legal
@@ -898,8 +1004,12 @@ class GenerationEngine:
         from ..ops.ragged_paged_attention import BLOCK_Q
         S = self._pool.num_slots
         i32 = lambda *shape: np.zeros(shape, np.int32)
-        head = (i32(Q), i32(Q), i32(Q), i32(Q), i32(Q // BLOCK_Q), i32(S),
-                i32(S), i32(S, T), i32(S), i32(S))
+        G = len(self._pool.groups)
+        # write targets, tables and floors are one a cache group
+        each = i32 if G == 1 else \
+            (lambda *shape: tuple(i32(*shape) for _ in range(G)))
+        head = (i32(Q), i32(Q), each(Q), i32(Q), i32(Q // BLOCK_Q), i32(S),
+                i32(S), each(S, T), each(S), i32(S))
         B = self._decoder_spec.generation.block_length
         if B > 1:
             from ..models.generation import BLOCK_UNFIXED
@@ -980,7 +1090,7 @@ class GenerationEngine:
                     self._model, S, Q, T, pool.block_size,
                     top_k=self._top_k, top_p=self._top_p,
                     quantized=pool.quantized, qmax=pool.qmax or 127.0)
-            args = (params, buffers, pool.data, *scales,
+            args = (params, buffers, self._pool_operand(), *scales,
                     *self._null_step_operands(Q, T))
             flavor, site = "fused", f"fused_step[q{Q},t{T}]"
         donate = (2, 3) if pool.quantized else (2,)
@@ -993,7 +1103,8 @@ class GenerationEngine:
         def _nbytes(a):
             return int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
 
-        operand_pool = _nbytes(pool.data) + sum(_nbytes(s) for s in scales)
+        operand_pool = sum(_nbytes(a) for a in pool.group_data) \
+            + sum(_nbytes(s) for s in scales)
         per_device_pool = pool.capacity_bytes
         total = rep.static_peak_bytes - operand_pool + per_device_pool
 
@@ -1003,6 +1114,7 @@ class GenerationEngine:
             "flavor": flavor, "q_bucket": Q, "table_bucket": T,
             "step_peak_bytes": int(rep.static_peak_bytes),
             "pool_bytes": int(per_device_pool),
+            "group_blocks": [g.num_blocks for g in pool.groups],
             "static_peak_bytes": int(total),
             "budget_bytes": budget,
             "fits": None if budget is None else bool(total <= budget),
@@ -1098,7 +1210,15 @@ class GenerationEngine:
         final tokens (the commit), then the next block's at the B
         positions after them, all the mask id, in its pass 0 — one q
         block of the kernel where 2 B is ``BLOCK_Q``, ``kv_len`` to the
-        new block's end. With B rows it commits alone."""
+        new block's end. With B rows it commits alone.
+
+        A spec with more than one cache group gets ``write_block``,
+        ``tables`` and ``lo`` as tuples, one entry a group (a row's offset
+        in its block is the same in all): a window group's table names
+        the scratch block where it has freed, and its ``lo`` is the first
+        position the slot still holds there. The ``(Q, T)`` program is
+        named by the first group's table bucket — every group's table
+        covers the same virtual blocks."""
         from ..ops.ragged_paged_attention import (BLOCK_Q, kv_group_blocks,
                                                   ragged_layout)
 
@@ -1143,7 +1263,10 @@ class GenerationEngine:
             q_lens, pos0s, q_bucket=Q)
         token_ids = np.zeros(Q, np.int32)
         qpos = np.zeros(Q, np.int32)
-        write_block = np.zeros(Q, np.int32)   # pad rows -> scratch block
+        G = len(pool.groups)
+        # pad rows -> scratch block, in every group
+        write_blocks = [np.zeros(Q, np.int32) for _ in range(G)]
+        write_block = write_blocks[0]
         write_off = np.zeros(Q, np.int32)
         token_src = np.full(Q, -1, np.int32)
         block = None
@@ -1181,9 +1304,35 @@ class GenerationEngine:
                 qpos[r0 + i] = p0 + i
                 write_block[r0 + i] = table[(p0 + i) // bs]
                 write_off[r0 + i] = (p0 + i) % bs
+            for g in range(1, G):
+                table = np.asarray(pool.slot_table(slot, g), np.int32)
+                at = p0 + np.arange(q_lens[slot])
+                write_blocks[g][r0:r0 + q_lens[slot]] = table[at // bs]
         T = max(pool.table_bucket(s) for s in row_tokens)
         tables = pool.table_array(T, row_tokens)
         lo = np.zeros(S, np.int32)            # paged virtual floor
+        more = {}
+        los = [lo] + [np.zeros(S, np.int32) for _ in range(1, G)]
+        for g, grp in enumerate(pool.groups):
+            if grp.window:           # what the group has not freed yet
+                for slot in row_tokens:
+                    los[g][slot] = pool.slot_lo(slot, g)
+        if G > 1:
+            tables = (tables,) + tuple(
+                pool.table_array(T, row_tokens, g) for g in range(1, G))
+            lo, write_block = tuple(los), tuple(write_blocks)
+        if self._window:
+            # what a window layer must read, and the pairs under its mask:
+            # a slot's rows see W - 1 tokens behind the first of them,
+            # a row at p its last min(p + 1, W)
+            W = self._window
+            more = dict(
+                kv_tokens_window=sum(
+                    min(int(kv_len[s]), W - 1 + n)
+                    for s, n in enumerate(q_lens) if n),
+                kv_row_tokens_window=sum(
+                    int(np.minimum(pos0s[s] + np.arange(n) + 1, W).sum())
+                    for s, n in enumerate(q_lens) if n))
         # every q block of a slot walks that slot's whole context: one
         # KV block a step, one wait for a group of G blocks a fetch (G
         # as the kernel reads it from its pool shard's shape)
@@ -1210,7 +1359,7 @@ class GenerationEngine:
             # layer by ops.kv_append
             kv_write_blocks=sum(
                 (pos0s[s] + n - 1) // bs - pos0s[s] // bs + 1
-                for s, n in enumerate(q_lens) if n))
+                for s, n in enumerate(q_lens) if n), **more)
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps, token_src, block)
@@ -1244,6 +1393,10 @@ class GenerationEngine:
             pool.data, pool.scales, nxt, self._key = step(
                 self._params, self._buffers, pool.data, pool.scales,
                 *args)
+        elif len(pool.groups) > 1:
+            # every cache group's array is donated and comes back
+            pool.group_data, nxt, self._key = step(
+                self._params, self._buffers, pool.group_data, *args)
         else:
             pool.data, nxt, self._key = step(
                 self._params, self._buffers, pool.data, *args)

@@ -1,22 +1,45 @@
 """Paged KV-cache memory manager: block-granular pooling + prefix cache.
 
-THE pool of the serving engine. The device array is ``[layers,
-num_blocks + 1, heads, block_size, 2 * head_dim]`` (K|V folded into the
-lanes — the ONE layout every reader of the pool uses, see
-ops/ragged_paged_attention.py): a request owns only the blocks covering
-its tokens SO FAR, addressed through a per-request page table that maps
-virtual cache index ``i`` to ``(table[i // block_size], i %
-block_size)``, so concurrency is bounded by the tokens in flight and not
-by worst-case sequence length (the Ragged-Paged-Attention argument,
-PAPERS.md). Physical block 0 is a reserved SCRATCH block — page-table
-padding points at it, pad rows' garbage lands in it, and nothing ever
-reads it through an unmasked position.
+THE pool of the serving engine: ONE manager of slots, positions,
+admission and preemption over one block array and one page table a
+request FOR EACH CACHE GROUP of the model (``models/decoder_spec.py``:
+the layers that share a cache descriptor and a window; GPT-2, A.X-K1 and
+SDAR have one group, and everything below reads as it always did for
+them). A group's device array is ``[its layers, num_blocks + 1, heads,
+block_size, lanes]`` (K|V folded into the lanes — the ONE layout every
+reader of the pool uses, see ops/ragged_paged_attention.py): a request
+owns only the blocks covering its tokens SO FAR, addressed through a
+per-request page table that maps virtual cache index ``i`` to
+``(table[i // block_size], i % block_size)``, so concurrency is bounded
+by the tokens in flight and not by worst-case sequence length (the
+Ragged-Paged-Attention argument, PAPERS.md). Physical block 0 of every
+group is a reserved SCRATCH block — page-table padding points at it, pad
+rows' garbage lands in it, and nothing ever reads it through an unmasked
+position.
+
+**A window group** (``window`` W > 0: its layers' rows see the last W
+positions only) FREES, whenever a launch's rows have been dispatched
+(``advance``), every block that lies wholly behind ``pos - W + 1``: the
+table entry goes back to the scratch block, the virtual index does not
+move, and the group's ``lo`` of the slot is the first position still
+held — the kernel's walk starts at the window and never reads the freed
+entries. A launch already dispatched may still read a block freed after
+it: the device runs launches in order, and the block's next writer is a
+later launch. Its blocks are allocated as rows are written
+(``ensure_writable_range``), never for a whole prompt at admission, so a
+slot holds ``W - 1 + n`` tokens of it while a chunk of ``n`` rows is
+planned and ``ceil(W / block_size) + 1`` blocks at most between chunks.
+With more than one group no block is offered to or matched in the
+prefix cache (a block of the window-0 group alone does not let a request
+skip its prompt: the window layers' last W - 1 tokens are gone), and
+int8/fp8 blocks, the host tier and a mesh are refused.
 
 Host-side manager (this module, scheduler-thread-owned):
 
 * **request slots** — the launch's batch axis: deterministic
   lowest-index allocation, per-slot ``pos`` (cache index of the next
-  write) and ``lo`` (first valid index; 0 — paged sequences are aligned
+  write) and, a group, ``lo`` (first valid index; 0 unless a window
+  group has freed blocks — paged sequences are aligned
   at virtual index 0, so block contents depend only on the token prefix,
   which is what makes them shareable across requests);
 * **free-list block allocator** — blocks move between the free list,
@@ -97,14 +120,52 @@ class BlockError(ValueError):
 
 
 class _PagedSlot:
-    """Per-request decode state: virtual positions + the page table."""
+    """Per-request decode state: the virtual position, and a cache group
+    the page table and the first position still held."""
 
-    __slots__ = ("pos", "lo", "table")
+    __slots__ = ("pos", "los", "tables")
 
-    def __init__(self):
+    def __init__(self, n_groups: int = 1):
         self.pos = 0
-        self.lo = 0
-        self.table: List[int] = []      # physical block ids, virtual order
+        self.los: List[int] = [0] * n_groups
+        # physical block ids, virtual order, a group
+        self.tables: List[List[int]] = [[] for _ in range(n_groups)]
+
+    # the first group's, as every one-group caller reads them
+    @property
+    def table(self) -> List[int]:
+        return self.tables[0]
+
+    @table.setter
+    def table(self, value: List[int]) -> None:
+        self.tables[0] = value
+
+
+class _BlockGroup:
+    """One cache group's blocks: the device array, the free list and the
+    refcounts. ``window`` 0 keeps a sequence's whole context."""
+
+    __slots__ = ("index", "num_layers", "num_heads", "lanes", "window",
+                 "num_blocks", "shape", "data", "free", "ref")
+
+    def __init__(self, index, num_layers, num_heads, lanes, window,
+                 num_blocks, block_size):
+        self.index = int(index)
+        self.num_layers = int(num_layers)
+        self.num_heads = int(num_heads)
+        self.lanes = int(lanes)
+        self.window = int(window)
+        self.num_blocks = int(num_blocks)
+        # +1: physical block 0 is the reserved scratch block
+        self.shape = (self.num_layers, self.num_blocks + 1, self.num_heads,
+                      int(block_size), self.lanes)
+        self.data = None
+        # min-heap: deterministic lowest-id allocation at O(log n) —
+        # unlike the slot list (num_slots entries), num_blocks is
+        # production-large and a min()+remove() scan per block would
+        # sit on the per-cycle hot path
+        self.free: List[int] = list(range(1, self.num_blocks + 1))
+        self.ref: Dict[int, int] = {}             # block -> request refs
 
 
 class _TrieNode:
@@ -122,13 +183,17 @@ class _TrieNode:
 class PagedKVPool:
     """Block-pooled KV cache + slot/page-table/prefix-cache manager.
 
-    ``data`` is the jnp array ``[layers, num_blocks + 1, heads,
-    block_size, 2 * head_dim]`` (block 0 = scratch; a row's lanes hold
-    K then V); the engine threads it through the donated fused step and
-    rebinds it here. ``num_slots`` bounds concurrent REQUESTS (the
+    ``data`` is the FIRST cache group's jnp array ``[layers,
+    num_blocks + 1, heads, block_size, 2 * head_dim]`` (block 0 = scratch;
+    a row's lanes hold K then V); the engine threads every group's array
+    (``group_data``) through the donated fused step and rebinds them
+    here. ``num_slots`` bounds concurrent REQUESTS (the
     launch's batch axis), ``num_blocks`` bounds their total KV footprint
     — with mixed lengths the block budget, not the slot count, is what
-    fills first.
+    fills first. The positional shape arguments, ``num_blocks``,
+    ``lanes`` and ``window`` describe the first group; ``more_groups``
+    the others, each a dict of ``num_layers``, ``num_heads``, ``lanes``,
+    ``window`` and ``num_blocks`` (module doc).
     """
 
     #: storage dtypes quantized with per-block max-abs scales (the
@@ -140,7 +205,8 @@ class PagedKVPool:
                  max_len: int, head_dim: int, *, block_size: int = 16,
                  num_blocks: Optional[int] = None, dtype="float32",
                  mesh=None, mp_axis: str = "mp",
-                 lanes: Optional[int] = None):
+                 lanes: Optional[int] = None, window: int = 0,
+                 more_groups=()):
         import jax.numpy as jnp
 
         if num_slots < 1:
@@ -167,15 +233,28 @@ class PagedKVPool:
             # max_len (callers shrink this to what the device holds —
             # admission then gates on blocks and pressure preempts)
             num_blocks = self.num_slots * self.max_table_len
-        self.num_blocks = int(num_blocks)
-        if self.num_blocks < self.max_table_len:
-            raise ValueError(
-                f"num_blocks={self.num_blocks} cannot hold even one "
-                f"max-length request ({self.max_table_len} blocks)")
-        # +1: physical block 0 is the reserved scratch block
-        self.shape = (self.num_layers, self.num_blocks + 1,
-                      self.num_heads, self.block_size, self.lanes)
+        self.groups: List[_BlockGroup] = [_BlockGroup(
+            0, self.num_layers, self.num_heads, self.lanes, window,
+            num_blocks, self.block_size)]
+        for g in more_groups:
+            self.groups.append(_BlockGroup(
+                len(self.groups), g["num_layers"], g["num_heads"],
+                g["lanes"], g.get("window", 0), g["num_blocks"],
+                self.block_size))
+        for grp in self.groups:
+            need = self._blocks_a_slot_needs(grp, self.max_len)
+            if grp.num_blocks < need:
+                raise ValueError(
+                    f"num_blocks={grp.num_blocks} cannot hold even one "
+                    f"max-length request ({need} blocks)")
         self.dtype = jnp.dtype(dtype)
+        if len(self.groups) > 1 and (
+                mesh is not None or self.dtype.name in self._QUANT_QMAX):
+            raise ValueError(
+                "more than one cache group over a mesh or over int8/fp8 "
+                "blocks is not built")
+        # blocks a window group gave back behind its window, lifetime
+        self.window_blocks_freed = 0
         # tensor-parallel pool: the block array is head-partitioned over
         # a 1-D mp mesh ([.., H/mp, ..] per device) while every host
         # structure below — page tables, free list, refcounts, prefix
@@ -204,14 +283,11 @@ class PagedKVPool:
                              self.num_heads)
         self.scales = (jnp.zeros(self.scales_shape, jnp.float32)
                        if self.quantized else None)
-        self.data = self._alloc_data()
-        # min-heap: deterministic lowest-id allocation at O(log n) —
-        # unlike the slot list (num_slots entries), num_blocks is
-        # production-large and a min()+remove() scan per block would
-        # sit on the per-cycle hot path
-        self._free: List[int] = list(range(1, self.num_blocks + 1))
-        self._ref: Dict[int, int] = {}            # block -> request refs
-        # prefix cache: exact-prefix-keyed trie + LRU of released blocks
+        for grp in self.groups:
+            grp.data = self._alloc_data(grp)
+        # prefix cache (the first group's blocks; nothing is offered or
+        # matched with more than one group): exact-prefix-keyed trie + LRU
+        # of released blocks
         # (before the ledger entry below is published: its in-use figure
         # reads blocks_in_use -> _lru)
         self._trie: Dict[Tuple[int, ...], _TrieNode] = {}
@@ -246,19 +322,68 @@ class PagedKVPool:
         self.tier_hits = {"hbm": 0, "host": 0, "miss": 0}
         self.tier_degraded = 0
 
-    def _alloc_data(self):
-        """Fresh zeroed block array — head-partitioned over the mesh's
-        ``mp`` axis when this is a tensor-parallel pool (each device
-        holds ``[L, NB+1, H/mp, bs, 2*Dh]``), a plain single-device
-        array otherwise."""
+    # -- the first group's, as every one-group caller reads them ----------
+    @property
+    def data(self):
+        return self.groups[0].data
+
+    @data.setter
+    def data(self, value) -> None:
+        self.groups[0].data = value
+
+    @property
+    def shape(self):
+        return self.groups[0].shape
+
+    @property
+    def num_blocks(self) -> int:
+        return self.groups[0].num_blocks
+
+    @property
+    def _free(self) -> List[int]:
+        return self.groups[0].free
+
+    @_free.setter
+    def _free(self, value: List[int]) -> None:
+        self.groups[0].free = value
+
+    @property
+    def _ref(self) -> Dict[int, int]:
+        return self.groups[0].ref
+
+    @property
+    def group_data(self) -> tuple:
+        """Every group's block array, in group order: what the fused
+        step donates and returns."""
+        return tuple(grp.data for grp in self.groups)
+
+    @group_data.setter
+    def group_data(self, arrays) -> None:
+        for grp, a in zip(self.groups, arrays):
+            grp.data = a
+
+    def _blocks_a_slot_needs(self, grp: _BlockGroup, n_tokens: int) -> int:
+        """The most blocks of ``grp`` one sequence of ``n_tokens`` holds
+        between two chunks: all of them, or the window's."""
+        n = self.blocks_for(n_tokens)
+        if grp.window:
+            n = min(n, self.blocks_for(grp.window) + 1)
+        return n
+
+    def _alloc_data(self, grp: Optional[_BlockGroup] = None):
+        """Fresh zeroed block array of a group — head-partitioned over
+        the mesh's ``mp`` axis when this is a tensor-parallel pool (each
+        device holds ``[L, NB+1, H/mp, bs, 2*Dh]``), a plain
+        single-device array otherwise."""
         import jax
         import jax.numpy as jnp
+        shape = (grp or self.groups[0]).shape
         if self.mesh is None:
-            return jnp.zeros(self.shape, self.dtype)
+            return jnp.zeros(shape, self.dtype)
         from jax.sharding import NamedSharding, PartitionSpec as P
         sh = NamedSharding(
             self.mesh, P(None, None, self.mp_axis, None, None))
-        return jax.device_put(jnp.zeros(self.shape, self.dtype), sh)
+        return jax.device_put(jnp.zeros(shape, self.dtype), sh)
 
     # -- HBM ledger (profiler/memory.py) -----------------------------------
     def _update_ledger(self) -> None:
@@ -283,7 +408,7 @@ class PagedKVPool:
             return None
         slot = min(self._free_slots)
         self._free_slots.remove(slot)
-        self._slots[slot] = _PagedSlot()
+        self._slots[slot] = _PagedSlot(len(self.groups))
         self._update_ledger()
         _memory.mark("kv/alloc", pool=self.ledger_key, slot=slot,
                      in_use=self.bytes_in_use)
@@ -298,8 +423,10 @@ class PagedKVPool:
         if slot not in self._slots:
             raise ValueError(f"slot {slot} is not allocated")
         st = self._slots.pop(slot)
-        for b in st.table:
-            self._unref(b)
+        for grp, table in zip(self.groups, st.tables):
+            for b in table:
+                if b:                     # 0: freed behind the window
+                    self._unref(b, grp)
         self._observe()
         self._free_slots.append(slot)
         self._update_ledger()
@@ -327,7 +454,7 @@ class PagedKVPool:
                 f"slot {slot}: bad position state lo={lo} pos={pos} "
                 f"(max_len={self.max_len})")
         st.pos = int(pos)
-        st.lo = int(lo)
+        st.los = [int(lo)] * len(self.groups)
 
     def advance(self, slot: int, n: int = 1) -> int:
         """``n`` tokens landed (a decode row, or one prefill chunk of
@@ -352,16 +479,43 @@ class PagedKVPool:
                 f"slot {slot} overran the virtual capacity "
                 f"{self.max_len} — the admission check "
                 f"(prompt + max_new <= max_len) is broken")
-        if new_pos < st.lo:
+        if new_pos < max(st.los):
             raise RuntimeError(
                 f"slot {slot}: rollback below the slot's floor "
-                f"(pos={new_pos} < lo={st.lo}) — a speculative rollback "
-                f"may only unwind rows written this cycle")
+                f"(pos={new_pos} < lo={max(st.los)}) — a speculative "
+                f"rollback may only unwind rows written this cycle")
         st.pos = new_pos
+        self._free_behind_window(st)
         return st.pos
+
+    def _free_behind_window(self, st: _PagedSlot) -> None:
+        """A window group keeps ``[pos - W + 1, pos)`` of a sequence and
+        what the next rows add: every block wholly behind that goes back
+        to the free list, its table entry to the scratch block, and the
+        group's ``lo`` moves to the first position still held."""
+        freed = 0
+        for grp in self.groups:
+            if not grp.window:
+                continue
+            g, table = grp.index, st.tables[grp.index]
+            keep = min(max(0, st.pos - grp.window + 1) // self.block_size,
+                       len(table))
+            for vb in range(st.los[g] // self.block_size, keep):
+                self._unref(table[vb], grp)
+                table[vb] = 0
+                freed += 1
+            st.los[g] = max(st.los[g], keep * self.block_size)
+        if freed:
+            self.window_blocks_freed += freed
+            stat_add("serving/window_blocks_freed", freed)
+            self._observe()
 
     def slot_pos(self, slot: int) -> int:
         return self._slots[slot].pos
+
+    def slot_lo(self, slot: int, group: int = 0) -> int:
+        """First position of the slot that ``group`` still holds."""
+        return self._slots[slot].los[group]
 
     def reset_data(self) -> None:
         """Reallocate the (donated, possibly already-deleted) device
@@ -373,14 +527,15 @@ class PagedKVPool:
         if self._slots:
             raise RuntimeError(
                 "reset_data with live slots: fail and free them first")
-        self.data = self._alloc_data()
+        for grp in self.groups:
+            grp.data = self._alloc_data(grp)
+            grp.ref.clear()
+            grp.free = list(range(1, grp.num_blocks + 1))
         if self.quantized:
             self.scales = jnp.zeros(self.scales_shape, jnp.float32)
         self._trie.clear()
         self._block_key.clear()
         self._lru.clear()
-        self._ref.clear()
-        self._free = list(range(1, self.num_blocks + 1))
         # pending demotions point at the old (possibly deleted) device
         # array — drop them; already-DEMOTED host copies stay valid
         # (content is a pure function of the prefix key)
@@ -403,6 +558,31 @@ class PagedKVPool:
         """Free plus evictable (released cached) blocks."""
         return len(self._free) + len(self._lru)
 
+    def group_blocks_in_use(self, group: int) -> int:
+        """Blocks of ``group`` that page tables reference."""
+        grp = self.groups[group]
+        return grp.num_blocks - len(grp.free) \
+            - (len(self._lru) if group == 0 else 0)
+
+    def group_block_bytes(self, group: int) -> int:
+        """Device bytes of ONE block of ``group`` across its layers."""
+        grp = self.groups[group]
+        return int(np.prod(grp.shape)) * self.dtype.itemsize \
+            // self.shards // (grp.num_blocks + 1)
+
+    @property
+    def live_bytes(self) -> int:
+        """Bytes of the blocks live page tables hold, every group."""
+        if len(self.groups) == 1:
+            return self.bytes_in_use
+        return sum(self.group_blocks_in_use(g) * self.group_block_bytes(g)
+                   for g in range(len(self.groups)))
+
+    @property
+    def live_tokens(self) -> int:
+        """Tokens of the live sequences' contexts (sum of ``pos``)."""
+        return sum(st.pos for st in self._slots.values())
+
     @property
     def cached_blocks(self) -> int:
         """Blocks currently registered in the prefix cache (referenced
@@ -415,8 +595,8 @@ class PagedKVPool:
         a tensor-parallel pool holds ``1/mp`` of the heads on each
         device, so the ledger (and every byte figure derived here)
         bills what ONE chip actually stores."""
-        return int(np.prod(self.shape)) * self.dtype.itemsize \
-            // self.shards
+        return sum(int(np.prod(grp.shape)) for grp in self.groups) \
+            * self.dtype.itemsize // self.shards
 
     @property
     def scales_bytes(self) -> int:
@@ -438,6 +618,8 @@ class PagedKVPool:
         """Device bytes of ONE block across every layer/kv plane —
         scale bytes included for quantized pools (the quantum the HBM
         ledger accounts paged usage in)."""
+        if len(self.groups) > 1:
+            return self.group_block_bytes(0)
         return self.capacity_bytes // (self.num_blocks + 1)
 
     @classmethod
@@ -464,27 +646,41 @@ class PagedKVPool:
     @property
     def bytes_in_use(self) -> int:
         """Bytes claimed by live requests: only blocks referenced by
-        page tables count."""
+        page tables count (every group's)."""
+        if len(self.groups) > 1:
+            return self.live_bytes
         return self.blocks_in_use * self.block_bytes
 
     def can_admit(self, n_tokens: int) -> bool:
-        """Admission gate: enough free + evictable blocks to hold the
-        request's first ``n_tokens`` tokens. Growth past that is the
+        """Admission gate: enough free + evictable blocks IN EVERY GROUP
+        to hold the request's first ``n_tokens`` tokens (a window group:
+        its window's). Growth past that is the
         preemption policy's problem, so a head request never waits for
         its WORST case — the whole point of paging."""
-        return self.blocks_available >= self.blocks_for(n_tokens)
+        return self.blocks_available >= self._blocks_a_slot_needs(
+            self.groups[0], n_tokens) and all(
+            len(grp.free) >= self._blocks_a_slot_needs(grp, n_tokens)
+            for grp in self.groups[1:])
 
     def _observe(self) -> None:
         stat_observe("serving/kv_blocks_in_use", self.blocks_in_use)
+        for grp in self.groups[1:]:
+            stat_observe(f"serving/kv_blocks_in_use/g{grp.index}",
+                         self.group_blocks_in_use(grp.index))
         # block-granular HBM ledger refresh: _observe already fires at
         # every block-count change (alloc/unref/evict/free/reset)
         self._update_ledger()
 
-    def _alloc_block(self) -> int:
-        if not self._free:
+    def _alloc_block(self, grp: Optional[_BlockGroup] = None) -> int:
+        grp = grp or self.groups[0]
+        if not grp.free:
+            if grp.index:                # only the first group has a trie
+                raise PoolExhaustedError(
+                    f"all {grp.num_blocks} blocks of cache group "
+                    f"{grp.index} are referenced")
             self._evict_one()            # raises PoolExhaustedError
-        b = heapq.heappop(self._free)    # deterministic, like slot alloc
-        self._ref[b] = 1
+        b = heapq.heappop(grp.free)      # deterministic, like slot alloc
+        grp.ref[b] = 1
         if self.quantized:
             # a recycled block carries its previous tenant's per-block
             # max-abs scale, and _quant_append only GROWS scales
@@ -496,15 +692,18 @@ class PagedKVPool:
             self.scales = self.scales.at[:, :, b].set(0.0)
         return b
 
-    def _unref(self, b: int) -> None:
-        rc = self._ref.get(b, 0)
+    def _unref(self, b: int, grp: Optional[_BlockGroup] = None) -> None:
+        grp = grp or self.groups[0]
+        rc = grp.ref.get(b, 0)
         if rc <= 0:
             raise BlockError(
-                f"block {b} is not referenced (double free would corrupt "
+                f"block {b}{f' of cache group {grp.index}' * bool(grp.index)}"
+                f" is not referenced (double free would corrupt "
                 f"the free list)")
-        self._ref[b] = rc - 1
+        grp.ref[b] = rc - 1
         if rc == 1:
-            key = self._block_key.get(b)
+            # only the first group's blocks are ever in the prefix cache
+            key = None if grp.index else self._block_key.get(b)
             if key is not None and key in self._trie:
                 # released but cached: joins the LRU (most-recent end),
                 # reusable by a later prefix hit until evicted
@@ -514,7 +713,7 @@ class PagedKVPool:
                     # tier_tick() if still evictable then
                     self._tier_pending.add(key)
             else:
-                heapq.heappush(self._free, b)
+                heapq.heappush(grp.free, b)
 
     def _evict_one(self) -> None:
         """Reclaim the least-recently-released cached block (and drop
@@ -728,7 +927,10 @@ class PagedKVPool:
         what produces the next-token logits, and the cap is also what
         keeps every write strictly past the shared region, making COW a
         guard rail instead of a hot path). Returns the physical block
-        ids, longest match first-to-last. Read-only."""
+        ids, longest match first-to-last. Read-only. With more than one
+        cache group nothing is matched (module doc)."""
+        if len(self.groups) > 1:
+            return []
         toks = tuple(int(t) for t in tokens)
         bs = self.block_size
         blocks: List[int] = []
@@ -764,17 +966,26 @@ class PagedKVPool:
         allocation is rolled back and :class:`PoolExhaustedError`
         propagates (admission re-tries next cycle)."""
         st = self._require(slot)
-        if st.table:
+        if any(st.tables):
             raise BlockError(f"slot {slot} already has a page table")
-        got: List[int] = []
+        # a window group's blocks come as its rows are written
+        # (ensure_writable_range), never for a whole prompt
+        done: List[_BlockGroup] = []
         try:
-            for _ in range(self.blocks_for(n_tokens)):
-                got.append(self._alloc_block())
+            for grp in self.groups:
+                if grp.window:
+                    continue
+                done.append(grp)
+                st.tables[grp.index] = mine = []
+                for _ in range(self.blocks_for(n_tokens)):
+                    mine.append(self._alloc_block(grp))
         except PoolExhaustedError:
-            for b in got:
-                self._unref(b)
+            for grp in done:
+                for b in st.tables[grp.index]:
+                    self._unref(b, grp)
+                st.tables[grp.index] = []
             raise
-        st.table = got
+        got = st.table
         self.prefix_misses += 1
         stat_add("serving/prefix_miss")
         self._observe()
@@ -784,7 +995,10 @@ class PagedKVPool:
         """Publish the slot's full token blocks into the prefix cache.
         Called after a prefill WROTE them; an existing entry for the
         same prefix stays canonical (this slot's duplicate block simply
-        remains privately owned)."""
+        remains privately owned). With more than one cache group nothing
+        is offered (module doc)."""
+        if len(self.groups) > 1:
+            return
         st = self._require(slot)
         toks = tuple(int(t) for t in tokens)
         bs = self.block_size
@@ -840,6 +1054,17 @@ class PagedKVPool:
                         last_pos // self.block_size + 1):
             try:
                 cow = self._ensure_block(slot, st, vb)
+                # the other groups' blocks are never shared: growth only
+                for grp in self.groups[1:]:
+                    table = st.tables[grp.index]
+                    if vb > len(table):
+                        raise RuntimeError(
+                            f"slot {slot}: group {grp.index}'s page table "
+                            f"has {len(table)} blocks but virtual block "
+                            f"{vb} is needed")
+                    if vb == len(table):
+                        table.append(self._alloc_block(grp))
+                        self._observe()
             except PoolExhaustedError as e:
                 e.partial_cows = list(cows)
                 raise
@@ -884,15 +1109,16 @@ class PagedKVPool:
             t *= 2
         return min(t, self.max_table_len)
 
-    def table_array(self, bucket: int, slots) -> np.ndarray:
-        """Dense int32 ``[num_slots, bucket]`` page-table operand for
-        the decode step. Rows of slots outside ``slots`` (and padding
-        past a member's table) read 0 — the scratch block, whose
+    def table_array(self, bucket: int, slots, group: int = 0) -> np.ndarray:
+        """Dense int32 ``[num_slots, bucket]`` page-table operand of
+        ``group`` for the decode step. Rows of slots outside ``slots``
+        (and padding past a member's table, and a window group's freed
+        entries) read 0 — the scratch block, whose
         gathered garbage the ``[lo, pos]`` mask hides and whose writes
         nobody reads."""
         out = np.zeros((self.num_slots, int(bucket)), np.int32)
         for slot in slots:
-            table = self._require(slot).table
+            table = self._require(slot).tables[group]
             if len(table) > bucket:
                 raise RuntimeError(
                     f"slot {slot}: table length {len(table)} exceeds its "
@@ -900,8 +1126,8 @@ class PagedKVPool:
             out[slot, :len(table)] = table
         return out
 
-    def slot_table(self, slot: int) -> List[int]:
-        return list(self._require(slot).table)
+    def slot_table(self, slot: int, group: int = 0) -> List[int]:
+        return list(self._require(slot).tables[group])
 
     def _require(self, slot: int) -> _PagedSlot:
         st = self._slots.get(slot)
@@ -910,7 +1136,11 @@ class PagedKVPool:
         return st
 
     def __repr__(self):
+        more = "".join(
+            f" g{grp.index}(w{grp.window})="
+            f"{self.group_blocks_in_use(grp.index)}/{grp.num_blocks}"
+            for grp in self.groups[1:])
         return (f"<PagedKVPool blocks={self.blocks_in_use}/"
-                f"{self.num_blocks} x{self.block_size} "
+                f"{self.num_blocks}{more} x{self.block_size} "
                 f"active={self.n_active}/{self.num_slots} "
                 f"cached={len(self._trie)}>")

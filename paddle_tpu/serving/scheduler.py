@@ -716,7 +716,9 @@ class Scheduler:
     def note_launch(self, rows: int, q: int, t: int, kv_tokens: int,
                     kv_steps: int, kv_fetches: int,
                     kv_row_tokens: int = 0,
-                    kv_write_blocks: int = 0) -> None:
+                    kv_write_blocks: int = 0,
+                    kv_tokens_window: Optional[int] = None,
+                    kv_row_tokens_window: Optional[int] = None) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
@@ -732,7 +734,14 @@ class Scheduler:
         — which is what an attention kernel's products are counted
         from; ``kv_write_blocks``, the (slot, block) pairs the real rows
         land in — the blocks the cache append reads, fills in and
-        writes back, once each a layer (``ops/kv_append.py``)."""
+        writes back, once each a layer (``ops/kv_append.py``). For a model
+        with a sliding-window cache group (``models/decoder_spec.py``)
+        ``kv_tokens`` / ``kv_row_tokens`` / ``kv_steps`` / ``kv_fetches``
+        are the window-0 (first) group's, and two keys join them:
+        ``kv_tokens_window``, the tokens a window layer must read (sum
+        over the planned slots of ``min(kv_len, W - 1 + rows)``), and
+        ``kv_row_tokens_window``, the (row, visible token) pairs under
+        the window mask."""
         if self._rec is not None:
             self._rec.update(launch_rows=int(rows), launch_q=int(q),
                              launch_t=int(t), kv_tokens=int(kv_tokens),
@@ -740,6 +749,10 @@ class Scheduler:
                              kv_fetches=int(kv_fetches),
                              kv_row_tokens=int(kv_row_tokens),
                              kv_write_blocks=int(kv_write_blocks))
+            if kv_tokens_window is not None:
+                self._rec.update(
+                    kv_tokens_window=int(kv_tokens_window),
+                    kv_row_tokens_window=int(kv_row_tokens_window))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into
@@ -1303,6 +1316,7 @@ class Scheduler:
             # commit alone; the tokens its landing emits; whether a
             # commit rode with the pass)
             passes: Dict[int, Tuple[int, int, bool]] = {}
+            freed0 = self._pool.window_blocks_freed
             for slot, req in active.items():
                 if self._block > 1 and not req.pending_feed:
                     passes[slot] = self._advance_block(slot, req,
@@ -1314,6 +1328,15 @@ class Scheduler:
                     fed[slot] = len(req.pending_feed)
                 if not req.pending_feed and self._block == 1:
                     req.in_flight += 1
+            if len(self._pool.groups) > 1:
+                # what the cache groups hold once this launch's rows are
+                # in: the blocks a window group gave back behind its
+                # window, the bytes of the blocks live tables still name
+                # (every group) and the tokens of the live contexts
+                rec["window_blocks_freed"] = \
+                    self._pool.window_blocks_freed - freed0
+                rec["kv_live_bytes"] = self._pool.live_bytes
+                rec["kv_live_tokens"] = self._pool.live_tokens
             rec["decode_dispatch_ms"] += (time.perf_counter() - t1) * 1e3
         return {"cycle": self._cycle, "rec": rec, "active": active,
                 "plan": plan, "spec": spec, "fed": fed, "toks": toks_dev,

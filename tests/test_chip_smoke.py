@@ -98,6 +98,23 @@ def test_axk1_serve_phase():
     assert out["tokens"] == 8 and out["max_gap"] < 1e-3
 
 
+def test_mimo_serve_phase():
+    """The window-and-global phase at toy widths: both cache groups, a
+    context of five windows."""
+    import json
+    data = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "tests", "data",
+        "tiny-mimo-config.json")
+    with open(data) as f:
+        cfg = json.load(f)
+    out = chip_smoke.phase_mimo_serve(
+        cfg["model"], dtype="float32", max_len=64, block_size=8,
+        num_slots=2, num_blocks=16, prefill_budget=16,
+        prompt_lens=(5, 34), new_tokens=6,
+        limits=cfg["serving"]["check"]["limits"], width=48, q_block=16)
+    assert out["tokens"] == 12 and out["max_gap"] < 1e-3
+
+
 def test_sdar_serve_phase():
     """The grouped-head / block-generation phase at toy widths."""
     import json

@@ -129,7 +129,8 @@ def _random_ragged_case(rng, *, dtype="float32"):
 
 def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
                dtype="float32", nan_at=(), seed=0, q_heads=None,
-               mask_block=1):
+               mask_block=1, window=0, sinks=False, dv=0, lanes=0,
+               free_behind=False):
     """A hand-built launch for the grouped KV walk: ``seqs`` is a list of
     ``(q_len, kv_len)`` (the q rows are the context's tail), each
     sequence's blocks drawn from a shuffled pool, its table padded with
@@ -140,9 +141,17 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
     ``[N, H, Dh]`` of the kernel and of the oracle, and the kernel's
     whole output. ``heads`` are the pool's KV heads; ``q_heads`` (a
     multiple of them: grouped-query attention) defaults to the same;
-    ``mask_block`` B lets a row see to the end of its block of B."""
+    ``mask_block`` B lets a row see to the end of its block of B;
+    ``window`` W lets it see its last W columns only, and with
+    ``free_behind`` every block wholly behind a sequence's first row's
+    window is FREED as the pool frees it (its table entry names the
+    scratch block, which is NaN, and ``lo`` is the first position still
+    held); ``sinks`` draws a logit a query head; ``dv`` makes V the last
+    ``dv`` lanes of a row of ``lanes`` (default ``dh + dv``) and K the
+    first ``dh``."""
     import jax.numpy as jnp
     q_heads = q_heads or heads
+    lanes = lanes or (dh + dv if dv else 2 * dh)
 
     rng = np.random.RandomState(seed)
     S = len(seqs)
@@ -155,7 +164,7 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
             .astype(np.int8)
         scales = (0.2 + rng.rand(2, 2, nb + 1, heads)).astype(np.float32) / 64
     else:
-        pool = rng.randn(2, nb + 1, heads, bs, 2 * dh).astype(np.float32)
+        pool = rng.randn(2, nb + 1, heads, bs, lanes).astype(np.float32)
         scales = None
     free = list(range(1, nb + 1))
     rng.shuffle(free)
@@ -168,12 +177,20 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
     blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, pos0s)
     q = rng.randn(q_heads, len(blk_seq) * 8, dh).astype(np.float32)
     lo = np.zeros(S, np.int32)
+    if free_behind:
+        for s in range(S):
+            gone = max(0, pos0s[s] - window + 1) // bs
+            tables[s, :gone] = 0
+            lo[s] = gone * bs
+        nan_at = tuple(nan_at) + ("scratch",)
+    sink = rng.randn(q_heads).astype(np.float32) if sinks else None
     rows = [(s, i) for s in range(S) for i in range(q_lens[s])]
     ref = reference_ragged_attention(
         np.stack([q[:, qstart[s] + i] for s, i in rows]), pool, 1,
         [s for s, _ in rows], [pos0s[s] + i for s, i in rows],
         [list(t) for t in tables], lo, scales=scales,
-        mask_block=mask_block, kv_len=kv_len)
+        mask_block=mask_block, kv_len=kv_len, window=window, sinks=sink,
+        v_lanes=dv)
     for at in nan_at:
         pool[:, 0 if at == "scratch" else tables[at]] = np.nan
     qdt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
@@ -181,7 +198,8 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
         jnp.asarray(q, qdt), jnp.asarray(pool, dtype), 1, blk_seq, qstart,
         pos0, tables, lo, kv_len,
         scales=None if scales is None else jnp.asarray(scales),
-        mask_block=mask_block).astype(jnp.float32))
+        mask_block=mask_block, window=window, sinks=sink,
+        v_lanes=dv).astype(jnp.float32))
     got = np.stack([out[:, qstart[s] + i] for s, i in rows])
     return got, ref, out
 
@@ -225,6 +243,51 @@ class TestKernelParity:
         got, ref, _ = _walk_case(seqs, **kw)
         tol = {"bfloat16": 0.08, "int8": 2e-4}.get(kw.get("dtype"), 2e-5)
         np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+
+    # window x sinks x lanes x group: a decode row far past its window,
+    # a row whose window is not full yet, chunks that cross blocks and
+    # windows, freed blocks behind the window (NaN scratch), K and V
+    # lanes apart (equal-sized tiles: the zero-extended form; whole
+    # 128-lane tiles with padding between: the split form at the
+    # published 192 | 128 in 384)
+    @pytest.mark.parametrize("seqs,kw", [
+        ([(1, 300), (1, 20), (44, 190)], {"window": 32, "bs": 16}),
+        ([(1, 300), (1, 20), (44, 190)],
+         {"window": 32, "bs": 16, "free_behind": True}),
+        ([(1, 300), (9, 130)], {"window": 33, "bs": 16, "sinks": True}),
+        ([(1, 300), (9, 130)], {"window": 31, "bs": 16, "sinks": True,
+                                "free_behind": True}),
+        ([(1, 135), (9, 263)], {"sinks": True}),          # sinks, no window
+        ([(1, 135), (9, 263)], {"bs": 16, "dh": 24, "dv": 16}),
+        ([(1, 135), (9, 263)], {"bs": 16, "dh": 24, "dv": 16, "lanes": 48,
+                                "window": 40, "sinks": True,
+                                "free_behind": True}),
+        ([(1, 200), (20, 150)],
+         {"heads": 2, "q_heads": 16, "bs": 16, "window": 48,
+          "sinks": True, "free_behind": True}),           # group of 8
+        ([(1, 200), (20, 150)],
+         {"heads": 1, "q_heads": 16, "bs": 16}),          # group of 16
+        ([(1, 300), (20, 200)],
+         {"heads": 2, "q_heads": 16, "bs": 16, "dh": 192, "dv": 128,
+          "lanes": 384, "window": 128, "sinks": True,
+          "free_behind": True}),                          # a window layer
+        ([(1, 300), (20, 200)],
+         {"heads": 1, "q_heads": 16, "bs": 16, "dh": 192, "dv": 128,
+          "lanes": 384}),                                 # a global layer
+        ([(1, 300), (20, 200)],
+         {"heads": 2, "q_heads": 16, "bs": 16, "dh": 192, "dv": 128,
+          "lanes": 384, "window": 128, "sinks": True,
+          "free_behind": True, "dtype": "bfloat16"}),
+    ], ids=["window", "window-freed", "window-sinks", "window-sinks-freed",
+            "sinks", "lanes24-16", "lanes24-16-in-48-window-sinks-freed",
+            "gqa8-window-sinks-freed", "gqa16", "window-layer-192-128",
+            "global-layer-192-128", "window-layer-192-128-bf16"])
+    def test_window_sinks_lanes_groups_match_oracle(self, seqs, kw):
+        got, ref, out = _walk_case(seqs, **kw)
+        tol = {"bfloat16": 0.08}.get(kw.get("dtype"), 2e-5)
+        np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+        if kw.get("free_behind"):
+            assert np.isfinite(out).all()    # a freed block is never read
 
     @pytest.mark.parametrize("seqs,nan_at,clean", [
         # the scratch block the tables pad with is never fetched

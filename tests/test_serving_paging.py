@@ -593,3 +593,185 @@ class TestValidationAndStats:
         assert s1["prefix_hit_ratio"] == 0.0
         assert s1["num_blocks"] == eng._pool.num_blocks
         assert 0 <= s1["block_utilization"] <= 1
+
+
+# ---------------------------------------------------------------------------
+# cache groups: a window-0 group and a sliding-window group in ONE manager
+# ---------------------------------------------------------------------------
+
+def _grouped_pool(window=16, window_blocks=8, **kw):
+    """Two groups over blocks of 8: the first keeps the whole context,
+    the second a window of ``window`` positions."""
+    kw.setdefault("num_blocks", 16)
+    return _paged_pool(more_groups=[dict(
+        num_layers=2, num_heads=2, lanes=4, window=window,
+        num_blocks=window_blocks)], **kw)
+
+
+def _check_group_lists(pool):
+    for grp in pool.groups[1:]:
+        free = set(grp.free)
+        assert len(free) == len(grp.free), "free list holds duplicates"
+        held = {b for b, rc in grp.ref.items() if rc > 0}
+        assert not free & held and 0 not in free | held
+        assert len(free) + len(held) == grp.num_blocks
+        named = [b for st in pool._slots.values()
+                 for b in st.tables[grp.index] if b]
+        assert sorted(named) == sorted(held)      # each named exactly once
+
+
+class TestCacheGroups:
+    def test_the_one_group_pools_numbers_are_what_they_were(self):
+        pool = _paged_pool(num_blocks=16, head_dim=2)
+        assert len(pool.groups) == 1 and pool.groups[0].window == 0
+        assert pool.shape == (1, 17, 1, 8, 4)
+        assert pool.capacity_bytes == 17 * 8 * 4 * 4
+        assert pool.block_bytes == 8 * 4 * 4
+        s = pool.alloc()
+        pool.admit_fresh(s, 20)
+        pool.set_slot(s, pos=0, lo=0)
+        assert pool.slot_table(s) == [1, 2, 3] and pool.blocks_in_use == 3
+        assert pool.bytes_in_use == pool.live_bytes == 3 * pool.block_bytes
+        pool.advance(s, 20)
+        assert pool.slot_lo(s) == 0 and pool.window_blocks_freed == 0
+        assert pool.live_tokens == 20
+        assert pool.table_array(4, [s]).tolist()[s] == [1, 2, 3, 0]
+        _check_free_list(pool)
+        pool.free(s)
+        assert pool.blocks_in_use == 0 and pool.blocks_available == 16
+
+    @pytest.mark.parametrize("window,chunk", [(16, 1), (16, 5), (10, 8),
+                                              (8, 16), (1, 3)],
+                             ids=["decode", "chunks-of-5", "w10-chunks-of-8",
+                                  "w8-chunks-of-16", "w1"])
+    def test_a_window_group_frees_exactly_the_blocks_behind_the_window(
+            self, window, chunk):
+        """Walk 60 positions in launches of ``chunk`` rows: before a
+        launch every position its rows may attend to is held, after it
+        exactly the blocks wholly behind ``pos - W + 1`` are gone (table
+        entry 0), nothing freed is handed out while held, and the
+        window-0 group keeps everything."""
+        pool = _grouped_pool(window=window)
+        s = pool.alloc()
+        pool.admit_fresh(s, 24)
+        pool.set_slot(s, pos=0, lo=0)
+        assert pool.slot_table(s, 1) == []        # window blocks come later
+        freed = 0
+        while pool.slot_pos(s) + chunk <= 60:
+            pos = pool.slot_pos(s)
+            assert pool.ensure_writable_range(s, pos + chunk - 1) == []
+            wt = pool.slot_table(s, 1)
+            lo = pool.slot_lo(s, 1)
+            assert len(wt) == len(pool.slot_table(s)) \
+                or len(wt) == (pos + chunk - 1) // 8 + 1
+            for p in range(max(0, pos - window + 1), pos + chunk):
+                assert wt[p // 8] != 0 and p >= lo, (pos, p)
+            assert len(set(b for b in wt if b)) == sum(b != 0 for b in wt)
+            pool.advance(s, chunk)
+            pos = pool.slot_pos(s)
+            wt = pool.slot_table(s, 1)
+            gone = max(0, pos - window + 1) // 8
+            assert [b == 0 for b in wt] == \
+                [vb < gone for vb in range(len(wt))]
+            assert pool.slot_lo(s, 1) == gone * 8
+            assert pool.window_blocks_freed == gone >= freed
+            freed = gone
+            assert pool.group_blocks_in_use(1) == len(wt) - gone
+            assert 0 not in pool.slot_table(s)    # the global group: all
+            _check_free_list(pool)
+            _check_group_lists(pool)
+        assert freed >= (60 - window - 7) // 8
+        assert pool.table_array(8, [s], group=1)[s].tolist()[:freed] \
+            == [0] * freed
+
+    def test_a_held_block_is_never_handed_out(self):
+        """Four slots decode side by side in a window group with exactly
+        the blocks the sizing rule gives (slots x (ceil(W / bs) + 2)): no
+        launch finds it short, and a block freed by one slot serves
+        another only once its table entry is 0."""
+        pool = _grouped_pool(window=16, window_blocks=4 * 4, num_blocks=40,
+                             max_len=80)
+        slots = [pool.alloc() for _ in range(4)]
+        for i, s in enumerate(slots):
+            pool.admit_fresh(s, 10 + i)
+            pool.set_slot(s, pos=0, lo=0)
+            pool.ensure_writable_range(s, 9 + i)
+            pool.advance(s, 10 + i)
+        for _ in range(60):
+            for s in slots:
+                pool.ensure_writable_range(s, pool.slot_pos(s))
+            for s in slots:
+                pool.advance(s, 1)
+            named = [b for s in slots for b in pool.slot_table(s, 1) if b]
+            assert len(named) == len(set(named))
+            _check_group_lists(pool)
+        assert pool.window_blocks_freed >= 4 * 6
+
+    def test_admission_gates_on_every_group_and_exhaustion_is_named(self):
+        pool = _grouped_pool(window=16, window_blocks=3, num_blocks=8,
+                             num_slots=2)
+        assert pool.can_admit(40)                 # 5 global, 3 of window
+        a = pool.alloc()
+        pool.admit_fresh(a, 24)
+        pool.set_slot(a, pos=0, lo=0)
+        pool.ensure_writable_range(a, 23)         # three window blocks
+        assert not pool.can_admit(8)              # the window group is out
+        assert pool.blocks_available == 5         # the global one is not
+        b = pool.alloc()
+        pool.admit_fresh(b, 8)
+        pool.set_slot(b, pos=0, lo=0)
+        with pytest.raises(PoolExhaustedError, match="cache group 1"):
+            pool.ensure_writable_range(b, 0)
+        pool.free(b)
+        pool.advance(a, 24)                       # frees one behind pos 9
+        assert pool.can_admit(8) and pool.window_blocks_freed == 1
+        _check_group_lists(pool)
+
+    def test_preemption_returns_both_groups_blocks(self):
+        pool = _grouped_pool(window=16)
+        s = pool.alloc()
+        pool.admit_fresh(s, 30)
+        pool.set_slot(s, pos=0, lo=0)
+        pool.ensure_writable_range(s, 29)
+        pool.advance(s, 30)
+        assert pool.blocks_in_use == 4 and pool.group_blocks_in_use(1) == 3
+        assert pool.live_bytes == 4 * pool.group_block_bytes(0) \
+            + 3 * pool.group_block_bytes(1)
+        assert pool.group_block_bytes(1) == 2 * 2 * 8 * 4 * 4
+        pool.free(s)                              # what a preemption does
+        assert pool.blocks_in_use == 0 and pool.group_blocks_in_use(1) == 0
+        assert pool.live_bytes == 0 and pool.live_tokens == 0
+        _check_free_list(pool)
+        _check_group_lists(pool)
+        s = pool.alloc()                          # and the slot starts anew
+        assert pool.slot_lo(s, 1) == 0 and pool.slot_table(s, 1) == []
+
+    def test_no_block_is_offered_or_matched_with_a_window_group(self):
+        pool = _grouped_pool()
+        toks = list(range(1, 25))
+        s = pool.alloc()
+        pool.admit_fresh(s, 24)
+        pool.set_slot(s, pos=0, lo=0)
+        pool.ensure_writable_range(s, 23)
+        pool.advance(s, 23)
+        pool.register_prefix(s, toks)
+        assert pool.cached_blocks == 0 and pool.match_prefix(toks) == []
+        pool.free(s)
+        assert pool.blocks_available == 16        # nothing waits in an LRU
+
+    def test_reset_and_refusals(self):
+        pool = _grouped_pool()
+        s = pool.alloc()
+        pool.admit_fresh(s, 8)
+        pool.set_slot(s, pos=0, lo=0)
+        pool.ensure_writable_range(s, 7)
+        pool.free(s)
+        pool.reset_data()
+        assert [g.data.shape for g in pool.groups] == [(1, 17, 1, 8, 2),
+                                                       (2, 9, 2, 8, 4)]
+        assert len(pool.groups[1].free) == 8
+        assert pool.capacity_bytes == (17 * 8 * 2 + 2 * 9 * 2 * 8 * 4) * 4
+        with pytest.raises(ValueError, match="more than one cache group"):
+            _grouped_pool(dtype="int8")
+        with pytest.raises(ValueError, match="cannot hold even one"):
+            _grouped_pool(window=16, window_blocks=2)

@@ -154,6 +154,60 @@ def test_kv_append_compiles_for_four_kv_heads_of_128(v5e):
     assert "kv_append" in text
 
 
+@pytest.mark.parametrize("hkv,window,q_lens,q_bucket", [
+    (4, 0, [1] * 128, 1024),               # a global layer, decode rows
+    (8, 128, [1] * 128, 1024),             # a window layer, decode rows
+    (4, 0, [1] * 64 + [1000], 2048),       # beside a prompt chunk
+    (8, 128, [1] * 64 + [1000], 2048),
+], ids=["global-q1024", "window-q1024", "global-chunk-q2048",
+        "window-chunk-q2048"])
+def test_ragged_paged_attention_compiles_for_window_and_global_layers(
+        v5e, hkv, window, q_lens, q_bucket):
+    """MiMo-V2-Flash's two layer kinds at the published widths: 64 query
+    heads of 192 on 4 (global) or 8 (window, W 128, a sink logit a head)
+    KV heads, V heads of 128, a row stored 384 lanes wide (K, zeros to
+    256, V), tables of 576 blocks. The window form carries its own
+    kernel name; both fetch 8 blocks a group inside the VMEM budget."""
+    S, T, NB, hq, dk, dv, lanes, bs = len(q_lens), 576, 64, 64, 192, 128, \
+        384, 16
+    assert kv_group_blocks(hkv, bs, 0, "bfloat16", lanes=lanes) * bs == 128
+    assert 2 * 8 * hkv * bs * lanes * 2 <= KV_VMEM_BUDGET
+    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, [4000] * S,
+                                                q_bucket=q_bucket)
+    tables, lo = np.zeros((S, T), np.int32), np.zeros(S, np.int32)
+    kv_len = np.asarray([4000 + n for n in q_lens], np.int32)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    def fn(q, pool, sinks):
+        return ragged_paged_attention(
+            q, pool, 1, blk_seq, qstart, pos0, tables, lo, kv_len,
+            window=window, sinks=sinks if window else None, v_lanes=dv)
+
+    text = _compile(fn, sds((hq, q_bucket, dk), jnp.bfloat16),
+                    sds((2, NB + 1, hkv, bs, lanes), jnp.bfloat16),
+                    sds((hq,), jnp.float32))
+    calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    want = "%ragged_paged_attention_window" if window \
+        else "%ragged_paged_attention"
+    assert len(calls) == 1 and calls[0].startswith(want), calls
+    assert window or "_window" not in calls[0]
+
+
+@pytest.mark.parametrize("hkv", [4, 8], ids=["global", "window"])
+def test_kv_append_compiles_for_rows_of_384_lanes(v5e, hkv):
+    """The same model's cache write: K 192 | V 128 in rows of 384."""
+    assert append_ring_blocks(hkv, 16, 192, "bfloat16") * hkv * 16 * 384 \
+        * 2 <= APPEND_VMEM_BUDGET
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    text = _compile(lambda pool, wb, off, rows:
+                    kv_append(pool, 1, wb, off, rows),
+                    sds((2, 65, hkv, 16, 384), jnp.bfloat16),
+                    sds((1024,), jnp.int32), sds((1024,), jnp.int32),
+                    sds((1024, hkv, 384), jnp.bfloat16))
+    assert "kv_append" in text
+
+
 def _append_avals(chip, heads, q_bucket, pool_dtype="bfloat16"):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     return [sds((2, 65, heads, 16, 2 * DH), pool_dtype),
