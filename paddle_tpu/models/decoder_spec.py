@@ -42,7 +42,9 @@ and no sinks.
 KV heads a token leaves in a ``full`` cache; ``attn_in`` may return more
 query heads than that (a multiple: grouped-query attention), and the
 kernel folds a KV head's group of query heads into the rows of its
-products (``ops/ragged_paged_attention.py``).
+products (``ops/ragged_paged_attention.py``). ``LayerSpec.query_heads``
+says how many (0: one a cache row), so that the engine can tell how wide
+the kernel's grid steps are without asking the model.
 
 **The generation rule** (:class:`GenerationRule`): ``block_length`` 1 is
 one token a sequence a step, the next token from the last row's logits.
@@ -109,10 +111,15 @@ class LayerSpec:
     ffn: str
     window: int = 0          # 0: a row sees all of the context
     sinks: bool = False      # a learned logit a query head in the softmax
+    query_heads: int = 0     # 0: as many as the cache's rows (KV heads)
 
     def __post_init__(self):
         if self.window < 0:
             raise ValueError(f"window {self.window} must be >= 0")
+        if self.query_heads % self.cache.rows:
+            raise ValueError(
+                f"query_heads {self.query_heads} is no multiple of the "
+                f"cache's {self.cache.rows} rows a token")
         if (self.window or self.sinks) and self.attention != FULL:
             raise ValueError(
                 "a sliding window and sink logits are built for the full "
@@ -206,8 +213,17 @@ class DecoderSpec:
                 keys.append(key)
                 members[key] = []
             members[key].append(i)
-        return tuple(CacheGroup(a, c, w, tuple(members[(a, c, w)]))
-                     for a, c, w in keys)
+        groups = []
+        for a, c, w in keys:
+            layers = tuple(members[(a, c, w)])
+            heads = {self.layers[i].query_heads for i in layers}
+            if len(heads) > 1:
+                raise ValueError(
+                    f"layers {layers} share a cache group and differ in "
+                    f"query heads {sorted(heads)}: one kernel form a group")
+            groups.append(CacheGroup(a, c, w, layers,
+                                     max(1, heads.pop() // c.rows)))
+        return tuple(groups)
 
     def layer_group(self, layer: int) -> Tuple[int, int]:
         """``(group, index inside the group's pool array)`` of a layer."""
@@ -226,6 +242,7 @@ class CacheGroup:
     cache: CacheSpec
     window: int
     layers: Tuple[int, ...]
+    q_group: int = 1         # query heads a KV head, of its layers
 
 
 def serving_decoder(model):

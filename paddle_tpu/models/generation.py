@@ -731,6 +731,11 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
         # the page table. The two XLA scatters send pad rows to the
         # scratch block nobody reads; kv_append skips them
         if ls.attention == FULL:
+            if q.shape[0] != (ls.query_heads or ls.cache.rows):
+                raise ValueError(
+                    f"layer {li} hands the kernel {q.shape[0]} query heads "
+                    f"and its spec says {ls.query_heads or ls.cache.rows}: "
+                    f"the engine counts the kernel's walks from the spec")
             if quantized:
                 pools[g], scales = _quant_append(
                     pools[g], scales, gi, wbs[g], write_off, *rows, qmax)

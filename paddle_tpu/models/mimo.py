@@ -326,7 +326,8 @@ class MiMoLayer(nn.Layer):
                              lanes=stored_lanes(a.dk, a.dv),
                              k_lanes=a.dk, v_lanes=a.dv)
         return DS.LayerSpec(DS.FULL, cache, self.ffn.kind,
-                            window=a.window, sinks=a.has_sinks)
+                            window=a.window, sinks=a.has_sinks,
+                            query_heads=a.heads)
 
     def _ffn(self, x, valid):
         y, counters = self.ffn.apply(

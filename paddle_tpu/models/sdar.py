@@ -281,7 +281,8 @@ class SDARForCausalLM(nn.Layer):
         cache = DS.CacheSpec(rows=cfg.num_key_value_heads,
                              lanes=2 * cfg.head_dim)
         self.spec = DS.DecoderSpec(
-            layers=tuple(DS.LayerSpec(DS.FULL, cache, DS.ROUTED)
+            layers=tuple(DS.LayerSpec(DS.FULL, cache, DS.ROUTED,
+                                      query_heads=cfg.num_attention_heads)
                          for _ in self.layers),
             vocab_size=cfg.vocab_size,
             max_positions=cfg.max_position_embeddings,

@@ -12,14 +12,14 @@ exactly the blocks a sequence owns. Nothing is gathered, nothing is
 padded to the table bucket, and a single launch serves a RAGGED batch of
 mixed prefill-chunk and decode rows (the chunked-prefill unlock).
 
-The KV walk (PR 28; the one statement of it): the grid is over q blocks
-only, and one grid step owns a sequence's q block for EVERY head, so
-the page table is walked once, not ``H`` times. A KV block comes in ONE
+The KV walk (PR 28; the one statement of it): a WALK serves a run of
+query rows of one sequence for EVERY head, so the page table is walked
+once, not ``H`` times. A KV block comes in ONE
 async DMA — ``pool[layer, pid]`` whole, every head's K|V, 80 KB at
 GPT-2 large — and ``G`` of them go out together as a group, into one of
 two VMEM buffers: the next group's DMAs are started before this group
-is waited for, so only a grid step's first group is exposed. Compute is
-once a group: per head the scores are ``[block_q, G * block_size]``, a
+is waited for, so only a walk's first group is exposed. Compute is
+once a group: per head the scores are ``[rows, G * block_size]``, a
 full 128 lanes, and the online-softmax update (max, exp, rescale,
 ``p . V``) runs on that tile; a block's K|V tile goes to the MXU whole
 (q zero-extended over the V lanes), never split along its lanes. ``G``
@@ -28,13 +28,32 @@ dtype alone (``kv_group_blocks``). Before it the walk was one blocking
 4 KB copy per (block, head) and two 16-lane products a copy: 0.65 us a
 step, 0.86 M steps a decode launch of GPT-2 large (PERF.md section 6).
 
+Wide q steps (PR 36): the grid is over SUPER blocks of ``M`` q blocks
+(``q_step_blocks``: 4, 32 query rows, unless the launch is narrower or
+a wide step's working set would pass ``Q_VMEM_BUDGET``). A grid step
+reads the ``blk_seq`` entries of its ``M`` q blocks. Where all name the
+same sequence — the inside of a prompt chunk — it makes ONE walk for all
+``32 * g`` folded rows: a KV block is fetched once for 32 query rows,
+not four times, in the same two buffers. Otherwise (decode rows, a
+chunk's first and last q blocks beside other sequences, pad blocks) it
+makes a walk a q block, as before, in a loop over the same code. And a
+walk ENDS where its rows stop seeing: at the block of its last row's
+last visible column (its own position, or its diffusion block's end),
+whatever ``kv_len`` is — a chunk's early rows do not fetch the chunk's
+later blocks (``_walk_extent``; a decode row's last column is ``kv_len
+- 1``, its walk is what it was). ``ragged_walk_counts`` counts, on the
+host, the DMAs and group waits of a launch from the same two functions
+the kernel decides by.
+
 Layout contract (the serving engine's fused step builds these):
 
 * queries are FLATTENED over the batch: each sequence's ``q_len[s]``
   rows sit contiguously, padded up to a multiple of ``block_q`` (8, the
-  fp32 sublane) so one grid step never mixes sequences — decode rows
-  cost one padded q block, prefill chunks amortize theirs;
-* scalar-prefetch metadata maps grid steps back to sequences:
+  fp32 sublane) so one q block never mixes sequences — decode rows
+  cost one padded q block, prefill chunks amortize theirs (a grid step
+  of ``M`` q blocks may hold several sequences: it then walks q block
+  by q block);
+* scalar-prefetch metadata maps q blocks back to sequences:
   ``blk_seq`` names the sequence of each q block (−1 = pad block),
   ``seq_qstart``/``seq_pos0`` recover every row's virtual cache
   position, ``tables`` is the page table, ``kv_len`` bounds the KV walk
@@ -52,10 +71,10 @@ Layout contract (the serving engine's fused step builds these):
   % B == 0``), so the rows of one are one DMA.
 
 A static ``window`` W > 0 (a sliding-window layer): the row sees
-``[max(lo, p - W + 1), p]``, W keys with its own, and a q block's walk
+``[max(lo, p - W + 1), p]``, W keys with its own, and a walk
 STARTS at the block of its first row's ``p - W + 1`` and ends at its last
 row's block — a decode row walks at most ``ceil(W / block_size) + 1``
-blocks whatever the context, and the table entries of the blocks before
+blocks whatever the context, the 32 rows of a wide step two more, and the table entries of the blocks before
 (freed, and pointing at the scratch block) are never read. ``sinks [H]``
 float32: a learned logit a query head joins the softmax's denominator
 and adds no value — the running max STARTS at the sink and the running
@@ -67,10 +86,10 @@ apart.
 Grouped-query heads: the pool holds ``Hkv`` KV heads and ``q`` has ``H =
 g * Hkv`` query heads, query head ``j`` reading KV head ``j // g``. A KV
 head's group of query heads is FOLDED INTO THE ROWS of the products: the
-q block of a grid step is ``[Hkv, block_q * g, Dh]`` (row ``r`` is query
-row ``r // g``, head ``r % g`` of the group: 8 x 8 = 64 MXU rows a KV
-tile at ``g`` 8), so a block is still fetched once for all its readers
-and the walk is unchanged. The fold and its inverse are two transposes
+q block of a grid step is ``[Hkv, M * block_q * g, Dh]`` (row ``r`` is
+query row ``r // g``, head ``r % g`` of the group: 8 x 8 = 64 MXU rows a
+KV tile and q block at ``g`` 8), so a block is still fetched once for all
+its readers and the walk is unchanged. The fold and its inverse are two transposes
 in the wrapper, which XLA joins with the caller's own. With ``g`` 1 and
 B 1 the kernel compiles to what it was.
 
@@ -93,9 +112,11 @@ that touches the pool — ``serving/paging.py``, ``ops/kv_append.py``,
 shard — reads this one layout.
 
 Mosaic legality (enforced by the ``pallas-block-tiling`` self-lint):
-q/o blocks are ``(H, block_q, Dh)`` with ``block_q = 8`` sublane-aligned
-and ``Dh`` the full array dim; a KV buffer is ``(H, G * block_size,
-2 * Dh)`` and a block's DMA lands in ``block_size`` of its rows, with
+q/o blocks are ``(H, M * block_q * g, Dh)`` with ``block_q = 8``
+sublane-aligned and ``Dh`` the full array dim (a q block on its own is a
+dynamic slice of ``block_q * g`` of those rows); a KV buffer is ``(H,
+G * block_size, 2 * Dh)`` and a block's DMA lands in ``block_size`` of
+its rows, with
 ``block_size`` at least the storage dtype's sublane count and, on a TPU,
 ``2 * Dh`` a multiple of 128 (``check_kv_tile``; compiled for a described
 v5e at GPT-2 widths in tests/test_tpu_compile.py).
@@ -129,14 +150,17 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_kernels import _interpret, _x64_off
 
 __all__ = ["ragged_paged_attention", "ragged_layout", "BLOCK_Q",
-           "MIN_KV_BLOCK", "KV_VMEM_BUDGET", "min_kv_block_for",
-           "check_kv_tile", "kv_group_blocks"]
+           "MIN_KV_BLOCK", "KV_VMEM_BUDGET", "Q_VMEM_BUDGET",
+           "Q_STEP_BLOCKS", "min_kv_block_for", "check_kv_tile",
+           "kv_group_blocks", "q_step_blocks", "ragged_walk_counts"]
 
 _NEG_INF = -1e30
 
-# q rows per grid step: the fp32 sublane count — the smallest
-# Mosaic-legal second-to-last block dim, so a decode row (1 real query)
-# wastes at most 7 pad rows while a prefill chunk fills whole blocks
+# q rows of a q block, the unit a sequence's rows are padded to and the
+# rows of a walk that serves one sequence alone: the fp32 sublane count —
+# the smallest Mosaic-legal second-to-last block dim, so a decode row (1
+# real query) wastes at most 7 pad rows while a prefill chunk fills whole
+# blocks. A GRID STEP covers q_step_blocks() of them
 BLOCK_Q = 8
 
 # the KV scratch block is (block_size, 2 * Dh): block_size below the
@@ -206,22 +230,135 @@ def kv_group_blocks(heads: int, block_size: int, head_dim: int,
     return g
 
 
-def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
-                lo_ref, kvlen_ref, *rest, block_q, block_size, group, scale,
-                quantized=False, q_group=1, mask_block=1, window=0,
-                sinks=False):
-    """One q-block grid step, every head at once: walk the owning
-    sequence's page table ONCE, a group of ``group`` KV blocks at a
-    time — one DMA a block brings ``pool[layer, pid]`` whole (all heads,
-    K|V), the next group's DMAs are started before this group is waited
-    for and computed on (two buffers) — and stream online softmax over
-    ``[block_q, group * block_size]`` score tiles, one update a group.
+# a grid step's own working set — its q and o blocks (double-buffered by
+# the pipeline), one group's scores and weights, the accumulator old and
+# new — may take this much VMEM beside the two group buffers: 7.3 MB at
+# MiMo-V2-Flash's global layers (4 KV heads x 512 folded rows), 1.6 MB at
+# gpt2-large
+Q_VMEM_BUDGET = 8 << 20
 
-    Only the blocks the sequence owns are fetched (``j < n_kv <= T``:
-    the table is never read past its width, the scratch block its
-    padding names never fetched). The rest of a partial group's buffer
-    holds whatever an earlier group left there, so those columns — and
-    a last block's rows past ``kv_len`` — are masked out of BOTH
+# the widest grid step, in q blocks: 32 query rows share one walk
+Q_STEP_BLOCKS = 4
+
+
+def q_step_blocks(heads: int, q_group: int, block_size: int, lanes: int,
+                  dtype, *, v_lanes: int = 0, q_blocks: int = 0) -> int:
+    """``M``: how many q blocks one grid step covers — read, like ``G``,
+    from the pool's shape and dtype (``heads`` KV heads, rows of ``lanes``
+    with V in the last ``v_lanes``), the query heads a KV head
+    (``q_group``) and the launch's q blocks, and from nothing else.
+    ``Q_STEP_BLOCKS``, halved while a wide step's working set would pass
+    ``Q_VMEM_BUDGET`` and until it divides ``q_blocks`` (the engine's
+    buckets are powers of two). The engine's counters ask here too."""
+    m = Q_STEP_BLOCKS
+    cols = kv_group_blocks(heads, block_size, 0, dtype,
+                           lanes=lanes) * int(block_size)
+    # the accumulator is V's lanes wide where K and V are whole tiles
+    # apart, a stored row's otherwise
+    dv = int(v_lanes) or int(lanes) // 2
+    acc_lanes = dv if (int(lanes) - dv) % 128 == 0 and dv % 128 == 0 \
+        else int(lanes)
+    row_bytes = (2 * int(lanes) * max(jnp.dtype(dtype).itemsize, 2)
+                 + 2 * cols * 4 + 2 * acc_lanes * 4)
+    rows = int(heads) * BLOCK_Q * int(q_group)
+    while m > 1 and (m * rows * row_bytes > Q_VMEM_BUDGET
+                     or int(q_blocks) % m):
+        m //= 2
+    return m
+
+
+def _one_sequence(seqs):
+    """Whether a grid step's q blocks (``seqs``: their ``blk_seq``
+    entries) are rows of ONE sequence, so that one walk serves them all:
+    the inside of a prompt chunk. Decode rows, a chunk's first and last q
+    blocks beside their neighbours and pad blocks are not. Scalars in the
+    kernel, arrays (a column a q block of the step) on the host."""
+    same = seqs[0] >= 0
+    for s in seqs[1:]:
+        same = same & (s == seqs[0])
+    return same
+
+
+def _walk_extent(xp, p_first, n_rows, lo, kv_len, t_len, *, block_size,
+                 mask_block, window):
+    """``(j_first, n_kv)``: the entries ``[j_first, n_kv)`` of a
+    sequence's page table that ``n_rows`` query rows at consecutive
+    positions from ``p_first`` walk. The walk ends at the block of the
+    last row's last visible column — its own position, or its diffusion
+    block's end — bounded by ``kv_len`` and the table; under a window it
+    starts at the block of the first row's ``p - W + 1`` (the entries
+    before name freed blocks). ``xp`` is ``jnp`` in the kernel and ``np``
+    in ``ragged_walk_counts``: the ONE statement of what is fetched."""
+    i32 = xp.int32
+    bs = i32(block_size)
+    p_last = p_first + i32(n_rows - 1)
+    if mask_block > 1:
+        p_last = p_last // i32(mask_block) * i32(mask_block) \
+            + i32(mask_block - 1)
+    n_kv = xp.minimum(xp.minimum((kv_len + bs - 1) // bs, i32(t_len)),
+                      p_last // bs + 1)
+    if not window:
+        return i32(0), n_kv
+    j_first = xp.maximum(xp.maximum(p_first - i32(window - 1), lo),
+                         i32(0)) // bs
+    return j_first, n_kv
+
+
+def ragged_walk_counts(blk_seq, seq_qstart, seq_pos0, lo, kv_len, t_len, *,
+                       step_blocks, block_size, group, mask_block=1,
+                       window=0):
+    """What the kernel does on a launch's layout, a layer (host, numpy):
+    ``kv_steps`` block DMAs, ``kv_fetches`` waits for a group of ``group``
+    blocks, ``q_blocks`` real q blocks and ``q_blocks_wide`` of them
+    served by a wide step — from ``_one_sequence`` and ``_walk_extent``,
+    the functions the kernel itself decides by."""
+    m = int(step_blocks)
+    blk_seq = np.asarray(blk_seq, np.int32)
+    steps = blk_seq.reshape(-1, m)
+    wide = _one_sequence([steps[:, i] for i in range(m)]) if m > 1 \
+        else np.zeros(len(steps), bool)
+    alone = ~np.repeat(wide, m) & (blk_seq >= 0)
+    kv_steps = kv_fetches = 0
+    for first, n_blocks in ((np.flatnonzero(wide) * m, m),
+                            (np.flatnonzero(alone), 1)):
+        seq = blk_seq[first]
+        p_first = (np.asarray(seq_pos0, np.int32)[seq] + first * BLOCK_Q
+                   - np.asarray(seq_qstart, np.int32)[seq]).astype(np.int32)
+        j_first, n_kv = _walk_extent(
+            np, p_first, n_blocks * BLOCK_Q, np.asarray(lo, np.int32)[seq],
+            np.asarray(kv_len, np.int32)[seq], t_len,
+            block_size=block_size, mask_block=mask_block, window=window)
+        blocks = np.maximum(n_kv - j_first, 0)
+        kv_steps += int(blocks.sum())
+        kv_fetches += int((-(-blocks // group)).sum())
+    return dict(kv_steps=kv_steps, kv_fetches=kv_fetches,
+                q_blocks=int((blk_seq >= 0).sum()),
+                q_blocks_wide=m * int(wide.sum()))
+
+
+def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
+                lo_ref, kvlen_ref, *rest, block_q, step_blocks, block_size,
+                group, scale, quantized=False, q_group=1, mask_block=1,
+                window=0, sinks=False):
+    """One grid step: ``step_blocks`` q blocks, every head at once. Where
+    they are rows of ONE sequence (``_one_sequence``: the inside of a
+    chunk) the step makes one WALK for all of them; otherwise it makes a
+    walk a q block (a pad block is written as zeros), in a loop over the
+    same code.
+
+    A walk goes over the owning sequence's page table ONCE, a group of
+    ``group`` KV blocks at a time — one DMA a block brings ``pool[layer,
+    pid]`` whole (all heads, K|V), the next group's DMAs are started
+    before this group is waited for and computed on (two buffers) — and
+    streams online softmax over ``[rows, group * block_size]`` score
+    tiles, one update a group. It ends at the block its last row stops
+    seeing at (``_walk_extent``).
+
+    Only the blocks the walk covers are fetched (``j_first <= j < n_kv
+    <= T``: the table is never read past its width, the scratch block
+    its padding names never fetched). The rest of a partial group's
+    buffer holds whatever an earlier group left there, so those columns
+    — and a last block's rows past ``kv_len`` — are masked out of BOTH
     products: the K|V rows by ``where`` to 0 before either (a 0 weight
     does not silence a NaN), the scores by ``where`` (so ``p`` is
     exactly 0).
@@ -239,15 +376,15 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
     pallas_kernels idiom; the call sites also trace under _x64_off)."""
     scales_ref = sinks_ref = None
     if quantized:
-        scales_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
+        scales_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem, *staged = rest
     elif sinks:
-        q_ref, sinks_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
+        q_ref, sinks_ref, pool_ref, o_ref, kv_scr, kv_sem, *staged = rest
     else:
-        q_ref, pool_ref, o_ref, kv_scr, kv_sem = rest
-    b = pl.program_id(0)
+        q_ref, pool_ref, o_ref, kv_scr, kv_sem, *staged = rest
     layer = layer_ref[0]
-    seq = blk_seq_ref[b]
-    n_heads, q_rows, dh = q_ref.shape       # q_rows = block_q * q_group
+    blk0 = pl.program_id(0) * jnp.int32(step_blocks)
+    n_heads, _, dh = q_ref.shape
+    blk_rows = block_q * q_group            # folded rows of one q block
     # V is the last dv lanes of a stored row, K what q multiplies before
     # them (the wrapper zero-extends q to the first V lane where the two
     # sides are whole tiles)
@@ -258,21 +395,19 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
     split = v0 % 128 == 0 and dv % 128 == 0
     cols_g = group * block_size             # KV columns of one group
     t_len = tables_ref.shape[1]
+    _BS = jnp.int32(block_size)
+    _BQ = jnp.int32(block_q)
+    _G = jnp.int32(group)
+    _CG = jnp.int32(cols_g)
 
-    @pl.when(seq < 0)
-    def _pad_block():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(seq >= 0)
-    def _attend():
-        _BS = jnp.int32(block_size)
-        _BQ = jnp.int32(block_q)
-        _G = jnp.int32(group)
-        _CG = jnp.int32(cols_g)
+    def walk(seq, blk, n_blocks, rows, q_src, o_dst):
+        # the q blocks [blk, blk + n_blocks) of sequence `seq`: rows
+        # `rows` of this step's q and o blocks (or of their staged copies)
+        q_rows = n_blocks * blk_rows
         # a block's K|V tile goes to the MXU whole, never split along
         # its lanes: q is zero-extended over the V lanes, so q . [K|V]^T
         # is q . K^T, and p . [K|V] holds p . V in its upper Dh lanes
-        q = q_ref[...]                                  # [H, bq, Dh]
+        q = q_src[:, rows, :].astype(q_ref.dtype)       # [H, rows, Dh]
         if not split:
             q = jnp.concatenate(
                 [q, jnp.zeros(q.shape[:-1] + (v0 + dv - dh,), q.dtype)],
@@ -281,47 +416,38 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         # consecutive tokens starting at seq_pos0 (pad rows past the
         # real q_len see the whole context and nobody reads them); a
         # folded row r is query row r // q_group
-        row0 = b * _BQ - qstart_ref[seq]
+        p_first = pos0_ref[seq] + blk * _BQ - qstart_ref[seq]
         q_row = jax.lax.broadcasted_iota(jnp.int32, (q_rows, 1), 0)
         if q_group > 1:
             q_row = q_row // jnp.int32(q_group)
-        qpos = pos0_ref[seq] + row0 + q_row             # [bq * g, 1]
+        qpos = p_first + q_row                          # [rows, 1]
         # the last column a row sees: its own, or its block's last
         q_last = qpos if mask_block == 1 else \
             qpos // jnp.int32(mask_block) * jnp.int32(mask_block) \
             + jnp.int32(mask_block - 1)
         lo = lo_ref[seq]
         kv_len = kvlen_ref[seq]
-        n_kv = jnp.minimum((kv_len + _BS - 1) // _BS, jnp.int32(t_len))
-        if window:
-            # the walk starts at the block of the q block's first row's
-            # p - W + 1 and ends at its last row's block: the entries
-            # before name freed blocks, the ones after are all masked
-            p_first = pos0_ref[seq] + row0
-            j_first = jnp.maximum(
-                jnp.maximum(p_first - jnp.int32(window - 1), lo), 0) // _BS
-            n_kv = jnp.minimum(n_kv, (p_first + _BQ - 1) // _BS + 1)
-            n_grp = (n_kv - j_first + _G - 1) // _G
-            col0 = j_first * _BS
-            # rows of the buffers past the walk's last block hold what an
-            # earlier walk left there
-            kv_end = jnp.minimum(kv_len, n_kv * _BS)
-        else:
-            n_grp = (n_kv + _G - 1) // _G
+        j_first, n_kv = _walk_extent(
+            jnp, p_first, n_blocks * block_q, lo, kv_len, t_len,
+            block_size=block_size, mask_block=mask_block, window=window)
+        n_grp = (n_kv - j_first + _G - 1) // _G
+        col0 = j_first * _BS
+        # rows of the buffers past the walk's last block hold what an
+        # earlier walk left there
+        kv_end = jnp.minimum(kv_len, n_kv * _BS)
 
         def block_copies(grp, slot, act):
             # the page-table walk: the grp-th group's blocks, each ONE
             # copy of pool[layer, pid] — every head's (bs, 2*Dh) K|V
             # tile — into its rows of buffer `slot`; `act` starts or
-            # waits. Blocks past the sequence's last are not touched.
-            j0 = grp * _G + j_first if window else grp * _G
+            # waits. Blocks past the walk's last are not touched.
+            j0 = grp * _G + j_first
 
             def one(g, carry):
-                rows = pl.ds(pl.multiple_of(g * _BS, block_size),
-                             block_size)
+                at = pl.ds(pl.multiple_of(g * _BS, block_size), block_size)
                 act(pltpu.make_async_copy(
                     pool_ref.at[layer, tables_ref[seq, j0 + g]],
-                    kv_scr.at[slot, :, rows, :], kv_sem.at[slot, g]))
+                    kv_scr.at[slot, :, at, :], kv_sem.at[slot, g]))
                 return carry
 
             jax.lax.fori_loop(jnp.int32(0), jnp.minimum(_G, n_kv - j0),
@@ -330,8 +456,8 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         def col_scales(grp):
             # K's and V's [H, 1, G*bs] f32: scales_ref[0|1, pid, h] over
             # the columns of the group's block holding pid (a block past
-            # the sequence's last reads the last one's: masked columns)
-            blk = jax.lax.broadcasted_iota(
+            # the walk's last reads the last one's: masked columns)
+            blk_of = jax.lax.broadcasted_iota(
                 jnp.int32, (1, cols_g), 1) // _BS
             pids = [tables_ref[seq, jnp.minimum(grp * _G + jnp.int32(g),
                                                 n_kv - 1)]
@@ -340,8 +466,8 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
             def over_columns(which, h):
                 row = jnp.zeros((1, cols_g), jnp.float32)
                 for g, pid in enumerate(pids):
-                    row = jnp.where(blk == g, scales_ref[which, pid, h],
-                                    row)
+                    row = jnp.where(blk_of == g,
+                                    scales_ref[which, pid, h], row)
                 return row
 
             return [jnp.stack([over_columns(which, h)
@@ -351,7 +477,7 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         block_copies(jnp.int32(0), jnp.int32(0), lambda cp: cp.start())
 
         def body(grp, carry):
-            # running softmax stats stay [H, bq, 1] (sublane-oriented);
+            # running softmax stats stay [H, rows, 1] (sublane-oriented);
             # rank-1 carries would force lane<->sublane relayouts
             m_prev, l_prev, acc = carry
             slot = grp % 2
@@ -361,30 +487,25 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
                 block_copies(grp + 1, 1 - slot, lambda cp: cp.start())
 
             block_copies(grp, slot, lambda cp: cp.wait())
-            # rows no block of this sequence filled, and a last
-            # block's rows past kv_len, go to the MXU as zeros
-            kv_rows = grp * _CG + jax.lax.broadcasted_iota(
+            # rows no block of this walk filled, and a last block's rows
+            # past kv_len, go to the MXU as zeros
+            kv_rows = col0 + grp * _CG + jax.lax.broadcasted_iota(
                 jnp.int32, (cols_g, 1), 0)
-            if window:
-                kv_rows = kv_rows + col0
             kv = kv_scr[slot]                           # [H, G*bs, 2*Dh]
-            kv = jnp.where(
-                (kv_rows < (kv_end if window else kv_len))[None], kv,
-                jnp.zeros_like(kv)).astype(q.dtype)
+            kv = jnp.where((kv_rows < kv_end)[None], kv,
+                           jnp.zeros_like(kv)).astype(q.dtype)
             # operands in storage dtype (a quantized pool's values are
             # exact in q's), f32 accumulation (MXU contract shared with
             # the flash kernels)
             s = jax.lax.dot_general(
                 q, kv[:, :, :v0] if split else kv,
                 (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32) * scale  # [H, bq, G*bs]
+                preferred_element_type=jnp.float32) * scale  # [H, rows, G*bs]
             if quantized:
                 k_scale, v_scale = col_scales(grp)
                 s = s * k_scale
-            cols = grp * _CG + jax.lax.broadcasted_iota(
+            cols = col0 + grp * _CG + jax.lax.broadcasted_iota(
                 jnp.int32, (q_rows, cols_g), 1)
-            if window:
-                cols = cols + col0
             seen = (cols >= lo) & (cols <= q_last) & (cols < kv_len)
             if window:
                 seen = seen & (cols > qpos - jnp.int32(window))
@@ -401,14 +522,15 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
             acc_new = acc * alpha + jax.lax.dot_general(
                 p.astype(q.dtype), kv[:, :, v0:] if split else kv,
                 (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)     # [H, bq, 2*Dh]
+                preferred_element_type=jnp.float32)     # [H, rows, 2*Dh]
             return m_new, l_new, acc_new
 
         if sinks:
             # the sink's term of the denominator: exp(s_h - m) with the
             # running max starting AT the sink — 1, rescaled from here on
-            # like every other term; it adds nothing to acc
-            m0 = sinks_ref[...]
+            # like every other term; it adds nothing to acc (every q
+            # block's rows of the operand are the same heads)
+            m0 = sinks_ref[:, :q_rows, :]
             l0 = jnp.ones((n_heads, q_rows, 1), jnp.float32)
         else:
             m0 = jnp.full((n_heads, q_rows, 1), _NEG_INF, jnp.float32)
@@ -420,8 +542,54 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         # trace happens outside the call site's _x64_off scope
         _, l, acc = jax.lax.fori_loop(jnp.int32(0), n_grp, body,
                                       (m0, l0, acc0))
-        o_ref[...] = ((acc if split else acc[:, :, v0:])
-                      / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_dst[:, rows, :] = ((acc if split else acc[:, :, v0:])
+                             / jnp.maximum(l, 1e-30)).astype(o_dst.dtype)
+
+    def q_block(i, q_src, o_dst):
+        # the step's i-th q block on its own: a pad block, or one walk
+        seq = blk_seq_ref[blk0 + i]
+        rows = slice(None) if step_blocks == 1 else pl.ds(
+            pl.multiple_of(i * jnp.int32(blk_rows), blk_rows), blk_rows)
+
+        @pl.when(seq < 0)
+        def _pad_block():
+            o_dst[:, rows, :] = jnp.zeros(
+                (n_heads, blk_rows, dv), o_dst.dtype)
+
+        @pl.when(seq >= 0)
+        def _attend():
+            walk(seq, blk0 + i, 1, rows, q_src, o_dst)
+
+    if step_blocks == 1:
+        q_block(jnp.int32(0), q_ref, o_ref)
+        return
+    one_seq = _one_sequence([blk_seq_ref[blk0 + jnp.int32(i)]
+                             for i in range(step_blocks)])
+
+    @pl.when(one_seq)
+    def _wide():
+        walk(blk_seq_ref[blk0], blk0, step_blocks, slice(None), q_ref,
+             o_ref)
+
+    @pl.when(jnp.logical_not(one_seq))
+    def _each():
+        # a q block of its own is a dynamic slice of the step's rows.
+        # Where it is not whole tiles of the packed dtype (8 rows of
+        # bf16 at g = 1: half a tile) the step's q and o blocks are
+        # staged in float32, in which 8 rows are a tile: one aligned
+        # conversion a step each way, the values bit for bit
+        q_src, o_dst = staged or (q_ref, o_ref)
+        if staged:
+            q_src[...] = q_ref[...].astype(jnp.float32)
+
+        def one(i, carry):
+            q_block(i, q_src, o_dst)
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(step_blocks), one,
+                          jnp.int32(0))
+        if staged:
+            o_ref[...] = o_dst[...].astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, pool, layer, blk_seq, seq_qstart, seq_pos0,
@@ -542,17 +710,23 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
         q = jnp.swapaxes(q.reshape(hkv, g, qp, dh), 1, 2).reshape(
             hkv, qp * g, dh)
     group = kv_group_blocks(hkv, bs, 0, pool.dtype, lanes=lanes)
+    m = q_step_blocks(hkv, g, bs, lanes, pool.dtype, v_lanes=v_lanes,
+                      q_blocks=qp // block_q)
     kernel = functools.partial(
-        _rpa_kernel, block_q=block_q, block_size=int(bs), group=group,
-        scale=scale, quantized=quant, q_group=g, mask_block=mask_block,
-        window=window, sinks=sinks is not None)
-    q_rows = block_q * g
+        _rpa_kernel, block_q=block_q, step_blocks=m, block_size=int(bs),
+        group=group, scale=scale, quantized=quant, q_group=g,
+        mask_block=mask_block, window=window, sinks=sinks is not None)
+    q_rows = m * block_q * g                # folded rows of a grid step
+    # a q block's rows on their own are a dynamic slice of the step's:
+    # staged in float32 where they are not whole tiles of q's dtype
+    # (the kernel says why)
+    staged = m > 1 and (block_q * g) % (8 * 4 // q.dtype.itemsize) != 0
     operands, sink_specs = [q], []
     if sinks is not None:
         # folded row r is head r % g of its KV head's group
         operands.append(jnp.tile(sinks.reshape(hkv, 1, g),
-                                 (1, block_q, 1)).reshape(hkv, q_rows, 1))
-        # the whole [Hkv, block_q * g, 1] operand is the block: its last
+                                 (1, m * block_q, 1)).reshape(hkv, q_rows, 1))
+        # the whole [Hkv, M * block_q * g, 1] operand is the block: its last
         # dim EQUALS the array's (compiled for a described v5e in
         # tests/test_tpu_compile.py), the layout of the kernel's running
         # max and sum
@@ -560,7 +734,7 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
             (hkv, q_rows, 1), lambda b, *_: (0, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8 if quant else 7,
-        grid=(qp // block_q,),
+        grid=(qp // (m * block_q),),
         in_specs=[
             pl.BlockSpec((hkv, q_rows, dh), lambda b, *_: (0, b, 0)),
             *sink_specs,
@@ -573,6 +747,9 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
             # whole group is one [G*bs, 2*Dh] tile stack
             pltpu.VMEM((2, hkv, group * bs, lanes), pool.dtype),
             pltpu.SemaphoreType.DMA((2, group)),
+            *([pltpu.VMEM((hkv, q_rows, dh), jnp.float32),
+               pltpu.VMEM((hkv, q_rows, dv), jnp.float32)] if staged
+              else []),
         ],
     )
     prefetch = [layer, blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len]

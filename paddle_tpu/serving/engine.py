@@ -34,7 +34,8 @@ like training-loop churn.
 Observability (PR-1 wiring + the ISSUE-6 SLO spine): counters
 ``serving/requests``, ``serving/completed``, ``serving/tokens``,
 ``serving/launch_overlapped``, ``serving/late_rows``,
-``serving/preempt``, ``serving/queue_full``, ``serving/cancelled``,
+``serving/q_blocks_wide`` (q blocks a wide step of the ragged kernel
+served: the inside of a prompt chunk), ``serving/preempt``, ``serving/queue_full``, ``serving/cancelled``,
 ``serving/deadline_exceeded``, ``serving/prefix_hit``/``prefix_miss``/
 ``prefill_tokens_saved``/``prefix_evict``; histograms
 ``serving/queue_depth``, ``serving/active_slots``,
@@ -1220,7 +1221,9 @@ class GenerationEngine:
         named by the first group's table bucket — every group's table
         covers the same virtual blocks."""
         from ..ops.ragged_paged_attention import (BLOCK_Q, kv_group_blocks,
-                                                  ragged_layout)
+                                                  q_step_blocks,
+                                                  ragged_layout,
+                                                  ragged_walk_counts)
 
         pool = self._pool
         S = pool.num_slots
@@ -1333,21 +1336,36 @@ class GenerationEngine:
                 kv_row_tokens_window=sum(
                     int(np.minimum(pos0s[s] + np.arange(n) + 1, W).sum())
                     for s, n in enumerate(q_lens) if n))
-        # every q block of a slot walks that slot's whole context: one
-        # KV block a step, one wait for a group of G blocks a fetch (G
-        # as the kernel reads it from its pool shard's shape)
-        if self._decoder_spec.cache.v_aliases_k:
+        # what the first group's kernel does on this layout, a layer: one
+        # KV block a step, one wait for a group of G blocks a fetch (G and
+        # the grid step's M q blocks as the kernel reads them from its
+        # pool shard's shape)
+        first = self._decoder_spec.cache_groups[0]
+        if first.cache.v_aliases_k:
+            # the latent kernel: every q block of a slot walks that
+            # slot's whole context
             from ..ops.mla_paged_attention import latent_group_blocks
             group = latent_group_blocks(bs, pool.lanes, pool.dtype)
+            walks = [(-(-n // BLOCK_Q), -(-int(kv_len[s]) // bs))
+                     for s, n in enumerate(q_lens) if n]
+            walked = dict(
+                kv_steps=sum(qb * kb for qb, kb in walks),
+                kv_fetches=sum(qb * -(-kb // group) for qb, kb in walks),
+                q_blocks=sum(qb for qb, _ in walks), q_blocks_wide=0)
         else:
-            group = kv_group_blocks(pool.num_heads // self._mp, bs,
-                                    pool.head_dim, pool.dtype)
-        walks = [(-(-n // BLOCK_Q), -(-int(kv_len[s]) // bs))
-                 for s, n in enumerate(q_lens) if n]
+            heads = pool.num_heads // self._mp
+            walked = ragged_walk_counts(
+                blk_seq, qstart, pos0, los[0], kv_len, T,
+                step_blocks=q_step_blocks(
+                    heads, first.q_group, bs, pool.lanes, pool.dtype,
+                    v_lanes=first.cache.v_lanes if first.cache.k_lanes
+                    else 0, q_blocks=Q // BLOCK_Q),
+                block_size=bs, group=kv_group_blocks(
+                    heads, bs, 0, pool.dtype, lanes=pool.lanes),
+                mask_block=B, window=first.window)
         self._sched.note_launch(
             rows=sum(q_lens), q=Q, t=T, kv_tokens=int(kv_len.sum()),
-            kv_steps=sum(qb * kb for qb, kb in walks),
-            kv_fetches=sum(qb * -(-kb // group) for qb, kb in walks),
+            **walked,
             # under the block mask a row sees to the end of its block
             kv_row_tokens=sum(
                 n * pos0s[s] + n * (n + 1) // 2 if B == 1 else int(

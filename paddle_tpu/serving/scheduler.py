@@ -714,8 +714,8 @@ class Scheduler:
                 self._rec.get("decode_flops", 0.0) + float(flops)
 
     def note_launch(self, rows: int, q: int, t: int, kv_tokens: int,
-                    kv_steps: int, kv_fetches: int,
-                    kv_row_tokens: int = 0,
+                    kv_steps: int, kv_fetches: int, q_blocks: int = 0,
+                    q_blocks_wide: int = 0, kv_row_tokens: int = 0,
                     kv_write_blocks: int = 0,
                     kv_tokens_window: Optional[int] = None,
                     kv_row_tokens_window: Optional[int] = None) -> None:
@@ -725,11 +725,15 @@ class Scheduler:
         ``launch_rows`` real query rows inside the ``(launch_q,
         launch_t)`` program's buckets; ``kv_tokens``, the context
         tokens the kernel must read (sum of the planned slots'
-        ``kv_len``); ``kv_steps``, the (q block, KV block) pairs it
-        walks per layer, one DMA of a whole block (every head) each;
-        ``kv_fetches``, the groups of blocks those DMAs go out in, each
-        waited for and computed on once (q blocks x ceil(KV blocks /
-        G)); ``kv_row_tokens``, the (query row, cached token) pairs of
+        ``kv_len``); ``kv_steps``, the KV blocks its walks fetch per
+        layer, one DMA of a whole block (every head) each;
+        ``kv_fetches``, the groups of G blocks those DMAs go out in, each
+        waited for and computed on once — both as the kernel really
+        walks (``ops.ragged_paged_attention.ragged_walk_counts``: a q
+        block's walk, or ONE for the q blocks of a wide step, ended
+        where its last row stops seeing); ``q_blocks``, the launch's
+        real q blocks, and ``q_blocks_wide``, those of them a wide step
+        served; ``kv_row_tokens``, the (query row, cached token) pairs of
         the causal mask — a row at position ``p`` sees ``p + 1`` tokens
         — which is what an attention kernel's products are counted
         from; ``kv_write_blocks``, the (slot, block) pairs the real rows
@@ -747,12 +751,16 @@ class Scheduler:
                              launch_t=int(t), kv_tokens=int(kv_tokens),
                              kv_steps=int(kv_steps),
                              kv_fetches=int(kv_fetches),
+                             q_blocks=int(q_blocks),
+                             q_blocks_wide=int(q_blocks_wide),
                              kv_row_tokens=int(kv_row_tokens),
                              kv_write_blocks=int(kv_write_blocks))
             if kv_tokens_window is not None:
                 self._rec.update(
                     kv_tokens_window=int(kv_tokens_window),
                     kv_row_tokens_window=int(kv_row_tokens_window))
+            if q_blocks_wide:
+                stat_add("serving/q_blocks_wide", int(q_blocks_wide))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into

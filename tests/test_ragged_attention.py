@@ -31,7 +31,8 @@ import paddle_tpu as paddle
 from paddle_tpu.framework import monitor, trace_probe
 from paddle_tpu.models import GPTConfig, GPTForPretraining, generate
 from paddle_tpu.ops.ragged_paged_attention import (
-    ragged_layout, ragged_paged_attention, reference_ragged_attention)
+    q_step_blocks, ragged_layout, ragged_paged_attention,
+    reference_ragged_attention)
 from paddle_tpu.serving import GenerationEngine
 from paddle_tpu.serving.scheduler import GenerationRequest
 
@@ -130,7 +131,7 @@ def _random_ragged_case(rng, *, dtype="float32"):
 def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
                dtype="float32", nan_at=(), seed=0, q_heads=None,
                mask_block=1, window=0, sinks=False, dv=0, lanes=0,
-               free_behind=False):
+               free_behind=False, q_bucket=0):
     """A hand-built launch for the grouped KV walk: ``seqs`` is a list of
     ``(q_len, kv_len)`` (the q rows are the context's tail), each
     sequence's blocks drawn from a shuffled pool, its table padded with
@@ -148,7 +149,8 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
     scratch block, which is NaN, and ``lo`` is the first position still
     held); ``sinks`` draws a logit a query head; ``dv`` makes V the last
     ``dv`` lanes of a row of ``lanes`` (default ``dh + dv``) and K the
-    first ``dh``."""
+    first ``dh``; ``q_bucket`` pads the launch to that many q rows (0: to
+    its content, which decides how many q blocks a grid step covers)."""
     import jax.numpy as jnp
     q_heads = q_heads or heads
     lanes = lanes or (dh + dv if dv else 2 * dh)
@@ -174,7 +176,8 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
     q_lens = [q for q, _ in seqs]
     pos0s = [kv - q for q, kv in seqs]
     kv_len = np.asarray([kv for _, kv in seqs], np.int32)
-    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, pos0s)
+    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, pos0s,
+                                                q_bucket=q_bucket)
     q = rng.randn(q_heads, len(blk_seq) * 8, dh).astype(np.float32)
     lo = np.zeros(S, np.int32)
     if free_behind:
@@ -202,6 +205,27 @@ def _walk_case(seqs, *, heads=3, bs=32, dh=16, t_len=None,
         v_lanes=dv).astype(jnp.float32))
     got = np.stack([out[:, qstart[s] + i] for s, i in rows])
     return got, ref, out
+
+
+# what a grid step of M q blocks meets: q blocks of ONE sequence (a wide
+# step), a sequence's first and last q blocks beside other rows, decode
+# rows only, pad blocks
+_STEP_CASES = {
+    "whole-steps": [(64, 200)],
+    "starts-and-ends-inside-a-step": [(1, 50), (70, 300), (1, 20)],
+    "decode-rows-only": [(1, 135), (1, 40), (1, 300), (1, 77), (1, 129)],
+    "pad-blocks": [(40, 170)],
+}
+_STEP_FORMS = {
+    "g1": {"bs": 16},
+    "g8-mask4-ride": {"heads": 2, "q_heads": 16, "bs": 16, "mask_block": 4,
+                      "more": [(8, 40)]},
+    "g16": {"heads": 1, "q_heads": 16, "bs": 16},
+    "window128-sinks-lanes-freed": {
+        "heads": 2, "q_heads": 16, "bs": 16, "dh": 192, "dv": 128,
+        "lanes": 384, "window": 128, "sinks": True, "free_behind": True},
+    "int8": {"dtype": "int8"},
+}
 
 
 class TestKernelParity:
@@ -288,6 +312,55 @@ class TestKernelParity:
         np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
         if kw.get("free_behind"):
             assert np.isfinite(out).all()    # a freed block is never read
+
+    # a grid step covers M = 4 q blocks (the launches here are padded to
+    # whole steps of 32 rows): the step's cases x the forms the cells use
+    @pytest.mark.parametrize("form,kw", _STEP_FORMS.items(),
+                             ids=list(_STEP_FORMS))
+    @pytest.mark.parametrize("case,seqs", _STEP_CASES.items(),
+                             ids=list(_STEP_CASES))
+    def test_wide_and_mixed_steps_match_oracle(self, case, seqs, form, kw):
+        kw = dict(kw)
+        # the block form's launches carry a ride: a commit's rows and the
+        # next block's, one q block
+        seqs = seqs + kw.pop("more", [])
+        rows = sum(-(-q // 8) * 8 for q, _ in seqs)
+        bucket = max(-(-rows // 32) * 32, 128 if case == "pad-blocks" else 0)
+        heads = kw.get("heads", 3)
+        assert q_step_blocks(
+            heads, kw.get("q_heads", heads) // heads, kw.get("bs", 32),
+            kw.get("lanes", 0) or 2 * kw.get("dh", 16),
+            kw.get("dtype", "float32"), v_lanes=kw.get("dv", 0),
+            q_blocks=bucket // 8) == 4
+        got, ref, out = _walk_case(seqs, q_bucket=bucket, **kw)
+        tol = {"int8": 2e-4}.get(kw.get("dtype"), 2e-5)
+        np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+        assert np.isfinite(out).all()            # pad blocks and pad rows
+
+    @pytest.mark.parametrize("seqs,rows,past", [
+        # a wide step: the chunk's rows 0..31 sit at 336..367, block 22
+        # is their last; the chunk's own later blocks are poison
+        ([(64, 400)], range(0, 32), 23),
+        # q blocks walked one by one beside a decode row: rows 0..15 of
+        # the chunk end in block 21
+        ([(1, 50), (64, 400)], range(1, 17), 22),
+    ], ids=["wide-step", "q-blocks-alone"])
+    @pytest.mark.parametrize("kw", [
+        {}, {"heads": 2, "q_heads": 16, "mask_block": 4}],
+        ids=["causal", "gqa8-mask4"])
+    def test_walk_stops_at_the_steps_last_row(self, seqs, rows, past, kw):
+        """Blocks past the last row of a step (a wide one, or a q block
+        on its own) are never fetched, whatever ``kv_len`` is: NaN there
+        leaves those rows finite and equal."""
+        s = len(seqs) - 1
+        got, ref, _ = _walk_case(
+            seqs, bs=16, nan_at=[(s, j) for j in range(past, 25)],
+            q_bucket=96, **kw)
+        rows = list(rows)
+        assert np.isfinite(got[rows]).all()
+        np.testing.assert_allclose(got[rows], ref[rows], rtol=2e-5,
+                                   atol=2e-5)
+        assert np.isnan(got[-1]).any()      # the last rows do see them
 
     @pytest.mark.parametrize("seqs,nan_at,clean", [
         # the scratch block the tables pad with is never fetched
@@ -600,6 +673,95 @@ class TestChunkedPrefill:
         # every chunk cycle also advanced decode: emitted >= 1
         assert all(c["emitted"] >= 1 for c in chunk_cycles), chunk_cycles
         assert max(c["chunk_tokens"] for c in chunk_cycles) <= 8
+
+    def test_launch_counters_equal_the_kernels_dmas(self, served_model,
+                                                    monkeypatch):
+        """``kv_steps`` / ``kv_fetches`` / ``q_blocks_wide`` of the launch
+        records are what the kernel does: every block DMA the
+        interpret-mode kernel starts on the engine's own layouts, and
+        every group it waits for (a group's first block is semaphore 0
+        of its buffer), are counted at run time and compared, and the
+        wide steps are read off each launch's ``blk_seq``."""
+        import jax
+
+        from paddle_tpu.ops import ragged_paged_attention as rpa
+        seen = {"starts": 0, "waits": 0, "groups": 0}
+        launches = []
+
+        def bump(key, g):
+            seen[key] += 1
+            if key == "waits" and int(g) == 0:
+                seen["groups"] += 1
+
+        class Counting:
+            def __init__(self, copy, g):
+                self.copy, self.g = copy, g
+
+            def start(self):
+                jax.debug.callback(lambda g: bump("starts", g), self.g)
+                self.copy.start()
+
+            def wait(self):
+                jax.debug.callback(lambda g: bump("waits", g), self.g)
+                self.copy.wait()
+
+        real_tpu = rpa.pltpu
+
+        class CountingTpu:
+            def __getattr__(self, name):
+                return getattr(real_tpu, name)
+
+        tpu = CountingTpu()
+        tpu.make_async_copy = lambda src, dst, sem: Counting(
+            real_tpu.make_async_copy(src, dst, sem),
+            sem.transforms[-1].indices[1])
+        # the kernel module alone: kv_append's copies are not the walk's
+        monkeypatch.setattr(rpa, "pltpu", tpu)
+        # the kernel's call is a jitted function of its own: traces made
+        # before this test do not count, and no later test may find these
+        rpa._rpa_call.clear_cache()
+        eng = GenerationEngine(served_model, num_slots=4, max_len=64,
+                               block_size=8, prefill_budget=56)
+        note = eng._sched.note_launch
+        real_ops = eng._ragged_operands
+
+        def ragged_operands(*a, **kw):
+            out = real_ops(*a, **kw)
+            launches[-1]["blk_seq"] = np.asarray(out[2][4])
+            return out
+
+        monkeypatch.setattr(
+            eng._sched, "note_launch",
+            lambda **kw: (launches.append(dict(kw)), note(**kw))[1])
+        monkeypatch.setattr(eng, "_ragged_operands", ragged_operands)
+        short = eng.submit(_prompt(np.random.RandomState(10), 4),
+                           max_new_tokens=12)
+        it = short.stream()
+        next(it)                        # a decode row beside the chunks
+        long_h = eng.submit(_prompt(np.random.RandomState(11), 60),
+                            max_new_tokens=3)
+        long_h.result(timeout=600)
+        short.result(timeout=600)
+        eng.close()
+        jax.effects_barrier()
+        rpa._rpa_call.clear_cache()
+        layers = 2
+        assert seen["starts"] == seen["waits"] \
+            == layers * sum(r["kv_steps"] for r in launches)
+        assert seen["groups"] == layers * sum(r["kv_fetches"]
+                                              for r in launches)
+        wide = 0
+        for r in launches:
+            blocks = r["blk_seq"]
+            assert r["q_blocks"] == (blocks >= 0).sum()
+            m = min(4, len(blocks))
+            steps = blocks.reshape(-1, m)
+            one = (steps[:, :1] >= 0) & (steps == steps[:, :1])
+            n = m * int(one.all(axis=1).sum()) if m > 1 else 0
+            assert r["q_blocks_wide"] == n
+            wide += n
+        # the 56-row chunk is q blocks 1..7 of its launch: 4..7 a step
+        assert wide == 4
 
     def test_chunk_plan_policy_mock_scheduler(self):
         """Deterministic mock-device policy check (no model): the chunk

@@ -23,7 +23,8 @@ from paddle_tpu.ops import pallas_kernels as pk  # noqa: E402
 from paddle_tpu.ops.kv_append import (  # noqa: E402
     APPEND_VMEM_BUDGET, append_ring_blocks, kv_append)
 from paddle_tpu.ops.ragged_paged_attention import (  # noqa: E402
-    KV_VMEM_BUDGET, kv_group_blocks, ragged_layout, ragged_paged_attention)
+    KV_VMEM_BUDGET, kv_group_blocks, q_step_blocks, ragged_layout,
+    ragged_paged_attention, ragged_walk_counts)
 
 H, DH = 12, 64          # GPT-2 124M: 12 heads of 64
 
@@ -192,6 +193,48 @@ def test_ragged_paged_attention_compiles_for_window_and_global_layers(
         else "%ragged_paged_attention"
     assert len(calls) == 1 and calls[0].startswith(want), calls
     assert window or "_window" not in calls[0]
+
+
+@pytest.mark.parametrize("hq,hkv,dk,dv,lanes,kw", [
+    (20, 20, 64, 64, 128, {}),                            # GPT-2 large
+    (32, 4, 128, 128, 256, {"mask_block": 4}),            # SDAR-30B-A3B
+    (64, 4, 192, 128, 384, {}),                           # MiMo, global
+    (64, 8, 192, 128, 384, {"window": 128, "sinks": True}),   # MiMo, window
+], ids=["gpt2-large", "sdar", "mimo-global", "mimo-window"])
+def test_wide_q_step_compiles_at_the_cells_widths(v5e, hq, hkv, dk, dv,
+                                                  lanes, kw):
+    """A grid step of M = 4 q blocks at the widths of the cells that run
+    it, at Q 2,048 with a 1,000-row chunk whose inside is wide steps: 32
+    query rows x g folded heads a KV head — at MiMo's global layers the q
+    block is 1 MB twice, scores and accumulator 1 MB each. A working set
+    Mosaic cannot place fails here, not on the chip."""
+    q_lens, q_bucket, T, NB, bs = [1] * 64 + [1000], 2048, 576, 64, 16
+    S, g = len(q_lens), hq // hkv
+    v_lanes = dv if dv != dk else 0
+    m = q_step_blocks(hkv, g, bs, lanes, "bfloat16", v_lanes=v_lanes,
+                      q_blocks=q_bucket // 8)
+    assert m == 4                   # its working set is inside the budget
+    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, [4000] * S,
+                                                q_bucket=q_bucket)
+    tables, lo = np.zeros((S, T), np.int32), np.zeros(S, np.int32)
+    kv_len = np.asarray([4000 + n for n in q_lens], np.int32)
+    walked = ragged_walk_counts(
+        blk_seq, qstart, pos0, lo, kv_len, T, step_blocks=m, block_size=bs,
+        group=8, mask_block=kw.get("mask_block", 1),
+        window=kw.get("window", 0))
+    assert walked["q_blocks_wide"] == 124 and walked["q_blocks"] == 189
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    def fn(q, pool, sinks):
+        return ragged_paged_attention(
+            q, pool, 1, blk_seq, qstart, pos0, tables, lo, kv_len,
+            mask_block=kw.get("mask_block", 1), window=kw.get("window", 0),
+            sinks=sinks if kw.get("sinks") else None, v_lanes=v_lanes)
+
+    text = _compile(fn, sds((hq, q_bucket, dk), jnp.bfloat16),
+                    sds((2, NB + 1, hkv, bs, lanes), jnp.bfloat16),
+                    sds((hq,), jnp.float32))
+    assert "ragged_paged_attention" in text
 
 
 @pytest.mark.parametrize("hkv", [4, 8], ids=["global", "window"])

@@ -235,7 +235,7 @@ def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
         rec = records[cycle]
         for key in ("plan_ms", "emit_ms", "launch_rows", "launch_q",
                     "launch_t", "kv_tokens", "kv_steps", "kv_fetches",
-                    "kv_write_blocks"):
+                    "q_blocks", "q_blocks_wide", "kv_write_blocks"):
             assert key in rec, key
         assert rec["launch_rows"] == rows
         assert rec["kv_tokens"] == kv
@@ -277,14 +277,19 @@ def test_kv_fetches_counts_the_groups_a_launch_waits_for():
     finally:
         eng.close()
     walked = [(c["launch_rows"], c["kv_steps"], c["kv_fetches"],
-               c["kv_write_blocks"])
+               c["kv_write_blocks"], c["q_blocks_wide"])
               for c in eng.flight_recorder.snapshot()["cycles"]
               if c.get("launch_rows")]
-    # (rows, q blocks x KV blocks, q blocks x groups of 4 blocks, blocks
-    # the rows land in): the chunks end at 64, 128 and 150 tokens, then
-    # decode rows at 151, 152
-    assert walked == [(64, 8 * 2, 8 * 1, 2), (64, 8 * 4, 8 * 1, 2),
-                      (22, 3 * 5, 3 * 2, 1), (1, 5, 2, 1), (1, 5, 2, 1)]
+    # (rows, KV blocks the walks fetch, groups of 4 blocks they wait for,
+    # blocks the rows land in, q blocks a wide step served): the chunks
+    # end at 64, 128 and 150 tokens, then decode rows at 151, 152. A
+    # 64-row chunk is two wide steps of 32 rows, each walking to its last
+    # row's block (1 + 2, then 3 + 4 blocks, a group each); the 22-row
+    # chunk's 3 q blocks share a step with a pad block and walk alone, 5
+    # blocks = 2 groups each
+    assert walked == [(64, 1 + 2, 2, 2, 8), (64, 3 + 4, 2, 2, 8),
+                      (22, 3 * 5, 3 * 2, 1, 0), (1, 5, 2, 1, 0),
+                      (1, 5, 2, 1, 0)]
 
 
 def test_a_train_step_is_in_the_trace_with_its_number(tmp_path):
