@@ -55,10 +55,6 @@ from . import decoder_spec as DS
 __all__ = ["AXK1Config", "AXK1ForCausalLM", "yarn_inv_freq",
            "yarn_attention_scale", "route_top_k", "routed_experts"]
 
-# the ops the expert layer's device time is found under, by name, in a
-# profiler trace (benchmark/layer_metrics/moe_*.py)
-MOE_SCOPE = "moe_experts"
-
 # (row, expert) pairs one trip of the grouped products takes, at least:
 # 128 decode rows choosing 8 of 192 experts put 64 +- 8 pairs on the 12
 # held here, and a 128-row tile is what the TPU's ragged dot then walks
@@ -477,8 +473,9 @@ class AXK1DenseFFN(nn.Layer):
         self.down = p("down", (I, E))
 
     def apply(self, x, valid):
-        return _swiglu(x, self.gate._data, self.up._data,
-                       self.down._data).astype(x.dtype), None
+        with DS.section(DS.MLP):
+            return _swiglu(x, self.gate._data, self.up._data,
+                           self.down._data).astype(x.dtype), None
 
 
 class AXK1RoutedFFN(nn.Layer):
@@ -500,20 +497,24 @@ class AXK1RoutedFFN(nn.Layer):
 
     def apply(self, x, valid):
         """``x [Q, E]`` -> ``(shared(x) + the held experts' part, counters)``."""
-        import jax
-        import jax.numpy as jnp
         cfg = self.cfg
-        with jax.named_scope(MOE_SCOPE):
-            idx, w, _ = route_top_k(
-                x, self.router._data, cfg.num_experts_per_tok,
-                cfg.routed_scaling_factor, cfg.norm_topk_prob)
+        # the whole expert layer is found by ``moe_experts``
+        # (benchmark/layer_metrics/moe_*.py): router and shared expert
+        # nest inside it
+        with DS.section(DS.MOE_SCOPE):
+            with DS.section(DS.ROUTER):
+                idx, w, _ = route_top_k(
+                    x, self.router._data, cfg.num_experts_per_tok,
+                    cfg.routed_scaling_factor, cfg.norm_topk_prob)
             y, counters = routed_experts(
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
                 max(MOE_PAIR_CHUNK, x.shape[0] // 8))
-            shared = _swiglu(x, self.shared_gate._data, self.shared_up._data,
-                             self.shared_down._data)
+            with DS.section(DS.SHARED_EXPERT):
+                shared = _swiglu(x, self.shared_gate._data,
+                                 self.shared_up._data,
+                                 self.shared_down._data)
             out = (shared + y).astype(x.dtype)
         return out, counters
 
@@ -532,19 +533,26 @@ class AXK1Layer(nn.Layer):
         self.ffn = ffn(cfg, make, prefix + "ffn.")
 
     def _ffn(self, x, valid):
-        y, counters = self.ffn.apply(
-            _rms_norm(x, self.ffn_norm._data, self.cfg.rms_norm_eps), valid)
-        return x + y, counters
+        with DS.section(DS.NORM):
+            h = _rms_norm(x, self.ffn_norm._data, self.cfg.rms_norm_eps)
+        y, counters = self.ffn.apply(h, valid)
+        with DS.section(DS.MLP):          # the add that closes the layer
+            return x + y, counters
 
     # -- the decoder spec's layer surface (x is a Tensor [1, Q, E]) --------
     def attn_in(self, x, positions):
-        h = _rms_norm(x._data[0], self.attn_norm._data, self.cfg.rms_norm_eps)
-        return self.attn.absorbed_in(h, positions)
+        with DS.section(DS.NORM):
+            h = _rms_norm(x._data[0], self.attn_norm._data,
+                          self.cfg.rms_norm_eps)
+        with DS.section(DS.QKV):
+            return self.attn.absorbed_in(h, positions)
 
     def attn_out(self, x, o_lat, row_valid):
-        y, counters = self._ffn(x._data[0] + self.attn.absorbed_out(o_lat),
-                                row_valid)
-        return Tensor(y[None], stop_gradient=True), counters
+        with DS.section(DS.O_PROJ):
+            x = x._data[0] + self.attn.absorbed_out(o_lat)
+        y, counters = self._ffn(x, row_valid)
+        with DS.section(DS.MLP):
+            return Tensor(y[None], stop_gradient=True), counters
 
     # -- no cache: one whole sequence [S, E] -------------------------------
     def full(self, x, positions):

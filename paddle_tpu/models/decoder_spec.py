@@ -55,6 +55,11 @@ pass runs the block's B rows (fixed ids, ``mask_token_id`` elsewhere),
 fixes the ``B / denoising_steps`` most confident unfixed positions and
 keeps nothing in the cache, and once nothing is unfixed a COMMIT pass
 runs the block's final tokens, whose K/V the cache keeps.
+
+**Sections.** :data:`SECTIONS` names the parts of a launch's device work
+(``embed``, ``norm``, ``qkv`` … ``head``, ``sample``); the tower and the
+four models put their ops under them, layer by layer, and the profiler
+reads a launch's device time by them (``profiler/xplane.py``).
 """
 from __future__ import annotations
 
@@ -64,10 +69,57 @@ from typing import Optional, Tuple
 
 __all__ = ["CacheSpec", "LayerSpec", "DecoderSpec", "GenerationRule",
            "CacheGroup", "serving_decoder", "FULL", "LATENT", "DENSE",
-           "ROUTED"]
+           "ROUTED", "SECTIONS", "section", "layer_scope", "section_of"]
 
 FULL, LATENT = "full", "latent"        # attention kinds
 DENSE, ROUTED = "dense", "routed"      # FFN kinds
+
+# The SECTIONS of a launch: the one vocabulary of ``jax.named_scope``s the
+# step programs put their device work under, so that every op of a launch
+# says in a profiler trace (its ``tf_op``) which part of the model it is.
+# An op belongs to the INNERMOST word of its scope path (:func:`section_of`):
+# the router and the shared expert nest inside ``moe_experts``, whose name
+# a reader may still find the whole expert layer by. Names only: a section
+# changes no operand, no output and no instruction of the compiled program.
+EMBED = "embed"                  # token ids (the block state) to rows
+NORM = "norm"                    # the layer norms and the final one
+QKV = "qkv"                      # q/k/v or latent projections, rotary
+CACHE_WRITE = "cache_write"      # the rows' entries into the paged pool
+ATTENTION = "attention"          # the paged attention kernel
+O_PROJ = "o_proj"                # output projection and its residual
+ROUTER = "router"                # expert scores and the choice
+MOE_SCOPE = "moe_experts"        # the routed experts (router, shared inside)
+SHARED_EXPERT = "shared_expert"
+MLP = "mlp"                      # a dense FFN; the add that closes a layer
+HEAD = "head"                    # the logits of the rows that are read
+SAMPLE = "sample"                # the pick of one token a slot
+UNMASK_SCOPE = "unmask"          # a block pass's head, confidence, choice
+SECTIONS = (EMBED, NORM, QKV, CACHE_WRITE, ATTENTION, O_PROJ, ROUTER,
+            MOE_SCOPE, SHARED_EXPERT, MLP, HEAD, SAMPLE, UNMASK_SCOPE)
+
+
+def section(name: str):
+    """The ``jax.named_scope`` of one of :data:`SECTIONS`."""
+    import jax
+    if name not in SECTIONS:
+        raise ValueError(f"{name!r} is no section: {SECTIONS}")
+    return jax.named_scope(name)
+
+
+def layer_scope(index: int):
+    """The scope the tower's loop puts layer ``index``'s sections under."""
+    import jax
+    return jax.named_scope(f"layer{int(index)}")
+
+
+def section_of(op_name: str) -> Optional[str]:
+    """The section an op traced under the scope path ``op_name``
+    (``jit(fused_step_q512_t64)/layer3/moe_experts/router/dot_general``)
+    belongs to: the innermost word of the vocabulary in it, or None."""
+    for part in reversed(op_name.split("/")):
+        if part in SECTIONS:
+            return part
+    return None
 
 
 @dataclass(frozen=True)

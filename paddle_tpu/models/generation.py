@@ -715,54 +715,77 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     from ..ops.kv_append import kv_append
     from ..ops.mla_paged_attention import mla_paged_attention
     from ..ops.ragged_paged_attention import ragged_paged_attention
-    from .decoder_spec import FULL
+    from . import decoder_spec as DS
 
     grouped = isinstance(pool, tuple)
     pools = list(pool) if grouped else [pool]
     wbs, tabs, los = (write_block, tables, lo) if grouped \
         else ((write_block,), (tables,), (lo,))
-    row_valid = wbs[0] > 0
+    with DS.section(DS.EMBED):
+        row_valid = wbs[0] > 0
     counters = None
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
         # the layer's cache group, and its place in the group's array
         g, gi = dec.spec.layer_group(li)
-        q, rows = layer.attn_in(x, positions)
-        # row i's entry lands at (write_block[i], write_off[i]) through
-        # the page table. The two XLA scatters send pad rows to the
-        # scratch block nobody reads; kv_append skips them
-        if ls.attention == FULL:
-            if q.shape[0] != (ls.query_heads or ls.cache.rows):
-                raise ValueError(
-                    f"layer {li} hands the kernel {q.shape[0]} query heads "
-                    f"and its spec says {ls.query_heads or ls.cache.rows}: "
-                    f"the engine counts the kernel's walks from the spec")
-            if quantized:
-                pools[g], scales = _quant_append(
-                    pools[g], scales, gi, wbs[g], write_off, *rows, qmax)
+        with DS.layer_scope(li):
+            # attn_in and attn_out name their own sections
+            q, rows = layer.attn_in(x, positions)
+            # row i's entry lands at (write_block[i], write_off[i])
+            # through the page table. The two XLA scatters send pad rows
+            # to the scratch block nobody reads; kv_append skips them
+            if ls.attention == DS.FULL:
+                if q.shape[0] != (ls.query_heads or ls.cache.rows):
+                    raise ValueError(
+                        f"layer {li} hands the kernel {q.shape[0]} query "
+                        f"heads and its spec says "
+                        f"{ls.query_heads or ls.cache.rows}: the engine "
+                        f"counts the kernel's walks from the spec")
+                with DS.section(DS.CACHE_WRITE):
+                    if quantized:
+                        pools[g], scales = _quant_append(
+                            pools[g], scales, gi, wbs[g], write_off, *rows,
+                            qmax)
+                    else:
+                        pools[g] = kv_append(
+                            pools[g], gi, wbs[g], write_off,
+                            _kv_lanes(*rows, ls.cache.lanes))
+                with DS.section(DS.ATTENTION):
+                    a = ragged_paged_attention(
+                        q, pools[g], gi, blk_seq, seq_qstart, seq_pos0,
+                        tabs[g], los[g], kv_len, scales=scales,
+                        mask_block=dec.spec.generation.block_length,
+                        window=ls.window,
+                        sinks=layer.sinks if ls.sinks else None,
+                        v_lanes=ls.cache.v_lanes if ls.cache.k_lanes else 0)
             else:
-                pools[g] = kv_append(pools[g], gi, wbs[g], write_off,
-                                     _kv_lanes(*rows, ls.cache.lanes))
-            a = ragged_paged_attention(
-                q, pools[g], gi, blk_seq, seq_qstart, seq_pos0, tabs[g],
-                los[g], kv_len, scales=scales,
-                mask_block=dec.spec.generation.block_length,
-                window=ls.window, sinks=layer.sinks if ls.sinks else None,
-                v_lanes=ls.cache.v_lanes if ls.cache.k_lanes else 0)
-        else:
-            pools[g] = _write_latent_rows(pools[g], gi, wbs[g], write_off,
-                                          rows)
-            a = mla_paged_attention(
-                q, pools[g], gi, blk_seq, seq_qstart, seq_pos0, tabs[g],
-                los[g], kv_len, v_lanes=ls.cache.v_lanes,
-                scale=dec.attention_scale)
-        x, c = layer.attn_out(x, a, row_valid)
-        if c is not None:
-            counters = c if counters is None else tuple(
-                u + v for u, v in zip(counters, c))
+                with DS.section(DS.CACHE_WRITE):
+                    pools[g] = _write_latent_rows(pools[g], gi, wbs[g],
+                                                  write_off, rows)
+                with DS.section(DS.ATTENTION):
+                    a = mla_paged_attention(
+                        q, pools[g], gi, blk_seq, seq_qstart, seq_pos0,
+                        tabs[g], los[g], kv_len, v_lanes=ls.cache.v_lanes,
+                        scale=dec.attention_scale)
+            x, c = layer.attn_out(x, a, row_valid)
+            if c is not None:
+                with DS.section(DS.MOE_SCOPE):
+                    counters = c if counters is None else tuple(
+                        u + v for u, v in zip(counters, c))
     if counters is not None:
-        counters = jnp.stack(counters).astype(jnp.int32)
-    return (dec.final_norm(x), tuple(pools) if grouped else pools[0],
-            scales, counters)
+        with DS.section(DS.MOE_SCOPE):
+            counters = jnp.stack(counters).astype(jnp.int32)
+    with DS.section(DS.NORM):
+        x = dec.final_norm(x)
+    return x, tuple(pools) if grouped else pools[0], scales, counters
+
+
+def _named(fn, name: str):
+    """``fn`` called ``name``: ``jax.jit`` names the compiled module for
+    the function (``jit_<name>``), which is what a launch is called on
+    the device's ``XLA Modules`` line of a profiler trace and in the
+    flight recorder's ``launch_program``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def _tokens_from_prev(token_ids, prev_tokens, token_src):
@@ -784,12 +807,6 @@ def _tokens_from_prev(token_ids, prev_tokens, token_src):
 # fixed is read from HERE, never by comparing an id with the mask id (a
 # prompt may hold that id)
 BLOCK_UNFIXED, BLOCK_GIVEN = -2, -1
-
-# the named scope of the step's last stage in a block-generation program:
-# the head on the blocks' rows, argmax and confidence, the choice of the
-# positions to fix (benchmark/layer_metrics/unmask_step_ms.py)
-UNMASK_SCOPE = "unmask"
-
 
 def block_result_layout(num_slots: int, block_length: int, routed: bool):
     """Where a block-generation step's result holds what: ``(fixed_at,
@@ -816,33 +833,32 @@ def _unmask(dec, x, blk_row0, blk_tok, blk_pass, pass_idx, rule):
     argmax and are stamped with the pass. The mask id is never chosen:
     its logit is out of the argmax and of the softmax alike. Ties go to
     the lowest position. Returns ``(blk_tok, blk_pass, fixed_pos [S],
-    logits_bad)``."""
+    logits_bad)``. The caller holds it under the section ``unmask``."""
     import jax
     import jax.numpy as jnp
     S, B = blk_tok.shape
-    with jax.named_scope(UNMASK_SCOPE):
-        rows = (blk_row0[:, None]
-                + jnp.arange(B, dtype=jnp.int32)[None, :]).reshape(-1)
-        logits = dec.logits(Tensor(x._data[0, rows][:, None, :]))._data[
-            :, 0].astype(jnp.float32)                     # [S * B, V]
-        vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        logits = jnp.where(vocab == rule.mask_token_id, -jnp.inf, logits)
-        top = jnp.max(logits, axis=-1)
-        best = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(S, B)
-        denom = jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
-        conf = (1.0 / denom).reshape(S, B)        # softmax prob of argmax
-        bad = jnp.any(~jnp.isfinite(top)) | jnp.any(~jnp.isfinite(denom))
-        denoise = (pass_idx >= 0)[:, None]
-        fixed_pos = jnp.full((S,), -1, jnp.int32)
-        cols = jnp.arange(B, dtype=jnp.int32)[None, :]
-        for _ in range(rule.fixed_per_pass):
-            open_ = (blk_pass == BLOCK_UNFIXED) & denoise
-            c = jnp.where(open_, conf, -1.0)
-            j = jnp.argmax(c, axis=-1).astype(jnp.int32)          # [S]
-            take = (cols == j[:, None]) & open_
-            blk_tok = jnp.where(take, best, blk_tok)
-            blk_pass = jnp.where(take, pass_idx[:, None], blk_pass)
-            fixed_pos = jnp.where(jnp.any(take, axis=-1), j, fixed_pos)
+    rows = (blk_row0[:, None]
+            + jnp.arange(B, dtype=jnp.int32)[None, :]).reshape(-1)
+    logits = dec.logits(Tensor(x._data[0, rows][:, None, :]))._data[
+        :, 0].astype(jnp.float32)                     # [S * B, V]
+    vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    logits = jnp.where(vocab == rule.mask_token_id, -jnp.inf, logits)
+    top = jnp.max(logits, axis=-1)
+    best = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(S, B)
+    denom = jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
+    conf = (1.0 / denom).reshape(S, B)        # softmax prob of argmax
+    bad = jnp.any(~jnp.isfinite(top)) | jnp.any(~jnp.isfinite(denom))
+    denoise = (pass_idx >= 0)[:, None]
+    fixed_pos = jnp.full((S,), -1, jnp.int32)
+    cols = jnp.arange(B, dtype=jnp.int32)[None, :]
+    for _ in range(rule.fixed_per_pass):
+        open_ = (blk_pass == BLOCK_UNFIXED) & denoise
+        c = jnp.where(open_, conf, -1.0)
+        j = jnp.argmax(c, axis=-1).astype(jnp.int32)          # [S]
+        take = (cols == j[:, None]) & open_
+        blk_tok = jnp.where(take, best, blk_tok)
+        blk_pass = jnp.where(take, pass_idx[:, None], blk_pass)
+        fixed_pos = jnp.where(jnp.any(take, axis=-1), j, fixed_pos)
     return blk_tok, blk_pass, fixed_pos, bad.astype(jnp.int32)
 
 
@@ -881,11 +897,11 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
 
     from ..framework import trace_probe as _probe
     from ..nn.layer.layers import functional_state
-    from .decoder_spec import ROUTED
+    from . import decoder_spec as DS
 
     rule = dec.spec.generation
     B = int(rule.block_length)
-    routed = any(ls.ffn == ROUTED for ls in dec.spec.layers)
+    routed = any(ls.ffn == DS.ROUTED for ls in dec.spec.layers)
     _, _, _, tokens_at, passes_at, size = block_result_layout(S, B, routed)
 
     def fn(params, buffers, pool, token_ids, qpos, write_block, write_off,
@@ -896,39 +912,44 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
                          {"q": Q, "table": T})
         with functional_state(model, params, buffers):
             with no_grad_guard():
-                from_prev = (state_src >= 0)[:, None]
-                blk_tok = jnp.where(
-                    from_prev,
-                    prev_result[tokens_at:passes_at].reshape(S, B), blk_tok)
-                blk_pass = jnp.where(
-                    from_prev, prev_result[passes_at:size].reshape(S, B),
-                    blk_pass)
-                shown = jnp.where(blk_pass == BLOCK_UNFIXED,
-                                  jnp.int32(rule.mask_token_id),
-                                  blk_tok).reshape(-1)
-                token_ids = jnp.where(
-                    row_blk >= 0, shown[jnp.maximum(row_blk, 0)], token_ids)
-                x = dec.embed_tokens(token_ids, qpos)
+                with DS.section(DS.EMBED):
+                    from_prev = (state_src >= 0)[:, None]
+                    blk_tok = jnp.where(
+                        from_prev,
+                        prev_result[tokens_at:passes_at].reshape(S, B),
+                        blk_tok)
+                    blk_pass = jnp.where(
+                        from_prev,
+                        prev_result[passes_at:size].reshape(S, B), blk_pass)
+                    shown = jnp.where(blk_pass == BLOCK_UNFIXED,
+                                      jnp.int32(rule.mask_token_id),
+                                      blk_tok).reshape(-1)
+                    token_ids = jnp.where(
+                        row_blk >= 0, shown[jnp.maximum(row_blk, 0)],
+                        token_ids)
+                    x = dec.embed_tokens(token_ids, qpos)
                 x, new_pool, _, counters = _fused_tower(
                     dec, x, qpos, pool, None, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     False, 0.0)
-                # a riding slot's rows have shown its finished block;
-                # the block it denoises is the next, B rows down, all open
-                riding = (ride > 0)[:, None]
-                blk_tok = jnp.where(riding, 0, blk_tok)
-                blk_pass = jnp.where(riding, BLOCK_UNFIXED, blk_pass)
-                blk_tok, blk_pass, fixed_pos, bad = _unmask(
-                    dec, x, seq_qstart + B * ride, blk_tok, blk_pass,
-                    pass_idx, rule)
-                parts = [fixed_pos, bad[None]]
-                if counters is not None:
-                    parts.append(counters)
-                parts += [blk_tok.reshape(-1), blk_pass.reshape(-1)]
-                result = jnp.concatenate(parts).astype(jnp.int32)
+                with DS.section(DS.UNMASK_SCOPE):
+                    # a riding slot's rows have shown its finished block;
+                    # the block it denoises is the next, B rows down, all
+                    # open
+                    riding = (ride > 0)[:, None]
+                    blk_tok = jnp.where(riding, 0, blk_tok)
+                    blk_pass = jnp.where(riding, BLOCK_UNFIXED, blk_pass)
+                    blk_tok, blk_pass, fixed_pos, bad = _unmask(
+                        dec, x, seq_qstart + B * ride, blk_tok, blk_pass,
+                        pass_idx, rule)
+                    parts = [fixed_pos, bad[None]]
+                    if counters is not None:
+                        parts.append(counters)
+                    parts += [blk_tok.reshape(-1), blk_pass.reshape(-1)]
+                    result = jnp.concatenate(parts).astype(jnp.int32)
         return new_pool, result, key
 
-    return fn
+    return _named(fn, f"block_step_q{Q}_t{T}")
 
 
 def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
@@ -995,9 +1016,9 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     from ..framework import trace_probe as _probe
     from ..nn.layer.layers import functional_state
     from ..ops.ragged_paged_attention import BLOCK_Q
-    from .decoder_spec import serving_decoder
+    from . import decoder_spec as DS
 
-    dec = serving_decoder(model)
+    dec = DS.serving_decoder(model)
     S, Q, T, bs = (int(num_slots), int(q_rows), int(table_len),
                    int(block_size))
     if S < 1:
@@ -1024,35 +1045,39 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                 [pool, token_ids, tables])), {"q": Q, "table": T})
         with functional_state(model, params, buffers):
             with no_grad_guard():
-                token_ids = _tokens_from_prev(token_ids, prev_tokens,
-                                              token_src)
-                # logical positions == virtual positions (paged
-                # sequences are aligned at virtual 0; lo is the mask
-                # floor, not a pad offset)
-                x = dec.embed_tokens(token_ids, qpos)
+                with DS.section(DS.EMBED):
+                    token_ids = _tokens_from_prev(token_ids, prev_tokens,
+                                                  token_src)
+                    # logical positions == virtual positions (paged
+                    # sequences are aligned at virtual 0; lo is the mask
+                    # floor, not a pad offset)
+                    x = dec.embed_tokens(token_ids, qpos)
                 x, new_pool, new_scales, counters = _fused_tower(
                     dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     quantized, qmax)
-                last = x._data[0, last_row]             # [S, E]
-                logits = dec.logits(
-                    Tensor(last[:, None, :]))._data[:, 0].astype(
-                        jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p, 1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature[:, None])
-                nxt = jnp.where(sample_mask, sampled, greedy)
-                nxt = _append_nonfinite_flag(nxt, logits)
-                if counters is not None:
-                    # the routed layers' counters ride the same fetch,
-                    # after the sentinel: [S + 1 : S + 4]
-                    nxt = jnp.concatenate([nxt, counters])
+                with DS.section(DS.HEAD):
+                    last = x._data[0, last_row]             # [S, E]
+                    logits = dec.logits(
+                        Tensor(last[:, None, :]))._data[:, 0].astype(
+                            jnp.float32)
+                with DS.section(DS.SAMPLE):
+                    key, sub = jax.random.split(key)
+                    greedy = _pick_token(logits, sub, False, top_k, top_p,
+                                         1.0)
+                    sampled = _pick_token(logits, sub, True, top_k, top_p,
+                                          temperature[:, None])
+                    nxt = jnp.where(sample_mask, sampled, greedy)
+                    nxt = _append_nonfinite_flag(nxt, logits)
+                    if counters is not None:
+                        # the routed layers' counters ride the same
+                        # fetch, after the sentinel: [S + 1 : S + 4]
+                        nxt = jnp.concatenate([nxt, counters])
         if quantized:
             return new_pool, new_scales, nxt, key
         return new_pool, nxt, key
 
-    return fn
+    return _named(fn, f"fused_step_q{Q}_t{T}")
 
 
 # ---------------------------------------------------------------------------
@@ -1101,7 +1126,9 @@ def _mp_qkv(block, x, mp, mp_axis):
     the LOCAL head count (``_split_heads`` reshapes by the global
     ``num_heads`` attribute, so the split happens manually here).
     Returns local ``q/k/v [B, L, H/mp, Dh]`` ndarrays."""
-    h = block.ln_1(x)._data
+    from . import decoder_spec as DS
+    with DS.section(DS.NORM):
+        h = block.ln_1(x)._data
     attn = block.attn
     hl = attn.num_heads // mp
     dh = attn.head_dim
@@ -1110,7 +1137,8 @@ def _mp_qkv(block, x, mp, mp_axis):
         y = _mp_col_linear(lin, h, mp_axis)
         return y.reshape(y.shape[0], y.shape[1], hl, dh)
 
-    return proj(attn.q_proj), proj(attn.k_proj), proj(attn.v_proj)
+    with DS.section(DS.QKV):
+        return proj(attn.q_proj), proj(attn.k_proj), proj(attn.v_proj)
 
 
 def _mp_tail(block, x, a_local, mp_axis):
@@ -1120,14 +1148,18 @@ def _mp_tail(block, x, a_local, mp_axis):
     psum — the Megatron two-collectives-per-layer count. ``a_local`` is
     a ``[B, L, H/mp, Dh]`` ndarray; returns the replicated Tensor."""
     from ..nn import functional as F
-    a = a_local.reshape(a_local.shape[0], a_local.shape[1], -1)
-    attn_out = _mp_row_linear(block.attn.out_proj, a, mp_axis)
-    x = x + block.dropout(Tensor(attn_out, stop_gradient=True))
-    h = block.ln_2(x)._data
-    g = F.gelu(Tensor(_mp_col_linear(block.mlp_fc, h, mp_axis),
-                      stop_gradient=True), approximate=True)
-    m = _mp_row_linear(block.mlp_proj, g._data, mp_axis)
-    return x + block.dropout(Tensor(m, stop_gradient=True))
+    from . import decoder_spec as DS
+    with DS.section(DS.O_PROJ):
+        a = a_local.reshape(a_local.shape[0], a_local.shape[1], -1)
+        attn_out = _mp_row_linear(block.attn.out_proj, a, mp_axis)
+        x = x + block.dropout(Tensor(attn_out, stop_gradient=True))
+    with DS.section(DS.NORM):
+        h = block.ln_2(x)._data
+    with DS.section(DS.MLP):
+        g = F.gelu(Tensor(_mp_col_linear(block.mlp_fc, h, mp_axis),
+                          stop_gradient=True), approximate=True)
+        m = _mp_row_linear(block.mlp_proj, g._data, mp_axis)
+        return x + block.dropout(Tensor(m, stop_gradient=True))
 
 
 def _mp_fused_tower(gpt, x, pool, write_block, write_off, blk_seq,
@@ -1144,18 +1176,24 @@ def _mp_fused_tower(gpt, x, pool, write_block, write_off, blk_seq,
 
     from ..ops.kv_append import kv_append
     from ..ops.ragged_paged_attention import ragged_paged_attention
+    from . import decoder_spec as DS
 
     for li, block in enumerate(gpt.blocks):
-        q, k, v = _mp_qkv(block, x, mp, mp_axis)
-        pool = kv_append(pool, li, write_block, write_off,
-                         _kv_lanes(k[0], v[0]))
-        qh = jnp.transpose(q, (0, 2, 1, 3))[0]       # [H/mp, Q, Dh]
-        a = ragged_paged_attention(
-            qh, pool, li, blk_seq, seq_qstart, seq_pos0, tables, lo,
-            kv_len)
-        a = jnp.transpose(a[None], (0, 2, 1, 3))     # [1, Q, H/mp, Dh]
-        x = _mp_tail(block, x, a, mp_axis)
-    return gpt.ln_f(x), pool
+        with DS.layer_scope(li):
+            q, k, v = _mp_qkv(block, x, mp, mp_axis)
+            with DS.section(DS.CACHE_WRITE):
+                pool = kv_append(pool, li, write_block, write_off,
+                                 _kv_lanes(k[0], v[0]))
+            with DS.section(DS.ATTENTION):
+                qh = jnp.transpose(q, (0, 2, 1, 3))[0]   # [H/mp, Q, Dh]
+                a = ragged_paged_attention(
+                    qh, pool, li, blk_seq, seq_qstart, seq_pos0, tables,
+                    lo, kv_len)
+                a = jnp.transpose(a[None], (0, 2, 1, 3))  # [1,Q,H/mp,Dh]
+            x = _mp_tail(block, x, a, mp_axis)
+    with DS.section(DS.NORM):
+        x = gpt.ln_f(x)
+    return x, pool
 
 
 def _mp_pool_spec(mp_axis):
@@ -1210,6 +1248,7 @@ def build_sharded_fused_step_fn(model, num_slots, q_rows, table_len,
     from ..framework import trace_probe as _probe
     from ..nn.layer.layers import functional_state
     from ..ops.ragged_paged_attention import BLOCK_Q
+    from . import decoder_spec as DS
 
     gpt = model.gpt if hasattr(model, "gpt") else model
     S, Q, T, bs = (int(num_slots), int(q_rows), int(table_len),
@@ -1230,26 +1269,29 @@ def build_sharded_fused_step_fn(model, num_slots, q_rows, table_len,
              temperature, key):
         with functional_state(model, params, buffers):
             with no_grad_guard():
-                token_ids = _tokens_from_prev(token_ids, prev_tokens,
-                                              token_src)
-                x = gpt.wte(Tensor(token_ids[None, :],
-                                   stop_gradient=True)) \
-                    + gpt.wpe(Tensor(qpos[None, :]))
+                with DS.section(DS.EMBED):
+                    token_ids = _tokens_from_prev(token_ids, prev_tokens,
+                                                  token_src)
+                    x = gpt.wte(Tensor(token_ids[None, :],
+                                       stop_gradient=True)) \
+                        + gpt.wpe(Tensor(qpos[None, :]))
                 x, new_pool = _mp_fused_tower(
                     gpt, x, pool, write_block, write_off, blk_seq,
                     seq_qstart, seq_pos0, tables, lo, kv_len, mp,
                     mp_axis)
-                last = x._data[0, last_row]             # [S, E]
-                logits = gpt.logits(
-                    Tensor(last[:, None, :]))._data[:, 0].astype(
-                        jnp.float32)
-                key, sub = jax.random.split(key)
-                greedy = _pick_token(logits, sub, False, top_k, top_p,
-                                     1.0)
-                sampled = _pick_token(logits, sub, True, top_k, top_p,
-                                      temperature[:, None])
-                nxt = jnp.where(sample_mask, sampled, greedy)
-                nxt = _append_nonfinite_flag(nxt, logits)
+                with DS.section(DS.HEAD):
+                    last = x._data[0, last_row]             # [S, E]
+                    logits = gpt.logits(
+                        Tensor(last[:, None, :]))._data[:, 0].astype(
+                            jnp.float32)
+                with DS.section(DS.SAMPLE):
+                    key, sub = jax.random.split(key)
+                    greedy = _pick_token(logits, sub, False, top_k, top_p,
+                                         1.0)
+                    sampled = _pick_token(logits, sub, True, top_k, top_p,
+                                          temperature[:, None])
+                    nxt = jnp.where(sample_mask, sampled, greedy)
+                    nxt = _append_nonfinite_flag(nxt, logits)
         return new_pool, nxt, key
 
     rep = P()
@@ -1271,7 +1313,7 @@ def build_sharded_fused_step_fn(model, num_slots, q_rows, table_len,
                   kv_len, last_row, prev_tokens, token_src, sample_mask,
                   temperature, key)
 
-    return fn
+    return _named(fn, f"fused_step_q{Q}_t{T}_mp{mp}")
 
 
 # ---------------------------------------------------------------------------
@@ -1318,8 +1360,8 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
     from ..nn.layer.layers import functional_state
     from ..ops.ragged_paged_attention import BLOCK_Q
 
-    from .decoder_spec import serving_decoder
-    dec = serving_decoder(model)
+    from . import decoder_spec as DS
+    dec = DS.serving_decoder(model)
     S, Q, K, T = (int(num_slots), int(q_rows), int(spec_k),
                   int(table_len))
     if S < 1:
@@ -1354,50 +1396,54 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
                 # d_{j+1}'s PREDECESSOR d_j... i.e. the fed token at
                 # verify position j+1 is draft_toks[:, j]; invalid
                 # (j >= n_spec - 1) overlays are dropped out of bounds
-                rows = seq_qstart[:, None] + 1 + jnp.arange(K)[None, :]
-                ok = jnp.arange(K)[None, :] < (n_spec[:, None] - 1)
-                safe = jnp.where(ok, rows, Q)         # Q = out of range
-                token_ids = token_ids.at[safe.reshape(-1)].set(
-                    draft_toks.reshape(-1), mode="drop")
-                x = dec.embed_tokens(token_ids, qpos)
+                with DS.section(DS.EMBED):
+                    rows = seq_qstart[:, None] + 1 \
+                        + jnp.arange(K)[None, :]
+                    ok = jnp.arange(K)[None, :] < (n_spec[:, None] - 1)
+                    safe = jnp.where(ok, rows, Q)     # Q = out of range
+                    token_ids = token_ids.at[safe.reshape(-1)].set(
+                        draft_toks.reshape(-1), mode="drop")
+                    x = dec.embed_tokens(token_ids, qpos)
                 x, new_pool, new_scales, _ = _fused_tower(
                     dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     quantized, qmax)
-                # gather the rows whose logits are actually read —
-                # the S*K verify rows plus each slot's last row —
-                # BEFORE the LM head: running the [vocab] matmul over
-                # all Q padded ragged rows would cost Q/(S*(K+1))x
-                # more for nothing (a chunk-heavy cycle reads none of
-                # its chunk rows' logits)
-                vrows = jnp.clip(
-                    seq_qstart[:, None] + jnp.arange(K)[None, :],
-                    0, Q - 1)                          # [S, K]
-                sel = x._data[0][jnp.concatenate(
-                    [vrows.reshape(-1), last_row])]    # [S*K+S, E]
-                logits = dec.logits(
-                    Tensor(sel[:, None, :]))._data[:, 0].astype(
-                        jnp.float32)                   # [S*K+S, V]
-                p = _sample_probs(
-                    logits[:S * K],
-                    jnp.repeat(sample_mask, K),
-                    top_k, top_p,
-                    jnp.repeat(temperature, K)).reshape(S, K, -1)
-                base = _sample_probs(logits[S * K:], sample_mask,
-                                     top_k, top_p, temperature)
-                key, sub = jax.random.split(key)
-                accepted, token = _spec_accept(
-                    p, draft_probs, draft_toks, n_spec, base, sub)
-                bad = jnp.any(~jnp.isfinite(logits)).astype(jnp.int32)
-                out = jnp.concatenate([
-                    accepted.astype(jnp.int32), token,
-                    draft_toks.astype(jnp.int32).reshape(-1),
-                    bad[None]])
+                with DS.section(DS.HEAD):
+                    # gather the rows whose logits are actually read —
+                    # the S*K verify rows plus each slot's last row —
+                    # BEFORE the LM head: running the [vocab] matmul
+                    # over all Q padded ragged rows would cost
+                    # Q/(S*(K+1))x more for nothing (a chunk-heavy cycle
+                    # reads none of its chunk rows' logits)
+                    vrows = jnp.clip(
+                        seq_qstart[:, None] + jnp.arange(K)[None, :],
+                        0, Q - 1)                      # [S, K]
+                    sel = x._data[0][jnp.concatenate(
+                        [vrows.reshape(-1), last_row])]  # [S*K+S, E]
+                    logits = dec.logits(
+                        Tensor(sel[:, None, :]))._data[:, 0].astype(
+                            jnp.float32)               # [S*K+S, V]
+                with DS.section(DS.SAMPLE):
+                    p = _sample_probs(
+                        logits[:S * K],
+                        jnp.repeat(sample_mask, K),
+                        top_k, top_p,
+                        jnp.repeat(temperature, K)).reshape(S, K, -1)
+                    base = _sample_probs(logits[S * K:], sample_mask,
+                                         top_k, top_p, temperature)
+                    key, sub = jax.random.split(key)
+                    accepted, token = _spec_accept(
+                        p, draft_probs, draft_toks, n_spec, base, sub)
+                    bad = jnp.any(~jnp.isfinite(logits)).astype(jnp.int32)
+                    out = jnp.concatenate([
+                        accepted.astype(jnp.int32), token,
+                        draft_toks.astype(jnp.int32).reshape(-1),
+                        bad[None]])
         if quantized:
             return new_pool, new_scales, out, key
         return new_pool, out, key
 
-    return fn
+    return _named(fn, f"spec_verify_q{Q}_t{T}_k{K}")
 
 
 def build_draft_prefill_fn(model, bucket_len, max_len, probe=None):

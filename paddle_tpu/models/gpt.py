@@ -64,7 +64,10 @@ class GPTBlock(nn.Layer):
 
     def _qkv(self, x):
         """ln_1 + split-head q/k/v projections (shared by train/serve)."""
-        h = self.ln_1(x)
+        return self._heads(self.ln_1(x))
+
+    def _heads(self, h):
+        """Split-head q/k/v projections of the normed rows."""
         q = self.attn._split_heads(self.attn.q_proj(h))
         k = self.attn._split_heads(self.attn.k_proj(h))
         v = self.attn._split_heads(self.attn.v_proj(h))
@@ -72,10 +75,16 @@ class GPTBlock(nn.Layer):
 
     def _tail(self, x, a):
         """out-proj + residual + MLP half of the block (shared)."""
-        a = self.attn.out_proj(self.attn._merge_heads(a))
-        x = x + self.dropout(a)
-        m = self.mlp_proj(F.gelu(self.mlp_fc(self.ln_2(x)),
-                                 approximate=True))
+        x = self._attn_residual(x, a)
+        return self._mlp_residual(x, self.ln_2(x))
+
+    def _attn_residual(self, x, a):
+        return x + self.dropout(
+            self.attn.out_proj(self.attn._merge_heads(a)))
+
+    def _mlp_residual(self, x, h):
+        """``x`` + the MLP of its normed rows ``h``."""
+        m = self.mlp_proj(F.gelu(self.mlp_fc(h), approximate=True))
         return x + self.dropout(m)
 
     def forward(self, x, cache=None):
@@ -236,21 +245,33 @@ class GPTModel(nn.Layer):
 
 
 class _GPTServingLayer:
-    """One ``GPTBlock`` behind the decoder spec's layer surface."""
+    """One ``GPTBlock`` behind the decoder spec's layer surface, its work
+    under the spec's sections (``_qkv`` and ``_tail`` piece by piece: the
+    training step, which calls those, is not sectioned)."""
 
     def __init__(self, block):
         self.block = block
 
     def attn_in(self, x, positions):
         import jax.numpy as jnp
-        q, k, v = self.block._qkv(x)
-        return jnp.transpose(q._data, (0, 2, 1, 3))[0], \
-            (k._data[0], v._data[0])
+        from . import decoder_spec as DS
+        with DS.section(DS.NORM):
+            h = self.block.ln_1(x)
+        with DS.section(DS.QKV):
+            q, k, v = self.block._heads(h)
+            return jnp.transpose(q._data, (0, 2, 1, 3))[0], \
+                (k._data[0], v._data[0])
 
     def attn_out(self, x, a, row_valid):
         import jax.numpy as jnp
-        a = jnp.transpose(a[None], (0, 2, 1, 3))
-        return self.block._tail(x, Tensor(a, stop_gradient=True)), None
+        from . import decoder_spec as DS
+        with DS.section(DS.O_PROJ):
+            a = jnp.transpose(a[None], (0, 2, 1, 3))
+            x = self.block._attn_residual(x, Tensor(a, stop_gradient=True))
+        with DS.section(DS.NORM):
+            h = self.block.ln_2(x)
+        with DS.section(DS.MLP):
+            return self.block._mlp_residual(x, h), None
 
 
 class GPTServingDecoder:

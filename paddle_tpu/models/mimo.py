@@ -48,7 +48,7 @@ from typing import Callable, Optional, Tuple
 from .. import nn
 from ..framework.tensor import Parameter, Tensor
 from . import decoder_spec as DS
-from .axk1 import (MOE_PAIR_CHUNK, MOE_SCOPE, _mm, _param_maker, _params,
+from .axk1 import (MOE_PAIR_CHUNK, _mm, _param_maker, _params,
                    _rms_norm, _swiglu, route_top_k, routed_experts)
 from .sdar import rope_half_split
 
@@ -263,8 +263,9 @@ class MiMoDenseFFN(nn.Layer):
         self.down = p("down", (I, E))
 
     def apply(self, x, valid):
-        return _swiglu(x, self.gate._data, self.up._data,
-                       self.down._data).astype(x.dtype), None
+        with DS.section(DS.MLP):
+            return _swiglu(x, self.gate._data, self.up._data,
+                           self.down._data).astype(x.dtype), None
 
 
 class MiMoRoutedFFN(nn.Layer):
@@ -287,19 +288,20 @@ class MiMoRoutedFFN(nn.Layer):
         counters)``; the grouped products' trip is sized as A.X-K1's is
         (a sixteenth of the experts are held: ``Q / 8`` pairs hold the
         ``Q k / 16`` expected with room)."""
-        import jax
         cfg = self.cfg
-        with jax.named_scope(MOE_SCOPE):
-            idx, w, _ = route_top_k(
-                x, self.router._data, cfg.num_experts_per_tok,
-                cfg.routed_scaling_factor or 1.0, cfg.norm_topk_prob,
-                scoring="sigmoid", select_bias=self.router_bias._data)
+        with DS.section(DS.MOE_SCOPE):
+            with DS.section(DS.ROUTER):
+                idx, w, _ = route_top_k(
+                    x, self.router._data, cfg.num_experts_per_tok,
+                    cfg.routed_scaling_factor or 1.0, cfg.norm_topk_prob,
+                    scoring="sigmoid", select_bias=self.router_bias._data)
             y, counters = routed_experts(
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
                 max(MOE_PAIR_CHUNK, x.shape[0] // 8))
-        return y.astype(x.dtype), counters
+        with DS.section(DS.MLP):          # with the add that closes the layer
+            return y.astype(x.dtype), counters
 
 
 class MiMoLayer(nn.Layer):
@@ -330,24 +332,31 @@ class MiMoLayer(nn.Layer):
                             query_heads=a.heads)
 
     def _ffn(self, x, valid):
-        y, counters = self.ffn.apply(
-            _rms_norm(x, self.ffn_norm._data, self.cfg.layernorm_epsilon),
-            valid)
-        return x + y, counters
+        with DS.section(DS.NORM):
+            h = _rms_norm(x, self.ffn_norm._data,
+                          self.cfg.layernorm_epsilon)
+        y, counters = self.ffn.apply(h, valid)
+        with DS.section(DS.MLP):
+            return x + y, counters
 
     # -- the decoder spec's layer surface (x is a Tensor [1, Q, E]) --------
     def attn_in(self, x, positions):
         import jax.numpy as jnp
-        h = _rms_norm(x._data[0], self.attn_norm._data,
-                      self.cfg.layernorm_epsilon)
-        q, k, v = self.attn.project(h, positions)
-        return jnp.swapaxes(q, 0, 1), (k, v)              # [H, Q, Dk]
+        with DS.section(DS.NORM):
+            h = _rms_norm(x._data[0], self.attn_norm._data,
+                          self.cfg.layernorm_epsilon)
+        with DS.section(DS.QKV):
+            q, k, v = self.attn.project(h, positions)
+            return jnp.swapaxes(q, 0, 1), (k, v)          # [H, Q, Dk]
 
     def attn_out(self, x, a, row_valid):
         import jax.numpy as jnp
-        o = self.attn.out(jnp.swapaxes(a, 0, 1))          # a [H, Q, Dv]
-        y, counters = self._ffn(x._data[0] + o, row_valid)
-        return Tensor(y[None], stop_gradient=True), counters
+        with DS.section(DS.O_PROJ):
+            o = self.attn.out(jnp.swapaxes(a, 0, 1))      # a [H, Q, Dv]
+            x = x._data[0] + o
+        y, counters = self._ffn(x, row_valid)
+        with DS.section(DS.MLP):
+            return Tensor(y[None], stop_gradient=True), counters
 
     # -- no cache: one whole sequence [S, E] -------------------------------
     def full(self, x, positions):

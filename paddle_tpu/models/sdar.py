@@ -42,7 +42,7 @@ import numpy as np
 from .. import nn
 from ..framework.tensor import Parameter, Tensor
 from . import decoder_spec as DS
-from .axk1 import (MOE_PAIR_CHUNK, MOE_SCOPE, _mm, _param_maker, _params,
+from .axk1 import (MOE_PAIR_CHUNK, _mm, _param_maker, _params,
                    _rms_norm, route_top_k, routed_experts)
 
 __all__ = ["SDARConfig", "SDARForCausalLM", "rope_half_split"]
@@ -207,18 +207,20 @@ class SDARRoutedFFN(nn.Layer):
         real), so three quarters of ``Q * k`` pairs hold them with room.
         (``Q * k`` itself read 0.2 ms a launch slower on the chip: a
         trip pays for its rows. PERF.md, PR 34.)"""
-        import jax
         cfg = self.cfg
         k = cfg.num_experts_per_tok
-        with jax.named_scope(MOE_SCOPE):
-            idx, w, _ = route_top_k(x, self.router._data, k, 1.0,
-                                    cfg.norm_topk_prob, scoring="softmax")
+        with DS.section(DS.MOE_SCOPE):
+            with DS.section(DS.ROUTER):
+                idx, w, _ = route_top_k(
+                    x, self.router._data, k, 1.0, cfg.norm_topk_prob,
+                    scoring="softmax")
             y, counters = routed_experts(
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
                 max(MOE_PAIR_CHUNK, x.shape[0] * k * 3 // 4))
-        return y.astype(x.dtype), counters
+        with DS.section(DS.MLP):          # with the add that closes the layer
+            return y.astype(x.dtype), counters
 
 
 class SDARLayer(nn.Layer):
@@ -233,22 +235,30 @@ class SDARLayer(nn.Layer):
         self.ffn = SDARRoutedFFN(cfg, make, prefix + "ffn.")
 
     def _ffn(self, x, valid):
-        y, counters = self.ffn.apply(
-            _rms_norm(x, self.ffn_norm._data, self.cfg.rms_norm_eps), valid)
-        return x + y, counters
+        with DS.section(DS.NORM):
+            h = _rms_norm(x, self.ffn_norm._data, self.cfg.rms_norm_eps)
+        y, counters = self.ffn.apply(h, valid)
+        with DS.section(DS.MLP):
+            return x + y, counters
 
     # -- the decoder spec's layer surface (x is a Tensor [1, Q, E]) --------
     def attn_in(self, x, positions):
         import jax.numpy as jnp
-        h = _rms_norm(x._data[0], self.attn_norm._data, self.cfg.rms_norm_eps)
-        q, k, v = self.attn.project(h, positions)
-        return jnp.swapaxes(q, 0, 1), (k, v)              # [H, Q, Dh]
+        with DS.section(DS.NORM):
+            h = _rms_norm(x._data[0], self.attn_norm._data,
+                          self.cfg.rms_norm_eps)
+        with DS.section(DS.QKV):
+            q, k, v = self.attn.project(h, positions)
+            return jnp.swapaxes(q, 0, 1), (k, v)          # [H, Q, Dh]
 
     def attn_out(self, x, a, row_valid):
         import jax.numpy as jnp
-        o = self.attn.out(jnp.swapaxes(a, 0, 1))
-        y, counters = self._ffn(x._data[0] + o, row_valid)
-        return Tensor(y[None], stop_gradient=True), counters
+        with DS.section(DS.O_PROJ):
+            o = self.attn.out(jnp.swapaxes(a, 0, 1))
+            x = x._data[0] + o
+        y, counters = self._ffn(x, row_valid)
+        with DS.section(DS.MLP):
+            return Tensor(y[None], stop_gradient=True), counters
 
     # -- no cache: one whole sequence [S, E] -------------------------------
     def full(self, x, positions):

@@ -33,9 +33,8 @@ like training-loop churn.
 
 Observability (PR-1 wiring + the ISSUE-6 SLO spine): counters
 ``serving/requests``, ``serving/completed``, ``serving/tokens``,
-``serving/launch_overlapped``, ``serving/late_rows``,
-``serving/q_blocks_wide`` (q blocks a wide step of the ragged kernel
-served: the inside of a prompt chunk), ``serving/preempt``, ``serving/queue_full``, ``serving/cancelled``,
+``serving/launch_overlapped``, ``serving/preempt``,
+``serving/queue_full``, ``serving/cancelled``,
 ``serving/deadline_exceeded``, ``serving/prefix_hit``/``prefix_miss``/
 ``prefill_tokens_saved``/``prefix_evict``; histograms
 ``serving/queue_depth``, ``serving/active_slots``,
@@ -1363,9 +1362,11 @@ class GenerationEngine:
                 block_size=bs, group=kv_group_blocks(
                     heads, bs, 0, pool.dtype, lanes=pool.lanes),
                 mask_block=B, window=first.window)
+        step = self._spec_step_fn(Q, T) if spec is not None \
+            else self._fused_step_fn(Q, T)
         self._sched.note_launch(
-            rows=sum(q_lens), q=Q, t=T, kv_tokens=int(kv_len.sum()),
-            **walked,
+            rows=sum(q_lens), q=Q, t=T, program=step.jitted.__name__,
+            kv_tokens=int(kv_len.sum()), **walked,
             # under the block mask a row sees to the end of its block
             kv_row_tokens=sum(
                 n * pos0s[s] + n * (n + 1) // 2 if B == 1 else int(

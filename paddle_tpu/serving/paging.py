@@ -507,7 +507,6 @@ class PagedKVPool:
             st.los[g] = max(st.los[g], keep * self.block_size)
         if freed:
             self.window_blocks_freed += freed
-            stat_add("serving/window_blocks_freed", freed)
             self._observe()
 
     def slot_pos(self, slot: int) -> int:

@@ -713,8 +713,9 @@ class Scheduler:
             self._rec["decode_flops"] = \
                 self._rec.get("decode_flops", 0.0) + float(flops)
 
-    def note_launch(self, rows: int, q: int, t: int, kv_tokens: int,
-                    kv_steps: int, kv_fetches: int, q_blocks: int = 0,
+    def note_launch(self, rows: int, q: int, t: int, program: str,
+                    kv_tokens: int, kv_steps: int, kv_fetches: int,
+                    q_blocks: int = 0,
                     q_blocks_wide: int = 0, kv_row_tokens: int = 0,
                     kv_write_blocks: int = 0,
                     kv_tokens_window: Optional[int] = None,
@@ -723,7 +724,10 @@ class Scheduler:
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
         ``launch_rows`` real query rows inside the ``(launch_q,
-        launch_t)`` program's buckets; ``kv_tokens``, the context
+        launch_t)`` program's buckets; ``launch_program``, that step
+        program's name — the device's ``XLA Modules`` line of a profiler
+        trace calls the launch ``jit_<launch_program>(…)``;
+        ``kv_tokens``, the context
         tokens the kernel must read (sum of the planned slots'
         ``kv_len``); ``kv_steps``, the KV blocks its walks fetch per
         layer, one DMA of a whole block (every head) each;
@@ -748,7 +752,8 @@ class Scheduler:
         the window mask."""
         if self._rec is not None:
             self._rec.update(launch_rows=int(rows), launch_q=int(q),
-                             launch_t=int(t), kv_tokens=int(kv_tokens),
+                             launch_t=int(t), launch_program=str(program),
+                             kv_tokens=int(kv_tokens),
                              kv_steps=int(kv_steps),
                              kv_fetches=int(kv_fetches),
                              q_blocks=int(q_blocks),
@@ -759,8 +764,6 @@ class Scheduler:
                 self._rec.update(
                     kv_tokens_window=int(kv_tokens_window),
                     kv_row_tokens_window=int(kv_row_tokens_window))
-            if q_blocks_wide:
-                stat_add("serving/q_blocks_wide", int(q_blocks_wide))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into
@@ -1567,9 +1570,7 @@ class Scheduler:
             self.spec_cycles += 1
             stat_add("serving/spec_cycles")
         stat_add("serving/tokens", emitted)
-        if late_rows:
-            self.late_rows += late_rows
-            stat_add("serving/late_rows", late_rows)
+        self.late_rows += late_rows
         rec["emitted"] += emitted
         rec["late_rows"] += late_rows
         rec["prefill_chunks"] = rec.get("prefill_chunks", 0) + chunks

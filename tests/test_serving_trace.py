@@ -283,6 +283,7 @@ class TestARecordIsALaunch:
             # the dispatch-side keys, as the engine's operand builder
             # stamps them
             sched.note_launch(rows=sides[-1]["rows"], q=8 * len(plan), t=1,
+                              program=f"fused_step_q{8 * len(plan)}_t1",
                               kv_tokens=0, kv_steps=0, kv_fetches=0)
             return dev.do_step(slot_requests, plan, prev)
 

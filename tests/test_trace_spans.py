@@ -169,7 +169,11 @@ def test_serving_cycles_are_in_the_trace_with_their_children_in_order(
             assert names[:5] == TURN, (n, names)
             # ... and the launch lands a turn later, inside that turn,
             # after that turn's own dispatch if it has one
-            assert names[5:] in (LANDING, []), (n, names)
+            # (the trace's end may cut the LAST traced cycle's landing
+            # after its fetch: the result is handed over inside the emit)
+            whole = (LANDING, LANDING[:1], []) if n == max(numbers) \
+                else (LANDING, [])
+            assert names[5:] in whole, (n, names)
             if names[5:] and n + 1 in turns:
                 landed += 1
                 assert turns[n + 1][0] <= mine[5][0] \
