@@ -272,7 +272,7 @@ def phase_train(cfg, batch: int, seq: int, steps: int) -> dict:
     log(f"  step compile {rec.last_compile_ms / 1e3:.1f} s, XLA temp "
         f"{rec.temp_bytes}, arguments {rec.argument_bytes} bytes")
     text = step_text_report(sites,
-                            kernels=("flash_attention_fwd", "flash_attention_dkv",
+                            kernels=("flash_attention_fwd", "flash_attention_bwd",
                                      "fused_layer_norm_fwd",
                                      "fused_layer_norm_bwd"))
     check(bool(flag_value("FLAGS_use_pallas")),

@@ -16,6 +16,7 @@ for tests: FLAGS_pallas_force (runs kernels even off-TPU, interpreted).
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -30,6 +31,8 @@ define_flag("FLAGS_use_pallas", True,
             "use Pallas TPU kernels where registered")
 define_flag("FLAGS_pallas_force", False,
             "force-select Pallas kernels off-TPU (interpret mode, tests)")
+
+logger = logging.getLogger(__name__)
 
 _NEG_INF = -1e30
 
@@ -75,11 +78,7 @@ def _x64_off():
 # Flash attention (fwd + bwd), layout [B, S, H, D]
 # ===========================================================================
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
-
-
-# LSE (and the bwd delta) travel between kernels as [BH, S, LSE_LANES]
+# Of the STREAMING kernels, LSE (and the bwd delta) travel as [BH, S, LSE_LANES]
 # fp32 with the value replicated across the trailing lane dim.  A plain
 # [BH, S] layout with a (1, block_q) block violates the Mosaic tiling rule
 # (second-to-last block dim must be divisible by 8 or equal the array dim)
@@ -358,309 +357,472 @@ def _fa_call_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
     return dq, dk, dv
 
 
-def _fa_fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                   block_q, block_k, seq_k):
-    # i32-typed block-size constants: bare python ints in fori_loop bodies
-    # get materialized as i64 by Mosaic, producing malformed mixed-type
-    # index arithmetic on TPU
-    _I32_BQ = jnp.int32(block_q)
-    _I32_BK = jnp.int32(block_k)
-    qi = pl.program_id(1)
-    q = q_ref[0]                                  # [bq, D] (native dtype)
-    bq, d = q.shape
-    nk_full = seq_k // block_k
-    if causal:
-        # kv blocks beyond the diagonal contribute nothing
-        nk = jnp.minimum(nk_full, ((qi + 1) * block_q + block_k - 1)
-                         // block_k)
-    else:
-        nk = nk_full
+# ---------------------------------------------------------------------------
+# The RESIDENT family: a head's whole K/V (forward) or Q/dO (backward) sits
+# in VMEM and one grid step walks it in wide tiles.  It works on the
+# layout the model already has, [B, S, H*D]: a 128-lane block of it IS
+# ``128 // D`` heads, so a step holds both heads of a pair at D = 64 and
+# no transpose surrounds the call.  The heads of a step are STACKED AS
+# ROWS: head h's copy of an operand keeps h's lanes and zeros the others
+# (``_stack_heads``), the MXU contracts all 128 lanes and the zeros add
+# exactly nothing — so the heads' products stay separate while one
+# product serves them all, on dense loads and dense accumulators.
+# ---------------------------------------------------------------------------
 
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _pow2(x):
+    """x * bf16 is exact: the scale may be folded into an operand."""
+    return math.frexp(x)[0] == 0.5
+
+
+def _head_masks(lanes, d):
+    """One [1, lanes] mask a head of the step; None where it holds one."""
+    if lanes == d:
+        return [None]
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) // d
+    return [head == h for h in range(lanes // d)]
+
+
+def _stack_heads(x, masks):
+    """[rows, lanes] -> [heads * rows, lanes]: head h's rows hold x on
+    h's lanes and zeros on the others'."""
+    if masks[0] is None:
+        return x
+    return jnp.concatenate(
+        [jnp.where(m, x, jnp.zeros_like(x)) for m in masks], axis=0)
+
+
+def _head_rows(x, heads):
+    """The row blocks of a head-stacked [heads * rows, n] value."""
+    rows = x.shape[0] // heads
+    return [x[h * rows:(h + 1) * rows] for h in range(heads)]
+
+
+def _to_lanes(stat, masks, lanes):
+    """A head-stacked statistic [heads * rows, w] (a row's value on every
+    lane) -> [rows, lanes] with each head's value on its own lanes."""
+    cols = _head_rows(stat, len(masks))
+    if stat.shape[1] != lanes:
+        cols = [c[:, :1] for c in cols]
+    out = cols[-1]
+    for col, mask in zip(cols[-2::-1], masks[-2::-1]):
+        out = jnp.where(mask, col, out)
+    return out
+
+
+def _lane_chunks(x, w):
+    return [x[:, c * w:(c + 1) * w] for c in range(x.shape[1] // w)]
+
+
+def _walk(step, lo, hi, masked):
+    """``step(j, masked)`` for j in [lo, hi): the state lives in scratch."""
     def body(j, carry):
-        # running softmax stats stay 2D [bq, 1] (sublane-oriented);
-        # rank-1 carries would force lane<->sublane relayouts in Mosaic
-        m_prev, l_prev, acc = carry
-        k_blk = k_ref[0, pl.ds(j * _I32_BK, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * _I32_BK, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [bq, bk]
-        if causal:
-            rows = qi * _I32_BQ + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            cols = j * _I32_BK + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)         # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
-
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, acc0))
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l_safe), (bq, LSE_LANES))
+        step(j, masked)
+        return carry
+    jax.lax.fori_loop(lo, hi, body, 0)
 
 
-def _fa_dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                  *, scale, causal, block_q, block_k, seq_k):
-    _I32_BQ = jnp.int32(block_q)
+def _fa_fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, v_scr,
+                            m_scr, l_scr, acc_scr, *, scale, causal,
+                            block_k, d):
+    """One q block of ``lanes // d`` heads against their resident K/V.
+    Key blocks wholly under the diagonal take no mask; only the block(s)
+    the diagonal crosses build one.  The statistics are kept a full vreg
+    wide: the row max is one cross-lane reduction a key block (after an
+    elementwise max over its 128-lane chunks) and the row sum stays in
+    per-lane partials until the q block ends."""
+    qi = pl.program_id(2)
+    bq, lanes = q_ref.shape[1:]
+    heads = lanes // d
+    nk = k_ref.shape[1] // block_k
+    w = m_scr.shape[1]
+    masks = _head_masks(lanes, d)
+    fold = _pow2(scale)
     _I32_BK = jnp.int32(block_k)
-    qi = pl.program_id(1)
+
+    @pl.when(qi == 0)
+    def _stack_v():          # once a (batch, head group)
+        v_scr[...] = _stack_heads(v_ref[0], masks)
+
     q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0][:, :1]                        # [bq, 1] of [bq, 8]
-    delta = delta_ref[0][:, :1]
-    bq, d = q.shape
-    nk_full = seq_k // block_k
-    nk = jnp.minimum(nk_full, ((qi + 1) * block_q + block_k - 1) //
-                     block_k) if causal else nk_full
+    q_st = _stack_heads(q * scale if fold else q, masks)    # [R, lanes]
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def body(j, dq):
-        k_blk = k_ref[0, pl.ds(j * _I32_BK, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * _I32_BK, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * _I32_BQ + jax.lax.broadcasted_iota(
+    def step(j, masked):
+        k0 = pl.multiple_of(j * _I32_BK, block_k) if nk > 1 else 0
+        s = jax.lax.dot_general(q_st, k_ref[0, pl.ds(k0, block_k), :], _NT,
+                                preferred_element_type=jnp.float32)
+        if not fold:
+            s = s * scale
+        if masked:
+            rows = qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 0)
-            cols = j * _I32_BK + jax.lax.broadcasted_iota(
+            cols = k0 + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            seen = rows >= cols
+            s = jnp.concatenate([jnp.where(seen, s_h, _NEG_INF)
+                                 for s_h in _head_rows(s, heads)], axis=0)
+        chunks = _lane_chunks(s, w)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(
+            functools.reduce(jnp.maximum, chunks), axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = [jnp.exp(c - m_new) for c in chunks]
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + functools.reduce(jnp.add, p)
+        # the heads' row blocks side by side: [bq, heads * block_k]
+        # against V stacked by head, one product for every head
+        p = [_head_rows(c, heads) for c in p]
+        p = jnp.concatenate([c[h] for h in range(heads) for c in p], axis=1)
+        v_st = jnp.concatenate(
+            [v_scr[pl.ds(pl.multiple_of(h * k_ref.shape[1] + k0, block_k),
+                         block_k), :] for h in range(heads)], axis=0)
+        pv = jax.lax.dot_general(p.astype(v_st.dtype), v_st, _NN,
+                                 preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * _to_lanes(alpha, masks, lanes) + pv
 
-    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    if nk == 1:              # one block: nothing to walk, no dynamic slice
+        step(0, causal)
+    elif causal:
+        n_clear = jnp.minimum((qi * bq + 1) // block_k, nk)
+        n_seen = jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k, nk)
+        _walk(step, 0, n_clear, False)
+        _walk(step, n_clear, n_seen, True)
+    else:
+        _walk(step, 0, nk, False)
+    l_safe = jnp.maximum(jnp.sum(l_scr[...], axis=-1, keepdims=True), 1e-30)
+    o_ref[0] = (acc_scr[...] / _to_lanes(l_safe, masks, lanes)
+                ).astype(o_ref.dtype)
+    # lse leaves as ROWS [heads, bq] (the backward wants it along lanes):
+    # one transpose of a [bq, 128] tile a q block
+    lse = m_scr[...] + jnp.log(l_safe)
+    lse_t = jnp.broadcast_to(_to_lanes(lse, masks, 128), (bq, 128)).T
+    for h in range(heads):
+        lse_ref[0, 0, h:h + 1, :] = lse_t[h * d:h * d + 1, :]
 
 
-def _fa_dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dk_ref, dv_ref, *, scale, causal, block_q, block_k,
-                   seq_q):
+def _fa_bwd_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                            *, scale, causal, block_q, d):
+    """dq, dk and dv in ONE pass: a key block (grid, outer) against the
+    resident q blocks (loop, inner), scores TRANSPOSED ([keys, queries]:
+    lse and delta broadcast along sublanes, dv and dk are plain products)
+    — S, P and dP are computed once a pair.  A head's dq gathers in
+    float32 scratch over the key blocks."""
+    ki = pl.program_id(2)
+    bk, lanes = k_ref.shape[1:]
+    heads = lanes // d
+    nq = q_ref.shape[1] // block_q
+    masks = _head_masks(lanes, d)
+    fold = _pow2(scale)
+    k = k_ref[0]
+    k_st = _stack_heads(k * scale if fold else k, masks)   # [R, lanes]
+    v_st = _stack_heads(v_ref[0], masks)
     _I32_BQ = jnp.int32(block_q)
-    _I32_BK = jnp.int32(block_k)
-    ki = pl.program_id(1)
-    k = k_ref[0]                                  # [bk, D] (native dtype)
-    v = v_ref[0]
-    bk, d = k.shape
-    nq_full = seq_q // block_q
-    start_q = (ki * block_k) // block_q if causal else 0
 
-    def body(j, carry):
-        dk, dv = carry
-        q_blk = q_ref[0, pl.ds(j * _I32_BQ, block_q), :]
-        do_blk = do_ref[0, pl.ds(j * _I32_BQ, block_q), :]
-        lse_blk = lse_ref[0, pl.ds(j * _I32_BQ, block_q), :1]   # [bq, 1]
-        delta_blk = delta_ref[0, pl.ds(j * _I32_BQ, block_q), :1]
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bk]
-        if causal:
-            rows = j * _I32_BQ + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            cols = ki * _I32_BK + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse_blk)
-        dv_new = dv + jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    dk_scr[...] = jnp.zeros_like(dk_scr)
+    dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def step(j, masked):
+        q0 = pl.multiple_of(j * _I32_BQ, block_q) if nq > 1 else 0
+        q_blk = q_ref[0, pl.ds(q0, block_q), :]
+        do_blk = do_ref[0, pl.ds(q0, block_q), :]
+        s_t = jax.lax.dot_general(k_st, q_blk, _NT,
+                                  preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(v_st, do_blk, _NT,
+                                   preferred_element_type=jnp.float32)
+        if masked:
+            keys = ki * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, block_q), 0)
+            rows = q0 + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, block_q), 1)
+            seen = rows >= keys
+        p_t, ds_t = [], []
+        for h, (s_h, dp_h) in enumerate(zip(_head_rows(s_t, heads),
+                                            _head_rows(dp_t, heads))):
+            lse = lse_ref[0, 0, h:h + 1, pl.ds(q0, block_q)]    # [1, bq]
+            delta = delta_ref[0, 0, h:h + 1, pl.ds(q0, block_q)]
+            if not fold:
+                s_h = s_h * scale
+            if masked:
+                s_h = jnp.where(seen, s_h, _NEG_INF)
+            p_h = jnp.exp(s_h - lse)                             # [bk, bq]
+            ds_h = p_h * (dp_h - delta)
+            if not fold:
+                ds_h = ds_h * scale
+            p_t.append(p_h.astype(do_blk.dtype))
+            ds_t.append(ds_h.astype(q_blk.dtype))
+        # [bk, heads * bq] against dO / Q stacked by head
+        dv_scr[...] += jax.lax.dot_general(
+            jnp.concatenate(p_t, axis=1), _stack_heads(do_blk, masks), _NN,
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_blk, v, (((1,), (1,)), ((), ())),
+        dk_scr[...] += jax.lax.dot_general(
+            jnp.concatenate(ds_t, axis=1), _stack_heads(q_blk, masks), _NN,
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk) * scale
-        dk_new = dk + jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
+        dq_scr[pl.ds(q0, block_q), :] += jax.lax.dot_general(
+            jnp.concatenate(ds_t, axis=0), k_st, _TN,
             preferred_element_type=jnp.float32)
-        return dk_new, dv_new
 
-    z = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_q, nq_full, body, (z, z))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if nq == 1:              # one block: nothing to walk, no dynamic slice
+        step(0, causal)
+    elif causal:
+        first = (ki * bk) // block_q
+        n_cut = jnp.minimum(((ki + 1) * bk - 1 + block_q - 1) // block_q, nq)
+        _walk(step, first, n_cut, True)
+        _walk(step, n_cut, nq, False)
+    else:
+        _walk(step, 0, nq, False)
+    # with the scale folded into k, ds was left unscaled: dq took the
+    # scale through k_st, dk takes it here
+    dk_ref[0] = (dk_scr[...] * scale if fold else dk_scr[...]
+                 ).astype(dk_ref.dtype)
+    dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _fa_call_fwd_resident(q, k, v, scale, causal, block_q, block_k):
-    """q,k,v: [BH, S, D] -> (o [BH, Sq, D], lse [BH, Sq, LSE_LANES])."""
-    bh, sq, d = q.shape
+# a step's tiles are its own to size: [512, 512] float32 scores and their
+# exponentials are megabytes, past the 16 MB Mosaic scopes by default on a
+# v5e of 128 MB
+_RESIDENT_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=48 * 2 ** 20)
+
+
+def _fa_call_fwd_resident(q, k, v, scale, causal, block_q, block_k, d):
+    """q, k, v: [B, S, lanes_all] (heads of d side by side in the lanes)
+    -> (o like q, lse [B, groups, heads a step, Sq] float32)."""
+    b, sq, width = q.shape
     sk = k.shape[1]
-    nq = sq // block_q
+    lanes = _step_lanes(width, d)
+    groups, hp = width // lanes, lanes // d
+    w = 128 if block_k % 128 == 0 else block_k
     kernel = functools.partial(
         _fa_fwd_kernel_resident, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_k=sk)
+        block_k=block_k, d=d)
     with _x64_off():
         return pl.pallas_call(
-        kernel,
-        name="flash_attention_fwd",
-        grid=(bh, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, LSE_LANES), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, LSE_LANES), jnp.float32),
-        ],
+            kernel,
+            name="flash_attention_fwd",
+            grid=(b, groups, sq // block_q),
+            in_specs=[
+                pl.BlockSpec((1, block_q, lanes), lambda b, g, i: (b, i, g)),
+                pl.BlockSpec((1, sk, lanes), lambda b, g, i: (b, 0, g)),
+                pl.BlockSpec((1, sk, lanes), lambda b, g, i: (b, 0, g)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, lanes), lambda b, g, i: (b, i, g)),
+                pl.BlockSpec((1, 1, hp, block_q),
+                             lambda b, g, i: (b, g, 0, i)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, sq, width), q.dtype),
+                jax.ShapeDtypeStruct((b, groups, hp, sq), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((hp * sk, lanes), v.dtype),
+                pltpu.VMEM((hp * block_q, w), jnp.float32),
+                pltpu.VMEM((hp * block_q, w), jnp.float32),
+                pltpu.VMEM((block_q, lanes), jnp.float32),
+            ],
+            compiler_params=_RESIDENT_PARAMS,
             interpret=_interpret(),
         )(q, k, v)
 
 
-def _fa_call_bwd_resident(q, k, v, o, lse, do, scale, causal, block_q, block_k):
-    bh, sq, d = q.shape
+def _fa_call_bwd_resident(q, k, v, o, lse, do, scale, causal, block_q,
+                          block_k, d):
+    b, sq, width = q.shape
     sk = k.shape[1]
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)                 # [BH, Sq, 1]
-    delta = jnp.broadcast_to(delta, (bh, sq, LSE_LANES))
+    lanes = _step_lanes(width, d)
+    groups, hp = width // lanes, lanes // d
+    # delta from the very bf16 o and dO the kernel reads: unfenced, XLA
+    # fuses this product into the matmul that makes dO and takes its
+    # value BEFORE the rounding — rows of dS then sum to the rounding of
+    # dO, not to zero (the key bias's zero gradient read a quarter more
+    # residue on the chip, PERF.md 39.4)
+    o, do = jax.lax.optimization_barrier((o, do))
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(b, sq, groups, hp, d), axis=-1)
+    delta = delta.transpose(0, 2, 3, 1)             # [B, groups, hp, Sq]
+    whole = pl.BlockSpec((1, sq, lanes), lambda b, g, j: (b, 0, g))
+    block = pl.BlockSpec((1, block_k, lanes), lambda b, g, j: (b, j, g))
+    rows = pl.BlockSpec((1, 1, hp, sq), lambda b, g, j: (b, g, 0, 0))
     with _x64_off():
-        dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel_resident, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_k=sk),
-        name="flash_attention_dq",
-        grid=(bh, sq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, LSE_LANES), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, LSE_LANES), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        return pl.pallas_call(
+            functools.partial(_fa_bwd_kernel_resident, scale=scale,
+                              causal=causal, block_q=block_q, d=d),
+            name="flash_attention_bwd",
+            grid=(b, groups, sk // block_k),
+            in_specs=[whole, block, block, whole, rows, rows],
+            out_specs=[whole, block, block],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, sq, width), q.dtype),
+                jax.ShapeDtypeStruct((b, sk, width), k.dtype),
+                jax.ShapeDtypeStruct((b, sk, width), v.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((sq, lanes), jnp.float32),
+                            pltpu.VMEM((block_k, lanes), jnp.float32),
+                            pltpu.VMEM((block_k, lanes), jnp.float32)],
+            compiler_params=_RESIDENT_PARAMS,
             interpret=_interpret(),
         )(q, k, v, do, lse, delta)
-        dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel_resident, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_q=sq),
-        name="flash_attention_dkv",
-        grid=(bh, sk // block_k),
-        in_specs=[
-            pl.BlockSpec((1, sq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sq, LSE_LANES), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sq, LSE_LANES), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-        ],
-            interpret=_interpret(),
-        )(q, k, v, do, lse, delta)
-    return dq, dk, dv
-
 
 
 # ---------------------------------------------------------------------------
-# kernel variant dispatch: the RESIDENT kernels map full K/V into VMEM
-# (fastest: one kernel invocation per q block, measured 1.4x the
-# streaming variant at s=1024) but cap the sequence at VMEM; the
-# STREAMING kernels above block K/V through a 3D grid with scratch
-# carries and have no sequence cap (32k+ tested on hardware). Pick per
-# shape.
+# What ONE grid step holds, decided in one place from what can be seen of
+# the call.  The RESIDENT family needs (sq + sk) * d inside its budget;
+# past it the STREAMING kernels above block K/V through a 3-D grid with
+# scratch carries, one head a step on [B*H, S, D], and have no sequence
+# cap (32k tested on hardware).  At [16, 1024, 12, 64] bf16 causal, both
+# at their best tiles, the streamed family takes 4.80 ms forward +
+# backward where the resident one takes 1.87 (v5e, PR 39): both stay.
 # ---------------------------------------------------------------------------
 
-_RESIDENT_VMEM_ELEMS = 1_500_000  # (sq + sk) * d fp32 budget, ~6MB x2
+_RESIDENT_VMEM_ELEMS = 1_500_000  # (sq + sk) * d
+_MIN_BLOCK = 128
 
 
 def _use_resident(sq, sk, d):
     return (sq + sk) * d <= _RESIDENT_VMEM_ELEMS
 
 
-def _fa_dispatch_fwd(q, k, v, scale, causal, block_q, block_k):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    if _use_resident(sq, sk, d):
-        return _fa_call_fwd_resident(q, k, v, scale, causal, block_q,
-                                     block_k)
-    return _fa_call_fwd(q, k, v, scale, causal, block_q, block_k)
+def _step_lanes(width, d):
+    """Lanes of one grid step out of ``width = heads * d``: 128 // d
+    heads side by side where they fill a 128-lane block, else one head."""
+    return 128 if d < 128 and 128 % d == 0 and width % 128 == 0 else d
 
 
-def _fa_dispatch_bwd(q, k, v, o, lse, do, scale, causal, block_q,
-                     block_k):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    if _use_resident(sq, sk, d):
-        return _fa_call_bwd_resident(q, k, v, o, lse, do, scale, causal,
-                                     block_q, block_k)
-    return _fa_call_bwd(q, k, v, o, lse, do, scale, causal, block_q,
-                        block_k)
+def _wide_block(seq, widest):
+    """The widest power-of-two tile that divides ``seq`` and leaves it at
+    least two blocks (a causal walk still skips, the pipeline still has a
+    next step); a short or odd length keeps the 128 every shape had."""
+    block = widest
+    while block > _MIN_BLOCK and (seq % block or block * 2 > seq):
+        block //= 2
+    return min(block, seq)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention_bhsd(q, k, v, scale, causal, block_q, block_k):
-    o, _ = _fa_dispatch_fwd(q, k, v, scale, causal, block_q, block_k)
-    return o
+def flash_attention_plan(sq, sk, d, heads, causal=False, dtype="bfloat16"):
+    """The plan of one flash-attention call, a plain dict: tile sizes of
+    the forward and the backward, heads a grid step, whether K/V stay
+    resident, and whether the call works on [B, S, H*D] as it stands
+    (``packed``) or on a transposed [B*H, S, D]."""
+    del causal, dtype         # seen, and so far not what decides
+    resident = _use_resident(sq, sk, d)
+    lanes = _step_lanes(heads * d, d) if resident else d
+    packed = resident and (lanes % 128 == 0 or heads == 1)
+    wide_q, wide_k = _WIDE_FWD if resident else _WIDE_STREAMED
+    bwd_q, bwd_k = _WIDE_BWD if resident else _WIDE_STREAMED
+    return {
+        "block_q": _wide_block(sq, wide_q), "block_k": _wide_block(sk, wide_k),
+        "bwd_block_q": _wide_block(sq, bwd_q),
+        "bwd_block_k": _wide_block(sk, bwd_k),
+        "heads_per_step": lanes // d if packed else 1,
+        "resident": resident, "packed": packed}
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k):
-    o, lse = _fa_dispatch_fwd(q, k, v, scale, causal, block_q, block_k)
+# The widest tiles the plan hands out, (q, k), each the measured winner of
+# one layer's call on a v5e (PR 39, PERF.md §6; ms forward | forward +
+# backward).  [16, 1024, 12, 64] bf16 causal, forward of 256x256 0.93,
+# 256x512 0.79, 512x256 0.84, 512x512 0.73; with it the one backward
+# kernel at 128x256 2.59, 256x128 2.36, 256x512 2.04, 512x256 2.05,
+# 512x512 2.02, 256x256 1.93.  The streamed family at [1, 16384, 12, 64]:
+# 128x128 75 | 230, 256x256 31 | 95, 512x512 13.5 | 42.4.
+_WIDE_FWD = (512, 512)
+_WIDE_BWD = (256, 256)
+_WIDE_STREAMED = (512, 512)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_plan(sq, sk, d, heads, causal, dtype, blocks):
+    """Once a shape, where its kernel is first built."""
+    logger.info("flash_attention sq=%d sk=%d d=%d heads=%d causal=%s %s: "
+                "blocks fwd/bwd %s of plan %s", sq, sk, d, heads, causal,
+                dtype, blocks,
+                flash_attention_plan(sq, sk, d, heads, causal, dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention_core(q, k, v, scale, causal, blocks, d, resident):
+    """q, k, v: [B', S, H' * d].  ``blocks`` = forward (q, k) + backward
+    (q, k) tile sizes."""
+    return _flash_fwd_rule(q, k, v, scale, causal, blocks, d, resident)[0]
+
+
+def _flash_fwd_rule(q, k, v, scale, causal, blocks, d, resident):
+    call = functools.partial(_fa_call_fwd_resident, d=d) if resident \
+        else _fa_call_fwd
+    o, lse = call(q, k, v, scale, causal, blocks[0], blocks[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, res, do):
-    q, k, v, o, lse = res
-    return _fa_dispatch_bwd(q, k, v, o, lse, do, scale, causal, block_q,
-                            block_k)
+def _flash_bwd_rule(scale, causal, blocks, d, resident, res, do):
+    call = functools.partial(_fa_call_bwd_resident, d=d) if resident \
+        else _fa_call_bwd
+    return call(*res, do, scale, causal, blocks[2], blocks[3])
 
 
-_flash_attention_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_attention_core.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention(q, k, v, is_causal=False, scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+                    block_q=None, block_k=None):
     """Flash attention on [B, S, H, D] inputs (the framework's attention
-    layout). Differentiable via the Pallas backward kernels."""
+    layout). Differentiable via the Pallas backward kernels.  The tiles
+    are ``flash_attention_plan``'s unless ``block_q``/``block_k`` name
+    them (the autotuner's candidates, a user's cache entry)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    if sq % block_q or sk % block_k:
+    plan = flash_attention_plan(sq, sk, d, h, is_causal, q.dtype)
+    blocks = (plan["block_q"], plan["block_k"],
+              plan["bwd_block_q"], plan["bwd_block_k"])
+    named = (min(block_q or blocks[0], sq), min(block_k or blocks[1], sk))
+    if named != blocks[:2]:
+        blocks = named + named
+    if any(sq % blk for blk in blocks[0::2]) or \
+            any(sk % blk for blk in blocks[1::2]):
         raise ValueError(
             f"flash_attention needs seq lengths divisible by the block "
-            f"sizes: sq={sq} %% {block_q}, sk={sk} %% {block_k}")
-    # [B,S,H,D] -> [B*H, S, D]
+            f"sizes: sq={sq}, sk={sk}, blocks (q, k, bwd q, bwd k)={blocks}")
+    _log_plan(sq, sk, d, h, bool(is_causal), str(q.dtype), blocks)
+    args = (float(s), bool(is_causal), tuple(int(x) for x in blocks), d,
+            plan["resident"])
+    if plan["packed"]:
+        # [B, S, H, D] -> [B, S, H*D]: a view, no copy
+        o = _flash_attention_core(
+            q.reshape(b, sq, h * d), k.reshape(b, sk, h * d),
+            v.reshape(b, sk, h * d), *args)
+        return o.reshape(b, sq, h, d)
+    # heads that do not fill a lane block: one head a step on [B*H, S, D]
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    o = _flash_attention_bhsd(qt, kt, vt, float(s), bool(is_causal),
-                              int(block_q), int(block_k))
+    o = _flash_attention_core(qt, kt, vt, *args)
     return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 
 
-# Measured crossover on v5e (BENCH r3): at seq 128 XLA's native fused
-# attention beats the flash kernel (BERT 47.6 vs 35.9 steps/s — the full
-# S^2 matrix is tiny and XLA's bf16 fusion wins), while at seq 1024 the
-# flash kernel wins 1.16x (GPT-2). This heuristic is only the DEFAULT:
-# the shape-class autotune cache (ops/autotune_cache.py, r3 verdict
-# item 9) overrides it wherever a measured winner is recorded, and
-# tune_attention() records winners per device kind.
+# Below this length the DEFAULT tier is XLA's own attention.  One layer's
+# call, causal bf16, 12 heads of 64, forward + backward on a v5e (PR 39,
+# PERF.md §6; ms flash | lax): [16, 1024] 1.87 | 9.06, [32, 512] 1.43 |
+# 4.70, [64, 256] 1.73 | 2.32 (forward alone 0.80 | 0.74), [128, 128]
+# 1.07 | 1.06 — a tie at 128, where the full S^2 matrix is tiny.  This
+# heuristic is only the DEFAULT: the shape-class autotune cache
+# (ops/autotune_cache.py) overrides it wherever a measured winner is
+# recorded, and tune_attention() records winners per device kind.
 FLASH_MIN_SEQ = 512
 
 
@@ -673,8 +835,7 @@ def _sdpa_key(b, h, sq, sk, d, dtype, is_causal):
                            causal=bool(is_causal), tune="bwd2")
 
 
-def _fa_supported(q, k, v, mask, dropout_key, dropout_p, is_causal,
-                  block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+def _fa_supported(q, k, v, mask, dropout_key, dropout_p, is_causal):
     qs, ks = _shape_of(q), _shape_of(k)
     if len(qs) != 4 or mask is not None or (dropout_p or 0.0) > 0.0:
         return False
@@ -682,15 +843,14 @@ def _fa_supported(q, k, v, mask, dropout_key, dropout_p, is_causal,
     sk = ks[1]
     if is_causal and sq != sk:
         return False
-    bq, bk = min(block_q, sq), min(block_k, sk)
     # structural requirements first — an unlowrable shape never dispatches
-    # to Pallas regardless of what the cache says.
-    # streaming kernels: VMEM holds only (block_q + 2*block_k) x d tiles
-    # plus scratch regardless of sequence length, so there is no seq cap —
-    # long context is bounded by HBM for Q/K/V themselves (e.g. 128k x 128
-    # bf16 = 32MB per head-batch).
-    if not (sq % bq == 0 and sk % bk == 0 and d <= 256 and
-            sq >= 8 and sk >= 8):
+    # to Pallas regardless of what the cache says.  There is no seq cap:
+    # past the resident budget the streaming kernels hold only
+    # (block_q + 2*block_k) x d tiles plus scratch, and long context is
+    # bounded by HBM for Q/K/V themselves (e.g. 128k x 128 bf16 = 32MB
+    # per head-batch).
+    if not (sq % min(_MIN_BLOCK, sq) == 0 and sk % min(_MIN_BLOCK, sk) == 0
+            and d <= 256 and sq >= 8 and sk >= 8):
         return False
     if flag_value("FLAGS_pallas_force"):
         return True
@@ -703,20 +863,21 @@ def _fa_supported(q, k, v, mask, dropout_key, dropout_p, is_causal,
     return choice.startswith("pallas")   # incl. "pallas:BQxBK" configs
 
 
-# block-size search space for tune_attention (r4 verdict item 3: the
-# flash bwd was undertuned at the default 128x128). Unlowerable or
-# non-dividing combos simply fail their measurement and never win.
-_TUNE_BLOCKS = [(128, 128), (256, 128), (128, 256), (256, 256)]
+# block-size search space for tune_attention beside the plan's own tiles
+# (which keep the plain name ``pallas``). Unlowerable or non-dividing
+# combos simply fail their measurement and never win.
+_TUNE_BLOCKS = [(128, 128), (256, 256), (256, 512), (512, 512), (512, 1024)]
 
 
 def tune_attention(q, a_k, v, is_causal=False, persist=True,
                    include_bwd=True, skip_if_cached=False):
-    """Measure lax vs pallas (across block-size configs) for this shape
-    class on CONCRETE arrays and record the winner in the autotune cache
-    (the reference's warmup-step measurement, made explicit). With
-    ``include_bwd`` the timed quantity is a full fwd+bwd — the training
-    crossover, which is what the benches dispatch on. Returns the
-    winning tier name (``lax``, ``pallas``, or ``pallas:BQxBK``)."""
+    """Measure lax vs pallas (the plan's tiles and other block-size
+    configs) for this shape class on CONCRETE arrays and record the
+    winner in the autotune cache (the reference's warmup-step
+    measurement, made explicit). With ``include_bwd`` the timed quantity
+    is a full fwd+bwd — the training crossover, which is what the benches
+    dispatch on. Returns the winning tier name (``lax``, ``pallas``, or
+    ``pallas:BQxBK``)."""
     import jax.numpy as jnp
 
     from . import autotune_cache as _at
@@ -744,21 +905,21 @@ def tune_attention(q, a_k, v, is_causal=False, persist=True,
         return lambda: jg(q, a_k, v)
 
     candidates = {
-        "lax": thunk(functools.partial(lax_fn, is_causal=is_causal))}
-    seen_effective = set()
+        "lax": thunk(functools.partial(lax_fn, is_causal=is_causal)),
+        "pallas": thunk(functools.partial(flash_attention,
+                                          is_causal=is_causal))}
+    plan = flash_attention_plan(sq, sk, d, h, is_causal, q.dtype)
+    # dedup on the CLAMPED blocks: at short seq several configs collapse
+    # to the same kernel — measuring it repeatedly under different names
+    # is pure tuning-budget waste (and blocks that name the plan's own
+    # forward tiles ARE the plan)
+    seen_effective = {(plan["block_q"], plan["block_k"])}
     for bq, bk in _TUNE_BLOCKS:
-        # dedup on the CLAMPED blocks: at short seq several configs
-        # collapse to the same kernel — measuring it repeatedly under
-        # different names is pure tuning-budget waste
         eff = (min(bq, sq), min(bk, sk))
         if eff in seen_effective:
             continue
         seen_effective.add(eff)
-        if eff == (min(DEFAULT_BLOCK_Q, sq), min(DEFAULT_BLOCK_K, sk)):
-            name = "pallas"       # default blocks keep the plain name
-        else:
-            name = f"pallas:{bq}x{bk}"
-        candidates[name] = thunk(functools.partial(
+        candidates[f"pallas:{bq}x{bk}"] = thunk(functools.partial(
             flash_attention, is_causal=is_causal, block_q=bq, block_k=bk))
     return _at.measure("scaled_dot_product_attention", key, candidates,
                        persist=persist)
@@ -766,12 +927,13 @@ def tune_attention(q, a_k, v, is_causal=False, persist=True,
 
 def _tuned_blocks(q, k, is_causal):
     """Dispatch-time lookup of the measured block config (host-side dict
-    read; shapes are static under trace). Falls back to the defaults
+    read; shapes are static under trace). Falls back to the plan's tiles
     when the tuned blocks do not divide THIS shape — the pow2-bucketed
     shape class can contain members the winning config cannot tile."""
     from . import autotune_cache as _at
     b, sq, h, d = _shape_of(q)
     sk = _shape_of(k)[1]
+    plan = flash_attention_plan(sq, sk, d, h, is_causal, _dtype_of(q))
     choice = _at.choose(
         "scaled_dot_product_attention",
         _sdpa_key(b, h, sq, sk, d, _dtype_of(q), is_causal),
@@ -782,11 +944,11 @@ def _tuned_blocks(q, k, is_causal):
         except ValueError:
             import warnings
             warnings.warn(f"malformed autotune entry {choice!r}; using "
-                          f"default flash blocks", RuntimeWarning)
-            return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
-        if sq % min(bq, sq) == 0 and sk % min(bk, sk) == 0:
-            return bq, bk
-    return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
+                          f"the plan's flash blocks", RuntimeWarning)
+        else:
+            if sq % min(bq, sq) == 0 and sk % min(bk, sk) == 0:
+                return bq, bk
+    return plan["block_q"], plan["block_k"]
 
 
 def _sdpa_pallas(q, k, v, mask=None, dropout_key=None, dropout_p=0.0,
