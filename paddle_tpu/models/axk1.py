@@ -208,14 +208,17 @@ def _mm(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _swiglu(x, gate, up, down):
-    """``down(silu(gate(x)) * up(x))``: operands in the weights' dtype,
-    float32 accumulation, the product of the two branches taken in
-    float32 and rounded once. Returns float32."""
+def _swiglu(x, gate, up, down, gate_scale: float = 1.0):
+    """``down(silu(gate(x) * gate_scale) * up(x))``: operands in the
+    weights' dtype, float32 accumulation, the product of the two branches
+    taken in float32 and rounded once. Returns float32."""
     import jax
     import jax.numpy as jnp
     f32 = lambda a, b: jnp.dot(a, b, preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(f32(x, gate)) * f32(x, up)).astype(x.dtype)
+    g = f32(x, gate)
+    if gate_scale != 1.0:
+        g = g * gate_scale
+    h = (jax.nn.silu(g) * f32(x, up)).astype(x.dtype)
     return f32(h, down)
 
 

@@ -19,12 +19,30 @@ object with
   ``attn_out(x, a, row_valid) -> (x, counters)`` (output projection,
   residual, FFN; ``row_valid [Q]`` marks the rows of the ragged batch that
   are real, which a routed FFN must not route; ``counters`` is ``None``
-  or the routed layer's three int32 scalars);
+  or the routed layer's three int32 scalars); a layer whose spec has a
+  recurrent STATE also ``mixer(x, layout, state, index) -> (s, state)``
+  (the rows in, the rows' sequence layout — ``ops/ssm.py:SeqLayout`` —
+  and the slots' state arrays in, the branch's output and the new state
+  out; ``index`` is the layer's place in the state arrays), and its
+  ``attn_out`` takes the branch's output as a fourth argument;
 * ``final_norm(x)`` and ``logits(hidden)``.
 
-Two attention kinds, two FFN kinds, one generation rule, four callers
+Two attention kinds, two FFN kinds, a recurrent state beside the
+attention cache or none, one generation rule, five callers
 (``models/gpt.py``, ``models/axk1.py``, ``models/sdar.py``,
-``models/mimo.py``). Nothing else is described here.
+``models/mimo.py``, ``models/falcon_h1.py``). Nothing else is described
+here.
+
+**Recurrent state.** A layer may run a state-space mixer BESIDE (not
+instead of) its attention: what a sequence then leaves behind in the
+layer is its cache entries, which grow with the context, and a STATE of
+fixed size — :class:`StateSpec`: the parts one sequence holds (the
+convolution's tail, the recurrence's state), their shapes and dtypes.
+The spec derives the layers that have one (``state_layers``), all of one
+descriptor, and the paged pool holds one array a part, ``[those layers,
+slots + 1, *shape]``, a row a slot (``serving/paging.py``). A state has
+no snapshot a block: nothing that rolls a position back or reuses a
+prefix composes with it (``serving/engine.py:_refuse_with_state``).
 
 **Cache groups.** The layers of one model need not share a cache
 descriptor: the spec derives its CACHE GROUPS — the layers with equal
@@ -67,9 +85,10 @@ from dataclasses import dataclass
 from functools import cached_property
 from typing import Optional, Tuple
 
-__all__ = ["CacheSpec", "LayerSpec", "DecoderSpec", "GenerationRule",
-           "CacheGroup", "serving_decoder", "FULL", "LATENT", "DENSE",
-           "ROUTED", "SECTIONS", "section", "layer_scope", "section_of"]
+__all__ = ["CacheSpec", "StateSpec", "LayerSpec", "DecoderSpec",
+           "GenerationRule", "CacheGroup", "serving_decoder", "FULL",
+           "LATENT", "DENSE", "ROUTED", "SECTIONS", "section", "layer_scope",
+           "section_of"]
 
 FULL, LATENT = "full", "latent"        # attention kinds
 DENSE, ROUTED = "dense", "routed"      # FFN kinds
@@ -94,8 +113,12 @@ MLP = "mlp"                      # a dense FFN; the add that closes a layer
 HEAD = "head"                    # the logits of the rows that are read
 SAMPLE = "sample"                # the pick of one token a slot
 UNMASK_SCOPE = "unmask"          # a block pass's head, confidence, choice
+SSM_PROJ = "ssm_proj"            # a mixer's in/out projection, gated norm
+SSM_CONV = "ssm_conv"            # its causal convolution and the tail
+SSM_SCAN = "ssm_scan"            # its recurrence and the D skip
 SECTIONS = (EMBED, NORM, QKV, CACHE_WRITE, ATTENTION, O_PROJ, ROUTER,
-            MOE_SCOPE, SHARED_EXPERT, MLP, HEAD, SAMPLE, UNMASK_SCOPE)
+            MOE_SCOPE, SHARED_EXPERT, MLP, HEAD, SAMPLE, UNMASK_SCOPE,
+            SSM_PROJ, SSM_CONV, SSM_SCAN)
 
 
 def section(name: str):
@@ -157,6 +180,30 @@ class CacheSpec:
 
 
 @dataclass(frozen=True)
+class StateSpec:
+    """What ONE sequence holds in one layer's recurrent state, whatever
+    its context's length: ``parts``, each ``(name, shape, dtype)`` — a
+    Mamba-2 mixer's are the convolution's tail ``("conv", (d_conv - 1,
+    channels), ...)`` and the recurrence's state ``("ssm", (heads, P,
+    N), ...)``."""
+    parts: Tuple[Tuple[str, Tuple[int, ...], str], ...]
+
+    def __post_init__(self):
+        if not self.parts:
+            raise ValueError("a state descriptor needs at least one part")
+        for name, shape, _ in self.parts:
+            if not shape or min(shape) < 1:
+                raise ValueError(f"state part {name!r}: bad shape {shape}")
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes one sequence's state takes in one layer."""
+        import numpy as np
+        return sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+                   for _, shape, dtype in self.parts)
+
+
+@dataclass(frozen=True)
 class LayerSpec:
     attention: str
     cache: CacheSpec
@@ -164,6 +211,7 @@ class LayerSpec:
     window: int = 0          # 0: a row sees all of the context
     sinks: bool = False      # a learned logit a query head in the softmax
     query_heads: int = 0     # 0: as many as the cache's rows (KV heads)
+    state: Optional[StateSpec] = None   # a recurrent state beside the cache
 
     def __post_init__(self):
         if self.window < 0:
@@ -177,11 +225,18 @@ class LayerSpec:
                 "a sliding window and sink logits are built for the full "
                 "attention kind only (the latent kernel has neither)")
         if self.attention not in (FULL, LATENT):
-            raise ValueError(f"attention kind {self.attention!r}: the fused "
-                             f"path knows {FULL!r} and {LATENT!r}")
+            raise ValueError(
+                f"attention kind {self.attention!r}: the fused path knows "
+                f"{FULL!r} and {LATENT!r} (a state-space mixer is no third "
+                f"kind: it runs beside one of them, state=)")
         if self.ffn not in (DENSE, ROUTED):
             raise ValueError(f"FFN kind {self.ffn!r}: the fused path knows "
                              f"{DENSE!r} and {ROUTED!r}")
+        if self.state is not None and (self.attention != FULL
+                                       or self.window):
+            raise ValueError(
+                "a recurrent state is built beside full attention over "
+                "the whole context only (no latent cache, no window)")
 
 
 @dataclass(frozen=True)
@@ -234,6 +289,17 @@ class DecoderSpec:
             raise ValueError(
                 "block generation is built for the full attention kind "
                 "only (the latent kernel's mask is causal)")
+        states = {ls.state for ls in self.layers if ls.state is not None}
+        if len(states) > 1:
+            raise ValueError(
+                "the layers that have a recurrent state differ in its "
+                "descriptor: the pool holds ONE array a part for all of "
+                "them")
+        if states and self.generation.block_length > 1:
+            raise ValueError(
+                "a recurrent state under block generation is not built: a "
+                "denoising pass rewrites its block's rows, and a "
+                "recurrence cannot take a row back")
         groups = self.cache_groups
         if len(groups) > 1 and (
                 self.generation.block_length > 1
@@ -277,6 +343,20 @@ class DecoderSpec:
                                      max(1, heads.pop() // c.rows)))
         return tuple(groups)
 
+    @cached_property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that hold a recurrent state, in order: a layer's
+        place here is its place in the pool's state arrays."""
+        return tuple(i for i, ls in enumerate(self.layers)
+                     if ls.state is not None)
+
+    @property
+    def state(self) -> Optional[StateSpec]:
+        """The state descriptor those layers share (None: no layer has
+        one)."""
+        return self.layers[self.state_layers[0]].state \
+            if self.state_layers else None
+
     def layer_group(self, layer: int) -> Tuple[int, int]:
         """``(group, index inside the group's pool array)`` of a layer."""
         for g, grp in enumerate(self.cache_groups):
@@ -305,5 +385,6 @@ def serving_decoder(model):
             f"{type(model).__name__} exposes no serving_decoder(): the "
             f"fused serving stack consumes a decoder spec "
             f"(models/decoder_spec.py), which models/gpt.py, "
-            f"models/axk1.py, models/sdar.py and models/mimo.py provide")
+            f"models/axk1.py, models/sdar.py, models/mimo.py and "
+            f"models/falcon_h1.py provide")
     return make()

@@ -691,7 +691,7 @@ def _write_latent_rows(pool, li, wb, off, rows):
 
 def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                  blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-                 quantized, qmax):
+                 quantized, qmax, state=None):
     """The fused ragged transformer tower shared by
     :func:`build_fused_step_fn` and :func:`build_spec_verify_fn`, over
     the layers of a decoder spec (``models/decoder_spec.py``; ``dec`` is a
@@ -708,8 +708,14 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     the pools come back as a tuple too.
     A ``routed`` FFN is told which rows are real (pad rows name the
     scratch block 0) and returns its three counters, summed over the
-    layers here. Returns ``(final_norm(x), pool, scales, counters)``,
-    ``counters`` ``None`` for a model without routed layers."""
+    layers here. A layer whose spec has a recurrent STATE runs its mixer
+    beside the attention: ``state`` is the slots' state arrays (one a
+    part of the descriptor, ``[layers with state, slots + 1, ...]``), the
+    mixer reads each sequence's row of them and leaves the state after
+    its last real row there (``ops/ssm.py``: a sequence at position 0
+    starts from zero, pad rows touch nothing). Returns ``(final_norm(x),
+    pool, scales, counters, state)``, ``counters`` ``None`` for a model
+    without routed layers, ``state`` ``None`` for one without state."""
     import jax.numpy as jnp
 
     from ..ops.kv_append import kv_append
@@ -723,6 +729,11 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
         else ((write_block,), (tables,), (lo,))
     with DS.section(DS.EMBED):
         row_valid = wbs[0] > 0
+        if state is not None:
+            from ..ops.ragged_paged_attention import BLOCK_Q
+            from ..ops.ssm import seq_layout
+            layout = seq_layout(blk_seq, seq_qstart, seq_pos0, kv_len,
+                                row_valid, BLOCK_Q)
     counters = None
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
         # the layer's cache group, and its place in the group's array
@@ -766,7 +777,13 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                         q, pools[g], gi, blk_seq, seq_qstart, seq_pos0,
                         tabs[g], los[g], kv_len, v_lanes=ls.cache.v_lanes,
                         scale=dec.attention_scale)
-            x, c = layer.attn_out(x, a, row_valid)
+            if ls.state is not None:
+                # the mixer names its own sections too
+                mixed, state = layer.mixer(
+                    x, layout, state, dec.spec.state_layers.index(li))
+                x, c = layer.attn_out(x, a, row_valid, mixed)
+            else:
+                x, c = layer.attn_out(x, a, row_valid)
             if c is not None:
                 with DS.section(DS.MOE_SCOPE):
                     counters = c if counters is None else tuple(
@@ -776,7 +793,8 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
             counters = jnp.stack(counters).astype(jnp.int32)
     with DS.section(DS.NORM):
         x = dec.final_norm(x)
-    return x, tuple(pools) if grouped else pools[0], scales, counters
+    return (x, tuple(pools) if grouped else pools[0], scales, counters,
+            state)
 
 
 def _named(fn, name: str):
@@ -928,7 +946,7 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
                         row_blk >= 0, shown[jnp.maximum(row_blk, 0)],
                         token_ids)
                     x = dec.embed_tokens(token_ids, qpos)
-                x, new_pool, _, counters = _fused_tower(
+                x, new_pool, _, counters, _ = _fused_tower(
                     dec, x, qpos, pool, None, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     False, 0.0)
@@ -967,7 +985,10 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     write_off, blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
     last_row, prev_tokens, token_src, sample_mask, temperature, key) ->
     (pool, next_tokens, key)`` over the block pool ``[layers,
-    num_blocks + 1, heads, block_size, 2 * head_dim]`` (``next_tokens``
+    num_blocks + 1, heads, block_size, 2 * head_dim]`` (for a spec whose
+    layers hold a recurrent state, ``pool`` is the pair ``(block pool,
+    state arrays)``: ``PagedKVPool.state_data``, one array a part, a row
+    a slot; ``next_tokens``
     ``[num_slots + 1]`` — the last element is the logits-finite sentinel
     of :func:`_append_nonfinite_flag`; a routed model appends its three
     counters):
@@ -1035,11 +1056,21 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                 "block generation over int8/fp8 blocks is not built")
         return _build_block_step_fn(model, dec, S, Q, T, probe)
 
+    stateful = bool(dec.spec.state_layers)
+    if stateful and quantized:
+        raise ValueError(
+            "a recurrent state beside int8/fp8 blocks is not built")
+
     def fn(params, buffers, pool, *rest):
         (scales, token_ids, qpos, write_block, write_off, blk_seq,
          seq_qstart, seq_pos0, tables, lo, kv_len, last_row,
          prev_tokens, token_src, sample_mask, temperature, key) = \
             rest if quantized else (None,) + rest
+        state = None
+        if stateful:
+            # the pool operand holds the slots' state arrays too: one
+            # donated pytree, back as it came
+            pool, state = pool
         if probe is not None:  # runs at trace time only (jit caches)
             probe.record(_probe.sig_of(jax.tree_util.tree_leaves(
                 [pool, token_ids, tables])), {"q": Q, "table": T})
@@ -1052,10 +1083,12 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                     # sequences are aligned at virtual 0; lo is the mask
                     # floor, not a pad offset)
                     x = dec.embed_tokens(token_ids, qpos)
-                x, new_pool, new_scales, counters = _fused_tower(
+                x, new_pool, new_scales, counters, state = _fused_tower(
                     dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-                    quantized, qmax)
+                    quantized, qmax, state)
+                if stateful:
+                    new_pool = (new_pool, state)
                 with DS.section(DS.HEAD):
                     last = x._data[0, last_row]             # [S, E]
                     logits = dec.logits(
@@ -1404,7 +1437,7 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
                     token_ids = token_ids.at[safe.reshape(-1)].set(
                         draft_toks.reshape(-1), mode="drop")
                     x = dec.embed_tokens(token_ids, qpos)
-                x, new_pool, new_scales, _ = _fused_tower(
+                x, new_pool, new_scales, _, _ = _fused_tower(
                     dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
                     quantized, qmax)

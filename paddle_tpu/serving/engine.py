@@ -323,6 +323,43 @@ def _refuse_with_groups(kv_dtype, mesh, spec_draft,
             "one head-partitioned pool and one table")
 
 
+def _refuse_with_state(kv_dtype, mesh, spec_draft,
+                       host_tier_bytes) -> None:
+    """A model whose layers hold a recurrent state beside their attention
+    cache (a state-space mixer, ``models/decoder_spec.py:StateSpec``):
+    each mechanism that needs a SNAPSHOT of a sequence's state at some
+    earlier position, or a state layout that is not built, is refused
+    here, by name. Prefix reuse is the same need and takes no option: the
+    pool offers no block to the prefix cache and matches none
+    (``serving/paging.py``), and a preempted request is re-fed from
+    position 0."""
+    import jax.numpy as jnp
+    if spec_draft is not None:
+        raise ValueError(
+            "spec_draft does not compose with a recurrent state yet: a "
+            "rejected draft rolls the pool's position back, and a "
+            "recurrence cannot take a row back (it would need the state "
+            "before every candidate row)")
+    if host_tier_bytes is not None:
+        raise ValueError(
+            "host_tier_bytes does not compose with a recurrent state yet: "
+            "the tier demotes and promotes the blocks of the prefix "
+            "cache, and nothing is offered to it here (a prefix's blocks "
+            "are no use without the state at its end)")
+    if kv_dtype is not None and jnp.dtype(kv_dtype).name in (
+            "int8", "float8_e4m3fn"):
+        raise ValueError(
+            "int8/fp8 KV blocks do not compose with a recurrent state "
+            "yet: the quantized step threads its scale array where this "
+            "one threads the state arrays, and the two are not built "
+            "together")
+    if mesh is not None:
+        raise ValueError(
+            "mesh= (tensor-parallel serving) does not compose with a "
+            "recurrent state yet: the sharded step has no mixer, and the "
+            "state arrays have no head-partitioned layout")
+
+
 def _group_block_counts(groups, num_slots, max_len, block_size, num_blocks,
                         prefill_budget):
     """Blocks a cache group's array holds. One group: ``num_blocks`` as
@@ -359,13 +396,13 @@ def _group_block_counts(groups, num_slots, max_len, block_size, num_blocks,
 class GenerationEngine:
     """Continuous-batching serving over a decoder the fused stack has a
     spec of (``models/decoder_spec.py``: GPT-2, A.X-K1, SDAR,
-    MiMo-V2-Flash) — one token
+    MiMo-V2-Flash, Falcon-H1) — one token
     a sequence a step, or a block of them by diffusion, as the spec's
     generation rule says.
 
     ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` /
-    ``AXK1ForCausalLM`` / ``SDARForCausalLM`` / ``MiMoV2ForCausalLM``
-    (anything
+    ``AXK1ForCausalLM`` / ``SDARForCausalLM`` / ``MiMoV2ForCausalLM`` /
+    ``FalconH1ForCausalLM`` (anything
     ``serving_decoder`` has a spec of);
     its parameters are snapshotted at construction (sharded parameters
     serve sharded — jit follows the placement).
@@ -391,7 +428,10 @@ class GenerationEngine:
       ``num_blocks`` is then what a cache held uniformly in every layer
       would hold, and its bytes are shared out by the spec's rule
       (``_group_block_counts``: the window group what its slots can hold
-      at all, the rest to the layers that keep the whole context);
+      at all, the rest to the layers that keep the whole context). A
+      model whose layers hold a recurrent STATE beside their cache (a
+      state-space mixer) gets one row of it a slot, sized from the spec
+      and ``num_slots`` and held by the same pool; nothing selects it;
     * ``kv_dtype`` — ``"int8"``/``"float8_e4m3fn"`` stores the blocks
       quantized with per-block max-abs scales;
     * ``spec_draft``/``spec_k`` — speculative decoding: a small draft
@@ -478,6 +518,9 @@ class GenerationEngine:
         if len(groups) > 1:
             _refuse_with_groups(kv_dtype, mesh, spec_draft,
                                 host_tier_bytes)
+        if spec.state is not None:
+            _refuse_with_state(kv_dtype, mesh, spec_draft,
+                               host_tier_bytes)
         max_len = int(max_len or spec.max_positions)
         # every jit is deferred, so without this check an oversized
         # max_len would only surface as SILENTLY WRONG tokens (XLA clamps
@@ -541,7 +584,10 @@ class GenerationEngine:
             window=groups[0].window, more_groups=[
                 dict(num_layers=len(g.layers), num_heads=g.cache.rows,
                      lanes=g.cache.lanes, window=g.window, num_blocks=n)
-                for g, n in zip(groups[1:], counts[1:])])
+                for g, n in zip(groups[1:], counts[1:])],
+            # a row a slot of every part of the spec's recurrent state
+            state=(len(spec.state_layers), spec.state.parts)
+            if spec.state is not None else None)
         # the first window group's W (0: none): what the launch counters
         # of the windowed walk are counted from
         self._window = next((g.window for g in groups if g.window), 0)
@@ -847,6 +893,18 @@ class GenerationEngine:
                  "block_bytes": pool.group_block_bytes(i)}
                 for i, g in enumerate(self._decoder_spec.cache_groups)]
             s["window_blocks_freed"] = pool.window_blocks_freed
+        if pool.state_parts:
+            # the recurrent state a slot, beside the blocks: a slot IS
+            # its row of every part
+            s["kv_bytes"]["state"] = pool.state_bytes
+            s["state"] = {
+                "layers": len(self._decoder_spec.state_layers),
+                "parts": {name: list(shape[2:])
+                          for name, shape, _ in pool.state_parts},
+                "dtype": pool.state_parts[0][2].name,
+                "slot_bytes": pool.state_slot_bytes,
+                "slots_in_use": pool.n_active,
+                "live_bytes": pool.state_live_bytes}
         if self._host_tier is not None:
             # hierarchical tier snapshot: host capacity/occupancy,
             # demotion/promotion volumes, and the end-to-end
@@ -994,9 +1052,22 @@ class GenerationEngine:
 
     def _pool_operand(self):
         """The step's pool operand: the block array, or every cache
-        group's as a tuple where the spec has more than one."""
+        group's as a tuple where the spec has more than one; paired with
+        the slots' state arrays where the spec's layers hold a recurrent
+        state."""
         pool = self._pool
-        return pool.data if len(pool.groups) == 1 else pool.group_data
+        blocks = pool.data if len(pool.groups) == 1 else pool.group_data
+        return (blocks, pool.state_data) if pool.state_parts else blocks
+
+    def _rebind_pool(self, operand) -> None:
+        """What a donated step gave back for :meth:`_pool_operand`."""
+        pool = self._pool
+        if pool.state_parts:
+            operand, pool.state_data = operand
+        if len(pool.groups) > 1:
+            pool.group_data = operand
+        else:
+            pool.data = operand
 
     def _null_step_operands(self, Q: int, T: int) -> tuple:
         """The fused step's operands after the pool, zeroed: a legal
@@ -1104,8 +1175,11 @@ class GenerationEngine:
             return int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
 
         operand_pool = sum(_nbytes(a) for a in pool.group_data) \
-            + sum(_nbytes(s) for s in scales)
-        per_device_pool = pool.capacity_bytes
+            + sum(_nbytes(s) for s in scales) \
+            + sum(_nbytes(a) for a in pool.state_data)
+        # the slots' recurrent state is taken first: what is left is the
+        # block arrays'
+        per_device_pool = pool.capacity_bytes + pool.state_bytes
         total = rep.static_peak_bytes - operand_pool + per_device_pool
 
         pk = rep.peak
@@ -1114,6 +1188,7 @@ class GenerationEngine:
             "flavor": flavor, "q_bucket": Q, "table_bucket": T,
             "step_peak_bytes": int(rep.static_peak_bytes),
             "pool_bytes": int(per_device_pool),
+            "state_bytes": int(pool.state_bytes),
             "group_blocks": [g.num_blocks for g in pool.groups],
             "static_peak_bytes": int(total),
             "budget_bytes": budget,
@@ -1378,7 +1453,14 @@ class GenerationEngine:
             # layer by ops.kv_append
             kv_write_blocks=sum(
                 (pos0s[s] + n - 1) // bs - pos0s[s] // bs + 1
-                for s, n in enumerate(q_lens) if n), **more)
+                for s, n in enumerate(q_lens) if n), **more,
+            # the slots whose recurrent state the launch reads and
+            # writes, the real rows through the mixer and those of them
+            # in a sequence of more than one (the chunked scan's)
+            **(dict(state_slots=sum(1 for n in q_lens if n),
+                    ssm_rows=sum(q_lens),
+                    ssm_chunk_rows=sum(n for n in q_lens if n > 1))
+               if pool.state_parts else {}))
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps, token_src, block)
@@ -1412,13 +1494,12 @@ class GenerationEngine:
             pool.data, pool.scales, nxt, self._key = step(
                 self._params, self._buffers, pool.data, pool.scales,
                 *args)
-        elif len(pool.groups) > 1:
-            # every cache group's array is donated and comes back
-            pool.group_data, nxt, self._key = step(
-                self._params, self._buffers, pool.group_data, *args)
         else:
-            pool.data, nxt, self._key = step(
-                self._params, self._buffers, pool.data, *args)
+            # every cache group's array (and the state arrays) is donated
+            # and comes back
+            operand, nxt, self._key = step(
+                self._params, self._buffers, self._pool_operand(), *args)
+            self._rebind_pool(operand)
         self._note_decode_dispatch(step)
         return nxt
 

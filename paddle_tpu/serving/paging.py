@@ -34,6 +34,21 @@ prefix cache (a block of the window-0 group alone does not let a request
 skip its prompt: the window layers' last W - 1 tokens are gone), and
 int8/fp8 blocks, the host tier and a mesh are refused.
 
+**Recurrent state** (``state=``: the spec's layers that run a state-space
+mixer beside their attention, ``models/decoder_spec.py:StateSpec``). What
+a sequence holds there has a fixed size, so it is a ROW A SLOT, not
+blocks a token: ``state_data`` is one device array a part of the
+descriptor, ``[those layers, num_slots + 1, *shape]`` (the last row is
+owned by no slot, as block 0 is by no request: ``ops/ssm.py`` parks a
+chunked scan's running state there). A slot's row is claimed with the
+slot (``alloc``) and given back with it (``free``); it is never cleared
+on the host — the step program starts a sequence at position 0 from zero
+itself, because the slot's previous owner may still have a launch in
+flight. The arrays ride the donated step beside the block arrays, and
+their bytes stand in the HBM ledger under ``<pool>/state``. A state has
+no snapshot a block, so nothing is offered to or matched in the prefix
+cache, and a preempted request is re-fed from position 0.
+
 Host-side manager (this module, scheduler-thread-owned):
 
 * **request slots** — the launch's batch axis: deterministic
@@ -99,8 +114,8 @@ _pool_ids = itertools.count(1)
 def _drop_pool_ledger(ledger_key: str) -> None:
     """weakref.finalize target for a pool's ledger entries — a module
     function so the finalizer holds no reference to the pool."""
-    _memory.ledger_drop(f"{ledger_key}/capacity")
-    _memory.ledger_drop(f"{ledger_key}/in_use")
+    for part in ("capacity", "in_use", "state"):
+        _memory.ledger_drop(f"{ledger_key}/{part}")
 
 
 class PoolCapacityError(ValueError):
@@ -193,7 +208,9 @@ class PagedKVPool:
     fills first. The positional shape arguments, ``num_blocks``,
     ``lanes`` and ``window`` describe the first group; ``more_groups``
     the others, each a dict of ``num_layers``, ``num_heads``, ``lanes``,
-    ``window`` and ``num_blocks`` (module doc).
+    ``window`` and ``num_blocks`` (module doc). ``state`` is ``(layers
+    with a recurrent state, ((name, shape, dtype), ...))``: one array a
+    part, a row a slot (module doc).
     """
 
     #: storage dtypes quantized with per-block max-abs scales (the
@@ -206,7 +223,7 @@ class PagedKVPool:
                  num_blocks: Optional[int] = None, dtype="float32",
                  mesh=None, mp_axis: str = "mp",
                  lanes: Optional[int] = None, window: int = 0,
-                 more_groups=()):
+                 more_groups=(), state=None):
         import jax.numpy as jnp
 
         if num_slots < 1:
@@ -253,6 +270,11 @@ class PagedKVPool:
             raise ValueError(
                 "more than one cache group over a mesh or over int8/fp8 "
                 "blocks is not built")
+        if state is not None and (
+                mesh is not None or self.dtype.name in self._QUANT_QMAX):
+            raise ValueError(
+                "a recurrent state over a mesh or beside int8/fp8 blocks "
+                "is not built")
         # blocks a window group gave back behind its window, lifetime
         self.window_blocks_freed = 0
         # tensor-parallel pool: the block array is head-partitioned over
@@ -285,6 +307,16 @@ class PagedKVPool:
                        if self.quantized else None)
         for grp in self.groups:
             grp.data = self._alloc_data(grp)
+        # the recurrent state a slot (module doc): (name, array shape,
+        # dtype) a part, and the arrays
+        self.state_parts = ()
+        if state is not None:
+            n_layers, parts = state
+            self.state_parts = tuple(
+                (str(name), (int(n_layers), self.num_slots + 1)
+                 + tuple(int(d) for d in shape), jnp.dtype(dtype))
+                for name, shape, dtype in parts)
+        self.state_data = self._alloc_state()
         # prefix cache (the first group's blocks; nothing is offered or
         # matched with more than one group): exact-prefix-keyed trie + LRU
         # of released blocks
@@ -385,6 +417,29 @@ class PagedKVPool:
             self.mesh, P(None, None, self.mp_axis, None, None))
         return jax.device_put(jnp.zeros(shape, self.dtype), sh)
 
+    def _alloc_state(self) -> tuple:
+        """Fresh zeroed state arrays, one a part (empty: no state)."""
+        import jax.numpy as jnp
+        return tuple(jnp.zeros(shape, dtype)
+                     for _, shape, dtype in self.state_parts)
+
+    @property
+    def state_bytes(self) -> int:
+        """Device bytes of the state arrays, every slot's row and the one
+        no slot owns."""
+        return sum(int(np.prod(shape)) * dtype.itemsize
+                   for _, shape, dtype in self.state_parts)
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes ONE slot's state takes, every part and layer."""
+        return self.state_bytes // (self.num_slots + 1)
+
+    @property
+    def state_live_bytes(self) -> int:
+        """State held by the slots that requests own."""
+        return self.n_active * self.state_slot_bytes
+
     # -- HBM ledger (profiler/memory.py) -----------------------------------
     def _update_ledger(self) -> None:
         """Publish capacity + in-use bytes into the process HBM ledger
@@ -394,6 +449,8 @@ class PagedKVPool:
         _memory.ledger_set(f"{self.ledger_key}/capacity",
                            self.capacity_bytes)
         _memory.ledger_set(f"{self.ledger_key}/in_use", self.bytes_in_use)
+        if self.state_parts:
+            _memory.ledger_set(f"{self.ledger_key}/state", self.state_bytes)
 
     def drop_ledger(self) -> None:
         """Remove this pool's ledger entries (engine close): the pool
@@ -409,6 +466,7 @@ class PagedKVPool:
         slot = min(self._free_slots)
         self._free_slots.remove(slot)
         self._slots[slot] = _PagedSlot(len(self.groups))
+        self._observe_state()
         self._update_ledger()
         _memory.mark("kv/alloc", pool=self.ledger_key, slot=slot,
                      in_use=self.bytes_in_use)
@@ -429,9 +487,15 @@ class PagedKVPool:
                     self._unref(b, grp)
         self._observe()
         self._free_slots.append(slot)
+        self._observe_state()
         self._update_ledger()
         _memory.mark("kv/free", pool=self.ledger_key, slot=slot,
                      in_use=self.bytes_in_use)
+
+    def _observe_state(self) -> None:
+        """A slot IS its state row: claimed and given back with it."""
+        if self.state_parts:
+            stat_observe("serving/state_slots_in_use", self.n_active)
 
     def is_allocated(self, slot: int) -> bool:
         return slot in self._slots
@@ -532,6 +596,7 @@ class PagedKVPool:
             grp.free = list(range(1, grp.num_blocks + 1))
         if self.quantized:
             self.scales = jnp.zeros(self.scales_shape, jnp.float32)
+        self.state_data = self._alloc_state()
         self._trie.clear()
         self._block_key.clear()
         self._lru.clear()
@@ -927,8 +992,9 @@ class PagedKVPool:
         keeps every write strictly past the shared region, making COW a
         guard rail instead of a hot path). Returns the physical block
         ids, longest match first-to-last. Read-only. With more than one
-        cache group nothing is matched (module doc)."""
-        if len(self.groups) > 1:
+        cache group, or a recurrent state, nothing is matched (module
+        doc)."""
+        if len(self.groups) > 1 or self.state_parts:
             return []
         toks = tuple(int(t) for t in tokens)
         bs = self.block_size
@@ -994,9 +1060,9 @@ class PagedKVPool:
         """Publish the slot's full token blocks into the prefix cache.
         Called after a prefill WROTE them; an existing entry for the
         same prefix stays canonical (this slot's duplicate block simply
-        remains privately owned). With more than one cache group nothing
-        is offered (module doc)."""
-        if len(self.groups) > 1:
+        remains privately owned). With more than one cache group, or a
+        recurrent state, nothing is offered (module doc)."""
+        if len(self.groups) > 1 or self.state_parts:
             return
         st = self._require(slot)
         toks = tuple(int(t) for t in tokens)

@@ -719,7 +719,9 @@ class Scheduler:
                     q_blocks_wide: int = 0, kv_row_tokens: int = 0,
                     kv_write_blocks: int = 0,
                     kv_tokens_window: Optional[int] = None,
-                    kv_row_tokens_window: Optional[int] = None) -> None:
+                    kv_row_tokens_window: Optional[int] = None,
+                    state_slots: Optional[int] = None, ssm_rows: int = 0,
+                    ssm_chunk_rows: int = 0) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
@@ -749,7 +751,12 @@ class Scheduler:
         ``kv_tokens_window``, the tokens a window layer must read (sum
         over the planned slots of ``min(kv_len, W - 1 + rows)``), and
         ``kv_row_tokens_window``, the (row, visible token) pairs under
-        the window mask."""
+        the window mask. For a model whose layers hold a recurrent state
+        three more: ``state_slots``, the sequences whose state the launch
+        reads and writes (once a layer with state); ``ssm_rows``, the
+        real rows through the mixer, and ``ssm_chunk_rows``, those of
+        them in a sequence of more than one row — the chunked scan's
+        (``ops/ssm.py``)."""
         if self._rec is not None:
             self._rec.update(launch_rows=int(rows), launch_q=int(q),
                              launch_t=int(t), launch_program=str(program),
@@ -764,6 +771,10 @@ class Scheduler:
                 self._rec.update(
                     kv_tokens_window=int(kv_tokens_window),
                     kv_row_tokens_window=int(kv_row_tokens_window))
+            if state_slots is not None:
+                self._rec.update(state_slots=int(state_slots),
+                                 ssm_rows=int(ssm_rows),
+                                 ssm_chunk_rows=int(ssm_chunk_rows))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into
@@ -1342,12 +1353,17 @@ class Scheduler:
             if len(self._pool.groups) > 1:
                 # what the cache groups hold once this launch's rows are
                 # in: the blocks a window group gave back behind its
-                # window, the bytes of the blocks live tables still name
-                # (every group) and the tokens of the live contexts
+                # window
                 rec["window_blocks_freed"] = \
                     self._pool.window_blocks_freed - freed0
+            if len(self._pool.groups) > 1 or self._pool.state_parts:
+                # the bytes of the blocks live tables still name (every
+                # group), the tokens of the live contexts, and the
+                # recurrent state the live slots hold
                 rec["kv_live_bytes"] = self._pool.live_bytes
                 rec["kv_live_tokens"] = self._pool.live_tokens
+            if self._pool.state_parts:
+                rec["state_live_bytes"] = self._pool.state_live_bytes
             rec["decode_dispatch_ms"] += (time.perf_counter() - t1) * 1e3
         return {"cycle": self._cycle, "rec": rec, "active": active,
                 "plan": plan, "spec": spec, "fed": fed, "toks": toks_dev,

@@ -23,6 +23,7 @@ ALWAYS = {DS.EMBED, DS.NORM, DS.QKV, DS.CACHE_WRITE, DS.ATTENTION,
           DS.O_PROJ, DS.MLP}
 ONE_TOKEN = {DS.HEAD, DS.SAMPLE}
 ROUTED = {DS.MOE_SCOPE, DS.ROUTER}
+MIXER = {DS.SSM_PROJ, DS.SSM_CONV, DS.SSM_SCAN}
 
 
 def _gpt():
@@ -45,6 +46,12 @@ def _mimo():
     return MiMoV2ForCausalLM(MiMoV2Config.tiny())
 
 
+def _falcon_h1():
+    from paddle_tpu.models.falcon_h1 import (FalconH1Config,
+                                             FalconH1ForCausalLM)
+    return FalconH1ForCausalLM(FalconH1Config.tiny())
+
+
 # family: (model, the vocabulary it uses, its step's name)
 FAMILIES = {
     "gpt": (_gpt, ALWAYS | ONE_TOKEN, "fused_step"),
@@ -52,6 +59,7 @@ FAMILIES = {
              "fused_step"),
     "sdar": (_sdar, ALWAYS | ROUTED | {DS.UNMASK_SCOPE}, "block_step"),
     "mimo": (_mimo, ALWAYS | ONE_TOKEN | ROUTED, "fused_step"),
+    "falcon_h1": (_falcon_h1, ALWAYS | ONE_TOKEN | MIXER, "fused_step"),
 }
 
 
@@ -62,7 +70,7 @@ def test_section_of_takes_the_innermost_word():
         == DS.MOE_SCOPE
     assert DS.section_of("jit(f)/layer3/add") is None
     assert DS.MOE_SCOPE == "moe_experts" and DS.UNMASK_SCOPE == "unmask"
-    assert len(set(DS.SECTIONS)) == len(DS.SECTIONS) == 13
+    assert len(set(DS.SECTIONS)) == len(DS.SECTIONS) == 16
     with pytest.raises(ValueError, match="no section"):
         DS.section("attn")
 

@@ -220,7 +220,7 @@ class TestQuantizedBlocks:
             wb[:n] = 1
             with functional_state(model, params, buffers), no_grad_guard():
                 x = dec.embed_tokens(ids, qpos)
-                x, pool, scales, _ = _fused_tower(
+                x, pool, scales, _, _ = _fused_tower(
                     dec, x, qpos, pool, scales, wb, qpos.copy(), blk_seq,
                     qstart, pos0, table, np.zeros(1, np.int32),
                     np.asarray([p0 + n], np.int32), quant, 127.0)

@@ -200,7 +200,10 @@ def test_ragged_paged_attention_compiles_for_window_and_global_layers(
     (32, 4, 128, 128, 256, {"mask_block": 4}),            # SDAR-30B-A3B
     (64, 4, 192, 128, 384, {}),                           # MiMo, global
     (64, 8, 192, 128, 384, {"window": 128, "sinks": True}),   # MiMo, window
-], ids=["gpt2-large", "sdar", "mimo-global", "mimo-window"])
+    # Falcon-H1: g = 5, the first group that is no power of two — a folded
+    # q block is 40 rows, two and a half packed bf16 tiles (staged in f32)
+    (20, 4, 128, 128, 256, {}),
+], ids=["gpt2-large", "sdar", "mimo-global", "mimo-window", "falcon-h1"])
 def test_wide_q_step_compiles_at_the_cells_widths(v5e, hq, hkv, dk, dv,
                                                   lanes, kw):
     """A grid step of M = 4 q blocks at the widths of the cells that run
