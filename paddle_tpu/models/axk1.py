@@ -63,6 +63,16 @@ __all__ = ["AXK1Config", "AXK1ForCausalLM", "yarn_inv_freq",
 MOE_PAIR_CHUNK = 128
 
 
+def trip_pairs(pairs: int) -> int:
+    """A trip of ``pairs`` pairs or more in whole ``MOE_PAIR_CHUNK``-row
+    tiles, one at least. A step's rows are no power of two since its
+    tower runs on the launch's real rows (1,152 of them are 144 pairs by
+    the rule above), and a trip that ends inside a tile made the grouped
+    products walk every held expert's weights in narrow tiles: twice the
+    time a pair (PERF.md 41.2)."""
+    return max(1, -(-int(pairs) // MOE_PAIR_CHUNK)) * MOE_PAIR_CHUNK
+
+
 def _default_rope_scaling() -> dict:
     return {"type": "yarn", "factor": 32, "beta_fast": 32, "beta_slow": 1,
             "mscale": 1, "mscale_all_dim": 1,
@@ -513,7 +523,7 @@ class AXK1RoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                max(MOE_PAIR_CHUNK, x.shape[0] // 8))
+                trip_pairs(x.shape[0] // 8))
             with DS.section(DS.SHARED_EXPERT):
                 shared = _swiglu(x, self.shared_gate._data,
                                  self.shared_up._data,

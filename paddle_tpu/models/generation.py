@@ -21,7 +21,7 @@ a Python sampling loop; here the loop itself is compiled.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -689,9 +689,80 @@ def _write_latent_rows(pool, li, wb, off, rows):
     return pool.at[li, wb, 0, off, :].set(rows.astype(pool.dtype))
 
 
+class _RowAxes(NamedTuple):
+    """The two row axes of a step whose tower runs on fewer rows than
+    its attention kernel (:func:`_row_axes`). ``R`` tower rows, ``Q``
+    kernel rows, ``S`` slots."""
+    start: object        # [S] int32: the sequence's first TOWER row
+    row_seq: object      # [R] int32: the tower row's slot; S for none
+    to_kernel: object    # [Q] int32: the kernel row's tower row; R: a pad
+    from_kernel: object  # [R] int32: the tower row's kernel row
+
+
+def _row_axes(rows: int, blk_seq, seq_qstart, seq_pos0, kv_len):
+    """The launch's TOWER axis against its KERNEL axis, once a launch,
+    on the device, from the kernel's own scalar metadata — or ``None``
+    where the two are one (``rows`` is the kernel's ``Q``: nothing is
+    traced, and the step is the program it was before there were two).
+
+    The kernel wants each sequence's rows padded to whole q blocks
+    (``ops/ragged_paged_attention.py``, Layout contract); nothing else in
+    a step does. So a step's per-row operands and everything its tower
+    computes run on ``rows`` = ``R <= Q`` rows that hold the same
+    sequences in the same slot order back to back (sequence ``s`` has
+    ``kv_len[s] - seq_pos0[s]`` real rows this launch, so it starts at
+    the sum of those before it: no operand says so), pad rows only at the
+    end. ``to_kernel`` lays a layer's query rows out for the kernel (a
+    row of no sequence reads past the end and is filled with zeros),
+    ``from_kernel`` reads the kernel's output back at the real rows (a
+    tower pad row reads kernel row 0: finite, and nobody's)."""
+    import jax.numpy as jnp
+
+    from ..ops.ragged_paged_attention import BLOCK_Q
+    R, S = int(rows), seq_qstart.shape[0]
+    Q = blk_seq.shape[0] * BLOCK_Q
+    if R == Q:
+        return None
+    if R > Q:
+        raise ValueError(
+            f"{R} tower rows for a kernel of {Q}: the tower runs on at "
+            f"most the kernel's rows")
+    i32 = jnp.int32
+    qstart = seq_qstart.astype(i32)
+    seq_len = (kv_len - seq_pos0).astype(i32)
+    ends = jnp.cumsum(seq_len).astype(i32)
+    start = ends - seq_len
+    r = jnp.arange(R, dtype=i32)
+    # the first sequence that ends after the row: absent ones end where
+    # they start and are passed over
+    row_seq = jnp.sum(ends[None, :] <= r[:, None], axis=1).astype(i32)
+    rs = jnp.minimum(row_seq, S - 1)
+    from_kernel = jnp.where(row_seq < S, qstart[rs] + r - start[rs], 0)
+    kseq = jnp.repeat(blk_seq.astype(i32), BLOCK_Q)
+    ks = jnp.maximum(kseq, 0)
+    off = jnp.arange(Q, dtype=i32) - qstart[ks]
+    to_kernel = jnp.where((kseq >= 0) & (off < seq_len[ks]),
+                          start[ks] + off, R)
+    return _RowAxes(start, row_seq, to_kernel, from_kernel)
+
+
+def _at_rows(v, axes, axis: int, to_kernel: bool = False):
+    """``v`` moved between a step's two row axes along ``axis``: the
+    tower's rows laid out for the kernel (``to_kernel``: pad rows zero)
+    or the kernel's output read back at the tower's. ``axes`` ``None``:
+    one axis, ``v`` as it is."""
+    import jax.numpy as jnp
+    if axes is None:
+        return v
+    if to_kernel:
+        return jnp.take(v, axes.to_kernel, axis=axis, mode="fill",
+                        fill_value=0)
+    return jnp.take(v, axes.from_kernel, axis=axis, mode="clip")
+
+
 def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                  blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-                 quantized, qmax, state=None):
+                 quantized, qmax, state=None, axes=None):
     """The fused ragged transformer tower shared by
     :func:`build_fused_step_fn` and :func:`build_spec_verify_fn`, over
     the layers of a decoder spec (``models/decoder_spec.py``; ``dec`` is a
@@ -715,7 +786,21 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     its last real row there (``ops/ssm.py``: a sequence at position 0
     starts from zero, pad rows touch nothing). Returns ``(final_norm(x),
     pool, scales, counters, state)``, ``counters`` ``None`` for a model
-    without routed layers, ``state`` ``None`` for one without state."""
+    without routed layers, ``state`` ``None`` for one without state.
+
+    TWO ROW AXES (PR 41). ``x``, ``positions``, ``write_block`` and
+    ``write_off`` — and so every op of every layer: norms, projections,
+    the cache write, the mixer, the FFN with its ``row_valid`` — are on
+    the step's ``R`` TOWER rows; ``blk_seq`` and ``seq_qstart`` describe
+    the kernel's ``Q`` rows, and ``seq_pos0``, ``tables``, ``lo``,
+    ``kv_len`` are a value a slot. ``axes`` (:func:`_row_axes`, the
+    caller's: it reads the sequences' starts from it too) joins the two:
+    a layer's query is laid into the kernel's rows just before the
+    attention call and the call's output read back at the tower's just
+    after it, two gathers a layer (and a third, of the layer's K|V rows,
+    where the cache write is ``ops.kv_append``, whose rewrites follow the
+    kernel's q blocks). ``axes`` ``None``: ``R == Q``, one axis, no
+    gather."""
     import jax.numpy as jnp
 
     from ..ops.kv_append import kv_append
@@ -732,8 +817,21 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
         if state is not None:
             from ..ops.ragged_paged_attention import BLOCK_Q
             from ..ops.ssm import seq_layout
+            # the mixer reads the TOWER's rows: runs of one row at the
+            # sequences' compact starts where the tower has its own axis
             layout = seq_layout(blk_seq, seq_qstart, seq_pos0, kv_len,
-                                row_valid, BLOCK_Q)
+                                row_valid, BLOCK_Q) if axes is None \
+                else seq_layout(axes.row_seq, axes.start, seq_pos0, kv_len,
+                                row_valid, 1)
+    # ops.kv_append finds a block's rewrites by the kernel's layout (a q
+    # block's rows are one sequence's: its module doc), so its write
+    # targets and a layer's K|V rows are laid out as the query is; the
+    # two XLA scatters take a target a row and run on the tower's rows
+    kwbs, koff = wbs, write_off
+    if axes is not None and not quantized:
+        with DS.section(DS.CACHE_WRITE):
+            kwbs = tuple(_at_rows(w, axes, 0, to_kernel=True) for w in wbs)
+            koff = _at_rows(write_off, axes, 0, to_kernel=True)
     counters = None
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
         # the layer's cache group, and its place in the group's array
@@ -758,25 +856,29 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                             qmax)
                     else:
                         pools[g] = kv_append(
-                            pools[g], gi, wbs[g], write_off,
-                            _kv_lanes(*rows, ls.cache.lanes))
+                            pools[g], gi, kwbs[g], koff, _at_rows(
+                                _kv_lanes(*rows, ls.cache.lanes), axes, 0,
+                                to_kernel=True))
                 with DS.section(DS.ATTENTION):
-                    a = ragged_paged_attention(
-                        q, pools[g], gi, blk_seq, seq_qstart, seq_pos0,
+                    a = _at_rows(ragged_paged_attention(
+                        _at_rows(q, axes, 1, to_kernel=True), pools[g], gi,
+                        blk_seq, seq_qstart, seq_pos0,
                         tabs[g], los[g], kv_len, scales=scales,
                         mask_block=dec.spec.generation.block_length,
                         window=ls.window,
                         sinks=layer.sinks if ls.sinks else None,
-                        v_lanes=ls.cache.v_lanes if ls.cache.k_lanes else 0)
+                        v_lanes=ls.cache.v_lanes if ls.cache.k_lanes else 0),
+                        axes, 1)
             else:
                 with DS.section(DS.CACHE_WRITE):
                     pools[g] = _write_latent_rows(pools[g], gi, wbs[g],
                                                   write_off, rows)
                 with DS.section(DS.ATTENTION):
-                    a = mla_paged_attention(
-                        q, pools[g], gi, blk_seq, seq_qstart, seq_pos0,
+                    a = _at_rows(mla_paged_attention(
+                        _at_rows(q, axes, 0, to_kernel=True), pools[g], gi,
+                        blk_seq, seq_qstart, seq_pos0,
                         tabs[g], los[g], kv_len, v_lanes=ls.cache.v_lanes,
-                        scale=dec.attention_scale)
+                        scale=dec.attention_scale), axes, 0)
             if ls.state is not None:
                 # the mixer names its own sections too
                 mixed, state = layer.mixer(
@@ -903,8 +1005,11 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
 
     ``fn(params, buffers, pool, token_ids, qpos, write_block, write_off,
     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len, prev_result,
-    state_src [S], blk_tok [S, B], blk_pass [S, B], row_blk [Q],
-    pass_idx [S], ride [S], key) -> (pool, result, key)``: ``row_blk``
+    state_src [S], blk_tok [S, B], blk_pass [S, B], row_blk [R],
+    pass_idx [S], ride [S], key) -> (pool, result, key)`` (the per-row
+    operands on the step's ``R`` TOWER rows, as in
+    :func:`build_fused_step_fn`; a slot's block is found from its first
+    tower row): ``row_blk``
     names, for a row that shows the slot's state, ``slot * B + position
     in the block`` (-1: the row keeps its ``token_ids``);
     ``state_src[s] >= 0`` takes slot ``s``'s block state from
@@ -946,10 +1051,12 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
                         row_blk >= 0, shown[jnp.maximum(row_blk, 0)],
                         token_ids)
                     x = dec.embed_tokens(token_ids, qpos)
+                    axes = _row_axes(token_ids.shape[0], blk_seq,
+                                     seq_qstart, seq_pos0, kv_len)
                 x, new_pool, _, counters, _ = _fused_tower(
                     dec, x, qpos, pool, None, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-                    False, 0.0)
+                    False, 0.0, axes=axes)
                 with DS.section(DS.UNMASK_SCOPE):
                     # a riding slot's rows have shown its finished block;
                     # the block it denoises is the next, B rows down, all
@@ -957,8 +1064,9 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
                     riding = (ride > 0)[:, None]
                     blk_tok = jnp.where(riding, 0, blk_tok)
                     blk_pass = jnp.where(riding, BLOCK_UNFIXED, blk_pass)
+                    first = seq_qstart if axes is None else axes.start
                     blk_tok, blk_pass, fixed_pos, bad = _unmask(
-                        dec, x, seq_qstart + B * ride, blk_tok, blk_pass,
+                        dec, x, first + B * ride, blk_tok, blk_pass,
                         pass_idx, rule)
                     parts = [fixed_pos, bad[None]]
                     if counters is not None:
@@ -993,17 +1101,25 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     of :func:`_append_nonfinite_flag`; a routed model appends its three
     counters):
 
-    * ``token_ids``/``qpos``/``write_block``/``write_off`` ``[q_rows]``
-      int32 — the flattened padded ragged batch (see
-      ``ops.ragged_paged_attention.ragged_layout``): each row's token,
-      virtual cache position, and page-table-resolved physical write
-      block/offset (pad rows name the scratch block 0); every real
-      row's K/V are appended to the pool BEFORE the attention kernel
-      runs, so a chunk row attends causally to its own chunk prefix;
-    * ``blk_seq [q_rows / 8]``, ``seq_qstart``/``seq_pos0``/``lo``/
-      ``kv_len`` ``[num_slots]``, ``tables [num_slots, table_len]`` —
-      the kernel's scalar-prefetch metadata;
-    * ``last_row [num_slots]`` int32 — the flattened row of each slot's
+    * ``token_ids``/``qpos``/``write_block``/``write_off`` ``[R]``
+      int32 — the flattened ragged batch on the step's TOWER rows: each
+      row's token, virtual cache position, and page-table-resolved
+      physical write block/offset (pad rows name the scratch block 0);
+      every real row's K/V are appended to the pool BEFORE the attention
+      kernel runs, so a chunk row attends causally to its own chunk
+      prefix. ``R`` is READ FROM THESE OPERANDS' SHAPE, ``R <= q_rows``:
+      the slots' real rows back to back in slot order, pad rows at the
+      end (:func:`_row_axes`) — everything of the step but the
+      attention call runs on them. ``R == q_rows`` is the padded layout
+      of ``ops.ragged_paged_attention.ragged_layout`` itself, one axis
+      for tower and kernel, and then nothing is moved between the two;
+      ``serving/engine.py:_tower_rows`` says which ``R`` a ``q_rows``
+      bucket's programs have;
+    * ``blk_seq [q_rows / 8]`` and ``seq_qstart [num_slots]`` — the
+      KERNEL's ``q_rows`` rows, each slot's padded to whole q blocks —
+      and ``seq_pos0``/``lo``/``kv_len`` ``[num_slots]``, ``tables
+      [num_slots, table_len]``: the kernel's scalar-prefetch metadata;
+    * ``last_row [num_slots]`` int32 — the TOWER row of each slot's
       LAST real token this launch: its hidden state produces the slot's
       next-token logits, so a slot whose final feed chunk lands this
       cycle gets its first generated token from the SAME launch that
@@ -1011,7 +1127,7 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
       garbage the scheduler ignores);
     * ``prev_tokens`` — the ``next_tokens`` of the launch before this
       one, handed back UN-fetched (zeros when none is in flight) — and
-      ``token_src [q_rows]`` int32: a decode row whose input token is
+      ``token_src [R]`` int32: a decode row whose input token is
       still on the device names its slot there, every other row says -1
       and keeps its ``token_ids`` (:func:`_tokens_from_prev`);
     * ``sample_mask``/``temperature`` ``[num_slots]`` are traced (one
@@ -1083,10 +1199,12 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                     # sequences are aligned at virtual 0; lo is the mask
                     # floor, not a pad offset)
                     x = dec.embed_tokens(token_ids, qpos)
+                    axes = _row_axes(token_ids.shape[0], blk_seq,
+                                     seq_qstart, seq_pos0, kv_len)
                 x, new_pool, new_scales, counters, state = _fused_tower(
                     dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-                    quantized, qmax, state)
+                    quantized, qmax, state, axes)
                 if stateful:
                     new_pool = (new_pool, state)
                 with DS.section(DS.HEAD):
@@ -1376,7 +1494,9 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
       (0 = plain feed/decode rows);
     * ``draft_toks [S, spec_k]`` int32 / ``draft_probs [S, spec_k, V]``
       f32 — the DEVICE-side proposals of the draft loop (the host never
-      fetched them); rows ``seq_qstart + 1 + j`` of ``token_ids`` are
+      fetched them); rows ``first + 1 + j`` of ``token_ids`` (``first``
+      a slot's first TOWER row: the per-row operands are ``[R]`` as in
+      :func:`build_fused_step_fn`) are
       overlaid with ``draft_toks[:, j]`` in-trace, because those token
       values only exist on the device;
     * ``out [2S + S*spec_k + 1]`` int32 — ``[accepted (S) | corrected
@@ -1430,17 +1550,21 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
                 # verify position j+1 is draft_toks[:, j]; invalid
                 # (j >= n_spec - 1) overlays are dropped out of bounds
                 with DS.section(DS.EMBED):
-                    rows = seq_qstart[:, None] + 1 \
-                        + jnp.arange(K)[None, :]
+                    # the slots' first TOWER rows (R of them: Q, or fewer)
+                    R = token_ids.shape[0]
+                    axes = _row_axes(R, blk_seq, seq_qstart, seq_pos0,
+                                     kv_len)
+                    first = seq_qstart if axes is None else axes.start
+                    rows = first[:, None] + 1 + jnp.arange(K)[None, :]
                     ok = jnp.arange(K)[None, :] < (n_spec[:, None] - 1)
-                    safe = jnp.where(ok, rows, Q)     # Q = out of range
+                    safe = jnp.where(ok, rows, R)     # R = out of range
                     token_ids = token_ids.at[safe.reshape(-1)].set(
                         draft_toks.reshape(-1), mode="drop")
                     x = dec.embed_tokens(token_ids, qpos)
                 x, new_pool, new_scales, _, _ = _fused_tower(
                     dec, x, qpos, pool, scales, write_block, write_off,
                     blk_seq, seq_qstart, seq_pos0, tables, lo, kv_len,
-                    quantized, qmax)
+                    quantized, qmax, axes=axes)
                 with DS.section(DS.HEAD):
                     # gather the rows whose logits are actually read —
                     # the S*K verify rows plus each slot's last row —
@@ -1449,8 +1573,8 @@ def build_spec_verify_fn(model, num_slots, q_rows, spec_k, table_len,
                     # Q/(S*(K+1))x more for nothing (a chunk-heavy cycle
                     # reads none of its chunk rows' logits)
                     vrows = jnp.clip(
-                        seq_qstart[:, None] + jnp.arange(K)[None, :],
-                        0, Q - 1)                      # [S, K]
+                        first[:, None] + jnp.arange(K)[None, :],
+                        0, R - 1)                      # [S, K]
                     sel = x._data[0][jnp.concatenate(
                         [vrows.reshape(-1), last_row])]  # [S*K+S, E]
                     logits = dec.logits(

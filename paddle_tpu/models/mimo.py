@@ -48,8 +48,8 @@ from typing import Callable, Optional, Tuple
 from .. import nn
 from ..framework.tensor import Parameter, Tensor
 from . import decoder_spec as DS
-from .axk1 import (MOE_PAIR_CHUNK, _mm, _param_maker, _params,
-                   _rms_norm, _swiglu, route_top_k, routed_experts)
+from .axk1 import (_mm, _param_maker, _params, _rms_norm, _swiglu,
+                   route_top_k, routed_experts, trip_pairs)
 from .sdar import rope_half_split
 
 __all__ = ["MiMoV2Config", "MiMoV2ForCausalLM", "stored_lanes"]
@@ -299,7 +299,7 @@ class MiMoRoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                max(MOE_PAIR_CHUNK, x.shape[0] // 8))
+                trip_pairs(x.shape[0] // 8))
         with DS.section(DS.MLP):          # with the add that closes the layer
             return y.astype(x.dtype), counters
 

@@ -42,8 +42,8 @@ import numpy as np
 from .. import nn
 from ..framework.tensor import Parameter, Tensor
 from . import decoder_spec as DS
-from .axk1 import (MOE_PAIR_CHUNK, _mm, _param_maker, _params,
-                   _rms_norm, route_top_k, routed_experts)
+from .axk1 import (_mm, _param_maker, _params, _rms_norm,
+                   route_top_k, routed_experts, trip_pairs)
 
 __all__ = ["SDARConfig", "SDARForCausalLM", "rope_half_split"]
 
@@ -218,7 +218,7 @@ class SDARRoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                max(MOE_PAIR_CHUNK, x.shape[0] * k * 3 // 4))
+                trip_pairs(x.shape[0] * k * 3 // 4))
         with DS.section(DS.MLP):          # with the add that closes the layer
             return y.astype(x.dtype), counters
 

@@ -25,8 +25,10 @@ the block's rows, every head at once), DMA it back. A pad row
 (``write_block == 0``) starts no DMA and the scratch block is never
 written.
 
-Layout contract (the fused step's own, ``engine._ragged_operands`` and
-``ops.ragged_paged_attention.ragged_layout``): the rows of one q block of
+Layout contract (the attention kernel's ``Q`` rows,
+``ops.ragged_paged_attention.ragged_layout``; a step whose tower runs on
+fewer rows lays a layer's K|V rows and the write targets out so before
+the call, ``models/generation.py:_fused_tower``): the rows of one q block of
 ``BLOCK_Q`` are consecutive cache positions of ONE sequence, real rows
 first, and no two sequences write one block. So a q block's rows name at
 most two blocks (``block_size >= BLOCK_Q``), all rows of a launch that

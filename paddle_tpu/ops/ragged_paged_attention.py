@@ -52,7 +52,21 @@ Layout contract (the serving engine's fused step builds these):
   fp32 sublane) so one q block never mixes sequences — decode rows
   cost one padded q block, prefill chunks amortize theirs (a grid step
   of ``M`` q blocks may hold several sequences: it then walks q block
-  by q block);
+  by q block). These are the KERNEL's ``Q`` rows, and since PR 41 they
+  exist only around the kernel call: the step's tower — embedding,
+  projections, cache write, mixer, FFN, head — runs on ``R <= Q`` TOWER
+  rows, the same sequences in the same slot order WITHOUT the padding
+  to ``block_q`` (sequence ``s`` starts at the sum of the real rows of
+  the sequences before it, pad rows only at the end), and
+  ``models/generation.py`` lays a layer's q out into the ``Q`` rows
+  just before the call and reads the output back at the real rows just
+  after it. ``R`` is a pure function of the program's ``Q``
+  (:func:`tower_rows`); where it equals ``Q`` the two layouts are one
+  and nothing is moved. What is ``[R]`` and what ``[Q]``: the per-row
+  operands of a step (``token_ids``, ``qpos``, ``write_block``,
+  ``write_off``, ``token_src``, a block step's ``row_blk``) and
+  ``last_row``'s values are TOWER rows; everything this kernel takes
+  (``q``, ``blk_seq``, ``seq_qstart``) is in kernel rows;
 * scalar-prefetch metadata maps q blocks back to sequences:
   ``blk_seq`` names the sequence of each q block (−1 = pad block),
   ``seq_qstart``/``seq_pos0`` recover every row's virtual cache
@@ -152,7 +166,8 @@ from .pallas_kernels import _interpret, _x64_off
 __all__ = ["ragged_paged_attention", "ragged_layout", "BLOCK_Q",
            "MIN_KV_BLOCK", "KV_VMEM_BUDGET", "Q_VMEM_BUDGET",
            "Q_STEP_BLOCKS", "min_kv_block_for", "check_kv_tile",
-           "kv_group_blocks", "q_step_blocks", "ragged_walk_counts"]
+           "kv_group_blocks", "q_step_blocks", "ragged_walk_counts",
+           "TOWER_ROW_MULTIPLE", "tower_rows"]
 
 _NEG_INF = -1e30
 
@@ -771,6 +786,50 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
     return out
 
 
+# the tower's rows come in whole MXU passes: 128, which is also whole
+# (16, 128) tiles of a packed bf16 activation. A matmul on 64 rows and
+# one on 128 both stream their weights at the HBM's pace, so rounding 64
+# decode rows up to 128 costs nothing a launch can see
+TOWER_ROW_MULTIPLE = 128
+
+
+def tower_rows(q_rows: int, num_slots: int, chunk_budget: int,
+               decode_rows: int = 1, *, block_q: int = BLOCK_Q) -> int:
+    """``R(Q)``: the rows the step program of ``q_rows`` KERNEL rows runs
+    its tower on (module doc, Layout contract) — a pure function of the
+    program's ``Q`` and of what an engine knows when it is built, so a
+    ``(Q, T)`` program has ONE shape and ``R`` is no bucket of its own.
+    With ``decode_rows`` the real rows a decode slot holds at most (1, or
+    2 B under block generation, or the candidates a speculating slot
+    verifies):
+
+    * a bucket that holds no more than every slot's padded decode rows
+      (``Q <= num_slots`` q blocks of a slot) is the plain launch's: the
+      slots' real decode rows;
+    * a bucket that holds them AND a whole chunk budget beside them is
+      the steady chunk launch's: the decode rows and the budget;
+    * a bucket between the two serves the mixes of a part-filled engine
+      (a ramp's chunk beside few decode rows, the tail of a prompt) and
+      keeps one axis: ``Q``.
+
+    Rounded up to ``TOWER_ROW_MULTIPLE``, never past ``Q``; where the
+    result is ``Q`` the program is the padded one (a launch of blocks of
+    8 rows a slot fills its q blocks). A launch whose real rows pass
+    ``R`` of the smallest bucket its padded rows fit goes to the next
+    bucket up (``serving/engine.py:_launch_bucket``): a chunk with few
+    decode rows beside it, which pays pad q blocks the kernel skips."""
+    Q, S, d = int(q_rows), int(num_slots), int(decode_rows)
+    slots_rows = S * -(-d // block_q) * block_q     # padded, every slot
+    if Q <= slots_rows:
+        rows = S * d
+    elif Q >= slots_rows + int(chunk_budget):
+        rows = S * d + int(chunk_budget)
+    else:
+        return Q
+    m = TOWER_ROW_MULTIPLE
+    return min(Q, -(-rows // m) * m)
+
+
 def ragged_layout(q_lens: Sequence[int], pos0s: Sequence[int], *,
                   block_q: int = BLOCK_Q,
                   q_bucket: int = 0) -> Tuple[np.ndarray, np.ndarray,
@@ -789,6 +848,13 @@ def ragged_layout(q_lens: Sequence[int], pos0s: Sequence[int], *,
     its logits row is garbage the caller ignores), and the unpadded
     ``total_rows``. ``q_bucket`` (a multiple of ``block_q``) fixes the
     padded width; 0 sizes it to the content.
+
+    Every row index here is a KERNEL row (the ``Q`` axis). A step whose
+    tower runs on fewer rows (:func:`tower_rows`) has its sequences'
+    real rows back to back: sequence ``s`` starts at ``sum(q_lens[:s])``
+    and its last real token sits ``q_lens[s] - 1`` after that — the
+    engine lays the per-row operands out so, and the step derives the
+    same starts on the device from ``kv_len - seq_pos0``.
     """
     S = len(q_lens)
     if len(pos0s) != S:

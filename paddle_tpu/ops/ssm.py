@@ -7,8 +7,10 @@ its context: the last ``d_conv - 1`` inputs of the depthwise convolution
 (the TAIL) and the recurrent state ``H [heads, P, N]``. The serving pool
 holds one row of each a slot (``serving/paging.py``: ``[layers with
 state, slots + 1, ...]``, float32), and a launch's ragged batch — per
-sequence contiguous rows, padded to whole q blocks
-(``ops.ragged_paged_attention.ragged_layout``) — mixes sequences of ONE
+sequence contiguous rows, on the step's TOWER rows: back to back where
+the tower has an axis of its own, padded to whole q blocks where it
+shares the attention kernel's (``models/generation.py:_row_axes``,
+``ops.ragged_paged_attention.ragged_layout``) — mixes sequences of ONE
 row (decode) with a few of up to a thousand (prompt chunks). Each starts
 from ITS slot's state and leaves the state after its last real row
 there. Everything here is ``jax.numpy`` in float32.
@@ -54,7 +56,9 @@ __all__ = ["SeqLayout", "seq_layout", "conv_rows", "ssm_scan",
 
 
 class SeqLayout(NamedTuple):
-    """A launch's rows by sequence. ``S`` slots, ``Q`` padded rows."""
+    """A launch's rows by sequence. ``S`` slots, ``Q`` rows of the
+    step's tower (``Q`` throughout this module: whichever axis the
+    caller's rows are on)."""
     row_seq: object      # [Q] int32: the row's slot; S for a row of none
     row_off: object      # [Q] int32: the row's place in its sequence's rows
     seq_qstart: object   # [S] int32: the sequence's first row
@@ -65,7 +69,13 @@ class SeqLayout(NamedTuple):
 def seq_layout(blk_seq, seq_qstart, seq_pos0, kv_len, row_valid,
                block_q: int) -> SeqLayout:
     """The layout from the attention kernel's scalar metadata (a launch
-    of one token a row: ``kv_len - seq_pos0`` rows a sequence)."""
+    of one token a row: ``kv_len - seq_pos0`` rows a sequence):
+    ``blk_seq`` names the sequence of each run of ``block_q`` rows (-1 or
+    ``S``: none) and ``seq_qstart`` each sequence's first row. Rows
+    padded to the kernel's q blocks are described by the kernel's own
+    ``blk_seq`` and 8; rows laid back to back, without that padding, by a
+    sequence a ROW (``block_q`` 1) and the sequences' compact starts —
+    :func:`conv_rows` and :func:`ssm_scan` read either."""
     import jax.numpy as jnp
     S = seq_qstart.shape[0]
     row_seq = jnp.repeat(blk_seq.astype(jnp.int32), block_q)

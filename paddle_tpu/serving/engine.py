@@ -33,7 +33,9 @@ like training-loop churn.
 
 Observability (PR-1 wiring + the ISSUE-6 SLO spine): counters
 ``serving/requests``, ``serving/completed``, ``serving/tokens``,
-``serving/launch_overlapped``, ``serving/preempt``,
+``serving/launch_overlapped``, ``serving/launch_rows`` and
+``serving/tower_rows`` (a launch's real rows and the rows its tower runs
+on), ``serving/preempt``,
 ``serving/queue_full``, ``serving/cancelled``,
 ``serving/deadline_exceeded``, ``serving/prefix_hit``/``prefix_miss``/
 ``prefill_tokens_saved``/``prefix_evict``; histograms
@@ -591,6 +593,10 @@ class GenerationEngine:
         # the first window group's W (0: none): what the launch counters
         # of the windowed walk are counted from
         self._window = next((g.window for g in groups if g.window), 0)
+        # the prompt rows a launch holds at most (the scheduler's chunk
+        # budget): with the slots and the generation rule, what the rows
+        # of a program's tower are counted from (_tower_rows)
+        self._chunk_budget = int(prefill_budget or max_len)
         self._fused_jits = {}         # (q bucket, table bucket) -> step
         # the fused step's "previous result" operand while no launch is
         # in flight: the shape of its own result ([slots | sentinel |
@@ -1022,13 +1028,14 @@ class GenerationEngine:
             from ..ops.ragged_paged_attention import BLOCK_Q
             Q, T = max(self._spec_jits)
             K = self._spec_k
+            R = self._tower_rows(Q)       # the per-row operands' axis
             V = self._decoder_spec.vocab_size
             scales = (self._pool.scales,) if self._pool.quantized else ()
             return analysis.analyze(
                 self._spec_step_fn(Q, T), self._params, self._buffers,
-                self._pool.data, *scales, np.zeros(Q, np.int32),
-                np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                np.zeros(Q, np.int32), np.zeros(Q // BLOCK_Q, np.int32),
+                self._pool.data, *scales, np.zeros(R, np.int32),
+                np.zeros(R, np.int32), np.zeros(R, np.int32),
+                np.zeros(R, np.int32), np.zeros(Q // BLOCK_Q, np.int32),
                 np.zeros(S, np.int32), np.zeros(S, np.int32),
                 np.zeros((S, T), np.int32), np.zeros(S, np.int32),
                 np.zeros(S, np.int32), np.zeros(S, np.int32),
@@ -1074,12 +1081,13 @@ class GenerationEngine:
         no-op launch of the (Q, T) program (``analyze``, the plan gate)."""
         from ..ops.ragged_paged_attention import BLOCK_Q
         S = self._pool.num_slots
+        R = self._tower_rows(Q)       # the per-row operands' axis
         i32 = lambda *shape: np.zeros(shape, np.int32)
         G = len(self._pool.groups)
         # write targets, tables and floors are one a cache group
         each = i32 if G == 1 else \
             (lambda *shape: tuple(i32(*shape) for _ in range(G)))
-        head = (i32(Q), i32(Q), each(Q), i32(Q), i32(Q // BLOCK_Q), i32(S),
+        head = (i32(R), i32(R), each(R), i32(R), i32(Q // BLOCK_Q), i32(S),
                 i32(S), each(S, T), each(S), i32(S))
         B = self._decoder_spec.generation.block_length
         if B > 1:
@@ -1087,9 +1095,9 @@ class GenerationEngine:
             return head + (
                 self._no_prev, np.full(S, -1, np.int32), i32(S, B),
                 np.full((S, B), BLOCK_UNFIXED, np.int32),
-                np.full(Q, -1, np.int32), np.full(S, -1, np.int32), i32(S),
+                np.full(R, -1, np.int32), np.full(S, -1, np.int32), i32(S),
                 self._key)
-        return head + (i32(S), self._no_prev, np.full(Q, -1, np.int32),
+        return head + (i32(S), self._no_prev, np.full(R, -1, np.int32),
                        np.zeros(S, bool), np.ones(S, np.float32), self._key)
 
     def plan_replica(self, hbm_budget_bytes: Optional[int] = None,
@@ -1130,9 +1138,10 @@ class GenerationEngine:
                 self._model, S, Q, K, T, pool.block_size,
                 top_k=self._top_k, top_p=self._top_p,
                 quantized=pool.quantized, qmax=pool.qmax or 127.0)
+            R = self._tower_rows(Q)
             args = (params, buffers, pool.data, *scales,
-                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
-                    np.zeros(Q, np.int32), np.zeros(Q, np.int32),
+                    np.zeros(R, np.int32), np.zeros(R, np.int32),
+                    np.zeros(R, np.int32), np.zeros(R, np.int32),
                     np.zeros(Q // BLOCK_Q, np.int32),
                     np.zeros(S, np.int32), np.zeros(S, np.int32),
                     np.zeros((S, T), np.int32), np.zeros(S, np.int32),
@@ -1264,8 +1273,17 @@ class GenerationEngine:
                          from_prev=()):
         """Host-side flattened ragged-row operands shared by the fused
         step and the speculative verify launch: per-slot contiguous
-        padded rows, page-table-resolved write targets, and the
-        scalar-prefetch metadata. Speculating slots (``spec``)
+        rows, page-table-resolved write targets, and the
+        scalar-prefetch metadata. TWO row axes (``models/generation.py
+        _row_axes``): the kernel's metadata (``blk_seq``, ``seq_qstart``)
+        describes the ``Q`` rows of the program, each slot's padded to
+        whole q blocks; the per-row operands (``token_ids``, ``qpos``,
+        ``write_block``, ``write_off``, ``token_src``, ``row_blk``) and
+        ``last_row``'s values are on the program's ``R = _tower_rows(Q)``
+        TOWER rows, the same slots in the same order back to back (one
+        axis where ``R == Q``). ``Q`` is the smallest bucket that holds
+        the launch's padded rows AND whose ``R`` holds its real ones.
+        Speculating slots (``spec``)
         contribute their candidate rows with only ``last_token``
         host-known — the draft tokens overlay on the device inside the
         verify program. The decode rows of the slots in ``from_prev``
@@ -1335,30 +1353,39 @@ class GenerationEngine:
                                     if req.pending_feed
                                     else [req.last_token])
         padded = sum(-(-n // BLOCK_Q) * BLOCK_Q for n in q_lens if n)
-        Q = self._q_bucket(padded)
+        Q = self._launch_bucket(padded, sum(q_lens))
+        R = self._tower_rows(Q)
         blk_seq, qstart, pos0, last_row, _ = ragged_layout(
             q_lens, pos0s, q_bucket=Q)
-        token_ids = np.zeros(Q, np.int32)
-        qpos = np.zeros(Q, np.int32)
+        # a slot's first TOWER row: its first kernel row, or — the tower
+        # on an axis of its own — the real rows of the slots before it
+        row0 = qstart
+        if R != Q:
+            lens = np.asarray(q_lens, np.int32)
+            row0 = (np.cumsum(lens) - lens).astype(np.int32)
+            last_row = np.where(lens > 0, row0 + lens - 1, 0).astype(
+                np.int32)
+        token_ids = np.zeros(R, np.int32)
+        qpos = np.zeros(R, np.int32)
         G = len(pool.groups)
         # pad rows -> scratch block, in every group
-        write_blocks = [np.zeros(Q, np.int32) for _ in range(G)]
+        write_blocks = [np.zeros(R, np.int32) for _ in range(G)]
         write_block = write_blocks[0]
-        write_off = np.zeros(Q, np.int32)
-        token_src = np.full(Q, -1, np.int32)
+        write_off = np.zeros(R, np.int32)
+        token_src = np.full(R, -1, np.int32)
         block = None
         if B > 1:
             from ..models.generation import BLOCK_UNFIXED
             state_src = np.full(S, -1, np.int32)
             blk_tok = np.zeros((S, B), np.int32)
             blk_pass = np.full((S, B), BLOCK_UNFIXED, np.int32)
-            row_blk = np.full(Q, -1, np.int32)
+            row_blk = np.full(R, -1, np.int32)
             pass_idx = np.full(S, -1, np.int32)
             ride = np.zeros(S, np.int32)
             for slot, req in slot_requests.items():
                 if not q_lens[slot] or req.pending_feed:
                     continue
-                r0 = int(qstart[slot])
+                r0 = int(row0[slot])
                 row_blk[r0:r0 + B] = slot * B + np.arange(B)
                 if not req.block_commits_next(gen):
                     pass_idx[slot] = req.block_pass
@@ -1371,9 +1398,9 @@ class GenerationEngine:
             block = (state_src, blk_tok, blk_pass, row_blk, pass_idx, ride)
         else:
             for slot in from_prev:
-                token_src[int(qstart[slot])] = slot
+                token_src[int(row0[slot])] = slot
         for slot, toks in row_tokens.items():
-            r0, p0 = int(qstart[slot]), int(pos0[slot])
+            r0, p0 = int(row0[slot]), int(pos0[slot])
             table = pool.slot_table(slot)
             for i in range(q_lens[slot]):
                 if i < len(toks):
@@ -1441,7 +1468,7 @@ class GenerationEngine:
             else self._fused_step_fn(Q, T)
         self._sched.note_launch(
             rows=sum(q_lens), q=Q, t=T, program=step.jitted.__name__,
-            kv_tokens=int(kv_len.sum()), **walked,
+            tower_rows=R, kv_tokens=int(kv_len.sum()), **walked,
             # under the block mask a row sees to the end of its block
             kv_row_tokens=sum(
                 n * pos0s[s] + n * (n + 1) // 2 if B == 1 else int(
@@ -1511,6 +1538,45 @@ class GenerationEngine:
         while b < rows:
             b *= 2
         return b
+
+    def _decode_rows(self) -> int:
+        """The most real rows a decode slot holds in a launch: 1, or 2 B
+        under block generation (a block's commit riding with the next
+        block's first pass), or ``spec_k`` candidates under speculation."""
+        B = self._decoder_spec.generation.block_length
+        return 2 * B if B > 1 else self._spec_k if self._spec else 1
+
+    def _tower_rows(self, Q: int) -> int:
+        """``R(Q)``: the rows the ``Q`` bucket's programs run their tower
+        on (``ops.ragged_paged_attention.tower_rows``) — from the slots,
+        the chunk budget and :meth:`_decode_rows`. No bucket of its own
+        and no knob: a function of ``Q`` and of what the engine was built
+        with. The tensor-parallel step is a tower of its own on one axis
+        (``_mp_fused_tower``): ``R == Q`` there."""
+        if self._mesh is not None:
+            return int(Q)
+        from ..ops.ragged_paged_attention import tower_rows
+        return tower_rows(Q, self._pool.num_slots, self._chunk_budget,
+                          self._decode_rows())
+
+    def _launch_bucket(self, padded: int, real: int) -> int:
+        """The ``Q`` bucket of a launch of ``padded`` kernel rows of
+        which ``real`` are real: the smallest whose kernel rows hold the
+        first and whose tower rows hold the second. A launch of decode
+        rows beside a chunk fits the bucket of its padded rows; a chunk
+        with few decode rows beside it (the ramp's) goes one up and pays
+        pad q blocks, which the kernel skips."""
+        most = self._pool.num_slots * self._decode_rows() \
+            + self._chunk_budget
+        if real > most and self._mesh is None:
+            raise ValueError(
+                f"a launch of {real} real rows fits no program: "
+                f"{self._pool.num_slots} slots' decode rows and a chunk "
+                f"budget of {self._chunk_budget} are {most}")
+        Q = self._q_bucket(padded)
+        while self._tower_rows(Q) < real:
+            Q *= 2
+        return Q
 
     def _fused_step_fn(self, q_rows: int, table_len: int):
         key = (q_rows, table_len)

@@ -721,12 +721,20 @@ class Scheduler:
                     kv_tokens_window: Optional[int] = None,
                     kv_row_tokens_window: Optional[int] = None,
                     state_slots: Optional[int] = None, ssm_rows: int = 0,
-                    ssm_chunk_rows: int = 0) -> None:
+                    ssm_chunk_rows: int = 0,
+                    tower_rows: Optional[int] = None) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
         ``launch_rows`` real query rows inside the ``(launch_q,
-        launch_t)`` program's buckets; ``launch_program``, that step
+        launch_t)`` program's buckets — ``launch_q`` the rows of its
+        attention KERNEL, each slot's padded to whole q blocks — and
+        ``launch_tower_rows`` (``tower_rows``; the kernel's where not
+        given) the rows everything else of the program runs on, which
+        hold the slots' real rows back to back
+        (``models/generation.py:_row_axes``); monitors
+        ``serving/launch_rows`` and ``serving/tower_rows`` sum the two;
+        ``launch_program``, that step
         program's name — the device's ``XLA Modules`` line of a profiler
         trace calls the launch ``jit_<launch_program>(…)``;
         ``kv_tokens``, the context
@@ -758,7 +766,11 @@ class Scheduler:
         them in a sequence of more than one row — the chunked scan's
         (``ops/ssm.py``)."""
         if self._rec is not None:
+            tower = int(q if tower_rows is None else tower_rows)
+            stat_add("serving/launch_rows", int(rows))
+            stat_add("serving/tower_rows", tower)
             self._rec.update(launch_rows=int(rows), launch_q=int(q),
+                             launch_tower_rows=tower,
                              launch_t=int(t), launch_program=str(program),
                              kv_tokens=int(kv_tokens),
                              kv_steps=int(kv_steps),

@@ -122,3 +122,47 @@ class MockDevice:
 
     def scheduler(self, **kw):
         return Scheduler(self.pool, self.do_prefill, self.do_step, **kw)
+
+
+class RaggedMockDevice(MockDevice):
+    """A ``MockDevice`` whose launches are laid out by the ENGINE's own
+    ``_ragged_operands``: the real scheduler plans, the real pool resolves
+    the page tables, the engine's code picks the ``(Q, T)`` program and its
+    tower rows and stamps the launch record — only the program itself is
+    missing (no model, nothing compiled). ``programs`` holds the ``(Q, T,
+    R)`` of every launch, in order.
+
+    The engine is its methods on a bare instance: what ``_ragged_operands``
+    reads of it (the pool, a one-layer full-attention spec, the chunk
+    budget) is set here, and a launch's step function is a name."""
+
+    def __init__(self, pool, prefill_budget, **kw):
+        import types
+
+        from paddle_tpu.models import decoder_spec as DS
+        from paddle_tpu.serving import GenerationEngine
+        super().__init__(pool, **kw)
+        self.programs = []
+        self.budget = int(prefill_budget)
+        eng = self.engine = GenerationEngine.__new__(GenerationEngine)
+        eng._pool, eng._mesh, eng._mp, eng._window = pool, None, 1, 0
+        eng._spec, eng._spec_k = False, 0
+        eng._chunk_budget = self.budget
+        eng._decoder_spec = DS.DecoderSpec(
+            layers=(DS.LayerSpec(DS.FULL, DS.CacheSpec(rows=1, lanes=2),
+                                 DS.DENSE),),
+            vocab_size=self.VOCAB, max_positions=pool.max_len)
+        eng._fused_step_fn = lambda Q, T: types.SimpleNamespace(
+            jitted=types.SimpleNamespace(__name__=f"fused_step_q{Q}_t{T}"))
+
+    def do_step(self, slot_requests, plan, prev=None):
+        from_prev = prev[1] if prev is not None else ()
+        Q, T, ops, *_ = self.engine._ragged_operands(
+            slot_requests, plan, from_prev=from_prev)
+        self.programs.append((Q, T, int(ops[0].shape[0])))
+        return super().do_step(slot_requests, plan, prev)
+
+    def scheduler(self, **kw):
+        sched = super().scheduler(prefill_budget=self.budget, **kw)
+        self.engine._sched = sched
+        return sched

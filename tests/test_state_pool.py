@@ -134,6 +134,23 @@ PARENT = {
     ("sdar", 32, 4): ("block_step_q32_t4", 46, 3, "ead2c95aa630", 395),
 }
 
+# Since PR 41 a program's tower runs on R(Q) <= Q rows
+# (`engine._tower_rows`; the rule's row multiple is 8 here, the toy
+# engines holding two slots and a chunk budget of 16): 2 decode rows + up
+# to 16 chunk rows are 24 tower rows under the 32 kernel rows of q32, which
+# hold the two slots' q blocks and the budget — the SAME program name and
+# result, per-row operands of 24, and the row axes' index arithmetic with
+# three gathers a full-attention layer (two a latent one) on top of the
+# parent's equations. Where R(Q) == Q the parent's program is traced,
+# equation for equation: every q8 program, and BOTH of sdar's, whose
+# two slots' blocks of 8 rows fill the kernel's rows (the identity
+# rule's proof)
+COMPACT = {
+    ("axk1", 32, 4): (24, ("fused_step_q32_t4", 64, 3, "3214d76828b5", 641)),
+    ("gpt2", 32, 4): (24, ("fused_step_q32_t4", 53, 3, "0ef8be99449b", 167)),
+    ("mimo", 32, 4): (24, ("fused_step_q32_t4", 68, 4, "ab1aa19060fd", 604)),
+}
+
 
 def _toy(name):
     if name == "gpt2":
@@ -165,17 +182,30 @@ def _signature(eng, net, Q, T):
 
 
 @pytest.mark.parametrize("family", ["gpt2", "axk1", "sdar", "mimo"])
-def test_a_spec_without_state_builds_the_parents_step_programs(family):
+def test_a_spec_without_state_builds_the_parents_step_programs(
+        family, monkeypatch):
+    import paddle_tpu.ops.ragged_paged_attention as rpa
     from paddle_tpu.models.decoder_spec import serving_decoder
     from paddle_tpu.serving import GenerationEngine
+    monkeypatch.setattr(rpa, "TOWER_ROW_MULTIPLE", 8)
     net = _toy(family)
     spec = serving_decoder(net).spec
     assert spec.state is None and spec.state_layers == ()
-    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8)
+    # the budget is no part of a program: it says which R a Q has
+    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8,
+                           prefill_budget=16)
     try:
         assert eng._pool.state_data == ()
         assert "state" not in eng.stats()
         for Q, T in ((8, 1), (32, 4)):
-            assert _signature(eng, net, Q, T) == PARENT[(family, Q, T)]
+            rows, want = COMPACT.get((family, Q, T),
+                                     (Q, PARENT[(family, Q, T)]))
+            assert eng._tower_rows(Q) == rows
+            assert _signature(eng, net, Q, T) == want
+        if family == "sdar":
+            assert not [k for k in COMPACT if k[0] == "sdar"]
+        # the padded twin of a compact program IS the parent's
+        eng._tower_rows = lambda Q: int(Q)
+        assert _signature(eng, net, 32, 4) == PARENT[(family, 32, 4)]
     finally:
         eng.close()
