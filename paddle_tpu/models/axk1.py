@@ -237,15 +237,17 @@ def _swiglu(x, gate, up, down, gate_scale: float = 1.0):
 # ---------------------------------------------------------------------------
 
 def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True,
-                scoring: str = "sigmoid", select_bias=None):
+                scoring: str = "sigmoid", select_bias=None,
+                eps: float = 0.0):
     """Scores, choice and weights of the router: ``x [Q, E]``, ``router_w
     [n_experts, E]`` -> ``(idx [Q, k] int32, w [Q, k] float32, scores [Q,
     n_experts] float32)``. ``scoring`` is ``"sigmoid"`` (A.X-K1) or
     ``"softmax"`` over all experts (SDAR). ``select_bias [n_experts]``
     (``topk_method`` ``noaux_tc``, MiMo-V2-Flash): the choice is the top-k
     of ``scores + select_bias``, the weights are the chosen experts'
-    SCORES (None: the choice is the top-k of the scores, as it was). The
-    scores are float32:
+    SCORES (None: the choice is the top-k of the scores, as it was).
+    ``eps`` joins the sum the chosen scores are divided by (``norm``;
+    LFM2's 1e-6, 0 elsewhere). The scores are float32:
     activations and router weights are exact in bfloat16, every product
     of two of them is exact in float32, and the MXU accumulates in
     float32, so this IS the float32 score up to the order of the sum."""
@@ -264,8 +266,10 @@ def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True,
         _, idx = jax.lax.top_k(
             scores + select_bias.astype(jnp.float32)[None, :], int(top_k))
         top = jnp.take_along_axis(scores, idx, axis=-1)
-    w = top / jnp.sum(top, axis=-1, keepdims=True) if norm else top
-    return idx.astype(jnp.int32), w * jnp.float32(scale), scores
+    if norm:
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        top = top / (total + jnp.float32(eps) if eps else total)
+    return idx.astype(jnp.int32), top * jnp.float32(scale), scores
 
 
 def routed_experts(x, valid, idx, w, experts, held, pair_chunk: int):

@@ -7,9 +7,10 @@ engine's memory planner consume THIS, not ``model.gpt``: a model is
 served by the fused paged path if ``serving_decoder()`` returns an
 object with
 
-* ``spec`` — a :class:`DecoderSpec`: per layer the attention kind with
-  its cache descriptor, its window and its sinks, and the FFN kind, plus
-  the vocabulary and the most positions the model takes;
+* ``spec`` — a :class:`DecoderSpec`: per layer the MIXER — the attention
+  kind with its cache descriptor, its window and its sinks, a recurrent
+  state beside it, or a state ALONE — and the FFN kind, plus the
+  vocabulary and the most positions the model takes;
 * ``embed_tokens(token_ids, positions)`` -> ``Tensor [1, Q, E]`` (positions
   that the model adds at the embedding, as GPT does, are added here;
   a rotary model ignores them here and reads them in ``attn_in``);
@@ -24,25 +25,42 @@ object with
   (the rows in, the rows' sequence layout — ``ops/ssm.py:SeqLayout`` —
   and the slots' state arrays in, the branch's output and the new state
   out; ``index`` is the layer's place in the state arrays), and its
-  ``attn_out`` takes the branch's output as a fourth argument;
+  ``attn_out`` takes the branch's output as a fourth argument; a layer
+  whose mixer is a state ALONE has no ``attn_in`` at all, and its
+  ``attn_out`` is handed ``None`` for the attention's output;
 * ``final_norm(x)`` and ``logits(hidden)``.
 
-Two attention kinds, two FFN kinds, a recurrent state beside the
-attention cache or none, one generation rule, five callers
+Two attention kinds, two FFN kinds, three mixers (attention with its
+cache; attention with its cache and a recurrent state beside it; a
+recurrent state alone), one generation rule, six callers
 (``models/gpt.py``, ``models/axk1.py``, ``models/sdar.py``,
-``models/mimo.py``, ``models/falcon_h1.py``). Nothing else is described
-here.
+``models/mimo.py``, ``models/falcon_h1.py``, ``models/lfm2.py``).
+Nothing else is described here.
 
-**Recurrent state.** A layer may run a state-space mixer BESIDE (not
-instead of) its attention: what a sequence then leaves behind in the
-layer is its cache entries, which grow with the context, and a STATE of
-fixed size — :class:`StateSpec`: the parts one sequence holds (the
-convolution's tail, the recurrence's state), their shapes and dtypes.
-The spec derives the layers that have one (``state_layers``), all of one
-descriptor, and the paged pool holds one array a part, ``[those layers,
-slots + 1, *shape]``, a row a slot (``serving/paging.py``). A state has
-no snapshot a block: nothing that rolls a position back or reuses a
-prefix composes with it (``serving/engine.py:_refuse_with_state``).
+**Recurrent state.** What a sequence leaves behind in a layer is its
+cache entries, which grow with the context, or a STATE of fixed size —
+:class:`StateSpec`: the parts one sequence holds (a convolution's tail, a
+recurrence's state), their shapes and dtypes — or both: a layer may run
+a state-space mixer BESIDE its attention (Falcon-H1: every layer), or
+have the state-holding mixer INSTEAD of attention (LFM2: a gated short
+convolution is three layers in four; ``attention`` and ``cache`` are then
+``None`` and the layer has no query heads). The spec derives the layers
+that have a state (``state_layers``), all of one descriptor, and the
+paged pool holds one array a part, ``[those layers, slots + 1, *shape]``,
+a row a slot (``serving/paging.py``). A state has no snapshot a block:
+nothing that rolls a position back or reuses a prefix composes with it
+(``serving/engine.py:_refuse_with_state``).
+
+**Cache-less layers.** The layers that hold a cache (``cache_layers``)
+are a SUBSET of the layers: the cache groups below are formed of them
+only, so the pool's block arrays, a block's bytes and a token's bytes
+count them and not ``len(layers)``; ``DecoderSpec.attention`` and
+``.cache`` describe the FIRST of them; ``layer_group`` of a cache-less
+layer is an error. The tower runs such a layer's ``mixer`` and
+``attn_out`` and nothing else of a layer: no ``attn_in``, no cache
+write, no attention kernel (``models/generation.py:_fused_tower``). A
+spec with no cache-bearing layer at all is refused: positions, page
+tables and the launch's row layout are the cache's.
 
 **Cache groups.** The layers of one model need not share a cache
 descriptor: the spec derives its CACHE GROUPS — the layers with equal
@@ -76,7 +94,7 @@ runs the block's final tokens, whose K/V the cache keeps.
 
 **Sections.** :data:`SECTIONS` names the parts of a launch's device work
 (``embed``, ``norm``, ``qkv`` … ``head``, ``sample``); the tower and the
-four models put their ops under them, layer by layer, and the profiler
+models put their ops under them, layer by layer, and the profiler
 reads a launch's device time by them (``profiler/xplane.py``).
 """
 from __future__ import annotations
@@ -113,7 +131,10 @@ MLP = "mlp"                      # a dense FFN; the add that closes a layer
 HEAD = "head"                    # the logits of the rows that are read
 SAMPLE = "sample"                # the pick of one token a slot
 UNMASK_SCOPE = "unmask"          # a block pass's head, confidence, choice
-SSM_PROJ = "ssm_proj"            # a mixer's in/out projection, gated norm
+# a state-holding mixer's parts. The prefix is historical (the first such
+# mixer was a state-space one): a gated short convolution's projections
+# and gates are ``ssm_proj`` and its convolution ``ssm_conv`` too
+SSM_PROJ = "ssm_proj"            # a mixer's in/out projection, gates, norm
 SSM_CONV = "ssm_conv"            # its causal convolution and the tail
 SSM_SCAN = "ssm_scan"            # its recurrence and the D skip
 SECTIONS = (EMBED, NORM, QKV, CACHE_WRITE, ATTENTION, O_PROJ, ROUTER,
@@ -205,15 +226,36 @@ class StateSpec:
 
 @dataclass(frozen=True)
 class LayerSpec:
-    attention: str
-    cache: CacheSpec
+    """One layer's mixer and FFN. ``attention`` and ``cache`` both
+    ``None``: the mixer is the ``state`` alone (module doc)."""
+    attention: Optional[str]
+    cache: Optional[CacheSpec]
     ffn: str
     window: int = 0          # 0: a row sees all of the context
     sinks: bool = False      # a learned logit a query head in the softmax
     query_heads: int = 0     # 0: as many as the cache's rows (KV heads)
-    state: Optional[StateSpec] = None   # a recurrent state beside the cache
+    state: Optional[StateSpec] = None   # beside the cache, or alone
 
     def __post_init__(self):
+        if self.ffn not in (DENSE, ROUTED):
+            raise ValueError(f"FFN kind {self.ffn!r}: the fused path knows "
+                             f"{DENSE!r} and {ROUTED!r}")
+        if (self.attention is None) != (self.cache is None):
+            raise ValueError(
+                "an attention kind and a cache descriptor come together: "
+                "both, or neither for a layer whose mixer is a state alone")
+        if self.attention is None:
+            if self.state is None:
+                raise ValueError(
+                    "a layer with neither attention nor a recurrent state "
+                    "has no mixer: give it attention= with cache=, state=, "
+                    "or both")
+            if self.window or self.sinks or self.query_heads:
+                raise ValueError(
+                    "a layer whose mixer is a state alone has no window, "
+                    "no sink logits and no query heads: they are the "
+                    "attention's")
+            return
         if self.window < 0:
             raise ValueError(f"window {self.window} must be >= 0")
         if self.query_heads % self.cache.rows:
@@ -227,16 +269,14 @@ class LayerSpec:
         if self.attention not in (FULL, LATENT):
             raise ValueError(
                 f"attention kind {self.attention!r}: the fused path knows "
-                f"{FULL!r} and {LATENT!r} (a state-space mixer is no third "
-                f"kind: it runs beside one of them, state=)")
-        if self.ffn not in (DENSE, ROUTED):
-            raise ValueError(f"FFN kind {self.ffn!r}: the fused path knows "
-                             f"{DENSE!r} and {ROUTED!r}")
+                f"{FULL!r} and {LATENT!r} (a state-holding mixer is no "
+                f"third kind: state= beside one of them, or state= alone "
+                f"with attention=None, cache=None)")
         if self.state is not None and (self.attention != FULL
                                        or self.window):
             raise ValueError(
                 "a recurrent state is built beside full attention over "
-                "the whole context only (no latent cache, no window)")
+                "the whole context, or alone (no latent cache, no window)")
 
 
 @dataclass(frozen=True)
@@ -285,6 +325,18 @@ class DecoderSpec:
     def __post_init__(self):
         if not self.layers:
             raise ValueError("a decoder spec needs at least one layer")
+        if not self.cache_layers:
+            raise ValueError(
+                "no layer of the spec holds a cache: a model of state-"
+                "alone layers only is not built (a sequence's positions, "
+                "its page table and the launch's row layout are the "
+                "cache's)")
+        if self.generation.block_length > 1 and len(self.cache_layers) \
+                < len(self.layers):
+            raise ValueError(
+                "a layer whose mixer is a state alone is not built under "
+                "block generation: a denoising pass rewrites its block's "
+                "rows, and a state cannot take a row back")
         if self.generation.block_length > 1 and self.attention != FULL:
             raise ValueError(
                 "block generation is built for the full attention kind "
@@ -309,23 +361,32 @@ class DecoderSpec:
                 "generated one token a step only: a latent group beside "
                 "another, and block generation over a window, are not")
 
+    @cached_property
+    def cache_layers(self) -> Tuple[int, ...]:
+        """The layers that hold a cache, in order (all of them, but for a
+        model with layers whose mixer is a state alone)."""
+        return tuple(i for i, ls in enumerate(self.layers)
+                     if ls.cache is not None)
+
     @property
     def attention(self) -> str:
-        return self.layers[0].attention
+        """The attention kind of the first layer that has one."""
+        return self.layers[self.cache_layers[0]].attention
 
     @property
     def cache(self) -> CacheSpec:
         """The FIRST group's descriptor (the only one, for a model whose
-        layers share it)."""
-        return self.layers[0].cache
+        cache-bearing layers share it)."""
+        return self.layers[self.cache_layers[0]].cache
 
     @cached_property
     def cache_groups(self) -> Tuple["CacheGroup", ...]:
-        """The layers with equal ``(attention, cache, window)``, in order
-        of first appearance: one pool array and one page table a request
-        each (module doc)."""
+        """The cache-bearing layers with equal ``(attention, cache,
+        window)``, in order of first appearance: one pool array and one
+        page table a request each (module doc)."""
         keys, members = [], {}
-        for i, ls in enumerate(self.layers):
+        for i in self.cache_layers:
+            ls = self.layers[i]
             key = (ls.attention, ls.cache, ls.window)
             if key not in members:
                 keys.append(key)
@@ -362,6 +423,10 @@ class DecoderSpec:
         for g, grp in enumerate(self.cache_groups):
             if layer in grp.layers:
                 return g, grp.layers.index(layer)
+        if 0 <= layer < len(self.layers):
+            raise ValueError(
+                f"layer {layer} holds no cache (its mixer is a state "
+                f"alone): it belongs to no cache group")
         raise IndexError(f"layer {layer} out of range")
 
 
@@ -385,6 +450,6 @@ def serving_decoder(model):
             f"{type(model).__name__} exposes no serving_decoder(): the "
             f"fused serving stack consumes a decoder spec "
             f"(models/decoder_spec.py), which models/gpt.py, "
-            f"models/axk1.py, models/sdar.py, models/mimo.py and "
-            f"models/falcon_h1.py provide")
+            f"models/axk1.py, models/sdar.py, models/mimo.py, "
+            f"models/falcon_h1.py and models/lfm2.py provide")
     return make()

@@ -787,6 +787,9 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     starts from zero, pad rows touch nothing). Returns ``(final_norm(x),
     pool, scales, counters, state)``, ``counters`` ``None`` for a model
     without routed layers, ``state`` ``None`` for one without state.
+    A layer whose mixer is a state ALONE (its spec has no cache) runs
+    ``mixer`` and ``attn_out`` and nothing else: no ``attn_in``, no cache
+    write, no move to the kernel's rows and back, no attention call.
 
     TWO ROW AXES (PR 41). ``x``, ``positions``, ``write_block`` and
     ``write_off`` — and so every op of every layer: norms, projections,
@@ -833,7 +836,26 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
             kwbs = tuple(_at_rows(w, axes, 0, to_kernel=True) for w in wbs)
             koff = _at_rows(write_off, axes, 0, to_kernel=True)
     counters = None
+
+    def summed(counters, c):
+        """A routed layer's three counters added to the layers' before."""
+        if c is None:
+            return counters
+        with DS.section(DS.MOE_SCOPE):
+            return c if counters is None else tuple(
+                u + v for u, v in zip(counters, c))
+
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
+        if ls.cache is None:
+            # the mixer is a state alone: the layer lives on the tower's
+            # rows only — nothing is projected for a kernel, written to
+            # the pool or read through a page table
+            with DS.layer_scope(li):
+                mixed, state = layer.mixer(
+                    x, layout, state, dec.spec.state_layers.index(li))
+                x, c = layer.attn_out(x, None, row_valid, mixed)
+                counters = summed(counters, c)
+            continue
         # the layer's cache group, and its place in the group's array
         g, gi = dec.spec.layer_group(li)
         with DS.layer_scope(li):
@@ -886,10 +908,7 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                 x, c = layer.attn_out(x, a, row_valid, mixed)
             else:
                 x, c = layer.attn_out(x, a, row_valid)
-            if c is not None:
-                with DS.section(DS.MOE_SCOPE):
-                    counters = c if counters is None else tuple(
-                        u + v for u, v in zip(counters, c))
+            counters = summed(counters, c)
     if counters is not None:
         with DS.section(DS.MOE_SCOPE):
             counters = jnp.stack(counters).astype(jnp.int32)

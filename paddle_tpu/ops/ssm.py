@@ -2,6 +2,11 @@
 launch, over a state array a slot — the mixer's counterpart of
 ``ops/ragged_paged_attention.py`` + ``ops/kv_append.py``.
 
+(The file's name says less than it holds since ``models/lfm2.py``:
+:func:`seq_layout` and :func:`conv_rows` serve ANY mixer whose state is a
+row a slot — LFM2's gated short convolution has no recurrence and uses
+those two alone, at ``K`` 3 without a bias.)
+
 What a sequence leaves behind in a state-space layer does not grow with
 its context: the last ``d_conv - 1`` inputs of the depthwise convolution
 (the TAIL) and the recurrent state ``H [heads, P, N]``. The serving pool
@@ -91,7 +96,7 @@ def seq_layout(blk_seq, seq_qstart, seq_pos0, kv_len, row_valid,
 def conv_rows(x, weight, bias, tail, layer: int, lay: SeqLayout):
     """Causal depthwise convolution over a ragged launch with the tail
     carried. ``x [Q, C]`` float32 inputs, ``weight [K, C]`` (tap ``j``
-    reads the input ``K - 1 - j`` rows back), ``bias [C]``; ``tail
+    reads the input ``K - 1 - j`` rows back), ``bias [C]`` or ``None``; ``tail
     [layers, S + 1, K - 1, C]`` the slots' last ``K - 1`` inputs (a fresh
     sequence reads zeros). Returns ``(out [Q, C]`` before the activation,
     ``tail)`` with each present sequence's row replaced by the inputs of
@@ -105,7 +110,9 @@ def conv_rows(x, weight, bias, tail, layer: int, lay: SeqLayout):
     present = lay.seq_len > 0
     # what a sequence's first rows see before them
     prev = jnp.where(lay.seq_fresh[:, None, None], 0.0, old)
-    out = x * w[K - 1] + bias.astype(jnp.float32)
+    out = x * w[K - 1]
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     for back in range(1, K):
         # the input `back` rows up, where it is the same sequence's
         shifted = jnp.concatenate(

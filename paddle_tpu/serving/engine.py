@@ -327,20 +327,21 @@ def _refuse_with_groups(kv_dtype, mesh, spec_draft,
 
 def _refuse_with_state(kv_dtype, mesh, spec_draft,
                        host_tier_bytes) -> None:
-    """A model whose layers hold a recurrent state beside their attention
-    cache (a state-space mixer, ``models/decoder_spec.py:StateSpec``):
-    each mechanism that needs a SNAPSHOT of a sequence's state at some
-    earlier position, or a state layout that is not built, is refused
-    here, by name. Prefix reuse is the same need and takes no option: the
-    pool offers no block to the prefix cache and matches none
-    (``serving/paging.py``), and a preempted request is re-fed from
-    position 0."""
+    """A model whose layers hold a recurrent state — beside their
+    attention cache (a state-space mixer in every layer) or IN PLACE of
+    one (a layer whose mixer is the state alone),
+    ``models/decoder_spec.py:StateSpec``: each mechanism that needs a
+    SNAPSHOT of a sequence's state at some earlier position, or a state
+    layout that is not built, is refused here, by name. Prefix reuse is
+    the same need and takes no option: the pool offers no block to the
+    prefix cache and matches none (``serving/paging.py``), and a
+    preempted request is re-fed from position 0."""
     import jax.numpy as jnp
     if spec_draft is not None:
         raise ValueError(
             "spec_draft does not compose with a recurrent state yet: a "
             "rejected draft rolls the pool's position back, and a "
-            "recurrence cannot take a row back (it would need the state "
+            "state cannot take a row back (it would need the state "
             "before every candidate row)")
     if host_tier_bytes is not None:
         raise ValueError(
@@ -358,8 +359,9 @@ def _refuse_with_state(kv_dtype, mesh, spec_draft,
     if mesh is not None:
         raise ValueError(
             "mesh= (tensor-parallel serving) does not compose with a "
-            "recurrent state yet: the sharded step has no mixer, and the "
-            "state arrays have no head-partitioned layout")
+            "recurrent state yet: the sharded step has no mixer (beside "
+            "attention or in place of it), and the state arrays have no "
+            "partitioned layout")
 
 
 def _group_block_counts(groups, num_slots, max_len, block_size, num_blocks,
@@ -398,13 +400,13 @@ def _group_block_counts(groups, num_slots, max_len, block_size, num_blocks,
 class GenerationEngine:
     """Continuous-batching serving over a decoder the fused stack has a
     spec of (``models/decoder_spec.py``: GPT-2, A.X-K1, SDAR,
-    MiMo-V2-Flash, Falcon-H1) — one token
+    MiMo-V2-Flash, Falcon-H1, LFM2-MoE) — one token
     a sequence a step, or a block of them by diffusion, as the spec's
     generation rule says.
 
     ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` /
     ``AXK1ForCausalLM`` / ``SDARForCausalLM`` / ``MiMoV2ForCausalLM`` /
-    ``FalconH1ForCausalLM`` (anything
+    ``FalconH1ForCausalLM`` / ``Lfm2MoeForCausalLM`` (anything
     ``serving_decoder`` has a spec of);
     its parameters are snapshotted at construction (sharded parameters
     serve sharded — jit follows the placement).
@@ -431,9 +433,11 @@ class GenerationEngine:
       would hold, and its bytes are shared out by the spec's rule
       (``_group_block_counts``: the window group what its slots can hold
       at all, the rest to the layers that keep the whole context). A
-      model whose layers hold a recurrent STATE beside their cache (a
-      state-space mixer) gets one row of it a slot, sized from the spec
-      and ``num_slots`` and held by the same pool; nothing selects it;
+      model whose layers hold a recurrent STATE — beside their cache (a
+      state-space mixer) or in place of one (a layer whose mixer is the
+      state alone: the block arrays then hold the OTHER layers only) —
+      gets one row of it a slot, sized from the spec and ``num_slots``
+      and held by the same pool; nothing selects it;
     * ``kv_dtype`` — ``"int8"``/``"float8_e4m3fn"`` stores the blocks
       quantized with per-block max-abs scales;
     * ``spec_draft``/``spec_k`` — speculative decoding: a small draft
@@ -505,8 +509,9 @@ class GenerationEngine:
                     "draft tower and verify program have no sharded "
                     "builders — run speculative engines single-device")
         # everything below sizes itself from the model's decoder spec
-        # (models/decoder_spec.py): layers, cache rows and lanes a token,
-        # vocabulary, positions
+        # (models/decoder_spec.py): the layers that hold a cache (the
+        # spec's ``cache``/``attention`` are the FIRST such layer's),
+        # cache rows and lanes a token, vocabulary, positions
         from ..models import decoder_spec as _ds
         spec = _ds.serving_decoder(model).spec
         cache = spec.cache
@@ -1441,7 +1446,8 @@ class GenerationEngine:
         # KV block a step, one wait for a group of G blocks a fetch (G and
         # the grid step's M q blocks as the kernel reads them from its
         # pool shard's shape)
-        first = self._decoder_spec.cache_groups[0]
+        dspec = self._decoder_spec
+        first = dspec.cache_groups[0]
         if first.cache.v_aliases_k:
             # the latent kernel: every q block of a slot walks that
             # slot's whole context
@@ -1487,7 +1493,11 @@ class GenerationEngine:
             **(dict(state_slots=sum(1 for n in q_lens if n),
                     ssm_rows=sum(q_lens),
                     ssm_chunk_rows=sum(n for n in q_lens if n > 1))
-               if pool.state_parts else {}))
+               if pool.state_parts else {}),
+            # the layers that read and write the pool — what a reader
+            # multiplies ``kv_tokens`` by — stamped only where some do not
+            cache_layers=len(dspec.cache_layers)
+            if len(dspec.cache_layers) < len(dspec.layers) else None)
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps, token_src, block)

@@ -5,7 +5,10 @@ admission and preemption over one block array and one page table a
 request FOR EACH CACHE GROUP of the model (``models/decoder_spec.py``:
 the layers that share a cache descriptor and a window; GPT-2, A.X-K1 and
 SDAR have one group, and everything below reads as it always did for
-them). A group's device array is ``[its layers, num_blocks + 1, heads,
+them; a group's layers are those that HOLD a cache — a layer whose mixer
+is a state alone is in none, so a 10-layer model with 2 attention layers
+has a block array of 2 layers, and a block's bytes and a token's count
+those 2). A group's device array is ``[its layers, num_blocks + 1, heads,
 block_size, lanes]`` (K|V folded into the lanes — the ONE layout every
 reader of the pool uses, see ops/ragged_paged_attention.py): a request
 owns only the blocks covering its tokens SO FAR, addressed through a
@@ -34,8 +37,9 @@ prefix cache (a block of the window-0 group alone does not let a request
 skip its prompt: the window layers' last W - 1 tokens are gone), and
 int8/fp8 blocks, the host tier and a mesh are refused.
 
-**Recurrent state** (``state=``: the spec's layers that run a state-space
-mixer beside their attention, ``models/decoder_spec.py:StateSpec``). What
+**Recurrent state** (``state=``: the spec's layers that run a state-holding
+mixer beside their attention or in place of it,
+``models/decoder_spec.py:StateSpec``). What
 a sequence holds there has a fixed size, so it is a ROW A SLOT, not
 blocks a token: ``state_data`` is one device array a part of the
 descriptor, ``[those layers, num_slots + 1, *shape]`` (the last row is

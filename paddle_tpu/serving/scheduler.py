@@ -722,7 +722,8 @@ class Scheduler:
                     kv_row_tokens_window: Optional[int] = None,
                     state_slots: Optional[int] = None, ssm_rows: int = 0,
                     ssm_chunk_rows: int = 0,
-                    tower_rows: Optional[int] = None) -> None:
+                    tower_rows: Optional[int] = None,
+                    cache_layers: Optional[int] = None) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
@@ -764,7 +765,10 @@ class Scheduler:
         reads and writes (once a layer with state); ``ssm_rows``, the
         real rows through the mixer, and ``ssm_chunk_rows``, those of
         them in a sequence of more than one row — the chunked scan's
-        (``ops/ssm.py``)."""
+        (``ops/ssm.py``). ``cache_layers`` (given only by a model with
+        layers whose mixer is a state alone): the layers that read and
+        write the pool, which is what ``kv_tokens`` and the walk counts
+        are a layer OF."""
         if self._rec is not None:
             tower = int(q if tower_rows is None else tower_rows)
             stat_add("serving/launch_rows", int(rows))
@@ -787,6 +791,8 @@ class Scheduler:
                 self._rec.update(state_slots=int(state_slots),
                                  ssm_rows=int(ssm_rows),
                                  ssm_chunk_rows=int(ssm_chunk_rows))
+            if cache_layers is not None:
+                self._rec["cache_layers"] = int(cache_layers)
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into

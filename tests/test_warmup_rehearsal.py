@@ -29,6 +29,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELLS = [("decode-backlog", "gpt2-large"),
          ("decode-backlog-s128", "axk1-ep16"),
          ("long-backlog-s128", "mimo-v2-flash-ep16"),
+         ("long-backlog-s128", "lfm2-24b-a2b-pp4"),
          ("decode-heavy-backlog-s64", "falcon-h1-34b-pp12")]
 
 
