@@ -686,7 +686,8 @@ class GenerationEngine:
                eos_token_id: Optional[int] = None,
                timeout: Optional[float] = None,
                tenant: str = "default",
-               lane: str = "interactive") -> GenerationRequest:
+               lane: str = "interactive",
+               sink=None) -> GenerationRequest:
         """Enqueue one generation; returns its handle immediately.
 
         The handle streams tokens as they are produced
@@ -711,7 +712,15 @@ class GenerationEngine:
         (lane, tenant) classes with per-lane weights
         (``GenerationEngine(lane_weights=...)``, default interactive 4
         : batch 1), so a batch flood cannot starve interactive TTFT.
-        Untagged traffic all shares one class — plain FCFS."""
+        Untagged traffic all shares one class — plain FCFS.
+
+        ``sink`` (anything with ``put(batch)``) takes the request's
+        tokens and its terminal item in place of the handle's own queue
+        — a launch's emissions for all the requests of one sink arrive
+        in ONE ``put``, a list of ``(handle, item)`` in emit order (an
+        int a token; at the end ``None`` or the error). ``stream()`` of
+        such a handle refuses; ``result()`` is unchanged. The front
+        door's one stream writer is such a sink."""
         if self._closed:
             raise RuntimeError("GenerationEngine is closed")
         if top_k is not None and int(top_k) != self._top_k:
@@ -751,7 +760,7 @@ class GenerationEngine:
             ids, max_new_tokens, do_sample=do_sample,
             temperature=temperature, eos_token_id=eos_token_id,
             pad_token_id=self._pad, timeout=timeout,
-            tenant=tenant, lane=lane)
+            tenant=tenant, lane=lane, sink=sink)
         handle = self._sched.submit(req)   # QueueFullError propagates
         stat_add("serving/requests")       # counts ACCEPTED requests
         return handle

@@ -27,13 +27,23 @@ Transport: the stdlib threaded HTTP server shared with
 routes in the ops route table so ``/metrics`` and ``/v1/completions``
 share one process and one port (``FrontDoor.start()`` builds and owns
 an ``OpsServer`` when there is none to mount on). Threaded, not async:
-the container bakes in no web framework and generation is minutes-long
-streaming against a thread-safe engine API — one OS thread per live
-connection is the honest concurrency model here, and the SSE loop is
-just a blocking iterator over ``handle.stream()``. The scheduler's
-one-fetch-per-cycle device contract is untouched: the front door never
-holds a device handle (the ``ops-handler-sync`` self-lint rule walks
-this module), it only enqueues work and drains host-side token queues.
+the container bakes in no web framework. A connection's handler thread
+parses, admits and submits; a unary response it collects itself from
+``handle.stream()``. A STREAMED response's bytes are not its to write:
+the door owns ONE stream-writer thread (:class:`_StreamWriter`), the
+request is submitted with that writer as its ``sink``, the scheduler
+hands a launch's tokens for all the door's streams over in one ``put``,
+and the writer formats and sends them, a batch a launch, on non-blocking
+sockets — what a slow client's socket does not take waits in that
+stream's own buffer for a ``selectors`` poll and holds nobody else up.
+The handler thread sends the headers, opens the stream at the writer and
+sleeps until its last byte is out: 64 live streams are 64 parked
+threads and one that wakes a launch, not 64 that wake a token each to
+take the interpreter lock from the turn that feeds the device. The
+scheduler's one-fetch-per-cycle device contract is untouched: the front
+door never holds a device handle (the ``ops-handler-sync`` self-lint
+rule walks this module), it only enqueues work and drains host-side
+tokens.
 
 Error surface (all JSON, the server thread survives every one):
 
@@ -51,14 +61,19 @@ Error surface (all JSON, the server thread survives every one):
 """
 from __future__ import annotations
 
+import collections
 import json
 import math
+import selectors
+import socket
 import threading
 import time
-from typing import Any, Dict, Iterable, Optional, Tuple
+import traceback
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from ..framework import metrics as _metrics
 from ..framework.monitor import stat_add
+from ..profiler import span as _prof
 from .scheduler import DeadlineExceeded, QueueFullError, RequestCancelled
 
 __all__ = ["FrontDoor", "TokenBucket", "LANES"]
@@ -164,6 +179,7 @@ class FrontDoor:
         self._streamed = 0
         self._shed: Dict[str, int] = {}
         self._ops: Optional[Any] = None      # owned server, if start()ed
+        self._writer: Optional[_StreamWriter] = None    # see mount()
 
     # -- mounting ------------------------------------------------------------
     def mount(self, ops: Any) -> "FrontDoor":
@@ -172,7 +188,16 @@ class FrontDoor:
         /metrics then share that server's process and port."""
         ops.add_route("POST", "/v1/completions", self._handle_completions)
         ops.add_route("GET", "/v1/models", self._handle_models)
+        self._stream_writer()
         return self
+
+    def _stream_writer(self) -> "_StreamWriter":
+        """The door's one stream writer: started when the routes go
+        live, stopped by ``close()``."""
+        with self._lock:
+            if self._writer is None or self._writer.closing:
+                self._writer = _StreamWriter()
+            return self._writer
 
     def start(self, host: str = "127.0.0.1", port: int = 0):
         """Build, mount on and start an owned ops server bound to the
@@ -189,6 +214,9 @@ class FrontDoor:
         ops, self._ops = self._ops, None
         if ops is not None:
             ops.close()
+        writer = self._writer       # kept: stats() reads its counters
+        if writer is not None:
+            writer.close()
 
     def __enter__(self):
         self.start()
@@ -379,6 +407,10 @@ class FrontDoor:
                          ("timeout_s", "timeout")):
             if body.get(wire) is not None:
                 kwargs[kw] = body[wire]
+        if stream:
+            # the sink rides the submit: a first token can land before
+            # this thread has as much as sent the headers
+            writer = kwargs["sink"] = self._stream_writer()
         try:
             handle = self._engine.submit(prompt, max_tokens, **kwargs)
         except QueueFullError as e:
@@ -408,7 +440,7 @@ class FrontDoor:
             if stream:
                 self._streamed += 1
         if stream:
-            self._stream_response(h, handle, len(prompt))
+            self._stream_response(h, handle, len(prompt), writer)
         else:
             self._unary_response(h, handle, len(prompt))
 
@@ -426,58 +458,267 @@ class FrontDoor:
         self._reply(h, 200, self._completion_doc(
             handle.id, tokens, n_prompt, self._finish_reason(handle, err)))
 
-    def _stream_response(self, h, handle, n_prompt: int) -> None:
+    def _stream_response(self, h, handle, n_prompt: int,
+                         writer: "_StreamWriter") -> None:
         """SSE over HTTP/1.0 connection-close framing: one ``data:``
         JSON chunk per token as the scheduler produces it, a final
-        chunk with ``finish_reason`` + usage, then ``data: [DONE]``."""
-        h.send_response(200)
-        h.send_header("Content-Type", "text/event-stream")
-        h.send_header("Cache-Control", "no-cache")
-        h.send_header("X-Accel-Buffering", "no")
-        h.end_headers()
-        rid = f"cmpl-{handle.id}"
-
-        def emit(doc: Any) -> None:
-            h.wfile.write(b"data: " + json.dumps(doc).encode() + b"\n\n")
-            h.wfile.flush()
-
-        n, err = 0, None
+        chunk with ``finish_reason`` + usage, then ``data: [DONE]`` —
+        all written by the door's stream writer; this thread sends the
+        headers, leaves the connection there and sleeps until the
+        stream's last byte is out."""
+        sock = h.connection
         try:
+            h.send_response(200)
+            h.send_header("Content-Type", "text/event-stream")
+            h.send_header("Cache-Control", "no-cache")
+            h.send_header("X-Accel-Buffering", "no")
+            h.end_headers()
+        except OSError:
+            # client went away before its headers: stop generating
+            handle.cancel()
+            sock = None
+        done = writer.open(handle, sock, n_prompt)
+        if getattr(handle, "sink", None) is not writer:
+            # an engine that took no sink answers through the handle's
+            # own queue: this thread carries it over, an item a put
+            err = None
             try:
                 for tok in handle.stream():
-                    emit({"id": rid, "object": "text_completion.chunk",
-                          "model": _MODEL_ID,
-                          "choices": [{"index": 0, "token_id": int(tok),
-                                       "text": f"{int(tok)} ",
-                                       "finish_reason": None}]})
-                    n += 1
-            except (DeadlineExceeded, RequestCancelled) as e:
+                    writer.put([(handle, int(tok))])
+            except Exception as e:                       # noqa: BLE001
                 err = e
-            emit({"id": rid, "object": "text_completion.chunk",
-                  "model": _MODEL_ID,
-                  "choices": [{"index": 0, "token_id": None, "text": "",
-                               "finish_reason":
-                               self._finish_reason(handle, err)}],
-                  "usage": {"prompt_tokens": n_prompt,
-                            "completion_tokens": n,
-                            "total_tokens": n_prompt + n}})
-            h.wfile.write(b"data: [DONE]\n\n")
-            h.wfile.flush()
-        except (BrokenPipeError, ConnectionResetError, OSError):
-            # client went away mid-stream: stop generating for it
-            handle.cancel()
+            writer.put([(handle, err)])
+        done.wait()
 
     # -- introspection -------------------------------------------------------
     def stats(self) -> Dict[str, Any]:
         with self._lock:
+            w = self._writer
             return {"served": self._served,
                     "streamed": self._streamed,
                     "shed": dict(self._shed),
                     "shed_total": sum(self._shed.values()),
                     "tenants_seen": sorted(
-                        set(self._buckets) | set(self._shed))}
+                        set(self._buckets) | set(self._shed)),
+                    "stream_writer_wakes": w.wakes if w else 0,
+                    "stream_writer_chunks": w.chunks if w else 0,
+                    "stream_writer_deferred": w.deferred if w else 0}
 
     def __repr__(self):
         s = self.stats()
         return (f"<FrontDoor served={s['served']} "
                 f"shed={s['shed_total']} engine={self._engine!r}>")
+
+
+def _sse(doc: Any) -> bytes:
+    return b"data: " + json.dumps(doc).encode() + b"\n\n"
+
+
+def _token_chunk(rid: str, tok: int) -> dict:
+    return {"id": rid, "object": "text_completion.chunk",
+            "model": _MODEL_ID,
+            "choices": [{"index": 0, "token_id": tok, "text": f"{tok} ",
+                         "finish_reason": None}]}
+
+
+def _final_chunk(rid: str, finish_reason: str, n_prompt: int,
+                 n: int) -> dict:
+    return {"id": rid, "object": "text_completion.chunk",
+            "model": _MODEL_ID,
+            "choices": [{"index": 0, "token_id": None, "text": "",
+                         "finish_reason": finish_reason}],
+            "usage": {"prompt_tokens": n_prompt, "completion_tokens": n,
+                      "total_tokens": n_prompt + n}}
+
+
+class _Stream:
+    """One open SSE stream, the writer thread's alone once opened."""
+
+    __slots__ = ("handle", "sock", "n_prompt", "parts", "n", "buf",
+                 "ended", "waiting", "dead", "done")
+
+    def __init__(self, handle, sock, n_prompt: int):
+        self.handle, self.sock, self.n_prompt = handle, sock, n_prompt
+        # a token's chunk is three constant pieces around its digits,
+        # cut from the document json.dumps gives: the same bytes
+        mark = 7777777
+        self.parts = _sse(_token_chunk(f"cmpl-{handle.id}", mark)) \
+            .rsplit(b"%d" % mark, 2)
+        self.n = 0                  # token chunks formatted
+        self.buf = bytearray()      # formatted, not yet taken by the socket
+        self.ended = False          # the terminal item has come
+        self.waiting = False        # registered for writability
+        self.dead = sock is None    # nobody to write to (any more)
+        self.done = threading.Event()   # the last byte is out, or never will
+
+
+class _StreamWriter:
+    """The door's ONE writer of streamed responses (module doc).
+
+    A sink of the scheduler's (``put``: a launch's ``(handle, item)``s,
+    any thread) and the place a handler thread leaves its connection
+    (``open``). Everything else — the streams, their buffers, every
+    ``send`` — belongs to the writer thread, which sleeps in ONE
+    ``select`` over a wake socket and the sockets that owe bytes.
+    """
+
+    def __init__(self):
+        self._inbox: collections.deque = collections.deque()
+        self._streams: Dict[Any, _Stream] = {}
+        self._early: Dict[Any, list] = {}   # items that beat their open
+        self._sel = selectors.DefaultSelector()
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._sel.register(self._wake_r, selectors.EVENT_READ)
+        self._lock = threading.Lock()       # open() against the exit
+        self.closing = self._closed = False
+        self.wakes = self.chunks = self.deferred = 0
+        self._thread = threading.Thread(
+            target=self._run, daemon=True, name="paddle-stream-writer")
+        self._thread.start()
+
+    # -- any thread ----------------------------------------------------------
+    def put(self, batch) -> None:
+        """Take ``[(handle, item), ...]`` in emit order: an int is a
+        token, ``None`` or an exception ends the stream."""
+        self._inbox.append(batch)
+        try:
+            self._wake_w.send(b"\0")
+        except OSError:
+            pass        # a full pipe holds wakes enough; closed: dropped
+
+    def open(self, handle, sock, n_prompt: int) -> threading.Event:
+        """Hand the writer a connection whose headers are out
+        (``sock`` None: they could not be sent, the stream is only
+        waited out). The event is set once the stream's last byte is
+        written, or as soon as there is nobody to write to."""
+        st = _Stream(handle, sock, n_prompt)
+        if sock is not None:
+            sock.setblocking(False)
+        with self._lock:
+            if self._closed:
+                st.dead = True
+            else:
+                self.put(st)
+        if st.dead:
+            handle.cancel()
+            st.done.set()
+        return st.done
+
+    def close(self) -> None:
+        self.closing = True
+        self.put(())
+        self._thread.join(timeout=5)
+
+    # -- the writer thread ---------------------------------------------------
+    def _run(self) -> None:
+        _prof.set_thread_name("stream writer")
+        while not self.closing:
+            ready = self._sel.select()
+            try:
+                self._turn([key.data for key, _ in ready
+                            if key.data is not None])
+            except Exception:                            # noqa: BLE001
+                traceback.print_exc()     # the door's streams live on
+        with self._lock:
+            self._closed = True
+            left = [e for e in self._inbox if isinstance(e, _Stream)]
+        for st in left + list(self._streams.values()):
+            if not st.ended:
+                st.handle.cancel()
+            self._drop(st)                # parked handlers go home
+        self._sel.close()
+        self._wake_r.close()
+        self._wake_w.close()
+
+    def _turn(self, writable: List[_Stream]) -> None:
+        try:
+            while self._wake_r.recv(4096):
+                pass
+        except BlockingIOError:
+            pass
+        # NOT a ``serving/`` name: the benchmark's readers take every
+        # ``serving/*`` span for a stretch of the scheduler thread
+        with _prof.record("frontdoor/stream_write", "serving"):
+            for st in writable:           # owed bytes first, in order
+                self._flush(st)
+            touched, chunks = {}, 0
+            while self._inbox:
+                entry = self._inbox.popleft()
+                if isinstance(entry, _Stream):
+                    self._streams[entry.handle] = entry
+                    entry = [(entry.handle, item) for item in
+                             self._early.pop(entry.handle, ())]
+                for handle, item in entry:
+                    st = self._streams.get(handle)
+                    if st is None:
+                        self._early.setdefault(handle, []).append(item)
+                    elif st.dead:
+                        if type(item) is not int:     # its end: forgotten
+                            del self._streams[handle]
+                    else:
+                        chunks += self._format(st, item)
+                        if not st.waiting:
+                            touched[st] = None
+            for st in touched:
+                self._flush(st)
+        if chunks:
+            self.wakes += 1
+            self.chunks += chunks
+            stat_add("serving/stream_writer_wakes")
+            stat_add("serving/stream_writer_chunks", chunks)
+
+    def _format(self, st: _Stream, item) -> int:
+        """``item``'s bytes onto the stream's buffer; 1 for a token's
+        chunk."""
+        if type(item) is int:
+            digits = b"%d" % item
+            head, mid, tail = st.parts
+            st.buf += head + digits + mid + digits + tail
+            st.n += 1
+            return 1
+        st.ended = True
+        st.buf += _sse(_final_chunk(
+            f"cmpl-{st.handle.id}",
+            FrontDoor._finish_reason(st.handle, item),
+            st.n_prompt, st.n)) + b"data: [DONE]\n\n"
+        return 0
+
+    def _flush(self, st: _Stream) -> None:
+        """Send what the socket takes now; what it does not stays in the
+        stream's buffer until the selector says it is writable."""
+        try:
+            sent = st.sock.send(st.buf)
+        except BlockingIOError:
+            sent = 0
+        except OSError:
+            # client went away mid-stream: stop generating for it
+            st.handle.cancel()
+            self._drop(st)
+            return
+        del st.buf[:sent]
+        if st.buf:
+            if not st.waiting:
+                st.waiting = True
+                self.deferred += 1
+                stat_add("serving/stream_writer_deferred")
+                self._sel.register(st.sock, selectors.EVENT_WRITE, st)
+        elif st.ended:
+            self._drop(st)
+        elif st.waiting:
+            st.waiting = False
+            self._sel.unregister(st.sock)
+
+    def _drop(self, st: _Stream) -> None:
+        """The stream is over for its connection and its handler goes
+        home. One that ended is forgotten; one whose client left stays,
+        dead, until the scheduler has sent its terminal item too."""
+        if st.waiting:
+            st.waiting = False
+            self._sel.unregister(st.sock)
+        st.dead = True
+        st.buf.clear()
+        if st.ended:
+            self._streams.pop(st.handle, None)
+        st.done.set()

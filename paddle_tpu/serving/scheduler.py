@@ -21,8 +21,10 @@ membership of the in-flight batch changes EVERY turn:
 * **land** — TWO LAUNCHES ARE IN FLIGHT: a turn dispatches launch N+1
   and only then fetches and emits launch N, so the device goes from one
   launch to the next with no host in between and the host's half of a
-  launch (the single fetch, each new token to its stream, the handler
-  threads that wakes) runs in the device's shadow. What the next plan
+  launch (the single fetch, each new token to its request's queue or
+  the launch's tokens in ONE ``put`` to the sink its requests were
+  submitted with, the thread or threads that wakes) runs in the
+  device's shadow. What the next plan
   needs — positions, how much of a feed is drained, who reaches
   ``max_new_tokens`` — is host arithmetic applied at DISPATCH; the one
   thing it cannot know, a decode row's input token, the step reads from
@@ -171,7 +173,8 @@ class GenerationRequest:
                  do_sample: bool = False, temperature: float = 1.0,
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
                  timeout: Optional[float] = None,
-                 tenant: str = "default", lane: str = "interactive"):
+                 tenant: str = "default", lane: str = "interactive",
+                 sink=None):
         self.id = next(self._ids)
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
         self.max_new_tokens = int(max_new_tokens)
@@ -227,7 +230,14 @@ class GenerationRequest:
         self.trace = RequestTrace(self.id, t_submit=self.submitted_at,
                                   tenant=self.tenant, lane=self.lane)
         self._recorder: Optional[FlightRecorder] = None   # set at submit
-        # caller-side plumbing
+        # caller-side plumbing: where the tokens and the terminal item
+        # go. Without a sink, this request's own queue (``stream()``);
+        # a sink is anything with ``put(batch)``, ``batch`` a list of
+        # ``(request, item)`` in emit order — an int a token, ``None``
+        # or the error at the end — and takes a launch's emissions for
+        # ALL its requests in one call (``Scheduler._land``). Given at
+        # construction: a first token can land right after the submit
+        self.sink = sink
         self._q: "queue.Queue" = queue.Queue()
         self._done = threading.Event()
         self.error: Optional[BaseException] = None
@@ -249,6 +259,10 @@ class GenerationRequest:
         (the first from the launch that fed the prompt's last chunk). Raises the terminal error
         (:class:`RequestCancelled` / :class:`DeadlineExceeded`) after
         any tokens produced before it."""
+        if self.sink is not None:
+            raise RuntimeError(
+                f"request {self.id} was submitted with a sink: its "
+                f"tokens go there, not to stream()")
         _prof.set_thread_name(
             f"stream consumer ({threading.current_thread().name})")
         while True:
@@ -306,8 +320,23 @@ class GenerationRequest:
         return self.block_pass == rule.passes(
             rule.block_length - len(self.block_given))
 
+    def _deliver(self, item, out: Optional[dict]) -> None:
+        """One item on its way to the caller: into the request's own
+        queue, or — with a sink — into ``out``, the landing launch's
+        outbox (sink → batch), which the scheduler hands over once the
+        per-slot loop is through; outside a landing (``out`` None) the
+        sink gets it at once."""
+        sink = self.sink
+        if sink is None:
+            self._q.put(_DONE if item is None else item)
+        elif out is None:
+            sink.put([(self, item)])
+        else:
+            out.setdefault(sink, []).append((self, item))
+
     def _emit(self, tok: int, fixed_pass: Optional[int] = None,
-              now: Optional[float] = None) -> None:
+              now: Optional[float] = None,
+              out: Optional[dict] = None) -> None:
         now = time.perf_counter() if now is None else now
         if self.first_token_at is None:
             self.first_token_at = now
@@ -326,9 +355,10 @@ class GenerationRequest:
         self.tokens.append(tok)
         self.emitted += 1
         self.last_token = tok
-        self._q.put(tok)
+        self._deliver(tok, out)
 
-    def _finish(self, error: Optional[BaseException] = None) -> None:
+    def _finish(self, error: Optional[BaseException] = None,
+                out: Optional[dict] = None) -> None:
         self.error = error
         if error is None:
             name = "finish"
@@ -347,7 +377,7 @@ class GenerationRequest:
             self._recorder.retire(self.trace)
         self.trace.export_spans()   # chrome-trace lane; no-op unarmed
         self._done.set()
-        self._q.put(error if error is not None else _DONE)
+        self._deliver(error, out)
 
     def __repr__(self):
         return (f"<GenerationRequest #{self.id} prompt={len(self.prompt)} "
@@ -444,6 +474,10 @@ class Scheduler:
         self._inflight: Optional[dict] = None
         self._landed: List[dict] = []
         self._landed_at = 0.0
+        # the landing launch's outbox: sink → [(request, item)] in emit
+        # order, handed over ONCE when the per-slot loop is through
+        # (_land); None between landings
+        self._out: Optional[dict] = None
         self._do_copy = do_copy          # device block copy (COW append)
         self.preempts = 0                # requests evicted mid-flight
         self.late_rows = 0               # rows launched for ended requests
@@ -1137,7 +1171,7 @@ class Scheduler:
             stat_add("serving/completed")
         if self._rec is not None:
             self._rec["retired"].append(req.id)
-        req._finish(error)
+        req._finish(error, out=self._out)
 
     # -- memory pressure: growth, copy-on-write, preemption ----------------
     def _preempt_youngest(self) -> bool:
@@ -1450,11 +1484,22 @@ class Scheduler:
                 # from its dispatch, or the landing before it if later
                 dt = t3 - max(launch["t"], self._landed_at)
                 self._landed_at = t3
-                self._emit_chunked(launch, toks, dt)
+                out = self._out = {}
+                try:
+                    self._emit_chunked(launch, toks, dt)
+                finally:
+                    # the hand-over: everything the loop emitted and
+                    # retired for the requests of one sink in ONE put —
+                    # one wake a launch, not one a token (a request
+                    # without a sink woke its own consumer in the loop)
+                    self._out = None
+                    for sink, batch in out.items():
+                        sink.put(batch)
                 # freed inside the span: freeing a device array lets go
-                # of the GIL, and the stream consumers the loop has just
-                # woken hold it for milliseconds — host time of this
-                # launch that would otherwise lie in no span
+                # of the GIL, and whoever the hand-over woke (a sink's
+                # one reader; the consumer thread of each request that
+                # has no sink) may hold it for a while — host time of
+                # this launch that would otherwise lie in no span
                 launch["toks"] = None
                 rec["emit_ms"] += (time.perf_counter() - t3) * 1e3
         except Exception as e:                          # noqa: BLE001
@@ -1585,7 +1630,7 @@ class Scheduler:
                     emit.append(int(corr_row[slot]))
                 slot_emitted = 0
                 for tok in emit:
-                    req._emit(tok)
+                    req._emit(tok, out=self._out)
                     emitted += 1
                     slot_emitted += 1
                     if self._finished(req, tok):
@@ -1596,7 +1641,7 @@ class Scheduler:
                              slot_emitted)
                 continue
             tok = int(toks[S + slot] if spec else toks[slot])
-            req._emit(tok)
+            req._emit(tok, out=self._out)
             emitted += 1
             if self._finished(req, tok):
                 self._retire(slot)
@@ -1654,7 +1699,8 @@ class Scheduler:
                 break               # the surplus of a last block
             if fixed[j] < 0:
                 continue            # given by the prompt
-            req._emit(int(tok[j]), fixed_pass=int(fixed[j]), now=now)
+            req._emit(int(tok[j]), fixed_pass=int(fixed[j]), now=now,
+                      out=self._out)
             out += 1
             if self._finished(req, int(tok[j])):
                 self._retire(slot)

@@ -166,3 +166,30 @@ class RaggedMockDevice(MockDevice):
         sched = super().scheduler(prefill_budget=self.budget, **kw)
         self.engine._sched = sched
         return sched
+
+
+class MockEngine:
+    """The ``submit`` contract of ``GenerationEngine`` over a mock
+    device's scheduler, for what stands in front of an engine (the HTTP
+    front door): requests are real ``GenerationRequest``s through the
+    real ``Scheduler`` — sink, deadline, cancel and all — and only the
+    launches are stand-ins. ``handles`` keeps every accepted request."""
+
+    def __init__(self, device, **sched_kw):
+        self.device = device
+        self.sched = device.scheduler(**sched_kw)
+        self.handles = []
+
+    def submit(self, prompt_ids, max_new_tokens=32, **kw):
+        from paddle_tpu.serving.scheduler import GenerationRequest
+        req = GenerationRequest(np.asarray(prompt_ids, np.int32),
+                                int(max_new_tokens), **kw)
+        self.handles.append(self.sched.submit(req))
+        return req
+
+    def stats(self):
+        return {"queue_depth": self.sched.queue_depth,
+                "active_requests": self.sched.active}
+
+    def close(self):
+        self.sched.close(cancel_pending=True)

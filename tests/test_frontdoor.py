@@ -535,3 +535,273 @@ class TestFleetFrontDoor:
         finally:
             d.close()
             fleet.close()
+
+
+# ---------------------------------------------------------------------------
+# the door's ONE stream writer (PR 43): a launch's tokens for every stream
+# arrive in one put and leave through one thread
+# ---------------------------------------------------------------------------
+
+import socket  # noqa: E402
+import sys  # noqa: E402
+
+from paddle_tpu.framework.monitor import stat_get  # noqa: E402
+
+from _mock_serving import MockEngine  # noqa: E402
+
+
+def _sse_expected(rid, tokens, n_prompt, finish_reason):
+    """The stream's body as the thread-a-connection writer wrote it: a
+    ``json.dumps`` of each document, framed."""
+    def frame(doc):
+        return b"data: " + json.dumps(doc).encode() + b"\n\n"
+    out = b"".join(frame(
+        {"id": rid, "object": "text_completion.chunk",
+         "model": "paddle-tpu",
+         "choices": [{"index": 0, "token_id": int(t), "text": f"{int(t)} ",
+                      "finish_reason": None}]}) for t in tokens)
+    n = len(tokens)
+    out += frame(
+        {"id": rid, "object": "text_completion.chunk",
+         "model": "paddle-tpu",
+         "choices": [{"index": 0, "token_id": None, "text": "",
+                      "finish_reason": finish_reason}],
+         "usage": {"prompt_tokens": n_prompt, "completion_tokens": n,
+                   "total_tokens": n_prompt + n}})
+    return out + b"data: [DONE]\n\n"
+
+
+def _open_stream(srv, prompt, max_tokens, rcvbuf=None, **body):
+    """A streamed completion on a raw socket: the request is sent,
+    nothing is read yet."""
+    data = json.dumps({"prompt": prompt, "max_tokens": max_tokens,
+                       "stream": True, **body}).encode()
+    s = socket.socket()
+    if rcvbuf is not None:          # before connect: it sizes the window
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    s.settimeout(30)
+    s.connect(("127.0.0.1", srv.port))
+    s.sendall((f"POST /v1/completions HTTP/1.0\r\n"
+               f"Content-Type: application/json\r\n"
+               f"Content-Length: {len(data)}\r\n\r\n").encode() + data)
+    return s
+
+
+def _read_body(s):
+    """Everything up to the close, less status line and headers."""
+    raw = b""
+    while True:
+        part = s.recv(65536)
+        if not part:
+            break
+        raw += part
+    s.close()
+    head, _, body = raw.partition(b"\r\n\r\n")
+    assert head.startswith(b"HTTP/1.0 200"), head[:80]
+    return body
+
+
+def _handle_of(eng, prompt):
+    """The accepted request with this prompt (handler threads race, so
+    ``eng.handles`` is in no test's order)."""
+    t_end = time.monotonic() + 30
+    while time.monotonic() < t_end:
+        for h in list(eng.handles):
+            if list(h.prompt) == list(prompt):
+                return h
+        time.sleep(0.005)
+    raise AssertionError(f"no request with prompt {prompt} was accepted")
+
+
+def _frames(body):
+    return [json.loads(f[len(b"data: "):])
+            for f in body.split(b"\n\n") if f and f != b"data: [DONE]"]
+
+
+@pytest.fixture()
+def mock_door():
+    """A door over the REAL scheduler on a chained mock device: a
+    request's output follows from its prompt."""
+    made = []
+
+    def make(slots=4, max_len=1024, **dev_kw):
+        eng = MockEngine(MockDevice(
+            mock_pool(slots=slots, max_len=max_len, block_size=16),
+            chain=True, **dev_kw), max_queue=256)
+        d = FrontDoor(eng)
+        made.append((d, eng))
+        return d, eng, d.start()
+    yield make
+    for d, eng in made:
+        d.close()
+        eng.close()
+
+
+class TestStreamWriter:
+    @pytest.mark.parametrize("eos", [None, "last"])
+    def test_sse_bytes_are_json_dumps_of_each_chunk(self, mock_door, eos):
+        _d, eng, srv = mock_door()
+        prompt, n = [5, 6, 7], 9
+        want = MockDevice.expected(prompt, n)
+        body = {} if eos is None else {"eos_token_id": want[-1]}
+        if eos is not None:         # ends at the first EOS in the output
+            want = want[:want.index(want[-1]) + 1]
+        got = _read_body(_open_stream(srv, prompt, n, **body))
+        assert got == _sse_expected(
+            f"cmpl-{eng.handles[0].id}", want, len(prompt),
+            "length" if eos is None else "stop")
+
+    def test_stalled_client_delays_nobody_and_loses_nothing(self, mock_door):
+        d, eng, srv = mock_door(slots=4)
+        # accepted sockets inherit the listener's buffer: a stream that
+        # nobody reads stalls after a few KB, not after megabytes
+        srv._httpd.socket.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF,
+                                     4096)
+        n = 600                                    # ~90 KB of chunks
+        stalled = _open_stream(srv, [3, 4], n, rcvbuf=2048)
+        others = [_open_stream(srv, [10 + i, 4], n) for i in range(3)]
+        for i, s in enumerate(others):             # whole, while one stalls
+            assert _read_body(s) == _sse_expected(
+                f"cmpl-{_handle_of(eng, [10 + i, 4]).id}",
+                MockDevice.expected([10 + i, 4], n), 2, "length")
+        h = _handle_of(eng, [3, 4])
+        h.result(timeout=30)                       # generated to its end
+        assert d.stats()["stream_writer_deferred"] > 0
+        assert stat_get("serving/stream_writer_deferred") > 0
+        assert _read_body(stalled) == _sse_expected(
+            f"cmpl-{h.id}", MockDevice.expected([3, 4], n), 2, "length")
+
+    def test_disconnect_cancels_the_request(self, mock_door):
+        _d, eng, srv = mock_door(decode_delay=0.002)
+        s = _open_stream(srv, [1, 2], 900)
+        assert s.recv(4096)                         # the stream is live
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                     b"\x01\x00\x00\x00\x00\x00\x00\x00")   # RST at close
+        s.close()
+        h = _handle_of(eng, [1, 2])
+        with pytest.raises(RequestCancelled):
+            h.result(timeout=30)
+        assert 0 < h.emitted < 900
+
+    @pytest.mark.parametrize("how", ["deadline", "cancelled"])
+    def test_terminal_errors_pick_the_finish_reason(self, mock_door, how):
+        _d, eng, srv = mock_door(decode_delay=0.005)
+        body = {"timeout_s": 0.5} if how == "deadline" else {}
+        s = _open_stream(srv, [1, 2], 900, **body)
+        h = _handle_of(eng, [1, 2])
+        if how == "cancelled":
+            while not h.emitted:
+                time.sleep(0.005)
+            h.cancel()
+        frames = _frames(_read_body(s))
+        assert 0 < len(frames) - 1 < 900
+        assert [f["choices"][0]["token_id"] for f in frames[:-1]] \
+            == MockDevice.expected([1, 2], len(frames) - 1) == h.tokens
+        assert frames[-1]["choices"][0]["finish_reason"] == how
+        assert frames[-1]["usage"]["completion_tokens"] == len(frames) - 1
+
+    @pytest.mark.parametrize("path", ["stream", "result", "unary"])
+    def test_without_a_sink_the_handle_keeps_its_queue(self, mock_door,
+                                                       path):
+        _d, eng, srv = mock_door()
+        want = MockDevice.expected([5, 6], 7)
+        if path == "unary":
+            st, doc, _ = _post(srv.url + "/v1/completions",
+                               {"prompt": [5, 6], "max_tokens": 7})
+            assert st == 200 and eng.handles[0].sink is None
+            assert doc["choices"][0]["token_ids"] == want
+            assert doc["choices"][0]["finish_reason"] == "length"
+            return
+        h = eng.submit([5, 6], 7)
+        if path == "stream":
+            assert list(h.stream()) == want
+        else:
+            assert list(h.result(timeout=30)) == [5, 6] + want
+
+    def test_a_sink_takes_a_launch_in_one_put(self):
+        """The scheduler's half alone: every launch is ONE put for all
+        the requests of a sink, a request's end rides with its last
+        token, and ``stream()`` of such a handle refuses."""
+        class Sink:
+            def __init__(self):
+                self.batches = []
+
+            def put(self, batch):
+                self.batches.append(list(batch))
+        sink = Sink()
+        dev = MockDevice(mock_pool(slots=4, max_len=64), chain=True)
+        eng = MockEngine(dev)
+        try:
+            hs = [eng.submit([7 + i], 5 + i, sink=sink) for i in range(4)]
+            for h in hs:
+                h.result(timeout=30)
+        finally:
+            eng.close()
+        assert len(sink.batches) <= len(dev.launches)
+        per = {h: [] for h in hs}
+        for batch in sink.batches:
+            assert len({id(h) for h, it in batch if it is not None}) \
+                == sum(it is not None for h, it in batch)   # a token each
+            for h, it in batch:
+                per[h].append(it)
+        for i, h in enumerate(hs):
+            assert per[h] == MockDevice.expected([7 + i], 5 + i) + [None]
+        with pytest.raises(RuntimeError, match="sink"):
+            next(hs[0].stream())
+
+    def test_backlog_wakes_the_writer_a_launch_not_a_token(self, mock_door):
+        slots, n = 16, 40
+        d, eng, srv = mock_door(slots=slots, decode_delay=0.01)
+        chunks0 = stat_get("serving/stream_writer_chunks")
+        socks = [_open_stream(srv, [2 + i, 9], n) for i in range(slots)]
+        for i, s in enumerate(socks):
+            toks = [f["choices"][0]["token_id"]
+                    for f in _frames(_read_body(s))[:-1]]
+            assert toks == MockDevice.expected([2 + i, 9], n)
+        st = d.stats()
+        assert st["stream_writer_chunks"] == slots * n
+        assert stat_get("serving/stream_writer_chunks") - chunks0 \
+            == slots * n
+        launches = len(eng.device.launches)
+        # a wake a launch (a few more: a put may find the writer awake),
+        # and most launches emit for most of the 16 slots
+        assert st["stream_writer_wakes"] <= launches + 8
+        assert st["stream_writer_chunks"] / st["stream_writer_wakes"] \
+            >= slots / 2
+        assert st["stream_writer_deferred"] == 0
+
+    def test_the_writers_span_is_no_serving_span(self, mock_door):
+        """A span a wake, on the writer's thread — and NOT named
+        ``serving/*``: the benchmark's readers take every such name for
+        a stretch of the scheduler thread (``lib/host_spans.py``)."""
+        from paddle_tpu.profiler import span as P
+        _d, _eng, srv = mock_door(decode_delay=0.002)
+        with P.profile():
+            _read_body(_open_stream(srv, [4, 5], 20))
+            evs = P.events()
+        sched = {e["tid"] for e in evs if e["name"] == "serving/emit"}
+        mine = {e["tid"] for e in evs
+                if e["name"] == "frontdoor/stream_write"}
+        assert len(sched) == 1 and len(mine) == 1 and mine != sched
+        assert not [e["name"] for e in evs if e["tid"] in mine
+                    and e["name"].startswith("serving/")]
+
+    def test_many_streams_whole_and_ordered_under_fast_switching(
+            self, mock_door):
+        """More streams than cores with the interpreter switching
+        threads every 10 us: the inbox is shared by the scheduler, 32
+        handler threads and the writer — a lost or reordered item breaks
+        a stream's token sequence."""
+        slots, n = 32, 60
+        _d, eng, srv = mock_door(slots=slots)
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            socks = [_open_stream(srv, [1 + i, 3], n) for i in range(slots)]
+            bodies = [_read_body(s) for s in socks]
+        finally:
+            sys.setswitchinterval(old)
+        for i, body in enumerate(bodies):
+            assert body == _sse_expected(
+                f"cmpl-{_handle_of(eng, [1 + i, 3]).id}",
+                MockDevice.expected([1 + i, 3], n), 2, "length")
