@@ -31,10 +31,12 @@ them, and adds only ``sum_{e in T, lo <= e < hi} w_e expert_e(x)`` (``w_e``
 normalised over all ``k`` chosen, held or not) to ``shared(x)``. What the
 absent experts would add is left out; nothing stands in for the other
 chips or their exchange. Dropless: the (row, expert) pairs that fall on
-held experts are sorted by expert and go through ``jax.lax.ragged_dot``
-in chunks of ``MOE_PAIR_CHUNK`` pairs or more, as many chunks as the pairs need
-(``lax.while_loop``), so no pair is ever dropped and the work follows
-the pairs, not a capacity.
+held experts are sorted by expert, every expert's rows laid out from a
+multiple of the grouped product's row tile on, and go through
+``jax.lax.ragged_dot`` in trips of whole tiles, as many trips as the rows
+need (:func:`routed_experts`; tile and trip from the shapes:
+:func:`routed_plan`), so no pair is ever dropped and the work follows the
+pairs, not a capacity.
 
 Parameters are made by ``param_init(name, shape, dtype)``, one call a
 parameter in the order of ``named_parameters()``: a 10 GB model is built
@@ -53,24 +55,72 @@ from ..framework.tensor import Parameter, Tensor
 from . import decoder_spec as DS
 
 __all__ = ["AXK1Config", "AXK1ForCausalLM", "yarn_inv_freq",
-           "yarn_attention_scale", "route_top_k", "routed_experts"]
+           "yarn_attention_scale", "route_top_k", "routed_experts",
+           "routed_plan"]
 
-# (row, expert) pairs one trip of the grouped products takes, at least:
-# 128 decode rows choosing 8 of 192 experts put 64 +- 8 pairs on the 12
-# held here, and a 128-row tile is what the TPU's ragged dot then walks
-# once a held expert. A launch of Q rows takes Q / 8 where that is more.
-# More pairs than one trip holds take more trips, never a drop.
-MOE_PAIR_CHUNK = 128
+# Where the grouped products' time goes on a TPU (PERF.md 44, measured on
+# a v5e): ``jax.lax.ragged_dot`` walks its rows in tiles of ``tm`` rows,
+# ``tm`` the largest power of two up to 512 that divides the row count it
+# is handed, and VISITS every (tile, group) pair that meet; a visit
+# streams the group's whole ``[K, N]`` weights and computes the whole
+# tile. A visit of the three products costs about ``1 + tm /
+# TILE_BALANCE_ROWS`` times what its weights take to stream.
+ROW_TILES = (8, 16, 32, 64, 128, 256)
+TILE_BALANCE_ROWS = 400
+REAL_ROW_SHARES = (0.5, 0.625, 0.75, 0.875, 1.0)
+TRIP_BYTES = 80 << 20       # a trip's xs, gate/up, h, o and way back, at most
+GATHER_ROWS_PER_CHOICE = 1024
 
 
-def trip_pairs(pairs: int) -> int:
-    """A trip of ``pairs`` pairs or more in whole ``MOE_PAIR_CHUNK``-row
-    tiles, one at least. A step's rows are no power of two since its
-    tower runs on the launch's real rows (1,152 of them are 144 pairs by
-    the rule above), and a trip that ends inside a tile made the grouped
-    products walk every held expert's weights in narrow tiles: twice the
-    time a pair (PERF.md 41.2)."""
-    return max(1, -(-int(pairs) // MOE_PAIR_CHUNK)) * MOE_PAIR_CHUNK
+def routed_plan(n: int, num_experts: int, rows: int, k: int, E: int,
+                I: int) -> Tuple[int, int, bool]:
+    """``(T, M, by_gather)`` of :func:`routed_experts` from the shapes and
+    nothing else: ``n`` held of ``num_experts`` experts, ``rows`` rows
+    choosing ``k`` each, experts ``[E, I]``.
+
+    ``T``, the rows an expert's group is padded to a multiple of, is the
+    row tile that makes the walk cheapest: a held expert expects ``each =
+    rows k / num_experts`` pairs (binomial: about normal with that
+    variance), so ``tiles(T) = sum_j P(pairs > j T)`` tiles of ``T`` rows,
+    each costing its weights' stream once and, per row, the FLOPs that do
+    not hide behind it (``T / TILE_BALANCE_ROWS``) and the rows' way in
+    and out (``xs``, two f32 products, ``h``, ``o``: ``12 E + 20 I`` bytes
+    against ``6 E I`` of weights). How many of ``rows`` are REAL cannot
+    be seen from here (a ragged batch's pad rows, a block-generation
+    slot's half-filled q block): the tile taken is the one whose cost is
+    least far from the best tile's at every share of ``REAL_ROW_SHARES``.
+
+    ``M``, the rows of a trip, is ``T`` times an ODD number — the
+    compiler's tile is then ``T`` itself, so no tile holds two experts'
+    rows (41.2's rule, a trip ends on a tile, is the case ``M = 128 x
+    odd``) — the smallest that holds the rows a launch of real rows is
+    expected to walk and a spare tile, within ``TRIP_BYTES`` of
+    temporaries (half of them where the way back keeps a buffer of the
+    layout). More rows take more trips.
+
+    ``by_gather``: the outputs go back through a buffer of the whole
+    layout and one gather of ``k`` rows a query row where the layout is
+    long (every expert held: the 0/1 product's FLOPs grow with query rows
+    x rows walked), through the 0/1 product a trip where it is short."""
+    per_row = 1.0 / TILE_BALANCE_ROWS + (12 * E + 20 * I) / (6.0 * E * I)
+
+    def tiles(T, each):
+        """Tiles of ``T`` rows an expert that expects ``each`` pairs takes."""
+        over = lambda v: 0.5 * math.erfc((v - each) / math.sqrt(2.0 * each))
+        return 1.0 + sum(over(j * T) for j in range(1, -(-rows // T) + 1))
+
+    full = max(rows * k / float(num_experts), 1e-9)
+    cost = {f: {T: tiles(T, f * full) * (1.0 + T * per_row)
+                for T in ROW_TILES} for f in REAL_ROW_SHARES}
+    T = min(ROW_TILES, key=lambda T: max(
+        cost[f][T] / min(cost[f].values()) for f in REAL_ROW_SHARES))
+    walked = n * tiles(T, full) * T
+    by_gather = walked > GATHER_ROWS_PER_CHOICE * k
+    bound = TRIP_BYTES // (2 if by_gather else 1)
+    most = max(1, bound // ((8 * E + 10 * I) * T))         # tiles a trip
+    want = int(walked // T) + 2                            # and a spare
+    odd = lambda v: v if v % 2 else v - 1
+    return T, T * max(1, min(odd(most), odd(want + 1))), by_gather
 
 
 def _default_rope_scaling() -> dict:
@@ -272,65 +322,119 @@ def route_top_k(x, router_w, top_k: int, scale: float, norm: bool = True,
     return idx.astype(jnp.int32), top * jnp.float32(scale), scores
 
 
-def routed_experts(x, valid, idx, w, experts, held, pair_chunk: int):
+def routed_experts(x, valid, idx, w, experts, held, num_experts: int):
     """The held experts' part of the routed sum, dropless.
 
     ``x [Q, E]``; ``valid [Q]`` bool (pad rows of a ragged batch are not
-    routed); ``idx``/``w [Q, k]`` from :func:`route_top_k`; ``experts =
-    (gate [n, E, I], up [n, E, I], down [n, I, E])`` the ``n = hi - lo``
-    held experts; ``held = (lo, hi)``. Returns ``(y [Q, E] float32,
-    (pairs, experts_hit, rows))`` with ``y = sum over the row's chosen
-    experts that are held of w_e expert_e(x)`` and the three int32
-    counters of REAL rows only.
+    routed); ``idx``/``w [Q, k]`` from :func:`route_top_k`, which chose
+    among ``num_experts``; ``experts = (gate [n, E, I], up [n, E, I], down
+    [n, I, E])`` the ``n = hi - lo`` held experts; ``held = (lo, hi)``.
+    Returns ``(y [Q, E] float32, (pairs, experts_hit, rows, rows_walked))``
+    with ``y = sum over the row's chosen experts that are held of w_e
+    expert_e(x)`` and four int32 counters: the first three of REAL rows
+    only, the fourth the rows the grouped products were handed (real +
+    pad).
 
-    Pairs on held experts are sorted by expert (stable, so by row inside
-    an expert); chunk ``c`` of ``M`` sorted pairs gathers its rows, runs
-    the three grouped products with the group sizes clipped to the chunk,
-    and adds its weighted outputs back through a 0/1 row-selection
-    product on the MXU (a scatter-add of ``M`` rows of ``E`` lanes is the
-    slow way to the same sum). ``ceil(pairs / M)`` chunks run."""
+    The layout (:func:`routed_plan`: ``T``, ``M`` and the combine, from
+    the shapes alone): pairs on held experts are sorted by expert
+    (stable, so by row inside an expert) and every expert's rows BEGIN ON
+    A MULTIPLE OF ``T``, its group padded to whole tiles with zero rows
+    (``silu(0) * 0 = 0`` and a zero row of ``down`` is 0: the same sum);
+    an expert without a pair gets no tile. Trip ``c`` takes rows ``[c M,
+    (c + 1) M)`` of that layout, ``M = T x odd``, so the grouped products
+    walk it in tiles of ``T`` and no tile holds two experts' rows: an
+    expert's weights are streamed once a tile of its own, whichever trip
+    the tile falls in. ``ceil(rows walked / M)`` trips run. A trip's
+    outputs go back to the query rows either through a 0/1 row-selection
+    product on the MXU (a short layout: a scatter-add of ``M`` rows of
+    ``E`` lanes is the slow way to the same sum) or, where the layout is
+    long, into a buffer of the whole layout from which each query row
+    gathers its ``k`` rows once, after the last trip."""
     import jax
     import jax.numpy as jnp
     gate, up, down = experts
     lo, hi = held
     n = hi - lo
     Q, k = idx.shape
-    M = int(pair_chunk)
+    P = Q * k
+    T, M, by_gather = routed_plan(n, int(num_experts), Q, k, x.shape[1],
+                                  gate.shape[2])
+    i32 = jnp.int32
     on = (idx >= lo) & (idx < hi) & valid[:, None]            # [Q, k]
     flat_e = jnp.where(on, idx - lo, n).reshape(-1)           # n = "not here"
-    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-    counts = jnp.sum(flat_e[:, None] == jnp.arange(n, dtype=jnp.int32),
-                     axis=0, dtype=jnp.int32)                 # [n]
+    order = jnp.argsort(flat_e, stable=True).astype(i32)
+    is_e = flat_e[:, None] == jnp.arange(n, dtype=i32)        # [P, n]
+    counts = jnp.sum(is_e, axis=0, dtype=i32)                 # [n]
     ends = jnp.cumsum(counts)
-    starts = ends - counts
-    pairs = ends[-1]
-    flat_w = jnp.where(on, w, 0.0).reshape(-1)
-    # pad so that the last chunk's slice never runs off the end
-    order = jnp.concatenate([order, jnp.zeros(M, jnp.int32)])
-    rows_iota = jnp.arange(Q, dtype=jnp.int32)[:, None]
+    padded = -(-counts // T) * T
+    pend = jnp.cumsum(padded)                                 # [n]
+    pstart = pend - padded
+    plive = pstart + counts             # an expert's real rows end here
+    shift = pstart - (ends - counts)    # layout position - sorted position
+    pairs, walked = ends[-1], pend[-1]
+    iota_m = jnp.arange(M, dtype=i32)
 
-    def chunk(c, y):
-        a = c * M
-        sel = jax.lax.dynamic_slice(order, (a,), (M,))
-        live = (a + jnp.arange(M, dtype=jnp.int32)) < pairs
-        rows = sel // k
-        sizes = jnp.clip(ends, a, a + M) - jnp.clip(starts, a, a + M)
-        xs = x[rows]                                          # [M, E]
+    def trip_rows(c):
+        """Of trip ``c``: the layout row it starts at, its group sizes,
+        which of its ``M`` rows hold a pair, and those pairs."""
+        a = jnp.asarray(c, i32) * M
+        sizes = jnp.clip(pend, a, a + M) - jnp.clip(pstart, a, a + M)
+        r = (a + iota_m)[:, None]
+        mine = (r >= pstart) & (r < plive)                    # [M, n]
+        live = jnp.any(mine, axis=1)
+        at = a + iota_m - jnp.sum(jnp.where(mine, shift, 0), axis=1)
+        sel = order[jnp.where(live, at, 0)]
+        return a, sizes, live, sel
+
+    def products(xs, sizes):
         rd = lambda l, r: jax.lax.ragged_dot(
             l, r, sizes, preferred_element_type=jnp.float32)
         h = (jax.nn.silu(rd(xs, gate)) * rd(xs, up)).astype(x.dtype)
-        o = rd(h, down)                                       # [M, E] f32
-        # a pair past the last one belongs to no group: whatever the
-        # grouped product left in its row is zeroed, not weighted
-        o = jnp.where(live[:, None], o * flat_w[sel][:, None], 0.0)
-        pick = (rows_iota == rows[None, :]) & live[None, :]   # [Q, M] 0/1
-        return y + jnp.dot(pick.astype(x.dtype), o.astype(x.dtype),
-                           preferred_element_type=jnp.float32)
+        return rd(h, down)                                    # [M, E] f32
 
-    y = jax.lax.fori_loop(jnp.int32(0), (pairs + M - 1) // M, chunk,
-                          jnp.zeros(x.shape, jnp.float32))
-    counters = (pairs, jnp.sum(counts > 0, dtype=jnp.int32),
-                jnp.sum(valid, dtype=jnp.int32))
+    trips = (walked + M - 1) // M
+    if by_gather:
+        # where a pair's row lies in the layout: its place in the sorted
+        # order (the inverse of ``order``) moved by its expert's shift
+        pos = jnp.argsort(order).astype(i32) \
+            + jnp.sum(jnp.where(is_e, shift, 0), axis=1)              # [P]
+        pos = jnp.where(on.reshape(-1), pos, 0)
+
+        def trip(c, buf):
+            a, sizes, live, sel = trip_rows(c)
+            xs = jnp.where(live[:, None], x[sel // k], 0)
+            o = products(xs, sizes).astype(x.dtype)
+            return jax.lax.dynamic_update_slice(buf, o, (a, i32(0)))
+
+        # the longest layout: every pair held, a tile less a row of pad a
+        # group; whole trips of it. Never cleared: the rows read below
+        # are rows a trip wrote, or masked
+        room = -(-((P + min(n, P) * (T - 1)) // T * T) // M) * M
+        buf = jax.lax.fori_loop(
+            i32(0), trips, trip, jax.lax.empty((room, x.shape[1]), x.dtype))
+        got = buf[pos].reshape(Q, k, -1).astype(jnp.float32)
+        y = jnp.sum(jnp.where(on[:, :, None], got * w[:, :, None], 0.0),
+                    axis=1)
+    else:
+        flat_w = jnp.where(on, w, 0.0).reshape(-1)
+        rows_iota = jnp.arange(Q, dtype=i32)[:, None]
+
+        def trip(c, y):
+            _, sizes, live, sel = trip_rows(c)
+            rows = sel // k
+            xs = jnp.where(live[:, None], x[rows], 0)
+            # a row that holds no pair belongs to no sum: whatever the
+            # grouped product left in it is zeroed, not weighted
+            o = jnp.where(live[:, None],
+                          products(xs, sizes) * flat_w[sel][:, None], 0.0)
+            pick = (rows_iota == rows[None, :]) & live[None, :]   # [Q, M] 0/1
+            return y + jnp.dot(pick.astype(x.dtype), o.astype(x.dtype),
+                               preferred_element_type=jnp.float32)
+
+        y = jax.lax.fori_loop(i32(0), trips, trip,
+                              jnp.zeros(x.shape, jnp.float32))
+    counters = (pairs, jnp.sum(counts > 0, dtype=i32),
+                jnp.sum(valid, dtype=i32), walked)
     return y, counters
 
 
@@ -527,7 +631,7 @@ class AXK1RoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                trip_pairs(x.shape[0] // 8))
+                cfg.n_routed_experts)
             with DS.section(DS.SHARED_EXPERT):
                 shared = _swiglu(x, self.shared_gate._data,
                                  self.shared_up._data,

@@ -20,8 +20,9 @@ object with
   ``attn_out(x, a, row_valid) -> (x, counters)`` (output projection,
   residual, FFN; ``row_valid [Q]`` marks the rows of the ragged batch that
   are real, which a routed FFN must not route; ``counters`` is ``None``
-  or the routed layer's three int32 scalars); a layer whose spec has a
-  recurrent STATE also ``mixer(x, layout, state, index) -> (s, state)``
+  or the routed layer's ``ROUTED_COUNTERS`` int32 scalars); a layer whose
+  spec has a recurrent STATE also
+  ``mixer(x, layout, state, index) -> (s, state)``
   (the rows in, the rows' sequence layout — ``ops/ssm.py:SeqLayout`` —
   and the slots' state arrays in, the branch's output and the new state
   out; ``index`` is the layer's place in the state arrays), and its
@@ -105,11 +106,15 @@ from typing import Optional, Tuple
 
 __all__ = ["CacheSpec", "StateSpec", "LayerSpec", "DecoderSpec",
            "GenerationRule", "CacheGroup", "serving_decoder", "FULL",
-           "LATENT", "DENSE", "ROUTED", "SECTIONS", "section", "layer_scope",
-           "section_of"]
+           "LATENT", "DENSE", "ROUTED", "ROUTED_COUNTERS", "SECTIONS",
+           "section", "layer_scope", "section_of"]
 
 FULL, LATENT = "full", "latent"        # attention kinds
 DENSE, ROUTED = "dense", "routed"      # FFN kinds
+# the int32 scalars a routed layer's ``attn_out`` returns, summed over the
+# layers into a launch's result after its sentinel: pairs on held experts,
+# held experts hit, real rows routed, rows the grouped products walked
+ROUTED_COUNTERS = 4
 
 # The SECTIONS of a launch: the one vocabulary of ``jax.named_scope``s the
 # step programs put their device work under, so that every op of a launch

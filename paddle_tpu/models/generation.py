@@ -778,7 +778,7 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     layer gets its group's (its window and sinks from its ``LayerSpec``);
     the pools come back as a tuple too.
     A ``routed`` FFN is told which rows are real (pad rows name the
-    scratch block 0) and returns its three counters, summed over the
+    scratch block 0) and returns its four counters, summed over the
     layers here. A layer whose spec has a recurrent STATE runs its mixer
     beside the attention: ``state`` is the slots' state arrays (one a
     part of the descriptor, ``[layers with state, slots + 1, ...]``), the
@@ -838,7 +838,7 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     counters = None
 
     def summed(counters, c):
-        """A routed layer's three counters added to the layers' before."""
+        """A routed layer's counters added to the layers' before."""
         if c is None:
             return counters
         with DS.section(DS.MOE_SCOPE):
@@ -952,12 +952,13 @@ def block_result_layout(num_slots: int, block_length: int, routed: bool):
     sentinel_at, counters_at, tokens_at, passes_at, size)`` into the one
     int32 array a launch returns — ``[S]`` the position each slot fixed
     last this pass (-1: none), the logits-finite sentinel, a routed
-    model's three counters (the same places as in a one-token step's
+    model's four counters (the same places as in a one-token step's
     result, so the scheduler's readers of those are one), then the block
     state: ``[S * B]`` token ids and ``[S * B]`` the pass each position
     was fixed in."""
+    from .decoder_spec import ROUTED_COUNTERS
     S, B = int(num_slots), int(block_length)
-    tokens_at = S + 1 + (3 if routed else 0)
+    tokens_at = S + 1 + (ROUTED_COUNTERS if routed else 0)
     return (0, S, S + 1, tokens_at, tokens_at + S * B,
             tokens_at + 2 * S * B)
 
@@ -1117,7 +1118,7 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
     state arrays)``: ``PagedKVPool.state_data``, one array a part, a row
     a slot; ``next_tokens``
     ``[num_slots + 1]`` — the last element is the logits-finite sentinel
-    of :func:`_append_nonfinite_flag`; a routed model appends its three
+    of :func:`_append_nonfinite_flag`; a routed model appends its four
     counters):
 
     * ``token_ids``/``qpos``/``write_block``/``write_off`` ``[R]``
@@ -1241,7 +1242,7 @@ def build_fused_step_fn(model, num_slots, q_rows, table_len, block_size,
                     nxt = _append_nonfinite_flag(nxt, logits)
                     if counters is not None:
                         # the routed layers' counters ride the same
-                        # fetch, after the sentinel: [S + 1 : S + 4]
+                        # fetch, after the sentinel: [S + 1 : S + 5]
                         nxt = jnp.concatenate([nxt, counters])
         if quantized:
             return new_pool, new_scales, nxt, key

@@ -54,7 +54,7 @@ from ..framework.tensor import Parameter, Tensor
 from ..ops import ssm as SSM
 from . import decoder_spec as DS
 from .axk1 import (_mm, _param_maker, _params, _rms_norm, _swiglu,
-                   route_top_k, routed_experts, trip_pairs)
+                   route_top_k, routed_experts)
 from .sdar import rope_half_split
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM"]
@@ -269,11 +269,11 @@ class Lfm2RoutedFFN(nn.Layer):
 
     def apply(self, x, valid):
         """``x [Q, E]`` -> ``(the held experts' part of the routed sum,
-        counters)``. Every (row, expert) pair goes through the grouped
-        products in ONE trip, sized from the shapes for all of them."""
+        counters)``. All 64 experts are held: every (row, expert) pair
+        goes through the grouped products, laid out and cut into trips by
+        ``axk1.routed_experts`` from the shapes."""
         cfg = self.cfg
         k = cfg.num_experts_per_tok
-        lo, hi = cfg.experts_held
         with DS.section(DS.MOE_SCOPE):
             with DS.section(DS.ROUTER):
                 idx, w, _ = route_top_k(
@@ -284,8 +284,7 @@ class Lfm2RoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                trip_pairs(-(-x.shape[0] * k * (hi - lo)
-                             // cfg.num_experts)))
+                cfg.num_experts)
         with DS.section(DS.MLP):          # with the add that closes the layer
             return y.astype(x.dtype), counters
 

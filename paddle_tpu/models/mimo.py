@@ -49,7 +49,7 @@ from .. import nn
 from ..framework.tensor import Parameter, Tensor
 from . import decoder_spec as DS
 from .axk1 import (_mm, _param_maker, _params, _rms_norm, _swiglu,
-                   route_top_k, routed_experts, trip_pairs)
+                   route_top_k, routed_experts)
 from .sdar import rope_half_split
 
 __all__ = ["MiMoV2Config", "MiMoV2ForCausalLM", "stored_lanes"]
@@ -285,9 +285,9 @@ class MiMoRoutedFFN(nn.Layer):
 
     def apply(self, x, valid):
         """``x [Q, E]`` -> ``(the held experts' part of the routed sum,
-        counters)``; the grouped products' trip is sized as A.X-K1's is
-        (a sixteenth of the experts are held: ``Q / 8`` pairs hold the
-        ``Q k / 16`` expected with room)."""
+        counters)``; the pairs' layout and the grouped products' trips are
+        ``axk1.routed_experts``' own, from the shapes (a sixteenth of the
+        experts are held: ``Q k / 16`` pairs are expected)."""
         cfg = self.cfg
         with DS.section(DS.MOE_SCOPE):
             with DS.section(DS.ROUTER):
@@ -299,7 +299,7 @@ class MiMoRoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                trip_pairs(x.shape[0] // 8))
+                cfg.n_routed_experts)
         with DS.section(DS.MLP):          # with the add that closes the layer
             return y.astype(x.dtype), counters
 

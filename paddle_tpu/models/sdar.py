@@ -43,7 +43,7 @@ from .. import nn
 from ..framework.tensor import Parameter, Tensor
 from . import decoder_spec as DS
 from .axk1 import (_mm, _param_maker, _params, _rms_norm,
-                   route_top_k, routed_experts, trip_pairs)
+                   route_top_k, routed_experts)
 
 __all__ = ["SDARConfig", "SDARForCausalLM", "rope_half_split"]
 
@@ -199,14 +199,12 @@ class SDARRoutedFFN(nn.Layer):
 
     def apply(self, x, valid):
         """``x [Q, E]`` -> ``(the held experts' part of the routed sum,
-        counters)``. Every (row, expert) pair of a plain launch goes
-        through the grouped products in ONE trip, sized from the shapes:
-        a slot's q block of 8 holds its block of 4, or 8 rows where a
-        finished block's commit rides with the next block's first pass
-        (one pass in four of a slot: 5/8 of a steady launch's rows are
-        real), so three quarters of ``Q * k`` pairs hold them with room.
-        (``Q * k`` itself read 0.2 ms a launch slower on the chip: a
-        trip pays for its rows. PERF.md, PR 34.)"""
+        counters)``. The layout of the pairs and the trips of the grouped
+        products are ``axk1.routed_experts``' own, from the shapes (a
+        slot's q block of 8 holds its block of 4, or 8 rows where a
+        finished block's commit rides with the next block's first pass:
+        5/8 of a steady launch's rows are real, and the trips follow the
+        real pairs)."""
         cfg = self.cfg
         k = cfg.num_experts_per_tok
         with DS.section(DS.MOE_SCOPE):
@@ -218,7 +216,7 @@ class SDARRoutedFFN(nn.Layer):
                 x, valid, idx, w,
                 (self.experts_gate._data, self.experts_up._data,
                  self.experts_down._data), cfg.experts_held,
-                trip_pairs(x.shape[0] * k * 3 // 4))
+                cfg.num_experts)
         with DS.section(DS.MLP):          # with the add that closes the layer
             return y.astype(x.dtype), counters
 

@@ -378,3 +378,50 @@ def test_fused_adamw_compiles_within_vmem(v5e, shape):
     sc = jax.ShapeDtypeStruct((7,), jnp.float32, sharding=v5e)
     fn = pk._fused_adamw_callable(shape, "float32", False)
     assert "fused_adamw" in _compile(fn, a, a, a, a, sc)
+
+
+# (held, experts, rows, k, E, I) of the routed-expert cells' launches
+ROUTED_CELLS = {
+    "lfm2-24b-a2b-pp4": (64, 64, 1152, 4, 2048, 1536),
+    "sdar-30b-a3b-pp8": (128, 128, 1024, 8, 2048, 768),
+    "axk1-ep16": (12, 192, 128, 8, 7168, 2048),
+    "axk1-ep16-chunk": (12, 192, 1152, 8, 7168, 2048),
+    "mimo-v2-flash-ep16": (16, 256, 1152, 8, 4096, 2048),
+}
+
+
+def _ragged_dot_tiles(chip, rows, n, K, N):
+    """The ``tm,tk,tn`` the compiled grouped product names for itself."""
+    import re
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    text = _compile(
+        lambda a, b, s: jax.lax.ragged_dot(
+            a, b, s, preferred_element_type=jnp.float32),
+        sds((rows, K), jnp.bfloat16), sds((n, K, N), jnp.bfloat16),
+        sds((n,), jnp.int32))
+    tiles = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
+    assert tiles, "the grouped product no longer names its tiling"
+    return [tuple(int(v) for v in t) for t in tiles]
+
+
+@pytest.mark.parametrize("cell", list(ROUTED_CELLS))
+def test_the_grouped_products_row_tile_is_the_plans(v5e, cell):
+    """``routed_experts`` starts every expert's rows on a multiple of
+    ``T`` and hands the grouped products ``M = T x odd`` rows a trip
+    BECAUSE the compiler then walks them in tiles of ``T`` (PERF.md 44):
+    if a compiler chooses its tile otherwise, this is where it shows."""
+    from paddle_tpu.models.axk1 import routed_plan
+    n, _, _, _, E, I = ROUTED_CELLS[cell]
+    T, M, _ = routed_plan(*ROUTED_CELLS[cell])
+    for K, N in ((E, I), (I, E)):               # gate | up, and down
+        assert {t[0] for t in _ragged_dot_tiles(v5e, M, n, K, N)} == {T}
+
+
+@pytest.mark.parametrize("rows,tile", [(144, 16), (256, 256), (4608, 512),
+                                       (1152, 128)])
+def test_the_row_tile_is_the_largest_power_of_two_that_divides_the_rows(
+        v5e, rows, tile):
+    """What 41.2 (144 rows: tiles of 16, twice the time) and 42.2 (4,608
+    rows: tiles of 512 over groups of 72) ran into."""
+    assert {t[0] for t in _ragged_dot_tiles(v5e, rows, 16, 2048, 1536)} \
+        == {tile}
