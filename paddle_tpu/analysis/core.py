@@ -336,7 +336,7 @@ def _trace_callable(fn, args, static_argnums=()):
 def _donated_invars(closed, donate_argnums, ranges):
     """Donation mask over the outer jaxpr's invars: the explicit
     donate_argnums argument wins; otherwise auto-detect a single
-    top-level pjit eqn's donated_invars (analyzing an already-jitted fn
+    top-level jit eqn's donated_invars (analyzing an already-jitted fn
     sees its donation contract without being told)."""
     n = len(closed.jaxpr.invars)
     if donate_argnums:
@@ -348,10 +348,10 @@ def _donated_invars(closed, donate_argnums, ranges):
                     mask[i] = True
         return mask
     eqns = closed.jaxpr.eqns
-    if len(eqns) == 1 and eqns[0].primitive.name == "pjit":
+    if len(eqns) == 1 and eqns[0].primitive.name == "jit":
         don = eqns[0].params.get("donated_invars")
         if don and any(don):
-            # map the pjit eqn's donated invars back onto outer invars
+            # map the jit eqn's donated invars back onto outer invars
             outer = {v: i for i, v in enumerate(closed.jaxpr.invars)}
             mask = [False] * n
             for v, d in zip(eqns[0].invars, don):

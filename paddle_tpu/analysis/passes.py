@@ -587,8 +587,8 @@ def sharding_consistency_pass(ctx: AnalysisContext) -> List[Finding]:
       collective-pairing structure, scoped to the sharded region where
       the mesh context makes the message precise (error);
     * an array >= SHARDING_REPLICATED_MIN_BYTES entering the shard_map
-      with a fully-replicated spec (empty in_names) costs its FULL
-      bytes on EVERY device — warning with the per-device cost and the
+      with a fully-replicated spec (no axis in its ``in_specs`` entry)
+      costs its FULL bytes on EVERY device — warning with the per-device cost and the
       saving the largest mesh axis would buy."""
     if ctx.closed_jaxpr is None:
         return []
@@ -662,9 +662,10 @@ def sharding_consistency_pass(ctx: AnalysisContext) -> List[Finding]:
                           "tiling before leaving the shard_map body")))
 
         # (3): large fully-replicated operands
-        in_names = eqn.params.get("in_names") or ()
-        for k, (v, names) in enumerate(zip(eqn.invars, in_names)):
-            if names:                      # partitioned on some axis
+        # a PartitionSpec an operand: an entry a dim, None where replicated
+        in_specs = eqn.params.get("in_specs") or ()
+        for k, (v, spec) in enumerate(zip(eqn.invars, in_specs)):
+            if any(d is not None for d in spec):   # partitioned somewhere
                 continue
             b = aval_bytes(getattr(v, "aval", None))
             if b < SHARDING_REPLICATED_MIN_BYTES:

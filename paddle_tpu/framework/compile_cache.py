@@ -22,12 +22,15 @@ at it.
 The cache is ON by default: ``enable()`` runs at package import unless
 ``FLAGS_compile_cache=0`` (framework/__init__.py), and again in every
 ``bench.py`` child. The one exception is a process pinned to the CPU
-(``JAX_PLATFORMS=cpu`` — the tests, the dry-run canary): its programs
-compile in milliseconds, and XLA:CPU logs two screens of
-machine-feature warnings on every reload of a cached executable, so the
-import hook leaves it off there and only an explicit ``enable()`` arms
-it. An unwritable directory leaves it off with the reason in
-``status()``.
+(``JAX_PLATFORMS=cpu`` — the tests, the dry-run canary): XLA:CPU logs
+two screens of machine-feature warnings on every reload of a cached
+executable, and a CPU run is a rehearsal nobody repeats on the same
+checkout, so the import hook leaves it off there and only an explicit
+``enable()`` arms it. Not because its programs are cheap: a step program
+of interpreted kernels is seconds to compile on the CPU, which is why
+``tests/conftest.py`` arms one cache for a whole test run (its workers
+and children build the same toys). An unwritable directory leaves it off
+with the reason in ``status()``.
 
 Reference analog: the reference caches serialized CUDA autotune/program
 state per machine; jax's compilation cache is the XLA-era equivalent.

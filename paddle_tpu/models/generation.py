@@ -152,8 +152,10 @@ def save_for_serving(model, path, batch, prompt_len, runtime_key=False,
             temp = float(resolved["temperature"])
 
             def _serve_keyed(ids, key):
-                return fn(params, buffers, ids, key, jnp.float32(temp),
-                          jnp.int32(0))
+                # jit.save hands Tensors: jax.random takes a key's data
+                # only as an array (a Tensor's shape is a list)
+                return fn(params, buffers, ids._data, key._data,
+                          jnp.float32(temp), jnp.int32(0))
 
             return jit.save(
                 _serve_keyed, path,

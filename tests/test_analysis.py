@@ -495,12 +495,12 @@ def test_cli_module_target_and_selflint():
     res = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.analysis",
          "__graft_entry__:entry"],
-        env=env, cwd=REPO, capture_output=True, text=True, timeout=300)
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=110)
     assert res.returncode == 0, res.stderr[-1500:]
     assert "clean" in res.stdout or "0 error(s)" in res.stdout
     res = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.analysis", "--selflint"],
-        env=env, cwd=REPO, capture_output=True, text=True, timeout=120)
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=110)
     assert res.returncode == 0, res.stdout[-1500:]
 
 
@@ -1000,7 +1000,7 @@ def test_cli_json_and_budget_gate():
     base = [sys.executable, "-m", "paddle_tpu.analysis",
             "__graft_entry__:entry"]
     res = subprocess.run(base + ["--json"], env=env, cwd=REPO,
-                         capture_output=True, text=True, timeout=300)
+                         capture_output=True, text=True, timeout=110)
     assert res.returncode == 0, res.stderr[-1500:]
     doc = _json.loads(res.stdout)
     assert doc["ok"] is True
@@ -1012,7 +1012,7 @@ def test_cli_json_and_budget_gate():
     # over budget: exit 1, --json unchanged in shape, fits_budget False
     res = subprocess.run(base + ["--json", "--budget", "1"], env=env,
                          cwd=REPO, capture_output=True, text=True,
-                         timeout=300)
+                         timeout=110)
     assert res.returncode == 1, res.stdout
     doc = _json.loads(res.stdout)
     assert doc["fits_budget"] is False and doc["ok"] is False
@@ -1021,7 +1021,7 @@ def test_cli_json_and_budget_gate():
     # generous budget: exit 0 with the human-readable verdict
     res = subprocess.run(base + ["--budget", str(1 << 40)], env=env,
                          cwd=REPO, capture_output=True, text=True,
-                         timeout=300)
+                         timeout=110)
     assert res.returncode == 0, res.stdout
     assert "fits" in res.stdout
 

@@ -19,25 +19,15 @@ from paddle_tpu.models import axk1 as AX
 from paddle_tpu.models.decoder_spec import serving_decoder
 from paddle_tpu.serving import GenerationEngine
 
-SEED = 2 ** 31 + 77
-SCALES = {"gain": 1.0, "norm_std": 0.1, "embed_std": 1.0}
+import _toys
+
+SEED = _toys.SEEDS["axk1"]
 ORDER_OF_SUM = 1e-4        # see the module doc
 
 
 def _model(**over):
     """The ``model`` group of a configuration at toy sizes."""
-    cfg = AX.AXK1Config.tiny()
-    m = {k: getattr(cfg, k) for k in (
-        "vocab_size", "hidden_size", "intermediate_size",
-        "moe_intermediate_size", "num_hidden_layers", "num_attention_heads",
-        "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-        "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
-        "num_experts_per_tok", "n_shared_experts", "first_k_dense_replace",
-        "routed_scaling_factor", "norm_topk_prob", "rms_norm_eps",
-        "rope_theta", "rope_scaling", "max_position_embeddings")}
-    m.update(experts_held=[4, 12], weight_scales=SCALES)
-    m.update(over)
-    return m
+    return _toys.config("axk1", **over)
 
 
 @pytest.fixture(scope="module")
@@ -46,13 +36,13 @@ def model():
 
 
 @pytest.fixture(scope="module")
-def net(model):
-    return F.build_lm(model, SEED, "float32")
+def net():
+    return _toys.seeded("axk1")
 
 
 @pytest.fixture(scope="module")
-def make(model):
-    return F.Weights(SEED, model, "float32")
+def make():
+    return _toys.weights("axk1")
 
 
 def _ids(rows, length, seed=0):
@@ -152,220 +142,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up(model):
                                atol=ORDER_OF_SUM)
 
 
-# -- 4. dropless; pad rows ------------------------------------------------------
-
-def _experts(rng, n, E=64, I=32):
-    return tuple(jnp.asarray(rng.standard_normal(s) / 8, jnp.float32)
-                 for s in ((n, E, I), (n, E, I), (n, I, E)))
-
-
-def _dense_experts(x, idx, w, experts, held):
-    gate, up, down = (np.asarray(a, np.float64) for a in experts)
-    x = np.asarray(x, np.float64)
-    y = np.zeros_like(x)
-    for r in range(x.shape[0]):
-        for e, we in zip(np.asarray(idx[r]), np.asarray(w[r], np.float64)):
-            if held[0] <= e < held[1]:
-                j = e - held[0]
-                g = x[r] @ gate[j]
-                y[r] += we * ((g / (1 + np.exp(-g)) * (x[r] @ up[j])) @ down[j])
-    return y
-
-
-def _forced(monkeypatch, T, M, gather):
-    """``routed_experts`` under the layout ``(T, M, combine)`` whatever
-    the shapes say: toy shapes alone would never fill a second trip."""
-    monkeypatch.setattr(AX, "routed_plan", lambda *shapes: (T, M, gather))
-
-
-@pytest.mark.parametrize("gather", [False, True], ids=["product", "gather"])
-def test_one_expert_gets_every_token_one_gets_none_and_nothing_is_dropped(
-        monkeypatch, gather):
-    rng = np.random.default_rng(4)
-    Q, held = 40, (4, 8)
-    x = jnp.asarray(rng.standard_normal((Q, 64)), jnp.float32)
-    # every row chooses expert 4 and one of 6, 7, 12; nobody chooses 5
-    idx = jnp.asarray(np.stack([np.full(Q, 4), rng.choice([6, 7, 12], Q)], 1),
-                      jnp.int32)
-    w = jnp.asarray(rng.uniform(0.5, 1.5, (Q, 2)), jnp.float32)
-    experts = _experts(rng, 4)
-    want = _dense_experts(x, idx, w, experts, held)
-    # trips of 8 rows: many trips, expert 4 alone fills five; 64: one or two
-    for T, M in ((1, 8), (8, 8), (8, 24), (1, 64), (16, 144)):
-        _forced(monkeypatch, T, M, gather)
-        y, (pairs, hit, rows, walked) = AX.routed_experts(
-            x, jnp.ones(Q, bool), idx, w, experts, held, 16)
-        np.testing.assert_allclose(np.asarray(y), want, atol=ORDER_OF_SUM)
-        on_held = np.asarray(idx)[(np.asarray(idx) >= 4) & (np.asarray(idx) < 8)]
-        assert (int(pairs), int(rows)) == (on_held.size, Q)
-        assert int(hit) == 3                          # 4, 6, 7; never 5
-        # every group padded to whole tiles, none for the expert without a pair
-        assert int(walked) == sum(-(-int(c) // T) * T
-                                  for c in np.bincount(on_held))
-
-
-def _routed_case(name):
-    """``(Q, k, experts, held, idx, valid)`` of a named layout case; rows
-    choose distinct experts unless the case says otherwise. ``T`` is 8."""
-    rng = np.random.default_rng(sum(map(ord, name)))
-    Q, k, N, held, valid = 40, 2, 16, (0, 16), None
-    draw = lambda Q, k, N: np.stack(
-        [rng.permutation(N)[:k] for _ in range(Q)])
-    if name == "no-pair-held":
-        held, idx = (4, 8), draw(Q, k, 4)              # experts 0-3 only
-    elif name == "all-on-one-expert":
-        k, idx = 1, np.full((Q, 1), 5)
-    elif name in ("exactly-T", "T-minus-1", "T-plus-1"):
-        c = {"exactly-T": 8, "T-minus-1": 7, "T-plus-1": 9}[name]
-        k, idx = 1, np.full((Q, 1), 3)                 # expert 2: c rows
-        idx[:c, 0] = 2
-    elif name == "pad-query-rows":
-        idx = draw(Q, k, N)
-        valid = rng.uniform(size=Q) < 0.6
-    elif name == "held-range-inside":
-        held, idx = (5, 11), draw(Q, k, N)
-    elif name == "axk1-ep16":                          # 12 of 192, k 8, Q 128
-        Q, k, N, held = 32, 4, 48, (3, 6)
-        idx = draw(Q, k, N)
-    elif name == "mimo-v2-flash-ep16":                 # 16 of 256, k 8
-        Q, k, N, held = 48, 4, 64, (0, 4)
-        idx = draw(Q, k, N)
-    elif name == "sdar-30b-a3b-pp8":                   # all 128, k 8, 5/8 real
-        Q, k, N, held = 64, 4, 32, (0, 32)
-        idx = draw(Q, k, N)
-        valid = np.arange(Q) % 8 < 5
-    elif name == "lfm2-24b-a2b-pp4":                   # all 64, k 4
-        Q, k, N, held = 72, 2, 16, (0, 16)
-        idx = draw(Q, k, N)
-    else:
-        raise KeyError(name)
-    valid = np.ones(Q, bool) if valid is None else valid
-    return Q, k, N, held, idx.astype(np.int32), valid
-
-
-ROUTED_CASES = ["no-pair-held", "all-on-one-expert", "exactly-T", "T-minus-1",
-                "T-plus-1", "pad-query-rows", "held-range-inside",
-                "axk1-ep16", "mimo-v2-flash-ep16", "sdar-30b-a3b-pp8",
-                "lfm2-24b-a2b-pp4"]
-# the layout forced on a case — one trip, two, three, a trip a tile, no
-# alignment, each under both combines — and what the shapes themselves say
-ROUTED_PLANS = {"one-trip": (8, 1096), "two-trips": 2, "three-trips": 3,
-                "tile-trips": (8, 8), "unaligned": (1, 24)}
-ROUTED_LAYOUTS = [(plan, gather) for plan in ROUTED_PLANS
-                  for gather in (False, True)] + [("own-rule", None)]
-
-
-@pytest.mark.parametrize(
-    "plan,gather", ROUTED_LAYOUTS,
-    ids=[p + {False: "-product", True: "-gather", None: ""}[g]
-         for p, g in ROUTED_LAYOUTS])
-@pytest.mark.parametrize("case", ROUTED_CASES)
-def test_the_aligned_layout_is_the_dense_per_expert_sum(monkeypatch, case,
-                                                        plan, gather):
-    """Whatever the layout — tile, rows a trip, combine — the held experts'
-    part is the sum a loop over rows and experts gives, the counters count
-    real rows only, and the rows walked are every group's whole tiles."""
-    Q, k, N, held, idx, valid = _routed_case(case)
-    rng = np.random.default_rng(7)
-    x = jnp.asarray(rng.standard_normal((Q, 64)), jnp.float32)
-    w = jnp.asarray(rng.uniform(0.5, 1.5, (Q, k)), jnp.float32)
-    experts = _experts(rng, held[1] - held[0])
-    routed = np.where(valid[:, None], idx, -1)
-    on_held = routed[(routed >= held[0]) & (routed < held[1])] - held[0]
-    counts = np.bincount(on_held, minlength=1)
-    if plan == "own-rule":
-        T, M, _ = AX.routed_plan(held[1] - held[0], N, Q, k, 64, 32)
-    else:
-        trips = ROUTED_PLANS[plan]
-        T, M = trips if isinstance(trips, tuple) else (
-            8, 8 * max(1, -(-int(sum(-(-counts // 8))) // trips)))
-        _forced(monkeypatch, T, M, gather)
-    y, counters = jax.jit(
-        lambda *a: AX.routed_experts(*a, held, N))(
-            x, jnp.asarray(valid), jnp.asarray(idx), w, experts)
-    want = _dense_experts(x, routed, w, experts, held)
-    np.testing.assert_allclose(np.asarray(y), want, atol=ORDER_OF_SUM)
-    assert np.all(np.asarray(y)[~valid] == 0.0)
-    assert [int(c) for c in counters] == [
-        on_held.size, int(np.sum(counts > 0)), int(valid.sum()),
-        int(sum(-(-counts // T) * T))]
-
-
-# (held, experts, rows, k, E, I) of the routed-expert cells' launches
-# (benchmark/configs; rows = the tower rows of their programs) -> the plan
-CELL_PLANS = {
-    "lfm2-24b-a2b-pp4": ((64, 64, 1152, 4, 2048, 1536), (128, 1152, True)),
-    "sdar-30b-a3b-pp8": ((128, 128, 1024, 8, 2048, 768), (64, 1728, True)),
-    "sdar-30b-a3b-pp8-chunk": ((128, 128, 2048, 8, 2048, 768),
-                               (128, 1664, True)),
-    "axk1-ep16": ((12, 192, 128, 8, 7168, 2048), (16, 240, False)),
-    "axk1-ep16-chunk": ((12, 192, 1152, 8, 7168, 2048), (64, 960, False)),
-    "mimo-v2-flash-ep16": ((16, 256, 1152, 8, 4096, 2048), (64, 1216, False)),
-}
-
-
-def _compilers_tile(rows):
-    """The row tile the TPU's ragged dot walks ``rows`` rows in: the
-    largest power of two up to 512 that divides them (PERF.md 44; held to
-    the compiler itself in tests/test_tpu_compile.py)."""
-    return min(512, rows & -rows)
-
-
-@pytest.mark.parametrize("cell", list(CELL_PLANS))
-def test_the_plan_is_a_function_of_shapes_and_a_trip_ends_on_its_tile(cell):
-    shapes, want = CELL_PLANS[cell]
-    T, M, by_gather = AX.routed_plan(*shapes)
-    assert (T, M, by_gather) == want == AX.routed_plan(*shapes)
-    n, N, rows, k, E, I = shapes
-    # 41.2: a trip of 144 pairs was walked in tiles of 16. A trip is whole
-    # tiles of T, and T is the tile the compiler walks it in: an expert's
-    # rows begin on a tile and no tile holds two experts' rows
-    assert T in AX.ROW_TILES and M % T == 0 and _compilers_tile(M) == T
-    # the tile holds what an expert expects (a launch's rows x k over the
-    # experts) with room, and is not the next size up from one that would
-    each = rows * k / N
-    assert each <= T <= max(AX.ROW_TILES[0], 4 * each)
-    # issue 44, step 4: a trip's temporaries within the bound, half of it
-    # where the way back keeps a buffer of the layout beside them
-    assert M * (8 * E + 10 * I) <= AX.TRIP_BYTES // (2 if by_gather else 1)
-    # a short layout (a share of the experts held) takes ONE trip: a tile
-    # an expert and a spare fit it
-    assert by_gather or M > n * T
-
-
-@pytest.mark.parametrize("rows", [8, 64, 128, 144, 640, 1024, 1152, 2048,
-                                  4096])
-@pytest.mark.parametrize("n,N,k,E,I", [(12, 192, 8, 7168, 2048),
-                                       (64, 64, 4, 2048, 1536),
-                                       (128, 128, 8, 2048, 768),
-                                       (4, 16, 4, 64, 32)])
-def test_every_plan_walks_whole_tiles_of_its_own(rows, n, N, k, E, I):
-    T, M, _ = AX.routed_plan(n, N, rows, k, E, I)
-    assert T in AX.ROW_TILES and M >= T and _compilers_tile(M) == T
-
-
-def test_pad_rows_change_neither_outputs_nor_counters():
-    rng = np.random.default_rng(5)
-    held = (0, 4)
-    experts = _experts(rng, 4)
-    x = jnp.asarray(rng.standard_normal((6, 64)), jnp.float32)
-    idx = jnp.asarray(rng.integers(0, 6, (6, 2)), jnp.int32)
-    w = jnp.asarray(rng.uniform(0.5, 1.5, (6, 2)), jnp.float32)
-    y, counters = AX.routed_experts(x, jnp.ones(6, bool), idx, w, experts,
-                                    held, 6)
-    # the same rows scattered among pad rows that "choose" held experts
-    at = np.asarray([0, 3, 8, 9, 17, 23])
-    big = lambda a, fill: jnp.full((24,) + a.shape[1:], fill, a.dtype
-                                   ).at[at].set(a)
-    valid = jnp.zeros(24, bool).at[at].set(True)
-    y2, counters2 = AX.routed_experts(big(x, 7.0), valid, big(idx, 1),
-                                      big(w, 1.0), experts, held, 6)
-    np.testing.assert_allclose(np.asarray(y2)[at], np.asarray(y),
-                               atol=ORDER_OF_SUM)
-    assert np.all(np.asarray(y2)[~np.asarray(valid)] == 0.0)
-    # the rows walked may differ (another row count, another tile): the
-    # three counters of real rows do not
-    assert [int(c) for c in counters2[:3]] == [int(c) for c in counters[:3]]
+# -- 4. dropless; pad rows: test_axk1_experts.py -----------------------------
 
 
 # -- 5. serving through the latent paged cache ---------------------------------
@@ -411,6 +188,11 @@ def test_chunked_prefill_then_decode_agrees_with_the_reference(
     assert all(c["kv_row_tokens"] >= c["launch_rows"] for c in launch)
 
 
+# the one engine of the tests that only serve a few requests: two slots,
+# contexts of up to six blocks of 8, chunks of at most 16 tokens
+TWO_SLOTS = dict(num_slots=2, max_len=48, block_size=8, prefill_budget=16)
+
+
 def test_a_preempted_request_resumes_and_still_agrees(net, make, model):
     """Two requests whose growth exceeds six blocks: the younger is
     preempted, re-admitted and replayed through chunks; both stay the
@@ -429,11 +211,11 @@ def test_a_preempted_request_resumes_and_still_agrees(net, make, model):
     assert eng._pool.blocks_in_use == 0
 
 
-def test_a_cow_copy_moves_a_latent_block_in_every_layer(net):
+def test_a_cow_copy_moves_a_latent_block_in_every_layer(net, engines):
     """Paging, COW and the prefix trie work on block ids: the engine's
     copy program clones block ``src`` over ``dst`` across every layer of
     the latent pool ``[L, NB + 1, 1, bs, lanes]`` as of any other."""
-    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8)
+    eng = engines(net, **TWO_SLOTS)     # fresh here: the file's first use
     pool = eng._pool
     assert pool.shape == (3, pool.num_blocks + 1, 1, 8, 128)
     list(eng.submit(_ids(1, 20, seed=8)[0].tolist(), 2).stream())
@@ -443,20 +225,19 @@ def test_a_cow_copy_moves_a_latent_block_in_every_layer(net):
     assert np.abs(before[:, src]).sum() > 0 and not before[:, dst].any()
     eng._run_copy(dst, src)
     after = np.asarray(pool.data)
-    eng.close()
     np.testing.assert_array_equal(after[:, dst], before[:, src])
     np.testing.assert_array_equal(after[:, src], before[:, src])
 
 
-def test_a_shared_prefix_is_served_from_the_trie(net, make, model):
+def test_a_shared_prefix_is_served_from_the_trie(net, make, model, engines):
     pre = _ids(1, 24, seed=9)[0].tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=48, block_size=8,
-                           prefill_budget=16)
+    eng = engines(net, **TWO_SLOTS)
+    before = eng.stats()
     first = [int(t) for t in eng.submit(pre + [5, 6], 4).stream()]
     again = [int(t) for t in eng.submit(pre + [7, 8, 9], 4).stream()]
     st = eng.stats()
-    eng.close()
-    assert st["prefix_hits"] >= 1 and st["prefill_tokens_saved"] >= 16
+    assert st["prefix_hits"] - before["prefix_hits"] >= 1
+    assert st["prefill_tokens_saved"] - before["prefill_tokens_saved"] >= 16
     assert float(_gaps(make, model, pre + [5, 6], first).max()) < ORDER_OF_SUM
     assert float(_gaps(make, model, pre + [7, 8, 9], again).max()) \
         < ORDER_OF_SUM
@@ -465,13 +246,12 @@ def test_a_shared_prefix_is_served_from_the_trie(net, make, model):
 # -- the decoder spec and the refusals ------------------------------------------
 
 def test_the_decoder_spec_describes_both_models(net):
-    from paddle_tpu.models import GPTConfig, GPTForPretraining
     ax = serving_decoder(net).spec
     assert ax.attention == "latent"
     assert [ls.ffn for ls in ax.layers] == ["dense", "routed", "routed"]
     assert (ax.cache.rows, ax.cache.lanes, ax.cache.v_aliases_k,
             ax.cache.v_lanes) == (1, 128, True, 32)
-    gpt = serving_decoder(GPTForPretraining(GPTConfig.tiny())).spec
+    gpt = serving_decoder(_toys.default("gpt2")).spec
     assert gpt.attention == "full"
     assert {ls.ffn for ls in gpt.layers} == {"dense"}
     assert (gpt.cache.rows, gpt.cache.lanes, gpt.cache.v_aliases_k) \

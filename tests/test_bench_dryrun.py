@@ -4,18 +4,80 @@ One tiny CPU train step under profiler.profile() must emit a metrics
 summary (counters non-empty), a chrome trace with >= 3 nested span
 categories, and a Prometheus exposition — the cheap canary that an
 instrumentation regression trips BEFORE it costs a real benchmark round.
-Runs in a subprocess like the real driver invocation; kept inside the
-tier-1 ``-m 'not slow'`` budget (one interpreter + jax-cpu startup).
+Runs in a subprocess like the real driver invocation. Since PR 45 the
+canary is ``slow`` (outside tier-1): it took 229-282 s against the 120 s
+every test has (``conftest.TEST_LIMIT_S``) and its own 300 s. It is a
+second benchmark's canary (ROADMAP D4 removes ``bench.py``), and tier-1
+holds each thing it asserts in the process of a test:
+
+* the windowed host syncs of ``fit`` and the prefetch put / wait
+  histograms, the compile cache's entries: ``test_async_fit.py``;
+* the analyzer on the GPT-2 / ResNet zoo steps and the fit pre-flight,
+  ``dispatch/retrace_cause``, the self-lint at zero findings:
+  ``test_analysis.py``, ``test_selflint.py``;
+* serving parity with ``generate``, live counters, prefix hits, chunked
+  prefill under a small budget, one trace a ``(q, table)`` bucket, the
+  step analysed clean: ``test_serving_engine.py``,
+  ``test_serving_paging.py``, ``test_ragged_attention.py``; speculative
+  parity, ``serving/spec_accept`` and int8 blocks: ``test_spec_decode.py``,
+  ``test_serving_paging.py::TestQuantizedBlocks``;
+* request traces, TTFT / TPOT, the flight recorder: ``test_serving_trace.py``;
+  the ops server's ``/metrics``, ``/healthz``, ``/tracez`` and goodput:
+  ``test_ops_server.py``; the front door's round trip, SSE, 429 and a
+  malformed body: ``test_frontdoor.py``;
+* compile cost in the registry, ``hapi/mfu`` and FLOPs a token:
+  ``test_program_registry.py``; the HBM ledger: ``test_memory_tracker.py``;
+* the numerics sentinel, its postmortem and its zero extra programs:
+  ``test_numerics.py``; ZeRO parity and sharded optimizer bytes:
+  ``test_zero_sharding.py``; mp=2 serving parity and per-device KV bytes:
+  ``test_serving_sharded.py``; the host tier's hits, promotions and hit
+  split: ``test_host_tier.py``; the planner's cross-check and its gate
+  before any compile: ``test_plan_gate.py``, ``test_analysis.py``;
+* the exports (chrome trace span categories, Prometheus text):
+  ``test_profiler.py``, ``test_metrics_registry.py``;
+* ``bench.py --compare``, which only the canary ran: the test below.
 """
 import json
 import os
 import subprocess
 import sys
 
+import pytest
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.join(os.path.dirname(HERE), "bench.py")
 
 
+def test_compare_gate_flags_a_doctored_artifact(tmp_path):
+    """``bench.py --compare OLD NEW`` as a driver would call it: a copy
+    with a fifth of the throughput gone and latency up two fifths exits 1,
+    an artifact against itself exits 0 (the parent entry imports no jax:
+    milliseconds a child)."""
+    seeded = {"metric": "gpt2_tps", "value": 100.0, "unit": "tokens/sec",
+              "extras": {
+                  "gpt2": {"metric": "gpt2_tps", "value": 100.0,
+                           "unit": "tokens/sec", "mfu": 0.40},
+                  "serve": {"metric": "serve_lenet_latency_p50_ms",
+                            "value": 10.0, "unit": "ms"}}}
+    doctored = json.loads(json.dumps(seeded))
+    doctored["extras"]["gpt2"]["value"] = 80.0
+    doctored["extras"]["serve"]["value"] = 14.0
+    (tmp_path / "a.json").write_text(json.dumps(seeded))
+    (tmp_path / "b.json").write_text(json.dumps(doctored))
+
+    def compare(new):
+        return subprocess.run(
+            [sys.executable, BENCH, "--compare", str(tmp_path / "a.json"),
+             str(tmp_path / new)], capture_output=True, text=True,
+            timeout=60)
+
+    same, worse = compare("a.json"), compare("b.json")
+    assert same.returncode == 0, same.stdout + same.stderr
+    assert worse.returncode == 1, worse.stdout + worse.stderr
+    assert "gpt2" in worse.stdout and "serve" in worse.stdout
+
+
+@pytest.mark.slow          # see the module doc for what covers it
 def test_dry_run_emits_metrics_summary():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     res = subprocess.run(

@@ -14,7 +14,11 @@ import paddle_tpu.nn.functional as F
 def test_understand_sentiment_bilstm():
     """Synthetic sentiment: class = whether token 7 appears. A
     variable-length biLSTM + max-pool classifier must beat 90% on its
-    training set within a few epochs."""
+    training set within a few epochs. Twenty eager steps at a rate of
+    2e-2 (it reads 100% from the fifteenth on, a loss of 0.02 at the
+    twentieth; sixty at 5e-3 were two minutes of eager LSTM steps). The
+    eager backward of an RNN under ``sequence_length`` is
+    ``test_rnn_sequence_length.py``'s."""
     paddle.seed(0)
     rng = np.random.RandomState(0)
     V, T, N = 20, 12, 64
@@ -37,11 +41,11 @@ def test_understand_sentiment_bilstm():
             return self.fc(h.max(axis=1))
 
     net = Net()
-    opt = paddle.optimizer.Adam(learning_rate=5e-3,
+    opt = paddle.optimizer.Adam(learning_rate=2e-2,
                                 parameters=net.parameters())
     x_t, l_t = paddle.to_tensor(xs), paddle.to_tensor(lens)
     y_t = paddle.to_tensor(ys)
-    for _ in range(60):
+    for _ in range(20):
         loss = F.cross_entropy(net(x_t, l_t), y_t)
         loss.backward()
         opt.step()

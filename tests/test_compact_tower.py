@@ -11,7 +11,8 @@ Layout contract, ``serving/engine.py:_tower_rows``).
 * a launch that mixes prompt chunks, decode rows and an absent slot gives,
   through the compact step, the tokens, the pool and the state the padded
   layout gives — the padded twin is the SAME engine code with ``R == Q``
-  forced;
+  forced (a family at a time in ``tests/test_compact_tower_twins.py``;
+  the verify step, int8 blocks and block generation here);
 * where ``R(Q) == Q`` nothing moves: block generation of 8 rows a slot,
   a ``mesh=`` engine.
 
@@ -25,7 +26,7 @@ import pytest
 
 import paddle_tpu.ops.ragged_paged_attention as rpa
 from paddle_tpu.ops.ragged_paged_attention import BLOCK_Q, tower_rows
-from test_state_pool import _toy as _stateless_toy   # gpt2, axk1, sdar, mimo
+import _toys
 
 # (slots, chunk budget, most real rows a decode slot holds) of the
 # configurations of BENCHMARK.json (configs/*.json `serving`, the traffic
@@ -155,83 +156,6 @@ def small_multiple(monkeypatch):
     monkeypatch.setattr(rpa, "TOWER_ROW_MULTIPLE", 8)
 
 
-def _toy(family):
-    if family != "falcon_h1":
-        return _stateless_toy(family)
-    from paddle_tpu.models.falcon_h1 import (FalconH1Config,
-                                             FalconH1ForCausalLM)
-    return FalconH1ForCausalLM(FalconH1Config.tiny())
-
-
-# three requests in slots 0, 1 and 3 of four (slot 2 stays absent): two
-# launches of chunks (the second beside a decode row), then two of decode
-# rows only — no context crosses into a third block, so one table bucket
-PROMPTS = {0: 14, 1: 3, 3: 11}
-LAUNCHES = [{0: 9, 1: 3, 3: 4}, {0: 5, 1: 1, 3: 7}, {0: 1, 1: 1, 3: 1},
-            {0: 1, 1: 1, 3: 1}]
-
-
-def _drive(net, padded_twin):
-    """The launches above through ``engine._run_fused_step`` with the
-    scheduler's bookkeeping done by hand (positions advance, the feed
-    drains, a slot whose feed is drained takes its token). Returns the
-    tokens each launch gave the slots that got one, the ``(Q, R)`` of the
-    launches and the pool's arrays at the end."""
-    from paddle_tpu.serving import GenerationEngine
-    from paddle_tpu.serving.scheduler import GenerationRequest
-    eng = GenerationEngine(net, num_slots=4, max_len=64, block_size=8,
-                           prefill_budget=16)
-    try:
-        if padded_twin:
-            eng._tower_rows = lambda Q: int(Q)
-        pool = eng._pool
-        reqs = {}
-        for slot in range(4):
-            assert pool.alloc() == slot
-        for slot, n in PROMPTS.items():
-            reqs[slot] = GenerationRequest(
-                (np.arange(n) * 7 + 3 * slot + 1) % 50 + 2, 8)
-            eng._run_admit(reqs[slot], slot)
-        tokens, shapes = [], []
-        for plan in LAUNCHES:
-            for slot, n in plan.items():
-                pool.ensure_writable_range(slot, pool.slot_pos(slot) + n - 1)
-            Q, _, ops, *_ = eng._ragged_operands(reqs, plan)
-            shapes.append((Q, int(ops[0].shape[0])))
-            toks = np.asarray(eng._run_fused_step(reqs, plan))
-            got = {}
-            for slot, n in plan.items():
-                req = reqs[slot]
-                pool.advance(slot, n)
-                del req.pending_feed[:n]
-                if not req.pending_feed:
-                    req.last_token = got[slot] = int(toks[slot])
-            tokens.append(got)
-        blocks = [np.asarray(a, np.float32)[:, 1:] for a in pool.group_data]
-        state = [np.asarray(a)[:, :4] for a in pool.state_data]
-        return tokens, shapes, blocks, state
-    finally:
-        eng.close()
-
-
-@pytest.mark.parametrize("family", ["gpt2", "axk1", "mimo", "falcon_h1"])
-def test_a_mixed_launch_through_the_compact_step_is_the_padded_one(
-        family, small_multiple):
-    net = _toy(family)
-    tokens, shapes, blocks, state = _drive(net, padded_twin=False)
-    t_tokens, t_shapes, t_blocks, t_state = _drive(net, padded_twin=True)
-    # the chunk launches move a bucket up and run 24 tower rows under 64
-    # kernel rows, the decode launches 8 under 32; the twin runs Q rows
-    assert shapes == [(64, 24), (64, 24), (32, 8), (32, 8)]
-    assert t_shapes == [(32, 32), (32, 32), (32, 32), (32, 32)]
-    assert [sorted(t) for t in tokens] == [[1], [0, 1, 3], [0, 1, 3],
-                                           [0, 1, 3]]
-    assert tokens == t_tokens
-    for a, b in zip(blocks + state, t_blocks + t_state):
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
-    assert state or family != "falcon_h1"
-
-
 def test_the_row_axes_of_a_launch(small_multiple):
     """``_row_axes`` from the kernel's metadata alone: slots' real rows
     back to back, pad kernel rows read past the end, pad tower rows are
@@ -265,7 +189,7 @@ def test_blocks_of_eight_rows_fill_the_kernels_rows(small_multiple):
     slots' blocks are the 16 kernel rows and a chunk the rest — every
     bucket the engine launches traces the one-axis program."""
     from paddle_tpu.serving import GenerationEngine
-    eng = GenerationEngine(_toy("sdar"), num_slots=2, max_len=32,
+    eng = GenerationEngine(_toys.default("sdar"), num_slots=2, max_len=32,
                            block_size=8)
     try:
         assert eng._decoder_spec.generation.block_length == 4
@@ -284,7 +208,7 @@ def test_block_generation_on_fewer_tower_rows_is_the_padded_one(
     many) the block step runs on its own axis too: same tokens, fixed in
     the same order, as the padded twin."""
     from paddle_tpu.serving import GenerationEngine
-    net = _toy("sdar")
+    net = _toys.default("sdar")
     outs = []
     for twin in (False, True):
         eng = GenerationEngine(net, num_slots=4, max_len=64, block_size=16,
@@ -296,9 +220,10 @@ def test_block_generation_on_fewer_tower_rows_is_the_padded_one(
                 assert eng._tower_rows(32) == 32 and \
                     eng._tower_rows(64) == 48
             # three short prompts and a long one behind them: its chunks
-            # ride beside three slots' blocks (24 + 16 rows: Q 64, R 48)
-            hs = [eng.submit((np.arange(n) * 5 + 1) % 40 + 2, 12)
-                  for n in (9, 6, 11, 36)]
+            # ride beside three slots' blocks (24 + 16 rows: Q 64, R 48);
+            # no context passes two cache blocks of 16 (tables of 1 and 2)
+            hs = [eng.submit((np.arange(n) * 5 + 1) % 40 + 2, 8)
+                  for n in (9, 6, 11, 24)]
             outs.append([[int(t) for t in h.stream()] for h in hs])
             eng.close()
             recs = eng.flight_recorder.snapshot()["cycles"]
@@ -322,8 +247,11 @@ def test_the_verify_step_and_int8_blocks_on_the_towers_own_axis(
     append is an XLA scatter with a target a row: no K|V gather). Greedy
     tokens through the compact programs are the padded twin's."""
     from paddle_tpu.serving import GenerationEngine
-    net = _toy("gpt2")
-    prompts = [(np.arange(n) * 5 + 3 * n) % 40 + 2 for n in (13, 4, 21, 7, 9)]
+    net = _toys.default("gpt2")
+    # four requests on four slots, one of them in two chunks (21 tokens at
+    # a budget of 16), six tokens each: chunk rows, decode rows and the
+    # verify launch's candidate rows all ride in launches of both kinds
+    prompts = [(np.arange(n) * 5 + 3 * n) % 40 + 2 for n in (13, 4, 21, 7)]
     outs = []
     for twin in (False, True):
         eng = GenerationEngine(net, num_slots=4, max_len=64,
@@ -331,7 +259,7 @@ def test_the_verify_step_and_int8_blocks_on_the_towers_own_axis(
         try:
             if twin:
                 eng._tower_rows = lambda Q: int(Q)
-            hs = [eng.submit(p, max_new_tokens=10) for p in prompts]
+            hs = [eng.submit(p, max_new_tokens=6) for p in prompts]
             outs.append([h.result(timeout=600).tolist() for h in hs])
             eng.close()
             rows = {(r["launch_q"], r["launch_tower_rows"])
@@ -349,8 +277,8 @@ def test_a_mesh_engine_keeps_one_axis():
 
     from paddle_tpu.serving import GenerationEngine
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("mp",))
-    eng = GenerationEngine(_toy("gpt2"), num_slots=2, max_len=32,
-                           block_size=8, mesh=mesh)
+    eng = GenerationEngine(_toys.new_default("gpt2"), num_slots=2,
+                           max_len=32, block_size=8, mesh=mesh)
     try:
         for Q in (8, 32, 256, 1024):
             assert eng._tower_rows(Q) == Q
@@ -364,7 +292,7 @@ def test_a_launch_counts_its_tower_rows(small_multiple):
     and ``launch_q``, and the two monitors sum them."""
     from paddle_tpu.framework import monitor
     from paddle_tpu.serving import GenerationEngine
-    eng = GenerationEngine(_toy("gpt2"), num_slots=4, max_len=64,
+    eng = GenerationEngine(_toys.default("gpt2"), num_slots=4, max_len=64,
                            block_size=8, prefill_budget=16)
     try:
         monitor.stat_reset("serving/launch_rows")

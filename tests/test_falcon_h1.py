@@ -10,39 +10,32 @@ another order differ by ~1e-6 of a unit-RMS value, so 1e-4 on logits of
 spread 1 and on states is two orders of room and still two under what
 bfloat16 anywhere would give.
 """
-import json
-import os
-
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from benchmark.lib import family_falcon_h1 as F
 from benchmark.lib import reference_falcon_h1 as R
 from paddle_tpu.models import decoder_spec as DS
-from paddle_tpu.models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
+from paddle_tpu.models.falcon_h1 import FalconH1Config
 from paddle_tpu.ops import ssm as SSM
 from paddle_tpu.serving import GenerationEngine
 
-SEED = 2 ** 31 + 40
-ORDER_OF_SUM = 1e-4        # see the module doc
+import _toys
 
-with open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "tests", "data",
-        "tiny-falcon-h1-config.json")) as _f:
-    TOY = json.load(_f)["model"]
+ORDER_OF_SUM = 1e-4        # see the module doc
+TOY = _toys.config("falcon_h1")
 
 
 @pytest.fixture(scope="module")
 def net():
-    return F.build_lm(TOY, SEED, "float32")
+    return _toys.seeded("falcon_h1")
 
 
 @pytest.fixture(scope="module")
 def make():
-    return F.Weights(SEED, TOY, "float32")
+    return _toys.weights("falcon_h1")
 
 
 def _ids(n, seed=0):
@@ -371,14 +364,15 @@ def test_a_reused_slot_starts_from_zero_with_the_late_row_in_the_air(
 
 
 def test_a_preempted_request_resumes_by_refeed_to_the_same_tokens(net, make):
-    """Two requests that outgrow eight blocks: the younger is preempted —
-    its state row is simply abandoned — re-admitted and re-fed from
-    position 0 (prompt + what it had generated, in chunks); both stay the
-    reference's own text."""
+    """Two requests that outgrow four blocks (contexts of 23 and 25
+    tokens: three and four blocks of 8, tables of 1, 2 and 4): the younger
+    is preempted — its state row is simply abandoned — re-admitted and
+    re-fed from position 0 (prompt + what it had generated, in chunks);
+    both stay the reference's own text."""
     pa, pb = _ids(9, seed=61).tolist(), _ids(11, seed=62).tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=64, block_size=8,
-                           num_blocks=8, prefill_budget=16)
-    ha, hb = eng.submit(pa, 36), eng.submit(pb, 36)
+    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8,
+                           num_blocks=4, prefill_budget=16)
+    ha, hb = eng.submit(pa, 14), eng.submit(pb, 14)
     oa = [int(t) for t in ha.stream()]
     ob = [int(t) for t in hb.stream()]
     preempts = eng.stats()["preempts"]

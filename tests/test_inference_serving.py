@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.inference import BatchingEngine, Config
+from paddle_tpu.inference import BatchingEngine, Config, create_predictor
 
 
 class _EchoPredictor:
@@ -168,8 +168,7 @@ class TestRuntimeKeyedSamplingExport:
                        temperature=0.8, seed=1).numpy()
         np.testing.assert_array_equal(o1, ref)
         # the C-API-compatible Predictor serves the two-input artifact
-        pred = inference.create_predictor(
-            Config(path + ".pdmodel"))
+        pred = create_predictor(Config(path + ".pdmodel"))
         np.testing.assert_array_equal(
             np.asarray(pred.run([ids, k1])[0]), o1)
 

@@ -125,7 +125,7 @@ class TestJitSaveLoad:
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
         proc = subprocess.run([sys.executable, "-c", code], env=env,
-                              capture_output=True, text=True, timeout=300)
+                              capture_output=True, text=True, timeout=110)
         assert proc.returncode == 0, proc.stderr[-2000:]
         out = np.load(str(tmp_path / "out.npy"))
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)

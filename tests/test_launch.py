@@ -12,7 +12,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_launch(extra_args, script_body, tmp_path, timeout=300,
+def _run_launch(extra_args, script_body, tmp_path, timeout=110,
                 local_devices=2):
     script = tmp_path / "worker.py"
     script.write_text(textwrap.dedent(script_body))
@@ -203,9 +203,9 @@ class TestElasticMembership:
         timelib.sleep(0.5)
         b = spawn(1)
         try:
-            b_out, b_err = b.communicate(timeout=240)
+            b_out, b_err = b.communicate(timeout=55)
             assert b.returncode == 17, b_err[-2000:]
-            a_out, a_err = a.communicate(timeout=240)
+            a_out, a_err = a.communicate(timeout=55)
             assert a.returncode == 0, a_err[-2000:]
         finally:
             for p in (a, b):

@@ -21,7 +21,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark.lib import family_lfm2 as F
 from benchmark.lib import reference_lfm2 as R
 from paddle_tpu.models import decoder_spec as DS
 from paddle_tpu.models.axk1 import route_top_k
@@ -29,24 +28,21 @@ from paddle_tpu.models.lfm2 import Lfm2MoeConfig
 from paddle_tpu.ops import ssm as SSM
 from paddle_tpu.serving import GenerationEngine
 
-SEED = 2 ** 31 + 42
-ORDER_OF_SUM = 1e-4        # see the module doc
+import _toys
 
-with open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "tests", "data",
-        "tiny-lfm2-config.json")) as _f:
-    TOY = json.load(_f)["model"]
+ORDER_OF_SUM = 1e-4        # see the module doc
+TOY = _toys.config("lfm2")
 CONV_LAYERS = (0, 1, 3, 4, 5)
 
 
 @pytest.fixture(scope="module")
 def net():
-    return F.build_lm(TOY, SEED, "float32")
+    return _toys.seeded("lfm2")
 
 
 @pytest.fixture(scope="module")
 def make():
-    return F.Weights(SEED, TOY, "float32")
+    return _toys.weights("lfm2")
 
 
 def _ids(n, seed=0):
@@ -309,23 +305,24 @@ def test_a_conv_layer_has_no_cache_write_and_no_attention_section(engine):
     assert DS.SSM_SCAN not in {w for ws in by_layer.values() for w in ws}
 
 
-def test_pad_rows_and_absent_slots_change_no_state(net):
-    """Three slots, one request: the tails of the two absent slots and of
-    the row no slot owns stay exactly what they were (planted garbage),
-    through a chunk launch with pad rows and decode launches."""
-    eng = GenerationEngine(net, num_slots=3, max_len=64, block_size=8,
-                           prefill_budget=24)
+def test_pad_rows_and_absent_slots_change_no_state(engine):
+    """Two slots, one request: the tails of the absent slot and of the row
+    no slot owns stay exactly what they were (planted garbage), through a
+    chunk launch with pad rows and decode launches."""
+    pool = engine._pool
     rng = np.random.default_rng(3)
-    junk = rng.standard_normal((5, 4, 2, 64)).astype(np.float32)
-    eng._pool.state_data = (jnp.asarray(junk),)     # donated to the step
-    out = [int(t) for t in eng.submit(_ids(21, seed=9).tolist(), 6).stream()]
-    while eng._pool.n_active:
+    junk = rng.standard_normal((5, 3, 2, 64)).astype(np.float32)
+    pool.state_data = (jnp.asarray(junk),)          # donated to the step
+    out = [int(t) for t in engine.submit(_ids(21, seed=9).tolist(),
+                                         6).stream()]
+    while pool.n_active:
         pass
-    state = np.asarray(eng._pool.state_data[0])
-    eng.close()
+    state = np.asarray(pool.state_data[0])
     assert len(out) == 6
-    np.testing.assert_array_equal(state[:, 1:], junk[:, 1:])
-    assert float(np.abs(state[:, 0] - junk[:, 0]).min()) > 0
+    took = [s for s in range(3)
+            if not np.array_equal(state[:, s], junk[:, s])]
+    assert len(took) == 1 and took[0] < 2            # a slot, not row 2
+    assert float(np.abs(state[:, took[0]] - junk[:, took[0]]).min()) > 0
 
 
 def test_a_reused_slot_starts_from_zero_with_the_late_row_in_the_air(
@@ -355,14 +352,15 @@ def test_a_reused_slot_starts_from_zero_with_the_late_row_in_the_air(
 
 
 def test_a_preempted_request_resumes_by_refeed_to_the_same_tokens(net, make):
-    """Two requests that outgrow eight blocks: the younger is preempted —
-    its tail rows are simply abandoned — re-admitted and re-fed from
-    position 0 (prompt + what it had generated, in chunks); both stay the
-    reference's own text."""
+    """Two requests that outgrow four blocks (contexts of 23 and 25
+    tokens: three and four blocks of 8, tables of 1, 2 and 4): the younger
+    is preempted — its tail rows are simply abandoned — re-admitted and
+    re-fed from position 0 (prompt + what it had generated, in chunks);
+    both stay the reference's own text."""
     pa, pb = _ids(9, seed=61).tolist(), _ids(11, seed=62).tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=64, block_size=8,
-                           num_blocks=8, prefill_budget=16)
-    ha, hb = eng.submit(pa, 36), eng.submit(pb, 36)
+    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8,
+                           num_blocks=4, prefill_budget=16)
+    ha, hb = eng.submit(pa, 14), eng.submit(pb, 14)
     oa = [int(t) for t in ha.stream()]
     ob = [int(t) for t in hb.stream()]
     preempts = eng.stats()["preempts"]

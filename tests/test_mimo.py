@@ -10,9 +10,6 @@ what bfloat16 or int8 anywhere would give (1e-2 and up) — and two under
 what each CONTROL moves them by (a fact of the mathematics left out or
 off by one: 1e-2 at the least, asserted below).
 """
-import json
-import os
-
 import numpy as np
 import pytest
 
@@ -26,20 +23,17 @@ from paddle_tpu.models import mimo as MM
 from paddle_tpu.models.decoder_spec import serving_decoder
 from paddle_tpu.serving import GenerationEngine
 
-SEED = 2 ** 31 + 35
+import _toys
+
+SEED = _toys.SEEDS["mimo"]
 ORDER_OF_SUM = 1e-4        # see the module doc
 A_FACT_MOVES = 1e-2        # the least a control must move a logit by
-
-with open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "tests", "data",
-        "tiny-mimo-config.json")) as _f:
-    TOY = json.load(_f)["model"]
 
 
 def _model(**over):
     """The ``model`` group of a configuration at toy sizes: layers global
     (dense), window, window, global; window 8; 16 experts, top 4."""
-    return dict(TOY, **over)
+    return _toys.config("mimo", **over)
 
 
 @pytest.fixture(scope="module")
@@ -48,13 +42,13 @@ def model():
 
 
 @pytest.fixture(scope="module")
-def net(model):
-    return F.build_lm(model, SEED, "float32")
+def net():
+    return _toys.seeded("mimo")
 
 
 @pytest.fixture(scope="module")
-def make(model):
-    return F.Weights(SEED, model, "float32")
+def make():
+    return _toys.weights("mimo")
 
 
 def _ids(rows, length, seed=0):
@@ -64,10 +58,17 @@ def _ids(rows, length, seed=0):
 
 # -- 1. the plain forward pass -------------------------------------------------
 
-def test_the_programs_forward_is_the_references(net, make, model):
-    ids = _ids(2, 48)                              # six windows long
+@pytest.fixture(scope="module")
+def forward(net):
+    """``(ids, the program's logits)`` on two rows six windows long: one
+    eager forward for the comparison and its six controls."""
+    ids = _ids(2, 48)
+    return ids, np.asarray(net(jnp.asarray(ids))._data)
+
+
+def test_the_programs_forward_is_the_references(forward, make, model):
+    ids, program = forward
     want = R.logits(make, model, ids, q_block=16)
-    program = np.asarray(net(jnp.asarray(ids))._data)
     assert float(want.std()) > 0.5                 # logits of spread ~1
     np.testing.assert_allclose(program, want, atol=ORDER_OF_SUM)
 
@@ -78,13 +79,12 @@ def test_the_programs_forward_is_the_references(net, make, model):
 ], ids=["no-sinks", "window-127-of-128", "window-129-of-128",
         "value-scale-left-out", "rotary-on-every-lane",
         "selection-bias-left-out"])
-def test_each_fact_of_the_mathematics_decides_the_logits(net, make, model,
-                                                         depart):
+def test_each_fact_of_the_mathematics_decides_the_logits(forward, make,
+                                                         model, depart):
     """The controls: the reference with ONE fact changed no longer agrees
     with the program — so the seeded weights make that fact decide, and
     the comparison above would catch the program getting it wrong."""
-    ids = _ids(2, 48)
-    program = np.asarray(net(jnp.asarray(ids))._data)
+    ids, program = forward
     off = R.logits(make, model, ids, q_block=16, depart=depart)
     assert float(np.abs(off - program).max()) > A_FACT_MOVES
     with pytest.raises(AssertionError):
@@ -204,30 +204,32 @@ def test_the_spec_has_a_global_and_a_window_group(net):
 
 def test_chunked_prefill_then_decode_through_both_groups_agrees(
         net, make, model):
-    """Prompts of 5 to 61 tokens in chunks of 16 (prefill_budget) over
-    blocks of 8 and a window of 8, twenty decode steps each: contexts up
-    to ten windows long, so the window group frees blocks behind every
-    slot while the global group keeps them all; every served token is the
+    """Prompts of 44 and 58 tokens in chunks of 16 (prefill_budget) over
+    blocks of 8 and a window of 8, six decode steps each: contexts up to
+    eight windows long (the toy's step is ~10 s a program here, and these
+    two need three: q 8, 16 and 32 rows against tables of 8 blocks), so
+    the window group frees blocks behind both slots while the global group
+    keeps them all; every served token is the
     reference's first choice by its own logits (gap under 1e-4 of the
     row's spread: float32 against float32, prefill + decode through the
     cache against the full forward)."""
-    prompts = [_ids(1, n, seed=n)[0].tolist() for n in (5, 19, 44, 61)]
-    eng = GenerationEngine(net, num_slots=4, max_len=96, block_size=8,
+    prompts = [_ids(1, n, seed=n)[0].tolist() for n in (44, 58)]
+    eng = GenerationEngine(net, num_slots=2, max_len=64, block_size=8,
                            prefill_budget=16)
     pool = eng._pool
     assert [(g.window, g.num_layers, g.num_heads, g.num_blocks)
-            for g in pool.groups] == [(0, 2, 2, 48), (8, 2, 4, 14)]
-    handles = [eng.submit(p, 20) for p in prompts]
+            for g in pool.groups] == [(0, 2, 2, 16), (8, 2, 4, 8)]
+    handles = [eng.submit(p, 6) for p in prompts]
     outs = [[int(t) for t in h.stream()] for h in handles]
     st = eng.stats()
     eng.close()
     rec = eng.flight_recorder.snapshot()["cycles"]
-    assert st["prefill_chunks"] >= 9 and st["preempts"] == 0
+    assert st["prefill_chunks"] >= 7 and st["preempts"] == 0
     for p, o in zip(prompts, outs):
-        assert len(o) == 20
+        assert len(o) == 6
         assert float(_gaps(make, model, p, o).max()) < ORDER_OF_SUM
     # freed behind the window, all returned at the end, prefix cache off
-    assert st["window_blocks_freed"] >= 20
+    assert st["window_blocks_freed"] >= 10
     assert st["prefix_hits"] == 0 and st["cached_blocks"] == 0
     assert pool.blocks_in_use == 0 and pool.group_blocks_in_use(1) == 0
     launch = [c for c in rec if "kv_tokens_window" in c]
@@ -238,7 +240,7 @@ def test_chunked_prefill_then_decode_through_both_groups_agrees(
         assert c["kv_tokens_window"] <= c["kv_tokens"]
         assert c["kv_row_tokens_window"] <= c["kv_row_tokens"]
         # a window layer reads at most W - 1 + rows a slot
-        assert c["kv_tokens_window"] <= c["launch_rows"] + 7 * 4
+        assert c["kv_tokens_window"] <= c["launch_rows"] + 7 * 2
     # late in the run the window group holds far less than the global
     late = launch[-1]
     per_token = late["kv_live_bytes"] / late["kv_live_tokens"]
@@ -248,13 +250,14 @@ def test_chunked_prefill_then_decode_through_both_groups_agrees(
 
 def test_block_pressure_in_either_group_preempts_and_stays_exact(
         net, make, model):
-    """Two requests that outgrow eight blocks of the global group: the
-    younger is preempted (BOTH groups' blocks come back), re-admitted and
-    replayed through chunks; both stay the reference's own text."""
+    """Two requests that outgrow four blocks of the global group (contexts
+    of 23 and 25 tokens: three and four blocks of 8, tables of 1, 2 and 4):
+    the younger is preempted (BOTH groups' blocks come back), re-admitted
+    and replayed through chunks; both stay the reference's own text."""
     pa, pb = _ids(1, 9, seed=61)[0].tolist(), _ids(1, 11, seed=62)[0].tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=64, block_size=8,
-                           num_blocks=8, prefill_budget=16)
-    ha, hb = eng.submit(pa, 36), eng.submit(pb, 36)
+    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8,
+                           num_blocks=4, prefill_budget=16)
+    ha, hb = eng.submit(pa, 14), eng.submit(pb, 14)
     oa = [int(t) for t in ha.stream()]
     ob = [int(t) for t in hb.stream()]
     preempts = eng.stats()["preempts"]

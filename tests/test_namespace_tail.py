@@ -283,21 +283,59 @@ class TestVisionTail:
             set_image_backend("cv2")
 
 
+# The functions the reference patches onto Tensor (python/paddle/tensor/
+# __init__.py, ``tensor_method_func``), held here because the reference's
+# tree is no part of this repository: written down without that tree at
+# hand (PR 45), so a name that list holds and this one lacks is checked
+# only where /root/reference exists.
+REFERENCE_TENSOR_METHODS = """
+    abs acos acosh add add_ add_n addmm all allclose amax amin angle any
+    argmax argmin argsort as_complex as_real asin asinh atan atan2 atanh
+    bincount bitwise_and bitwise_not bitwise_or bitwise_xor bmm
+    broadcast_shape broadcast_tensors broadcast_to cast ceil ceil_
+    cholesky cholesky_solve chunk clip clip_ concat cond conj corrcoef
+    cos cosh cov cross cumprod cumsum deg2rad det diagonal diff digamma
+    dist divide dot eig eigh eigvals eigvalsh equal equal_all erf erfinv
+    erfinv_ exp exp_ expand expand_as expm1 exponential_ fill_ flatten
+    flatten_ flip floor floor_ floor_divide floor_mod fmax fmin frac
+    gather gather_nd gcd greater_equal greater_than heaviside histogram
+    imag increment index_sample index_select inner inverse is_complex
+    is_empty is_floating_point is_integer is_tensor isclose isfinite
+    isinf isnan kron kthvalue lcm lerp lerp_ less_equal less_than lgamma
+    log log10 log1p log2 logical_and logical_not logical_or logical_xor
+    logit logsumexp lstsq lu lu_unpack masked_select matmul matrix_power
+    max maximum mean median min minimum mm mod mode moveaxis multi_dot
+    multiplex multiply mv nanmean nanmedian nanquantile nansum neg
+    nonzero norm not_equal numel outer pinv pow prod put_along_axis
+    put_along_axis_ qr quantile rad2deg rank real reciprocal reciprocal_
+    remainder renorm repeat_interleave reshape reshape_ reverse roll
+    rot90 round round_ rsqrt rsqrt_ scale scale_ scatter scatter_
+    scatter_nd scatter_nd_add searchsorted shape shard_index sign sin
+    sinh slice solve sort split sqrt sqrt_ square squeeze squeeze_ stack
+    stanh std strided_slice subtract subtract_ sum svd t take_along_axis
+    tan tanh tanh_ tensordot tile tolist topk trace transpose
+    triangular_solve tril triu trunc unbind uniform_ unique
+    unique_consecutive unsqueeze unsqueeze_ unstack var where zero_
+""".split()
+
+
 class TestTensorMethodParity:
     def test_all_reference_tensor_methods_exist(self):
-        """The reference patches 219 functions onto Tensor
-        (tensor/__init__.py tensor_method_func); every one must resolve
-        as a method here."""
+        """Every function the reference patches onto Tensor must resolve
+        as a method here: the list above, and the reference's own where
+        its tree is present."""
         import ast
-        src = open("/root/reference/python/paddle/tensor/__init__.py")\
-            .read()
-        names = set()
-        for n in ast.walk(ast.parse(src)):
-            if isinstance(n, ast.Assign) and any(
-                    getattr(t, "id", "") == "tensor_method_func"
-                    for t in n.targets):
-                names = set(ast.literal_eval(n.value))
+        names = set(REFERENCE_TENSOR_METHODS)
         assert len(names) > 200
+        ref = "/root/reference/python/paddle/tensor/__init__.py"
+        if os.path.exists(ref):
+            with open(ref) as f:
+                tree = ast.parse(f.read())
+            for n in ast.walk(tree):
+                if isinstance(n, ast.Assign) and any(
+                        getattr(t, "id", "") == "tensor_method_func"
+                        for t in n.targets):
+                    names |= set(ast.literal_eval(n.value))
         t = paddle.to_tensor(np.zeros((2, 2), "float32"))
         missing = sorted(m for m in names if not hasattr(t, m))
         assert not missing, missing

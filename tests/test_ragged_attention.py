@@ -22,54 +22,28 @@ Four layers of guarantees:
 * **validation** — a Mosaic-tileable block size, fail-fast at
   construction; the removed options refused by name.
 """
-import threading
 
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.framework import monitor, trace_probe
-from paddle_tpu.models import GPTConfig, GPTForPretraining, generate
+from paddle_tpu.models import generate
 from paddle_tpu.ops.ragged_paged_attention import (
     q_step_blocks, ragged_layout, ragged_paged_attention,
     reference_ragged_attention)
 from paddle_tpu.serving import GenerationEngine
 from paddle_tpu.serving.scheduler import GenerationRequest
 
+import _toys
 from _mock_serving import MockDevice, mock_pool
 
-VOCAB = 96
+VOCAB = _toys.VOCAB
 
-
-@pytest.fixture(scope="module")
-def served_model():
-    """A tiny char GPT trained for a few steps: trained logits have
-    clear argmax margins, so greedy parity between the fused (ragged
-    Pallas kernel) step and ``generate``'s loop cannot flake on numeric
-    noise."""
-    paddle.seed(11)
-    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=64, num_hidden_layers=2,
-                    num_attention_heads=4, intermediate_size=128,
-                    max_position_embeddings=64, hidden_dropout_prob=0.0,
-                    attention_dropout_prob=0.0)
-    model = GPTForPretraining(cfg)
-    opt = paddle.optimizer.Adam(learning_rate=3e-3,
-                                parameters=model.parameters())
-    corpus = ("the quick brown fox jumps over the lazy dog. "
-              "pack my box with five dozen liquor jugs. ") * 6
-    data = np.frombuffer(corpus.encode(), np.uint8).astype(np.int32) % VOCAB
-    rng = np.random.RandomState(0)
-    seq, batch = 24, 8
-    for _ in range(30):
-        starts = rng.randint(0, len(data) - seq - 1, batch)
-        chunk = np.stack([data[s:s + seq + 1] for s in starts])
-        loss, _ = model(paddle.to_tensor(chunk[:, :-1]),
-                        paddle.to_tensor(chunk[:, 1:].astype(np.int64)))
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-    model.eval()
-    return model
+# the two engines the tests that only serve requests share (``engines``
+# hands each out drained, its pool and trie as new): the kernel's smallest
+# block, and four slots fed in chunks of at most 8 tokens
+PLAIN = dict(num_slots=2, max_len=48, block_size=8)
+CHUNKS = dict(num_slots=4, max_len=64, block_size=8, prefill_budget=8)
 
 
 def _prompt(rng, n):
@@ -441,89 +415,22 @@ class TestKernelParity:
 # ---------------------------------------------------------------------------
 
 class TestFusedEngineParity:
-    def test_single_request_matches_generate(self, served_model):
-        eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               block_size=8)
+    def test_single_request_matches_generate(self, served_model, engines):
         p = _prompt(np.random.RandomState(1), 7)
-        out = eng.submit(p, max_new_tokens=8).result(timeout=300)
+        out = engines(served_model, **PLAIN) \
+            .submit(p, max_new_tokens=8).result(timeout=300)
         ref = generate(served_model, p[None, :], max_new_tokens=8)
         np.testing.assert_array_equal(out, ref.numpy()[0])
-        eng.close()
 
-    def test_32_mixed_requests_match_generate_and_analyze_clean(
-            self, served_model):
-        """The fused acceptance criterion at the kernel's smallest
-        block: 32 mixed-length concurrent greedy requests, a sample of
-        them held to per-request ``generate`` (all 32 are, at the
-        default block size, in ``test_serving_engine.py``); every (q,
-        table) bucket traces once, the fused step analyzes clean and no
-        block leaks."""
-        rng = np.random.RandomState(2)
-        specs = [(_prompt(rng, int(rng.randint(2, 21))),
-                  int(rng.randint(1, 9))) for _ in range(32)]
-
-        def storm(eng):
-            outs = [None] * len(specs)
-
-            def client(i):
-                p, n = specs[i]
-                outs[i] = eng.submit(p, max_new_tokens=n)
-
-            threads = [threading.Thread(target=client, args=(i,))
-                       for i in range(len(specs))]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            return [h.result(timeout=600) for h in outs]
-
-        eng = GenerationEngine(served_model, num_slots=8, max_len=48,
-                               block_size=8)
-        # no warmup: the storm compiles its own (q, table) buckets, and
-        # the discipline assertion below is per-site trace counts (a
-        # deterministic zero-retrace check lives in
-        # test_warm_buckets_serve_with_zero_retraces)
-        fused_outs = storm(eng)
-        sites = {k: v for k, v in trace_probe.snapshot().items()
-                 if k.startswith("serving/fused") and f"#{eng._eid}" in k}
-        report = eng.analyze()
-        stats = eng.stats()
-        eng.close()
-
-        for (p, n), fout in zip(specs, fused_outs):
-            assert fout.shape == (p.size + n,)
-        for i in (0, 5, 9, 13, 17, 22, 26, 31):
-            p, n = specs[i]
-            ref = generate(served_model, p[None, :], max_new_tokens=n)
-            np.testing.assert_array_equal(fused_outs[i], ref.numpy()[0])
-        # compile discipline: which (q, table) buckets a storm reaches
-        # depends on scheduling, but every bucket traces EXACTLY ONCE
-        # (traces > 1 would be the retrace-storm bug class) and the
-        # ladder is bounded by the pow2 products — q in {8..128} x
-        # table in {1, 2, 4, max_table_len=6} here
-        assert sites, "fused probe sites missing"
-        for name, rec in sites.items():
-            assert rec["traces"] == 1, (name, rec)
-            assert not rec["causes"], (name, rec)
-        assert len(sites) <= 20, sorted(sites)
-        # the clean bill: donation-safe, host-sync-free fused step
-        assert report.ok(), report.table()
-        assert "donation-safety" in report.passes_run
-        assert "host-sync" in report.passes_run
-        assert stats["active_requests"] == 0
-        assert stats["kv_blocks_in_use"] == 0
-
-    def test_eos_early_stop_matches_generate(self, served_model):
+    def test_eos_early_stop_matches_generate(self, served_model, engines):
         p = _prompt(np.random.RandomState(3), 6)
         ref8 = generate(served_model, p[None, :], max_new_tokens=8)
         eos = int(ref8.numpy()[0, 6 + 2])
         ref = generate(served_model, p[None, :], max_new_tokens=8,
                        eos_token_id=eos, pad_token_id=0)
-        eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               block_size=8)
-        out = eng.submit(p, max_new_tokens=8, eos_token_id=eos) \
-                 .result(timeout=300)
-        eng.close()
+        out = engines(served_model, **PLAIN) \
+            .submit(p, max_new_tokens=8, eos_token_id=eos) \
+            .result(timeout=300)
         np.testing.assert_array_equal(out, ref.numpy()[0])
 
     def test_prefix_hit_cow_and_preemption_interleavings(
@@ -573,13 +480,13 @@ class TestFusedEngineParity:
                          max_new_tokens=24).numpy()[0])
         assert eng._pool.blocks_in_use == 0
 
-    def test_warm_buckets_serve_with_zero_retraces(self, served_model):
+    def test_warm_buckets_serve_with_zero_retraces(self, served_model,
+                                                   engines):
         """The deterministic zero-retrace assertion: a request identical
         in shape class to one already served reuses every fused (q,
         table) bucket program — no new trace anywhere, and the
         dispatch/retrace_cause counters stay untouched."""
-        eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               block_size=8)
+        eng = engines(served_model, **PLAIN)
         rng = np.random.RandomState(4)
         eng.submit(_prompt(rng, 7), max_new_tokens=8).result(timeout=300)
         retrace0 = monitor.stat_get("dispatch/retrace_cause")
@@ -589,7 +496,6 @@ class TestFusedEngineParity:
         assert sites0
         out = eng.submit(_prompt(rng, 7), max_new_tokens=8) \
                  .result(timeout=300)
-        eng.close()
         assert out.shape == (15,)
         assert monitor.stat_get("dispatch/retrace_cause") == retrace0
         sites1 = {k: v["traces"]
@@ -621,24 +527,26 @@ class TestFusedEngineParity:
 
 class TestChunkedPrefill:
     def test_long_prompt_chunks_within_budget_and_stays_exact(
-            self, served_model):
-        eng = GenerationEngine(served_model, num_slots=4, max_len=64,
-                               block_size=8, prefill_budget=8)
+            self, served_model, engines):
+        eng = engines(served_model, **CHUNKS)
+        since, before = eng._sched._cycle, eng.stats()
         p = _prompt(np.random.RandomState(9), 40)
         h = eng.submit(p, max_new_tokens=4)
         out = h.result(timeout=600)
+        _toys.settle(eng)
         stats = eng.stats()
         rec = eng.dump_flight_recorder()
-        eng.close()
         ref = generate(served_model, p[None, :], max_new_tokens=4)
         np.testing.assert_array_equal(out, ref.numpy()[0])
         # 40 feed tokens at an 8-token budget: >= 5 chunk launches,
         # visible in stats() and in the flight recorder's cycle ring
-        assert stats["prefill_chunks"] >= 5
-        assert stats["chunked_prefill_tokens"] == 40
+        assert stats["prefill_chunks"] - before["prefill_chunks"] >= 5
+        assert stats["chunked_prefill_tokens"] \
+            - before["chunked_prefill_tokens"] == 40
         assert stats.get("chunked_prefill_tokens_per_sec", 0) > 0
         chunk_cycles = [c for c in rec["cycles"]
-                        if c.get("chunk_tokens", 0) > 0]
+                        if c.get("chunk_tokens", 0) > 0
+                        and c["cycle"] > since]
         assert chunk_cycles
         assert max(c["chunk_tokens"] for c in chunk_cycles) <= 8
         # the request trace carries the per-chunk marks and the
@@ -646,14 +554,15 @@ class TestChunkedPrefill:
         assert h.trace.count("prefill_chunk") >= 5
         assert h.trace.t("chunked_prefill_done") is not None
 
-    def test_long_prompt_does_not_starve_decode(self, served_model):
+    def test_long_prompt_does_not_starve_decode(self, served_model,
+                                                engines):
         """The anti-starvation policy: while a 40-token prompt is being
         chunk-fed at an 8-token budget, the already-decoding request
         keeps emitting IN THE SAME cycles — no cycle spends its whole
         budget on the prompt alone (the prompt-burst monopoly a
         whole-prompt prefill at admission could not avoid)."""
-        eng = GenerationEngine(served_model, num_slots=4, max_len=64,
-                               block_size=8, prefill_budget=8)
+        eng = engines(served_model, **CHUNKS)
+        since = eng._sched._cycle
         short = eng.submit(_prompt(np.random.RandomState(10), 4),
                            max_new_tokens=40)
         it = short.stream()
@@ -665,10 +574,11 @@ class TestChunkedPrefill:
         with pytest.raises(Exception):
             for _ in it:
                 pass
+        _toys.settle(eng)
         rec = eng.dump_flight_recorder()
-        eng.close()
         chunk_cycles = [c for c in rec["cycles"]
-                        if c.get("chunk_tokens", 0) > 0]
+                        if c.get("chunk_tokens", 0) > 0
+                        and c["cycle"] > since]
         assert len(chunk_cycles) >= 5
         # every chunk cycle also advanced decode: emitted >= 1
         assert all(c["emitted"] >= 1 for c in chunk_cycles), chunk_cycles
@@ -811,13 +721,11 @@ class TestFusedValidation:
                              attention="flash")
 
     def test_fused_admits_prompts_the_bucket_ladder_rejects(
-            self, served_model):
+            self, served_model, engines):
         """No prefill buckets: a feed whose pow2 bucket would overshoot
         a non-pow2 max_len chunks through the ragged step like any
         other; ``prompt + max_new <= max_len`` is the only bound."""
-        eng = GenerationEngine(served_model, num_slots=2, max_len=48,
-                               block_size=8)
-        out = eng.submit(np.ones(33, np.int32), max_new_tokens=1) \
-                 .result(timeout=300)
+        out = engines(served_model, **PLAIN) \
+            .submit(np.ones(33, np.int32), max_new_tokens=1) \
+            .result(timeout=300)
         assert out.shape == (34,)
-        eng.close()

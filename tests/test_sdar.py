@@ -8,6 +8,8 @@ reference's own loop. The tolerance is ``tests/test_axk1.py``'s, for its
 reason: float32 sums in another order differ by ~1e-6 of a unit-RMS
 value, so 1e-4 on logits of spread 1 is two orders of room.
 """
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -21,25 +23,22 @@ from paddle_tpu.models import sdar as SD
 from paddle_tpu.models.decoder_spec import GenerationRule, serving_decoder
 from paddle_tpu.serving import GenerationEngine, scheduler
 
-SEED = 2 ** 31 + 33
-SCALES = {"gain": 1.0, "norm_std": 0.1, "qk_gain": 1.5, "router_gain": 2.0,
-          "expert_gain": 0.5, "embed_std": 1.0}
+import _toys
+
+SEED = _toys.SEEDS["sdar"]
 ORDER_OF_SUM = 1e-4        # see the module doc
+
+# The two engines the tests that only serve requests share (``engines``
+# hands each out drained, its pool and trie as new): ONE slot, so that a
+# request's launches are the only ones, and TWO. Contexts of up to eight
+# cache blocks of 8; chunks of at most 12 tokens, which end inside a block.
+ONE = dict(num_slots=1, max_len=64, block_size=8, prefill_budget=12)
+TWO = dict(num_slots=2, max_len=64, block_size=8, prefill_budget=12)
 
 
 def _model(**over):
     """The ``model`` group of a configuration at toy sizes."""
-    cfg = SD.SDARConfig.tiny()
-    m = {k: getattr(cfg, k) for k in (
-        "vocab_size", "hidden_size", "moe_intermediate_size",
-        "num_hidden_layers", "num_attention_heads", "num_key_value_heads",
-        "head_dim", "num_experts_per_tok", "norm_topk_prob", "rms_norm_eps",
-        "rope_theta", "max_position_embeddings", "block_length",
-        "denoising_steps", "mask_token_id")}
-    m.update(n_routed_experts=cfg.num_experts, experts_held=[0, 16],
-             first_k_dense_replace=0, weight_scales=SCALES)
-    m.update(over)
-    return m
+    return _toys.config("sdar", **over)
 
 
 @pytest.fixture(scope="module")
@@ -48,13 +47,13 @@ def model():
 
 
 @pytest.fixture(scope="module")
-def net(model):
-    return F.build_lm(model, SEED, "float32")
+def net():
+    return _toys.seeded("sdar")
 
 
 @pytest.fixture(scope="module")
-def make(model):
-    return F.Weights(SEED, model, "float32")
+def make():
+    return _toys.weights("sdar")
 
 
 def _blocks(p, n):
@@ -185,9 +184,11 @@ def _serial(monkeypatch):
                         lambda self, cold=False: real(self, True))
 
 
+@contextlib.contextmanager
 def _spy_dispatches(eng):
-    """Record every launch the scheduler dispatches: ``[(plan, the slots
-    whose state the step is told to take from the launch in flight)]``."""
+    """Record every launch the scheduler dispatches inside the block:
+    ``[(plan, the slots whose state the step is told to take from the
+    launch in flight)]``."""
     seen, real = [], eng._sched._do_chunked
 
     def spy(active, plan, prev):
@@ -195,24 +196,29 @@ def _spy_dispatches(eng):
         return real(active, plan, prev)
 
     eng._sched._do_chunked = spy
-    return seen
+    try:
+        yield seen
+    finally:
+        eng._sched._do_chunked = real
 
 
-def _launches(eng):
-    """Close the engine and return its launches' records: the last one
-    enters the ring at the END of the turn that woke the client."""
-    eng.close()
+def _launches(eng, since=0):
+    """The records of the launches ``eng`` made after its turn ``since``
+    (``eng._sched._cycle`` when the test took the engine), once the last
+    one has entered the ring."""
+    _toys.settle(eng)
     jax.effects_barrier()
     return [c for c in eng.flight_recorder.snapshot()["cycles"]
-            if c.get("launch_q")]
+            if c.get("launch_q") and c["cycle"] > since]
 
 
-def _served(net, prompt, n, **kw):
-    """One request through an engine of its own with the head's output of
-    every launch recorded: ``(tokens, passes, [logits of the block's rows
-    a denoising launch], cycle records, dispatches)``."""
-    seen = []
-    real = net.logits
+@pytest.fixture(scope="module")
+def heard(model):
+    """``(net, seen)``: a second build of the toy whose head reports its
+    output of every launch into ``seen`` — the report is traced into the
+    step programs, so the net is this fixture's alone."""
+    net = F.build_lm(model, SEED, "float32")
+    seen, real = [], net.logits
 
     def recording(hidden):
         out = real(hidden)
@@ -220,16 +226,22 @@ def _served(net, prompt, n, **kw):
         return out
 
     net.logits = recording
-    try:
-        kw = dict(dict(num_slots=1, max_len=64, block_size=8,
-                       prefill_budget=12), **kw)
-        eng = GenerationEngine(net, **kw)
-        dispatches = _spy_dispatches(eng)
+    return net, seen
+
+
+def _served(engines, heard, prompt, n):
+    """One request through the one-slot engine with the head's output of
+    every launch recorded: ``(tokens, passes, [logits of the block's rows
+    a denoising launch], cycle records, dispatches)``."""
+    net, seen = heard
+    eng = engines(net, **ONE)
+    jax.effects_barrier()
+    seen.clear()
+    since = eng._sched._cycle
+    with _spy_dispatches(eng) as dispatches:
         h = eng.submit(prompt, n)
         toks = [int(t) for t in h.stream()]
-        cycles = _launches(eng)
-    finally:
-        del net.logits
+        cycles = _launches(eng, since)
     assert len(seen) == len(cycles) == len(dispatches)
     denoise = [lg[:, 0] for lg, c in zip(seen, cycles)
                if c.get("denoise_slots")]
@@ -241,7 +253,7 @@ def _served(net, prompt, n, **kw):
                          ids=["residue0", "residue1", "residue2", "residue3",
                               "no-prefill"])
 def test_every_pass_through_the_paged_cache_is_the_references(
-        net, make, model, monkeypatch, p, n, in_flight):
+        engines, heard, make, model, monkeypatch, p, n, in_flight):
     """Prompts of every residue mod 4 (and one shorter than a block: no
     prefill at all), fed in chunks of 12 that end inside a cache block of
     8, outputs that are and are not multiples of 4: the logits of every
@@ -254,7 +266,8 @@ def test_every_pass_through_the_paged_cache_is_the_references(
     if in_flight == 1:
         _serial(monkeypatch)
     prompt = _ids(1, p, seed=p)[0].tolist()
-    toks, passes, logits, cycles, dispatches = _served(net, prompt, n)
+    toks, passes, logits, cycles, dispatches = _served(engines, heard,
+                                                       prompt, n)
     want = R.generate(make, model, prompt, n)
     assert toks == want["tokens"] and passes == want["passes"]
     assert model["mask_token_id"] not in toks
@@ -302,7 +315,7 @@ def test_two_fixed_a_pass_follows_the_references_loop(make, monkeypatch):
     model = _model(denoising_steps=2)
     net = F.build_lm(model, SEED, "float32")
     prompt = _ids(1, 9, seed=5)[0].tolist()
-    eng = GenerationEngine(net, num_slots=1, max_len=32, block_size=8)
+    eng = GenerationEngine(net, **ONE)
     h = eng.submit(prompt, 10)
     toks = [int(t) for t in h.stream()]
     eng.close()
@@ -328,6 +341,7 @@ def test_a_batch_of_mixed_requests_agrees_and_the_trie_holds_prompts_only(
     outs = [[int(t) for t in h.stream()] for h in handles]
     keys = list(eng._pool._trie)
     cycles = _launches(eng)
+    eng.close()
     for (p, n), h, got in zip(reqs, handles, outs):
         want = R.generate(make, model, p, n)
         assert got == want["tokens"]
@@ -351,7 +365,7 @@ def test_a_batch_of_mixed_requests_agrees_and_the_trie_holds_prompts_only(
 
 
 def test_a_commit_with_no_next_rows_in_its_launch_rides_alone(
-        net, make, model, monkeypatch):
+        engines, net, make, model, monkeypatch):
     """The degenerate ride: were a finished block's slot handed its B rows
     only, the launch commits alone (no token, ``commit_slots``), the next
     block opens a launch later and the text is still the reference's —
@@ -362,11 +376,11 @@ def test_a_commit_with_no_next_rows_in_its_launch_rides_alone(
         lambda self: {s: min(n, 4) if not self._slots[s].pending_feed else n
                       for s, n in real(self).items()})
     reqs = [(_ids(1, p, seed=p)[0].tolist(), n) for p, n in [(13, 9), (6, 8)]]
-    eng = GenerationEngine(net, num_slots=2, max_len=64, block_size=8,
-                           prefill_budget=12)
+    eng = engines(net, **TWO)
+    since = eng._sched._cycle
     handles = [eng.submit(p, n) for p, n in reqs]
     outs = [[int(t) for t in h.stream()] for h in handles]
-    cycles = _launches(eng)
+    cycles = _launches(eng, since)
     for (p, n), h, got in zip(reqs, handles, outs):
         want = R.generate(make, model, p, n)
         assert got == want["tokens"]
@@ -401,7 +415,7 @@ def test_a_request_preempted_inside_a_block_resumes_and_still_agrees(
 
 
 def test_a_request_preempted_at_a_ride_resumes_and_still_agrees(
-        net, make, model, monkeypatch):
+        engines, net, make, model, monkeypatch):
     """The younger of two requests is preempted in the turn that planned
     its ride (its block finished and emitted, not committed): the pipeline
     is drained, the request fed again from its emitted tokens, and both
@@ -422,13 +436,13 @@ def test_a_request_preempted_at_a_ride_resumes_and_still_agrees(
 
     monkeypatch.setattr(scheduler.Scheduler, "_prepare_chunked", prepare)
     pa, pb = _ids(1, 6, seed=71)[0].tolist(), _ids(1, 9, seed=72)[0].tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=48, block_size=8,
-                           prefill_budget=16)
+    eng = engines(net, **TWO)
+    since, preempts = eng._sched._cycle, eng.stats()["preempts"]
     ha, hb = eng.submit(pa, 14), eng.submit(pb, 13)
     oa = [int(t) for t in ha.stream()]
     ob = [int(t) for t in hb.stream()]
-    assert eng.stats()["preempts"] == 1
-    cycles = _launches(eng)
+    assert eng.stats()["preempts"] - preempts == 1
+    cycles = _launches(eng, since)
     # preempted with its first block (positions 9-11) whole and emitted
     assert state == {"emitted": 3, "passes": 3}
     for p, o, h in ((pa, oa, ha), (pb, ob, hb)):
@@ -442,7 +456,7 @@ def test_a_request_preempted_at_a_ride_resumes_and_still_agrees(
 
 @pytest.mark.parametrize("in_flight", [1, 2])
 def test_a_request_cancelled_at_a_ride_retires_and_its_rows_are_late(
-        net, make, model, monkeypatch, in_flight):
+        engines, net, make, model, monkeypatch, in_flight):
     """``cancel()`` as the ride is dispatched. With two launches in flight
     the launch before it lands afterwards, finds the cancel and retires
     the request (the finished block is not emitted); the ride's 2 B rows
@@ -451,7 +465,8 @@ def test_a_request_cancelled_at_a_ride_retires_and_its_rows_are_late(
     ride itself lands the cancel, and nothing is late."""
     if in_flight == 1:
         _serial(monkeypatch)
-    eng = GenerationEngine(net, num_slots=1, max_len=48, block_size=8)
+    eng = engines(net, **ONE)
+    since, late = eng._sched._cycle, eng._sched.late_rows
     real = eng._sched._do_chunked
     prompt = _ids(1, 8, seed=81)[0].tolist()
 
@@ -461,26 +476,28 @@ def test_a_request_cancelled_at_a_ride_retires_and_its_rows_are_late(
         return real(active, plan, prev)
 
     eng._sched._do_chunked = cancelling
-    h = eng.submit(prompt, 12)
-    with pytest.raises(scheduler.RequestCancelled):
-        got = []
-        for t in h.stream():
-            got.append(int(t))
-    eng._sched._do_chunked = real
+    try:
+        h = eng.submit(prompt, 12)
+        with pytest.raises(scheduler.RequestCancelled):
+            got = []
+            for t in h.stream():
+                got.append(int(t))
+    finally:
+        eng._sched._do_chunked = real
     again = [int(t) for t in eng.submit(prompt, 12).stream()]
-    cycles = _launches(eng)
+    cycles = _launches(eng, since)
     want = R.generate(make, model, prompt, 12)["tokens"]
     # one launch in flight: the block was emitted a turn before the ride
     assert got == ([] if in_flight == 2 else want[:4])
     assert again == want
     assert sum(c["late_rows"] for c in cycles) == (8 if in_flight == 2 else 0)
-    assert eng._sched.late_rows == (8 if in_flight == 2 else 0)
+    assert eng._sched.late_rows - late == (8 if in_flight == 2 else 0)
     assert eng._pool.blocks_in_use == 0
 
 
 @pytest.mark.parametrize("in_flight", [1, 2])
 def test_a_request_that_ends_inside_a_block_takes_no_ride_after_it(
-        net, make, model, monkeypatch, in_flight):
+        engines, net, make, model, monkeypatch, in_flight):
     """``max_new_tokens`` ends inside the second block: its surplus is
     denoised and dropped, no commit and no ride follow it, nothing is
     late. An EOS in the first block is learned a launch late: with two
@@ -490,47 +507,46 @@ def test_a_request_that_ends_inside_a_block_takes_no_ride_after_it(
         _serial(monkeypatch)
     prompt = _ids(1, 12, seed=91)[0].tolist()
     want = R.generate(make, model, prompt, 10)["tokens"]
-    eng = GenerationEngine(net, num_slots=1, max_len=48, block_size=8)
+    eng = engines(net, **ONE)
+    since, late = eng._sched._cycle, eng._sched.late_rows
     toks = [int(t) for t in eng.submit(prompt, 6).stream()]
-    ended = eng._sched.late_rows
+    ended = eng._sched.late_rows - late
     at = max(i for i in range(4) if want[i] not in want[:i])
     eos = [int(t) for t in eng.submit(prompt, 10,
                                       eos_token_id=want[at]).stream()]
-    cycles = _launches(eng)
+    cycles = _launches(eng, since)
     assert toks == want[:6] and ended == 0
     assert eos == want[:at + 1]
     first = [c for c in cycles if not c.get("chunk_tokens")][:8]
     assert [c["ride_slots"] for c in first] == [0, 0, 0, 0, 1, 0, 0, 0]
     assert [c["emitted"] for c in first] == [0, 0, 0, 4, 0, 0, 0, 2]
     assert sum(c["tokens_fixed"] for c in first) == 8
-    assert eng._sched.late_rows == (8 if in_flight == 2 else 0)
+    assert eng._sched.late_rows - late == (8 if in_flight == 2 else 0)
     assert eng._pool.blocks_in_use == 0
 
 
-def test_a_shared_prefix_is_served_from_the_trie(net, make, model):
+def test_a_shared_prefix_is_served_from_the_trie(engines, net, make, model):
     pre = _ids(1, 24, seed=9)[0].tolist()
-    eng = GenerationEngine(net, num_slots=2, max_len=48, block_size=8,
-                           prefill_budget=16)
+    eng = engines(net, **TWO)
+    before = eng.stats()
     first = [int(t) for t in eng.submit(pre + [5, 6], 6).stream()]
     again = [int(t) for t in eng.submit(pre + [7, 8, 9], 6).stream()]
     st = eng.stats()
-    eng.close()
-    assert st["prefix_hits"] >= 1 and st["prefill_tokens_saved"] >= 16
+    assert st["prefix_hits"] - before["prefix_hits"] >= 1
+    assert st["prefill_tokens_saved"] - before["prefill_tokens_saved"] >= 16
     assert first == R.generate(make, model, pre + [5, 6], 6)["tokens"]
     assert again == R.generate(make, model, pre + [7, 8, 9], 6)["tokens"]
 
 
 def test_the_teacher_forced_check_reads_zero_on_the_programs_own_text(
-        net, make, model):
+        engines, net, make, model):
     """What the cell's ``correct`` computes: on float32 against float32
     every served token is the reference's first choice at the pass that
     fixed it, and every pass fixed the reference's most confident
     position; a token altered after the fact is seen."""
     prompt = _ids(1, 14, seed=8)[0].tolist()
-    eng = GenerationEngine(net, num_slots=1, max_len=64, block_size=8)
-    h = eng.submit(prompt, 17)
+    h = engines(net, **ONE).submit(prompt, 17)
     toks = [int(t) for t in h.stream()]
-    eng.close()
     passes = list(h.trace.token_passes)
     kw = dict(width=32, states=32, q_block=16, states_per_call=8,
               head_rows=64)
@@ -546,15 +562,15 @@ def test_the_teacher_forced_check_reads_zero_on_the_programs_own_text(
     assert float((bad["gap"] / bad["std"]).max()) > 0.1
 
 
-def test_the_request_lane_of_a_profile_shows_blocks(net, tmp_path):
+def test_the_request_lane_of_a_profile_shows_blocks(engines, net, tmp_path):
     """A finished request exports a span a block, from the block before it
     to the stamp its tokens share, with the passes that fixed them."""
     import json
     from paddle_tpu import profiler
+    eng = engines(net, **ONE)
     with profiler.profile() as sess:
-        eng = GenerationEngine(net, num_slots=1, max_len=32, block_size=8)
         toks = list(eng.submit(_ids(1, 9, seed=3)[0].tolist(), 7).stream())
-        eng.close()
+        _toys.settle(eng)
     with open(sess.export_chrome_trace(str(tmp_path / "blocks.json"))) as f:
         evs = json.load(f)["traceEvents"]
     blocks = [e for e in evs if e.get("ph") == "X" and e["name"] == "block"
@@ -568,7 +584,6 @@ def test_the_request_lane_of_a_profile_shows_blocks(net, tmp_path):
 # -- 3. the decoder spec, the tile law and the refusals ---------------------------
 
 def test_the_decoder_spec_says_what_each_model_is(net):
-    from paddle_tpu.models import GPTConfig, GPTForPretraining
     sd = serving_decoder(net).spec
     assert sd.attention == "full" and {ls.ffn for ls in sd.layers} == {"routed"}
     assert (sd.cache.rows, sd.cache.lanes) == (2, 32)     # KV heads, 2 x Dh
@@ -576,8 +591,8 @@ def test_the_decoder_spec_says_what_each_model_is(net):
     assert sd.generation.passes(4) == 4 and sd.generation.passes(1) == 1
     big = SD.SDARConfig()
     assert (big.num_key_value_heads, 2 * big.head_dim) == (4, 256)
-    gpt = serving_decoder(GPTForPretraining(GPTConfig.tiny())).spec
-    ax = serving_decoder(AX.AXK1ForCausalLM(AX.AXK1Config.tiny())).spec
+    gpt = serving_decoder(_toys.default("gpt2")).spec
+    ax = serving_decoder(_toys.default("axk1")).spec
     assert gpt.generation.block_length == ax.generation.block_length == 1
     with pytest.raises(ValueError, match="mask_token_id"):
         GenerationRule(block_length=4, denoising_steps=4)
@@ -628,14 +643,12 @@ def test_what_block_generation_cannot_do_yet_is_refused_by_name(net, kwargs,
         GenerationEngine(net, **kw)
 
 
-def test_a_threshold_schedule_and_sampling_are_refused(net, model):
+def test_a_threshold_schedule_and_sampling_are_refused(engines, net, model):
     with pytest.raises(ValueError, match="low_confidence_dynamic"):
         SD.SDARConfig.tiny(remasking="low_confidence_dynamic").generation
     wide = F.build_lm(_model(block_length=16, denoising_steps=16), SEED,
                       "float32")
     with pytest.raises(ValueError, match="never straddles a cache block"):
         GenerationEngine(wide, num_slots=1, max_len=32, block_size=8)
-    eng = GenerationEngine(net, num_slots=1, max_len=32, block_size=8)
     with pytest.raises(ValueError, match="do_sample"):
-        eng.submit([1, 2, 3], 4, do_sample=True)
-    eng.close()
+        engines(net, **ONE).submit([1, 2, 3], 4, do_sample=True)

@@ -13,6 +13,9 @@ Three layers of guarantees:
   order), slot reuse without leaks, queue-full backpressure, deadline
   errors and graceful drain, fuzzed over a real engine plus
   deterministic mock-device scheduler tests.
+
+Two launches in flight (parity whatever ends a request a launch late, late
+rows, the pipeline's order) are ``tests/test_serving_in_flight.py``.
 """
 import threading
 import time
@@ -20,46 +23,22 @@ import time
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.framework import monitor, trace_probe
-from paddle_tpu.models import GPTConfig, GPTForPretraining, generate
+from paddle_tpu.models import generate
 from paddle_tpu.serving import (DeadlineExceeded, GenerationEngine,
                                 GenerationRequest, QueueFullError,
                                 RequestCancelled, Scheduler)
 
+import _toys
 from _mock_serving import MockDevice, mock_pool
 
-VOCAB = 96
+VOCAB = _toys.VOCAB
 
-
-@pytest.fixture(scope="module")
-def served_model():
-    """A tiny char GPT trained for a few steps: trained logits have
-    clear argmax margins, so greedy parity cannot flake on numeric
-    noise between the batched-slot and single-request programs."""
-    paddle.seed(11)
-    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=64, num_hidden_layers=2,
-                    num_attention_heads=4, intermediate_size=128,
-                    max_position_embeddings=64, hidden_dropout_prob=0.0,
-                    attention_dropout_prob=0.0)
-    model = GPTForPretraining(cfg)
-    opt = paddle.optimizer.Adam(learning_rate=3e-3,
-                                parameters=model.parameters())
-    corpus = ("the quick brown fox jumps over the lazy dog. "
-              "pack my box with five dozen liquor jugs. ") * 6
-    data = np.frombuffer(corpus.encode(), np.uint8).astype(np.int32) % VOCAB
-    rng = np.random.RandomState(0)
-    seq, batch = 24, 8
-    for _ in range(30):
-        starts = rng.randint(0, len(data) - seq - 1, batch)
-        chunk = np.stack([data[s:s + seq + 1] for s in starts])
-        loss, _ = model(paddle.to_tensor(chunk[:, :-1]),
-                        paddle.to_tensor(chunk[:, 1:].astype(np.int64)))
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-    model.eval()
-    return model
+# the engine of the tests that only serve a request or two (``engines``
+# hands it out drained, its pool and trie as new), and the one with room
+# for a request of 40 tokens beside two others
+PLAIN = dict(num_slots=2, max_len=48)
+LONG = dict(num_slots=2, max_len=64)
 
 
 def _prompt(rng, n):
@@ -71,24 +50,33 @@ def _prompt(rng, n):
 # ---------------------------------------------------------------------------
 
 class TestParity:
-    def test_single_request_matches_generate(self, served_model):
-        eng = GenerationEngine(served_model, num_slots=2, max_len=48)
+    def test_single_request_matches_generate(self, served_model, engines):
+        eng = engines(served_model, **PLAIN)
         p = _prompt(np.random.RandomState(1), 7)
         out = eng.submit(p, max_new_tokens=8).result(timeout=300)
         ref = generate(served_model, p[None, :], max_new_tokens=8)
         np.testing.assert_array_equal(out, ref.numpy()[0])
-        eng.close()
 
+    @pytest.mark.parametrize("block_size", [None, 8],
+                             ids=["default-block", "block-8"])
     def test_32_mixed_requests_parity_and_one_trace_per_bucket(
-            self, served_model):
-        """The acceptance criterion: 8 slots, 32 concurrent mixed-length
-        requests — all complete, outputs match per-request greedy
-        generate, and every fused (Q, T) program the storm reached was
-        traced exactly once, with no retrace cause on record."""
-        eng = GenerationEngine(served_model, num_slots=8, max_len=48)
+            self, served_model, block_size):
+        """The acceptance criterion, at the default block (16) and at the
+        kernel's smallest (8; until PR 45 a test of its own in
+        ``test_ragged_attention.py``, the same storm): 8 slots, concurrent
+        mixed-length requests — all complete, outputs match per-request
+        greedy generate, every fused (Q, T) program the storm reached was
+        traced exactly once with no retrace cause on record, the fused
+        step analyzes clean and no block leaks. 16 requests (32 until
+        PR 45): they fill the eight slots twice over and reach seven of
+        the eight programs the 32 reached (q 8, 16, 32, 64 against tables
+        of 1, 2 and 4 blocks at block 8; ``(q16, t4)`` is the one they
+        miss)."""
+        eng = GenerationEngine(served_model, num_slots=8, max_len=48,
+                               block_size=block_size)
         rng = np.random.RandomState(2)
         specs = [(_prompt(rng, int(rng.randint(2, 21))),
-                  int(rng.randint(1, 9))) for _ in range(32)]
+                  int(rng.randint(1, 9))) for _ in range(16)]
 
         handles = [None] * len(specs)
 
@@ -103,6 +91,8 @@ class TestParity:
         for t in threads:
             t.join()
         outs = [h.result(timeout=300) for h in handles]
+        report = eng.analyze()
+        stats = eng.stats()
         eng.close()
 
         for (p, n), out in zip(specs, outs):
@@ -112,16 +102,22 @@ class TestParity:
         # depends on scheduling, but the fused step is the ONLY serving
         # program and every bucket traces EXACTLY ONCE (traces > 1 would
         # be the retrace-storm bug class); the ladder is bounded by the
-        # pow2 products — q in {8..128} x table in {1, 2, 3} here
+        # pow2 products — q in {8..128} x table in {1, 2, 4, 6} at most
         sites = {k: v for k, v in trace_probe.snapshot().items()
                  if k.startswith("serving/") and f"#{eng._eid}" in k}
         assert sites, "serving probe sites missing"
         assert all(k.startswith("serving/fused[q") for k in sites), \
             sorted(sites)
-        assert len(sites) <= 15, sorted(sites)
+        assert len(sites) <= 20, sorted(sites)
         for name, rec in sites.items():
             assert rec["traces"] == 1, (name, rec)
             assert not rec["causes"], (name, rec)
+        # the clean bill: donation-safe, host-sync-free fused step
+        assert report.ok(), report.table()
+        assert "donation-safety" in report.passes_run
+        assert "host-sync" in report.passes_run
+        assert stats["active_requests"] == 0
+        assert stats["kv_blocks_in_use"] == 0
 
     def test_an_engine_built_with_no_options_serves_the_fused_step(
             self, served_model):
@@ -171,23 +167,22 @@ class TestParity:
             GenerationEngine(served_model, num_slots=1, max_len=16,
                              **kwargs)
 
-    def test_eos_early_stop_matches_generate(self, served_model):
+    def test_eos_early_stop_matches_generate(self, served_model, engines):
         p = _prompt(np.random.RandomState(3), 6)
         ref8 = generate(served_model, p[None, :], max_new_tokens=8)
         eos = int(ref8.numpy()[0, 6 + 2])   # stop at the third new token
         ref = generate(served_model, p[None, :], max_new_tokens=8,
                        eos_token_id=eos, pad_token_id=0)
-        eng = GenerationEngine(served_model, num_slots=2, max_len=48)
-        out = eng.submit(p, max_new_tokens=8, eos_token_id=eos) \
-                 .result(timeout=300)
-        eng.close()
+        out = engines(served_model, **PLAIN) \
+            .submit(p, max_new_tokens=8, eos_token_id=eos) \
+            .result(timeout=300)
         np.testing.assert_array_equal(out, ref.numpy()[0])
 
-    def test_streaming_yields_tokens_incrementally(self, served_model):
-        eng = GenerationEngine(served_model, num_slots=2, max_len=48)
+    def test_streaming_yields_tokens_incrementally(self, served_model,
+                                                   engines):
+        eng = engines(served_model, **PLAIN)
         p = _prompt(np.random.RandomState(4), 5)
         got = list(eng.stream(p, max_new_tokens=6))
-        eng.close()
         ref = generate(served_model, p[None, :], max_new_tokens=6)
         np.testing.assert_array_equal(np.asarray(got, np.int32),
                                       ref.numpy()[0, 5:])
@@ -212,12 +207,11 @@ class TestParity:
         for name, rec in sites.items():
             assert rec["traces"] == 1, (name, rec)
 
-    def test_analyze_clean_bill(self, served_model):
-        eng = GenerationEngine(served_model, num_slots=2, max_len=32)
+    def test_analyze_clean_bill(self, served_model, engines):
+        eng = engines(served_model, **PLAIN)
         eng.submit(_prompt(np.random.RandomState(6), 4),
                    max_new_tokens=2).result(timeout=300)
         report = eng.analyze()
-        eng.close()
         assert report.ok(), report.table()
         # donation-safe AND host-sync-free, not merely "no findings ran"
         assert "donation-safety" in report.passes_run
@@ -245,8 +239,9 @@ class TestChurn:
         assert monitor.stat_get("serving/completed") == 200
         eng.close()
 
-    def test_cancel_mid_generation_frees_the_slot(self, served_model):
-        eng = GenerationEngine(served_model, num_slots=2, max_len=64)
+    def test_cancel_mid_generation_frees_the_slot(self, served_model,
+                                                  engines):
+        eng = engines(served_model, **LONG)
         p = _prompt(np.random.RandomState(8), 4)
         h = eng.submit(p, max_new_tokens=40)
         it = h.stream()
@@ -261,8 +256,8 @@ class TestChurn:
         # capacity was reclaimed: a follow-up request still serves
         out = eng.submit(p, max_new_tokens=3).result(timeout=300)
         assert out.shape == (7,)
+        _toys.settle(eng)
         assert eng._pool.n_active == 0
-        eng.close()
 
     def test_close_drains_in_flight_work(self, served_model):
         eng = GenerationEngine(served_model, num_slots=2, max_len=48)
@@ -573,222 +568,3 @@ class TestPoolAndValidation:
         with pytest.raises(ValueError, match="at least one"):
             eng.submit(np.zeros(0, np.int32))
         eng.close()
-
-
-# ---------------------------------------------------------------------------
-# two launches in flight: launch N+1 is dispatched before launch N is
-# fetched and emitted, the next tokens stay on the device
-# ---------------------------------------------------------------------------
-
-def _launch_records(recorder):
-    """The records that describe a launch (a turn that only lands
-    records itself too, with no ``launch_q`` / ``decode_dispatch_ms``)."""
-    return [c for c in recorder.snapshot()["cycles"]
-            if c["decode_dispatch_ms"] > 0]
-
-
-def _submit_together(eng, specs):
-    """Submit ``specs`` (``(prompt, kwargs)``) so that ONE turn of the
-    scheduler admits them all: the queue's lock is re-entrant, so the
-    loop cannot look at the queue until the last one is in it."""
-    with eng._sched._cond:
-        return [eng.submit(p, **kw) for p, kw in specs]
-
-
-class TestTwoLaunchesInFlight:
-    @pytest.mark.parametrize("end", ["eos", "max_new_tokens", "cancel",
-                                     "deadline"])
-    def test_greedy_parity_whatever_ends_a_request_one_launch_late(
-            self, served_model, end):
-        """Two slots: X ends by ``end`` beside a long-running Y, and F,
-        queued behind them, takes over X's slot and blocks. Every token
-        anyone got is ``models.generate``'s; a request that the host
-        found ended one launch late (EOS, cancel, deadline) leaves a
-        LATE row behind, counted and dropped: nothing is emitted after
-        the end, and F reads none of the dead row's K/V."""
-        eng = GenerationEngine(served_model, num_slots=2, max_len=64)
-        rng = np.random.RandomState(31)
-        px, py, pf = _prompt(rng, 6), _prompt(rng, 9), _prompt(rng, 7)
-        # warm the (Q, T) programs so that a deadline is not spent on a
-        # compile
-        eng.submit(py, max_new_tokens=2).result(timeout=300)
-        ref_x = generate(served_model, px[None, :],
-                         max_new_tokens=30).numpy()[0, 6:]
-        kw = {"max_new_tokens": 30}
-        if end == "eos":
-            # a token first seen mid-stream, when two launches are in
-            # flight (the stretch's first launch lands in its own turn)
-            seen = list(ref_x)
-            at = next(i for i in range(3, 30) if seen.index(seen[i]) == i)
-            kw["eos_token_id"], n_x = int(seen[at]), at + 1
-        elif end == "max_new_tokens":
-            kw["max_new_tokens"] = n_x = 5
-        y = eng.submit(py, max_new_tokens=40)
-        x = eng.submit(px, **kw)
-        f = eng.submit(pf, max_new_tokens=6)
-        if end in ("cancel", "deadline"):
-            it = x.stream()
-            next(it)
-            if end == "cancel":
-                x.cancel()
-            else:
-                x.deadline = time.perf_counter()    # it passes mid-stream
-            with pytest.raises(RequestCancelled if end == "cancel"
-                               else DeadlineExceeded):
-                x.result(timeout=300)
-            n_x = len(x.tokens)
-            assert 1 <= n_x < 30
-        else:
-            assert x.result(timeout=300).shape == \
-                (6 + kw["max_new_tokens"],)
-        assert x._q.qsize() <= n_x + 1      # its tokens and the terminator
-        out_f, out_y = f.result(timeout=300), y.result(timeout=300)
-        eng.close()
-        assert len(x.tokens) == n_x         # nothing emitted after the end
-        np.testing.assert_array_equal(x.tokens, ref_x[:n_x])
-        for p, n, out in ((pf, 6, out_f), (py, 40, out_y)):
-            ref = generate(served_model, p[None, :], max_new_tokens=n)
-            np.testing.assert_array_equal(out, ref.numpy()[0])
-        launches = _launch_records(eng.flight_recorder)
-        late = sum(c["late_rows"] for c in launches)
-        # max_new_tokens is known at plan time: the request gets no row
-        # in the launch after its last token's. The other three the host
-        # learns at the emit, after that launch went out
-        assert late == (0 if end == "max_new_tokens" else 1), launches
-        assert late == eng._sched.late_rows
-        # two busy stretches (the warming request's, then this one): each
-        # opens with a launch that lands in its own turn, and the launch
-        # after that finds nothing in flight
-        assert sum(c["overlapped"] for c in launches) >= len(launches) - 6
-
-    def test_sampled_batch_is_reproducible_from_the_seed(self, served_model):
-        """Which launch a request lands in decides its key, so a batch
-        submitted together — one launch sequence — gives the same tokens
-        from two engines of one seed, and other tokens from another
-        seed's."""
-        rng = np.random.RandomState(32)
-        specs = [(_prompt(rng, 4 + i), dict(
-            max_new_tokens=8, do_sample=True, temperature=0.9))
-            for i in range(3)]
-
-        def run(seed):
-            eng = GenerationEngine(served_model, num_slots=4, max_len=48,
-                                   seed=seed)
-            outs = [h.result(timeout=300)
-                    for h in _submit_together(eng, specs)]
-            launches = _launch_records(eng.flight_recorder)
-            eng.close()
-            assert any(c["overlapped"] for c in launches)
-            return outs
-
-        a, b, c = run(5), run(5), run(6)
-        for u, v in zip(a, b):
-            np.testing.assert_array_equal(u, v)
-        assert any((u != w).any() for u, w in zip(a, c))
-
-    def test_the_next_token_never_visits_the_host(self):
-        """The chained mock answers a row by its INPUT token: a decode
-        row dispatched while the request's newest token is un-fetched
-        can only be right if the scheduler named the slot and the step
-        read the previous result."""
-        pool = mock_pool(slots=3, max_len=64)
-        dev = MockDevice(pool, chain=True)
-        sched = dev.scheduler(prefill_budget=8)
-        rng = np.random.RandomState(33)
-        prompts = [_prompt(rng, n) for n in (5, 13, 3)]
-        hs = [sched.submit(GenerationRequest(p, 7)) for p in prompts]
-        for p, h in zip(prompts, hs):
-            out = h.result(timeout=30)
-            assert list(out[len(p):]) == MockDevice.expected(p, 7)
-        sched.close()
-        assert any(dev.from_prev), "no launch read the previous result"
-        # a row reads the previous result only for a slot that had a
-        # token in it: planned there, feed drained by then
-        for before, plan, slots in zip(dev.launches, dev.launches[1:],
-                                       dev.from_prev[1:]):
-            assert set(slots) <= set(before) & set(plan)
-            assert all(plan[s] == 1 for s in slots)
-        launches = _launch_records(sched.recorder)
-        assert not launches[0]["overlapped"] and not launches[1]["overlapped"]
-        assert all(c["overlapped"] for c in launches[2:])
-        assert sched.late_rows == 0
-
-    def test_pool_pressure_drains_the_pipeline_before_it_preempts(self):
-        """4 usable blocks of 8, two requests that want 3 each: growth
-        exhausts the pool mid-decode. The launch in flight is landed
-        first, so the victim's history is whole at re-admission — the
-        chained mock would answer a dropped or doubled token with a
-        wrong successor."""
-        pool = mock_pool(slots=2, max_len=32, num_blocks=4)
-        dev = MockDevice(pool, chain=True)
-        sched = dev.scheduler()
-        rng = np.random.RandomState(34)
-        prompts = [_prompt(rng, 8), _prompt(rng, 8)]
-        hs = [sched.submit(GenerationRequest(p, 12)) for p in prompts]
-        for p, h in zip(prompts, hs):
-            out = h.result(timeout=30)
-            assert list(out[8:]) == MockDevice.expected(p, 12)
-        sched.close()
-        assert sched.preempts >= 1
-        launches = _launch_records(sched.recorder)
-        for c in launches:
-            if c["preempts"]:
-                assert not c["overlapped"], c
-        assert any(c["overlapped"] for c in launches)
-        assert pool.n_active == 0
-
-    def test_a_copy_on_write_drains_the_pipeline_first(self):
-        """A plan that has to copy a shared block lands the launch in
-        flight before the copy goes out."""
-        pool = mock_pool(slots=1, max_len=32)
-        dev = MockDevice(pool, chain=True)
-        seen = []
-
-        def step(slot_requests, plan, prev=None):
-            if len(dev.launches) == 3:
-                # someone else takes a reference to the block the NEXT
-                # decode row writes into: its append must copy
-                block = pool.slot_table(0)[pool.slot_pos(0) // 8]
-                pool._ref[block] = pool._ref.get(block, 1) + 1
-            return dev.do_step(slot_requests, plan, prev)
-
-        def copy(dst, src):
-            seen.append((sched._inflight is None, dst, src))
-
-        sched = Scheduler(pool, dev.do_prefill, step, do_copy=copy)
-        p = _prompt(np.random.RandomState(35), 4)
-        out = sched.submit(GenerationRequest(p, 10)).result(timeout=30)
-        sched.close()
-        assert list(out[4:]) == MockDevice.expected(p, 10)
-        assert len(seen) == 1 and seen[0][0], seen
-        launches = _launch_records(sched.recorder)
-        assert [c["overlapped"] for c in launches[:6]] == \
-            [False, False, True, True, False, True]
-
-    def test_a_failing_step_fails_the_launch_in_flight_too(self):
-        pool = mock_pool(slots=2)
-        dev = MockDevice(pool, chain=True)
-
-        def step(slot_requests, plan, prev=None):
-            if len(dev.launches) == 2:
-                dev.launches.append("failed")
-                raise RuntimeError("device fell over")
-            return dev.do_step(slot_requests, plan, prev)
-
-        sched = Scheduler(pool, dev.do_prefill, step)
-        hs = [sched.submit(GenerationRequest(np.ones(4, np.int32), 9))
-              for _ in range(2)]
-        for h in hs:
-            with pytest.raises(RuntimeError, match="serving step failed"):
-                h.result(timeout=10)
-            assert len(h.tokens) <= 1       # launch 2's tokens never came
-        assert pool.n_active == 0 and sched._inflight is None
-        # the loop survived and serves on
-        p = _prompt(np.random.RandomState(36), 5)
-        out = sched.submit(GenerationRequest(p, 4)).result(timeout=10)
-        assert list(out[5:]) == MockDevice.expected(p, 4)
-        sched.close()
-        failed = [c for c in sched.recorder.snapshot()["cycles"]
-                  if "failed" in c]
-        # the launch in flight and the turn whose dispatch failed
-        assert len(failed) == 2 and failed[0]["decode_dispatch_ms"] > 0

@@ -27,36 +27,6 @@ from paddle_tpu.serving import (BlockError, GenerationEngine, PagedKVPool,
 VOCAB = 96
 
 
-@pytest.fixture(scope="module")
-def served_model():
-    """A tiny char GPT trained for a few steps: trained logits have
-    clear argmax margins, so greedy parity between the engine's ragged
-    step and ``generate``'s loop cannot flake on numeric noise."""
-    paddle.seed(11)
-    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=64, num_hidden_layers=2,
-                    num_attention_heads=4, intermediate_size=128,
-                    max_position_embeddings=64, hidden_dropout_prob=0.0,
-                    attention_dropout_prob=0.0)
-    model = GPTForPretraining(cfg)
-    opt = paddle.optimizer.Adam(learning_rate=3e-3,
-                                parameters=model.parameters())
-    corpus = ("the quick brown fox jumps over the lazy dog. "
-              "pack my box with five dozen liquor jugs. ") * 6
-    data = np.frombuffer(corpus.encode(), np.uint8).astype(np.int32) % VOCAB
-    rng = np.random.RandomState(0)
-    seq, batch = 24, 8
-    for _ in range(30):
-        starts = rng.randint(0, len(data) - seq - 1, batch)
-        chunk = np.stack([data[s:s + seq + 1] for s in starts])
-        loss, _ = model(paddle.to_tensor(chunk[:, :-1]),
-                        paddle.to_tensor(chunk[:, 1:].astype(np.int64)))
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-    model.eval()
-    return model
-
-
 def _prompt(rng, n):
     return rng.randint(1, VOCAB, n).astype(np.int32)
 

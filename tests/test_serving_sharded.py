@@ -2,7 +2,7 @@
 
 The head-sharded engine must be a DROP-IN for the single-device one:
 
-* **parity** — 32 mixed concurrent greedy requests (a shared system
+* **parity** — 16 mixed concurrent greedy requests (a shared system
   prompt riding the prefix cache + copy-on-write, per-request EOS
   early stop, mixed lengths) through the mp=2 sharded FUSED engine are
   token-identical to the single-device fused engine, with ZERO
@@ -25,11 +25,12 @@ import pytest
 import jax
 from jax.sharding import Mesh
 
-import paddle_tpu as paddle
 from paddle_tpu.framework import trace_probe
-from paddle_tpu.models import GPTConfig, GPTForPretraining, generate
+from paddle_tpu.models import generate
 from paddle_tpu.profiler import memory as _memory
 from paddle_tpu.serving import GenerationEngine
+
+import _toys
 
 VOCAB = 96
 MP = 2
@@ -46,39 +47,11 @@ def _mesh():
 @pytest.fixture(scope="module")
 def make_model():
     """Factory for identically-trained tiny char GPTs. Sharding
-    device_puts the params IN PLACE (``shard_params_megatron``), so the
-    single-device and sharded engines must each get their OWN model —
-    seeded init + seeded data make every copy bit-identical, and the
-    few training steps give the logits clear argmax margins so greedy
-    parity cannot flake on the psum's reduction order."""
-    def make():
-        paddle.seed(11)
-        cfg = GPTConfig(vocab_size=VOCAB, hidden_size=64,
-                        num_hidden_layers=2, num_attention_heads=4,
-                        intermediate_size=128, max_position_embeddings=64,
-                        hidden_dropout_prob=0.0,
-                        attention_dropout_prob=0.0)
-        model = GPTForPretraining(cfg)
-        opt = paddle.optimizer.Adam(learning_rate=3e-3,
-                                    parameters=model.parameters())
-        corpus = ("the quick brown fox jumps over the lazy dog. "
-                  "pack my box with five dozen liquor jugs. ") * 6
-        data = np.frombuffer(corpus.encode(), np.uint8) \
-                 .astype(np.int32) % VOCAB
-        rng = np.random.RandomState(0)
-        seq, batch = 24, 8
-        for _ in range(30):
-            starts = rng.randint(0, len(data) - seq - 1, batch)
-            chunk = np.stack([data[s:s + seq + 1] for s in starts])
-            loss, _ = model(
-                paddle.to_tensor(chunk[:, :-1]),
-                paddle.to_tensor(chunk[:, 1:].astype(np.int64)))
-            loss.backward()
-            opt.step()
-            opt.clear_grad()
-        model.eval()
-        return model
-    return make
+    device_puts the params IN PLACE (``shard_params_megatron``), so a
+    sharded engine gets a model of its OWN — seeded init + seeded data
+    make every copy bit-identical to the one the single-device engines and
+    ``generate`` share."""
+    return _toys.new_char_gpt
 
 
 def _prompt(rng, n):
@@ -86,20 +59,27 @@ def _prompt(rng, n):
 
 
 def _specs():
-    """32 mixed requests: 12 share an 8-token system prompt (one whole
-    block — prefix-cache hits, then copy-on-write when the tails
-    diverge), 20 are random mixed lengths. EOS entries are patched in
-    by the test (the token needs a trained model to pick)."""
+    """16 mixed requests through 4 slots: 6 share an 8-token system prompt
+    (one whole block — prefix-cache hits, then copy-on-write when the
+    tails diverge), 10 are random mixed lengths. EOS entries are patched
+    in by the test (the token needs a trained model to pick). Until PR 45
+    there were 32 (12 + 20) through 8 slots, with contexts of up to 28
+    tokens: the shape of ``test_serving_engine.py``'s storm, which still
+    runs it on one device. What is THIS file's is the same kinds of
+    traffic through a mesh, and here a storm's cost is its step programs
+    (3 to 9 s each to build, tens of ms to run): contexts stay within two
+    blocks of 8, so that the table buckets are 1 and 2, and four slots
+    keep every slot full for the whole storm at half the rows a launch."""
     rng = np.random.RandomState(2)
     sys_prompt = _prompt(rng, 8)
     specs = []
-    for _ in range(12):
-        tail = _prompt(rng, int(rng.randint(1, 9)))
+    for _ in range(6):
+        tail = _prompt(rng, int(rng.randint(1, 5)))
         specs.append([np.concatenate([sys_prompt, tail]),
-                      int(rng.randint(2, 9)), None])
-    for _ in range(20):
-        specs.append([_prompt(rng, int(rng.randint(2, 21))),
-                      int(rng.randint(1, 9)), None])
+                      int(rng.randint(2, 5)), None])
+    for _ in range(10):
+        specs.append([_prompt(rng, int(rng.randint(2, 13))),
+                      int(rng.randint(1, 5)), None])
     return specs
 
 
@@ -131,7 +111,7 @@ def _warm(eng, specs):
 
 class TestShardedFusedParity:
     def test_32_mixed_requests_sharded_equals_single(self, make_model):
-        """The acceptance criterion: the same 32 mixed concurrent
+        """The acceptance criterion: the same mixed concurrent
         greedy requests (prefix hits, COW, EOS early stop) through the
         single-device fused engine and the mp=2 sharded fused engine
         produce token-identical output; the storm causes ZERO retraces
@@ -139,21 +119,22 @@ class TestShardedFusedParity:
         clean; and both stats() and the HBM ledger bill the sharded
         pool's per-device block bytes at exactly 1/mp."""
         specs = _specs()
-        single_model = make_model()
+        single_model = _toys.char_gpt()
         # per-request EOS on four mixed requests: the token the trained
         # model actually emits third, so both engines stop early at the
         # same position
-        for i in (3, 9, 17, 25):
+        short = [i for i, (p, _, _) in enumerate(specs) if len(p) <= 8]
+        assert len(short) >= 4
+        for i in short[:4]:
             p = specs[i][0]
             ref = generate(single_model, p[None, :], max_new_tokens=8)
             specs[i] = [p, 8, int(ref.numpy()[0, len(p) + 2])]
 
         def mk_engine(model, mesh):
-            return GenerationEngine(model, num_slots=8, max_len=48,
+            return GenerationEngine(model, num_slots=4, max_len=48,
                                     min_bucket=8, block_size=8, mesh=mesh)
 
         single = mk_engine(single_model, None)
-        _warm(single, specs)
         single_outs = _storm(single, specs)
         single_stats = single.stats()
         single.close()
@@ -225,7 +206,7 @@ class TestShardedPreemption:
         stats = eng.stats()
         eng.close()
         assert stats["preempts"] >= 1
-        ref_model = make_model()
+        ref_model = _toys.char_gpt()
         ra = generate(ref_model, pa[None, :], max_new_tokens=24)
         rb = generate(ref_model, pb[None, :], max_new_tokens=24)
         np.testing.assert_array_equal(oa, ra.numpy()[0])

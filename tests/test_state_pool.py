@@ -14,6 +14,8 @@ from paddle_tpu.framework.monitor import stat_histogram
 from paddle_tpu.profiler import memory as M
 from paddle_tpu.serving import PagedKVPool
 
+import _toys
+
 PARTS = (("conv", (3, 24), "float32"), ("ssm", (2, 4, 8), "float32"))
 SLOT_BYTES = 3 * (3 * 24 + 2 * 4 * 8) * 4          # three layers with state
 
@@ -155,20 +157,6 @@ COMPACT = {
 }
 
 
-def _toy(name):
-    if name == "gpt2":
-        from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
-        return GPTForPretraining(GPTConfig.tiny())
-    if name == "axk1":
-        from paddle_tpu.models.axk1 import AXK1Config, AXK1ForCausalLM
-        return AXK1ForCausalLM(AXK1Config.tiny())
-    if name == "sdar":
-        from paddle_tpu.models.sdar import SDARConfig, SDARForCausalLM
-        return SDARForCausalLM(SDARConfig.tiny())
-    from paddle_tpu.models.mimo import MiMoV2Config, MiMoV2ForCausalLM
-    return MiMoV2ForCausalLM(MiMoV2Config.tiny())
-
-
 def _signature(eng, net, Q, T):
     import jax
     from paddle_tpu.models.generation import build_fused_step_fn
@@ -191,7 +179,7 @@ def test_a_spec_without_state_builds_the_parents_step_programs(
     from paddle_tpu.models.decoder_spec import serving_decoder
     from paddle_tpu.serving import GenerationEngine
     monkeypatch.setattr(rpa, "TOWER_ROW_MULTIPLE", 8)
-    net = _toy(family)
+    net = _toys.default(family)
     spec = serving_decoder(net).spec
     assert spec.state is None and spec.state_layers == ()
     # the budget is no part of a program: it says which R a Q has

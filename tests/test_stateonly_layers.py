@@ -8,6 +8,7 @@ toy spec; the four stateless families' are held by
 import pytest
 
 from paddle_tpu.models import decoder_spec as DS
+import _toys
 from test_state_pool import _signature
 
 FULL = DS.CacheSpec(rows=2, lanes=16)
@@ -105,11 +106,9 @@ PARENT = {
 def test_a_state_beside_every_cache_builds_the_parents_program(
         Q, T, monkeypatch):
     import paddle_tpu.ops.ragged_paged_attention as rpa
-    from paddle_tpu.models.falcon_h1 import (FalconH1Config,
-                                             FalconH1ForCausalLM)
     from paddle_tpu.serving import GenerationEngine
     monkeypatch.setattr(rpa, "TOWER_ROW_MULTIPLE", 8)
-    net = FalconH1ForCausalLM(FalconH1Config.tiny())
+    net = _toys.default("falcon_h1")
     spec = DS.serving_decoder(net).spec
     assert spec.cache_layers == spec.state_layers == (0, 1)
     eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8,

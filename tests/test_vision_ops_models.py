@@ -1,4 +1,5 @@
-"""Tests for paddle.vision.ops (detection ops) and the extended model zoo."""
+"""Tests for paddle.vision.ops (detection ops) and channels-last layouts of
+the model zoo (its train steps: ``test_model_zoo_train.py``)."""
 import numpy as np
 import pytest
 
@@ -178,39 +179,6 @@ class TestYolo:
         assert np.isfinite(loss_v) and loss_v > 0
         paddle.mean(loss).backward()
         assert np.abs(np.asarray(x.grad._data)).sum() > 0
-
-
-class TestModelZooTrains:
-    def test_new_models_train_step(self):
-        import paddle_tpu.vision.models as M
-        rng = np.random.RandomState(7)
-        for ctor, size in [(M.squeezenet1_1, 64), (M.densenet121, 64),
-                           (M.mobilenet_v3_small, 64),
-                           (M.shufflenet_v2_x0_25, 64)]:
-            model = ctor(num_classes=4)
-            model.train()
-            opt = paddle.optimizer.SGD(learning_rate=0.01,
-                                       parameters=model.parameters())
-            x = t(rng.randn(2, 3, size, size).astype(np.float32))
-            y = t(rng.randint(0, 4, (2,)))
-            out = model(x)
-            loss = F.cross_entropy(out, y)
-            loss.backward()
-            opt.step()
-            opt.clear_grad()
-            assert np.isfinite(float(loss)), ctor.__name__
-
-    def test_googlenet_aux_heads(self):
-        import paddle_tpu.vision.models as M
-        m = M.googlenet(num_classes=4)
-        m.train()
-        x = t(np.random.randn(1, 3, 96, 96).astype(np.float32))
-        out, aux1, aux2 = m(x)
-        assert tuple(out.shape) == (1, 4)
-        assert tuple(aux1.shape) == (1, 4) and tuple(aux2.shape) == (1, 4)
-        m.eval()
-        out = m(x)
-        assert tuple(out.shape) == (1, 4)
 
 
 class TestChannelsLast:
