@@ -123,6 +123,13 @@ def routed_plan(n: int, num_experts: int, rows: int, k: int, E: int,
     return T, T * max(1, min(odd(most), odd(want + 1))), by_gather
 
 
+def latent_lanes(kv_lora_rank: int, qk_rope_head_dim: int) -> int:
+    """Lanes of a cached latent row: ``kv_lora_rank + qk_rope_head_dim``
+    rounded up to whole 128-lane tiles (576 -> 640; the pad lanes are
+    zeros and nothing reads them into a score)."""
+    return -(-(kv_lora_rank + qk_rope_head_dim) // 128) * 128
+
+
 def _default_rope_scaling() -> dict:
     return {"type": "yarn", "factor": 32, "beta_fast": 32, "beta_slow": 1,
             "mscale": 1, "mscale_all_dim": 1,
@@ -174,10 +181,7 @@ class AXK1Config:
 
     @property
     def latent_lanes(self) -> int:
-        """Lanes of a cached row: ``kv_lora_rank + qk_rope_head_dim``
-        rounded up to whole 128-lane tiles (576 -> 640; the pad lanes are
-        zeros and nothing reads them into a score)."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+        return latent_lanes(self.kv_lora_rank, self.qk_rope_head_dim)
 
     @classmethod
     def tiny(cls, **over):  # tests
@@ -254,18 +258,22 @@ def _rope(x, cos, sin):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _rms_norm(x, w, eps):
+def _rms_norm(x, w, eps, scale: float = 1.0):
+    """RMSNorm in float32, times ``scale`` there too: rounded once."""
     import jax
     import jax.numpy as jnp
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
+    y = y * w.astype(jnp.float32)
+    return (y if scale == 1.0 else y * scale).astype(x.dtype)
 
 
-def _mm(x, w):
-    """``x @ w`` in the weights' dtype, float32 accumulation on the MXU."""
+def _mm(x, w, scale: float = 1.0):
+    """``x @ w`` in the weights' dtype, float32 accumulation on the MXU
+    (times ``scale`` before the one rounding)."""
     import jax.numpy as jnp
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+    y = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    return (y if scale == 1.0 else y * scale).astype(x.dtype)
 
 
 def _swiglu(x, gate, up, down, gate_scale: float = 1.0):
@@ -329,11 +337,13 @@ def routed_experts(x, valid, idx, w, experts, held, num_experts: int):
     routed); ``idx``/``w [Q, k]`` from :func:`route_top_k`, which chose
     among ``num_experts``; ``experts = (gate [n, E, I], up [n, E, I], down
     [n, I, E])`` the ``n = hi - lo`` held experts; ``held = (lo, hi)``.
-    Returns ``(y [Q, E] float32, (pairs, experts_hit, rows, rows_walked))``
-    with ``y = sum over the row's chosen experts that are held of w_e
-    expert_e(x)`` and four int32 counters: the first three of REAL rows
-    only, the fourth the rows the grouped products were handed (real +
-    pad).
+    Returns ``(y [Q, E] float32, (pairs, experts_hit, rows, rows_walked,
+    zero_pairs))`` with ``y = sum over the row's chosen experts that are
+    held of w_e expert_e(x)`` and the ``decoder_spec.ROUTED_COUNTERS``
+    int32 counters: the first three of REAL rows only, the fourth the
+    rows the grouped products were handed (real + pad), the fifth — pairs
+    on identity experts — 0 here: a layer whose router has such experts
+    counts them where it adds their term (``models/longcat.py``).
 
     The layout (:func:`routed_plan`: ``T``, ``M`` and the combine, from
     the shapes alone): pairs on held experts are sorted by expert
@@ -434,7 +444,7 @@ def routed_experts(x, valid, idx, w, experts, held, num_experts: int):
         y = jax.lax.fori_loop(i32(0), trips, trip,
                               jnp.zeros(x.shape, jnp.float32))
     counters = (pairs, jnp.sum(counts > 0, dtype=i32),
-                jnp.sum(valid, dtype=i32), walked)
+                jnp.sum(valid, dtype=i32), walked, i32(0))
     return y, counters
 
 
@@ -479,10 +489,20 @@ def _param_maker(dtype, param_init: Optional[Callable],
 
 
 class AXK1Attention(nn.Layer):
-    def __init__(self, cfg: AXK1Config, make, prefix):
+    """MLA as the module doc has it. Two things a sibling switches on
+    (``models/longcat.py``): ``q_scale`` multiplies ``q = c_q W_qb`` and
+    ``kv_scale`` the normed ``c_kv`` — so the STORED row is ``[kv_scale
+    c_kv | k_pe]`` (``k_pe`` takes neither) and the absorbed and the
+    naive form read it as they read any ``c_kv`` — and a
+    ``cfg.rope_scaling`` of ``None`` is plain rotary positions: no
+    YaRN ramp, cos and sin unscaled, ``scale = (nope + rope)^-1/2``."""
+
+    def __init__(self, cfg, make, prefix, q_scale: float = 1.0,
+                 kv_scale: float = 1.0):
         super().__init__()
         p = _params(make, prefix)
         self.cfg = cfg
+        self.q_scale, self.kv_scale = float(q_scale), float(kv_scale)
         E, H = cfg.hidden_size, cfg.num_attention_heads
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         self.wq_a = p("wq_a", (E, cfg.q_lora_rank))
@@ -495,10 +515,18 @@ class AXK1Attention(nn.Layer):
             "wkv_b", (cfg.kv_lora_rank,
                       H * (cfg.qk_nope_head_dim + cfg.v_head_dim)))
         self.wo = p("wo", (H * cfg.v_head_dim, E))
-        self._inv_freq = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta,
-                                       cfg.rope_scaling)
-        self._cs_scale = yarn_cos_sin_scale(cfg.rope_scaling)
-        self.scale = yarn_attention_scale(cfg)
+        if cfg.rope_scaling is None:
+            rope = cfg.qk_rope_head_dim
+            self._inv_freq = (1.0 / float(cfg.rope_theta) ** (
+                np.arange(0, rope, 2, dtype=np.float64) / rope)
+                ).astype(np.float32)
+            self._cs_scale = 1.0
+            self.scale = qk ** -0.5
+        else:
+            self._inv_freq = yarn_inv_freq(cfg.qk_rope_head_dim,
+                                           cfg.rope_theta, cfg.rope_scaling)
+            self._cs_scale = yarn_cos_sin_scale(cfg.rope_scaling)
+            self.scale = yarn_attention_scale(cfg)
 
     def _cos_sin(self, positions):
         import jax.numpy as jnp
@@ -515,12 +543,13 @@ class AXK1Attention(nn.Layer):
         cos, sin = self._cos_sin(positions)
         c_q = _rms_norm(_mm(h, self.wq_a._data), self.q_norm._data,
                         cfg.rms_norm_eps)
-        q = _mm(c_q, self.wq_b._data).reshape(h.shape[0], H, -1)
+        q = _mm(c_q, self.wq_b._data, self.q_scale).reshape(
+            h.shape[0], H, -1)
         q_nope = q[..., :cfg.qk_nope_head_dim]
         q_pe = _rope(q[..., cfg.qk_nope_head_dim:], cos[:, None], sin[:, None])
         kv = _mm(h, self.wkv_a._data)
         c_kv = _rms_norm(kv[:, :cfg.kv_lora_rank], self.kv_norm._data,
-                         cfg.rms_norm_eps)
+                         cfg.rms_norm_eps, self.kv_scale)
         k_pe = _rope(kv[:, cfg.kv_lora_rank:], cos, sin)
         return q_nope, q_pe, c_kv, k_pe
 

@@ -21,6 +21,9 @@ object with
   residual, FFN; ``row_valid [Q]`` marks the rows of the ragged batch that
   are real, which a routed FFN must not route; ``counters`` is ``None``
   or the routed layer's ``ROUTED_COUNTERS`` int32 scalars); a layer whose
+  spec OPENS A SHORTCUT returns ``(x, counters, carried)``, ``carried [Q,
+  E]`` the sum of its routed experts, which the TOWER keeps and adds to
+  the stream at the end of the layer the shortcut closes at; a layer whose
   spec has a recurrent STATE also
   ``mixer(x, layout, state, index) -> (s, state)``
   (the rows in, the rows' sequence layout — ``ops/ssm.py:SeqLayout`` —
@@ -31,12 +34,25 @@ object with
   ``attn_out`` is handed ``None`` for the attention's output;
 * ``final_norm(x)`` and ``logits(hidden)``.
 
-Two attention kinds, two FFN kinds, three mixers (attention with its
-cache; attention with its cache and a recurrent state beside it; a
-recurrent state alone), one generation rule, six callers
-(``models/gpt.py``, ``models/axk1.py``, ``models/sdar.py``,
-``models/mimo.py``, ``models/falcon_h1.py``, ``models/lfm2.py``).
-Nothing else is described here.
+Two attention kinds, two FFN kinds and a routed shortcut around dense
+ones, three mixers (attention with its cache; attention with its cache
+and a recurrent state beside it; a recurrent state alone), one generation
+rule, seven callers (``models/gpt.py``, ``models/axk1.py``,
+``models/sdar.py``, ``models/mimo.py``, ``models/falcon_h1.py``,
+``models/lfm2.py``, ``models/longcat.py``). Nothing else is described
+here.
+
+**A routed shortcut** (``LayerSpec.shortcut`` n > 0; LongCat-Flash's
+shortcut-connected experts, n = 1): the layer's own FFN is dense, and
+BESIDE it routed experts read the layer's post-attention norm; their sum
+is no part of this layer's output — it joins the stream at the END of
+layer ``li + n``, after that layer's own FFN. So a second value crosses
+layer boundaries beside ``x``: the spec says where it opens and closes,
+``__post_init__`` holds it to one open shortcut at a time that closes
+inside the model, and the tower (``models/generation.py:_fused_tower``)
+alone carries it — a layer object keeps nothing between calls. Such a
+layer ROUTES (``LayerSpec.routes``: its launch counters ride the result
+as a routed layer's do) though its ``ffn`` is dense.
 
 **Recurrent state.** What a sequence leaves behind in a layer is its
 cache entries, which grow with the context, or a STATE of fixed size —
@@ -113,8 +129,10 @@ FULL, LATENT = "full", "latent"        # attention kinds
 DENSE, ROUTED = "dense", "routed"      # FFN kinds
 # the int32 scalars a routed layer's ``attn_out`` returns, summed over the
 # layers into a launch's result after its sentinel: pairs on held experts,
-# held experts hit, real rows routed, rows the grouped products walked
-ROUTED_COUNTERS = 4
+# held experts hit, real rows routed, rows the grouped products walked,
+# pairs that fell on identity ("zero-computation") experts — of a router
+# wider than the experts with weights (LongCat-Flash); 0 elsewhere
+ROUTED_COUNTERS = 5
 
 # The SECTIONS of a launch: the one vocabulary of ``jax.named_scope``s the
 # step programs put their device work under, so that every op of a launch
@@ -132,7 +150,9 @@ O_PROJ = "o_proj"                # output projection and its residual
 ROUTER = "router"                # expert scores and the choice
 MOE_SCOPE = "moe_experts"        # the routed experts (router, shared inside)
 SHARED_EXPERT = "shared_expert"
+ZERO_EXPERTS = "zero_experts"    # identity experts: their weights' sum x u
 MLP = "mlp"                      # a dense FFN; the add that closes a layer
+SHORTCUT = "shortcut"            # the add that closes a routed shortcut
 HEAD = "head"                    # the logits of the rows that are read
 SAMPLE = "sample"                # the pick of one token a slot
 UNMASK_SCOPE = "unmask"          # a block pass's head, confidence, choice
@@ -144,7 +164,7 @@ SSM_CONV = "ssm_conv"            # its causal convolution and the tail
 SSM_SCAN = "ssm_scan"            # its recurrence and the D skip
 SECTIONS = (EMBED, NORM, QKV, CACHE_WRITE, ATTENTION, O_PROJ, ROUTER,
             MOE_SCOPE, SHARED_EXPERT, MLP, HEAD, SAMPLE, UNMASK_SCOPE,
-            SSM_PROJ, SSM_CONV, SSM_SCAN)
+            SSM_PROJ, SSM_CONV, SSM_SCAN, ZERO_EXPERTS, SHORTCUT)
 
 
 def section(name: str):
@@ -240,11 +260,28 @@ class LayerSpec:
     sinks: bool = False      # a learned logit a query head in the softmax
     query_heads: int = 0     # 0: as many as the cache's rows (KV heads)
     state: Optional[StateSpec] = None   # beside the cache, or alone
+    # n > 0: routed experts BESIDE the dense FFN read this layer's
+    # post-attention norm, and their sum joins the stream at the end of
+    # layer ``li + n`` (module doc)
+    shortcut: int = 0
+
+    @property
+    def routes(self) -> bool:
+        """The layer has a router: its FFN is routed, or it opens a
+        routed shortcut around a dense one."""
+        return self.ffn == ROUTED or self.shortcut > 0
 
     def __post_init__(self):
         if self.ffn not in (DENSE, ROUTED):
             raise ValueError(f"FFN kind {self.ffn!r}: the fused path knows "
                              f"{DENSE!r} and {ROUTED!r}")
+        if self.shortcut < 0:
+            raise ValueError(f"shortcut {self.shortcut} must be >= 0")
+        if self.shortcut and self.ffn == ROUTED:
+            raise ValueError(
+                "a routed shortcut is built around a DENSE FFN: the "
+                "layer's own FFN is routed already, and one router a layer "
+                "is what the launch counters count")
         if (self.attention is None) != (self.cache is None):
             raise ValueError(
                 "an attention kind and a cache descriptor come together: "
@@ -336,6 +373,23 @@ class DecoderSpec:
                 "alone layers only is not built (a sequence's positions, "
                 "its page table and the launch's row layout are the "
                 "cache's)")
+        closes = -1              # the layer the open shortcut closes at
+        for i, ls in enumerate(self.layers):
+            if not ls.shortcut:
+                continue
+            if i <= closes:
+                raise ValueError(
+                    f"layer {i} opens a routed shortcut before the one "
+                    f"that closes at layer {closes} has closed: the tower "
+                    f"carries ONE value beside x (nested or overlapping "
+                    f"shortcuts are not built)")
+            closes = i + ls.shortcut
+            if closes >= len(self.layers):
+                raise ValueError(
+                    f"layer {i}'s routed shortcut closes at layer "
+                    f"{closes}, past the last layer "
+                    f"{len(self.layers) - 1}: its experts' sum would "
+                    f"join nothing")
         if self.generation.block_length > 1 and len(self.cache_layers) \
                 < len(self.layers):
             raise ValueError(
@@ -456,5 +510,6 @@ def serving_decoder(model):
             f"fused serving stack consumes a decoder spec "
             f"(models/decoder_spec.py), which models/gpt.py, "
             f"models/axk1.py, models/sdar.py, models/mimo.py, "
-            f"models/falcon_h1.py and models/lfm2.py provide")
+            f"models/falcon_h1.py, models/lfm2.py and models/longcat.py "
+            f"provide")
     return make()

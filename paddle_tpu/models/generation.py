@@ -780,8 +780,14 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     layer gets its group's (its window and sinks from its ``LayerSpec``);
     the pools come back as a tuple too.
     A ``routed`` FFN is told which rows are real (pad rows name the
-    scratch block 0) and returns its four counters, summed over the
-    layers here. A layer whose spec has a recurrent STATE runs its mixer
+    scratch block 0) and returns its ``ROUTED_COUNTERS`` counters, summed
+    over the layers here. A layer whose spec OPENS A ROUTED SHORTCUT
+    (``LayerSpec.shortcut`` n) returns its experts' sum beside ``(x,
+    counters)``: the value is held HERE, and added to the stream after
+    layer ``li + n``'s own ``attn_out``, under that layer's scope and the
+    section ``shortcut`` — nothing passes from a layer to a later one but
+    through this loop.
+    A layer whose spec has a recurrent STATE runs its mixer
     beside the attention: ``state`` is the slots' state arrays (one a
     part of the descriptor, ``[layers with state, slots + 1, ...]``), the
     mixer reads each sequence's row of them and leaves the state after
@@ -838,13 +844,25 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
             kwbs = tuple(_at_rows(w, axes, 0, to_kernel=True) for w in wbs)
             koff = _at_rows(write_off, axes, 0, to_kernel=True)
     counters = None
+    carried = {}      # closing layer -> the open shortcut's experts' sum
 
-    def summed(counters, c):
-        """A routed layer's counters added to the layers' before."""
+    def landed(li, ls, out, counters):
+        """Layer ``li``'s ``attn_out`` result into ``(x, counters)``: a
+        routed layer's counters added to the layers' before, an opened
+        shortcut's value kept for its closing layer, and the one that
+        closes here added to the stream."""
+        if ls.shortcut:
+            x, c, carried[li + ls.shortcut] = out
+        else:
+            x, c = out
+        if li in carried:
+            with DS.section(DS.SHORTCUT):
+                x = Tensor(x._data + carried.pop(li)[None],
+                           stop_gradient=True)
         if c is None:
-            return counters
+            return x, counters
         with DS.section(DS.MOE_SCOPE):
-            return c if counters is None else tuple(
+            return x, c if counters is None else tuple(
                 u + v for u, v in zip(counters, c))
 
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
@@ -855,8 +873,8 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
             with DS.layer_scope(li):
                 mixed, state = layer.mixer(
                     x, layout, state, dec.spec.state_layers.index(li))
-                x, c = layer.attn_out(x, None, row_valid, mixed)
-                counters = summed(counters, c)
+                x, counters = landed(li, ls, layer.attn_out(
+                    x, None, row_valid, mixed), counters)
             continue
         # the layer's cache group, and its place in the group's array
         g, gi = dec.spec.layer_group(li)
@@ -907,10 +925,10 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                 # the mixer names its own sections too
                 mixed, state = layer.mixer(
                     x, layout, state, dec.spec.state_layers.index(li))
-                x, c = layer.attn_out(x, a, row_valid, mixed)
+                out = layer.attn_out(x, a, row_valid, mixed)
             else:
-                x, c = layer.attn_out(x, a, row_valid)
-            counters = summed(counters, c)
+                out = layer.attn_out(x, a, row_valid)
+            x, counters = landed(li, ls, out, counters)
     if counters is not None:
         with DS.section(DS.MOE_SCOPE):
             counters = jnp.stack(counters).astype(jnp.int32)
@@ -954,8 +972,8 @@ def block_result_layout(num_slots: int, block_length: int, routed: bool):
     sentinel_at, counters_at, tokens_at, passes_at, size)`` into the one
     int32 array a launch returns — ``[S]`` the position each slot fixed
     last this pass (-1: none), the logits-finite sentinel, a routed
-    model's four counters (the same places as in a one-token step's
-    result, so the scheduler's readers of those are one), then the block
+    model's ``ROUTED_COUNTERS`` counters (the same places as in a
+    one-token step's result, so the scheduler's readers of those are one), then the block
     state: ``[S * B]`` token ids and ``[S * B]`` the pass each position
     was fixed in."""
     from .decoder_spec import ROUTED_COUNTERS
@@ -1046,7 +1064,7 @@ def _build_block_step_fn(model, dec, S, Q, T, probe):
 
     rule = dec.spec.generation
     B = int(rule.block_length)
-    routed = any(ls.ffn == DS.ROUTED for ls in dec.spec.layers)
+    routed = any(ls.routes for ls in dec.spec.layers)
     _, _, _, tokens_at, passes_at, size = block_result_layout(S, B, routed)
 
     def fn(params, buffers, pool, token_ids, qpos, write_block, write_off,

@@ -605,11 +605,11 @@ class GenerationEngine:
         self._fused_jits = {}         # (q bucket, table bucket) -> step
         # the fused step's "previous result" operand while no launch is
         # in flight: the shape of its own result ([slots | sentinel |
-        # a routed model's four counters]), never read (token_src -1)
+        # a routed model's counters]), never read (token_src -1)
         # (a block-generation step's result holds the block state too)
-        from ..models.decoder_spec import ROUTED, ROUTED_COUNTERS
+        from ..models.decoder_spec import ROUTED_COUNTERS
         from ..models.generation import block_result_layout
-        routed = any(ls.ffn == ROUTED for ls in spec.layers)
+        routed = any(ls.routes for ls in spec.layers)
         self._no_prev = np.zeros(
             num_slots + 1 + ROUTED_COUNTERS * routed
             if spec.generation.block_length == 1 else block_result_layout(

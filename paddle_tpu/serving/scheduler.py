@@ -722,24 +722,28 @@ class Scheduler:
                 rec["nonfinite"] = True
 
     def _note_routed(self, toks, rec) -> None:
-        """Read a routed model's four launch counters off the fetched
-        token row (elements ``[num_slots + 1 : num_slots + 5]``, after
-        the sentinel; absent from every other model's row) into the
+        """Read a routed model's launch counters off the fetched token
+        row (the ``decoder_spec.ROUTED_COUNTERS`` elements after the
+        sentinel; absent from every other model's row) into the
         cycle record: ``moe_pairs`` (real rows x held experts they
         chose, summed over the expert layers), ``moe_experts_hit`` (held
         experts with at least one token, summed over the layers),
         ``moe_rows`` (real rows routed, summed over the layers) and
         ``moe_rows_walked`` (the rows the grouped products were handed:
         the pairs and the zero rows that pad an expert's group to whole
-        tiles, summed over the layers). They ride the cycle's one fetch:
-        no sync of their own."""
+        tiles, summed over the layers) and ``moe_zero_pairs`` (real rows
+        x identity experts they chose — experts without weights, which
+        cost nothing: of ``moe_rows`` x k choices, these and the ones on
+        absent experts are in no ``moe_pairs``; 0 for a model without
+        such experts). They ride the cycle's one fetch: no sync of their
+        own."""
+        from ..models.decoder_spec import ROUTED_COUNTERS
         at = self._pool.num_slots + 1
         shape = getattr(toks, "shape", None)
-        if rec is not None and shape and shape[0] >= at + 4:
-            rec.update(moe_pairs=int(toks[at]),
-                       moe_experts_hit=int(toks[at + 1]),
-                       moe_rows=int(toks[at + 2]),
-                       moe_rows_walked=int(toks[at + 3]))
+        if rec is not None and shape and shape[0] >= at + ROUTED_COUNTERS:
+            rec.update(zip(("moe_pairs", "moe_experts_hit", "moe_rows",
+                            "moe_rows_walked", "moe_zero_pairs"),
+                           (int(v) for v in toks[at:at + ROUTED_COUNTERS])))
 
     def note_decode_flops(self, flops: float) -> None:
         """Record the FLOPs of the decode program dispatched THIS cycle

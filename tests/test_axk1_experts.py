@@ -55,8 +55,9 @@ def test_one_expert_gets_every_token_one_gets_none_and_nothing_is_dropped(
     # trips of 8 rows: many trips, expert 4 alone fills five; 64: one or two
     for T, M in ((1, 8), (8, 8), (8, 24), (1, 64), (16, 144)):
         _forced(monkeypatch, T, M, gather)
-        y, (pairs, hit, rows, walked) = AX.routed_experts(
+        y, (pairs, hit, rows, walked, zero) = AX.routed_experts(
             x, jnp.ones(Q, bool), idx, w, experts, held, 16)
+        assert int(zero) == 0         # no identity experts in this router
         np.testing.assert_allclose(np.asarray(y), want, atol=ORDER_OF_SUM)
         on_held = np.asarray(idx)[(np.asarray(idx) >= 4) & (np.asarray(idx) < 8)]
         assert (int(pairs), int(rows)) == (on_held.size, Q)
@@ -150,7 +151,7 @@ def test_the_aligned_layout_is_the_dense_per_expert_sum(monkeypatch, case,
     assert np.all(np.asarray(y)[~valid] == 0.0)
     assert [int(c) for c in counters] == [
         on_held.size, int(np.sum(counts > 0)), int(valid.sum()),
-        int(sum(-(-counts // T) * T))]
+        int(sum(-(-counts // T) * T)), 0]
 
 
 # (held, experts, rows, k, E, I) of the routed-expert cells' launches

@@ -46,7 +46,7 @@ def test_section_of_takes_the_innermost_word():
         == DS.MOE_SCOPE
     assert DS.section_of("jit(f)/layer3/add") is None
     assert DS.MOE_SCOPE == "moe_experts" and DS.UNMASK_SCOPE == "unmask"
-    assert len(set(DS.SECTIONS)) == len(DS.SECTIONS) == 16
+    assert len(set(DS.SECTIONS)) == len(DS.SECTIONS) == 18
     with pytest.raises(ValueError, match="no section"):
         DS.section("attn")
 

@@ -30,7 +30,8 @@ CELLS = [("decode-backlog", "gpt2-large"),
          ("decode-backlog-s128", "axk1-ep16"),
          ("long-backlog-s128", "mimo-v2-flash-ep16"),
          ("long-backlog-s128", "lfm2-24b-a2b-pp4"),
-         ("decode-heavy-backlog-s64", "falcon-h1-34b-pp12")]
+         ("decode-heavy-backlog-s64", "falcon-h1-34b-pp12"),
+         ("decode-heavy-backlog-s128", "longcat-flash-ep32")]
 
 
 def _load(traffic, config):
