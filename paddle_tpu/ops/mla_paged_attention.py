@@ -25,6 +25,34 @@ computes on that row's ``H`` MXU rows alone — it is not padded to
 ``BLOCK_Q x H``. Both bodies are in the one kernel; ``kv_len - pos0``
 (the sequence's real rows this launch) picks.
 
+A block that small makes what the walk pays a block weigh as much as its
+bytes, so the walk is a pipeline of three parts (PR 47; ``benchmark/
+tools/latent_walk_sweep.py`` times each):
+
+* **one fetch = one wait.** The copies of a group signal ONE semaphore a
+  buffer and the group is awaited by one wait for the bytes of the whole
+  buffer. A sequence's last group may be partial: copies start, and are
+  waited for, in QUARTERS of a group (8 blocks), the last quarter filled
+  up with the pool's scratch block ``NB`` (finite junk the pad rows of a
+  launch are written to) — at most 7 blocks a sequence are read for
+  nothing, and their rows lie past ``kv_len``, where the select below
+  zeroes them and the mask silences their columns.
+* **the issue is unrolled a quarter at a time**: a loop over the
+  quarters a group holds, its body 8 copies started back to back — no
+  loop trip and no branch a block. In the compiled schedule a started
+  DMA holds back every vector load after it, so the issue shares no
+  bundle with the products wherever it stands; it stands where the
+  parent's did, before this group's wait (after it the next group's
+  copies start later and nothing is won: measured).
+* **a sequence's first group is in flight before its grid step begins.**
+  The last trip of a step's walk starts group 0 of the NEXT q block's
+  sequence (a chunk's next q block: the same sequence again) into the
+  buffer it does not hold, and leaves the buffer's index in SMEM; the
+  grid is sequential and scratch persists, so the next step finds it
+  started. The first real block of a call (and one after a pad block)
+  starts its own; a block followed by a pad block or by the end of the
+  grid starts nothing, so no copy is outstanding when the call returns.
+
 Layout contract: ``q [Qp, H, lanes]`` flattened padded rows as
 ``ragged_layout`` lays them out; the pool ``[L, NB + 1, 1, bs, lanes]``;
 the same scalar-prefetch metadata as the per-head kernel, with
@@ -66,18 +94,94 @@ def latent_group_blocks(block_size: int, lanes: int, dtype) -> int:
 
 
 def _mla_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
-                lo_ref, kvlen_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem, *,
-                block_q, n_heads, block_size, group, scale, v_lanes):
+                lo_ref, kvlen_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem,
+                slot_ref, *, block_q, n_heads, block_size, group, scale,
+                v_lanes):
     """One q-block grid step: walk the owning sequence's page table once,
     a group of ``group`` latent blocks at a time, and stream the online
     softmax of ``[rows * H, group * block_size]`` score tiles against the
-    one cached row. i32-typed constants throughout (the framework's
-    global x64, as in ``_rpa_kernel``)."""
+    one cached row. ``kv_scr`` / ``kv_sem`` are the two group buffers and
+    their semaphores, ``slot_ref`` the buffer the NEXT step's walk begins
+    on (the module doc's pipeline). i32-typed constants throughout (the
+    framework's global x64, as in ``_rpa_kernel``)."""
     b = pl.program_id(0)
+    n_blk = pl.num_programs(0)
     layer = layer_ref[0]
     seq = blk_seq_ref[b]
     cols_g = group * block_size
     t_len = tables_ref.shape[1]
+    scratch_block = jnp.int32(pool_ref.shape[1] - 1)
+    # copies start and are waited for in quarters of a group
+    parts = 4 if group % 4 == 0 else 1
+    part = group // parts
+    _BS = jnp.int32(block_size)
+    _G = jnp.int32(group)
+    _CG = jnp.int32(cols_g)
+
+    # the scalar arithmetic of the issue is spelled in lax primitives: a
+    # jnp operator on a traced scalar is a jitted function of its own,
+    # traced again at every one of the unrolled starts (~180 of them a
+    # step program: 0.6 s a program on the chip's host, PERF.md 47.5)
+    def blocks_of(s):
+        return jax.lax.min(
+            jax.lax.div(jax.lax.add(kvlen_ref[s], jnp.int32(block_size - 1)),
+                        _BS), jnp.int32(t_len))
+
+    def quarters_of(s_blocks, j0):
+        """quarters of the group at block ``j0`` that hold a block of a
+        sequence of ``s_blocks`` blocks"""
+        own = jax.lax.min(_G, jax.lax.sub(s_blocks, j0))
+        return jax.lax.div(jax.lax.add(own, jnp.int32(part - 1)),
+                           jnp.int32(part))
+
+    def quarter_rows(p):
+        return pl.ds(pl.multiple_of(
+            jax.lax.mul(p, jnp.int32(part * block_size)),
+            part * block_size), part * block_size)
+
+    def start_group(s, s_blocks, j0, slot, cond):
+        """If ``cond``: start the copies of sequence ``s``'s group at
+        block ``j0`` into buffer ``slot`` — the quarters that hold a
+        block of it, each whole (past ``s_blocks``: the scratch block)."""
+        def quarter(p, carry):
+            dst = kv_scr.at[slot, :, quarter_rows(p), :]
+            j_first = jax.lax.add(j0, jax.lax.mul(p, jnp.int32(part)))
+            for g in range(part):
+                j = jax.lax.add(j_first, jnp.int32(g))
+                pid = tables_ref[s, jax.lax.min(j, jnp.int32(t_len - 1))]
+                pid = jax.lax.select(jax.lax.lt(j, s_blocks), pid,
+                                     scratch_block)
+                pltpu.make_async_copy(
+                    pool_ref.at[layer, pid],
+                    dst.at[:, pl.ds(g * block_size, block_size), :],
+                    kv_sem.at[slot]).start()
+            return carry
+
+        @pl.when(cond)
+        def _issue():
+            jax.lax.fori_loop(jnp.int32(0), quarters_of(s_blocks, j0),
+                              quarter, jnp.int32(0))
+
+    def wait_group(s_blocks, j0, slot):
+        """Wait for what ``start_group`` started there: a whole group in
+        ONE wait for the buffer's bytes, a partial one a quarter a wait."""
+        def wait_for(dst):
+            # a wait reads its byte count off the destination alone
+            pltpu.make_async_copy(dst, dst, kv_sem.at[slot]).wait()
+
+        def quarter(p, carry):
+            wait_for(kv_scr.at[slot, :, quarter_rows(p), :])
+            return carry
+
+        n_parts = quarters_of(s_blocks, j0)
+
+        @pl.when(jax.lax.eq(n_parts, jnp.int32(parts)))
+        def _whole():
+            wait_for(kv_scr.at[slot])
+
+        @pl.when(jax.lax.lt(n_parts, jnp.int32(parts)))
+        def _partial():
+            jax.lax.fori_loop(jnp.int32(0), n_parts, quarter, jnp.int32(0))
 
     @pl.when(seq < 0)
     def _pad_block():
@@ -85,30 +189,22 @@ def _mla_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
 
     @pl.when(seq >= 0)
     def _attend():
-        _BS = jnp.int32(block_size)
-        _G = jnp.int32(group)
-        _CG = jnp.int32(cols_g)
         pos_first = pos0_ref[seq] + b * jnp.int32(block_q) - qstart_ref[seq]
         lo = lo_ref[seq]
         kv_len = kvlen_ref[seq]
-        n_kv = jnp.minimum((kv_len + _BS - 1) // _BS, jnp.int32(t_len))
+        n_kv = blocks_of(seq)
         n_grp = (n_kv + _G - 1) // _G
-
-        def block_copies(grp, slot, act):
-            j0 = grp * _G
-
-            def one(g, carry):
-                rows = pl.ds(pl.multiple_of(g * _BS, block_size),
-                             block_size)
-                act(pltpu.make_async_copy(
-                    pool_ref.at[layer, tables_ref[seq, j0 + g]],
-                    kv_scr.at[slot, :, rows, :], kv_sem.at[slot, g]))
-                return carry
-
-            jax.lax.fori_loop(jnp.int32(0), jnp.minimum(_G, n_kv - j0),
-                              one, jnp.int32(0))
-
-        block_copies(jnp.int32(0), jnp.int32(0), lambda cp: cp.start())
+        # the step before this one started group 0 unless it was a pad
+        # block's (or there was none): then this step starts its own
+        first = (b == 0) | (blk_seq_ref[jnp.maximum(b - 1, 0)] < 0)
+        slot0 = jnp.where(first, jnp.int32(0), slot_ref[0])
+        nxt = jnp.where(b + 1 < n_blk,
+                        blk_seq_ref[jnp.minimum(b + 1, n_blk - 1)],
+                        jnp.int32(-1))
+        nxt_seq = jnp.maximum(nxt, 0)
+        nxt_blocks = blocks_of(nxt_seq)
+        slot_ref[0] = (slot0 + n_grp) % 2
+        start_group(seq, n_kv, jnp.int32(0), slot0, first)
 
         def walk(q):
             """Online softmax of ``q [M, lanes]`` (``M = rows * H``, row
@@ -120,16 +216,20 @@ def _mla_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
 
             def body(grp, carry):
                 m_prev, l_prev, acc = carry
-                slot = grp % 2
-
-                @pl.when(grp + 1 < n_grp)
-                def _prefetch():
-                    block_copies(grp + 1, 1 - slot, lambda cp: cp.start())
-
-                block_copies(grp, slot, lambda cp: cp.wait())
-                # rows no block of this sequence filled, and a last
-                # block's rows past kv_len, go to the MXU as zeros (a 0
-                # weight does not silence a NaN)
+                slot = (slot0 + grp) % 2
+                # what follows this group: the sequence's next, or after
+                # its last the first of the next q block's sequence
+                in_seq = grp + 1 < n_grp
+                start_group(jnp.where(in_seq, seq, nxt_seq),
+                            jnp.where(in_seq, n_kv, nxt_blocks),
+                            jnp.where(in_seq, (grp + 1) * _G, 0), 1 - slot,
+                            in_seq | (nxt >= 0))
+                wait_group(n_kv, grp * _G, slot)
+                # rows no copy of THIS walk filled, the scratch block's
+                # and a last block's rows past kv_len go to the MXU as
+                # zeros (a 0 weight does not silence a NaN); in every
+                # group: under the products' loads the select is free,
+                # in the last group alone it cost a pass of its own
                 kv_rows = grp * _CG + jax.lax.broadcasted_iota(
                     jnp.int32, (cols_g, 1), 0)
                 kv = kv_scr[slot, 0]                      # [G*bs, lanes]
@@ -244,7 +344,8 @@ def _mla_call(layer, q2, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
         out_specs=pl.BlockSpec((m_blk, v_lanes), lambda b, *_: (b, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, 1, group * bs, lanes), pool.dtype),
-            pltpu.SemaphoreType.DMA((2, group)),
+            pltpu.SemaphoreType.DMA((2,)),          # one a group buffer
+            pltpu.SMEM((1,), jnp.int32),    # the buffer the next step begins on
         ],
     )
     return pl.pallas_call(

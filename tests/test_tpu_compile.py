@@ -240,6 +240,44 @@ def test_wide_q_step_compiles_at_the_cells_widths(v5e, hq, hkv, dk, dv,
     assert "ragged_paged_attention" in text
 
 
+@pytest.mark.parametrize("q_lens,q_bucket,table_len", [
+    ([1] * 128, 1024, 192),              # 128 decode rows: the plain launch
+    ([1] * 128 + [1024], 2048, 320),     # the same beside a prompt chunk
+], ids=["decode-q1024", "decode-and-chunk-q2048"])
+def test_mla_paged_attention_compiles_at_the_cells_widths(v5e, q_lens,
+                                                          q_bucket,
+                                                          table_len):
+    """The latent kernel at the widths of the two cells that run it
+    (``axk1-ep16``, ``longcat-flash-ep32``): 64 heads folded into the
+    rows, stored rows of 640 lanes whose first 512 are the value, blocks
+    of 16 tokens over a pool of the cells' order of blocks. A group is 32
+    blocks of 20 KB, its copies unrolled (what Mosaic refuses of them —
+    a slice off the tiling, a wait whose bytes no copy has, scratch past
+    VMEM — shows here), and the two group buffers stay inside the budget
+    the per-head kernel states."""
+    from paddle_tpu.ops.mla_paged_attention import (latent_group_blocks,
+                                                    mla_paged_attention)
+    S, heads, lanes, v_lanes, bs, NB = len(q_lens), 64, 640, 512, 16, 33000
+    group = latent_group_blocks(bs, lanes, "bfloat16")
+    assert group == 32
+    assert 2 * group * bs * lanes * 2 <= KV_VMEM_BUDGET
+    blk_seq, qstart, pos0, _, _ = ragged_layout(q_lens, [1500] * S,
+                                                q_bucket=q_bucket)
+    tables, lo = np.zeros((S, table_len), np.int32), np.zeros(S, np.int32)
+    kv_len = np.asarray([1500 + n for n in q_lens], np.int32)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    def fn(q, pool):
+        return mla_paged_attention(q, pool, 3, blk_seq, qstart, pos0,
+                                   tables, lo, kv_len, v_lanes=v_lanes,
+                                   scale=0.1)
+
+    text = _compile(fn, sds((q_bucket, heads, lanes), jnp.bfloat16),
+                    sds((8, NB + 1, 1, bs, lanes), jnp.bfloat16))
+    assert text.count("tpu_custom_call") == 1
+    assert "mla_paged_attention" in text
+
+
 @pytest.mark.parametrize("hkv", [4, 8], ids=["global", "window"])
 def test_kv_append_compiles_for_rows_of_384_lanes(v5e, hkv):
     """The same model's cache write: K 192 | V 128 in rows of 384."""
