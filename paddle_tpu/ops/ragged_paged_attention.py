@@ -45,6 +45,34 @@ later blocks (``_walk_extent``; a decode row's last column is ``kv_len
 host, the DMAs and group waits of a launch from the same two functions
 the kernel decides by.
 
+The hand-over (PR 49): **a walk's first group is in flight before the
+walk begins.** The last trip of a walk starts group 0 of the NEXT walk —
+whatever the kernel will really do next, decided by the functions it
+decides by: the next q block of this grid step, or from a step's last
+walk the next step's wide walk or its first q block's — into the buffer
+it does not hold, where its own next group's start would stand (before
+this group's wait: after it nothing is won), and leaves that buffer's
+index, and that it did, in SMEM; the grid is sequential and scratch
+persists, so the next walk finds its group started, skips its own start
+and begins on that buffer. The handed group's ``j_first`` and ``n_kv``
+are the receiver's own (its window, its block mask, its ``lo``), so the
+copies started are exactly those it waits for, on the same semaphores.
+The first real walk of a call (and one after a pad block, or after a
+walk of no blocks) starts its own; a walk followed by a pad block or by
+the end of the grid starts nothing, so no copy is outstanding and no
+semaphore signalled when the call returns. A decode walk is 1-8 groups
+long and its first group's latency was the one thing nothing hid
+(``benchmark/tools/paged_walk_sweep.py``; PERF.md section 6, PR 49). The
+issue and the waits STAY loops with a traced trip count, one semaphore
+and one wait a block: on blocks of 80 KB the per-block issue and wait
+that the latent kernel's 20 KB blocks made it unroll (``ops/
+mla_paged_attention.py``) cost a walk of a few groups nothing (one wait a
+group and an unrolled issue read +-2% there at PR 48, and paid 6-11% only
+on walks of ten groups and more), while their text cost every warm-up
+4-6 s — every ``(Q, T)`` step program traces and lowers this kernel, and
+``tests/test_ragged_attention.py`` holds its size. ``ragged_walk_counts``
+counts the walks and the handed ones too.
+
 Layout contract (the serving engine's fused step builds these):
 
 * queries are FLATTENED over the batch: each sequence's ``q_len[s]``
@@ -153,6 +181,7 @@ from __future__ import annotations
 
 import functools
 import math
+import types
 from typing import List, Sequence, Tuple
 
 import jax
@@ -294,6 +323,16 @@ def _one_sequence(seqs):
     return same
 
 
+# numpy's names for jax.lax's scalar primitives: what ``_walk_extent``
+# computes with inside the kernel. A jnp operator on a traced scalar is a
+# jitted function of its own, traced again wherever it stands (PERF.md
+# 47.5); the operands are never negative, so ``lax.div`` is the floor
+_LAX = types.SimpleNamespace(
+    int32=jnp.int32, add=jax.lax.add, subtract=jax.lax.sub,
+    multiply=jax.lax.mul, floor_divide=jax.lax.div, minimum=jax.lax.min,
+    maximum=jax.lax.max)
+
+
 def _walk_extent(xp, p_first, n_rows, lo, kv_len, t_len, *, block_size,
                  mask_block, window):
     """``(j_first, n_kv)``: the entries ``[j_first, n_kv)`` of a
@@ -302,20 +341,26 @@ def _walk_extent(xp, p_first, n_rows, lo, kv_len, t_len, *, block_size,
     last row's last visible column — its own position, or its diffusion
     block's end — bounded by ``kv_len`` and the table; under a window it
     starts at the block of the first row's ``p - W + 1`` (the entries
-    before name freed blocks). ``xp`` is ``jnp`` in the kernel and ``np``
-    in ``ragged_walk_counts``: the ONE statement of what is fetched."""
+    before name freed blocks). ``xp`` is ``_LAX`` in the kernel (int32
+    scalars, of the walk it makes and of the walk it hands a first group
+    to) and ``np`` in ``ragged_walk_counts`` (int32 arrays, an entry a
+    walk): the ONE statement of what is fetched."""
     i32 = xp.int32
     bs = i32(block_size)
-    p_last = p_first + i32(n_rows - 1)
+    p_last = xp.add(p_first, xp.subtract(n_rows, i32(1)))
     if mask_block > 1:
-        p_last = p_last // i32(mask_block) * i32(mask_block) \
-            + i32(mask_block - 1)
-    n_kv = xp.minimum(xp.minimum((kv_len + bs - 1) // bs, i32(t_len)),
-                      p_last // bs + 1)
+        b = i32(mask_block)
+        p_last = xp.add(xp.multiply(xp.floor_divide(p_last, b), b),
+                        i32(mask_block - 1))
+    n_kv = xp.minimum(
+        xp.minimum(xp.floor_divide(xp.add(kv_len, i32(block_size - 1)), bs),
+                   i32(t_len)),
+        xp.add(xp.floor_divide(p_last, bs), i32(1)))
     if not window:
         return i32(0), n_kv
-    j_first = xp.maximum(xp.maximum(p_first - i32(window - 1), lo),
-                         i32(0)) // bs
+    j_first = xp.floor_divide(
+        xp.maximum(xp.maximum(xp.subtract(p_first, i32(window - 1)), lo),
+                   i32(0)), bs)
     return j_first, n_kv
 
 
@@ -325,30 +370,40 @@ def ragged_walk_counts(blk_seq, seq_qstart, seq_pos0, lo, kv_len, t_len, *,
     """What the kernel does on a launch's layout, a layer (host, numpy):
     ``kv_steps`` block DMAs, ``kv_fetches`` waits for a group of ``group``
     blocks, ``q_blocks`` real q blocks and ``q_blocks_wide`` of them
-    served by a wide step — from ``_one_sequence`` and ``_walk_extent``,
-    the functions the kernel itself decides by."""
+    served by a wide step, ``kv_walks`` walks of at least one block and
+    ``kv_walks_handed`` of them that found their first group started by
+    the walk before (every walk whose q blocks follow, with no pad block
+    between, those of another walk of at least one block) — from
+    ``_one_sequence`` and ``_walk_extent``, the functions the kernel
+    itself decides by."""
     m = int(step_blocks)
     blk_seq = np.asarray(blk_seq, np.int32)
     steps = blk_seq.reshape(-1, m)
     wide = _one_sequence([steps[:, i] for i in range(m)]) if m > 1 \
         else np.zeros(len(steps), bool)
-    alone = ~np.repeat(wide, m) & (blk_seq >= 0)
-    kv_steps = kv_fetches = 0
-    for first, n_blocks in ((np.flatnonzero(wide) * m, m),
-                            (np.flatnonzero(alone), 1)):
-        seq = blk_seq[first]
-        p_first = (np.asarray(seq_pos0, np.int32)[seq] + first * BLOCK_Q
-                   - np.asarray(seq_qstart, np.int32)[seq]).astype(np.int32)
-        j_first, n_kv = _walk_extent(
-            np, p_first, n_blocks * BLOCK_Q, np.asarray(lo, np.int32)[seq],
-            np.asarray(kv_len, np.int32)[seq], t_len,
-            block_size=block_size, mask_block=mask_block, window=window)
-        blocks = np.maximum(n_kv - j_first, 0)
-        kv_steps += int(blocks.sum())
-        kv_fetches += int((-(-blocks // group)).sum())
-    return dict(kv_steps=kv_steps, kv_fetches=kv_fetches,
+    in_wide = np.repeat(wide, m)
+    # every walk by its first q block, in the order the kernel makes
+    # them: a real q block on its own, or a wide step's first
+    opens = ~in_wide & (blk_seq >= 0)
+    opens[np.flatnonzero(wide) * m] = True
+    first = np.flatnonzero(opens)
+    n_blocks = np.where(in_wide[first], m, 1).astype(np.int32)
+    seq = blk_seq[first]
+    p_first = (np.asarray(seq_pos0, np.int32)[seq] + first * BLOCK_Q
+               - np.asarray(seq_qstart, np.int32)[seq]).astype(np.int32)
+    j_first, n_kv = _walk_extent(
+        np, p_first, n_blocks * np.int32(BLOCK_Q),
+        np.asarray(lo, np.int32)[seq], np.asarray(kv_len, np.int32)[seq],
+        t_len, block_size=block_size, mask_block=mask_block, window=window)
+    blocks = np.maximum(n_kv - j_first, 0)
+    walks = blocks > 0
+    handed = walks[1:] & walks[:-1] \
+        & (first[:-1] + n_blocks[:-1] == first[1:])
+    return dict(kv_steps=int(blocks.sum()),
+                kv_fetches=int((-(-blocks // group)).sum()),
                 q_blocks=int((blk_seq >= 0).sum()),
-                q_blocks_wide=m * int(wide.sum()))
+                q_blocks_wide=m * int(wide.sum()),
+                kv_walks=int(walks.sum()), kv_walks_handed=int(handed.sum()))
 
 
 def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
@@ -367,7 +422,9 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
     before this group is waited for and computed on (two buffers) — and
     streams online softmax over ``[rows, group * block_size]`` score
     tiles, one update a group. It ends at the block its last row stops
-    seeing at (``_walk_extent``).
+    seeing at (``_walk_extent``). From its last trip it starts the first
+    group of the walk after it (``walk_from``; the module doc's
+    hand-over): ``hand_ref`` says whether, and into which buffer.
 
     Only the blocks the walk covers are fetched (``j_first <= j < n_kv
     <= T``: the table is never read past its width, the scratch block
@@ -391,11 +448,13 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
     pallas_kernels idiom; the call sites also trace under _x64_off)."""
     scales_ref = sinks_ref = None
     if quantized:
-        scales_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem, *staged = rest
+        scales_ref, q_ref, pool_ref, o_ref, kv_scr, kv_sem, hand_ref, \
+            *staged = rest
     elif sinks:
-        q_ref, sinks_ref, pool_ref, o_ref, kv_scr, kv_sem, *staged = rest
+        q_ref, sinks_ref, pool_ref, o_ref, kv_scr, kv_sem, hand_ref, \
+            *staged = rest
     else:
-        q_ref, pool_ref, o_ref, kv_scr, kv_sem, *staged = rest
+        q_ref, pool_ref, o_ref, kv_scr, kv_sem, hand_ref, *staged = rest
     layer = layer_ref[0]
     blk0 = pl.program_id(0) * jnp.int32(step_blocks)
     n_heads, _, dh = q_ref.shape
@@ -414,6 +473,56 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
     _BQ = jnp.int32(block_q)
     _G = jnp.int32(group)
     _CG = jnp.int32(cols_g)
+    # the hand-over's scalar arithmetic is spelled in lax primitives on
+    # int32 values (``_LAX`` says why)
+    lax = jax.lax
+    i32 = jnp.int32
+    n_blk = lax.mul(pl.num_programs(0), i32(step_blocks))
+
+    @pl.when(lax.eq(pl.program_id(0), i32(0)))
+    def _nothing_handed_yet():
+        hand_ref[0] = i32(0)
+
+    def block_copies(s, j0, s_kv, slot, act):
+        # the page-table walk: the group of sequence `s` at its block
+        # `j0`, each block ONE copy of pool[layer, pid] — every head's
+        # (bs, 2*Dh) K|V tile — into its rows of buffer `slot`; `act`
+        # starts or waits. Blocks past `s_kv`, the walk's last, are not
+        # touched.
+        def one(g, carry):
+            at = pl.ds(pl.multiple_of(g * _BS, block_size), block_size)
+            act(pltpu.make_async_copy(
+                pool_ref.at[layer, tables_ref[s, j0 + g]],
+                kv_scr.at[slot, :, at, :], kv_sem.at[slot, g]))
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.minimum(_G, s_kv - j0),
+                          one, jnp.int32(0))
+
+    def walk_from(nb):
+        # the walk the kernel makes next, at q block `nb`, decided as the
+        # kernel will decide it when it gets there: -> (whether it is one
+        # of at least a block, its sequence, its j_first, its n_kv). A
+        # pad block and the end of the grid are none
+        seqs = [blk_seq_ref[lax.min(lax.add(nb, i32(i)),
+                                    lax.sub(n_blk, i32(1)))]
+                for i in range(step_blocks)]
+        s = lax.max(seqs[0], i32(0))
+        n_rows = i32(block_q)
+        if step_blocks > 1:
+            # a step's first q block opens a wide walk where the step's
+            # q blocks are all one sequence's
+            wide = lax.bitwise_and(
+                lax.eq(lax.rem(nb, i32(step_blocks)), i32(0)),
+                _one_sequence(seqs))
+            n_rows = lax.select(wide, i32(step_blocks * block_q), n_rows)
+        p_first = lax.sub(lax.add(pos0_ref[s], lax.mul(nb, _BQ)),
+                          qstart_ref[s])
+        j_first, n_kv = _walk_extent(
+            _LAX, p_first, n_rows, lo_ref[s], kvlen_ref[s], t_len,
+            block_size=block_size, mask_block=mask_block, window=window)
+        real = lax.bitwise_and(lax.lt(nb, n_blk), lax.ge(seqs[0], i32(0)))
+        return lax.bitwise_and(real, lax.gt(n_kv, j_first)), s, j_first, n_kv
 
     def walk(seq, blk, n_blocks, rows, q_src, o_dst):
         # the q blocks [blk, blk + n_blocks) of sequence `seq`: rows
@@ -443,30 +552,30 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
         lo = lo_ref[seq]
         kv_len = kvlen_ref[seq]
         j_first, n_kv = _walk_extent(
-            jnp, p_first, n_blocks * block_q, lo, kv_len, t_len,
+            _LAX, p_first, i32(n_blocks * block_q), lo, kv_len, t_len,
             block_size=block_size, mask_block=mask_block, window=window)
         n_grp = (n_kv - j_first + _G - 1) // _G
         col0 = j_first * _BS
         # rows of the buffers past the walk's last block hold what an
         # earlier walk left there
         kv_end = jnp.minimum(kv_len, n_kv * _BS)
+        # the hand-over: the walk before this one started this walk's
+        # first group, into the buffer the scratch names, unless there
+        # was none (a call's first walk, one after a pad block): then
+        # the walk starts its own, in buffer 0. And this walk will start
+        # the next one's from its last trip — what it leaves in the
+        # scratch for that walk to read
+        handed = lax.eq(hand_ref[0], i32(1))
+        slot0 = lax.select(handed, hand_ref[1], i32(0))
+        give, nxt_seq, nxt_first, nxt_kv = walk_from(
+            lax.add(blk, i32(n_blocks)))
+        give = lax.bitwise_and(give, lax.gt(n_grp, i32(0)))
+        hand_ref[0] = lax.convert_element_type(give, jnp.int32)
+        hand_ref[1] = lax.rem(lax.add(slot0, n_grp), i32(2))
 
-        def block_copies(grp, slot, act):
-            # the page-table walk: the grp-th group's blocks, each ONE
-            # copy of pool[layer, pid] — every head's (bs, 2*Dh) K|V
-            # tile — into its rows of buffer `slot`; `act` starts or
-            # waits. Blocks past the walk's last are not touched.
-            j0 = grp * _G + j_first
-
-            def one(g, carry):
-                at = pl.ds(pl.multiple_of(g * _BS, block_size), block_size)
-                act(pltpu.make_async_copy(
-                    pool_ref.at[layer, tables_ref[seq, j0 + g]],
-                    kv_scr.at[slot, :, at, :], kv_sem.at[slot, g]))
-                return carry
-
-            jax.lax.fori_loop(jnp.int32(0), jnp.minimum(_G, n_kv - j0),
-                              one, jnp.int32(0))
+        @pl.when(lax.bitwise_not(handed))
+        def _own_first_group():
+            block_copies(seq, j_first, n_kv, i32(0), lambda cp: cp.start())
 
         def col_scales(grp):
             # K's and V's [H, 1, G*bs] f32: scales_ref[0|1, pid, h] over
@@ -489,19 +598,28 @@ def _rpa_kernel(layer_ref, blk_seq_ref, qstart_ref, pos0_ref, tables_ref,
                                for h in range(n_heads)])
                     for which in (0, 1)]
 
-        block_copies(jnp.int32(0), jnp.int32(0), lambda cp: cp.start())
-
         def body(grp, carry):
             # running softmax stats stay [H, rows, 1] (sublane-oriented);
             # rank-1 carries would force lane<->sublane relayouts
             m_prev, l_prev, acc = carry
-            slot = grp % 2
+            slot = lax.rem(lax.add(slot0, grp), i32(2))
+            # started before this group is waited for (after it nothing
+            # is won): the walk's next group or, from its last trip, the
+            # first group of the walk after it
+            more = lax.lt(lax.add(grp, i32(1)), n_grp)
 
-            @pl.when(grp + 1 < n_grp)
+            @pl.when(lax.bitwise_or(more, give))
             def _prefetch():
-                block_copies(grp + 1, 1 - slot, lambda cp: cp.start())
+                block_copies(
+                    lax.select(more, seq, nxt_seq),
+                    lax.select(more, lax.add(lax.mul(lax.add(grp, i32(1)),
+                                                     _G), j_first),
+                               nxt_first),
+                    lax.select(more, n_kv, nxt_kv), lax.sub(i32(1), slot),
+                    lambda cp: cp.start())
 
-            block_copies(grp, slot, lambda cp: cp.wait())
+            block_copies(seq, lax.add(lax.mul(grp, _G), j_first), n_kv, slot,
+                         lambda cp: cp.wait())
             # rows no block of this walk filled, and a last block's rows
             # past kv_len, go to the MXU as zeros
             kv_rows = col0 + grp * _CG + jax.lax.broadcasted_iota(
@@ -762,6 +880,9 @@ def _rpa_call(layer, q, pool, blk_seq, seq_qstart, seq_pos0, tables, lo,
             # whole group is one [G*bs, 2*Dh] tile stack
             pltpu.VMEM((2, hkv, group * bs, lanes), pool.dtype),
             pltpu.SemaphoreType.DMA((2, group)),
+            # whether the walk that ended started the next one's first
+            # group, and the buffer it went into (the hand-over)
+            pltpu.SMEM((2,), jnp.int32),
             *([pltpu.VMEM((hkv, q_rows, dh), jnp.float32),
                pltpu.VMEM((hkv, q_rows, dv), jnp.float32)] if staged
               else []),

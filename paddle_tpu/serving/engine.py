@@ -35,7 +35,8 @@ Observability (PR-1 wiring + the ISSUE-6 SLO spine): counters
 ``serving/requests``, ``serving/completed``, ``serving/tokens``,
 ``serving/launch_overlapped``, ``serving/launch_rows`` and
 ``serving/tower_rows`` (a launch's real rows and the rows its tower runs
-on), ``serving/preempt``,
+on), ``serving/kv_walks_handed`` (the attention kernel's walks that began
+on a group the walk before them started), ``serving/preempt``,
 ``serving/queue_full``, ``serving/cancelled``,
 ``serving/deadline_exceeded``, ``serving/prefix_hit``/``prefix_miss``/
 ``prefill_tokens_saved``/``prefix_evict``; histograms

@@ -765,7 +765,9 @@ class Scheduler:
                     state_slots: Optional[int] = None, ssm_rows: int = 0,
                     ssm_chunk_rows: int = 0,
                     tower_rows: Optional[int] = None,
-                    cache_layers: Optional[int] = None) -> None:
+                    cache_layers: Optional[int] = None,
+                    kv_walks: Optional[int] = None,
+                    kv_walks_handed: int = 0) -> None:
         """Record the shape of the ragged launch built THIS cycle into
         the live cycle record (called by the engine's
         ``_ragged_operands``, scheduler thread; host ints only):
@@ -810,7 +812,12 @@ class Scheduler:
         (``ops/ssm.py``). ``cache_layers`` (given only by a model with
         layers whose mixer is a state alone): the layers that read and
         write the pool, which is what ``kv_tokens`` and the walk counts
-        are a layer OF."""
+        are a layer OF. ``kv_walks`` (given by the per-head ragged
+        kernel's counts alone): the walks of at least one block the
+        kernel makes a layer, and ``kv_walks_handed``, those of them
+        whose first group the walk before had started (monitor
+        ``serving/kv_walks_handed``) — every walk but a launch's first
+        and one after a pad block."""
         if self._rec is not None:
             tower = int(q if tower_rows is None else tower_rows)
             stat_add("serving/launch_rows", int(rows))
@@ -835,6 +842,10 @@ class Scheduler:
                                  ssm_chunk_rows=int(ssm_chunk_rows))
             if cache_layers is not None:
                 self._rec["cache_layers"] = int(cache_layers)
+            if kv_walks is not None:
+                stat_add("serving/kv_walks_handed", int(kv_walks_handed))
+                self._rec.update(kv_walks=int(kv_walks),
+                                 kv_walks_handed=int(kv_walks_handed))
 
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into

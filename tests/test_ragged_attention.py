@@ -410,6 +410,301 @@ class TestKernelParity:
 
 
 # ---------------------------------------------------------------------------
+# the hand-over (PR 49): a walk's first group is started from the last
+# trip of the walk before it
+# ---------------------------------------------------------------------------
+
+# a launch of 16 q blocks laid out by hand, an entry a q block: None is a
+# pad block, (s, kv) a DECODE row of sequence s whose context is kv
+# tokens, (s, kv, k, n) the k-th of the n q blocks of sequence s's chunk
+# (the chunk's last row is the context's last). Blocks of 32 tokens in
+# groups of G = 4: a group is 128 tokens
+def _chunk(s, kv, n):
+    return [(s, kv, k, n) for k in range(n)]
+
+
+_HANDOVER_LAYOUTS = {
+    # contexts that end on a group border, and one token past it
+    "group-border": [(0, 128), (1, 129), (2, 256), (3, 257)] + [None] * 12,
+    # consecutive walks of 1, 2, 3, 2, 1, 4 groups: the buffer a walk
+    # begins on follows from the walk before it
+    "odd-and-even-walks": [(0, 100), (1, 200), (2, 300), (3, 250), (4, 30),
+                           (5, 500)] + [None] * 10,
+    # real blocks followed by pads: nothing is started for them
+    "pads-after-real": [(0, 140), (1, 270)] + [None] * 14,
+    # the only real block is the call's last
+    "only-the-last-is-real": [None] * 15 + [(0, 300)],
+    # a pad block between real ones (inside a step, and a whole pad step)
+    "pad-between-real": [(0, 140), None, (1, 300), (2, 60)] + [None] * 4
+    + [(3, 129), (4, 257)] + [None] * 6,
+    # decode rows -> a chunk whose middle q blocks are two WIDE steps ->
+    # decode rows: from _each into _wide and back, across grid steps
+    "each-wide-each": [(0, 200), (1, 129)] + _chunk(2, 470, 11)
+    + [(3, 300), (4, 100), None],
+    # a wide step first, and a wide step last
+    "wide-first-and-last": _chunk(0, 380, 4) + [(1, 257), (2, 90), None,
+                                               None] + _chunk(3, 512, 8),
+}
+_HANDOVER_FORMS = {
+    "plain": {},
+    "window": {"window": 150, "free_behind": True},
+    "mask4": {"mask_block": 4},
+    "gqa4": {"heads": 2, "q_heads": 8},
+    "gqa5": {"heads": 1, "q_heads": 5},
+    "sinks": {"sinks": True},
+    "int8": {"dtype": "int8"},
+    # NaN in the scratch block and in every row past a context's end
+    "nans": {"nan_rows": True},
+}
+
+
+def _handover_case(layout, *, heads=3, q_heads=None, dtype="float32",
+                   window=0, free_behind=False, mask_block=1, sinks=False,
+                   nan_rows=False, seed=0):
+    """The kernel's operands for a hand-laid ``layout`` (see
+    ``_HANDOVER_LAYOUTS``) over a pool of 96 blocks of 32 tokens, a
+    sequence's table 16 entries wide: ``(call, check)`` — ``call()`` runs
+    the kernel and returns its whole output, ``check(out)`` holds the
+    real rows to the oracle, the pad blocks to zeros and everything to
+    finite."""
+    import jax.numpy as jnp
+    bs, dh, t_len, nb, S = 32, 16, 16, 96, 6
+    q_heads = q_heads or heads
+    rng = np.random.RandomState(seed)
+    quant = dtype == "int8"
+    if quant:
+        pool = rng.randint(-127, 128, (2, nb + 1, heads, bs, 2 * dh)) \
+            .astype(np.int8)
+        scales = (0.2 + rng.rand(2, 2, nb + 1, heads)).astype(np.float32) / 64
+    else:
+        pool = rng.randn(2, nb + 1, heads, bs, 2 * dh).astype(np.float32)
+        scales = None
+    blk_seq = np.full(len(layout), -1, np.int32)
+    qstart, pos0 = np.zeros(S, np.int32), np.zeros(S, np.int32)
+    kv_len, lo = np.zeros(S, np.int32), np.zeros(S, np.int32)
+    rows = []                               # (q row, sequence, position)
+    for b, entry in enumerate(layout):
+        if entry is None:
+            continue
+        s, kv, k, n = entry + (0, 1)[len(entry) - 2:]
+        blk_seq[b] = s
+        kv_len[s] = kv
+        # a decode row is its q block's only real row; a chunk of n q
+        # blocks is 8 n - 3 rows, so its last q block ends in 3 pad rows
+        q_len = 1 if len(entry) == 2 else 8 * n - 3
+        if k == 0:
+            qstart[s], pos0[s] = 8 * b, kv - q_len
+        for r in range(8):
+            if 8 * k + r < q_len:
+                rows.append((8 * b + r, s, pos0[s] + 8 * k + r))
+    free = list(range(1, nb + 1))
+    rng.shuffle(free)
+    tables = np.zeros((S, t_len), np.int32)
+    for s in range(S):
+        need = -(-int(kv_len[s]) // bs)
+        tables[s, :need] = [free.pop() for _ in range(need)]
+        if free_behind:
+            gone = max(0, int(pos0[s]) - window + 1) // bs
+            tables[s, :gone] = 0
+            lo[s] = gone * bs
+    q = rng.randn(q_heads, 8 * len(layout), dh).astype(np.float32)
+    sink = rng.randn(q_heads).astype(np.float32) if sinks else None
+    at, seq, pos = (np.asarray(c) for c in zip(*rows))
+    ref = reference_ragged_attention(
+        np.stack([q[:, r] for r in at]), pool, 1, seq, pos,
+        [list(t) for t in tables], lo, scales=scales, mask_block=mask_block,
+        kv_len=kv_len, window=window, sinks=sink)
+    if nan_rows or free_behind:
+        pool[:, 0] = np.nan                 # the scratch block
+    if nan_rows:
+        for s in range(S):
+            end = int(kv_len[s])
+            if end % bs:
+                pool[:, tables[s, end // bs], :, end % bs:] = np.nan
+    operands = (jnp.asarray(q), jnp.asarray(pool, dtype), 1, blk_seq, qstart,
+                pos0, tables, lo, kv_len)
+    kw = dict(scales=None if scales is None else jnp.asarray(scales),
+              mask_block=mask_block, window=window, sinks=sink)
+
+    def check(out):
+        assert np.isfinite(out).all()
+        tol = 2e-4 if quant else 2e-5
+        np.testing.assert_allclose(out[:, at].transpose(1, 0, 2), ref,
+                                   rtol=tol, atol=tol)
+        for b in np.flatnonzero(blk_seq < 0):
+            assert not out[:, 8 * b:8 * b + 8].any()
+
+    counts = dict(t_len=t_len, block_size=bs, mask_block=mask_block,
+                  window=window)
+    return (lambda: np.asarray(ragged_paged_attention(*operands, **kw))), \
+        check, (blk_seq, qstart, pos0, lo, kv_len), counts
+
+
+def _logging_tpu(real_tpu, events):
+    """A stand-in for the kernel module's ``pltpu`` whose async copies
+    append ``(what, buffer, block of the group)`` to ``events`` when they
+    are started and waited for — at run time, in the order the
+    interpreted kernel does it: every event sits in a trip of a loop
+    that carries the semaphores' state."""
+    import jax
+
+    class Logged:
+        def __init__(self, copy, at):
+            self.copy, self.at = copy, at
+
+        def _note(self, what):
+            jax.debug.callback(
+                lambda slot, g: events.append((what, int(slot), int(g))),
+                *self.at)
+
+        def start(self):
+            self._note("start")
+            self.copy.start()
+
+        def wait(self):
+            self._note("wait")
+            self.copy.wait()
+
+    class LoggingTpu:
+        def __getattr__(self, name):
+            return getattr(real_tpu, name)
+
+    tpu = LoggingTpu()
+    tpu.make_async_copy = lambda src, dst, sem: Logged(
+        real_tpu.make_async_copy(src, dst, sem), sem.transforms[-1].indices)
+    return tpu
+
+
+@pytest.fixture(scope="class")
+def dma_log():
+    """Every DMA the interpreted kernel starts and waits for, in order:
+    the kernel module's ``pltpu`` is ``_logging_tpu`` for the class's
+    tests, and the jitted call's cache is dropped on both sides (traces
+    made with the proxy are no other test's)."""
+    from paddle_tpu.ops import ragged_paged_attention as rpa
+    events = []
+    patch = pytest.MonkeyPatch()
+    patch.setattr(rpa, "pltpu", _logging_tpu(rpa.pltpu, events))
+    rpa._rpa_call.clear_cache()
+    yield events
+    patch.undo()
+    rpa._rpa_call.clear_cache()
+
+
+class TestHandOver:
+    @pytest.mark.parametrize("form", _HANDOVER_FORMS)
+    @pytest.mark.parametrize("layout", _HANDOVER_LAYOUTS)
+    def test_the_handed_walk_against_the_oracle(self, dma_log, layout, form):
+        """Each case twice: the second call runs on what the first left
+        behind (nothing may be: no copy outstanding, no semaphore
+        signalled) and has to give the same bits. And the DMAs of the
+        first call, in order: every wait finds its copy started, nothing
+        is started twice, nothing is left; the blocks fetched and the
+        groups waited for are ``ragged_walk_counts``'s — what the parent
+        fetched — and so are the groups that were NOT in flight before
+        the group before them was waited for: a walk's own first group,
+        ``kv_walks - kv_walks_handed`` of them."""
+        import jax
+
+        from paddle_tpu.ops.ragged_paged_attention import (
+            kv_group_blocks, ragged_walk_counts)
+        kw = _HANDOVER_FORMS[form]
+        call, check, meta, counts = _handover_case(
+            _HANDOVER_LAYOUTS[layout], seed=len(layout) + len(form), **kw)
+        del dma_log[:]
+        out = call()
+        jax.effects_barrier()
+        events = list(dma_log)
+        check(out)
+        np.testing.assert_array_equal(call(), out)
+        jax.effects_barrier()
+        assert dma_log[len(events):] == events
+        heads = kw.get("heads", 3)
+        group = kv_group_blocks(heads, 32, 16, kw.get("dtype", "float32"))
+        assert group == 4
+        want = ragged_walk_counts(
+            *meta[:3], meta[3], meta[4], step_blocks=4, group=group,
+            **counts)
+        in_flight, waited, exposed = {}, 0, 0
+        for what, slot, g in events:
+            if what == "start":
+                assert (slot, g) not in in_flight, "started twice"
+                # a group is known by its first block: the groups waited
+                # for before it was started
+                in_flight[slot, g] = waited if g == 0 \
+                    else in_flight[slot, 0]
+            else:
+                assert (slot, g) in in_flight, "waited for, never started"
+                began = in_flight.pop((slot, g))
+                if g == 0:
+                    # started after the group before it was waited for:
+                    # nothing hid its latency
+                    exposed += began == waited
+                    waited += 1
+        assert not in_flight, "a copy is outstanding when the call returns"
+        assert sum(w == "start" for w, _, _ in events) == want["kv_steps"]
+        assert waited == want["kv_fetches"]
+        assert exposed == want["kv_walks"] - want["kv_walks_handed"]
+
+    @pytest.mark.parametrize("layout,walks,handed", [
+        ("group-border", 4, 3), ("odd-and-even-walks", 6, 5),
+        ("pads-after-real", 2, 1), ("only-the-last-is-real", 1, 0),
+        ("pad-between-real", 5, 2), ("each-wide-each", 9, 8),
+        ("wide-first-and-last", 5, 3),
+    ])
+    def test_the_counter_follows_the_kernels_rule(self, layout, walks,
+                                                  handed):
+        """``kv_walks`` / ``kv_walks_handed`` on the layouts above, by
+        hand: every walk but the call's first and one after a pad block."""
+        from paddle_tpu.ops.ragged_paged_attention import ragged_walk_counts
+        _, _, meta, counts = _handover_case(_HANDOVER_LAYOUTS[layout])
+        got = ragged_walk_counts(*meta[:3], meta[3], meta[4], step_blocks=4,
+                                 group=4, **counts)
+        assert (got["kv_walks"], got["kv_walks_handed"]) == (walks, handed)
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr, those of every jaxpr nested in them too."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _equations(sub)
+    return n
+
+
+@pytest.mark.parametrize("q_heads,kv_heads,q_rows,window,at_most", [
+    (20, 20, 512, 0, 510),         # gpt2-large's plain launch: 378 at PR 47
+    (32, 8, 1024, 0, 540),         # 32 heads on 8 KV heads of 64: 400
+    (32, 8, 1024, 128, 556),       # the same under a window of 128: 434
+], ids=["gpt2-large-q512", "gqa4-q1024", "gqa4-q1024-window128"])
+def test_the_kernels_text_stays_small(q_heads, kv_heads, q_rows, window,
+                                      at_most):
+    """What this protects is ``setup_s``: every ``(Q, T)`` step program of
+    every warm-up traces this kernel and lowers its text to Mosaic, cache
+    warm or not — PR 48's unrolled DMA starts cost +4-6 s of every warm-up
+    of ``gpt2-large.decode`` and the PR with it. The equations of
+    ``jax.make_jaxpr(ragged_paged_attention)``, counted through every
+    nested jaxpr at three of the cells' shapes, stay under 1.35 x what
+    PR 47's kernel counted (378 | 400 | 412 by the issue's count; 452 |
+    474 | 496 with the hand-over of PR 49)."""
+    import jax
+    import jax.numpy as jnp
+    S, T = 64, 64
+    meta = (np.zeros(q_rows // 8, np.int32), np.zeros(S, np.int32),
+            np.zeros(S, np.int32), np.zeros((S, T), np.int32),
+            np.zeros(S, np.int32), np.ones(S, np.int32))
+    jaxpr = jax.make_jaxpr(lambda q, pool: ragged_paged_attention(
+        q, pool, 0, *meta, window=window))(
+            jax.ShapeDtypeStruct((q_heads, q_rows, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 9, kv_heads, 16, 128), jnp.bfloat16))
+    assert 300 < _equations(jaxpr.jaxpr) <= at_most
+
+
+# ---------------------------------------------------------------------------
 # fused engine parity: engine == generate, one trace a bucket, clean
 # analysis — the acceptance criterion
 # ---------------------------------------------------------------------------
@@ -595,38 +890,9 @@ class TestChunkedPrefill:
         import jax
 
         from paddle_tpu.ops import ragged_paged_attention as rpa
-        seen = {"starts": 0, "waits": 0, "groups": 0}
-        launches = []
-
-        def bump(key, g):
-            seen[key] += 1
-            if key == "waits" and int(g) == 0:
-                seen["groups"] += 1
-
-        class Counting:
-            def __init__(self, copy, g):
-                self.copy, self.g = copy, g
-
-            def start(self):
-                jax.debug.callback(lambda g: bump("starts", g), self.g)
-                self.copy.start()
-
-            def wait(self):
-                jax.debug.callback(lambda g: bump("waits", g), self.g)
-                self.copy.wait()
-
-        real_tpu = rpa.pltpu
-
-        class CountingTpu:
-            def __getattr__(self, name):
-                return getattr(real_tpu, name)
-
-        tpu = CountingTpu()
-        tpu.make_async_copy = lambda src, dst, sem: Counting(
-            real_tpu.make_async_copy(src, dst, sem),
-            sem.transforms[-1].indices[1])
+        events, launches = [], []
         # the kernel module alone: kv_append's copies are not the walk's
-        monkeypatch.setattr(rpa, "pltpu", tpu)
+        monkeypatch.setattr(rpa, "pltpu", _logging_tpu(rpa.pltpu, events))
         # the kernel's call is a jitted function of its own: traces made
         # before this test do not count, and no later test may find these
         rpa._rpa_call.clear_cache()
@@ -656,10 +922,11 @@ class TestChunkedPrefill:
         jax.effects_barrier()
         rpa._rpa_call.clear_cache()
         layers = 2
-        assert seen["starts"] == seen["waits"] \
+        starts = sum(what == "start" for what, _, _ in events)
+        assert starts == len(events) - starts \
             == layers * sum(r["kv_steps"] for r in launches)
-        assert seen["groups"] == layers * sum(r["kv_fetches"]
-                                              for r in launches)
+        assert sum(what == "wait" and g == 0 for what, _, g in events) \
+            == layers * sum(r["kv_fetches"] for r in launches)
         wide = 0
         for r in launches:
             blocks = r["blk_seq"]

@@ -239,8 +239,12 @@ def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
         rec = records[cycle]
         for key in ("plan_ms", "emit_ms", "launch_rows", "launch_q",
                     "launch_t", "kv_tokens", "kv_steps", "kv_fetches",
-                    "q_blocks", "q_blocks_wide", "kv_write_blocks"):
+                    "q_blocks", "q_blocks_wide", "kv_write_blocks",
+                    "kv_walks", "kv_walks_handed"):
             assert key in rec, key
+        # every walk of a launch but its first begins on a handed group
+        # (these launches hold no pad block between real ones)
+        assert rec["kv_walks_handed"] == rec["kv_walks"] - 1 >= 0
         assert rec["launch_rows"] == rows
         assert rec["kv_tokens"] == kv
         assert rec["kv_steps"] == steps
@@ -281,19 +285,21 @@ def test_kv_fetches_counts_the_groups_a_launch_waits_for():
     finally:
         eng.close()
     walked = [(c["launch_rows"], c["kv_steps"], c["kv_fetches"],
-               c["kv_write_blocks"], c["q_blocks_wide"])
+               c["kv_write_blocks"], c["q_blocks_wide"], c["kv_walks"],
+               c["kv_walks_handed"])
               for c in eng.flight_recorder.snapshot()["cycles"]
               if c.get("launch_rows")]
     # (rows, KV blocks the walks fetch, groups of 4 blocks they wait for,
-    # blocks the rows land in, q blocks a wide step served): the chunks
+    # blocks the rows land in, q blocks a wide step served, walks, walks
+    # that began on a group the walk before them started): the chunks
     # end at 64, 128 and 150 tokens, then decode rows at 151, 152. A
     # 64-row chunk is two wide steps of 32 rows, each walking to its last
     # row's block (1 + 2, then 3 + 4 blocks, a group each); the 22-row
     # chunk's 3 q blocks share a step with a pad block and walk alone, 5
     # blocks = 2 groups each
-    assert walked == [(64, 1 + 2, 2, 2, 8), (64, 3 + 4, 2, 2, 8),
-                      (22, 3 * 5, 3 * 2, 1, 0), (1, 5, 2, 1, 0),
-                      (1, 5, 2, 1, 0)]
+    assert walked == [(64, 1 + 2, 2, 2, 8, 2, 1), (64, 3 + 4, 2, 2, 8, 2, 1),
+                      (22, 3 * 5, 3 * 2, 1, 0, 3, 2), (1, 5, 2, 1, 0, 1, 0),
+                      (1, 5, 2, 1, 0, 1, 0)]
 
 
 def test_a_train_step_is_in_the_trace_with_its_number(tmp_path):
