@@ -73,10 +73,13 @@ GATHER_ROWS_PER_CHOICE = 1024
 
 
 def routed_plan(n: int, num_experts: int, rows: int, k: int, E: int,
-                I: int) -> Tuple[int, int, bool]:
+                I: int, matrices: int = 3) -> Tuple[int, int, bool]:
     """``(T, M, by_gather)`` of :func:`routed_experts` from the shapes and
     nothing else: ``n`` held of ``num_experts`` experts, ``rows`` rows
-    choosing ``k`` each, experts ``[E, I]``.
+    choosing ``k`` each, experts ``[E, I]`` of ``matrices`` matrices each
+    — 3: gated, ``down(silu(gate x) * up x)``; 2: ungated, ``down(relu(up
+    x)^2)`` (``E`` is the width the experts READ, the stream's or a
+    latent's).
 
     ``T``, the rows an expert's group is padded to a multiple of, is the
     row tile that makes the walk cheapest: a held expert expects ``each =
@@ -85,7 +88,9 @@ def routed_plan(n: int, num_experts: int, rows: int, k: int, E: int,
     each costing its weights' stream once and, per row, the FLOPs that do
     not hide behind it (``T / TILE_BALANCE_ROWS``) and the rows' way in
     and out (``xs``, two f32 products, ``h``, ``o``: ``12 E + 20 I`` bytes
-    against ``6 E I`` of weights). How many of ``rows`` are REAL cannot
+    against ``6 E I`` of weights; an ungated expert has one f32 product
+    less and a matrix less: ``12 E + 12 I`` against ``4 E I``). How many
+    of ``rows`` are REAL cannot
     be seen from here (a ragged batch's pad rows, a block-generation
     slot's half-filled q block): the tile taken is the one whose cost is
     least far from the best tile's at every share of ``REAL_ROW_SHARES``.
@@ -102,7 +107,11 @@ def routed_plan(n: int, num_experts: int, rows: int, k: int, E: int,
     layout and one gather of ``k`` rows a query row where the layout is
     long (every expert held: the 0/1 product's FLOPs grow with query rows
     x rows walked), through the 0/1 product a trip where it is short."""
-    per_row = 1.0 / TILE_BALANCE_ROWS + (12 * E + 20 * I) / (6.0 * E * I)
+    if matrices not in (2, 3):
+        raise ValueError(f"an expert is 2 or 3 matrices, not {matrices}")
+    gated = matrices == 3
+    per_row = 1.0 / TILE_BALANCE_ROWS \
+        + (12 * E + (20 if gated else 12) * I) / (2.0 * matrices * E * I)
 
     def tiles(T, each):
         """Tiles of ``T`` rows an expert that expects ``each`` pairs takes."""
@@ -117,7 +126,8 @@ def routed_plan(n: int, num_experts: int, rows: int, k: int, E: int,
     walked = n * tiles(T, full) * T
     by_gather = walked > GATHER_ROWS_PER_CHOICE * k
     bound = TRIP_BYTES // (2 if by_gather else 1)
-    most = max(1, bound // ((8 * E + 10 * I) * T))         # tiles a trip
+    # tiles a trip
+    most = max(1, bound // ((8 * E + (10 if gated else 6) * I) * T))
     want = int(walked // T) + 2                            # and a spare
     odd = lambda v: v if v % 2 else v - 1
     return T, T * max(1, min(odd(most), odd(want + 1))), by_gather
@@ -335,8 +345,15 @@ def routed_experts(x, valid, idx, w, experts, held, num_experts: int):
 
     ``x [Q, E]``; ``valid [Q]`` bool (pad rows of a ragged batch are not
     routed); ``idx``/``w [Q, k]`` from :func:`route_top_k`, which chose
-    among ``num_experts``; ``experts = (gate [n, E, I], up [n, E, I], down
-    [n, I, E])`` the ``n = hi - lo`` held experts; ``held = (lo, hi)``.
+    among ``num_experts``; ``experts`` the ``n = hi - lo`` held experts,
+    whose FORM is read from what is handed over: three matrices ``(gate
+    [n, E, I], up [n, E, I], down [n, I, E])`` are GATED experts,
+    ``down(silu(gate x) * up x)`` (every family until Nemotron-H); two,
+    ``(up [n, E, I], down [n, I, E])``, are UNGATED ones, ``down(relu(up
+    x)^2)`` (``mlp_hidden_act: relu2``). ``E`` is whatever width ``x``
+    has: the stream's, or a latent's the LAYER projected ``x`` into and
+    will project ``y`` out of (LatentMoE: both projections are the
+    layer's, outside the grouped products). ``held = (lo, hi)``.
     Returns ``(y [Q, E] float32, (pairs, experts_hit, rows, rows_walked,
     zero_pairs))`` with ``y = sum over the row's chosen experts that are
     held of w_e expert_e(x)`` and the ``decoder_spec.ROUTED_COUNTERS``
@@ -349,7 +366,9 @@ def routed_experts(x, valid, idx, w, experts, held, num_experts: int):
     the shapes alone): pairs on held experts are sorted by expert
     (stable, so by row inside an expert) and every expert's rows BEGIN ON
     A MULTIPLE OF ``T``, its group padded to whole tiles with zero rows
-    (``silu(0) * 0 = 0`` and a zero row of ``down`` is 0: the same sum);
+    (``silu(0) * 0 = relu(0)^2 = 0`` and a zero row of ``down`` is 0: the
+    same sum, for either form — what the padded layout rests on: an
+    activation with ``f(0) != 0`` would need its pad rows masked);
     an expert without a pair gets no tile. Trip ``c`` takes rows ``[c M,
     (c + 1) M)`` of that layout, ``M = T x odd``, so the grouped products
     walk it in tiles of ``T`` and no tile holds two experts' rows: an
@@ -362,13 +381,18 @@ def routed_experts(x, valid, idx, w, experts, held, num_experts: int):
     gathers its ``k`` rows once, after the last trip."""
     import jax
     import jax.numpy as jnp
-    gate, up, down = experts
+    if len(experts) not in (2, 3):
+        raise ValueError(
+            f"an expert is (gate, up, down) or (up, down): got "
+            f"{len(experts)} arrays")
+    up, down = experts[-2:]
+    gate = experts[0] if len(experts) == 3 else None
     lo, hi = held
     n = hi - lo
     Q, k = idx.shape
     P = Q * k
     T, M, by_gather = routed_plan(n, int(num_experts), Q, k, x.shape[1],
-                                  gate.shape[2])
+                                  up.shape[2], len(experts))
     i32 = jnp.int32
     on = (idx >= lo) & (idx < hi) & valid[:, None]            # [Q, k]
     flat_e = jnp.where(on, idx - lo, n).reshape(-1)           # n = "not here"
@@ -399,8 +423,11 @@ def routed_experts(x, valid, idx, w, experts, held, num_experts: int):
     def products(xs, sizes):
         rd = lambda l, r: jax.lax.ragged_dot(
             l, r, sizes, preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(rd(xs, gate)) * rd(xs, up)).astype(x.dtype)
-        return rd(h, down)                                    # [M, E] f32
+        if gate is not None:
+            h = jax.nn.silu(rd(xs, gate)) * rd(xs, up)
+        else:
+            h = jnp.square(jax.nn.relu(rd(xs, up)))
+        return rd(h.astype(x.dtype), down)                    # [M, E] f32
 
     trips = (walked + M - 1) // M
     if by_gather:

@@ -9,8 +9,8 @@ object with
 
 * ``spec`` — a :class:`DecoderSpec`: per layer the MIXER — the attention
   kind with its cache descriptor, its window and its sinks, a recurrent
-  state beside it, or a state ALONE — and the FFN kind, plus the
-  vocabulary and the most positions the model takes;
+  state beside it, a state ALONE, or none — and the FFN kind (or none),
+  plus the vocabulary and the most positions the model takes;
 * ``embed_tokens(token_ids, positions)`` -> ``Tensor [1, Q, E]`` (positions
   that the model adds at the embedding, as GPT does, are added here;
   a rotary model ignores them here and reads them in ``attn_in``);
@@ -31,16 +31,31 @@ object with
   out; ``index`` is the layer's place in the state arrays), and its
   ``attn_out`` takes the branch's output as a fourth argument; a layer
   whose mixer is a state ALONE has no ``attn_in`` at all, and its
-  ``attn_out`` is handed ``None`` for the attention's output;
+  ``attn_out`` is handed ``None`` for the attention's output; a layer
+  WITHOUT A MIXER has neither, and ``ffn_out(x, row_valid) -> (x,
+  counters)`` (norm, FFN, residual) is all of it;
 * ``final_norm(x)`` and ``logits(hidden)``.
 
 Two attention kinds, two FFN kinds and a routed shortcut around dense
 ones, three mixers (attention with its cache; attention with its cache
-and a recurrent state beside it; a recurrent state alone), one generation
-rule, seven callers (``models/gpt.py``, ``models/axk1.py``,
+and a recurrent state beside it; a recurrent state alone), a layer that
+is a mixer WITHOUT an FFN or an FFN WITHOUT a mixer, one generation
+rule, eight callers (``models/gpt.py``, ``models/axk1.py``,
 ``models/sdar.py``, ``models/mimo.py``, ``models/falcon_h1.py``,
-``models/lfm2.py``, ``models/longcat.py``). Nothing else is described
-here.
+``models/lfm2.py``, ``models/longcat.py``, ``models/nemotron_h.py``).
+Nothing else is described here.
+
+**Half a layer** (Nemotron-H: ``hybrid_override_pattern`` makes every
+published block ONE of a Mamba-2 mixer, attention, or an expert FFN, each
+``x + f(norm(x))``). ``LayerSpec.ffn`` ``"none"``: the layer's
+``attn_out`` ends with the mixer's output projection and its residual,
+routes nothing and returns no counters. ``attention``, ``cache`` and
+``state`` all ``None``: the layer has no mixer, holds no cache and no
+state, and the tower calls its ``ffn_out`` and nothing else — no
+``attn_in``, no ``mixer``, no cache write, no kernel, no ``attn_out``.
+One or the other: a layer with neither computes nothing and is refused.
+Neither form is built under block generation or around a routed
+shortcut.
 
 **A routed shortcut** (``LayerSpec.shortcut`` n > 0; LongCat-Flash's
 shortcut-connected experts, n = 1): the layer's own FFN is dense, and
@@ -122,11 +137,13 @@ from typing import Optional, Tuple
 
 __all__ = ["CacheSpec", "StateSpec", "LayerSpec", "DecoderSpec",
            "GenerationRule", "CacheGroup", "serving_decoder", "FULL",
-           "LATENT", "DENSE", "ROUTED", "ROUTED_COUNTERS", "SECTIONS",
+           "LATENT", "DENSE", "ROUTED", "NO_FFN", "ROUTED_COUNTERS",
+           "SECTIONS",
            "section", "layer_scope", "section_of"]
 
 FULL, LATENT = "full", "latent"        # attention kinds
 DENSE, ROUTED = "dense", "routed"      # FFN kinds
+NO_FFN = "none"                        # the layer is its mixer alone
 # the int32 scalars a routed layer's ``attn_out`` returns, summed over the
 # layers into a launch's result after its sentinel: pairs on held experts,
 # held experts hit, real rows routed, rows the grouped products walked,
@@ -153,6 +170,10 @@ SHARED_EXPERT = "shared_expert"
 ZERO_EXPERTS = "zero_experts"    # identity experts: their weights' sum x u
 MLP = "mlp"                      # a dense FFN; the add that closes a layer
 SHORTCUT = "shortcut"            # the add that closes a routed shortcut
+# the projections of a routed layer whose experts run in a LATENT narrower
+# than the stream (Nemotron-H's LatentMoE): into it before the grouped
+# products, out of it after them
+LATENT_PROJ = "latent_proj"
 HEAD = "head"                    # the logits of the rows that are read
 SAMPLE = "sample"                # the pick of one token a slot
 UNMASK_SCOPE = "unmask"          # a block pass's head, confidence, choice
@@ -164,7 +185,8 @@ SSM_CONV = "ssm_conv"            # its causal convolution and the tail
 SSM_SCAN = "ssm_scan"            # its recurrence and the D skip
 SECTIONS = (EMBED, NORM, QKV, CACHE_WRITE, ATTENTION, O_PROJ, ROUTER,
             MOE_SCOPE, SHARED_EXPERT, MLP, HEAD, SAMPLE, UNMASK_SCOPE,
-            SSM_PROJ, SSM_CONV, SSM_SCAN, ZERO_EXPERTS, SHORTCUT)
+            SSM_PROJ, SSM_CONV, SSM_SCAN, ZERO_EXPERTS, SHORTCUT,
+            LATENT_PROJ)
 
 
 def section(name: str):
@@ -252,7 +274,9 @@ class StateSpec:
 @dataclass(frozen=True)
 class LayerSpec:
     """One layer's mixer and FFN. ``attention`` and ``cache`` both
-    ``None``: the mixer is the ``state`` alone (module doc)."""
+    ``None``: the mixer is the ``state`` alone, or — ``state`` ``None``
+    too — the layer has no mixer and is its FFN alone; ``ffn``
+    :data:`NO_FFN`: the layer is its mixer alone (module doc)."""
     attention: Optional[str]
     cache: Optional[CacheSpec]
     ffn: str
@@ -271,10 +295,16 @@ class LayerSpec:
         routed shortcut around a dense one."""
         return self.ffn == ROUTED or self.shortcut > 0
 
+    @property
+    def has_mixer(self) -> bool:
+        """The layer has attention, a recurrent state, or both."""
+        return self.attention is not None or self.state is not None
+
     def __post_init__(self):
-        if self.ffn not in (DENSE, ROUTED):
+        if self.ffn not in (DENSE, ROUTED, NO_FFN):
             raise ValueError(f"FFN kind {self.ffn!r}: the fused path knows "
-                             f"{DENSE!r} and {ROUTED!r}")
+                             f"{DENSE!r}, {ROUTED!r} and {NO_FFN!r} (the "
+                             f"layer is its mixer alone)")
         if self.shortcut < 0:
             raise ValueError(f"shortcut {self.shortcut} must be >= 0")
         if self.shortcut and self.ffn == ROUTED:
@@ -282,21 +312,27 @@ class LayerSpec:
                 "a routed shortcut is built around a DENSE FFN: the "
                 "layer's own FFN is routed already, and one router a layer "
                 "is what the launch counters count")
+        if self.shortcut and (self.ffn == NO_FFN or self.attention is None):
+            raise ValueError(
+                "a routed shortcut is built around a DENSE FFN of a layer "
+                "with attention: its experts read that layer's "
+                "post-attention norm, and a layer without an FFN or "
+                "without attention has none")
         if (self.attention is None) != (self.cache is None):
             raise ValueError(
                 "an attention kind and a cache descriptor come together: "
                 "both, or neither for a layer whose mixer is a state alone")
         if self.attention is None:
-            if self.state is None:
+            if self.state is None and self.ffn == NO_FFN:
                 raise ValueError(
-                    "a layer with neither attention nor a recurrent state "
-                    "has no mixer: give it attention= with cache=, state=, "
-                    "or both")
+                    "a layer with neither a mixer (attention= with cache=, "
+                    "state=, or both) nor an FFN computes nothing: give it "
+                    "one or the other")
             if self.window or self.sinks or self.query_heads:
                 raise ValueError(
-                    "a layer whose mixer is a state alone has no window, "
-                    "no sink logits and no query heads: they are the "
-                    "attention's")
+                    "a layer without attention (its mixer a state alone, "
+                    "or none) has no window, no sink logits and no query "
+                    "heads: they are the attention's")
             return
         if self.window < 0:
             raise ValueError(f"window {self.window} must be >= 0")
@@ -390,6 +426,12 @@ class DecoderSpec:
                     f"{closes}, past the last layer "
                     f"{len(self.layers) - 1}: its experts' sum would "
                     f"join nothing")
+        if self.generation.block_length > 1 and any(
+                ls.ffn == NO_FFN or not ls.has_mixer for ls in self.layers):
+            raise ValueError(
+                "a layer without an FFN or without a mixer is not built "
+                "under block generation: the block step's passes are "
+                "written and tested for layers of a mixer AND an FFN")
         if self.generation.block_length > 1 and len(self.cache_layers) \
                 < len(self.layers):
             raise ValueError(
@@ -485,7 +527,7 @@ class DecoderSpec:
         if 0 <= layer < len(self.layers):
             raise ValueError(
                 f"layer {layer} holds no cache (its mixer is a state "
-                f"alone): it belongs to no cache group")
+                f"alone, or it has none): it belongs to no cache group")
         raise IndexError(f"layer {layer} out of range")
 
 
@@ -510,6 +552,6 @@ def serving_decoder(model):
             f"fused serving stack consumes a decoder spec "
             f"(models/decoder_spec.py), which models/gpt.py, "
             f"models/axk1.py, models/sdar.py, models/mimo.py, "
-            f"models/falcon_h1.py, models/lfm2.py and models/longcat.py "
-            f"provide")
+            f"models/falcon_h1.py, models/lfm2.py, models/longcat.py and "
+            f"models/nemotron_h.py provide")
     return make()

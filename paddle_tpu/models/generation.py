@@ -797,7 +797,11 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
     without routed layers, ``state`` ``None`` for one without state.
     A layer whose mixer is a state ALONE (its spec has no cache) runs
     ``mixer`` and ``attn_out`` and nothing else: no ``attn_in``, no cache
-    write, no move to the kernel's rows and back, no attention call.
+    write, no move to the kernel's rows and back, no attention call. A
+    layer WITHOUT A MIXER (no cache, no state) runs ``ffn_out`` and
+    nothing else; a layer without an FFN (``LayerSpec.ffn`` ``"none"``)
+    is one of the forms above whose ``attn_out`` ends with its residual —
+    nothing stands in for the missing half of either.
 
     TWO ROW AXES (PR 41). ``x``, ``positions``, ``write_block`` and
     ``write_off`` — and so every op of every layer: norms, projections,
@@ -866,6 +870,12 @@ def _fused_tower(dec, x, positions, pool, scales, write_block, write_off,
                 u + v for u, v in zip(counters, c))
 
     for li, (layer, ls) in enumerate(zip(dec.layers, dec.spec.layers)):
+        if not ls.has_mixer:
+            # the layer is its FFN alone: norm, FFN, residual
+            with DS.layer_scope(li):
+                x, counters = landed(li, ls, layer.ffn_out(x, row_valid),
+                                     counters)
+            continue
         if ls.cache is None:
             # the mixer is a state alone: the layer lives on the tower's
             # rows only — nothing is projected for a kernel, written to

@@ -401,13 +401,15 @@ def _group_block_counts(groups, num_slots, max_len, block_size, num_blocks,
 class GenerationEngine:
     """Continuous-batching serving over a decoder the fused stack has a
     spec of (``models/decoder_spec.py``: GPT-2, A.X-K1, SDAR,
-    MiMo-V2-Flash, Falcon-H1, LFM2-MoE) — one token
+    MiMo-V2-Flash, Falcon-H1, LFM2-MoE, LongCat-Flash, Nemotron-H) — one
+    token
     a sequence a step, or a block of them by diffusion, as the spec's
     generation rule says.
 
     ``model`` is a ``models.GPTForPretraining`` / ``GPTModel`` /
     ``AXK1ForCausalLM`` / ``SDARForCausalLM`` / ``MiMoV2ForCausalLM`` /
-    ``FalconH1ForCausalLM`` / ``Lfm2MoeForCausalLM`` (anything
+    ``FalconH1ForCausalLM`` / ``Lfm2MoeForCausalLM`` /
+    ``LongCatForCausalLM`` / ``NemotronHForCausalLM`` (anything
     ``serving_decoder`` has a spec of);
     its parameters are snapshotted at construction (sharded parameters
     serve sharded — jit follows the placement).
@@ -1507,7 +1509,11 @@ class GenerationEngine:
             # the layers that read and write the pool — what a reader
             # multiplies ``kv_tokens`` by — stamped only where some do not
             cache_layers=len(dspec.cache_layers)
-            if len(dspec.cache_layers) < len(dspec.layers) else None)
+            if len(dspec.cache_layers) < len(dspec.layers) else None,
+            # and the layers that read and write the slots' state, where
+            # some (and not all) do
+            state_layers=len(dspec.state_layers)
+            if 0 < len(dspec.state_layers) < len(dspec.layers) else None)
         return (Q, T, (token_ids, qpos, write_block, write_off, blk_seq,
                        qstart, pos0, tables, lo, kv_len, last_row),
                 n_spec, sample_mask, temps, token_src, block)

@@ -766,6 +766,7 @@ class Scheduler:
                     ssm_chunk_rows: int = 0,
                     tower_rows: Optional[int] = None,
                     cache_layers: Optional[int] = None,
+                    state_layers: Optional[int] = None,
                     kv_walks: Optional[int] = None,
                     kv_walks_handed: int = 0) -> None:
         """Record the shape of the ragged launch built THIS cycle into
@@ -812,7 +813,10 @@ class Scheduler:
         (``ops/ssm.py``). ``cache_layers`` (given only by a model with
         layers whose mixer is a state alone): the layers that read and
         write the pool, which is what ``kv_tokens`` and the walk counts
-        are a layer OF. ``kv_walks`` (given by the per-head ragged
+        are a layer OF; ``state_layers`` (given only where the layers
+        with a recurrent state are fewer than the layers): what
+        ``state_slots`` and the mixer's rows are a layer of. ``kv_walks``
+        (given by the per-head ragged
         kernel's counts alone): the walks of at least one block the
         kernel makes a layer, and ``kv_walks_handed``, those of them
         whose first group the walk before had started (monitor
@@ -842,6 +846,8 @@ class Scheduler:
                                  ssm_chunk_rows=int(ssm_chunk_rows))
             if cache_layers is not None:
                 self._rec["cache_layers"] = int(cache_layers)
+            if state_layers is not None:
+                self._rec["state_layers"] = int(state_layers)
             if kv_walks is not None:
                 stat_add("serving/kv_walks_handed", int(kv_walks_handed))
                 self._rec.update(kv_walks=int(kv_walks),

@@ -46,7 +46,7 @@ _DATA = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
 # the seed of each family's benchmark weights in its tests
 SEEDS = {"axk1": 2 ** 31 + 77, "sdar": 2 ** 31 + 33, "mimo": 2 ** 31 + 35,
          "falcon_h1": 2 ** 31 + 40, "lfm2": 2 ** 31 + 42,
-         "longcat": 2 ** 31 + 46}
+         "longcat": 2 ** 31 + 46, "nemotron_h": 2 ** 31 + 50}
 
 
 def new_char_gpt():
@@ -112,6 +112,10 @@ def new_default(family):
         from paddle_tpu.models.longcat import (LongCatConfig,
                                                LongCatForCausalLM)
         return LongCatForCausalLM(LongCatConfig.tiny())
+    if family == "nemotron_h":
+        from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                                  NemotronHForCausalLM)
+        return NemotronHForCausalLM(NemotronHConfig.tiny())
     assert family == "lfm2", family
     from paddle_tpu.models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
     return Lfm2MoeForCausalLM(Lfm2MoeConfig.tiny())
@@ -160,10 +164,12 @@ def config(family, **over):
         m.update(n_routed_experts=cfg.num_experts, experts_held=[0, 16],
                  first_k_dense_replace=0, weight_scales=_SDAR_SCALES)
     else:
-        # mimo, falcon_h1, lfm2, longcat (mimo's: layers global (dense),
-        # window, window, global; window 8; 16 experts, top 4; longcat's:
-        # 2 published layers = 4 sub-blocks, a router of 16 + 8 identity
-        # outputs, top 4, experts 4..11 held)
+        # mimo, falcon_h1, lfm2, longcat, nemotron_h (mimo's: layers
+        # global (dense), window, window, global; window 8; 16 experts,
+        # top 4; longcat's: 2 published layers = 4 sub-blocks, a router of
+        # 16 + 8 identity outputs, top 4, experts 4..11 held;
+        # nemotron_h's: blocks MEM*EM, 16 experts in a latent of 32, top
+        # 4, experts 4..11 held)
         name = family.replace("_", "-")
         with open(os.path.join(_DATA, f"tiny-{name}-config.json")) as f:
             m = json.load(f)["model"]

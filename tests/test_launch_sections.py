@@ -36,6 +36,9 @@ FAMILIES = {
     "sdar": (ALWAYS | ROUTED | {DS.UNMASK_SCOPE}, "block_step"),
     "mimo": (ALWAYS | ONE_TOKEN | ROUTED, "fused_step"),
     "falcon_h1": (ALWAYS | ONE_TOKEN | MIXER, "fused_step"),
+    # every block is half a layer: the E blocks' add is the ``mlp`` word
+    "nemotron_h": (ALWAYS | ONE_TOKEN | MIXER | ROUTED
+                   | {DS.SHARED_EXPERT, DS.LATENT_PROJ}, "fused_step"),
 }
 
 
@@ -46,7 +49,7 @@ def test_section_of_takes_the_innermost_word():
         == DS.MOE_SCOPE
     assert DS.section_of("jit(f)/layer3/add") is None
     assert DS.MOE_SCOPE == "moe_experts" and DS.UNMASK_SCOPE == "unmask"
-    assert len(set(DS.SECTIONS)) == len(DS.SECTIONS) == 18
+    assert len(set(DS.SECTIONS)) == len(DS.SECTIONS) == 19
     with pytest.raises(ValueError, match="no section"):
         DS.section("attn")
 

@@ -63,7 +63,7 @@ def test_a_state_beside_attention_and_a_state_alone_share_one_descriptor():
 
 
 @pytest.mark.parametrize("make,match", [
-    (lambda: DS.LayerSpec(None, None, DS.DENSE), "has no mixer"),
+    (lambda: DS.LayerSpec(None, None, DS.NO_FFN), "computes nothing"),
     (lambda: DS.LayerSpec(None, FULL, DS.DENSE, state=TAIL),
      "come together"),
     (lambda: DS.LayerSpec(DS.FULL, None, DS.DENSE, state=TAIL),
@@ -78,7 +78,8 @@ def test_a_state_beside_attention_and_a_state_alone_share_one_descriptor():
     (lambda: _spec(_attn(), _conv(), generation=DS.GenerationRule(
         block_length=4, denoising_steps=4, mask_token_id=255)),
      "state alone is not built under block generation"),
-], ids=["no-mixer", "cache-without-attention", "attention-without-cache",
+], ids=["no-mixer-and-no-ffn", "cache-without-attention",
+        "attention-without-cache",
         "query-heads", "window", "a-third-kind", "no-cache-at-all",
         "block-generation"])
 def test_what_is_not_built_is_refused_by_its_message(make, match):
