@@ -352,6 +352,27 @@ def test_a_preempted_request_is_re_fed_to_the_same_tokens(net, make):
     assert eng._pool.blocks_in_use == 0 and eng._pool.n_active == 0
 
 
+def test_the_plan_and_the_analyzer_take_the_step_with_state(net):
+    """The plan counts the three ``M`` blocks' state rows (the slots' and
+    the row no slot owns) beside the ONE cache layer's blocks, and the
+    analyzer bills the step kernel's state — an output aliased to its
+    operand — once: a step that ran leaves no error, a budget short by a
+    slot's state is refused."""
+    eng = GenerationEngine(net, num_slots=2, max_len=32, block_size=8,
+                           hbm_budget_bytes=1 << 30)
+    assert eng._plan["fits"] and eng._plan["state_bytes"] == 3 * SLOT_BYTES
+    assert eng._plan["pool_bytes"] == eng._pool.capacity_bytes \
+        + 3 * SLOT_BYTES
+    list(eng.submit(_ids(12, seed=5).tolist(), 3).stream())
+    report = eng.analyze()
+    eng.close()
+    assert not [f for f in report.findings if f.severity == "error"]
+    with pytest.raises(Exception, match="does not fit"):
+        GenerationEngine(net, num_slots=2, max_len=32, block_size=8,
+                         hbm_budget_bytes=eng._plan["static_peak_bytes"]
+                         - SLOT_BYTES)
+
+
 def test_a_block_computes_nothing_for_the_half_it_lacks(engine):
     """The compiled step's text by layer scope: an ``M`` block names the
     mixer's sections and no attention, no cache write, no FFN; the ``*``

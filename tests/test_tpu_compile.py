@@ -463,3 +463,53 @@ def test_the_row_tile_is_the_largest_power_of_two_that_divides_the_rows(
     rows: tiles of 512 over groups of 72) ran into."""
     assert {t[0] for t in _ragged_dot_tiles(v5e, rows, 16, 2048, 1536)} \
         == {tile}
+
+
+# ---------------------------------------------------------------------------
+# The Mamba-2 decode update (ops/ssm.py:state_step) at the two cells' widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("L,S,H,P,N,G", [
+    (6, 64, 32, 128, 256, 2),       # falcon-h1-34b-pp12.decode
+    (5, 128, 128, 64, 128, 8),      # nemotron3-super-ep4.decode
+], ids=["falcon-h1", "nemotron3"])
+def test_the_state_step_updates_the_donated_state_in_place(v5e, L, S, H, P,
+                                                           N, G):
+    """Two layers of ``ssm_scan`` — the step kernel, then the chunked
+    scan's loop reading the kernel's OUTPUT — over the cell's whole state
+    array, donated: the head block the module derives fits its budget,
+    the compiled module holds the two kernels, aliases the state to its
+    result with no temporary of its size, and has NO ``copy`` of the
+    state's shape (1.6 | 2.7 GB: one would not fit beside the weights)."""
+    from paddle_tpu.ops import ssm as SSM
+    hb = SSM.step_head_block(H, P, N, G)
+    assert H % hb == 0 and 4 * hb * P * N * 4 <= SSM.STEP_VMEM_BUDGET
+    Q = S + 256                     # decode rows beside a chunk
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=v5e)
+    i32 = jnp.int32
+    lay = SSM.SeqLayout(sds((Q,), i32), sds((Q,), i32), sds((S,), i32),
+                        sds((S,), i32), sds((S,), jnp.bool_))
+    state = sds((L, S + 1, H, P, N))
+
+    def mixers(state, lay, x, dt, a, b, c, d):
+        for layer in (1, 2):
+            y, state = SSM.ssm_scan(x, dt, a, b, c, d, state, layer, lay)
+            x = x + y                   # the next layer waits for this one
+        return x, state
+
+    compiled = jax.jit(mixers, donate_argnums=0).lower(
+        state, lay, sds((Q, H, P)), sds((Q, H)), sds((H,)), sds((Q, G, N)),
+        sds((Q, G, N)), sds((H,))).compile()
+    text = compiled.as_text()
+    calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    assert sum(c.startswith("%ssm_step") for c in calls) == 2, calls
+    shape = "f32[%s]" % ",".join(str(n) for n in state.shape)
+    copies = [ln[:200] for ln in text.splitlines()
+              if " copy(" in ln and shape in ln]
+    assert not copies, copies
+    state_bytes = int(np.prod(state.shape)) * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 4
