@@ -269,7 +269,12 @@ def phase_train(cfg, batch: int, seq: int, steps: int) -> dict:
     rec = program_registry.get(sites[0])
     check(rec.compiles == 1, f"the step compiled once for {steps + 4} "
                              f"steps (zero recompiles after the first)")
-    log(f"  step compile {rec.last_compile_ms / 1e3:.1f} s, XLA temp "
+    build = rec.builds[-1]
+    log(f"  step build: trace {build['trace_ms'] / 1e3:.1f}, lower "
+        f"{build['lower_ms'] / 1e3:.1f}, compile "
+        f"{build['compile_ms'] / 1e3:.1f} s ({build['cache_hits']} cache "
+        f"hits, {build['cache_misses']} misses), first call "
+        f"{build['first_call_ms'] / 1e3:.1f} s; XLA temp "
         f"{rec.temp_bytes}, arguments {rec.argument_bytes} bytes")
     text = step_text_report(sites,
                             kernels=("flash_attention_fwd", "flash_attention_bwd",

@@ -44,10 +44,14 @@ from typing import Optional
 from .flags import flag_value
 
 __all__ = ["cache_root", "default_dir", "enable", "disable", "status",
-           "entries", "maybe_enable"]
+           "entries", "maybe_enable", "lookups"]
 
 _lock = threading.Lock()
 _state = {"enabled": False, "dir": None, "reason": None}
+# jax's own count of this process's persistent-cache lookups, since the
+# first lookups() call: [hits, misses] (see lookups())
+_lookups = [0, 0]
+_listening = False
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -121,6 +125,32 @@ def disable() -> None:
 def status() -> dict:
     with _lock:
         return dict(_state)
+
+
+def _on_event(event: str, **_) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _lookups[0] += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        _lookups[1] += 1
+
+
+def lookups() -> tuple:
+    """``(hits, misses)`` of the persistent cache in this process, from
+    jax's own monitoring events (``/jax/compilation_cache/cache_hits``:
+    an executable was retrieved; ``cache_misses``: one was compiled and
+    written) — ONE process-wide listener, registered at the first call.
+    Read it before and after a compile: builds are serialised
+    (``program_registry._TRACE_LOCK``), so the difference is that
+    program's. A step with Pallas kernels makes several lookups; a
+    compile under jax's persistence floor, or with the cache off, makes
+    none."""
+    global _listening
+    with _lock:
+        if not _listening:
+            import jax
+            jax.monitoring.register_event_listener(_on_event)
+            _listening = True
+        return _lookups[0], _lookups[1]
 
 
 def entries(cache_dir: Optional[str] = None) -> int:

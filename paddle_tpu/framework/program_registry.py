@@ -8,10 +8,16 @@ eager-op dispatch wrappers, the hapi donated train step, the serving
 prefill/decode steps per bucket) registers its compiled executable here
 at compile time:
 
-* **compile cost** — wall ms per compile into the ``compile/ms`` and
-  ``compile/ms/<site>`` histograms plus the ``compile/count`` counter
-  (framework/monitor.py), so compile churn is a queryable distribution,
-  not a feeling;
+* **build cost** — a program's build split where the work happens:
+  tracing, lowering, and the compile (a backend compile cold, a
+  persistent-cache retrieval warm: the cache's hits and misses ride
+  along), then the FIRST call of the fresh executable. Each part is a
+  ``program/*`` span (``profiler/span.py``, so a ``TraceAnnotation`` in
+  anyone's jax trace) and a field of a stamped event in the site's
+  :attr:`ProgramRecord.builds`; the three build parts' sum goes into the
+  ``compile/ms`` and ``compile/ms/<site>`` histograms with the
+  ``compile/count`` counter (framework/monitor.py), so compile churn is
+  a queryable distribution, not a feeling;
 * **program cost** — jaxpr eqn count, XLA ``cost_analysis()`` FLOPs and
   bytes-accessed, and ``memory_analysis()`` temp/argument/output bytes,
   wherever the backend provides them (CPU provides cost analysis; a
@@ -27,9 +33,10 @@ without the override only raw FLOP/s are reported).
 Two integration shapes:
 
 * :func:`aot_site` — wraps a function the way ``jax.jit`` would, but
-  compiles EXPLICITLY (``trace → lower → compile``) per signature and
-  calls the held executable directly. This is how the few big owned
-  sites (train step, serving steps) register full cost analysis with
+  compiles EXPLICITLY (``trace → lower → compile``, each timed apart)
+  per signature and calls the held executable directly. This is how
+  the few big owned sites (train step, serving steps) register full
+  cost analysis with
   exactly ONE XLA compile — jax 0.4.x does NOT share its jit dispatch
   cache with ``lower().compile()``, so querying analysis lazily from a
   normally-jitted function would compile everything twice.
@@ -42,13 +49,16 @@ estimate_flops`` and ``hapi.model_summary.flops`` dedupe onto.
 """
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import threading
 import time
 import weakref
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..profiler.span import record as _span
+from . import compile_cache
 from .monitor import stat_add, stat_observe
 
 __all__ = ["ProgramRecord", "aot_site", "AotSite", "note_compile", "get",
@@ -68,6 +78,12 @@ _sites: "weakref.WeakValueDictionary[str, AotSite]" = \
 # cap records still accumulate for callers holding them by reference,
 # only snapshot() visibility is bounded
 _MAX_RECORDS = 1024
+# build events kept a site (newest): an engine's sites build one program
+# each; a train step rebuilt at every new batch shape keeps its last few
+_MAX_BUILDS = 8
+# what a build event says beside where it began (``at``)
+_BUILD_KEYS = ("trace_ms", "lower_ms", "compile_ms", "cache_hits",
+               "cache_misses", "first_call_ms", "eqns")
 
 # bf16 peak FLOPs/sec per chip by device-kind substring (the bench.py
 # table, hoisted here so fit()/stats() MFU and the bench children agree
@@ -81,18 +97,32 @@ PEAK_FLOPS_TABLE = (
 
 
 class ProgramRecord:
-    """Per-site compile + cost bookkeeping (host ints/floats only)."""
+    """Per-site compile + cost bookkeeping (host ints/floats only).
+
+    ``builds`` holds the site's newest build events, oldest first, each
+    ``{"at", "trace_ms", "lower_ms", "compile_ms", "cache_hits",
+    "cache_misses", "first_call_ms", "eqns"}``: ``at`` is
+    ``time.perf_counter()`` where the build began (the flight recorder's
+    clock), the three parts add up to what ``compile_ms_total`` gained,
+    ``cache_hits`` / ``cache_misses`` are the persistent cache's lookups
+    of that compile (``compile_cache.lookups``) and ``first_call_ms`` the
+    wall of the first call of the fresh executable (``None`` until it
+    returned). A site on the plain-``jit`` fallback has ONE wall for
+    everything: its events say ``"fallback": True``, carry that wall as
+    ``wall_ms`` and ``None`` for every part."""
 
     __slots__ = ("site", "compiles", "compile_ms_total", "last_compile_ms",
                  "eqns", "flops", "bytes_accessed", "temp_bytes",
                  "argument_bytes", "output_bytes", "generated_code_bytes",
-                 "static_peak_bytes")
+                 "static_peak_bytes", "builds")
 
     def __init__(self, site: str):
         self.site = site
         self.compiles = 0
         self.compile_ms_total = 0.0
         self.last_compile_ms: Optional[float] = None
+        self.builds: collections.deque = collections.deque(
+            maxlen=_MAX_BUILDS)
         self.eqns: Optional[int] = None
         self.flops: Optional[float] = None
         self.bytes_accessed: Optional[float] = None
@@ -106,7 +136,9 @@ class ProgramRecord:
         self.static_peak_bytes: Optional[int] = None
 
     def as_dict(self) -> dict:
-        return {k: getattr(self, k) for k in self.__slots__}
+        out = {k: getattr(self, k) for k in self.__slots__}
+        out["builds"] = [dict(b) for b in self.builds]
+        return out
 
     def __repr__(self):
         return (f"<ProgramRecord {self.site!r} compiles={self.compiles} "
@@ -124,17 +156,22 @@ def _record(site: str) -> ProgramRecord:
 
 
 def note_compile(site: str, wall_ms: float, eqns: Optional[int] = None,
-                 analysis: Optional[dict] = None) -> ProgramRecord:
+                 analysis: Optional[dict] = None,
+                 build: Optional[dict] = None) -> ProgramRecord:
     """Record one compile of ``site``: wall ms into the ``compile/ms``
     histograms (global + per-site), ``compile/count``, and — when the
     caller has them — the program's eqn count and cost/memory analysis
     onto the site's :class:`ProgramRecord` (latest compile wins: a
-    retrace at a new shape supersedes the old figures)."""
+    retrace at a new shape supersedes the old figures). ``build`` is the
+    stamped event an :class:`AotSite` keeps of it
+    (:attr:`ProgramRecord.builds`)."""
     rec = _record(site)
     with _lock:
         rec.compiles += 1
         rec.compile_ms_total += float(wall_ms)
         rec.last_compile_ms = float(wall_ms)
+        if build is not None:
+            rec.builds.append(build)
         if eqns is not None:
             rec.eqns = int(eqns)
         if analysis:
@@ -327,9 +364,10 @@ _TRACE_LOCK = threading.RLock()
 
 class AotSite:
     """A jit site that owns its executables: per input signature it
-    traces, lowers and compiles EXPLICITLY (timing the compile and
-    registering the program's cost analysis), then dispatches straight
-    to the held executable — drop-in for ``jax.jit(fn, static_argnums,
+    traces, lowers and compiles EXPLICITLY (timing each part, and the
+    first call after them, into a stamped build event and registering
+    the program's cost analysis), then dispatches straight to the held
+    executable — drop-in for ``jax.jit(fn, static_argnums,
     donate_argnums)`` at sites whose signatures are flat and stable (the
     donated train step, the serving prefill/decode steps).
 
@@ -342,13 +380,23 @@ class AotSite:
     un-flattenable argument) falls back PERMANENTLY to the plain jitted
     call for this site, still noting first-call wall time — robustness
     first, cost analysis when available.
+
+    ``on_build(event)``, where given, is called once a build, on the
+    thread that called, after the first call of the fresh executable
+    returned: the owner's place to stamp what it knows of the caller
+    onto the event (the serving engine: the launch's rows and slots).
     """
 
     _MAX_SIGNATURES = 64     # executables kept per site (oldest evicted)
 
-    def __init__(self, name: str, fn, static_argnums=(), donate_argnums=()):
+    def __init__(self, name: str, fn, static_argnums=(), donate_argnums=(),
+                 on_build: Optional[Callable[[dict], None]] = None):
         import jax
         self.site = name
+        self.on_build = on_build
+        # the program/* spans' argument: a TraceMe encodes its arguments
+        # as ``name#k=v,...#``, so a ``#`` in a value would end them
+        self._span_args = {"site": name.replace("#", "@")}
         self.static_argnums = tuple(int(i) for i in static_argnums)
         self.donate_argnums = tuple(int(i) for i in donate_argnums)
         self.jitted = jax.jit(fn, static_argnums=self.static_argnums or
@@ -405,7 +453,10 @@ class AotSite:
         # leaf — tens of µs for a full train-state tree against the
         # multi-ms step it dispatches. A cheaper identity probe (leaf
         # count + first-leaf aval) could serve the wrong program when a
-        # LATER leaf changes shape, so the full key stays.
+        # LATER leaf changes shape, so the full key stays. A call that
+        # finds its executable runs nothing of the build's bookkeeping:
+        # the parts of a build and its first call are timed in the
+        # ``compiled is None`` branch alone.
         try:
             key, tracer = self._key(args)
         except Exception:                                # noqa: BLE001
@@ -419,11 +470,26 @@ class AotSite:
             return self._call_fallback(key, args)
         compiled = self._compiled.get(key)
         if compiled is None:
-            compiled = self._compile(key, args)
+            compiled, build = self._compile(key, args)
             if compiled is None:             # explicit path unavailable
                 return self._call_fallback(key, args)
+            return self._first_call(compiled, build, key, args)
         self.last_dispatch_flops = self._flops_by_key.get(key)
         return compiled(*self._dynamic(args))
+
+    def _first_call(self, compiled, build: dict, key, args):
+        """The first call of a fresh executable, timed into its build
+        event: the call's own wall — whatever the runtime does before it
+        returns (loading the executable onto the device) — with no sync
+        added, so the device work it enqueues is not in it."""
+        self.last_dispatch_flops = self._flops_by_key.get(key)
+        t0 = time.perf_counter()
+        with _span("program/first_call", "startup", self._span_args):
+            out = compiled(*self._dynamic(args))
+        build["first_call_ms"] = (time.perf_counter() - t0) * 1e3
+        if self.on_build is not None:
+            self.on_build(build)
+        return out
 
     def _donated_mask(self, args):
         """Donation mask over the traced program's flat invars: the
@@ -445,7 +511,9 @@ class AotSite:
             return None
 
     def _compile(self, key, args):
-        t0 = time.perf_counter()
+        """(executable, its build event) — ``(None, None)`` where the
+        explicit path is unavailable."""
+        site = self._span_args
         try:
             # ONE trace at a time, process-wide: the serving/hapi step
             # bodies trace through functional_state(net, ...), which
@@ -453,35 +521,54 @@ class AotSite:
             # engine scheduler threads tracing over a SHARED model
             # concurrently corrupt each other's captures ("compiled for
             # 79 inputs but called with 43", then a backend abort).
-            # Compiles are rare and the executable DISPATCH below stays
+            # Compiles are rare and the executable DISPATCH stays
             # outside the lock, so fleets serialize only their cold
-            # start.
+            # start. The persistent cache's lookups are counted process-
+            # wide: under the lock, their difference is this program's.
             with _TRACE_LOCK:
-                traced = self.jitted.trace(*args)
-                eqns = len(traced.jaxpr.jaxpr.eqns)
-                compiled = traced.lower().compile()
+                at = time.perf_counter()
+                with _span("program/trace", "startup", site):
+                    traced = self.jitted.trace(*args)
+                    eqns = len(traced.jaxpr.jaxpr.eqns)
+                t_traced = time.perf_counter()
+                with _span("program/lower", "startup", site):
+                    lowered = traced.lower()
+                t_lowered = time.perf_counter()
+                hits, misses = compile_cache.lookups()
+                with _span("program/compile", "startup", site):
+                    compiled = lowered.compile()
+                t_compiled = time.perf_counter()
+                hits_after, misses_after = compile_cache.lookups()
         except Exception as e:                           # noqa: BLE001
             logger.debug("AotSite %s: explicit compile failed (%r); "
                          "falling back to plain jit", self.site, e)
             self._fallback = True
-            return None
-        wall_ms = (time.perf_counter() - t0) * 1e3
+            return None, None
+        build = {"at": at, "trace_ms": (t_traced - at) * 1e3,
+                 "lower_ms": (t_lowered - t_traced) * 1e3,
+                 "compile_ms": (t_compiled - t_lowered) * 1e3,
+                 "cache_hits": hits_after - hits,
+                 "cache_misses": misses_after - misses,
+                 "first_call_ms": None, "eqns": eqns}
         analysis = analyze_compiled(compiled)
         analysis["static_peak_bytes"] = static_peak_of_trace(
             traced.jaxpr, self._donated_mask(args))
-        note_compile(self.site, wall_ms, eqns=eqns, analysis=analysis)
+        note_compile(self.site, (t_compiled - at) * 1e3, eqns=eqns,
+                     analysis=analysis, build=build)
         if len(self._compiled) >= self._MAX_SIGNATURES:
             oldest = next(iter(self._compiled))
             self._compiled.pop(oldest)
             self._flops_by_key.pop(oldest, None)
         self._compiled[key] = compiled
         self._flops_by_key[key] = analysis.get("flops")
-        return compiled
+        return compiled, build
 
     def _call_fallback(self, key, args):
         """Plain jitted call; first call per signature still timed and
         noted (trace+compile+first-run wall — the dispatch-layer
-        approximation) so ``compile/ms``/``compile/count`` stay live."""
+        approximation) so ``compile/ms``/``compile/count`` stay live.
+        Its build event holds that ONE wall as ``wall_ms`` and no parts,
+        marked ``fallback``: no reader adds a lump to a part."""
         first = key is not None and key not in self._seen_fallback_keys
         t0 = time.perf_counter()
         if first:
@@ -493,7 +580,12 @@ class AotSite:
             out = self.jitted(*args)
         if first:
             self._seen_fallback_keys.add(key)
-            note_compile(self.site, (time.perf_counter() - t0) * 1e3)
+            wall_ms = (time.perf_counter() - t0) * 1e3
+            build = {"at": t0, **dict.fromkeys(_BUILD_KEYS),
+                     "fallback": True, "wall_ms": wall_ms}
+            note_compile(self.site, wall_ms, build=build)
+            if self.on_build is not None:
+                self.on_build(build)
         # best effort on the fallback path: latest-compile figures
         self.last_dispatch_flops = self.record.flops
         return out
@@ -503,9 +595,10 @@ class AotSite:
                 f"fallback={self._fallback}>")
 
 
-def aot_site(name: str, fn, static_argnums=(), donate_argnums=()) -> AotSite:
+def aot_site(name: str, fn, static_argnums=(), donate_argnums=(),
+             on_build: Optional[Callable[[dict], None]] = None) -> AotSite:
     """Build an :class:`AotSite` — the registry-instrumented replacement
     for ``jax.jit(fn, static_argnums=..., donate_argnums=...)`` at owned
     program sites."""
     return AotSite(name, fn, static_argnums=static_argnums,
-                   donate_argnums=donate_argnums)
+                   donate_argnums=donate_argnums, on_build=on_build)

@@ -57,7 +57,9 @@ prefix.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
+import time
 import weakref
 from typing import Iterator, Optional
 
@@ -68,6 +70,7 @@ from ..framework import program_registry as _registry
 from ..framework import trace_probe as _probe
 from ..framework.monitor import stat_add
 from ..profiler import memory as _memory
+from ..profiler import span as _prof
 from .paging import PagedKVPool, PoolCapacityError
 from .scheduler import GenerationRequest, Scheduler
 
@@ -124,6 +127,32 @@ _statusz_registered = False
 _statusz_lock = threading.Lock()
 
 
+@contextlib.contextmanager
+def _phase(phases_ms: dict, name: str):
+    """One part of the engine's build: the span ``startup/<name>`` and
+    its wall into ``phases_ms[name]``."""
+    t0 = time.perf_counter()
+    with _prof.record(f"startup/{name}", "startup"):
+        yield
+    phases_ms[name] = (time.perf_counter() - t0) * 1e3
+
+
+def _startup_line(st: dict) -> str:
+    """``stats()["startup"]`` on one line: the engine's build, then what
+    building its programs took, part by part."""
+    built = [p for p in st["programs"] if not p.get("fallback")]
+    secs = lambda key: sum(p[key] or 0.0 for p in built) / 1e3
+    hits = sum(p["cache_hits"] for p in built)
+    lookups = hits + sum(p["cache_misses"] for p in built)
+    return (f"startup: build {st['build_ms'] / 1e3:.1f} s | "
+            f"{len(st['programs'])} programs: trace {secs('trace_ms'):.1f}, "
+            f"lower {secs('lower_ms'):.1f}, "
+            f"{'load' if lookups and hits == lookups else 'compile'} "
+            f"{secs('compile_ms'):.1f}, first call "
+            f"{secs('first_call_ms'):.1f} s "
+            f"({hits}/{lookups} cache hits)")
+
+
 def _engine_section() -> str:
     """statusz section: one line of load + cache + health per live
     engine, plus recent flight-recorder trouble (failed cycles, the
@@ -151,6 +180,7 @@ def _engine_section() -> str:
             if s.get("decode_tokens_per_sec") is not None:
                 head += f" tok/s={s['decode_tokens_per_sec']:.1f}"
             lines.append(head)
+            lines.append("  " + _startup_line(s["startup"]))
             ttft = s.get("ttft_ms")
             if ttft:
                 lines.append(f"  ttft p50 {ttft['p50']:.1f} ms  "
@@ -457,6 +487,7 @@ class GenerationEngine:
     per request (the parity contract, tests/test_serving_engine.py).
     """
 
+    @_prof.record("startup/engine_build", "startup")
     def __init__(self, model, num_slots: int = 8,
                  max_len: Optional[int] = None, *, top_k: int = 0,
                  top_p: float = 1.0, pad_token_id: int = 0,
@@ -471,6 +502,12 @@ class GenerationEngine:
                  hbm_budget_bytes: Optional[int] = None,
                  lane_weights: Optional[dict] = None,
                  host_tier_bytes: Optional[int] = None):
+        # the engine's own build, for stats()["startup"]: where it began
+        # on the flight recorder's clock, and what each part took
+        t_build = time.perf_counter()
+        phases_ms = dict.fromkeys(
+            ("params", "pallas_smoke", "pool", "plan_gate", "scheduler"),
+            0.0)
         import jax
 
         from ..nn.layer.layers import get_buffers_tree, get_params_tree
@@ -547,16 +584,17 @@ class GenerationEngine:
         self._mesh = mesh
         self._mp_axis = str(mp_axis)
         self._mp = 1
-        if mesh is not None:
-            from ..models.generation import (_mp_mesh_check,
-                                             shard_params_megatron)
-            self._mp = _mp_mesh_check(model, mesh, self._mp_axis)
-            # lay the weights out Megatron-style BEFORE the snapshot:
-            # the params tree then holds the sharded arrays and the
-            # shard_map'd steps consume their local shards directly
-            shard_params_megatron(model, mesh, mp_axis=self._mp_axis)
-        self._params = get_params_tree(model)
-        self._buffers = get_buffers_tree(model)
+        with _phase(phases_ms, "params"):
+            if mesh is not None:
+                from ..models.generation import (_mp_mesh_check,
+                                                 shard_params_megatron)
+                self._mp = _mp_mesh_check(model, mesh, self._mp_axis)
+                # lay the weights out Megatron-style BEFORE the snapshot:
+                # the params tree then holds the sharded arrays and the
+                # shard_map'd steps consume their local shards directly
+                shard_params_megatron(model, mesh, mp_axis=self._mp_axis)
+            self._params = get_params_tree(model)
+            self._buffers = get_buffers_tree(model)
         if dtype is None:
             dtype = self._params[next(iter(self._params))].dtype
         # a per-head K|V row is two head_dims wide; a latent row has no
@@ -577,7 +615,8 @@ class GenerationEngine:
                 f"diffusion block never straddles a cache block (its rows "
                 f"are one rewrite of ops/kv_append.py and one DMA of the "
                 f"attention kernel)")
-        pallas_smoke.ensure()
+        with _phase(phases_ms, "pallas_smoke"):
+            pallas_smoke.ensure()
         self._key = jax.random.PRNGKey(int(seed))
         self._eid = _next_engine_id()
         self._min_bucket = int(min_bucket)    # the draft's prefill ladder
@@ -586,18 +625,33 @@ class GenerationEngine:
         # say, not a knob (_group_block_counts)
         counts = _group_block_counts(groups, num_slots, max_len,
                                      block_size, num_blocks, prefill_budget)
-        self._pool = PagedKVPool(
-            len(groups[0].layers), num_slots, cache.rows,
-            max_len, head_dim, block_size=block_size,
-            num_blocks=counts[0], dtype=kv_dtype or dtype,
-            mesh=mesh, mp_axis=mp_axis, lanes=cache.lanes,
-            window=groups[0].window, more_groups=[
-                dict(num_layers=len(g.layers), num_heads=g.cache.rows,
-                     lanes=g.cache.lanes, window=g.window, num_blocks=n)
-                for g, n in zip(groups[1:], counts[1:])],
-            # a row a slot of every part of the spec's recurrent state
-            state=(len(spec.state_layers), spec.state.parts)
-            if spec.state is not None else None)
+        with _phase(phases_ms, "pool"):
+            self._pool = PagedKVPool(
+                len(groups[0].layers), num_slots, cache.rows,
+                max_len, head_dim, block_size=block_size,
+                num_blocks=counts[0], dtype=kv_dtype or dtype,
+                mesh=mesh, mp_axis=mp_axis, lanes=cache.lanes,
+                window=groups[0].window, more_groups=[
+                    dict(num_layers=len(g.layers), num_heads=g.cache.rows,
+                         lanes=g.cache.lanes, window=g.window, num_blocks=n)
+                    for g, n in zip(groups[1:], counts[1:])],
+                # a row a slot of every part of the spec's recurrent state
+                state=(len(spec.state_layers), spec.state.parts)
+                if spec.state is not None else None)
+            # hierarchical KV cache (ISSUE 20): a bounded host-DRAM block
+            # store behind the device prefix cache — LRU-evicted
+            # refcount-0 blocks demote instead of dying, and a hit on a
+            # demoted prefix promotes it back via async H2D copies the
+            # scheduler overlaps with decode. Host DRAM, so hbm_budget
+            # planning never bills it.
+            self._host_tier = None
+            if host_tier_bytes is not None:
+                from .host_tier import HostBlockPool
+                self._host_tier = HostBlockPool(
+                    int(host_tier_bytes), self._pool.host_block_nbytes,
+                    scale_nbytes=self._pool.host_scale_nbytes,
+                    name=f"serving/host_tier#{self._eid}")
+                self._pool.attach_host_tier(self._host_tier)
         # the first window group's W (0: none): what the launch counters
         # of the windowed walk are counted from
         self._window = next((g.window for g in groups if g.window), 0)
@@ -605,6 +659,7 @@ class GenerationEngine:
         # budget): with the slots and the generation rule, what the rows
         # of a program's tower are counted from (_tower_rows)
         self._chunk_budget = int(prefill_budget or max_len)
+        self._sites = []              # every jit site of this engine
         self._fused_jits = {}         # (q bucket, table bucket) -> step
         # the fused step's "previous result" operand while no launch is
         # in flight: the shape of its own result ([slots | sentinel |
@@ -626,20 +681,6 @@ class GenerationEngine:
                 self._no_prev, NamedSharding(mesh, PartitionSpec()))
         self._spec_jits = {}          # (q, table) -> spec verify step
         self._copy_jit = None         # lazy COW device block copy
-        # hierarchical KV cache (ISSUE 20): a bounded host-DRAM block
-        # store behind the device prefix cache — LRU-evicted
-        # refcount-0 blocks demote instead of dying, and a hit on a
-        # demoted prefix promotes it back via async H2D copies the
-        # scheduler overlaps with decode. Host DRAM, so hbm_budget
-        # planning never bills it.
-        self._host_tier = None
-        if host_tier_bytes is not None:
-            from .host_tier import HostBlockPool
-            self._host_tier = HostBlockPool(
-                int(host_tier_bytes), self._pool.host_block_nbytes,
-                scale_nbytes=self._pool.host_scale_nbytes,
-                name=f"serving/host_tier#{self._eid}")
-            self._pool.attach_host_tier(self._host_tier)
         self._closed = False
         self._close_lock = threading.Lock()
         # speculative decoding: a small draft
@@ -658,11 +699,12 @@ class GenerationEngine:
         # naming the fattest program point before any compile. The plan
         # is a make_jaxpr trace of the RAW step builder — no AotSite,
         # no probe, no registry record, zero compiles.
-        self._hbm_budget_bytes = int(hbm_budget_bytes) \
-            if hbm_budget_bytes is not None else _device_memory_limit()
         self._plan = None
-        if self._hbm_budget_bytes is not None:
-            self._plan = self.plan_replica(self._hbm_budget_bytes)
+        with _phase(phases_ms, "plan_gate"):
+            self._hbm_budget_bytes = int(hbm_budget_bytes) \
+                if hbm_budget_bytes is not None else _device_memory_limit()
+            if self._hbm_budget_bytes is not None:
+                self._plan = self.plan_replica(self._hbm_budget_bytes)
         # per-engine compute accounting (scheduler-thread writes, host
         # ints): FLOPs of the step programs actually DISPATCHED — the
         # (q, table)-bucket programs differ widely in cost, so stats()
@@ -670,13 +712,18 @@ class GenerationEngine:
         # cycle
         self._decode_flops_dispatched = 0.0
         self._decode_dispatches = 0
-        self._sched = Scheduler(
-            self._pool, self._run_admit, self._run_fused_step,
-            max_queue=max_queue, prefill_budget=prefill_budget,
-            do_copy=self._run_copy,
-            do_spec_step=self._run_spec_step if self._spec else None,
-            spec_k=self._spec_k, lane_weights=lane_weights,
-            generation=spec.generation)
+        with _phase(phases_ms, "scheduler"):
+            self._sched = Scheduler(
+                self._pool, self._run_admit, self._run_fused_step,
+                max_queue=max_queue, prefill_budget=prefill_budget,
+                do_copy=self._run_copy,
+                do_spec_step=self._run_spec_step if self._spec else None,
+                spec_k=self._spec_k, lane_weights=lane_weights,
+                generation=spec.generation)
+        self._startup = {
+            "t_build": t_build,
+            "build_ms": (time.perf_counter() - t_build) * 1e3,
+            "phases_ms": phases_ms}
         # telemetry spine wiring (ISSUE 13): the engine joins the
         # statusz console and publishes its stats() island through the
         # labeled metrics registry ({engine=<id>} gauges/counters)
@@ -864,6 +911,7 @@ class GenerationEngine:
         if tenants:
             s["tenants"] = tenants
         s.update(self._compute_stats())
+        s["startup"] = self._startup_stats()
         # KV memory, from the HBM ledger (profiler/memory.py — the pool
         # publishes capacity + in-use bytes there on every alloc/free)
         led = _memory.ledger()
@@ -967,6 +1015,36 @@ class GenerationEngine:
                 int(np.prod(self._draft_shape)) \
                 * np.dtype(self._draft_dtype).itemsize
         return s
+
+    def _aot_site(self, name: str, fn, donate_argnums):
+        """One of this engine's jit sites: its builds are stamped with
+        the launch that asked for them (``scheduler.note_build``) and
+        listed in ``stats()["startup"]``."""
+        site = _registry.aot_site(name, fn, donate_argnums=donate_argnums,
+                                  on_build=self._sched.note_build)
+        self._sites.append(site)
+        return site
+
+    def _startup_stats(self) -> dict:
+        """What standing this engine up took, from the inside:
+        ``t_build`` (``time.perf_counter()`` at the constructor's entry
+        — the flight recorder's clock), ``build_ms`` and its
+        ``phases_ms`` (the ``startup/*`` spans), and ``programs``: every
+        build event of this engine's sites, oldest first
+        (``program_registry.ProgramRecord.builds`` — the ``program/*``
+        spans' times, the persistent cache's hits and misses, the first
+        call) under its ``site``, with the ``launch_rows`` and
+        ``slots_active`` of the launch that asked for the program
+        (``scheduler.note_build``; ``None`` until its first call
+        returned). A program built after the warm-up is listed like any
+        other: its ``at`` says when."""
+        programs = [
+            {"site": site.site, "launch_rows": None, "slots_active": None,
+             **build}
+            for site in list(self._sites)
+            for build in list(site.record.builds)]
+        programs.sort(key=lambda p: p["at"])
+        return {**self._startup, "programs": programs}
 
     def _compute_stats(self) -> dict:
         """Model-FLOPs-per-token and serving MFU, from the fused
@@ -1625,7 +1703,7 @@ class GenerationEngine:
                     top_k=self._top_k, top_p=self._top_p, probe=probe,
                     quantized=self._pool.quantized,
                     qmax=self._pool.qmax or 127.0)
-            fn = _registry.aot_site(
+            fn = self._aot_site(
                 f"serving/fused[q{q_rows},t{table_len}]#{self._eid}",
                 built,
                 donate_argnums=(2, 3) if self._pool.quantized else (2,))
@@ -1704,7 +1782,7 @@ class GenerationEngine:
             from ..models.generation import build_draft_prefill_fn
             probe = _probe.site(
                 f"serving/spec_prefill[{bucket}]#{self._eid}")
-            fn = _registry.aot_site(
+            fn = self._aot_site(
                 f"serving/spec_prefill[{bucket}]#{self._eid}",
                 build_draft_prefill_fn(self._draft_model, bucket,
                                        self._draft_max_len, probe=probe),
@@ -1724,7 +1802,7 @@ class GenerationEngine:
             from ..models.generation import build_draft_propose_scan_fn
             probe = _probe.site(
                 f"serving/spec_draft[k{kmax}]#{self._eid}")
-            fn = _registry.aot_site(
+            fn = self._aot_site(
                 f"serving/spec_draft[k{kmax}]#{self._eid}",
                 build_draft_propose_scan_fn(
                     self._draft_model, self._pool.num_slots,
@@ -1741,7 +1819,7 @@ class GenerationEngine:
             from ..models.generation import build_spec_verify_fn
             probe = _probe.site(
                 f"serving/spec[q{q_rows},t{table_len}]#{self._eid}")
-            fn = _registry.aot_site(
+            fn = self._aot_site(
                 f"serving/spec[q{q_rows},t{table_len}]#{self._eid}",
                 build_spec_verify_fn(self._model, self._pool.num_slots,
                                      q_rows, self._spec_k, table_len,
@@ -1881,14 +1959,14 @@ class GenerationEngine:
                     return (pool.at[:, dst].set(pool[:, src]),
                             scales.at[:, :, dst].set(scales[:, :, src]))
 
-                self._copy_jit = _registry.aot_site(
+                self._copy_jit = self._aot_site(
                     f"serving/copy#{self._eid}", _copy,
                     donate_argnums=(0, 1))
             else:
                 def _copy(pool, dst, src):
                     return pool.at[:, dst].set(pool[:, src])
 
-                self._copy_jit = _registry.aot_site(
+                self._copy_jit = self._aot_site(
                     f"serving/copy#{self._eid}", _copy,
                     donate_argnums=(0,))
         if self._pool.quantized:

@@ -177,6 +177,10 @@ class FrontDoor:
         self._lock = threading.Lock()
         self._served = 0
         self._streamed = 0
+        # time.perf_counter() when the first completion request came
+        # through the door (set once): where the program first sees its
+        # clients — a benchmark's ramp starts here
+        self._first_request_t: Optional[float] = None
         self._shed: Dict[str, int] = {}
         self._ops: Optional[Any] = None      # owned server, if start()ed
         self._writer: Optional[_StreamWriter] = None    # see mount()
@@ -356,6 +360,11 @@ class FrontDoor:
                                        "owned_by": "paddle_tpu"}]})
 
     def _handle_completions(self, h) -> None:
+        if self._first_request_t is None:
+            t = time.perf_counter()
+            with self._lock:            # the first burst arrives together
+                if self._first_request_t is None:
+                    self._first_request_t = t
         tenant, auth_err = self._resolve_tenant(h)
         if auth_err is not None:
             self._reply_error(h, 401, auth_err, "invalid_api_key")
@@ -496,6 +505,7 @@ class FrontDoor:
             w = self._writer
             return {"served": self._served,
                     "streamed": self._streamed,
+                    "first_request_t": self._first_request_t,
                     "shed": dict(self._shed),
                     "shed_total": sum(self._shed.values()),
                     "tenants_seen": sorted(

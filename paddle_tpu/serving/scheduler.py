@@ -853,6 +853,27 @@ class Scheduler:
                 self._rec.update(kv_walks=int(kv_walks),
                                  kv_walks_handed=int(kv_walks_handed))
 
+    def note_build(self, build: dict) -> None:
+        """A program was BUILT by this turn's dispatch (the ``on_build``
+        of the engine's jit sites — ``program_registry.AotSite`` —
+        called on the scheduler thread once the fresh executable's first
+        call returned). The build event learns what the launch that
+        asked for it held: ``launch_rows``, the launch's real rows
+        (:meth:`note_launch`; ``None`` for a program that is no launch —
+        a block copy, the draft's) and ``slots_active``. The live cycle
+        record gets ``built_ms``, the build's parts and first call
+        summed: the one key a record holds ONLY when its dispatch built
+        a program, so the turn that paid shows in the ring."""
+        rec = self._rec
+        if rec is None:
+            return
+        build["launch_rows"] = rec.get("launch_rows")
+        build["slots_active"] = rec["active"]
+        rec["built_ms"] = rec.get("built_ms", 0.0) + sum(
+            build.get(k) or 0.0 for k in (
+                "trace_ms", "lower_ms", "compile_ms", "first_call_ms",
+                "wall_ms"))
+
     def note_spec_dispatches(self, n: int) -> None:
         """Count the draft-proposal programs dispatched THIS cycle into
         the live cycle record (called by the engine's spec step,

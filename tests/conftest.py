@@ -53,6 +53,26 @@ import pytest  # noqa: E402
 
 from _toys import engines, served_model  # noqa: E402,F401  (fixtures)
 
+
+@pytest.fixture
+def cache_env():
+    """Let a test move JAX_COMPILATION_CACHE_DIR and arm the cache, then
+    put the process back as it was (the import hook leaves a CPU-pinned
+    test process unarmed; jax's 1 s persistence floor)."""
+    import jax
+    old = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    was_on = compile_cache.status()["enabled"]
+    yield
+    if old is None:
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    else:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = old
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    if was_on:
+        compile_cache.enable()
+    else:
+        compile_cache.disable()
+
 # Every test has this much wall clock for its setup and its call together,
 # and no way to ask for more: tier-1 runs under one limit for the whole
 # suite, and a test of minutes is paid again by every later PR. A test that

@@ -381,27 +381,6 @@ class TestPrefetchInFit:
         assert monitor.stat_get("prefetch_batches") >= 8
 
 
-@pytest.fixture
-def cache_env():
-    """Let a test move JAX_COMPILATION_CACHE_DIR and arm the cache, then
-    put the process back as it was (the import hook leaves a CPU-pinned
-    test process unarmed; jax's 1 s persistence floor)."""
-    import jax
-    from paddle_tpu.framework import compile_cache
-    old = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    was_on = compile_cache.status()["enabled"]
-    yield
-    if old is None:
-        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-    else:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = old
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    if was_on:
-        compile_cache.enable()
-    else:
-        compile_cache.disable()
-
-
 class TestCompileCache:
     def test_env_dir_is_used_and_no_other(self, tmp_path, cache_env):
         """JAX_COMPILATION_CACHE_DIR set -> the cache is that directory:

@@ -127,6 +127,24 @@ class TestWireProtocol:
         assert (prompt, max_new) == ([5, 6, 7], 3)
         assert kw["tenant"] == "acme" and kw["lane"] == "interactive"
 
+    def test_the_first_request_is_stamped_once(self, door):
+        """``stats()["first_request_t"]``: nothing before a request, the
+        first one's arrival after it, and no later request moves it."""
+        d, _eng, url, base = door
+        assert d.stats()["first_request_t"] is None
+        with urllib.request.urlopen(base + "/v1/models", timeout=30):
+            pass                         # no completion request: no stamp
+        assert d.stats()["first_request_t"] is None
+        t0 = time.perf_counter()
+        assert _post(url, {"prompt": [5], "max_tokens": 1})[0] == 200
+        t1 = time.perf_counter()
+        first = d.stats()["first_request_t"]
+        assert t0 <= first <= t1
+        # a refused request is a request all the same, and moves nothing
+        assert _post(url, {"prompt": "text"})[0] == 400
+        assert _post(url, {"prompt": [5], "max_tokens": 1})[0] == 200
+        assert d.stats()["first_request_t"] == first
+
     def test_finish_reason_stop_on_eos(self):
         eng = _StubEngine(eos=9)
         d = FrontDoor(eng)

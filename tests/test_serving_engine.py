@@ -568,3 +568,77 @@ class TestPoolAndValidation:
         with pytest.raises(ValueError, match="at least one"):
             eng.submit(np.zeros(0, np.int32))
         eng.close()
+
+    def test_startup_stats_say_what_standing_the_engine_up_took(
+            self, served_model):
+        """``stats()["startup"]`` (ISSUE 52): the documented shape, plain
+        data, and a program built long after the first ``stats()`` call is
+        listed like the warm-up's, with the launch that asked for it."""
+        import json
+
+        t_before = time.perf_counter()
+        eng = GenerationEngine(served_model, num_slots=2, max_len=32,
+                               block_size=8, prefill_budget=8)
+        try:
+            first = eng.stats()["startup"]
+            assert set(first) == {"t_build", "build_ms", "phases_ms",
+                                  "programs"}
+            assert first["programs"] == []       # nothing launched yet
+            assert t_before <= first["t_build"] <= time.perf_counter()
+            assert set(first["phases_ms"]) == {
+                "params", "pallas_smoke", "pool", "plan_gate", "scheduler"}
+            assert all(ms >= 0 for ms in first["phases_ms"].values())
+            assert sum(first["phases_ms"].values()) <= first["build_ms"]
+            eng.submit(np.arange(1, 4, dtype=np.int32),
+                       max_new_tokens=2).result(timeout=300)
+            _toys.settle(eng)
+            warm = eng.stats()["startup"]
+            assert {k: warm[k] for k in ("t_build", "build_ms")} == \
+                {k: first[k] for k in ("t_build", "build_ms")}
+            n = len(warm["programs"])
+            assert n >= 1
+            # a longer prompt: a chunk of 8 rows beside nothing — a (Q, T)
+            # nobody launched before, built inside a later launch
+            eng.submit(np.arange(1, 20, dtype=np.int32),
+                       max_new_tokens=2).result(timeout=300)
+            _toys.settle(eng)
+            late = eng.stats()["startup"]
+            cycles = eng.flight_recorder.snapshot()["cycles"]
+        finally:
+            eng.close()
+        assert late == json.loads(json.dumps(late))
+        assert late["programs"][:n] == warm["programs"]
+        assert len(late["programs"]) > n
+        stamps = [p["at"] for p in late["programs"]]
+        assert stamps == sorted(stamps) and stamps[0] > late["t_build"]
+        for p in late["programs"]:
+            assert p["site"].startswith("serving/fused[") \
+                and p["site"].endswith(f"#{eng._eid}")
+            assert p["launch_rows"] >= 1 and 1 <= p["slots_active"] <= 2
+            assert p["first_call_ms"] is not None
+        # the turn that paid for a build says so; the others hold no key
+        paid = [c for c in cycles if "built_ms" in c]
+        assert len(paid) == len(late["programs"]) < len(cycles)
+        for c, p in zip(paid, late["programs"]):
+            assert c["launch_rows"] == p["launch_rows"]
+            assert c["built_ms"] == pytest.approx(
+                p["trace_ms"] + p["lower_ms"] + p["compile_ms"]
+                + p["first_call_ms"])
+            assert c["built_ms"] <= c["decode_dispatch_ms"]
+
+    def test_statusz_shows_the_startup_on_one_line(self, served_model):
+        from paddle_tpu.framework import metrics
+        eng = GenerationEngine(served_model, num_slots=2, max_len=32)
+        try:
+            eng.submit(np.arange(1, 4, dtype=np.int32),
+                       max_new_tokens=2).result(timeout=300)
+            lines = metrics.statusz().splitlines()
+        finally:
+            eng.close()
+        at = next(i for i, ln in enumerate(lines)
+                  if ln.startswith(f"engine #{eng._eid} "))
+        row = lines[at + 1].strip()
+        programs = len(eng.stats()["startup"]["programs"])
+        assert row.startswith("startup: build ") and \
+            f"| {programs} programs: trace " in row
+        assert ", first call " in row and row.endswith(" cache hits)")

@@ -1,8 +1,9 @@
 """The program's own spans in the profiler's trace: every ``record()``
 span is a ``jax.profiler.TraceAnnotation``, so a jax trace taken by
 ANYONE (no ``profile()`` session armed) holds the serving cycle and the
-train step on ``/host:CPU``, with their numbers; and the launch counters
-the engine notes into the cycle record."""
+train step on ``/host:CPU``, with their numbers; the launch counters
+the engine notes into the cycle record; and the engine's own build and
+its programs' builds (``startup/*``, ``program/*``)."""
 import glob
 import os
 import time
@@ -203,6 +204,72 @@ def test_serving_cycles_are_in_the_trace_with_their_children_in_order(
     for s, e, _, _ in prefills:
         assert any(lo <= s and e <= hi for lo, hi, _, _ in admits)
     assert S.events() == []                      # no session, no buffer
+
+
+STARTUP = ["startup/engine_build", "startup/params", "startup/pallas_smoke",
+           "startup/pool", "startup/plan_gate", "startup/scheduler"]
+BUILD = ["program/trace", "program/lower", "program/compile",
+         "program/first_call"]
+
+
+@pytest.fixture(scope="module")
+def a_traced_start(tiny_lm, tmp_path_factory):
+    """A jax trace over an engine's construction and its first request:
+    (the ``/host:CPU`` events by prefix, the engine's startup stats, its
+    cycle records)."""
+    with _JaxTrace(tmp_path_factory.mktemp("start")) as trace:
+        eng = GenerationEngine(tiny_lm, num_slots=2, max_len=32,
+                               block_size=8, prefill_budget=8,
+                               hbm_budget_bytes=1 << 30)
+        try:
+            eng.submit(_prompt(np.random.RandomState(3), 5),
+                       max_new_tokens=2).result(timeout=300)
+        finally:
+            eng.close()
+    return (trace.host_events, eng.stats()["startup"],
+            eng.flight_recorder.snapshot()["cycles"])
+
+
+def test_the_engines_build_is_in_the_trace_part_by_part(a_traced_start):
+    host_events, startup, _ = a_traced_start
+    events = host_events("startup/")
+    assert [e[2] for e in events] == STARTUP     # in time order, once each
+    whole, parts = events[0], events[1:]
+    for (_, end, _, _), (start, _, _, _) in zip(parts, parts[1:]):
+        assert end <= start
+    assert whole[0] <= parts[0][0] and parts[-1][1] <= whole[1]
+    # the same clock stops as stats()["startup"], to a loaded host's hiccups
+    assert (whole[1] - whole[0]) / 1e6 == pytest.approx(
+        startup["build_ms"], abs=50.0)
+    for (lo, hi, name, _) in parts:
+        assert (hi - lo) / 1e6 == pytest.approx(
+            startup["phases_ms"][name[len("startup/"):]], abs=50.0)
+
+
+@pytest.mark.parametrize("part", BUILD)
+def test_a_programs_build_lies_inside_the_dispatch_that_asked_for_it(
+        a_traced_start, part):
+    host_events, startup, cycles = a_traced_start
+    # a span's ``site=`` writes the engine's ``#n`` as ``@n``: ``#`` ends
+    # a TraceMe's arguments
+    programs = {p["site"].replace("#", "@"): p
+                for p in startup["programs"]}
+    events = [e for e in host_events("program/") if e[2] == part]
+    assert len(events) == len(programs) >= 1
+    assert {e[3]["site"] for e in events} == set(programs)
+    dispatches = {e[3]["cycle"]: e
+                  for e in host_events("serving/decode_dispatch")}
+    paid = {c["cycle"] for c in cycles if "built_ms" in c}
+    key = {"program/first_call": "first_call_ms"}.get(
+        part, part[len("program/"):] + "_ms")
+    for lo, hi, _, stats in events:
+        inside = [n for n, (s, e, _, _) in dispatches.items()
+                  if s <= lo and hi <= e]
+        assert len(inside) == 1 and inside[0] in paid
+        assert (hi - lo) / 1e6 == pytest.approx(
+            programs[stats["site"]][key], abs=50.0)
+    # a launch that found its executable paid nothing and says nothing
+    assert len(paid) == len(programs) < len(dispatches)
 
 
 def test_the_cycle_record_carries_the_launch_as_it_was_built(tiny_lm):
